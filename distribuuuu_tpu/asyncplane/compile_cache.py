@@ -1,11 +1,12 @@
 """Persistent compilation cache wiring (the ``COMPILE_CACHE`` node).
 
 A restart — crash recovery, preemption resume, elastic resume at the
-same topology, a rolling serve-replica deploy — pays the full compile
-storm again: every step program, every serve bucket, every reshard
-helper. JAX ships an on-disk executable cache keyed on (program, flags,
-backend); this module turns it on from config, points it at a
-restart-stable directory, and makes its effect OBSERVABLE:
+same topology, a rolling serve-replica deploy, the next call of the chip
+tool — pays the full compile storm again: every step program, every
+serve bucket, every reshard helper. JAX ships an on-disk executable
+cache keyed on (program, flags, backend, cache path); this module puts
+it where ``JAX_COMPILATION_CACHE_DIR`` says or else at one fixed
+directory in the checkout, and makes its effect OBSERVABLE:
 
 * ``jit.cache_hits`` / ``jit.cache_misses`` registry counters and one
   ``kind="compile.cache"`` telemetry record per lookup
@@ -26,6 +27,18 @@ import os
 
 from distribuuuu_tpu.utils.logger import get_logger
 
+ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+
+# Where the cache lives when nobody placed it: one fixed directory at the
+# root of the checkout (git-ignored). The directory is part of the cache
+# key, so it must not move between runs — never OUT_DIR, a tempfile name,
+# a pid or a timestamp.
+CHECKOUT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))),
+    ".compile_cache",
+)
+
 
 def validate_cfg(cc) -> None:
     """Refuse nonsense knob values before they reach jax.config (the
@@ -43,28 +56,36 @@ def validate_cfg(cc) -> None:
 
 
 def setup_from_cfg(cfg) -> str | None:
-    """Apply the ``COMPILE_CACHE`` node. Returns the resolved cache dir
-    when enabled, None otherwise.
+    """Place the persistent compilation cache for this process; returns
+    the active cache dir, or None when the cache is off. The ONE function
+    every entry point that compiles calls (train_net, test_net,
+    serve_net, bench.py, chip_smoke.py), after platform selection — it
+    reads ``jax.default_backend()``.
 
-    The knob is authoritative per run: ENABLED False actively CLEARS any
-    previously-configured cache dir (jax config is process-global —
-    without the clear, a later run in the same process would silently
-    keep writing into the earlier run's cache directory).
+    * ``JAX_COMPILATION_CACHE_DIR`` set: the cache was placed from
+      outside. jax already read the variable into its config; nothing
+      here clears or replaces ``jax_compilation_cache_dir``, whatever
+      the ``COMPILE_CACHE`` node says.
+    * not set: ``COMPILE_CACHE.DIR`` if given, else :data:`CHECKOUT_DIR`.
+      On the TPU backend the cache is on without a knob (a cold chip
+      call compiles every step program and every serve tile); on the
+      CPU it stays opt-in through ``COMPILE_CACHE.ENABLED``, and a
+      disabled run clears a directory an earlier run in the same
+      process set (jax config is process-global).
     """
     import jax
 
     cc = cfg.COMPILE_CACHE
     validate_cfg(cc)
-    if not cc.ENABLED:
-        if getattr(jax.config, "jax_compilation_cache_dir", None):
-            jax.config.update("jax_compilation_cache_dir", None)
-        return None
-    cache_dir = os.path.abspath(
-        cc.DIR or os.path.join(cfg.OUT_DIR, "compile_cache")
-    )
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_enable_compilation_cache", True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    cache_dir = os.environ.get(ENV_DIR)
+    if not cache_dir:
+        if not (cc.ENABLED or jax.default_backend() == "tpu"):
+            if jax.config.jax_compilation_cache_dir:
+                jax.config.update("jax_compilation_cache_dir", None)
+            return None
+        cache_dir = os.path.abspath(cc.DIR or CHECKOUT_DIR)
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     # jax's own default (1s) skips everything test/CPU-sized; the node
     # default (0) persists every compile — restarts are what we optimize
     jax.config.update(
@@ -82,8 +103,10 @@ def setup_from_cfg(cfg) -> str | None:
 
     telemetry_runtime.install_compile_listener()
     get_logger().info(
-        "persistent compilation cache: %s (min_compile_time %.3fs%s)",
-        cache_dir, float(cc.MIN_COMPILE_TIME_S),
+        "persistent compilation cache: %s (%s; min_compile_time %.3fs%s)",
+        cache_dir,
+        f"from {ENV_DIR}" if os.environ.get(ENV_DIR) else "set here",
+        float(cc.MIN_COMPILE_TIME_S),
         f", max {int(cc.MAX_SIZE_MB)} MB" if int(cc.MAX_SIZE_MB) else "",
     )
     return cache_dir

@@ -45,6 +45,7 @@ to the full teacher-forced ``GPT.__call__`` forward — the test
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import threading
@@ -832,9 +833,11 @@ class GenerateEngine:
         self._draft_prefill_exec: dict[int, Any] = {}
         self._draft_insert_exec: dict[tuple[int, int, int], Any] = {}
         self._draft_grow_exec: dict[tuple, Any] = {}
-        self._compile_tiles()
-        if self.spec_k:
-            self._compile_draft_tiles()
+        # every tile executable traces inside the engine's kernel scope
+        with self._kernel_scope():
+            self._compile_tiles()
+            if self.spec_k:
+                self._compile_draft_tiles()
 
         # -- live state ----------------------------------------------------
         self._lock = threading.Condition()
@@ -902,6 +905,17 @@ class GenerateEngine:
             ),
             variables, shardings,
         )
+
+    def _kernel_scope(self):
+        """Trace-time scope of the tile executables: an engine without a
+        mesh compiles for its one device, so the decode kernel may engage
+        (ops/pallas/__init__.py); the TP engine's cache is head-sharded
+        under GSPMD, which cannot partition a Mosaic call."""
+        from distribuuuu_tpu.ops import pallas as kernel_tier
+
+        if self._mesh is None:
+            return kernel_tier.single_device_program()
+        return contextlib.nullcontext()
 
     def _jit(self, fn, *, donate=()):
         """jax.jit with the TP output contract pinned when a mesh is

@@ -47,21 +47,13 @@ def _axis_is_bound(name: str) -> bool:
     we are inside a shard_map body). Trace-time check — resolves before
     compilation, so both branches stay jit-compatible.
 
-    Pinned JAX behavior (ADVICE r3 #3): the axis-size probe raises
-    ``NameError`` for an unbound axis name as of jax 0.4-0.7. That
-    exception type is not a stable API, so any exception here is treated
-    as 'unbound' — the safe default: selecting the fallback path at worst
-    costs the inline optimization, while crashing at trace time would
-    take the whole PP-MoE step down with a future JAX. Routed through
-    parallel/compat.axis_size (r6): a bare ``jax.lax.axis_size`` does not
-    exist on jax 0.4.x, so the probe ALWAYS took the except branch there
-    and silently disabled the inline path."""
-    from distribuuuu_tpu.parallel.compat import axis_size
-
+    ``jax.lax.axis_size`` raises ``NameError`` for an unbound axis name
+    on jax 0.9.0 (the supported installation); the PP×EP trainer tests
+    exercise both outcomes."""
     try:
-        axis_size(name)
+        jax.lax.axis_size(name)
         return True
-    except Exception:
+    except NameError:
         return False
 
 
@@ -174,9 +166,7 @@ class MoeMlp(nn.Module):
             # shard_map is illegal; the collectives compose fine on the
             # bound axes). x is this rank's token shard. Collapses to the
             # dense loop + free collectives at model-axis size 1.
-            from distribuuuu_tpu.parallel.compat import axis_size
-
-            n = axis_size(MODEL_AXIS)
+            n = jax.lax.axis_size(MODEL_AXIS)
             r = jax.lax.axis_index(MODEL_AXIS)
             if E % n:
                 raise ValueError(

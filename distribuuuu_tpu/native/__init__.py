@@ -66,23 +66,34 @@ def _build() -> str | None:
     return None
 
 
+def _unavailable(why: str) -> None:
+    """Record why the kernel is out, and say once that decoding runs on
+    the pure-PIL path (``DATA.BACKEND auto`` falls back without asking)."""
+    global _build_error
+    _build_error = why
+    from distribuuuu_tpu.utils.logger import get_logger
+
+    get_logger().warning(
+        "native decode kernel unavailable — image decode falls back to "
+        "the pure-PIL path (DATA.BACKEND native would refuse): %s", why,
+    )
+
+
 def _load():
-    global _lib, _build_error
+    global _lib
     with _lock:
         if _lib is not None or _build_error is not None:
             return _lib
         err = _build()
         if err is not None:
-            _build_error = err
-            return None
+            return _unavailable(err)
         try:
             lib = ctypes.CDLL(_LIB)
         except OSError as exc:
-            _build_error = f"native lib load failed: {exc}"
-            return None
+            return _unavailable(f"native lib load failed: {exc}")
         if lib.dtpu_abi_version() != _ABI_VERSION:
-            _build_error = "native ABI mismatch (stale _libdtpu_decode.so?)"
-            return None
+            return _unavailable(
+                "native ABI mismatch (stale _libdtpu_decode.so?)")
         lib.dtpu_file_dims.restype = ctypes.c_int
         lib.dtpu_file_dims.argtypes = [
             ctypes.c_char_p,

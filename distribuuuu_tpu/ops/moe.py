@@ -34,7 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from distribuuuu_tpu.parallel.compat import axis_size, shard_map
 
 
 def init_moe_params(key, d_model: int, d_ff: int, num_experts: int):
@@ -200,11 +199,12 @@ def moe_ffn_partial(params, x, *, mesh, axis: str = "model", top_k: int = 2):
     def per_rank(params, x):
         return _rank_partials(params, x, axis, top_k)
 
-    return shard_map(
+    return jax.shard_map(
         per_rank,
         mesh=mesh,
         in_specs=(_moe_param_specs(axis), P()),
         out_specs=P(),
+        check_vma=False,
     )(params, x)
 
 
@@ -237,11 +237,12 @@ def moe_ffn_partial_batched(
 
     data_sharded = bool(data_axis) and mesh.shape.get(data_axis, 1) > 1
     x_spec = P(data_axis) if data_sharded else P()
-    return shard_map(
+    return jax.shard_map(
         per_rank,
         mesh=mesh,
         in_specs=(_moe_param_specs(axis), x_spec),
         out_specs=x_spec,
+        check_vma=False,
     )(params, x)
 
 
@@ -350,11 +351,12 @@ def moe_ffn_dispatch(
         )
         return out
 
-    return shard_map(
+    return jax.shard_map(
         per_rank,
         mesh=mesh,
         in_specs=(_moe_param_specs(axis), P(axis)),
         out_specs=P(axis),
+        check_vma=False,
     )(params, x)
 
 
@@ -401,11 +403,12 @@ def moe_ffn_dispatch_batched(
         )
 
     x_spec = P(data_axis) if data_sharded else P()
-    return shard_map(
+    return jax.shard_map(
         per_rank,
         mesh=mesh,
         in_specs=(_moe_param_specs(axis), x_spec),
         out_specs=(x_spec, P()),
+        check_vma=False,
     )(params, x)
 
 
@@ -435,7 +438,7 @@ def dispatch_inline(
     ``axes_bound`` — a nested shard_map would be illegal, but the
     collectives compose fine on the already-bound axes; VERDICT r3 #3).
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     E = params_local["gate"].shape[-1]
     B_l, S, d = xl.shape
     T = B_l * S

@@ -34,6 +34,18 @@ Tier discipline (every kernel, no exceptions):
   — the same ``pallas_call`` with ``interpret=True``) and a pinned
   bit-exactness or tolerance A/B test against the XLA reference
   (tests/test_pallas_kernels.py).
+* a compiled kernel never meets GSPMD bare: XLA refuses to partition a
+  Mosaic call ("Mosaic kernels cannot be automatically partitioned"), so
+  on a program that spans several devices a kernel runs under
+  ``shard_map`` or not at all. ``opt_update`` has the state layout and
+  lowers per shard on every multi-device mesh. ``conv_epilogue`` and
+  ``decode_attn`` sit inside model code that knows no mesh: they engage
+  only where the caller declared a one-device program
+  (:func:`single_device_program` — the serving engines, one replica per
+  chip) or the backend has one device; in the trainer's eval step on a
+  dp mesh and in the tensor-parallel decode engine ``auto`` means
+  ``xla`` (PERF.md "Bring-up on the chip tool"; ROADMAP S1 decides
+  whether they earn a shard_map of their own).
 
 This tier supersedes the repo's earlier one-off Pallas work: the retired
 r5 BoTNet attention kernel (deleted at 0.854× XLA e2e — PERF.md) and the
@@ -45,6 +57,9 @@ boundary).
 
 from __future__ import annotations
 
+import contextlib
+import threading
+
 VALID_IMPLS = ("auto", "pallas", "xla")
 
 # op name -> KERNELS knob
@@ -54,10 +69,14 @@ KNOBS = {
     "decode_attn": "DECODE_ATTN",
 }
 
+# ops whose call sites have no mesh to shard_map over (module docstring)
+_NO_SHARD_MAP = ("conv_epilogue", "decode_attn")
+
 # process-lifetime emission/warn dedup: one kernel.select per (op, impl,
 # requested) resolution, one kernel.fallback + warning per (op, reason)
 _emitted: set = set()
 _warned: set = set()
+_program = threading.local()  # trace-time: is this a one-device program?
 
 
 def reset_selection() -> None:
@@ -109,6 +128,32 @@ def interpret_mode() -> bool:
     return jax.default_backend() != "tpu"
 
 
+@contextlib.contextmanager
+def single_device_program():
+    """Declare that the code traced inside compiles for ONE device (a
+    serving engine pinned to its chip), whatever ``jax.device_count()``
+    says — the kernels without a shard_map of their own may engage."""
+    prev = getattr(_program, "single", False)
+    _program.single = True
+    try:
+        yield
+    finally:
+        _program.single = prev
+
+
+def compiled_across_devices() -> bool:
+    """True when a kernel traced here would be a Mosaic call in a program
+    that may span several devices — which GSPMD refuses to partition.
+    Interpret mode is plain jax ops and partitions like any other."""
+    import jax
+
+    return (
+        not interpret_mode()
+        and not getattr(_program, "single", False)
+        and jax.device_count() > 1
+    )
+
+
 def _emit_once(key, kind: str, **fields) -> None:
     if key in _emitted:
         return
@@ -141,6 +186,11 @@ def select(op: str, *, supported: bool = True, reason: str = "") -> str:
     """
     if op not in KNOBS:
         raise ValueError(f"unknown kernel op {op!r} — one of {list(KNOBS)}")
+    if supported and op in _NO_SHARD_MAP and compiled_across_devices():
+        supported, reason = False, (
+            "the program may span several devices, GSPMD cannot partition "
+            "a Mosaic call, and this call site has no shard_map"
+        )
     req = requested(op)
     if req == "xla":
         impl = "xla"

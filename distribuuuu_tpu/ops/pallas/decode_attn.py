@@ -29,6 +29,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from distribuuuu_tpu.ops.flash_attention import _NEG_BIG
 
@@ -65,21 +66,28 @@ def supported(t: int, cache_len: int, head_dim: int,
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, scale, blk_k):
-    q = q_ref[0, 0].reshape(1, -1).astype(jnp.float32)  # [1, D]
+    # len_ref is the scalar-prefetched [B] lengths vector (SMEM): the
+    # row's length is a loop bound, and Mosaic reads loop bounds from
+    # scalar memory, never from a VMEM tile
+    q = q_ref[0, 0].astype(jnp.float32)  # [1, D]
     d = q.shape[1]
     c = k_ref.shape[2]
     nk = c // blk_k
-    length = len_ref[0, 0]
+    length = len_ref[pl.program_id(0)]
 
     def body(t, carry):
         m, l, acc = carry
-        kb = k_ref[0, 0, pl.ds(t * blk_k, blk_k), :].astype(jnp.float32)
-        vb = v_ref[0, 0, pl.ds(t * blk_k, blk_k), :].astype(jnp.float32)
+        # t is a Python 0 for a one-block tile: a static slice, which
+        # Mosaic takes at any height (a dynamic one must be provably
+        # aligned to the dtype's sublane packing)
+        start = t * blk_k if nk == 1 else pl.multiple_of(t * blk_k, blk_k)
+        kb = k_ref[0, 0, pl.ds(start, blk_k), :].astype(jnp.float32)
+        vb = v_ref[0, 0, pl.ds(start, blk_k), :].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale  # [1, blk_k]
-        kpos = t * blk_k + jax.lax.broadcasted_iota(jnp.int32, (1, blk_k), 1)
+        kpos = start + jax.lax.broadcasted_iota(jnp.int32, (1, blk_k), 1)
         # the new token sits at absolute position ``length``: keys
         # 0..length inclusive are visible, stale tail positions masked
         s = jnp.where(kpos <= length, s, _NEG_BIG)
@@ -93,12 +101,15 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, scale, blk_k):
     # ragged block-skip: blocks starting past this row's length are fully
     # masked — never read them (the continuous-batching win: a short row
     # in a long tile reads only its own live blocks)
-    nk_hi = jnp.minimum(nk, length // blk_k + 1)
     m0 = jnp.full((1, 1), _NEG_BIG, jnp.float32)
     l0 = jnp.zeros((1, 1), jnp.float32)
     a0 = jnp.zeros((1, d), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, nk_hi, body, (m0, l0, a0))
-    o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).reshape(d)
+    if nk == 1:
+        m, l, acc = body(0, (m0, l0, a0))
+    else:
+        nk_hi = jnp.minimum(nk, length // blk_k + 1)
+        m, l, acc = jax.lax.fori_loop(0, nk_hi, body, (m0, l0, a0))
+    o_ref[0, 0] = acc / jnp.maximum(l, 1e-30)
 
 
 def decode_attention(q, cache_k, cache_v, lengths, *, scale: float,
@@ -117,20 +128,28 @@ def decode_attention(q, cache_k, cache_v, lengths, *, scale: float,
         raise ValueError(
             f"decode_attention: block {blk_k} does not divide cache {c}"
         )
-    lens = lengths.astype(jnp.int32).reshape(b, 1)
-    return pl.pallas_call(
+    # q and the output ride as [B, H, 1, D] so their (1, D) blocks equal
+    # the arrays' last two dims — Mosaic takes a block whose trailing
+    # dims are (8, 128)-divisible or whole, and a (1, D) row of [H, D]
+    # is neither
+    out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, blk_k=blk),
-        out_shape=jax.ShapeDtypeStruct((b, h, d), jnp.float32),
-        grid=(b, h),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, 1, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 1, c, d), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, c, d), lambda i, j: (i, j, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda i, j: (i, j, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, 1, d), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h),
+            in_specs=[
+                pl.BlockSpec((1, 1, 1, d), lambda i, j, lens: (i, j, 0, 0)),
+                pl.BlockSpec((1, 1, c, d), lambda i, j, lens: (i, j, 0, 0)),
+                pl.BlockSpec((1, 1, c, d), lambda i, j, lens: (i, j, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, 1, 1, d), lambda i, j, lens: (i, j, 0, 0)
+            ),
+        ),
         interpret=interpret,
-    )(lens, q, cache_k, cache_v)
+    )(lengths.astype(jnp.int32), q[:, :, None, :], cache_k, cache_v)
+    return out[:, :, 0, :]
 
 
 def pass_bytes(b: int, h: int, c: int, d: int, cache_dtype) -> int:

@@ -26,8 +26,10 @@ update (pinned by test). Under a ZeRO layout the kernel lowers
 PER-SHARD via :func:`per_shard_update` (shard_map over the rest
 layout): each rank runs the one-pass kernel on its own 1/N slice, no
 gather and no re-scatter — the fusion point of the gather-once schedule
-(ISSUE 15, delivered ROADMAP #1). Plain-replicated layouts run the
-whole-leaf call unchanged.
+(ISSUE 15, delivered ROADMAP #1). A plain-replicated layout on several
+devices goes through the same shard_map (every rank updates its own
+replica): XLA refuses to partition a bare Mosaic call. Only a one-device
+mesh runs the whole-leaf call unwrapped.
 """
 
 from __future__ import annotations
@@ -272,20 +274,30 @@ def fused_optimizer_update(params, grads, opt_state, *, kind: str,
     return new_params, new_state
 
 
-def fused_update_for(optimizer_kind: str | None = None):
+def fused_update_for(optimizer_kind: str | None = None, layout=None):
     """The trainer hook (partition/lowering.py): resolve KERNELS.OPT_UPDATE
     for the configured optimizer and return the fused update callable, or
     ``None`` when the XLA reference path should run. Captures the OPTIM
-    hyperparams at step-build time, like the optax chain itself does."""
+    hyperparams at step-build time, like the optax chain itself does.
+
+    ``layout`` is the ``specs.state_layout`` dict of the step being
+    built: on a mesh of several devices the update lowers per shard
+    through it (:func:`per_shard_update`). A step built without one
+    (direct callers of the step builders) cannot shard_map, so where the
+    kernel would compile into a multi-device program it is unsupported
+    and the optax chain runs."""
     from distribuuuu_tpu.config import cfg
     from distribuuuu_tpu.ops import pallas as tier
 
     kind = optimizer_kind or str(cfg.OPTIM.OPTIMIZER)
     supported = kind in ("sgd", "adamw")
-    impl = tier.select(
-        "opt_update", supported=supported,
-        reason="" if supported else f"optimizer {kind!r} has no fused kernel",
-    )
+    reason = "" if supported else f"optimizer {kind!r} has no fused kernel"
+    if supported and layout is None and tier.compiled_across_devices():
+        supported, reason = False, (
+            "the step was built without a state layout to shard_map over, "
+            "and GSPMD cannot partition a Mosaic call across devices"
+        )
+    impl = tier.select("opt_update", supported=supported, reason=reason)
     if impl != "pallas":
         return None
     interpret = tier.interpret_mode()
@@ -303,7 +315,9 @@ def fused_update_for(optimizer_kind: str | None = None):
     def update(params, grads, opt_state):
         return fused_optimizer_update(params, grads, opt_state, **kwargs)
 
-    return update
+    if layout is None or jax.tree.leaves(layout["grads"])[0].mesh.size == 1:
+        return update
+    return per_shard_update(update, layout)
 
 
 def per_shard_update(update, layout):
@@ -329,7 +343,6 @@ def per_shard_update(update, layout):
     shard_specs = jax.tree.map(lambda sh: sh.spec, layout["grads"])
 
     def call(params, grads, opt_state):
-        from distribuuuu_tpu.parallel.compat import shard_map
         from jax.sharding import PartitionSpec as P
 
         tdef = jax.tree.structure(params)
@@ -349,10 +362,10 @@ def per_shard_update(update, layout):
         # trees (param-structured) ride the shard specs, everything else
         # (counters, hyperparams) is replicated
         ospecs = jax.tree.map(place, opt_state, is_leaf=is_param_shaped)
-        fn = shard_map(
+        fn = jax.shard_map(
             update, mesh=mesh,
             in_specs=(shard_specs, shard_specs, ospecs),
-            out_specs=(shard_specs, ospecs),
+            out_specs=(shard_specs, ospecs), check_vma=False,
         )
         return fn(params, grads, opt_state)
 

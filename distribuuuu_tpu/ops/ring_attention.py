@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from distribuuuu_tpu.parallel.compat import axis_size, shard_map
 
 _NEG_BIG = -0.7 * float(jnp.finfo(jnp.float32).max)  # safe additive -inf
 
@@ -125,7 +124,7 @@ def ring_self_attention(
     and masks them), and the local block runs the kernel's causal
     block-skip — ring + causal flash composition (VERDICT r3 #4).
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     b, h, sq, d = q.shape
     sk = k.shape[2]
@@ -220,7 +219,7 @@ def ulysses_self_attention(
     runs full (flash-style fp32-softmax) attention on the local head subset,
     and re-shards back. heads must divide by the axis size.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     assert q.shape[1] % n == 0, (
         f"heads {q.shape[1]} not divisible by seq axis {n}"
     )
@@ -269,8 +268,9 @@ def ring_attention(
         ring_self_attention, axis_name=seq_axis, causal=causal, scale=scale,
         impl=impl,
     )
-    return shard_map(
-        fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
     )(q, k, v)
 
 
@@ -284,8 +284,9 @@ def ulysses_attention(
     fn = functools.partial(
         ulysses_self_attention, axis_name=seq_axis, causal=causal, scale=scale
     )
-    return shard_map(
-        fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
     )(q, k, v)
 
 

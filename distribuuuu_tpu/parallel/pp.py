@@ -31,7 +31,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from distribuuuu_tpu.parallel.compat import axis_size, shard_map
 
 
 _logged_schedules: set[tuple[int, int]] = set()
@@ -113,7 +112,7 @@ def pipeline_apply(
       channel; gradients flow through the scan carry, so an aux-derived
       loss term trains correctly through the pipeline).
     """
-    S = axis_size(axis)
+    S = jax.lax.axis_size(axis)
     s = jax.lax.axis_index(axis)
     M = microbatches.shape[0]
     T = M + S - 1
@@ -261,12 +260,13 @@ def pipelined(
     # per-microbatch batch dim sharded over data (when present)
     out_spec = P(None, data_axis) if data_sharded else P()
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(param_specs if param_specs is not None else P(axis),
                   batch_spec),
         out_specs=(out_spec, P()) if stage_aux else out_spec,
+        check_vma=False,
     )
 
     def apply(stacked_params, batch):

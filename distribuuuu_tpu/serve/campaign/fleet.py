@@ -31,7 +31,13 @@ from __future__ import annotations
 import os
 import threading
 
-from distribuuuu_tpu.serve.fleet.pool import PoolManager, spawn_serve_net
+from distribuuuu_tpu.serve.fleet.pool import (
+    ChipSlots,
+    PoolManager,
+    check_fleet_fits,
+    local_chips,
+    spawn_serve_net,
+)
 from distribuuuu_tpu.serve.fleet.router import Router
 from distribuuuu_tpu.utils.logger import get_logger
 
@@ -69,6 +75,12 @@ class MultiModelFleet:
         self._targets: dict[str, int] = {}
         self._cfg_paths: dict[str, str] = {}
         self.logger = get_logger()
+        # every pool's replicas draw on the same local chips
+        n_chips = local_chips(cfg.DEVICE.PLATFORM)
+        check_fleet_fits(
+            sum(int(s.get("replicas", 1)) for s in model_specs), n_chips
+        )
+        chips = ChipSlots(n_chips)
         for spec in model_specs:
             bad = sorted(set(spec) - _SPEC_KEYS)
             if bad:
@@ -89,7 +101,7 @@ class MultiModelFleet:
                 self.router,
                 spawn_serve_net(
                     cfg_path, host=cfg.SERVE.HOST,
-                    out_dir=os.path.join(model_dir, "fleet"),
+                    out_dir=os.path.join(model_dir, "fleet"), chips=chips,
                 ),
                 model=name,
                 host=cfg.SERVE.HOST,

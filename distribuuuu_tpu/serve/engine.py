@@ -220,7 +220,12 @@ class Engine:
             from distribuuuu_tpu.data.transforms import normalize_in_graph
 
             images = normalize_in_graph(images)
-        return self.model.apply(variables, images, train=False)
+        # one replica, one chip: the kernels with no shard_map of their
+        # own may engage (ops/pallas/__init__.py)
+        from distribuuuu_tpu.ops import pallas as kernel_tier
+
+        with kernel_tier.single_device_program():
+            return self.model.apply(variables, images, train=False)
 
     # -- client surface ----------------------------------------------------
     def start(self) -> "Engine":

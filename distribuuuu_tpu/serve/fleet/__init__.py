@@ -27,9 +27,13 @@ from distribuuuu_tpu.serve.fleet.autoscale import (  # noqa: F401
     Observation,
 )
 from distribuuuu_tpu.serve.fleet.pool import (  # noqa: F401
+    ChipSlots,
     FleetService,
     PoolManager,
+    check_fleet_fits,
     free_port,
+    local_chips,
+    one_chip_env,
     probe_stats,
     spawn_serve_net,
     warmed_up,

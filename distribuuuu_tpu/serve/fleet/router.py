@@ -846,6 +846,7 @@ class Router:
                 "warm_jit_compiles": r.warm_jit_compiles,
                 "aot_compiles": int(r.stats.get("aot_compiles", 0)),
                 "model": r.model,
+                "device": str(r.stats.get("device", "")),
             }
             for r in reps
         ]

@@ -104,17 +104,34 @@ def split_model_envelope(payload: bytes) -> tuple[str | None, bytes]:
     return mid.decode("utf-8"), payload[start + n:]
 
 
+def engine_device(engine) -> str:
+    """Which device(s) hold the engine's weights, as jax names them, plus
+    the chip the fleet pool handed this process (``TPU_VISIBLE_CHIPS``:
+    every one-chip replica sees its own chip as local device 0)."""
+    import jax
+
+    devs = sorted(
+        jax.tree.leaves(engine._variables)[0].devices(), key=lambda d: d.id
+    )
+    chip = os.environ.get("TPU_VISIBLE_CHIPS")
+    return ",".join(str(d) for d in devs) + (
+        f" (chip {chip})" if chip is not None else ""
+    )
+
+
 def replica_stats(engine) -> dict:
     """The replica-side stats snapshot a ``ctrl_request("stats")`` returns:
     the engine's metrics/queue view plus the process-global ``jit.compiles``
     counter (telemetry/runtime.py's compile listener) — how the fleet
-    asserts zero steady-state recompiles across every replica."""
+    asserts zero steady-state recompiles across every replica — and the
+    device the replica runs on."""
     from distribuuuu_tpu.telemetry import registry as telemetry_registry
 
     reg = telemetry_registry.get_registry()
     out = engine.stats()
     out.update(
         pid=os.getpid(),
+        device=engine_device(engine),
         accepting=engine._admission.is_open,
         jit_compiles=int(reg.counter("jit.compiles").value),
         aot_compiles=int(reg.counter("serve.aot_compiles").value),

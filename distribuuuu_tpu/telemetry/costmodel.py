@@ -94,7 +94,7 @@ ANALYTIC_FWD_FLOPS_PER_IMG: dict[str, float] = {
 TRAIN_FLOPS_MULT = 3.0
 
 _seen_labels: set[str] = set()
-_state: dict = {"memory_skip_logged": False}
+_state: dict = {}
 
 
 def reset() -> None:
@@ -395,7 +395,10 @@ b = opts.executable_build_options
 b.num_partitions = nparts
 b.num_replicas = nreps
 b.use_spmd_partitioning = nparts > 1
-exe = jax.devices()[0].client.compile(text, opts)
+devs = jax.devices()[: nparts * nreps]
+exe = devs[0].client.compile_and_load(
+    text, xla_client.DeviceList(tuple(devs)), opts
+)
 ms = exe.get_compiled_memory_stats()
 print("PROBE_MEM " + json.dumps({
     "argument_size_in_bytes": ms.argument_size_in_bytes,
@@ -463,30 +466,19 @@ def probe_memory_subprocess(lowered, timeout: float = 600.0) -> dict | None:
 
 
 def _memory_analysis(lowered) -> dict | None:
-    """The memory half of the ledger for one lowered program. With the
-    persistent compilation cache INACTIVE: the plain in-process AOT
-    compile. With the cache active: the subprocess-isolated probe on the
-    CPU backend (one logged notice), so cache + HBM ledger coexist —
-    PR 10's mutual-exclusion caveat is gone. A cache-active non-CPU
-    backend still skips (the corruption was pinned on CPU; other
-    backends are unproven either way and a production chip is not the
-    place to find out)."""
+    """The memory half of the ledger for one lowered program: the plain
+    in-process AOT compile, except on the CPU backend with the persistent
+    compilation cache active, where the compile runs in the
+    subprocess-isolated probe (one logged notice) — the heap corruption
+    that motivated the probe was pinned on the CPU backend. On the TPU
+    the cache is always on (asyncplane/compile_cache.py) and the
+    in-process compile is the cached executable's own analysis; PERF.md
+    "Bring-up on the chip tool" records the run that checked it."""
     import jax
 
-    if not getattr(jax.config, "jax_compilation_cache_dir", None):
+    if (not jax.config.jax_compilation_cache_dir
+            or jax.devices()[0].platform != "cpu"):
         return normalize_memory(lowered.compile().memory_analysis())
-    if jax.devices()[0].platform != "cpu":
-        if not _state.get("memory_skip_logged"):
-            _state["memory_skip_logged"] = True
-            from distribuuuu_tpu.utils.logger import get_logger
-
-            get_logger().info(
-                "cost-model HBM ledger (TELEMETRY.COSTMODEL_MEMORY) "
-                "skipped: persistent compilation cache active on a "
-                "non-CPU backend — the subprocess probe is CPU-only; "
-                "disable COMPILE_CACHE for a run that needs the ledger"
-            )
-        return None
     if not _state.get("memory_probe_logged"):
         _state["memory_probe_logged"] = True
         from distribuuuu_tpu.utils.logger import get_logger

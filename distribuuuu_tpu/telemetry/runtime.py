@@ -40,7 +40,8 @@ import time
 
 from distribuuuu_tpu.telemetry import registry as registry_lib, spans
 
-# the monitoring key of one backend compilation (jax 0.4.x); the other
+# the monitoring key of one backend compilation (jax 0.9.0:
+# /jax/core/compile/backend_compile_duration); the other
 # /jax/core/compile/* keys are sub-phases of the same compile
 _COMPILE_EVENT = "backend_compile"
 # persistent-compilation-cache lookup outcomes (same bus, plain events);

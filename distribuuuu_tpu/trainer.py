@@ -1215,8 +1215,9 @@ def train_model():
     state = create_train_state(model, key, mesh, cfg.TRAIN.IM_SIZE, layout=layout)
     m_params, mb = count_parameters(state.params)
     logger.info(
-        "model %s: %.3fM params (%.2f MB fp32), mesh %s [%s]",
+        "model %s: %.3fM params (%.2f MB fp32), mesh %s [%s], %s",
         cfg.MODEL.ARCH, m_params, mb, dict(mesh.shape), topo.class_name(),
+        mesh_lib.describe_devices(),
     )
 
     train_loader = construct_train_loader()

@@ -21,14 +21,18 @@ _LOGGER_NAME = "distribuuuu_tpu"
 _configured = False
 
 
-def setup_logger() -> logging.Logger:
+def setup_logger(rank: int | None = None) -> logging.Logger:
+    """``rank`` defaults to ``jax.process_index()`` — which initializes
+    the backend and so takes every chip the process can see. A process
+    that must stay off the chips (the fleet router) passes its rank."""
     global _configured
     logger = logging.getLogger(_LOGGER_NAME)
     if _configured:
         return logger
     logger.setLevel(logging.INFO)
     logger.propagate = False
-    rank = jax.process_index()
+    if rank is None:
+        rank = jax.process_index()
     fmt = logging.Formatter(
         fmt=f"%(asctime)s | %(levelname)s | p{rank} | %(message)s",
         datefmt="%Y-%m-%d %H:%M:%S",

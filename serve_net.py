@@ -86,11 +86,14 @@ def main(argv=None):
     telemetry.setup_from_cfg(
         cfg, rank=int(os.environ.get("DTPU_REPLICA_RANK", "0"))
     )
-    # persistent compilation cache (COMPILE_CACHE): a restarted or
-    # replacement replica deserializes its AOT bucket executables from
-    # disk instead of paying the warm-up compile storm again
+    # persistent compilation cache (asyncplane/compile_cache.py): a
+    # restarted or replacement replica deserializes its AOT bucket
+    # executables from disk instead of paying the warm-up compile storm
+    # again. Placed after platform selection — it reads the backend.
     from distribuuuu_tpu.asyncplane import compile_cache
+    from distribuuuu_tpu.parallel import mesh as mesh_lib
 
+    mesh_lib.apply_platform(cfg.DEVICE.PLATFORM)
     compile_cache.setup_from_cfg(cfg)
     if cfg.MODEL.ARCH.startswith("gpt"):
         # the LM generation plane (lm/service.py): KV-cache continuous
@@ -107,18 +110,18 @@ def main(argv=None):
         engine = lm_service.engine_from_cfg()
         logger.info(
             "generating with %s: %d tile executables compiled "
-            "(decode tiles %s), %d slots, prompt<=%d, max_new=%d",
+            "(decode tiles %s), %d slots, prompt<=%d, max_new=%d, %s",
             cfg.MODEL.ARCH, engine.n_compiles,
             sorted(engine._decode_exec), engine.n_slots,
-            engine.prompt_len, engine.max_new,
+            engine.prompt_len, engine.max_new, _device_line(engine),
         )
     else:
         engine = engine_from_cfg()
         logger.info(
             "serving %s: buckets %s compiled (%d shapes), max_wait %.1f ms, "
-            "queue bound %d",
+            "queue bound %d, %s",
             cfg.MODEL.ARCH, engine.buckets, engine.n_compiles,
-            cfg.SERVE.MAX_WAIT_MS, cfg.SERVE.MAX_QUEUE,
+            cfg.SERVE.MAX_WAIT_MS, cfg.SERVE.MAX_QUEUE, _device_line(engine),
         )
     engine.start()
 
@@ -144,6 +147,19 @@ def main(argv=None):
     logger.info("drained; exiting")
 
 
+def _device_line(engine) -> str:
+    """The backend and the device(s) this engine's weights live on — so a
+    replica that fell back to the CPU, or two replicas on one chip, show
+    in the start-up line."""
+    from distribuuuu_tpu.parallel import mesh as mesh_lib
+    from distribuuuu_tpu.serve import protocol
+
+    return (
+        f"{mesh_lib.describe_devices()}, engine on "
+        f"{protocol.engine_device(engine)}"
+    )
+
+
 def run_fleet(n: int):
     """The ``--fleet N`` entrypoint: this process is the router; replicas
     are child ``serve_net.py`` processes spawned from a dump of the merged
@@ -155,7 +171,10 @@ def run_fleet(n: int):
     from distribuuuu_tpu.utils.jsonlog import setup_metrics_log
     from distribuuuu_tpu.utils.logger import get_logger, setup_logger
 
-    setup_logger()
+    # the router never initializes a jax backend: a process that has
+    # touched jax holds every chip it can see, and the chips are the
+    # replicas'. (setup_logger would ask jax for the process index.)
+    setup_logger(rank=0)
     logger = get_logger()
     telemetry.setup_from_cfg(cfg, rank=0)  # replicas take ranks 1..N
     setup_metrics_log(cfg.OUT_DIR)

@@ -265,7 +265,8 @@ def test_concurrent_eval_relaunch_guard_and_error_propagation():
 
 
 # ----------------------------------------------------------- compile cache
-def test_compile_cache_config_validation(tmp_path):
+def test_compile_cache_config_validation(tmp_path, monkeypatch):
+    monkeypatch.delenv(compile_cache.ENV_DIR, raising=False)
     cfg.COMPILE_CACHE.MIN_COMPILE_TIME_S = -1.0
     with pytest.raises(ValueError, match="MIN_COMPILE_TIME_S"):
         compile_cache.setup_from_cfg(cfg)
@@ -274,16 +275,76 @@ def test_compile_cache_config_validation(tmp_path):
     with pytest.raises(ValueError, match="MAX_SIZE_MB"):
         compile_cache.setup_from_cfg(cfg)
     config.reset_cfg()
-    assert compile_cache.setup_from_cfg(cfg) is None  # disabled → no-op
+    # CPU backend, not enabled: off (the cache is opt-in off the chip)
+    assert compile_cache.setup_from_cfg(cfg) is None
     cfg.COMPILE_CACHE.ENABLED = True
     cfg.COMPILE_CACHE.DIR = str(tmp_path / "cc")
     cache_dir = compile_cache.setup_from_cfg(cfg)
     assert cache_dir == str(tmp_path / "cc") and os.path.isdir(cache_dir)
     assert jax.config.jax_compilation_cache_dir == cache_dir
-    # the knob is authoritative: disabling CLEARS the process-global dir
+    # a later disabled run in the same process does not keep writing there
     config.reset_cfg()
     compile_cache.setup_from_cfg(cfg)
     assert not jax.config.jax_compilation_cache_dir
+
+
+_PLACEMENT_SCRIPT = """
+import json, sys
+import jax
+import distribuuuu_tpu.config as config
+from distribuuuu_tpu.config import cfg
+from distribuuuu_tpu.asyncplane import compile_cache
+out = []
+for out_dir, enabled in json.loads(sys.argv[1]):
+    config.reset_cfg()
+    cfg.OUT_DIR = out_dir
+    cfg.COMPILE_CACHE.ENABLED = enabled
+    before = jax.config.jax_compilation_cache_dir
+    got = compile_cache.setup_from_cfg(cfg)
+    out.append([before, got, jax.config.jax_compilation_cache_dir])
+print("PLACED " + json.dumps(out))
+"""
+
+
+def _placement(tmp_path, runs, env_dir=None):
+    """[before, returned, after] of jax's cache dir for each (OUT_DIR,
+    ENABLED) run, in a fresh process."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": REPO}
+    env.pop(compile_cache.ENV_DIR, None)
+    if env_dir:
+        env[compile_cache.ENV_DIR] = env_dir
+    out = subprocess.run(
+        [sys.executable, "-c", _PLACEMENT_SCRIPT, json.dumps(runs)],
+        capture_output=True, text=True, timeout=120, env=env,
+        cwd=str(tmp_path),
+    )
+    assert out.returncode == 0, out.stdout + out.stderr
+    line = [ln for ln in out.stdout.splitlines() if ln.startswith("PLACED ")]
+    return json.loads(line[-1][len("PLACED "):])
+
+
+def test_cache_placed_from_outside_is_untouched(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: no code path clears or replaces
+    ``jax_compilation_cache_dir``, whatever COMPILE_CACHE says."""
+    outside = str(tmp_path / "placed_outside")
+    rows = _placement(
+        tmp_path, [[str(tmp_path / "a"), False], [str(tmp_path / "b"), True]],
+        env_dir=outside,
+    )
+    for before, returned, after in rows:
+        assert before == returned == after == outside
+    assert not os.path.exists(tmp_path / "a" / "compile_cache")
+
+
+def test_cache_default_is_one_fixed_dir_in_the_checkout(tmp_path):
+    """Variable unset and enabled: the fixed in-checkout directory —
+    identical across two OUT_DIRs and two processes, never under OUT_DIR."""
+    runs = [[str(tmp_path / "a"), True], [str(tmp_path / "b"), True]]
+    first = _placement(tmp_path, runs)
+    second = _placement(tmp_path, runs[::-1])
+    placed = {row[1] for row in first + second}
+    assert placed == {os.path.join(REPO, ".compile_cache")}
+    assert compile_cache.CHECKOUT_DIR == placed.pop()
 
 
 def test_cache_hit_suppresses_compile_count(tmp_path):
@@ -779,11 +840,14 @@ def test_dispatch_wedge_rule_fires_and_dedups():
     assert "dispatch-wedge" in {r.kind for r in rules}
 
 
-def test_bench_index_carries_asyncplane_series():
+def test_bench_index_carries_asyncplane_series(chip_bench_root):
     """BENCH_r06.json indexed (regeneration pin: tests/test_monitor.py
     asserts committed == rebuilt; here the asyncplane series exist and
     none of them rides a throughput-reference name)."""
-    index = bench_history.build_index(REPO)
+    root, values, copy_in = chip_bench_root
+    copy_in("BENCH_r06.json")
+    copy_in("BENCH_r07.json")
+    index = bench_history.build_index(root)
     series = index["series"]
     assert "ckpt_trainer_blocked_s_async" in series
     assert "ckpt_trainer_blocked_s_sync" in series
@@ -804,11 +868,7 @@ def test_bench_index_carries_asyncplane_series():
     assert "sequencer_trainer_blocked_s" in series
     assert "sequencer_token_max_wait_s" in series
     # none of the new series can poison the throughput gate
-    mapped = run_report.comparable_metrics(
-        json.load(open(os.path.join(REPO, "BENCH_INDEX.json")))
-    )
-    r5 = json.load(open(os.path.join(REPO, "BENCH_r05.json")))
-    assert mapped["img_per_sec"] == r5["parsed"]["value"]
+    assert run_report.comparable_metrics(index)["img_per_sec"] == values[-1]
 
 
 # ---------------------------------------------- cross-host dispatch ring

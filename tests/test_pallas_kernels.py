@@ -626,15 +626,16 @@ def test_run_report_kernels_section(tmp_path):
     assert kern["fallbacks"][0]["op"] == "conv_epilogue"
 
 
-def test_bench_index_kernel_series_and_resnet50_reference():
+def test_bench_index_kernel_series_and_resnet50_reference(chip_bench_root):
     """BENCH_r09's kernel_* series must ride the index WITHOUT touching
     the img/s regression reference (the PR 8 clobbering lesson): the
-    resnet50 throughput series still sources BENCH_r05.json after
-    regeneration, and run_report's gate extractor still reads it."""
+    resnet50 throughput series still sources the newest chip record
+    after regeneration, and run_report's gate extractor still reads it."""
     import bench_history
     import run_report
 
-    root = os.path.join(os.path.dirname(__file__), "..")
+    root, _, copy_in = chip_bench_root
+    copy_in("BENCH_r09.json")
     index = bench_history.build_index(root)
     series = index["series"]
     kernel_series = [k for k in series if k.startswith("kernel_")]

@@ -405,11 +405,12 @@ def test_run_report_compare_gate_both_ways(tmp_path):
     assert not cmp["ok"]
 
 
-def test_regression_gate_against_committed_bench_artifact(tmp_path):
-    """Satellite 6: the committed BENCH_r05.json is a usable --compare
-    reference point, and the gate fails/passes correctly around it —
-    exercised end-to-end through the CLI so the gate itself can't rot."""
-    bench = json.load(open(os.path.join(REPO, "BENCH_r05.json")))
+def test_regression_gate_against_bench_artifact(tmp_path, chip_bench_root):
+    """Satellite 6: a driver bench record (``BENCH_r*.json`` shape) is a
+    usable --compare reference point, and the gate fails/passes correctly
+    around it — exercised through compare() so the gate itself can't rot."""
+    bench = json.load(
+        open(os.path.join(chip_bench_root[0], "BENCH_r05.json")))
     ref_ips = float(bench["parsed"]["value"])
     base = run_report.comparable_metrics(bench)
     assert base == {"img_per_sec": ref_ips}

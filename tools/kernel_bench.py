@@ -430,8 +430,12 @@ def main(argv=None) -> int:
 
     import jax
 
+    from distribuuuu_tpu.config import cfg
     from distribuuuu_tpu.telemetry import costmodel
 
+    from distribuuuu_tpu.asyncplane import compile_cache
+
+    compile_cache.setup_from_cfg(cfg)  # on the chip: warm across processes
     peaks = costmodel.peaks_for()
     doc = {
         "bench": BENCH_SCHEMA,

@@ -531,6 +531,9 @@ def main(argv=None) -> int:
     config.reset_cfg()
     from distribuuuu_tpu.config import cfg
 
+    from distribuuuu_tpu.asyncplane import compile_cache
+
+    compile_cache.setup_from_cfg(cfg)  # on the chip: warm across processes
     cfg.TELEMETRY.ENABLED = False  # bench times raw dispatch
     platform = jax.devices()[0].platform
     if args.long_context:

@@ -241,7 +241,7 @@ def main():
         # wall fraction covered by decode activity ≡ achieved rate over
         # the in-run decode ceiling; *_vs_decode_only keeps the historical
         # external-denominator ratio (loader-only pass below) comparable
-        # with REALDATA_r03-r05
+        # with REALDATA_r04-r05
         "overlap_efficiency": att["overlap_efficiency"],
         "overlap_efficiency_vs_decode_only": round(
             stats["img_per_sec"] / decode_rate, 3

@@ -11,7 +11,7 @@ machinery fire — printed as a table and written as ``RUN_REPORT.json``.
     python tools/run_report.py --trace out/
 
     # regression gate against a committed reference point:
-    python tools/run_report.py out/ --compare BENCH_r05.json --tol-pct 10
+    python tools/run_report.py out/ --compare BENCH_INDEX.json --tol-pct 10
 
 Metrics:
 
@@ -40,8 +40,8 @@ throughput reference), or the ``BENCH_INDEX.json`` trajectory written by
 the gate tracks the newest committed bench automatically). Direction-aware thresholds: ``--tol-pct`` (global,
 default 10%) and repeatable ``--tol METRIC=PCT`` overrides; any metric
 worse than its tolerance FAILs and the exit code is 1 — the CI gate
-(tests/test_telemetry.py exercises both directions against the committed
-BENCH_r05.json so the gate itself can't rot).
+(tests/test_telemetry.py exercises both directions against a bench
+record so the gate itself can't rot).
 """
 
 from __future__ import annotations

@@ -498,6 +498,11 @@ def main():
 
     import jax
 
+    from distribuuuu_tpu.config import cfg
+
+    from distribuuuu_tpu.asyncplane import compile_cache
+
+    compile_cache.setup_from_cfg(cfg)  # on the chip: warm across processes
     images = make_requests(64, args.im_size)
     results = {
         "metric": "serve_latency_throughput_frontier",
