@@ -6,9 +6,13 @@ site in the package (``metrics_log`` / ``emit_event`` / ``mirror_event``
 / ``timeline_log`` / ``emit_span``) must use a literal kind that is
 declared in ``telemetry/schema.py`` with its required fields statically
 present (or splatted), and only the sink modules may forward a dynamic
-kind. The old CLI remains as a thin wrapper over :func:`check_file` /
-:func:`check_tree`, which keep their historical ``(violations, seen)``
-string API — existing invocations and tests work unchanged.
+kind. Program spans are held to the same discipline: a literal name
+passed to ``span`` / ``emit_span`` / ``annotate`` must be declared in
+``schema.SPANS`` (the table that gives it its ``dtpu.<layer>.<name>``
+profiler annotation). The old CLI remains as a thin wrapper over
+:func:`check_file` / :func:`check_tree`, which keep their historical
+``(violations, seen)`` string API — existing invocations and tests work
+unchanged.
 """
 
 from __future__ import annotations
@@ -28,6 +32,9 @@ EMIT_FUNCS = {
     "timeline_log": "timeline",
     "emit_span": "span",
 }
+
+# span surface: the first positional argument is the span's name
+SPAN_FUNCS = ("span", "emit_span", "annotate")
 
 # modules allowed to forward a caller's kind variable (the sinks themselves)
 DYNAMIC_KIND_OK = ("utils/jsonlog.py", "telemetry/spans.py")
@@ -63,9 +70,24 @@ def check_file(path: str, rel: str) -> tuple[list, set]:
         if not isinstance(node, ast.Call):
             continue
         name = _func_name(node)
-        if name not in EMIT_FUNCS:
+        if name not in EMIT_FUNCS and name not in SPAN_FUNCS:
             continue
         where = f"{rel}:{node.lineno}"
+        if name in SPAN_FUNCS and node.args:
+            first = node.args[0]
+            if (
+                isinstance(first, ast.Constant)
+                and isinstance(first.value, str)
+                and first.value not in schema.SPANS
+            ):
+                findings.append(_finding(
+                    where, f"span-{first.value}",
+                    f"undeclared span name {first.value!r} — declare it "
+                    "(with its layer) in SPANS of "
+                    "distribuuuu_tpu/telemetry/schema.py",
+                ))
+        if name not in EMIT_FUNCS:
+            continue
         kind = EMIT_FUNCS[name]
         if kind is None:
             if not node.args:

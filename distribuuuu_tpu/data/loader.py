@@ -26,10 +26,7 @@ from distribuuuu_tpu.config import cfg
 from distribuuuu_tpu.data.dummy import DummyDataset
 from distribuuuu_tpu.data.sampler import DistributedSampler
 from distribuuuu_tpu.parallel import mesh as mesh_lib
-from distribuuuu_tpu.telemetry import (
-    registry as telemetry_registry,
-    spans as telemetry_spans,
-)
+from distribuuuu_tpu.telemetry import spans as telemetry_spans
 from distribuuuu_tpu.utils import faults
 from distribuuuu_tpu.utils.jsonlog import metrics_log
 from distribuuuu_tpu.utils.logger import get_logger
@@ -261,10 +258,6 @@ class Loader:
             # for rank 0; these make a rank-3 decode stall visible)
             telemetry_spans.emit_span("decode", dec0, dec1, track="loader", n=n)
             telemetry_spans.emit_span("assemble", dec1, asm1, track="loader", n=n)
-        reg = telemetry_registry.get_registry()
-        reg.counter("data.batches").inc(1)
-        reg.counter("data.samples").inc(n)
-        reg.counter("data.decode_s").inc(dec1 - dec0)
         return batch, {"submit": submit, "dec0": dec0, "dec1": dec1,
                        "asm1": asm1}
 
@@ -293,7 +286,6 @@ class Loader:
             "substituting a good sample from the same batch",
             int(i), self.retries + 1, type(err).__name__, err,
         )
-        telemetry_registry.get_registry().counter("data.errors").inc(1)
         metrics_log(
             "data_error", index=int(i), attempts=self.retries + 1,
             error=f"{type(err).__name__}: {err}",
@@ -408,17 +400,22 @@ def device_prefetch(loader, put_fn, depth: int):
     src = iter(loader)
 
     def pull():
+        # the profiler's twin of each stamped interval (dtpu.trainer.wait,
+        # dtpu.trainer.h2d): the JSONL spans are emitted later from the
+        # stamps (trainer._emit_batch_spans) and cannot annotate then
         get0 = time.perf_counter()
-        try:
-            hb = next(src)
-        except StopIteration:
-            return None
+        with telemetry_spans.annotate("wait"):
+            try:
+                hb = next(src)
+            except StopIteration:
+                return None
         get1 = time.perf_counter()
         tl = dict(get_timing() or {})
         tl["get0"], tl["get1"] = get0, get1
         tl["n"] = int(np.shape(hb["image"])[0]) if "image" in hb else 0
         tl["put0"] = time.perf_counter()
-        db = put_fn(hb)
+        with telemetry_spans.annotate("h2d"):
+            db = put_fn(hb)
         tl["put1"] = time.perf_counter()
         return db, tl
 

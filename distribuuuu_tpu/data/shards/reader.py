@@ -40,7 +40,6 @@ from distribuuuu_tpu.data.shards.format import (
     read_shard_manifest,
 )
 from distribuuuu_tpu.data.transforms import train_transform, val_transform
-from distribuuuu_tpu.telemetry import registry as telemetry_registry
 
 
 class RecordShards:
@@ -122,13 +121,7 @@ class RecordShards:
                 f"to truncation (shard has {len(offsets)} readable records, "
                 f"manifest says {self._shards[s]['records']})"
             )
-        rec = read_record_at(fd, offsets[r], self._shards[s]["file"])
-        # shard-IO tallies in the shared registry (telemetry/registry.py):
-        # run_report's per-rank IO line comes from the epoch snapshots
-        reg = telemetry_registry.get_registry()
-        reg.counter("shards.records").inc(1)
-        reg.counter("shards.bytes").inc(len(rec[0]))
-        return rec
+        return read_record_at(fd, offsets[r], self._shards[s]["file"])
 
     def close(self) -> None:
         with self._open_lock:

@@ -130,6 +130,7 @@ def conv1x1_bn_act(x, kernel, a, c, act: str = "id", *,
         ],
         out_specs=pl.BlockSpec((blk_m, blk_n), lambda i, j: (i, j)),
         interpret=interpret,
+        name="dtpu_conv_epilogue",
     )(x2, w2, a2, c2)
     return out[:m, :cout].reshape(*lead, cout)
 

@@ -148,6 +148,7 @@ def decode_attention(q, cache_k, cache_v, lengths, *, scale: float,
             ),
         ),
         interpret=interpret,
+        name="dtpu_decode_attn",
     )(lengths.astype(jnp.int32), q[:, :, None, :], cache_k, cache_v)
     return out[:, :, 0, :]
 

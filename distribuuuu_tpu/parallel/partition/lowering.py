@@ -47,6 +47,7 @@ from distribuuuu_tpu.models.layers import head_dtype
 from distribuuuu_tpu.parallel import sharding as sharding_lib, tp, zero
 from distribuuuu_tpu.parallel.partition import specs as specs_lib
 from distribuuuu_tpu.resilience import supervisor
+from distribuuuu_tpu.telemetry import spans as telemetry_spans
 from distribuuuu_tpu.utils import faults
 from distribuuuu_tpu.utils.metrics import accuracy, cross_entropy
 
@@ -146,6 +147,26 @@ def make_gather_entry(layout):
         return gathered
 
     return gather_fn, int(n_hoisted)
+
+
+def value_and_grad_scoped(loss_fn):
+    """``jax.value_and_grad(loss_fn, has_aux=True)`` spelled with
+    ``jax.vjp``, which leaves a place to stand between the two passes: the
+    transposed pass runs under the ``bwd`` named scope, so its operations
+    read ``…/bwd/transpose(jvp(fwd))/…`` in HLO metadata and a trace reader
+    splits forward from backward by it. Metadata only: the compiled program
+    and the trajectory are the ones ``value_and_grad`` gives
+    (tests/test_device_scopes.py)."""
+
+    def grad_fn(params, *rest):
+        loss, vjp, aux = jax.vjp(
+            lambda p: loss_fn(p, *rest), params, has_aux=True
+        )
+        with jax.named_scope("bwd"):
+            (grads,) = vjp(jnp.ones((), loss.dtype))
+        return (loss, aux), grads
+
+    return grad_fn
 
 
 def train_step_body(model, optimizer, topk: int, accum_steps: int = 1,
@@ -323,7 +344,7 @@ def train_step_body(model, optimizer, topk: int, accum_steps: int = 1,
         dropped = sum(dstats) / len(dstats) if dstats else None
         return loss, (logits, mutated.get("batch_stats", {}), dropped)
 
-    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+    grad_fn = value_and_grad_scoped(loss_fn)
 
     def step_metrics(loss, logits, labels, dropped):
         acc1, acck = accuracy(logits, labels, topk=(1, topk))
@@ -597,6 +618,7 @@ class Lowered:
         return state, batch
 
 
+@telemetry_spans.setup_timer("lower")
 def lower(model, optimizer, topk: int, *, mesh, topology, im_size: int,
           fold: int = 1, accum: int = 1) -> Lowered:
     """Build the train/eval(/folded) step for ANY validated topology from

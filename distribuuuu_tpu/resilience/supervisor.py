@@ -40,7 +40,6 @@ from contextlib import contextmanager
 import jax
 import jax.numpy as jnp
 
-from distribuuuu_tpu.telemetry import registry as telemetry_registry
 from distribuuuu_tpu.utils.jsonlog import metrics_log
 from distribuuuu_tpu.utils.logger import get_logger
 
@@ -119,7 +118,6 @@ class NonFiniteMonitor:
         """True ⇒ this step was skipped in-graph (exclude it from meters)."""
         if not nonfinite:
             return False
-        telemetry_registry.get_registry().counter("resilience.nonfinite").inc(1)
         if self.policy == "skip":
             self.skipped += 1
             self.logger.warning(
@@ -146,9 +144,9 @@ def watch_blocking(label: str, timeout: float, logger=None, on_flag=None):
     checkpoint committer's join barrier, the cross-host commit barrier
     wait, a preemption drain, a restore, the dispatch sequencer's
     token/fence waits. Same signal contract as the heartbeat — a warning
-    line, the ``resilience.stalls`` counter, and a ``kind="stall"``
-    record — when the wrapped block exceeds ``timeout`` seconds (the
-    operator's first clue that storage, not training, is what hung).
+    line and a ``kind="stall"`` record — when the wrapped block exceeds
+    ``timeout`` seconds (the operator's first clue that storage, not
+    training, is what hung).
     ``timeout <= 0`` disables (zero overhead: no thread is started).
     Flag, not kill — the block keeps waiting; the restart decision stays
     external.
@@ -178,9 +176,6 @@ def watch_blocking(label: str, timeout: float, logger=None, on_flag=None):
                     "docs/RUNBOOK.md 'Async checkpointing and warm "
                     "restarts'", label, age, timeout,
                 )
-                telemetry_registry.get_registry().counter(
-                    "resilience.stalls"
-                ).inc(1)
                 metrics_log(
                     "stall", age_s=round(age, 3), last=label, count=1
                 )
@@ -237,9 +232,6 @@ class Heartbeat:
                     "'Recovering a wedged run'",
                     age, self._label, self.timeout,
                 )
-                telemetry_registry.get_registry().counter(
-                    "resilience.stalls"
-                ).inc(1)
                 metrics_log(
                     "stall", age_s=round(age, 3), last=self._label,
                     count=self.stall_count,

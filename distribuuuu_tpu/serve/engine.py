@@ -52,7 +52,7 @@ import jax
 import numpy as np
 
 from distribuuuu_tpu.config import cfg
-from distribuuuu_tpu.serve.admission import AdmissionController
+from distribuuuu_tpu.serve.admission import AdmissionController, QueueFullError
 from distribuuuu_tpu.serve.metrics import ServeMetrics
 from distribuuuu_tpu.telemetry import registry as telemetry_registry
 from distribuuuu_tpu.telemetry import spans
@@ -253,7 +253,13 @@ class Engine:
                 f"{image.dtype.name}"
             )
         with self._cond:
-            self._admission.admit(len(self._pending), self._retry_after_ms())
+            try:
+                self._admission.admit(
+                    len(self._pending), self._retry_after_ms()
+                )
+            except QueueFullError:
+                self.metrics.record_rejection()  # stats()["rejected"]
+                raise
             req = _Request(image, time.perf_counter())
             self._pending.append(req)
             self._cond.notify()

@@ -81,6 +81,10 @@ class ServeMetrics:
             "p99_ms": round(percentile(lat, 0.99) * 1e3, 3),
             "mean_ms": round(sum(lat) / len(lat) * 1e3, 3) if lat else 0.0,
             "batch_occupancy": round(filled / slots, 4) if slots else 0.0,
+            # the ratio's raw counters: a reader differences them over its
+            # own window instead of a ratio that runs from engine start
+            "occ_filled": int(filled),
+            "occ_slots": int(slots),
             "mean_batch_ms": round(batch_s / n_b * 1e3, 3) if n_b else 0.0,
             "window_s": round(window, 3),
         }

@@ -23,8 +23,11 @@ thread-local (concurrent compiles on other threads cannot steal each
 other's suppression).
 
 The listener registers once per process and stays registered (JAX has no
-public unregister); it is a no-op while the telemetry sink is closed, so
-tests and library use pay one predicate per compile, nothing more.
+public unregister). It counts into the process-wide registry ALWAYS
+(``jit.compiles`` / ``jit.compile_s`` / ``jit.cache_hits`` / … — a few
+counter increments per compile, none on the step path) and writes records
+only while the telemetry sink is open: a server without a sink still
+answers ``jit_compiles`` in its ``stats`` op.
 
 **Memory stats.** ``device.memory_stats()`` (bytes_in_use /
 peak_bytes_in_use on TPU; ``None`` on the CPU backend — skipped) sampled
@@ -66,10 +69,7 @@ def _on_event(event: str, **_kw) -> None:
         outcome = "miss"
     else:
         return
-    if not spans.enabled():
-        return
-    reg = registry_lib.get_registry()
-    reg.counter(
+    registry_lib.get_registry().counter(
         "jit.cache_hits" if outcome == "hit" else "jit.cache_misses"
     ).inc(1)
     spans.emit_event(
@@ -81,13 +81,8 @@ def _on_event(event: str, **_kw) -> None:
 def _on_event_duration(event: str, duration: float, **_kw) -> None:
     if _COMPILE_EVENT not in event:
         return
-    # consume the thread-local hit flag FIRST: a cache-served executable
-    # must not count as a compile even while the sink is closed (the flag
-    # would otherwise leak onto the next real compile)
     was_hit = getattr(_tls, "cache_hit", False)
     _tls.cache_hit = False
-    if not spans.enabled():
-        return
     reg = registry_lib.get_registry()
     if was_hit:
         reg.counter("jit.cache_hit_s").inc(float(duration))

@@ -206,6 +206,37 @@ KINDS: dict[str, frozenset] = {
     "trace.exemplar": frozenset({"v", "rule", "trace", "latency_ms"}),
 }
 
+# -- program spans (telemetry/spans.py) ----------------------------------
+# span name -> layer, for every name the package passes to ``span()``,
+# ``emit_span()`` or ``annotate()`` (static check: analysis/passes/
+# telemetry.py). The JSONL record keeps the bare name; the profiler-side
+# twin of the same interval is the ``jax.profiler.TraceAnnotation``
+# ``dtpu.<layer>.<name>`` (``ANNOTATIONS``), which lands in any profiler
+# capture on the device's clock. Names measured after the fact by
+# ``emit_span`` alone (decode, assemble, fold_window) have no annotation
+# site and stay JSONL-only. PERF.md "spans and counters" says which
+# metric reads each.
+ANNOTATION_PREFIX = "dtpu."
+SPANS: dict[str, str] = {
+    # trainer loop (trainer.train_epoch, data/loader.device_prefetch)
+    "wait": "trainer",
+    "h2d": "trainer",
+    "step": "trainer",
+    "metrics_fetch": "trainer",
+    "fold_window": "trainer",
+    # loader worker threads (data/loader.py)
+    "decode": "loader",
+    "assemble": "loader",
+    # checkpoint save/restore (utils/checkpoint.py)
+    "ckpt_save": "ckpt",
+    "ckpt_snapshot": "ckpt",
+    "ckpt_commit": "ckpt",
+    "ckpt_restore": "ckpt",
+}
+ANNOTATIONS: dict[str, str] = {
+    name: f"{ANNOTATION_PREFIX}{layer}.{name}" for name, layer in SPANS.items()
+}
+
 
 class SchemaError(ValueError):
     """A record (or call site) violates the declared kind schema."""

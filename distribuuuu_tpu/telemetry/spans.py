@@ -7,7 +7,17 @@ Every process (rank) appends one JSON object per line to its OWN file,
 step times, a rank-3 data stall, a lone recompile storm) survive on every
 rank and merge later (telemetry/export.py, tools/run_report.py).
 
-Two timestamp domains, bridged per file:
+One API, two sinks. Every program span also becomes a
+``jax.profiler.TraceAnnotation`` named ``dtpu.<layer>.<name>``
+(telemetry/schema.py ``SPANS``) around the very statements it measures, so
+it lands in any profiler capture (``PROF.*``, the benchmark's) on the
+DEVICE's clock, beside the device's operations — whether or not the JSONL
+sink is open. ``span()`` does both; ``emit_span()`` takes finished stamps
+and cannot annotate after the fact, so its hot-path sites wrap the measured
+statements in :func:`annotate` themselves. Outside a profiler session an
+annotation is a sub-microsecond no-op.
+
+Two timestamp domains in the JSONL sink, bridged per file:
 
 * ``t``    — ``time.time()`` unix seconds (event kinds mirrored from
              jsonlog, resilience events);
@@ -38,6 +48,9 @@ import os
 import threading
 import time
 from contextlib import contextmanager
+
+from distribuuuu_tpu.telemetry import schema
+from distribuuuu_tpu.telemetry.registry import get_registry
 
 SPAN_SCHEMA = 1
 
@@ -122,6 +135,40 @@ def emit_span(name: str, t0: float, t1: float, *, track: str = "main",
     )
 
 
+_TraceAnnotation = None  # jax.profiler.TraceAnnotation, imported at first use
+
+
+def annotate(name: str):
+    """The profiler half of a program span: a context manager that marks
+    the enclosed statements as ``dtpu.<layer>.<name>`` in whatever profiler
+    capture is open (none: a no-op). ``span()`` applies it itself; the
+    sites that stamp first and ``emit_span`` later (``wait``, ``h2d``,
+    ``step``) wrap the stamped statements in it. jax is imported here, at
+    first use, so importing this module stays jax-free (the fleet router)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(schema.ANNOTATIONS[name])
+
+
+@contextmanager
+def setup_timer(name: str):
+    """Seconds of one piece of set-up (``lower``, ``init_state``) into the
+    process-wide registry counter ``setup.<name>_s``, whether or not a sink
+    is open: what a reader in the same process (the benchmark's
+    ``entry.*_s``) finds. Not a span — set-up is over before any profiler
+    capture starts, so an annotation there would land nowhere. One pair of
+    ``perf_counter`` per call, a handful of calls per process. Also a
+    decorator (``@setup_timer("lower")``), as every ``contextmanager`` is."""
+    t0 = time.perf_counter()
+    try:
+        yield
+    finally:
+        get_registry().counter(f"setup.{name}_s").inc(time.perf_counter() - t0)
+
+
 def _stack() -> list:
     st = getattr(_tls, "stack", None)
     if st is None:
@@ -134,22 +181,26 @@ def span(name: str, *, track: str | None = None, **attrs):
     """Context-manager span with nesting: depth and parent name come from
     a thread-local stack, so ``span("ckpt_save")`` inside
     ``span("epoch")`` renders nested in Perfetto and carries
-    ``depth``/``parent`` for programmatic consumers. Cheap no-op (one
-    truthiness check) when telemetry is off."""
-    if _sink["f"] is None:
-        yield
-        return
-    st = _stack()
-    if track is None:
-        track = st[-1][1] if st else f"thread-{threading.get_ident() % 10000}"
-    st.append((name, track))
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        t1 = time.perf_counter()
-        st.pop()
-        extra = {}
-        if st:
-            extra = {"depth": len(st), "parent": st[-1][0]}
-        emit_span(name, t0, t1, track=track, **attrs, **extra)
+    ``depth``/``parent`` for programmatic consumers. The same interval
+    is annotated for the profiler (:func:`annotate`). With telemetry off
+    that annotation and one truthiness check are all it costs."""
+    with annotate(name):
+        if _sink["f"] is None:
+            yield
+            return
+        st = _stack()
+        if track is None:
+            track = (
+                st[-1][1] if st else f"thread-{threading.get_ident() % 10000}"
+            )
+        st.append((name, track))
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            t1 = time.perf_counter()
+            st.pop()
+            extra = {}
+            if st:
+                extra = {"depth": len(st), "parent": st[-1][0]}
+            emit_span(name, t0, t1, track=track, **attrs, **extra)

@@ -270,6 +270,7 @@ def build_model_from_cfg(topology=None):
     return model
 
 
+@telemetry_spans.setup_timer("init_state")
 def create_train_state(model, key, mesh, im_size: int, layout=None) -> TrainState:
     """Initialize params/stats/optimizer laid out over the mesh.
 
@@ -524,33 +525,38 @@ def train_epoch(loader, mesh, state, train_step, epoch: int, logger,
     heartbeat = supervisor.Heartbeat(cfg.TRAIN.STALL_TIMEOUT, logger)
 
     def flush_pending():
-        for n, m in pending:
-            if n == 1:
-                if nf_mon.observe(
-                    float(m["loss"]), float(m.get("nonfinite", 0.0)), done
-                ):
-                    continue  # skipped in-graph — keep it out of the meters
-                losses.update(float(m["loss"]))
-                top1.update(float(m["top1"]))
-                topk_m.update(float(m["topk"]))
-                if "moe_dropped" in m:
-                    moe_dropped.update(float(m["moe_dropped"]))
-            else:  # stacked (fold,) metrics from a scan call
-                nfs = np.asarray(
-                    m.get("nonfinite", np.zeros(n))
-                ).reshape(-1)
-                for j, (ls, t1, tk) in enumerate(zip(
-                    np.asarray(m["loss"]), np.asarray(m["top1"]),
-                    np.asarray(m["topk"]),
-                )):
-                    if nf_mon.observe(float(ls), float(nfs[j]), done):
-                        continue
-                    losses.update(float(ls))
-                    top1.update(float(t1))
-                    topk_m.update(float(tk))
-                if "moe_dropped" in m:
-                    for dv in np.asarray(m["moe_dropped"]).reshape(-1):
-                        moe_dropped.update(float(dv))
+        if not pending:
+            return
+        # the float() reads below are the loop's only fence on the device:
+        # device-idle gaps under this span are the print interval's price
+        with telemetry_spans.span("metrics_fetch", track="pipeline"):
+            for n, m in pending:
+                if n == 1:
+                    if nf_mon.observe(
+                        float(m["loss"]), float(m.get("nonfinite", 0.0)), done
+                    ):
+                        continue  # skipped in-graph — keep it out of the meters
+                    losses.update(float(m["loss"]))
+                    top1.update(float(m["top1"]))
+                    topk_m.update(float(m["topk"]))
+                    if "moe_dropped" in m:
+                        moe_dropped.update(float(m["moe_dropped"]))
+                else:  # stacked (fold,) metrics from a scan call
+                    nfs = np.asarray(
+                        m.get("nonfinite", np.zeros(n))
+                    ).reshape(-1)
+                    for j, (ls, t1, tk) in enumerate(zip(
+                        np.asarray(m["loss"]), np.asarray(m["top1"]),
+                        np.asarray(m["topk"]),
+                    )):
+                        if nf_mon.observe(float(ls), float(nfs[j]), done):
+                            continue
+                        losses.update(float(ls))
+                        top1.update(float(t1))
+                        topk_m.update(float(tk))
+                    if "moe_dropped" in m:
+                        for dv in np.asarray(m["moe_dropped"]).reshape(-1):
+                            moe_dropped.update(float(dv))
         pending.clear()
 
     def maybe_print():
@@ -679,9 +685,10 @@ def train_epoch(loader, mesh, state, train_step, epoch: int, logger,
                     # token-ordered when a second dispatch stream is
                     # active (asyncplane/sequencer.py); pass-through with
                     # one attribute read otherwise
-                    state, metrics = sequencer.dispatch(
-                        sequencer.TRAIN_STREAM, scan_step, state, batch
-                    )
+                    with telemetry_spans.annotate("step"):
+                        state, metrics = sequencer.dispatch(
+                            sequencer.TRAIN_STREAM, scan_step, state, batch
+                        )
                     prof.end(done + fold - 1, state)
                     pending.append((fold, metrics))
                 else:  # ragged tail: per-step dispatch
@@ -693,9 +700,10 @@ def train_epoch(loader, mesh, state, train_step, epoch: int, logger,
                             phase="train",
                         )
                         prof.begin(done + i)
-                        state, metrics = sequencer.dispatch(
-                            sequencer.TRAIN_STREAM, train_step, state, b
-                        )
+                        with telemetry_spans.annotate("step"):
+                            state, metrics = sequencer.dispatch(
+                                sequencer.TRAIN_STREAM, train_step, state, b
+                            )
                         prof.end(done + i, state)
                         pending.append((1, metrics))
                 done += n
@@ -746,9 +754,10 @@ def train_epoch(loader, mesh, state, train_step, epoch: int, logger,
                 )
                 prof.begin(abs_it)
                 tl["step0"] = time.perf_counter()
-                state, metrics = sequencer.dispatch(
-                    sequencer.TRAIN_STREAM, train_step, state, batch
-                )
+                with telemetry_spans.annotate("step"):
+                    state, metrics = sequencer.dispatch(
+                        sequencer.TRAIN_STREAM, train_step, state, batch
+                    )
                 tl["step1"] = time.perf_counter()
                 prof.end(abs_it, state)
                 pending.append((1, metrics))
@@ -817,9 +826,10 @@ def validate(loader, mesh, state, eval_step, epoch: int, logger,
         # before the token releases) — the eval thread absorbs the wait,
         # the train stream never fences on eval (asyncplane/sequencer.py
         # has the dispatch-ordering story); pass-through when inactive
-        m = sequencer.dispatch(
-            sequencer.EVAL_STREAM, eval_step, state, batch, fence=True
-        )
+        with telemetry_spans.annotate("step"):
+            m = sequencer.dispatch(
+                sequencer.EVAL_STREAM, eval_step, state, batch, fence=True
+            )
         totals = (
             m
             if totals is None

@@ -194,6 +194,34 @@ def test_backpressure_rejects_at_max_queue(served):
     eng.drain()
 
 
+def test_rejections_and_raw_occupancy_are_counted(served):
+    """``stats()["rejected"]`` counts every ``QueueFullError`` of ``submit``
+    (it read 0 whatever happened: ``record_rejection`` had no caller), and
+    the occupancy ratio's raw counters are in the snapshot, so a reader can
+    difference them over a window of its own."""
+    model, variables = served
+    eng = Engine(
+        model, variables, IM, max_batch=2, max_wait_ms=1.0, max_queue=3,
+        input_dtype=np.float32,
+    )
+    images = _float_images(5, seed=6)
+    futs = [eng.submit(img) for img in images[:3]]
+    for img in images[3:]:
+        with pytest.raises(QueueFullError):
+            eng.submit(img)
+    assert eng.stats()["rejected"] == 2 and eng.stats()["requests"] == 0
+    eng.start()
+    for f in futs:
+        f.result()
+    eng.drain()
+    stats = eng.stats()
+    assert stats["rejected"] == 2 and stats["requests"] == 3
+    assert stats["occ_filled"] == 3
+    assert stats["occ_slots"] >= stats["occ_filled"]
+    assert stats["batch_occupancy"] == pytest.approx(
+        stats["occ_filled"] / stats["occ_slots"], abs=1e-4)
+
+
 def test_graceful_drain_completes_inflight(served):
     model, variables = served
     eng = Engine(

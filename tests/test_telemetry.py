@@ -47,7 +47,7 @@ def _read(path):
 def test_noop_before_setup():
     spans.emit_event("stall", age_s=1.0, count=1)  # must not raise
     spans.emit_span("step", 0.0, 1.0)
-    with spans.span("anything"):
+    with spans.span("ckpt_save"):
         pass
     assert not spans.enabled()
 
@@ -68,14 +68,14 @@ def test_sink_opens_with_clock_anchor(tmp_path):
 
 def test_span_nesting_and_timestamps(tmp_path):
     path = spans.setup_telemetry(str(tmp_path), rank=0)
-    with spans.span("outer", track="t"):
+    with spans.span("ckpt_save", track="t"):
         time.sleep(0.01)
-        with spans.span("inner", foo=7):
+        with spans.span("ckpt_snapshot", foo=7):
             time.sleep(0.01)
     recs = [r for r in _read(path) if r["kind"] == "span"]
-    inner = next(r for r in recs if r["name"] == "inner")
-    outer = next(r for r in recs if r["name"] == "outer")
-    assert inner["parent"] == "outer" and inner["depth"] == 1
+    inner = next(r for r in recs if r["name"] == "ckpt_snapshot")
+    outer = next(r for r in recs if r["name"] == "ckpt_save")
+    assert inner["parent"] == "ckpt_save" and inner["depth"] == 1
     assert inner["track"] == "t"  # inherited from the enclosing span
     assert "depth" not in outer
     assert inner["foo"] == 7
