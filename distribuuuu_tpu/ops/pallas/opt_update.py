@@ -1,16 +1,16 @@
 """Fused optimizer update: ONE HBM pass over params+grads+moments.
 
-The cost ledger's motivation, measured on the lowered XLA programs (the
-numbers tools/kernel_bench.py re-derives into BENCH_r09.json): the optax
-chain re-reads its operands per transform — ``add_decayed_weights`` →
-``trace``/``scale_by_adam`` → ``scale`` each materialize an
-intermediate, so the SGD-momentum update accesses ~5.4× and AdamW ~8×
-the one-pass byte count. At ResNet-50 scale (25.6M params) that is
-~500 MB of avoidable HBM traffic per step on a path with near-zero
-arithmetic intensity — pure roofline loss. These kernels read each of
-p/g/m(/v) exactly once and write p/m(/v) exactly once per leaf: the
-per-shard fused weight update of arXiv:2004.13336, which is also the
-fusion point ROADMAP #1's overlapped ZeRO update will reuse.
+These kernels read each of p/g/m(/v) exactly once and write p/m(/v)
+exactly once per leaf, in place: the per-shard fused weight update of
+arXiv:2004.13336, and the fusion point of the gather-once ZeRO schedule.
+Every leaf is updated where it rests — the kernel's operands are views
+of the state's own buffers in the layout the device keeps them in
+(``_plan``), aliased to the outputs, so no copy of a leaf goes into or out
+of a call. The byte counts that first motivated the kernel
+(tools/kernel_bench.py, BENCH_r09.json: the optax chain re-reads its
+operands per transform, ~5.4× the one-pass bytes for SGD-momentum and ~8×
+for AdamW) are a CPU lowering's; on the v5e XLA fuses the chain into the
+gradient fusions, and PERF.md §6 (PR 25) has both arms measured.
 
 Numerics are optax's EXACTLY — same op order, same promotion points
 (``mom * trace`` in the trace's own dtype for the bf16 momentum
@@ -35,33 +35,79 @@ mesh runs the whole-leaf call unwrapped.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.layout import Layout
 
-# Tile geometry: leaves are flattened and viewed as (rows, 128) lanes;
-# one grid step updates _BLK_ROWS rows (_BLK_ROWS·128·4B·~5 tensors
-# ≈ 1.3 MiB VMEM-resident — well under budget with double buffering).
+# Block geometry. A leaf is updated in the layout it rests in. The device
+# says which that is: the TPU rests an array in the dimension order that pads
+# its (8, 128) tiles least, so ResNet-50's classifier [2048, 1000] rests
+# column-major and a [1, 1, 256, 64] conv with 256 on the lanes. The kernel
+# sees the leaf in that order as the 3-D view [prod(leading), second-minor,
+# minor]: only the dimensions above the tiled two are collapsed, which is a
+# bitcast whatever the shape and the tile (a stem [7, 7, 3, 64] and RegNetY's
+# SE convs, whose 308 channels rest in (1, 128) tiles, included); a 1-D leaf
+# stays 1-D. Nothing is flattened, padded, copied or sliced.
 _LANES = 128
-_BLK_ROWS = 512
+_SUBLANES = 8  # rows of a 32-bit tile; a 16-bit operand packs 16
+# VMEM per operand block: 5 operands (AdamW: 7), double-buffered
+_BLOCK_BYTES = 512 * _LANES * 4
 
 
-def _pad_rows(n: int) -> tuple[int, int]:
-    """(rows, block_rows) for an n-element leaf: rows is the padded
-    (rows, 128) view's height — a multiple of 8 sublanes, and of the
-    block height when the leaf spans multiple blocks."""
-    rows = -(-n // _LANES)
-    rows = -(-rows // 8) * 8
-    if rows > _BLK_ROWS:
-        rows = -(-rows // _BLK_ROWS) * _BLK_ROWS
-        return rows, _BLK_ROWS
-    return rows, rows
+def _resting_order(shape, dtype, device) -> tuple:
+    """The dimensions of a leaf of this shape, major to minor, in the layout
+    it rests in on ``device`` (what the client gives every array it holds,
+    and so a step's state). Row-major where there is no device to ask."""
+    if device is None or len(shape) < 2:
+        return tuple(range(len(shape)))
+    layout = device.client.get_default_layout(jnp.dtype(dtype), shape, device)
+    return tuple(Layout.from_pjrt_layout(layout).major_to_minor)
 
 
-# Attribution (HLO metadata only, no instruction changes): the pad/reshape
-# of a leaf to (rows, 128) and back reads ``opt_tile`` in a trace, the
-# Pallas calls alone ``opt_kernel`` — the two halves of the step's
+def _plan(shape, dtypes, device=None):
+    """How one leaf goes to the kernel, decided from its (local) shape, the
+    operands' dtypes and the device's layout rule alone: ``(order, view,
+    block, copied)``. The operands are transposed to ``order`` (the
+    parameter's resting one) and reshaped to the 3-D ``view`` (a 1-D leaf of
+    32-bit operands stays 1-D); ``block`` is the BlockSpec on it, sized to
+    ``_BLOCK_BYTES`` of VMEM from the minor dimension up: the last dimension
+    whole unless one sublane tile of it overflows, then whole [second-minor,
+    minor] slabs if one fits, else rows of one. A ragged last block is masked
+    by Pallas; no row is padded. ``copied`` says an operand of another dtype
+    (a bfloat16 momentum) rests in another order than the parameter, so XLA
+    re-lays it out for the call."""
+    shape = tuple(shape)
+    item = max(jnp.dtype(d).itemsize for d in dtypes)
+    sub = max(_SUBLANES * 4 // jnp.dtype(d).itemsize for d in dtypes)
+    order = _resting_order(shape, dtypes[0], device)
+    if len(shape) == 1 and sub == _SUBLANES:
+        # a 1-D leaf rests in 1-D tiles (of up to 1024 elements), which pad
+        # otherwise than [1, 1, n]'s: Mosaic takes it as it is where every
+        # operand is 32-bit (a 16-bit one it refuses at some lengths)
+        return order, shape, (min(shape[0], _BLOCK_BYTES // item),), False
+    rested = (1,) * (2 - len(shape)) + tuple(shape[d] for d in order)
+    slabs, rows, cols = math.prod(rested[:-2]), rested[-2], rested[-1]
+    blk_cols = min(cols, _BLOCK_BYTES // (sub * item) // _LANES * _LANES)
+    row_bytes = -(-blk_cols // _LANES) * _LANES * item
+    blk_rows = min(rows, _BLOCK_BYTES // row_bytes // sub * sub)
+    # a second-minor dimension under a sublane tile rests in a smaller one
+    tile_rows = min(sub, 1 << (rows - 1).bit_length())
+    slab_bytes = -(-rows // tile_rows) * tile_rows * row_bytes
+    blk_slabs = min(slabs, max(1, _BLOCK_BYTES // slab_bytes))
+    copied = any(
+        _resting_order(shape, d, device) != order for d in set(dtypes[1:])
+    )
+    return order, (slabs, rows, cols), (blk_slabs, blk_rows, blk_cols), copied
+
+
+# Attribution (HLO metadata only, no instruction changes): what turns a leaf
+# into the kernel's view and back (bitcasts, which take no device time;
+# whatever XLA makes a copy after all shows here) reads ``opt_tile`` in a
+# trace, the Pallas calls alone ``opt_kernel`` — the two halves of the step's
 # ``optimizer_update`` scope that the benchmark's readers tell apart
 # (kernels.opt_tile_ms_per_step / kernels.opt_kernel_ms_per_step).
 TILE_SCOPE = "opt_tile"
@@ -69,37 +115,50 @@ KERNEL_SCOPE = "opt_kernel"
 KERNEL_NAME = "dtpu_opt_update_{kind}"  # kind: sgd | sgd_plain | adamw
 
 
-def _tiled(x, rows: int):
+def _to_view(x, order, view):
     with jax.named_scope(TILE_SCOPE):
-        flat = x.reshape(-1)
-        pad = rows * _LANES - flat.shape[0]
-        if pad:
-            flat = jnp.pad(flat, (0, pad))
-        return flat.reshape(rows, _LANES)
+        return x.transpose(order).reshape(view)
 
 
-def _untiled(t, shape, n: int):
+def _from_view(t, order, shape):
     with jax.named_scope(TILE_SCOPE):
-        return t.reshape(-1)[:n].reshape(shape)
+        rested = t.reshape([shape[d] for d in order])
+        return rested.transpose([order.index(d) for d in range(len(order))])
 
 
-def _call(kind, kernel, scalars, tensors, out_dtypes, rows, blk, interpret):
-    spec = pl.BlockSpec((blk, _LANES), lambda i: (i, 0))
-    sspec = pl.BlockSpec(scalars.shape, lambda i: (0, 0))
+def _call(kind, kernel, scalars, tensors, out_dtypes, interpret, device):
+    """One Pallas call over one leaf's operands (all of the leaf's shape);
+    returns the outputs in that shape."""
+    shape = tensors[0].shape
+    order, view, block, _ = _plan(shape, [t.dtype for t in tensors], device)
+    spec = pl.BlockSpec(block, lambda *ijk: ijk)
+    sspec = pl.BlockSpec(scalars.shape, lambda *ijk: (0, 0))
+    views = [_to_view(t, order, view) for t in tensors]
     with jax.named_scope(KERNEL_SCOPE):
-        return pl.pallas_call(
+        outs = pl.pallas_call(
             kernel,
-            out_shape=tuple(
-                jax.ShapeDtypeStruct((rows, _LANES), d) for d in out_dtypes
-            ),
-            grid=(rows // blk,),
-            in_specs=[sspec] + [spec] * len(tensors),
+            # the outputs, and with them the operands aliased to them, are
+            # declared in HBM: left to itself XLA stages a third of the state
+            # through VMEM with asynchronous copies outside the call, which is
+            # 0.2-0.5 ms a step faster and leaves the update's traffic where
+            # no scope can read it (PERF.md §7 has both arms)
+            out_shape=tuple(pltpu.HBM(view, d) for d in out_dtypes),
+            grid=tuple(pl.cdiv(n, b) for n, b in zip(view, block)),
+            in_specs=[sspec] + [spec] * len(views),
             out_specs=tuple(spec for _ in out_dtypes),
+            # every output overwrites the operand it updates (p, then the
+            # moments; g sits between): the step donates its state, and
+            # without the alias XLA copies p and each moment in front of the
+            # call to keep the donated buffers for the outputs
+            input_output_aliases={
+                1 if k == 0 else k + 2: k for k in range(len(out_dtypes))
+            },
             interpret=interpret,
             # a stable kernel name: a trace reader must not depend on what
             # XLA happens to call the custom call under the current scopes
             name=KERNEL_NAME.format(kind=kind),
-        )(scalars, *tensors)
+        )(scalars, *views)
+    return tuple(_from_view(o, order, shape) for o in outs)
 
 
 # ------------------------------------------------------------- the kernels
@@ -154,49 +213,60 @@ def _adamw_kernel(sc_ref, p_ref, g_ref, mu_ref, nu_ref,
 # ------------------------------------------------------------ per-leaf ops
 
 
-def sgd_leaf(p, g, t, lr, *, wd, mom, nesterov, interpret):
+def sgd_leaf(p, g, t, lr, *, wd, mom, nesterov, interpret, device=None):
     """Fused SGD-momentum for ONE leaf → (p_new, trace_new). ``t=None``
-    is the momentum-less configuration (no trace tensor at all)."""
-    n = p.size
-    rows, blk = _pad_rows(n)
+    is the momentum-less configuration (no trace tensor at all).
+    ``device`` is the one the leaf rests on (:func:`_resting_order`)."""
     sc = jnp.asarray(lr, jnp.float32).reshape(1, 1)
     if t is None:
         (po,) = _call(
             "sgd_plain", functools.partial(_sgd_plain_kernel, wd=wd),
-            sc, (_tiled(p, rows), _tiled(g, rows)), (p.dtype,),
-            rows, blk, interpret,
+            sc, (p, g), (p.dtype,), interpret, device,
         )
-        return _untiled(po, p.shape, n), None
-    po, to = _call(
+        return po, None
+    return _call(
         "sgd",
         functools.partial(_sgd_kernel, wd=wd, mom=mom, nesterov=nesterov),
-        sc, (_tiled(p, rows), _tiled(g, rows), _tiled(t, rows)),
-        (p.dtype, t.dtype),
-        rows, blk, interpret,
+        sc, (p, g, t), (p.dtype, t.dtype), interpret, device,
     )
-    return _untiled(po, p.shape, n), _untiled(to, t.shape, n)
 
 
-def adamw_leaf(p, g, mu, nu, lr, c1, c2, *, b1, b2, eps, wd, interpret):
+def adamw_leaf(p, g, mu, nu, lr, c1, c2, *, b1, b2, eps, wd, interpret,
+               device=None):
     """Fused AdamW for ONE leaf → (p_new, mu_new, nu_new). ``c1``/``c2``
     are the 1−β₁ᵗ / 1−β₂ᵗ bias corrections (traced scalars)."""
-    n = p.size
-    rows, blk = _pad_rows(n)
     sc = jnp.stack([
         jnp.asarray(lr, jnp.float32),
         jnp.asarray(c1, jnp.float32),
         jnp.asarray(c2, jnp.float32),
     ]).reshape(1, 3)
-    po, muo, nuo = _call(
+    return _call(
         "adamw",
         functools.partial(_adamw_kernel, b1=b1, b2=b2, eps=eps, wd=wd),
-        sc, (_tiled(p, rows), _tiled(g, rows), _tiled(mu, rows),
-             _tiled(nu, rows)),
-        (p.dtype, mu.dtype, nu.dtype),
-        rows, blk, interpret,
+        sc, (p, g, mu, nu), (p.dtype, mu.dtype, nu.dtype), interpret, device,
     )
-    return (_untiled(po, p.shape, n), _untiled(muo, mu.shape, n),
-            _untiled(nuo, nu.shape, n))
+
+
+def _gauge_plans(device, *trees) -> None:
+    """How the leaves of the update being traced go to the kernel, into the
+    telemetry registry next to ``setup.*``: ``opt_update.viewed_leaves`` /
+    ``viewed_bytes`` (every operand updated where it rests) and
+    ``copied_leaves`` / ``copied_bytes`` (an operand re-laid out for the call
+    and back), in parameter bytes.
+    Gauges of the update traced last, so a re-trace does not double them;
+    under ``shard_map`` the shapes, and so the bytes, are one shard's."""
+    from distribuuuu_tpu.telemetry import get_registry
+
+    tally = dict.fromkeys(
+        ("viewed_leaves", "viewed_bytes", "copied_leaves", "copied_bytes"), 0
+    )
+    for p, *rest in zip(*map(jax.tree.leaves, trees)):
+        copied = _plan(p.shape, [x.dtype for x in (p, *rest)], device)[3]
+        how = "copied" if copied else "viewed"
+        tally[f"{how}_leaves"] += 1
+        tally[f"{how}_bytes"] += p.size * p.dtype.itemsize
+    for name, value in tally.items():
+        get_registry().gauge(f"opt_update.{name}").set(value)
 
 
 # ------------------------------------------------- the optax-shaped update
@@ -228,7 +298,7 @@ def _find_state(inner, field: str):
 def fused_optimizer_update(params, grads, opt_state, *, kind: str,
                            wd: float, mom: float, nesterov: bool,
                            b1: float, b2: float, eps: float,
-                           interpret: bool):
+                           interpret: bool, device=None):
     """Drop-in replacement for ``optimizer.update`` + ``apply_updates``
     for the two shipped optimizers (utils/optim.construct_optimizer):
     reads the injected learning rate and the moment trees out of the
@@ -244,10 +314,11 @@ def fused_optimizer_update(params, grads, opt_state, *, kind: str,
         found = _find_state(inner, "trace") if mom else None
         if found is not None:
             trace_state, rebuild = found
+            _gauge_plans(device, params, grads, trace_state.trace)
             out = jax.tree.map(
                 lambda p, g, t: sgd_leaf(
                     p, g, t, lr, wd=wd, mom=mom, nesterov=nesterov,
-                    interpret=interpret,
+                    interpret=interpret, device=device,
                 ),
                 params, grads, trace_state.trace,
             )
@@ -257,10 +328,11 @@ def fused_optimizer_update(params, grads, opt_state, *, kind: str,
             new_trace = jax.tree.map(lambda _, o: o[1], params, out)
             new_inner = rebuild(trace_state._replace(trace=new_trace))
         else:
+            _gauge_plans(device, params, grads)
             new_params = jax.tree.map(
                 lambda p, g: sgd_leaf(
                     p, g, None, lr, wd=wd, mom=0.0, nesterov=False,
-                    interpret=interpret,
+                    interpret=interpret, device=device,
                 )[0],
                 params, grads,
             )
@@ -270,10 +342,11 @@ def fused_optimizer_update(params, grads, opt_state, *, kind: str,
         count_inc = optax.safe_int32_increment(adam_state.count)
         c1 = 1 - b1 ** count_inc  # optax.tree_bias_correction's exact expr
         c2 = 1 - b2 ** count_inc
+        _gauge_plans(device, params, grads, adam_state.mu, adam_state.nu)
         out = jax.tree.map(
             lambda p, g, m, v: adamw_leaf(
                 p, g, m, v, lr, c1, c2, b1=b1, b2=b2, eps=eps, wd=wd,
-                interpret=interpret,
+                interpret=interpret, device=device,
             ),
             params, grads, adam_state.mu, adam_state.nu,
         )
@@ -319,6 +392,7 @@ def fused_update_for(optimizer_kind: str | None = None, layout=None):
     if impl != "pallas":
         return None
     interpret = tier.interpret_mode()
+    mesh = None if layout is None else jax.tree.leaves(layout["grads"])[0].mesh
     kwargs = dict(
         kind=kind,
         wd=float(cfg.OPTIM.WEIGHT_DECAY),
@@ -328,12 +402,14 @@ def fused_update_for(optimizer_kind: str | None = None, layout=None):
         b2=float(cfg.OPTIM.BETA2),
         eps=1e-8,  # optax.adamw's default — construct_optimizer passes none
         interpret=interpret,
+        # the device the state rests on says in which layout
+        device=jax.devices()[0] if mesh is None else mesh.devices.flat[0],
     )
 
     def update(params, grads, opt_state):
         return fused_optimizer_update(params, grads, opt_state, **kwargs)
 
-    if layout is None or jax.tree.leaves(layout["grads"])[0].mesh.size == 1:
+    if mesh is None or mesh.size == 1:
         return update
     return per_shard_update(update, layout)
 

@@ -69,7 +69,8 @@ def _dp8_step():
 def test_compiled_step_carries_the_scopes(build, under_shard_map):
     _toy_cfg()
     step, state, batch = build()
-    op_names = trace.op_names_from_hlo(step.lower(state, batch).compile().as_text())
+    lowered = step.lower(state, batch)
+    op_names = trace.op_names_from_hlo(lowered.compile().as_text())
     paths = list(op_names.values())
 
     def count(scope, also=()):
@@ -78,7 +79,11 @@ def test_compiled_step_carries_the_scopes(build, under_shard_map):
             for p in paths
         )
 
-    assert count("bwd") and count("opt_tile") and count("opt_kernel")
+    assert count("bwd") and count("opt_kernel")
+    # ``opt_tile`` is the reshape of every leaf to the kernel's view and
+    # back: in the program as lowered, but bitcasts once compiled (all of
+    # them here; on the chip all but the copied leaves'), which run nothing
+    assert "opt_tile/reshape" in lowered.as_text(debug_info=True)
     # the transposed pass carries the forward's scope inside ``bwd``
     assert any("bwd/transpose(jvp(fwd))" in p for p in paths)
     # forward-only operations exist and are told apart
@@ -92,6 +97,31 @@ def test_compiled_step_carries_the_scopes(build, under_shard_map):
     assert count("dtpu_opt_update_sgd", ["opt_kernel"]) == count("opt_kernel")
     assert not count("opt_tile", ["opt_kernel"])
     assert bool(count("shard_map", ["opt_kernel"])) == under_shard_map
+
+
+@pytest.mark.parametrize("build", [_plain_step, _dp8_step],
+                         ids=["plain", "shard_map"])
+def test_the_update_gauges_how_its_leaves_went(build):
+    """``opt_update.viewed_*`` + ``opt_update.copied_*`` = every leaf and
+    every parameter byte of the update traced last. With one dtype for
+    parameters and momentum every operand rests in one order: all viewed."""
+    from distribuuuu_tpu.telemetry import get_registry
+
+    _toy_cfg()
+    step, state, batch = build()
+    get_registry().reset()
+    step.lower(state, batch)
+    gauges = get_registry().snapshot()["gauges"]
+    went = {k.split(".")[1]: int(v) for k, v in gauges.items()
+            if k.startswith("opt_update.")}
+    leaves = jax.tree.leaves(state.params)
+    assert went["viewed_leaves"] + went["copied_leaves"] == len(leaves)
+    assert went["viewed_bytes"] + went["copied_bytes"] == sum(
+        x.size * x.dtype.itemsize for x in leaves)
+    assert went["copied_leaves"] == went["copied_bytes"] == 0
+    # a re-trace sets the same values again: gauges, not running sums
+    step.lower(state, batch)
+    assert get_registry().snapshot()["gauges"] == gauges
 
 
 def _tpu_text(fn, *avals) -> str:

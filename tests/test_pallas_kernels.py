@@ -179,6 +179,94 @@ def test_fused_sgd_without_momentum():
     assert _tree_bit_equal(p1, p2)
 
 
+# Leaf shapes ResNet-50 and RegNetY-16GF really have, one per way
+# ``opt_update._plan`` blocks a leaf: one [second-minor, minor] slab a block,
+# a last dimension under one lane tile, a plain 2-D leaf in row blocks, rows
+# that no sublane tile divides over a ragged last block, a last dimension
+# that is no lane multiple with two slabs a block, a stem whose second-minor
+# dimension is 3, a 1-D leaf, and the largest conv (36 blocks).
+MODEL_LEAVES = [
+    (3, 3, 256, 256), (1, 1, 64, 256), (2048, 1000), (1, 1, 308, 1232),
+    (3, 3, 112, 224), (7, 7, 3, 64), (64,), (3, 3, 512, 512),
+]
+UPDATE_KINDS = {  # config overrides, fused kind
+    "sgd_f32": ({}, "sgd"),
+    "sgd_bf16_trace": ({"MOMENTUM_DTYPE": "bfloat16"}, "sgd"),
+    "sgd_plain": ({"MOMENTUM": 0.0}, "sgd"),
+    "adamw": ({"OPTIMIZER": "adamw"}, "adamw"),
+}
+
+
+@pytest.mark.parametrize("shape", MODEL_LEAVES, ids=str)
+@pytest.mark.parametrize("update", list(UPDATE_KINDS))
+def test_fused_update_bit_exact_on_model_leaf_shapes(update, shape):
+    """Every way a leaf goes to the kernel (viewed in place, blocked with
+    a ragged end, slabs, one block) gives optax's bits: parameters
+    and every moment, two steps, so the moments feed back."""
+    from distribuuuu_tpu.utils.optim import construct_optimizer
+
+    overrides, kind = UPDATE_KINDS[update]
+    cfg.defrost()
+    for key, value in overrides.items():
+        cfg.OPTIM[key] = value
+    rng = np.random.default_rng(4)
+    params = {"leaf": jnp.asarray(rng.standard_normal(shape), jnp.float32)}
+    grads = jax.tree.map(lambda x: x * 0.1, params)
+    opt = construct_optimizer()
+    state = opt.init(params)
+    _, view, block, _ = ou._plan(shape, [jnp.float32])
+    if shape == (1, 1, 308, 1232):
+        assert view[1] % block[1], "the ragged last block is the point"
+
+    @jax.jit
+    def ref(p, g, s):
+        u, s2 = opt.update(g, s, p)
+        return optax.apply_updates(p, u), s2
+
+    @jax.jit
+    def fused(p, g, s):
+        return ou.fused_optimizer_update(
+            p, g, s, kind=kind, wd=float(cfg.OPTIM.WEIGHT_DECAY),
+            mom=float(cfg.OPTIM.MOMENTUM),
+            nesterov=bool(cfg.OPTIM.NESTEROV), b1=float(cfg.OPTIM.BETA1),
+            b2=float(cfg.OPTIM.BETA2), eps=1e-8, interpret=True,
+        )
+
+    p1, s1 = params, state
+    p2, s2 = params, state
+    for _ in range(2):
+        p1, s1 = ref(p1, grads, s1)
+        p2, s2 = fused(p2, grads, s2)
+    assert _tree_bit_equal(p1, p2)
+    assert _tree_bit_equal(s1, s2)
+    assert (jax.tree_util.tree_structure(s1)
+            == jax.tree_util.tree_structure(s2))
+
+
+@pytest.mark.parametrize("shape,order", [
+    ((2048, 1000), (1, 0)),            # the TPU rests the classifier so
+    ((1, 1, 256, 64), (0, 1, 3, 2)),   # ... and this conv
+    ((1, 1, 308, 1232), (0, 2, 1, 3)),  # ... and RegNetY's SE convs
+])
+def test_fused_sgd_bit_exact_in_a_resting_order(monkeypatch, shape, order):
+    """The kernel sees a leaf in the dimension order the device rests it
+    in; the CPU rests everything row-major, so the TPU's orders are put in
+    by hand here. Elementwise, so any order gives optax's bits."""
+    monkeypatch.setattr(ou, "_resting_order", lambda *a: order)
+    _, view, _, copied = ou._plan(shape, [jnp.float32])
+    assert view[1:] == (shape[order[-2]], shape[order[-1]]) and not copied
+    rng = np.random.default_rng(5)
+    p, g, t = (jnp.asarray(rng.standard_normal(shape), jnp.float32)
+               for _ in range(3))
+    kw = dict(wd=5e-5, mom=0.9, nesterov=True)
+    got = jax.jit(lambda p, g, t: ou.sgd_leaf(
+        p, g, t, jnp.float32(0.1), interpret=True, **kw))(p, g, t)
+    monkeypatch.undo()
+    want = jax.jit(lambda p, g, t: ou.sgd_leaf(
+        p, g, t, jnp.float32(0.1), interpret=True, **kw))(p, g, t)
+    assert got[0].shape == shape and _tree_bit_equal(got, want)
+
+
 def test_zero_sharded_update_equals_unsharded_then_shard():
     """The partition layer's shard-compat contract: the fused update is
     elementwise per leaf, so updating a ZeRO shard must equal slicing

@@ -46,20 +46,100 @@ def test_decode_attn_lowers(b, h, c, d, cache_dtype):
 
 # a conv, a bias-sized vector and the classifier leaf of ResNet-50
 LEAVES = [(3, 3, 64, 64), (64,), (2048, 1000)]
+# every other block shape the benchmark's cells give the kernel (RegNetY's
+# widths are no lane multiples): 308 rows over a ragged last block, a last
+# dimension of 224 and of 8, a stem's second-minor 3, RegNetY's classifier
+SGD_LEAVES = LEAVES + [
+    (1, 1, 308, 1232), (3, 3, 112, 224), (1, 1, 224, 8), (7, 7, 3, 64),
+    (3024, 1000),
+]
+# AdamW holds four operands a block: the widest leaf and a ragged one
+ADAMW_LEAVES = LEAVES + [(1, 1, 1232, 3024), (1, 1, 308, 1232)]
 
 
-@pytest.mark.parametrize("shape", LEAVES)
-@pytest.mark.parametrize("trace_dtype", [jnp.float32, jnp.bfloat16])
-def test_opt_update_sgd_lowers(shape, trace_dtype):
-    _lowers_for_tpu(
+def _sgd_text(shape, trace_dtype=jnp.float32) -> str:
+    return jax.jit(
         lambda p, g, t, lr: opt_update.sgd_leaf(
-            p, g, t, lr, wd=5e-5, mom=0.9, nesterov=True, interpret=False),
+            p, g, t, lr, wd=5e-5, mom=0.9, nesterov=True, interpret=False)
+    ).trace(
         _f32(*shape), _f32(*shape),
         jax.ShapeDtypeStruct(shape, trace_dtype), _f32(),
-    )
+    ).lower(lowering_platforms=("tpu",)).as_text()
 
 
-@pytest.mark.parametrize("shape", LEAVES)
+@pytest.mark.parametrize("shape", [(3, 3, 256, 256), (64,), (7, 7, 3, 64)])
+def test_opt_update_reaches_the_kernel_through_reshapes_alone(shape):
+    """A leaf goes to the kernel as a 3-D view (a 1-D leaf as [1, 1, n]):
+    reshapes that leave the two tiled dimensions alone, so bitcasts on the
+    TPU. Nothing is padded and nothing sliced back."""
+    text = _sgd_text(shape)
+    assert "tpu_custom_call" in text and "stablehlo.reshape" in text
+    assert "stablehlo.pad" not in text and "stablehlo.slice" not in text
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One chip of a described (not attached) v5e host: the installed
+    XLA:TPU and Mosaic compile for it. Described inside the fixture, so only
+    the worker that runs this file loads the TPU's library."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return topo.devices[0]
+
+
+@pytest.mark.parametrize("shape,order", [
+    ((3, 3, 256, 256), (0, 1, 2, 3)),   # rests row-major
+    ((2048, 1000), (1, 0)),             # ResNet-50's classifier: column-major
+    ((1, 1, 256, 64), (0, 1, 3, 2)),    # 256 on the lanes, not 64
+    ((1, 1, 224, 8), (0, 1, 3, 2)),     # RegNetY's first SE reduce
+    ((1, 1, 308, 1232), (0, 2, 1, 3)),  # an SE expand: 308 in (1, 128) tiles
+    ((7, 7, 3, 64), (0, 2, 1, 3)),      # ResNet-50's stem
+])
+def test_opt_update_compiles_for_the_v5e_with_no_copy_of_the_leaf(
+        v5e_chip, shape, order):
+    """The whole point of the view: compiled for the chip, with the state
+    resting in the layouts the TPU's client gives it and donated as the
+    trainer donates it, the program around the Mosaic call holds no copy,
+    transpose, pad or slice of the leaf — the kernel reads and writes the
+    leaf where it rests."""
+    from jax.sharding import SingleDeviceSharding
+
+    assert opt_update._resting_order(shape, jnp.float32, v5e_chip) == order
+    chip = SingleDeviceSharding(v5e_chip)
+    leaf = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=chip)
+    compiled = jax.jit(
+        lambda p, g, t, lr: opt_update.sgd_leaf(
+            p, g, t, lr, wd=5e-5, mom=0.9, nesterov=True, interpret=False,
+            device=v5e_chip),
+        donate_argnums=(0, 2),
+    ).lower(
+        leaf, leaf, leaf, jax.ShapeDtypeStruct((), jnp.float32, sharding=chip)
+    ).compile().as_text()
+    entry = compiled[compiled.index("\nENTRY "):]
+    view = opt_update._plan(shape, [jnp.float32], v5e_chip)[1]
+    of_the_leaf = tuple(
+        "f32[" + ",".join(map(str, dims)) + "]" for dims in (shape, view))
+    moving = ("copy(", "transpose(", "pad(", "slice(", "fusion(", "reshape(")
+    moved = [
+        line.strip()[:120] for line in entry.splitlines()
+        if any(f" {op}" in line for op in moving)
+        and line.split(" = ")[1].startswith(of_the_leaf)
+    ]
+    assert "tpu_custom_call" in entry and not moved, moved
+
+
+@pytest.mark.parametrize("shape", SGD_LEAVES)
+@pytest.mark.parametrize("trace_dtype", [jnp.float32, jnp.bfloat16])
+def test_opt_update_sgd_lowers(shape, trace_dtype):
+    assert "tpu_custom_call" in _sgd_text(shape, trace_dtype)
+
+
+@pytest.mark.parametrize("shape", ADAMW_LEAVES)
 def test_opt_update_adamw_lowers(shape):
     _lowers_for_tpu(
         lambda p, g, m, v, lr, c1, c2: opt_update.adamw_leaf(
