@@ -19,9 +19,11 @@ repo's own means:
                  greedy tokens equal the ``KERNELS.DECODE_ATTN xla``
                  engine's (or part only where the reference model ties),
                  kernel-vs-reference logits within the pinned tolerance
-5. flash         the one ``auto`` kernel no shipped config reaches: flash
-                 attention fwd+bwd at [2, 4, 1024, 64] causal against the
-                 dense reference
+5. flash         flash attention fwd+bwd, causal: at [2, 4, 1024, 64] float32
+                 against the dense reference, and at the shape the
+                 benchmark's LM cell runs ([4, 16, 4096, 128] bfloat16,
+                 default blocks) against ``blockwise_attention``, so that a
+                 Mosaic that stops taking the kernel fails here first
 
 Every phase is reported by name with pass/fail and wall seconds split into
 compile and run; a ``kernel.fallback`` record anywhere is a failure. It
@@ -67,6 +69,7 @@ class Sizes:
     max_new: int
     flash_shape: tuple
     flash_blk: int
+    flash_cell_shape: tuple  # the benchmark's LM cell: bf16, default blocks
 
 
 FULL = Sizes(
@@ -80,6 +83,7 @@ FULL = Sizes(
     max_new=24,
     flash_shape=(2, 4, 1024, 64),
     flash_blk=512,
+    flash_cell_shape=(4, 16, 4096, 128),  # olmoe_1b_7b.train_seq4096
 )
 
 TOY = Sizes(
@@ -101,6 +105,7 @@ TOY = Sizes(
     max_new=6,
     flash_shape=(1, 2, 256, 32),
     flash_blk=128,
+    flash_cell_shape=(1, 2, 256, 32),
 )
 
 
@@ -552,19 +557,38 @@ def phase_flash(sizes: Sizes, out_dir: str) -> dict:
         loss = lambda q, k, v: jnp.sum(fn(q, k, v) * w)  # noqa: E731
         return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, k, v)
 
-    (lf, gf), (lr, gr) = run(flash), run(ref)
+    def compare(prefix, got, want, tol, out):
+        (lf, gf), (lr, gr) = got, want
+        out[f"{prefix}loss_rel_diff"] = abs(float(lf) - float(lr)) / abs(float(lr))
+        _check(out[f"{prefix}loss_rel_diff"] <= tol, f"flash loss off by {out}")
+        for name, a, r in zip(("dq", "dk", "dv"), gf, gr):
+            diff = float(jnp.abs(a.astype(jnp.float32) - r.astype(jnp.float32)).max())
+            out[f"{prefix}{name}_max_abs_diff"] = diff
+            _check(bool(jnp.isfinite(a).all()), f"flash {prefix}{name} is not finite")
+            _check(diff <= tol * max(1.0, float(jnp.abs(r).max())),
+                   f"flash {prefix}{name} differs from its reference by {diff}")
+        return out
+
     # fp32 inputs: the MXU's default precision rounds both sides' matmul
     # operands to bf16 on the TPU, in different places
-    tol = 5e-5 if interpret else 0.05
-    out = {"loss_rel_diff": abs(float(lf) - float(lr)) / abs(float(lr))}
-    _check(out["loss_rel_diff"] <= tol, f"flash loss off by {out}")
-    for name, a, r in zip(("dq", "dk", "dv"), gf, gr):
-        diff = float(jnp.abs(a - r).max())
-        out[f"{name}_max_abs_diff"] = diff
-        _check(bool(jnp.isfinite(a).all()), f"flash {name} is not finite")
-        _check(diff <= tol * max(1.0, float(jnp.abs(r).max())),
-               f"flash {name} differs from dense by {diff}")
-    return out
+    out = compare("", run(flash), run(ref), 5e-5 if interpret else 0.05, {})
+
+    # the LM cell's shape and dtype, the blocks the model gets; the dense
+    # reference would hold 4 GB of scores there, the blockwise scan does not
+    from distribuuuu_tpu.ops.ring_attention import blockwise_attention
+
+    b, h, L, d = sizes.flash_cell_shape
+    q, k, v = (jnp.asarray(rng.standard_normal((b, h, L, d)), jnp.bfloat16)
+               for _ in range(3))
+    w = jnp.asarray(rng.standard_normal((d,)), jnp.float32)
+    return compare(
+        "cell_",
+        run(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, interpret=interpret).astype(jnp.float32)),
+        run(lambda q, k, v: blockwise_attention(
+            q, k, v, causal=True).astype(jnp.float32)),
+        0.05, out,
+    )
 
 
 # ------------------------------------------------------------------ driver

@@ -226,6 +226,9 @@ _C.MODEL.MOE.IMPL = "partial"
 # Dispatch capacity: each expert takes ceil(T_shard·top_k/E × this) slots
 # per source rank. Raise toward E/top_k for exactness, lower for speed.
 _C.MODEL.MOE.CAPACITY_FACTOR = 2.0
+# Weight of the router z-loss mean(logsumexp(router logits)^2) that the
+# olmoe_* archs sow (ops/moe.router_z_loss); 0 disables.
+_C.MODEL.MOE.Z_WEIGHT = 0.0
 
 # ------------------------------- training ----------------------------------
 _C.TRAIN = CfgNode()
@@ -356,6 +359,10 @@ _C.LM = CfgNode()
 # construction with the repack command. Also the learned-position table
 # size, so generation prompts + new tokens must fit under it.
 _C.LM.SEQ_LEN = 256
+# Depth override for archs whose depth is a knob (olmoe_*): 0 keeps the
+# arch's own. One chip holds 1 of OLMoE-1B-7B's 16 layers with its
+# optimizer state (PERF.md section 4).
+_C.LM.LAYERS = 0
 # -------------------------------- generation --------------------------------
 # Autoregressive serving (lm/generate.py): paged per-request KV cache,
 # prefill/decode split, continuous batching. The serve engine's AOT-bucket

@@ -39,6 +39,8 @@ from distribuuuu_tpu.models.vit import (  # noqa: F401
     vit_tiny_moe,
 )
 from distribuuuu_tpu.models.gpt import gpt_nano, gpt_nano_moe  # noqa: F401
+from distribuuuu_tpu.models.olmoe import olmoe_1b_7b, olmoe_tiny  # noqa: F401
+from distribuuuu_tpu.models.traits import ArchTraits
 
 _REGISTRY = {}
 
@@ -76,12 +78,22 @@ for _fn in (
     # batches, causal attention, next-token CE through the same trainer
     gpt_nano,
     gpt_nano_moe,
+    # OLMoE (models/olmoe.py): rotary / RMSNorm / QK-norm attention and
+    # dropless sorted top-k gated experts, at the published sizes and tiny
+    olmoe_1b_7b,
+    olmoe_tiny,
 ):
     register_model(_fn)
 
 
 def available_models():
     return sorted(_REGISTRY)
+
+
+def traits(arch: str) -> ArchTraits:
+    """What ``arch`` declares of itself (models/traits.py); the defaults for
+    an arch that declares nothing, or that the registry lacks."""
+    return getattr(_REGISTRY.get(str(arch)), "traits", None) or ArchTraits()
 
 
 def build_model(arch: str, **kwargs):

@@ -38,6 +38,7 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from distribuuuu_tpu.models.layers import Dense, head_dtype
+from distribuuuu_tpu.models.traits import ArchTraits
 from distribuuuu_tpu.models.vit import Block
 
 
@@ -162,3 +163,6 @@ def gpt_nano_moe(num_classes=320, **kw):
     ``MESH.EXPERT`` when populated, the ``model`` axis otherwise."""
     kw.setdefault("moe_experts", 8)
     return _gpt(num_classes, kw, dim=128, depth=4, num_heads=4)
+
+
+gpt_nano.traits = gpt_nano_moe.traits = ArchTraits(token_batch=True, batch_norm=False)

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from distribuuuu_tpu.models.layers import Dense
+from distribuuuu_tpu.models.traits import ArchTraits
 
 
 class Mlp(nn.Module):
@@ -70,6 +71,13 @@ class MoeMlp(nn.Module):
     The switch-transformer load-balancing aux (arXiv:2101.03961) is sown
     into the ``intermediates`` collection under ``moe_aux``; the trainer
     adds ``MODEL.MOE.AUX_WEIGHT ×`` its mean to the task loss.
+
+    Gating is renormalized over the selected experts
+    (``ops/moe.top_k_from_probs``) and an expert is two matrices with
+    biases and a GELU. The other convention and the third path of
+    ``ops/moe.py`` — probabilities as they are, gated bias-free experts,
+    dropless and sorted — are ``models/olmoe.MoE``'s; this module does not
+    select them (ROADMAP D5 decides between the paths).
 
     ``impl`` selects the execution strategy (config ``MODEL.MOE.IMPL``):
     ``"partial"`` — every rank runs its local experts on all tokens, one
@@ -922,3 +930,6 @@ def vit_tiny_moe(num_classes=1000, **kw):
     expert-parallel arch: expert tensors shard over the ``model`` axis."""
     kw.setdefault("moe_experts", 8)
     return _vit(num_classes, kw, dim=192, depth=12, num_heads=3)
+
+
+vit_tiny.traits = vit_small.traits = vit_tiny_moe.traits = ArchTraits(batch_norm=False)

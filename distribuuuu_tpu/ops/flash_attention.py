@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distribuuuu_tpu.ops import pallas as kernel_tier
+
 _NEG_BIG = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 # VMEM headroom for the whole-sequence-resident tensors (see module
@@ -324,6 +326,7 @@ def _flash_forward(q, k, v, scale, interpret, blk_q, blk_k, causal):
         in_specs=[blocked(), whole(), whole()],
         out_specs=(blocked(), vec_blocked()),
         interpret=interpret,
+        name="dtpu_flash_fwd",
     )(qf, kf, vf)
     return (
         o[:, :L].reshape(b, h, L, d),
@@ -366,6 +369,7 @@ def _flash_backward(res, g, scale, interpret, blk_q, blk_k, causal,
                   vec_blocked_q(), vec_blocked_q()],
         out_specs=blocked_q(),
         interpret=interpret,
+        name="dtpu_flash_dq",
     )(qf, kf, vf, gf, lse, delta)
 
     blocked_k, _, vec_blocked_k, _ = _specs(lp, d, blk_k)
@@ -382,6 +386,7 @@ def _flash_backward(res, g, scale, interpret, blk_q, blk_k, causal,
                   vec_whole(), vec_whole()],
         out_specs=(blocked_k(), blocked_k()),
         interpret=interpret,
+        name="dtpu_flash_dkdv",
     )(qf, kf, vf, gf, lse, delta)
 
     def unpad(t):
@@ -445,6 +450,7 @@ _flash_attention_lse.defvjp(_fal_fwd, _fal_bwd)
 def flash_attention(
     q, k, v, *, scale: float | None = None, causal: bool = False,
     interpret: bool | None = None, blk_q: int = BLK_Q, blk_k: int = BLK_K,
+    mesh=None,
 ):
     """Exact softmax attention, flash-tiled in Pallas.
 
@@ -456,7 +462,13 @@ def flash_attention(
     bounds shrink with the program id — ~2× fewer blocks at large L) and
     the diagonal blocks mask elementwise.
 
-    Off-TPU (and when ``interpret`` is not forced), and for sequences past
+    A caller that knows its ``mesh`` hands it over: where its ``data`` axis is
+    populated (and divides the batch) every data rank runs the kernel on its
+    own sequences under ``shard_map``, as ``ops/moe.moe_ffn_sorted`` and
+    ``opt_update`` do, because GSPMD cannot partition a bare Mosaic call.
+
+    Off-TPU, in a program that may span several devices when no mesh came
+    with the call (when ``interpret`` is not forced), and for sequences past
     the VMEM-residency bound (~19k tokens at D=64 — module docstring),
     this falls back to ``blockwise_attention`` — the same exact-softmax
     math as a lax.scan — so call sites run unchanged at any length and on
@@ -466,6 +478,21 @@ def flash_attention(
     if d > 128:
         raise ValueError(f"head_dim {d} > 128: lane tiling not supported")
     scale = d ** -0.5 if scale is None else scale
+    shards = int(dict(mesh.shape).get("data", 1)) if mesh is not None else 1
+    if shards > 1 and q.shape[0] % shards == 0:
+        def per_shard(q, k, v):
+            # one device's sequences: the kernel tier may engage
+            with kernel_tier.single_device_program():
+                return flash_attention(
+                    q, k, v, scale=scale, causal=causal, interpret=interpret,
+                    blk_q=blk_q, blk_k=blk_k,
+                )
+
+        rows = jax.sharding.PartitionSpec("data")
+        return jax.shard_map(
+            per_shard, mesh=mesh, in_specs=(rows, rows, rows), out_specs=rows,
+            check_vma=False,
+        )(q, k, v)
 
     def _scan_fallback():
         from distribuuuu_tpu.ops.ring_attention import blockwise_attention
@@ -481,7 +508,11 @@ def flash_attention(
         # via the scan path instead of failing at Mosaic compile time
         return _scan_fallback()
     if interpret is None:
-        if jax.default_backend() != "tpu":
+        # the kernel tier's two questions (ops/pallas/__init__.py): off the
+        # TPU the interpreter is the test path, not the auto path; and a
+        # Mosaic call in a program that may span devices cannot be
+        # partitioned by GSPMD (a caller with a mesh got its shard_map above)
+        if kernel_tier.interpret_mode() or kernel_tier.compiled_across_devices():
             return _scan_fallback()
         interpret = False
     return _flash_attention(q, k, v, scale, interpret, blk_q, blk_k, causal)

@@ -4,30 +4,46 @@ Beyond the reference's capability set (DDP-only, SURVEY.md §2.3) — expert
 parallelism completes the framework's parallelism matrix (DP/TP/SP/PP/EP)
 because distributed scale is a first-class goal here.
 
-Two execution strategies over the same parameters:
+Three execution paths and two gating conventions.
+
+Paths:
 
 - ``moe_ffn_partial``: every rank runs its LOCAL experts over all tokens and
   the gate-weighted partial outputs are summed with one ``psum`` over the
   expert axis. Exact (no token dropping, no capacity), communication = one
-  allreduce of the output — the right choice when tokens-per-expert is dense
-  (small expert counts, top-k close to E).
+  allreduce of the output, cost O(E/n) expert rows a token — the right
+  choice when tokens-per-expert is dense (small expert counts, top-k close
+  to E). ``moe_ffn_reference`` is its one-device form and the oracle.
 - ``moe_ffn_dispatch``: classic switch-style routing. Tokens are dispatched
   to their top-k experts' ranks with ``all_to_all``, processed by the local
   experts at a fixed capacity, and combined back. Communication = 2
-  all_to_alls of the routed tokens — the scalable path when E is large and
-  top-k small. Over-capacity tokens are dropped (standard switch semantics),
-  so it matches the exact path only when capacity is ample.
+  all_to_alls of the routed tokens — the scalable path when the experts are
+  sharded, E is large and top-k small. Over-capacity tokens are dropped
+  (standard switch semantics), so it matches the exact path only when
+  capacity is ample.
+- ``moe_ffn_sorted``: dropless, O(top_k) expert rows a token, for experts
+  that are NOT sharded (one chip, or every data rank holding all of them):
+  the (token, slot) assignments are sorted by expert, each projection is one
+  grouped matmul over the sorted rows (``jax.lax.ragged_dot``), and the
+  rows are gathered back to their tokens, weighted and summed. No capacity,
+  nothing dropped. It runs the gated three-matrix bias-free expert
+  (``silu(x W_gate) * (x W_up)) W_down``); ``models/olmoe.py`` is its caller.
 
-Gating is top-k softmax (renormalized over the selected experts), the
-standard switch/mixtral formulation.
+Gating: ``top_k_from_probs`` renormalizes the selected probabilities to sum
+to 1 (the switch/mixtral convention; ``gpt_nano_moe``, ``vit_tiny_moe``).
+``top_k_as_is`` uses them as they come out of the softmax (OLMoE,
+``norm_topk_prob`` false).
 
 Parameters (functional, like ops/ring_attention.py):
   gate  [d, E]              (replicated)
   w_in  [E, d, f], b_in  [E, f]   (sharded over the expert axis, dim 0)
   w_out [E, f, d], b_out [E, d]   (sharded over the expert axis, dim 0)
+and for the sorted path ``w_gate``/``w_up`` [E, d, f], ``w_down`` [E, f, d].
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -81,6 +97,14 @@ def top_k_from_probs(probs, top_k: int):
     weights = weights / jnp.maximum(
         weights.sum(axis=-1, keepdims=True), 1e-9
     )
+    return weights, indices.astype(jnp.int32)
+
+
+def top_k_as_is(probs, top_k: int):
+    """Top-k gate whose weights are the selected probabilities as the
+    softmax gave them: no renormalization (OLMoE's ``norm_topk_prob``
+    false). Returns (weights [T, k] f32, indices [T, k] i32)."""
+    weights, indices = jax.lax.top_k(probs, top_k)
     return weights, indices.astype(jnp.int32)
 
 
@@ -462,3 +486,128 @@ def dispatch_inline(
     total = jax.lax.psum(total, reduce_axes)
     dropped = 1.0 - kept / jnp.maximum(total, 1.0)
     return out, dropped
+
+
+# ------------------------------------------------- dropless sorted experts
+
+
+def expert_counts(indices, num_experts: int):
+    """Rows each expert receives: [E] int32 from [..., k] expert ids."""
+    hot = indices[..., None] == jnp.arange(num_experts, dtype=indices.dtype)
+    return hot.sum(axis=tuple(range(indices.ndim)), dtype=jnp.int32)
+
+
+def load_max_over_mean(counts):
+    """The fullest expert's rows over the mean: 1.0 at a uniform routing,
+    E when every assignment lands on one expert."""
+    counts = counts.astype(jnp.float32)
+    return counts.max() / jnp.maximum(counts.mean(), 1e-9)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_sorted(x, order, inverse, top_k: int):
+    """Row ``i`` of the result is token ``order[i] // top_k``: the tokens
+    repeated ``top_k`` times and permuted into expert order. The backward
+    is a gather through the inverse permutation and a sum over the slots,
+    not the scatter-add a plain ``x[ids]`` transposes to."""
+    return x[order // top_k]
+
+
+def _take_sorted_fwd(x, order, inverse, top_k):
+    return x[order // top_k], (inverse, x.shape[0])
+
+
+def _take_sorted_bwd(top_k, res, g):
+    inverse, tokens = res
+    return g[inverse].reshape(tokens, top_k, -1).sum(axis=1), None, None
+
+
+_take_sorted.defvjp(_take_sorted_fwd, _take_sorted_bwd)
+
+
+@jax.custom_vjp
+def _permute_rows(y, perm, inverse):
+    """``y[perm]`` for a permutation; the backward gathers by ``inverse``."""
+    return y[perm]
+
+
+_permute_rows.defvjp(
+    lambda y, perm, inverse: (y[perm], inverse),
+    lambda inverse, g: (g[inverse], None, None),
+)
+
+
+def sorted_experts(params, x, weights, indices):
+    """The sorted path's body on ``x`` [T, d] with the router's verdict
+    ``weights``/``indices`` [T, k] already taken: sort, three grouped
+    matmuls, gather back, weight, sum. ``params`` holds ``w_gate``/``w_up``
+    [E, d, f] and ``w_down`` [E, f, d]; the matmuls run in ``x.dtype``."""
+    T, k = indices.shape
+    E = params["w_gate"].shape[0]
+    with jax.named_scope("moe_route"):
+        flat = indices.reshape(T * k)
+        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+        inverse = jnp.argsort(order).astype(jnp.int32)
+        sizes = expert_counts(flat, E)
+        rows = _take_sorted(x, order, inverse, k)  # [T*k, d]
+    with jax.named_scope("moe_experts"):
+        w_gate, w_up, w_down = (
+            params[name].astype(x.dtype) for name in ("w_gate", "w_up", "w_down")
+        )
+        hidden = jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, sizes))
+        hidden = hidden * jax.lax.ragged_dot(rows, w_up, sizes)
+        rows = jax.lax.ragged_dot(hidden, w_down, sizes)  # [T*k, d]
+    with jax.named_scope("moe_route"):
+        rows = _permute_rows(rows, inverse, order).reshape(T, k, -1)
+        return (rows * weights[..., None].astype(rows.dtype)).sum(axis=1)
+
+
+def moe_ffn_sorted(params, x, *, top_k: int, mesh=None, data_axis: str = "data",
+                   router_x=None):
+    """Dropless top-k MoE with unsharded gated experts (module docstring).
+
+    ``x``: [B, S, d]. ``params``: ``router`` [d, E] and the three expert
+    tensors. Returns ``(out [B, S, d], route)`` with ``route`` the router's
+    verdict: ``probs`` [B*S, E] f32 (for the balancing loss), ``indices``
+    [B, S, k] (the experts chosen) and ``counts`` [E] (rows an expert
+    received). Router, softmax and top-k run in float32; the weights
+    are the probabilities as they are (:func:`top_k_as_is`); ``router_x``
+    is what the router reads where that is not ``x`` (the same activations
+    before their rounding to the compute dtype). On a mesh
+    whose ``data_axis`` is populated every data rank sorts its own tokens
+    (``shard_map`` over the batch dim); nothing crosses ranks."""
+    B, S, d = x.shape
+    E = params["router"].shape[-1]
+    with jax.named_scope("moe_route"):
+        routed = x if router_x is None else router_x
+        probs = gating_probs(routed.reshape(B * S, d), params["router"])
+        weights, indices = top_k_as_is(probs, top_k)
+        counts = expert_counts(indices, E)
+    experts = {name: params[name] for name in ("w_gate", "w_up", "w_down")}
+
+    def body(experts, x, weights, indices):
+        b = x.shape[0]
+        out = sorted_experts(
+            experts, x.reshape(b * S, d), weights.reshape(b * S, top_k),
+            indices.reshape(b * S, top_k),
+        )
+        return out.reshape(b, S, d)
+
+    weights = weights.reshape(B, S, top_k)
+    indices = indices.reshape(B, S, top_k)
+    shards = int(dict(mesh.shape).get(data_axis, 1)) if mesh is not None else 1
+    if shards > 1 and B % shards == 0:
+        body = jax.shard_map(
+            body, mesh=mesh,
+            in_specs=(P(), P(data_axis), P(data_axis), P(data_axis)),
+            out_specs=P(data_axis), check_vma=False,
+        )
+    route = {"probs": probs, "indices": indices, "counts": counts}
+    return body(experts, x, weights, indices), route
+
+
+def router_z_loss(x, router_w):
+    """``mean(logsumexp(x @ router)^2)`` in float32 (ST-MoE, arXiv:2202.08906
+    eq. 5): keeps the router's logits small."""
+    logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
+    return jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))

@@ -131,8 +131,10 @@ def interpret_mode() -> bool:
 @contextlib.contextmanager
 def single_device_program():
     """Declare that the code traced inside compiles for ONE device (a
-    serving engine pinned to its chip), whatever ``jax.device_count()``
-    says — the kernels without a shard_map of their own may engage."""
+    serving engine pinned to its chip, or the body of a caller's own
+    ``shard_map``: ``flash_attention`` per data rank), whatever
+    ``jax.device_count()`` says — the kernels without a shard_map of their
+    own may engage."""
     prev = getattr(_program, "single", False)
     _program.single = True
     try:

@@ -44,6 +44,7 @@ import optax
 
 from distribuuuu_tpu.config import cfg
 from distribuuuu_tpu.models.layers import head_dtype
+from distribuuuu_tpu.ops import token_head
 from distribuuuu_tpu.parallel import sharding as sharding_lib, tp, zero
 from distribuuuu_tpu.parallel.partition import specs as specs_lib
 from distribuuuu_tpu.resilience import supervisor
@@ -309,7 +310,15 @@ def train_step_body(model, optimizer, topk: int, accum_steps: int = 1,
     # values into ``intermediates``); captured at step-build time. Zero
     # overhead for dense archs: the collection stays empty.
     moe_aux_weight = float(cfg.MODEL.MOE.AUX_WEIGHT)
+    moe_z_weight = float(cfg.MODEL.MOE.Z_WEIGHT)
     prep_images = make_image_prep()
+    # A token model that leaves its head to the step (models/olmoe.py
+    # ``head_kernel``, ``head_chunk``): head, loss and hits ``head_chunk``
+    # positions at a time (ops/token_head.py), never the [B, S, V] logits.
+    # Image models and the gpt_* archs have no such hook and keep the
+    # program they had.
+    head_kernel = getattr(model, "head_kernel", None)
+    loss_chunk = getattr(model, "head_chunk", 0)
     # FAULTS.NAN_STEP (utils/faults.py): trace-time gate — None (the
     # common case) compiles nothing in; an int multiplies the loss by
     # where(step==k, NaN, 1), poisoning loss AND grads at exactly step k.
@@ -326,13 +335,31 @@ def train_step_body(model, optimizer, topk: int, accum_steps: int = 1,
                 {"params": params, "batch_stats": stats},
                 images,
                 train=True,
-                mutable=["batch_stats", "intermediates", "moe_stats"],
+                mutable=["batch_stats", "intermediates", "moe_stats",
+                         "moe_z", "moe_load"],
                 rngs={"dropout": key},
+                **({} if head_kernel is None else {"hidden_only": True}),
             )
-        loss = cross_entropy(logits, labels)
+            if head_kernel is not None:
+                # the hits take the logits' place on the way to step_metrics
+                with jax.named_scope("lm_head"):
+                    loss, logits = token_head.loss_and_accuracy(
+                        logits, head_kernel(params), labels,
+                        topk=(1, topk), chunk=loss_chunk,
+                    )
+        if head_kernel is None:
+            loss = cross_entropy(logits, labels)
+        extra = {} if head_kernel is None else {"ce": loss}
         aux = jax.tree.leaves(mutated.get("intermediates", {}))
         if aux and moe_aux_weight:
             loss = loss + moe_aux_weight * sum(aux) / len(aux)
+        z = jax.tree.leaves(mutated.get("moe_z", {}))
+        if z:
+            extra["moe_aux"], extra["moe_z"] = sum(aux) / len(aux), sum(z) / len(z)
+            loss = loss + moe_z_weight * extra["moe_z"]
+        load = jax.tree.leaves(mutated.get("moe_load", {}))
+        if load:
+            extra["moe_load_max_over_mean"] = jnp.stack(load).max()
         if nan_step is not None:
             loss = loss * jnp.where(
                 step == nan_step, jnp.float32(jnp.nan), jnp.float32(1.0)
@@ -341,17 +368,18 @@ def train_step_body(model, optimizer, topk: int, accum_steps: int = 1,
         # fractions (models/vit.MoeMlp sows the sum; empty for dense and
         # partial-MoE models — zero overhead there)
         dstats = jax.tree.leaves(mutated.get("moe_stats", {}))
-        dropped = sum(dstats) / len(dstats) if dstats else None
-        return loss, (logits, mutated.get("batch_stats", {}), dropped)
+        if dstats:
+            extra["moe_dropped"] = sum(dstats) / len(dstats)
+        return loss, (logits, mutated.get("batch_stats", {}), extra)
 
     grad_fn = value_and_grad_scoped(loss_fn)
 
-    def step_metrics(loss, logits, labels, dropped):
-        acc1, acck = accuracy(logits, labels, topk=(1, topk))
-        metrics = {"loss": loss, "top1": acc1, "topk": acck}
-        if dropped is not None:
-            metrics["moe_dropped"] = dropped
-        return metrics
+    def step_metrics(loss, logits, labels, extra):
+        if head_kernel is None:
+            acc1, acck = accuracy(logits, labels, topk=(1, topk))
+        else:
+            acc1, acck = logits  # loss_fn took the hits with the head
+        return {"loss": loss, "top1": acc1, "topk": acck, **extra}
 
     def train_step(state: TrainState, batch):
         step_key = jax.random.fold_in(state.key, state.step)
@@ -360,13 +388,13 @@ def train_step_body(model, optimizer, topk: int, accum_steps: int = 1,
         # consume the one gathered value, and the explicit grads
         # constraint in apply_grads stays the lone reduce-scatter
         params = gather_entry(state.params)
-        (loss, (logits, new_stats, dropped)), grads = grad_fn(
+        (loss, (logits, new_stats, extra)), grads = grad_fn(
             params, state.batch_stats, batch["image"], batch["label"],
             step_key, state.step,
         )
         return apply_grads(
             state, grads, new_stats,
-            step_metrics(loss, logits, batch["label"], dropped),
+            step_metrics(loss, logits, batch["label"], extra),
         )
 
     def accum_train_step(state: TrainState, micro):
@@ -386,13 +414,13 @@ def train_step_body(model, optimizer, topk: int, accum_steps: int = 1,
         def body(carry, mb):
             stats, gsum, i = carry
             mkey = jax.random.fold_in(step_key, i)
-            (loss, (logits, new_stats, dropped)), grads = grad_fn(
+            (loss, (logits, new_stats, extra)), grads = grad_fn(
                 gathered_params, stats, mb["image"], mb["label"], mkey,
                 state.step,
             )
             gsum = jax.tree.map(jnp.add, gsum, grads)
             return (new_stats, gsum, i + 1), step_metrics(
-                loss, logits, mb["label"], dropped
+                loss, logits, mb["label"], extra
             )
 
         zeros = jax.tree.map(jnp.zeros_like, state.params)
@@ -459,6 +487,9 @@ def make_eval_step(model, topk: int, layout=None):
         make_gather_entry(layout)[0] if layout is not None else (lambda p: p)
     )
 
+    head_kernel = getattr(model, "head_kernel", None)
+    loss_chunk = getattr(model, "head_chunk", 0)
+
     def eval_step(state: TrainState, batch):
         params = gather_entry(state.params)
         with jax.named_scope("eval_fwd"):
@@ -466,9 +497,22 @@ def make_eval_step(model, topk: int, layout=None):
                 {"params": params, "batch_stats": state.batch_stats},
                 prep_images(batch["image"]),
                 train=False,
+                **({} if head_kernel is None else {"hidden_only": True}),
             )
         mask = batch["mask"]
         labels = batch["label"]
+        if head_kernel is not None:
+            # the head in chunks, as in the train step (ops/token_head.py)
+            nll, rank = token_head.head_stats(
+                logits, head_kernel(params), labels, chunk=loss_chunk
+            )
+            mask = jnp.broadcast_to(mask[:, None], labels.shape)
+            return {
+                "loss_sum": (nll * mask).sum(),
+                "correct1": ((rank < 1) * mask).sum(),
+                "correctk": ((rank < topk) * mask).sum(),
+                "count": mask.sum(),
+            }
         if logits.ndim == 3:
             # per-token logits (the LM's [B, S, V]): every token of a
             # masked-in sequence is one example — flatten the token dim

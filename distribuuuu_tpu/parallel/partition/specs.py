@@ -221,6 +221,14 @@ TOKEN_BATCH_TABLE = SpecTable(
 )
 
 
+def is_token_arch(arch: str) -> bool:
+    """Archs that declare ``[B, S]`` token-id batches (models/traits.py):
+    no image transforms, TOKEN_BATCH_TABLE."""
+    from distribuuuu_tpu import models
+
+    return models.traits(arch).token_batch
+
+
 def batch_table_for(model=None, arch: str | None = None) -> SpecTable:
     """The batch spec table for a model (or a config arch name): token
     models declare their own via a ``batch_spec_table`` hook (models/gpt.py
@@ -232,7 +240,7 @@ def batch_table_for(model=None, arch: str | None = None) -> SpecTable:
         if fn is not None:
             return fn()
         return BATCH_TABLE
-    if arch is not None and arch.startswith("gpt"):
+    if arch is not None and is_token_arch(arch):
         return TOKEN_BATCH_TABLE
     return BATCH_TABLE
 
@@ -273,7 +281,8 @@ def lm_spec_table(moe_axis: str = "model") -> SpecTable:
       * ``pos_embed`` ``[1, S, D]`` — replicated (tiny, read every step);
       * ``head/kernel`` ``[D, V]`` — column-parallel over ``model``:
         vocab-parallel logits, the transpose-consistent layout to the
-        embedding.
+        embedding (``head`` itself for the olmoe_* archs, whose head has
+        no bias and no wrapper module).
 
     The attention/MLP kernel rules RESTATE what the shared modules already
     annotate (``tp.column_init``) — ``state_layout`` cross-checks rule
@@ -295,6 +304,16 @@ def lm_spec_table(moe_axis: str = "model") -> SpecTable:
             SpecRule(r"Mlp_0/Dense_\d+/Dense_0/kernel$", P(None, "model")),
             SpecRule(r"MoeMlp_0/(w_in|w_out)$", P(moe_axis)),
             SpecRule(r"MoeMlp_0/(b_in|b_out)$", P(moe_axis)),
+            # the olmoe_* family (models/olmoe.py): bias-free projections,
+            # column-parallel in and row-parallel out; the three gated
+            # expert tensors by the rule w_in/w_out have; the untied head
+            # vocab-parallel like gpt's; norm scales and router replicated
+            SpecRule(r"attn/[qkv]_proj/kernel$", P(None, "model")),
+            SpecRule(r"attn/o_proj/kernel$", P("model")),
+            SpecRule(r"moe/(w_gate|w_up|w_down)$", P(moe_axis)),
+            SpecRule(r"moe/router$", P()),
+            SpecRule(r"(_norm|/[qk]_norm)/scale$", P()),
+            SpecRule(r"^head$", P(None, "model")),
         ),
         default=None,  # unmatched leaves keep their annotation/replication
         strict=False,

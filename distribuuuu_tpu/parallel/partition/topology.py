@@ -262,6 +262,25 @@ def _rule_expert_seq(t, arch, moe):
     return None
 
 
+def _rule_declared_axes(t, arch, moe):
+    from distribuuuu_tpu import models
+
+    axes = models.traits(arch).mesh_axes
+    if axes is None:
+        return None
+    sizes = {"data": t.data, "model": t.model, "seq": t.seq, "pipe": t.pipe,
+             "expert": t.expert}
+    extra = {name: n for name, n in sizes.items() if n > 1 and name not in axes}
+    if extra:
+        return (
+            f"{arch!r} declares (models/traits.py) that it lowers on "
+            + "/".join(f"MESH.{a.upper()}=n" for a in axes)
+            + " meshes only, got "
+            + " ".join(f"{name}={n}" for name, n in extra.items())
+        )
+    return None
+
+
 # NOTE what is deliberately ABSENT here: the old trainer refusal of
 # MESH.ZERO=3 with MESH.PIPE>1. Under the partition layer FSDP params are
 # a rest LAYOUT — GSPMD derives the gather at the stage shard_map
@@ -270,6 +289,7 @@ def _rule_expert_seq(t, arch, moe):
 # by the dryrun sweep and tests/test_partition_lowering.py.
 RULES: tuple[Rule, ...] = (
     Rule("zero_stage", _rule_zero_stage),
+    Rule("declared_axes", _rule_declared_axes),
     Rule("pipe_arch", _rule_pipe_arch),
     Rule("pipe_depth", _rule_pipe_depth),
     Rule("pipe_moe_every", _rule_pipe_moe_every),

@@ -41,7 +41,7 @@ def _batch_leaf_specs(tree, batch_dim: int):
 
     blanket = P(*([None] * batch_dim + ["data"]))
     flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
-    if not str(cfg.MODEL.ARCH).startswith("gpt"):
+    if not specs_lib.is_token_arch(cfg.MODEL.ARCH):
         return jax.tree.unflatten(treedef, [blanket] * len(flat))
     table = specs_lib.batch_table_for(arch=str(cfg.MODEL.ARCH))
     out = []

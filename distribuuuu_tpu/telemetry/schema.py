@@ -238,6 +238,36 @@ ANNOTATIONS: dict[str, str] = {
 }
 
 
+# -- device scopes (jax.named_scope) and kernel names -----------------------
+# What a device trace can be split by: the named scopes the step program is
+# traced under (HLO ``op_name`` metadata; the backward carries the forward's
+# scopes under ``bwd``) and the ``name=`` of the Pallas calls, each with the
+# layer of PERF.md's table whose metrics read it. XLA:TPU's own kernels
+# (``ragged-dot-*``, the grouped expert matmuls) drop their scope and are
+# found by name.
+DEVICE_SCOPES: dict[str, str] = {
+    # parallel/partition/lowering.py
+    "fwd": "models",
+    "bwd": "models",
+    "lm_head": "models",
+    "optimizer_update": "kernels",
+    "eval_fwd": "models",
+    # models/olmoe.py, ops/moe.py
+    "attn": "models",
+    "moe": "models",
+    "moe_route": "models",
+    "moe_experts": "kernels",
+    # ops/pallas/opt_update.py
+    "opt_tile": "kernels",
+    "opt_kernel": "kernels",
+}
+KERNEL_NAMES: tuple[str, ...] = (
+    "dtpu_opt_update_sgd", "dtpu_opt_update_sgd_plain", "dtpu_opt_update_adamw",
+    "dtpu_conv_epilogue", "dtpu_decode_attn",
+    "dtpu_flash_fwd", "dtpu_flash_dq", "dtpu_flash_dkdv",
+)
+
+
 class SchemaError(ValueError):
     """A record (or call site) violates the declared kind schema."""
 

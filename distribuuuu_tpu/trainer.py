@@ -127,10 +127,13 @@ def build_model_from_cfg(topology=None):
         num_classes=cfg.MODEL.NUM_CLASSES,
         dtype=resolve_dtype(cfg.DEVICE.COMPUTE_DTYPE),
     )
-    if not cfg.MODEL.ARCH.startswith(("vit", "gpt")):
+    arch_traits = models.traits(cfg.MODEL.ARCH)
+    if arch_traits.batch_norm:
         # every CNN arch in the zoo normalizes with BN (the transformer
-        # families — ViT, GPT — are LayerNorm-only)
+        # families declare that they do not: models/traits.py)
         kwargs["bn_group"] = bn_group_from_cfg()
+    if arch_traits.kwargs_from_cfg is not None:
+        kwargs.update(arch_traits.kwargs_from_cfg(cfg, topology))
     if cfg.MODEL.ARCH.startswith(
         ("resnet", "resnext", "wide_resnet", "botnet", "densenet")
     ):
@@ -1118,8 +1121,8 @@ def check_batch_geometry(mesh, eval_only: bool = False):
                     "2×PIPE); adjust TRAIN.BATCH_SIZE or MESH.MICROBATCH"
                 )
         bn_g = (
-            0 if cfg.MODEL.ARCH.startswith(("vit", "gpt"))
-            else bn_group_from_cfg()
+            bn_group_from_cfg() if models.traits(cfg.MODEL.ARCH).batch_norm
+            else 0
         )
         if bn_g > 0 and global_micro > bn_g and global_micro % bn_g:
             # _BNCore would raise the same condition at first train-step trace

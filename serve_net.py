@@ -68,6 +68,11 @@ def main(argv=None):
     config.merge_from_file(args.cfg_file)
     cfg.merge_from_list(args.opts)
     cfg.freeze()
+    from distribuuuu_tpu import models  # an import; no backend is touched
+
+    refusal = models.traits(cfg.MODEL.ARCH).serve_refusal
+    if refusal:
+        raise SystemExit(f"serve_net: {cfg.MODEL.ARCH!r} {refusal}")
 
     if args.fleet:
         return run_fleet(args.fleet)

@@ -165,11 +165,18 @@ def test_conv_epilogue_lowers(m, cin, cout):
     )
 
 
-def test_flash_fwd_bwd_lowers():
-    qkv = jax.ShapeDtypeStruct((2, 4, 1024, 64), jnp.bfloat16)
+@pytest.mark.parametrize("data", [1, 8])
+def test_flash_fwd_bwd_lowers(data):
+    """Bare, and per data rank under the caller's mesh (a bare Mosaic call
+    in a program over several devices is what GSPMD refuses)."""
+    from distribuuuu_tpu.parallel import mesh as mesh_lib
+
+    qkv = jax.ShapeDtypeStruct((8, 4, 1024, 64), jnp.bfloat16)
+    mesh = mesh_lib.build_mesh(data=data) if data > 1 else None
 
     def loss(q, k, v):
         return fa.flash_attention(
-            q, k, v, causal=True, interpret=False).astype(jnp.float32).sum()
+            q, k, v, causal=True, interpret=False, mesh=mesh
+        ).astype(jnp.float32).sum()
 
     _lowers_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)

@@ -116,6 +116,46 @@ def _entry(label, phase, cost, memory, *, images, arch, peaks, n_devices,
     return entry
 
 
+def _abstract_arch(arch, yaml_path, model, optimizer, mesh, train_step,
+                   eval_step, *, batch, with_memory, token_arch) -> dict:
+    """``build_arch`` without a state or a batch: ``Lowered.abstract_args``'
+    shapes, lowered and compiled, never run (``--time-steps 0``)."""
+    import jax
+
+    from distribuuuu_tpu import trainer
+    from distribuuuu_tpu.config import cfg
+    from distribuuuu_tpu.parallel.partition import lowering
+    from distribuuuu_tpu.telemetry import costmodel
+
+    im = cfg.TRAIN.IM_SIZE
+    low = lowering.Lowered(
+        mesh=mesh, topology=None, layout=trainer._state_layout(model, mesh, im),
+        step_layout=None, train_step=train_step, eval_step=eval_step,
+        model=model, optimizer=optimizer, im_size=im,
+    )
+    state, batch_tree = low.abstract_args(batch, with_mask=True)
+    if token_arch:  # abstract_args sizes tokens by the model's init dummy
+        shape = (batch, int(cfg.LM.SEQ_LEN))
+        batch_tree = {
+            k: v if k == "mask" else jax.ShapeDtypeStruct(
+                shape, v.dtype, sharding=v.sharding)
+            for k, v in batch_tree.items()
+        }
+    entries = {}
+    for phase, fn in (("eval", eval_step), ("train", train_step)):
+        cost, memory, _ = _analyze(
+            fn, (state, batch_tree), with_memory=with_memory, time_steps=0,
+            donated_state=False,
+        )
+        entries[phase] = _entry(
+            f"{phase}_step", phase, cost, memory, images=batch, arch=arch,
+            peaks=costmodel.peaks_for(), n_devices=len(jax.devices()),
+            mean_step_s=None,
+        )
+    return {"yaml": os.path.relpath(yaml_path), "im_size": im, "batch": batch,
+            "abstract": True, **entries}
+
+
 def build_arch(arch: str, yaml_path: str, *, batch: int, with_memory: bool,
                time_steps: int) -> dict:
     import jax
@@ -125,6 +165,7 @@ def build_arch(arch: str, yaml_path: str, *, batch: int, with_memory: bool,
     from distribuuuu_tpu import trainer
     from distribuuuu_tpu.config import cfg
     from distribuuuu_tpu.parallel import mesh as mesh_lib, sharding as sharding_lib
+    from distribuuuu_tpu.parallel.partition import specs as partition_specs
     from distribuuuu_tpu.telemetry import costmodel
     from distribuuuu_tpu.utils.optim import construct_optimizer
 
@@ -140,17 +181,26 @@ def build_arch(arch: str, yaml_path: str, *, batch: int, with_memory: bool,
     mesh = mesh_lib.build_mesh()
     model = trainer.build_model_from_cfg()
     layout = trainer._state_layout(model, mesh, im)
-    state = trainer.create_train_state(model, jax.random.key(0), mesh, im,
-                                       layout=layout)
     optimizer = construct_optimizer()
     step_layout = layout if cfg.MESH.ZERO else None
     train_step = trainer.make_train_step(
         model, optimizer, topk=trainer.effective_topk(), layout=step_layout
     )
     eval_step = trainer.make_eval_step(model, trainer.effective_topk())
+    token_arch = partition_specs.is_token_arch(arch)
+    if time_steps == 0:
+        # nothing runs, so nothing is materialized: the state and the batch
+        # are shapes with their declared shardings. The only way to ledger an
+        # arch whose state no host holds (olmoe_1b_7b: 110 GB with AdamW)
+        return _abstract_arch(
+            arch, yaml_path, model, optimizer, mesh, train_step, eval_step,
+            batch=batch, with_memory=with_memory, token_arch=token_arch,
+        )
+    state = trainer.create_train_state(model, jax.random.key(0), mesh, im,
+                                       layout=layout)
 
     rng = np.random.default_rng(0)
-    if arch.startswith("gpt"):
+    if token_arch:
         # the LM species eats token batches (ISSUE 12); "images" counts
         # sequences — the lm bench converts to tokens/s with the seq len
         S = int(cfg.LM.SEQ_LEN)
