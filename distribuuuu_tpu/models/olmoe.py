@@ -235,9 +235,11 @@ class OLMoE(nn.Module):
     attn_impl: str = "auto"
     mesh: Any = None
     moe_axis: str = "model"
-    # positions of every sequence whose head, loss and hits the step takes
-    # at a time (ops/token_head.py): [B, head_chunk, V] float32 logits are
-    # the largest block that ever exists (412 MB at 4 x 512 x 50,304)
+    # positions of every sequence whose head, loss, hits and (in training)
+    # both of the head's gradients the step takes at a time
+    # (ops/token_head.py): [B, head_chunk, V] float32 logits are the largest
+    # block that ever exists (412 MB at 4 x 512 x 50,304), made once a chunk;
+    # between forward and backward the head holds its two gradients only
     head_chunk: int = 512
 
     @nn.compact

@@ -80,8 +80,8 @@ def program_terms(model, params, tokens, labels, chunk=CHUNK):
         {"params": params}, tokens, train=True, hidden_only=True,
         mutable=["intermediates", "moe_z", "moe_stats", "moe_load", "moe_route"],
     )
-    nll, _ = token_head.head_stats(
-        hidden, model.head_kernel(params), labels, chunk=chunk
+    ce, _ = token_head.loss_and_accuracy(
+        hidden, model.head_kernel(params), labels, topk=(1,), chunk=chunk
     )
 
     def mean(name):
@@ -89,7 +89,7 @@ def program_terms(model, params, tokens, labels, chunk=CHUNK):
         return sum(leaves) / len(leaves)
 
     return {
-        "ce": nll.mean(), "load_balance": mean("intermediates"),
+        "ce": ce, "load_balance": mean("intermediates"),
         "router_z": mean("moe_z"), "dropped": mean("moe_stats"),
         "experts": jnp.stack(jax.tree.leaves(sown["moe_route"])),
     }
@@ -212,17 +212,24 @@ def test_loss_terms_and_every_gradient_equal_the_reference(size):
         assert float(jnp.linalg.norm(g - w)) <= 1e-4 * norm, jax.tree_util.keystr(path)
 
 
+def _head_inputs(dtype=jnp.float32):
+    """100 tokens a sequence: chunks of 48 leave a ragged last chunk of 4."""
+    k = jax.random.split(jax.random.key(3), 4)
+    hidden = jax.random.normal(k[0], (3, 100, 64)).astype(dtype)
+    kernel = jax.random.normal(k[1], (64, VOCAB)) * 0.3
+    labels = jax.random.randint(k[2], (3, 100), 0, VOCAB)
+    return hidden, kernel, labels, k[3]
+
+
 def test_chunked_head_equals_the_whole_head():
     """Loss, hits and gradients of head and hidden state: chunks of 48 of a
     100-token sequence (padded to 144) against one block."""
-    k = jax.random.split(jax.random.key(3), 3)
-    hidden = jax.random.normal(k[0], (3, 100, 64))
-    kernel = jax.random.normal(k[1], (64, VOCAB)) * 0.3
-    labels = jax.random.randint(k[2], (3, 100), 0, VOCAB)
+    hidden, kernel, labels, _ = _head_inputs()
+    mean = jnp.full(labels.shape, 1.0 / labels.size)
 
     def loss(hidden, kernel, chunk):
-        nll, rank = token_head.head_stats(hidden, kernel, labels, chunk=chunk)
-        return nll.mean(), rank
+        loss, (_, rank) = token_head.weighted_loss(hidden, kernel, labels, mean, chunk=chunk)
+        return loss, rank
 
     (whole, rank_whole), g_whole = jax.value_and_grad(loss, (0, 1), has_aux=True)(hidden, kernel, 0)
     (parts, rank_parts), g_parts = jax.value_and_grad(loss, (0, 1), has_aux=True)(hidden, kernel, CHUNK)
@@ -230,6 +237,10 @@ def test_chunked_head_equals_the_whole_head():
     assert np.array_equal(rank_parts, rank_whole)
     for a, b in zip(g_parts, g_whole):
         np.testing.assert_allclose(a, b, atol=1e-6)
+    # evaluation's walk gives the same statistics
+    nll, rank = token_head.head_stats(hidden, kernel, labels, chunk=CHUNK)
+    np.testing.assert_allclose(nll.mean(), whole, rtol=1e-6)
+    assert np.array_equal(rank, rank_whole)
     # the rank is lax.top_k's order: hits equal utils.metrics.accuracy's
     from distribuuuu_tpu.utils.metrics import accuracy, cross_entropy
 
@@ -238,6 +249,83 @@ def test_chunked_head_equals_the_whole_head():
     assert float((rank_whole < 1).mean() * 100) == pytest.approx(float(acc1))
     assert float((rank_whole < 5).mean() * 100) == pytest.approx(float(acc5))
     np.testing.assert_allclose(whole, cross_entropy(logits, labels), rtol=1e-6)
+
+
+def _vocabulary_wide_matmuls(jaxpr) -> int:
+    """``dot_general``s with the vocabulary among their dimensions."""
+    return sum(
+        eqn.primitive.name == "dot_general" and any(
+            VOCAB in getattr(v.aval, "shape", ())
+            for v in list(eqn.invars) + list(eqn.outvars))
+        for eqn in _walk(jaxpr)
+    )
+
+
+@pytest.mark.parametrize("chunk,chunks", [(CHUNK, 3), (0, 1)])
+def test_each_chunks_logits_are_computed_once(chunk, chunks):
+    """From the traced programs: the differentiated head has three
+    vocabulary-wide matmuls a chunk (logits, dX, dW; a recomputing backward
+    has four) and the evaluated head one, on either entry."""
+    hidden, kernel, labels, _ = _head_inputs()
+    mean = jnp.full(labels.shape, 1.0 / labels.size)
+
+    def loss(hidden, kernel):
+        return token_head.weighted_loss(hidden, kernel, labels, mean, chunk=chunk)[0]
+
+    def stats(hidden, kernel):
+        return token_head.head_stats(hidden, kernel, labels, chunk=chunk)
+
+    differentiated = jax.make_jaxpr(jax.value_and_grad(loss, (0, 1)))(hidden, kernel)
+    assert _vocabulary_wide_matmuls(differentiated.jaxpr) == 3 * chunks
+    for evaluated in (loss, stats):
+        assert _vocabulary_wide_matmuls(
+            jax.make_jaxpr(evaluated)(hidden, kernel).jaxpr) == chunks
+
+
+def _weights(kind, key, shape):
+    if kind == "mask":  # a 0/1 mask over the count it keeps
+        mask = jax.random.bernoulli(key, 0.7, shape).astype(jnp.float32)
+        return mask / mask.sum()
+    if kind == "arbitrary":  # either sign, as a per-token cotangent may be
+        return jax.random.normal(key, shape)
+    return jnp.full(shape, 1.0 / (shape[0] * shape[1]))
+
+
+@pytest.mark.parametrize("dtype,tolerance", [("float32", 2e-6), ("bfloat16", 8e-3)])
+@pytest.mark.parametrize("kind,cotangent", [
+    ("uniform", 1.0), ("mask", 1.0), ("arbitrary", 1.0), ("uniform", 3.0),
+])
+def test_weighted_loss_equals_autodiff_on_the_full_logits(kind, cotangent, dtype, tolerance):
+    """Value and both gradients against plain autodiff through
+    ``utils.metrics.cross_entropy`` on the full logits, with a ragged last
+    chunk. bfloat16's tolerance is the rounding of the logits' cotangent to
+    8 bits, relative to the gradient's largest entry."""
+    from distribuuuu_tpu.utils.metrics import cross_entropy
+
+    hidden, kernel, labels, key = _head_inputs(jnp.dtype(dtype))
+    weights = _weights(kind, key, labels.shape)
+
+    def program(hidden, kernel):
+        loss, (nll, _) = token_head.weighted_loss(
+            hidden, kernel, labels, weights, chunk=CHUNK)
+        # nll is a statistic: adding it moves the value, not the gradient
+        return cotangent * loss + nll.sum(), nll.sum()
+
+    def plain(hidden, kernel):
+        logits = jnp.einsum("bsd,dv->bsv", hidden, kernel.astype(hidden.dtype),
+                            preferred_element_type=jnp.float32)
+        nll = jax.vmap(jax.vmap(
+            lambda row, label: cross_entropy(row[None], label[None])))(logits, labels)
+        return cotangent * (nll * weights).sum()
+
+    (got, added), got_grads = jax.value_and_grad(program, (0, 1), has_aux=True)(hidden, kernel)
+    want, want_grads = jax.value_and_grad(plain, (0, 1))(hidden, kernel)
+    # (the subtraction rounds at float32's step at the sum's size)
+    np.testing.assert_allclose(got - added, want, rtol=1e-5, atol=1e-6 * float(added))
+    for g, w in zip(got_grads, want_grads):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.abs(g - w).max() <= tolerance * np.abs(w).max()
 
 
 def test_rank_breaks_ties_as_top_k_does():
@@ -278,6 +366,12 @@ def test_the_step_chunked_equals_the_step_unchunked_and_the_reference():
         state = low.init_state(jax.random.key(0), 32)
         params = jax.device_get(state.params)
         batch = low.put_batch(host)
+        # the step holds three vocabulary-wide matmuls a chunk, evaluation one
+        chunks = -(-100 // chunk) if chunk else 1
+        assert _vocabulary_wide_matmuls(jax.make_jaxpr(low.train_step)(
+            state, {k: batch[k] for k in ("image", "label")}).jaxpr) == 3 * chunks
+        assert _vocabulary_wide_matmuls(
+            jax.make_jaxpr(low.eval_step)(state, batch).jaxpr) == chunks
         evaluated = jax.device_get(low.eval_step(state, batch))
         state, metrics = low.train_step(state, {k: batch[k] for k in ("image", "label")})
         out[chunk] = jax.device_get((metrics, state.params, evaluated))
