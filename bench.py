@@ -16,7 +16,6 @@ that torch DDP ResNet-50 fp32 achieves on the reference's A100-class hardware
 from __future__ import annotations
 
 import json
-import os
 import sys
 import time
 
@@ -46,15 +45,12 @@ PEAK_BF16 = {
 }
 
 
-def build_workload(fold: int = 4, per_chip_batch: int = 128):
+def build_workload(per_chip_batch: int = 128):
     """Build the bench's compiled+warmed train step.
 
-    Returns ``(window, meta)`` — ``window(iters)`` runs ``iters`` calls
-    (``fold`` optimizer steps each) and returns elapsed seconds, fenced
-    with ``block_until_ready`` on the updated state; ``meta`` has batch
-    geometry. Factored out so
-    ``tools/ab_bench.py`` can build the SAME workload under two different
-    trace-time environments and interleave paired timing windows.
+    Returns ``(window, meta)`` — ``window(iters)`` runs ``iters`` optimizer
+    steps and returns elapsed seconds, fenced with ``block_until_ready``
+    on the updated state; ``meta`` has batch geometry.
     """
     import jax
     import numpy as np
@@ -69,14 +65,7 @@ def build_workload(fold: int = 4, per_chip_batch: int = 128):
 
     config.reset_cfg()
     compile_cache.setup_from_cfg(cfg)
-    # DISTRIBUUUU_BENCH_ARCH: run the same harness on another zoo arch
-    # (ab_bench env plumbing reaches this at build time) — e.g. the
-    # regnety_160 grouped-conv A/Bs (PERF.md r5).
-    cfg.MODEL.ARCH = os.environ.get("DISTRIBUUUU_BENCH_ARCH", "resnet50")
-    # DISTRIBUUUU_REMAT=1: TRAIN.REMAT (stage 1-2 rematerialization) for
-    # the remat-for-traffic A/B — `tools/ab_bench.py --preset remat`.
-    if os.environ.get("DISTRIBUUUU_REMAT", "") not in ("", "0"):
-        cfg.TRAIN.REMAT = True
+    cfg.MODEL.ARCH = "resnet50"
     cfg.MODEL.NUM_CLASSES = 1000
     n_chips = len(jax.devices())
     batch = per_chip_batch * n_chips
@@ -88,44 +77,19 @@ def build_workload(fold: int = 4, per_chip_batch: int = 128):
     model = trainer.build_model_from_cfg(topo)
     lowered = partition_lowering.lower(
         model, construct_optimizer(), 5, mesh=mesh, topology=topo,
-        im_size=224, fold=fold,
+        im_size=224,
     )
     state = trainer.create_train_state(
         model, jax.random.key(0), mesh, 224, layout=lowered.layout
     )
-    train_step = lowered.scan_step if fold > 1 else lowered.train_step
-
-    # DISTRIBUUUU_XLA_OPTS="k=v;k=v": per-variant XLA compiler options for
-    # the flag-sweep experiments (tools/xla_flag_sweep.py). An outer jit
-    # re-wrap — the inner jit inlines during tracing, so the options govern
-    # the whole step compilation.
-    xla_opts = os.environ.get("DISTRIBUUUU_XLA_OPTS", "")
-    if xla_opts:
-        copts = {}
-        for p in xla_opts.split(";"):
-            if not p:
-                continue
-            if "=" not in p:
-                # a silently-dropped flag would make a sweep report ~1.00×
-                # for an option that was never applied
-                raise ValueError(
-                    f"DISTRIBUUUU_XLA_OPTS entry {p!r} is not k=v"
-                )
-            k, v = p.split("=", 1)
-            copts[k] = v
-        train_step = jax.jit(
-            train_step, donate_argnums=0, compiler_options=copts
-        )
+    train_step = lowered.train_step
 
     rng = np.random.default_rng(0)
-    host_batch = {
-        "image": rng.standard_normal(
-            (fold, batch, 224, 224, 3)
-        ).astype(np.float32),
-        "label": rng.integers(0, 1000, size=(fold, batch)).astype(np.int32),
-        "mask": np.ones((fold, batch), np.float32),
-    }
-    gbatch = sharding_lib.shard_stacked_batch(mesh, host_batch)
+    gbatch = sharding_lib.shard_batch(mesh, {
+        "image": rng.standard_normal((batch, 224, 224, 3)).astype(np.float32),
+        "label": rng.integers(0, 1000, size=(batch,)).astype(np.int32),
+        "mask": np.ones((batch,), np.float32),
+    })
 
     box = {"state": state}
 
@@ -141,16 +105,12 @@ def build_workload(fold: int = 4, per_chip_batch: int = 128):
 
     # XLA cost-model ledger of this workload (lowering only re-traces —
     # no extra compile): the measured flops the mfu field is sourced
-    # from, extracted BEFORE the warmup donates the state buffers. The
-    # probe is a PER-STEP program, not the folded one — XLA cost
-    # analysis counts a lax.scan body once regardless of trip count, so
-    # the folded program cannot source per-step flops
-    # (telemetry/costmodel.py has the same rule). ``cost`` is per step
-    # of ``batch`` images; None when the backend omits cost keys —
-    # main() falls back to the hand table, flagged analytic.
-    single = jax.tree.map(lambda x: x[0], gbatch)  # one (batch,...) step
+    # from, extracted BEFORE the warmup donates the state buffers.
+    # ``cost`` is per step of ``batch`` images; None when the backend
+    # omits cost keys — main() falls back to the hand table, flagged
+    # analytic.
     cost = costmodel.normalize_cost(
-        lowered.train_step.lower(box["state"], single).cost_analysis()
+        train_step.lower(box["state"], gbatch).cost_analysis()
     )
 
     # compile + warmup
@@ -160,7 +120,6 @@ def build_workload(fold: int = 4, per_chip_batch: int = 128):
     meta = {
         "n_chips": n_chips,
         "batch": batch,
-        "fold": fold,
         "per_chip_batch": per_chip_batch,
         "device_kind": jax.devices()[0].device_kind,
         "cost": cost,  # ONE optimizer step of `batch` images (see above)
@@ -188,7 +147,7 @@ def build_eval_workload(per_chip_batch: int = 128):
 
     config.reset_cfg()
     compile_cache.setup_from_cfg(cfg)
-    cfg.MODEL.ARCH = os.environ.get("DISTRIBUUUU_BENCH_ARCH", "resnet50")
+    cfg.MODEL.ARCH = "resnet50"
     cfg.MODEL.NUM_CLASSES = 1000
     n_chips = len(jax.devices())
     batch = per_chip_batch * n_chips
@@ -223,19 +182,15 @@ def build_eval_workload(per_chip_batch: int = 128):
 def main():
     import jax
 
-    # The framework's folded dispatch mode (≙ TRAIN.STEPS_PER_CALL in the
-    # trainer): FOLD optimizer steps per compiled call via lax.scan,
-    # removing the per-step host dispatch from the critical path. Same
-    # train-step math.
-    window, meta = build_workload(fold=4, per_chip_batch=128)
-    n_chips, batch, fold = meta["n_chips"], meta["batch"], meta["fold"]
+    window, meta = build_workload(per_chip_batch=128)
+    n_chips, batch = meta["n_chips"], meta["batch"]
     per_chip_batch = meta["per_chip_batch"]
 
     # timed steady state — best of three windows
-    iters = 10  # calls; fold steps each
+    iters = 40  # optimizer steps
     dt = min(window(iters) for _ in range(3))
 
-    img_per_sec = batch * fold * iters / dt
+    img_per_sec = batch * iters / dt
     img_per_sec_per_chip = img_per_sec / n_chips
     peak = PEAK_BF16.get(jax.devices()[0].device_kind)
     out = {
@@ -248,7 +203,6 @@ def main():
             img_per_sec_per_chip / BASELINE_IMG_PER_SEC_PER_CHIP, 3
         ),
         "baseline": "A100 fp32 DDP ~400 img/s/GPU (reference has no AMP)",
-        "fold": fold,
         "per_chip_batch": per_chip_batch,
     }
     # mfu: measured flops (XLA cost ledger of the very step program the
@@ -261,20 +215,19 @@ def main():
         flops_per_img = cost["flops"] / batch  # cost is per step (meta)
         out["flops_per_img"] = round(flops_per_img, 1)
         out["mfu_source"] = "xla"
-        if os.environ.get("DISTRIBUUUU_BENCH_ARCH", "resnet50") == "resnet50":
-            drift = costmodel.drift_pct(
-                flops_per_img, RESNET50_TRAIN_FLOPS_PER_IMG
+        drift = costmodel.drift_pct(
+            flops_per_img, RESNET50_TRAIN_FLOPS_PER_IMG
+        )
+        out["flops_drift_pct"] = round(drift, 2)
+        if abs(drift) > DRIFT_WARN_PCT:
+            print(
+                f"# WARNING: hand FLOP table drifted {drift:+.1f}% from "
+                f"the XLA cost model ({flops_per_img / 1e9:.2f} vs "
+                f"{RESNET50_TRAIN_FLOPS_PER_IMG / 1e9:.2f} GFLOP/img) — "
+                "update RESNET50_TRAIN_FLOPS_PER_IMG",
+                file=sys.stderr,
             )
-            out["flops_drift_pct"] = round(drift, 2)
-            if abs(drift) > DRIFT_WARN_PCT:
-                print(
-                    f"# WARNING: hand FLOP table drifted {drift:+.1f}% from "
-                    f"the XLA cost model ({flops_per_img / 1e9:.2f} vs "
-                    f"{RESNET50_TRAIN_FLOPS_PER_IMG / 1e9:.2f} GFLOP/img) — "
-                    "update RESNET50_TRAIN_FLOPS_PER_IMG",
-                    file=sys.stderr,
-                )
-    elif os.environ.get("DISTRIBUUUU_BENCH_ARCH", "resnet50") == "resnet50":
+    else:
         # backend omitted cost keys: analytic fallback, flagged
         flops_per_img = RESNET50_TRAIN_FLOPS_PER_IMG
         out["mfu_source"] = "analytic"

@@ -248,29 +248,20 @@ _C.TRAIN.WORKERS = 4
 _C.TRAIN.PIN_MEMORY = True
 _C.TRAIN.PRINT_FREQ = 30
 _C.TRAIN.TOPK = 5
-# Fold this many optimizer steps into ONE compiled call (lax.scan over the
-# step body). >1 removes the per-step host dispatch from the critical path
-# (its cost on this installation is not measured — PERF.md) at the cost of
-# metric/profiler granularity rounding up to the fold size. 1 = the
-# reference's one-dispatch-per-step behavior.
-_C.TRAIN.STEPS_PER_CALL = 1
 # Device-side prefetch ring depth (data/loader.device_prefetch): the H2D
 # transfer of batches k+1..k+PREFETCH_DEVICE is dispatched while the
 # compiled step still works on batch k, so transfers never serialize
-# behind steps. Applies to the per-step dispatch path (STEPS_PER_CALL 1)
-# of train_epoch AND validate; the folded path has its own ping-pong
-# double buffering. 0 = the unoverlapped put-then-step order. Results are
-# bit-identical at every depth (same device_put order, same step order —
-# tests/test_overlap.py); only dispatch timing moves. HBM cost: depth
-# extra device batches resident.
+# behind steps. Applies to train_epoch AND validate. 0 = the unoverlapped
+# put-then-step order. Results are bit-identical at every depth (same
+# device_put order, same step order — tests/test_overlap.py); only dispatch
+# timing moves. HBM cost: depth extra device batches resident.
 _C.TRAIN.PREFETCH_DEVICE = 2
 # Per-batch stage-boundary timeline records (kind="timeline" in
 # {OUT_DIR}/metrics.jsonl — utils/jsonlog.timeline_log): decode/augment,
 # host assembly, H2D dispatch, and step dispatch monotonic timestamps for
-# every batch on the per-step dispatch path (train + eval). Feed them to
-# tools/overlap_report.py for exact wall-time attribution. Primary
-# process only; one small JSON line per batch (folded dispatch emits
-# none — set STEPS_PER_CALL 1 to diagnose an input-bound run).
+# every batch (train + eval). Feed them to tools/overlap_report.py for
+# exact wall-time attribution. Primary process only; one small JSON line
+# per batch.
 _C.TRAIN.TIMELINE = True
 # Rematerialize (jax.checkpoint via nn.remat) ResNet stages 1-2 — the
 # largest-activation stages: their block activations are not stored for
@@ -279,7 +270,7 @@ _C.TRAIN.TIMELINE = True
 # roofline lever, VERDICT r5 #3). Exact same math (step-equivalence:
 # tests/test_remat.py). resnet/resnext/wide_resnet family only (densenet
 # always remats its dense layers; other archs refuse the knob loudly).
-# A/B on hardware: python tools/ab_bench.py --preset remat
+# A/B on hardware: benchmark/run.py --workload … --set (PERF.md §2).
 _C.TRAIN.REMAT = False
 # Split each optimizer step's batch into this many sequential micro-batches,
 # summing gradients in-graph before the (single) update. Runs the

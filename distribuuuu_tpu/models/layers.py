@@ -138,53 +138,6 @@ class UnrolledGroupConv(nn.Module):
         x = x.astype(self.dtype)  # lax.conv requires matching dtypes
         s = self.strides
         strides = s if isinstance(s, (tuple, list)) else (s, s)
-        use_pallas = (
-            (kh, kw) == (3, 3)
-            and strides == (1, 1)  # Mosaic: no stride-2 VMEM slices
-            and list(map(tuple, self.padding)) == [(1, 1), (1, 1)]
-            # small-spatial stages only: ≥28² grids send the Mosaic
-            # compiler into multi-minute/OOM territory, and XLA's own
-            # lowering is least bad there anyway (PERF.md r5)
-            and x.shape[1] <= 14 and x.shape[2] <= 14
-        )
-        mode = os.environ.get("DISTRIBUUUU_GROUP_CONV", "auto")
-        if use_pallas and mode == "pallas":
-            # hand-tiled Pallas kernel (ops/group_conv.py). Measured
-            # 1.3-1.5× XLA's formulations PER OP, but 0.74× end-to-end:
-            # the custom-call boundaries forfeit XLA's epilogue fusion and
-            # prefetch scheduling (trace: +12 ms DMA waits, +15 ms glue on
-            # regnety_160 — PERF.md r5 "Grouped convs"). NOT in `auto`;
-            # the knob remains for kernel work that fuses the full block.
-            from distribuuuu_tpu.ops.group_conv import group_conv3x3
-
-            # interpret mode off-TPU so the forced knob stays testable on
-            # the CPU mesh (slow but exact); compiled Mosaic on the chip
-            interp = jax.devices()[0].platform != "tpu"
-            return group_conv3x3(x, kernel, 1, self.groups, interp)
-        if mode == "blockdiag":
-            # grouped conv as ONE dense conv over a block-diagonal kernel:
-            # zero blocks kill every cross-group term, so the math — and
-            # the canonical param, and its gradient (autodiff drops the
-            # zero blocks' grads) — is exactly the grouped conv's. Trades
-            # G× more MXU FLOPs for one large well-tiled conv instead of
-            # G small ones (A/B experiment, PERF.md r5).
-            blocks = kernel.reshape(kh, kw, cg, self.groups, fg)
-            dense = jnp.zeros(
-                (kh, kw, self.groups, cg, self.groups, fg), self.dtype
-            )
-            idx = jnp.arange(self.groups)
-            # advanced indices at axes 2 and 4 move to the front: the set
-            # payload is [G, kh, kw, cg, fg]
-            dense = dense.at[:, :, idx, :, idx, :].set(
-                jnp.moveaxis(blocks, 3, 0)
-            )
-            dense = dense.reshape(
-                kh, kw, self.groups * cg, self.features
-            )
-            return jax.lax.conv_general_dilated(
-                x, dense, strides, self.padding,
-                dimension_numbers=("NHWC", "HWIO", "NHWC"),
-            )
         outs = [
             jax.lax.conv_general_dilated(
                 x[..., g * cg : (g + 1) * cg],
@@ -264,18 +217,10 @@ class ConvBN(nn.Module):
     s2d_stem: bool = False
 
     def _group_conv_unrolled(self, in_channels: int) -> bool:
-        """Grouped-conv compute path at trace time. ``auto`` (default):
-        unroll when the per-group width is MXU-wide (≥64, the r1 rule —
-        PERF.md "Grouped convs"). ``DISTRIBUUUU_GROUP_CONV`` forces
-        ``unrolled``/``fused`` for paired A/B runs; params and checkpoints
-        are identical either way (same canonical kernel)."""
-        mode = os.environ.get("DISTRIBUUUU_GROUP_CONV", "auto")
-        if mode in ("unrolled", "blockdiag", "pallas"):
-            return True  # blockdiag/pallas are handled inside UnrolledGroupConv
-        if mode == "fused":
-            return False
-        if mode != "auto":
-            raise ValueError(f"DISTRIBUUUU_GROUP_CONV={mode!r}")
+        """Grouped-conv compute path at trace time: unroll when the
+        per-group width is MXU-wide (≥64); narrower groups keep
+        ``nn.Conv``'s ``feature_group_count``. Params and checkpoints are
+        identical either way (same canonical kernel)."""
         return in_channels // self.groups >= 64
 
     @nn.compact
@@ -402,8 +347,8 @@ class _BNCore(nn.Module):
         spatial = 1
         for d in x.shape[1:-1]:
             spatial *= d
-        # One-pass shifted variance (r4, default). The batch stats come from
-        # a SINGLE read of the activations: d = x − m̂ with the shift m̂ a
+        # One-pass shifted variance. The batch stats come from a SINGLE
+        # read of the activations: d = x − m̂ with the shift m̂ a
         # per-channel constant *independent of this batch* (the running
         # mean), then mean = E[d] + m̂ and var = E[d²] − E[d]² — an exact
         # identity for any m̂. Because m̂ does not depend on x, XLA folds
@@ -419,28 +364,11 @@ class _BNCore(nn.Module):
         # uncentered form until the running mean tracks the scale; the
         # clamp keeps var ≥ 0 (finite rsqrt) in that corner. Post-conv
         # activations under fp32 accumulation do not occupy that regime.
-        #
-        # DISTRIBUUUU_BN_VARIANCE selects the formulation at trace time —
-        # "shifted" (default), "centered" (two-pass, torch-exact rounding
-        # in all regimes, costs the extra read), "uncentered" (r2's
-        # E[x²]−E[x]², fastest-equal but cancels at large mean). The env
-        # knob exists for paired A/B benchmarking (tools/ab_bench.py) and
-        # as the documented escape hatch for cold-start large-mean inputs.
-        mode = os.environ.get("DISTRIBUUUU_BN_VARIANCE", "shifted")
-        if mode not in ("shifted", "centered", "uncentered"):
-            raise ValueError(f"DISTRIBUUUU_BN_VARIANCE={mode!r}")
         xf = x.astype(stats_dtype)
 
-        def moments(v, axes, bshape):
-            """(mean, biased var) over ``axes``; bshape re-broadcasts."""
-            if mode == "centered":
-                m = v.mean(axes)
-                var = jnp.square(v - m.reshape(bshape)).mean(axes)
-                return m, var
-            shift = (
-                0.0 if mode == "uncentered"
-                else jax.lax.stop_gradient(ra_mean.value)
-            )
+        def moments(v, axes):
+            """(mean, biased var) over ``axes``."""
+            shift = jax.lax.stop_gradient(ra_mean.value)
             d = v - shift
             s1 = d.mean(axes)  # E[d] — both sums in one pass over v
             s2 = jnp.square(d).mean(axes)  # E[d²]
@@ -459,7 +387,7 @@ class _BNCore(nn.Module):
             xg = xf.reshape((g, gs) + x.shape[1:])
             axes = tuple(range(1, xg.ndim - 1))
             bshape = (g,) + (1,) * (xg.ndim - 2) + (feat,)
-            gmean, gvar = moments(xg, axes, bshape)  # (g, C)
+            gmean, gvar = moments(xg, axes)  # (g, C)
             inv = jax.lax.rsqrt(gvar + self.epsilon).reshape(bshape) * scale
             y = ((xg - gmean.reshape(bshape)) * inv + bias).reshape(x.shape)
             count = gs * spatial
@@ -469,16 +397,16 @@ class _BNCore(nn.Module):
             var_upd = gvar.mean(0) * count / max(count - 1, 1)
         else:
             axes = tuple(range(x.ndim - 1))
-            mean, var = moments(xf, axes, (1,) * (x.ndim - 1) + (feat,))
+            mean, var = moments(xf, axes)
             inv = jax.lax.rsqrt(var + self.epsilon) * scale
             y = (xf - mean) * inv + bias
             count = n * spatial
             mean_upd, var_upd = mean, var * count / max(count - 1, 1)
         if not self.is_initializing():
-            # DISTRIBUUUU_BN_MOMENTUM (trace-time, like _BN_VARIANCE):
-            # overrides EVERY BN layer's running-stats decay — a bench/
-            # experiment knob (the r5 eval-wobble investigation, PERF.md);
-            # unset ⇒ each module's own momentum (torch parity)
+            # DISTRIBUUUU_BN_MOMENTUM (trace-time) overrides EVERY BN
+            # layer's running-stats decay — a bench/experiment knob (the
+            # r5 eval-wobble investigation, PERF.md); unset ⇒ each
+            # module's own momentum (torch parity)
             m = float(os.environ.get("DISTRIBUUUU_BN_MOMENTUM",
                                      self.momentum))
             # cast back to the stored (fp32) dtype: under promoted-f64

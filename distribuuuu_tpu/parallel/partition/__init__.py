@@ -17,9 +17,9 @@ Three layers:
                scattered trainer refusals), enumerates the valid mesh
                space for the dryrun sweep, and feeds elastic-resume
                classification (resilience/manifest.py)
-  lowering.py  the one pjit-style lowering — builds the train/eval/
-               folded step from specs alone for ANY validated topology
-               (the trainer's fold/accum/ZeRO/PP/EP case analysis
+  lowering.py  the one pjit-style lowering — builds the train/eval
+               step from specs alone for ANY validated topology
+               (the trainer's accum/ZeRO/PP/EP case analysis
                collapsed into a single code path)
 
 Compositions that previously had no code path — ZeRO-3 under PP, and a
@@ -49,6 +49,5 @@ from distribuuuu_tpu.parallel.partition.lowering import (  # noqa: F401
     Lowered,
     lower,
     make_eval_step,
-    make_scan_train_step,
     make_train_step,
 )

@@ -1,4 +1,4 @@
-"""The one lowering: specs → compiled train/eval/folded steps.
+"""The one lowering: specs → compiled train/eval steps.
 
 This is where the per-leaf declarations (partition/specs.py) and the
 validated topology (partition/topology.py) become executable programs.
@@ -172,7 +172,7 @@ def value_and_grad_scoped(loss_fn):
 
 def train_step_body(model, optimizer, topk: int, accum_steps: int = 1,
                     layout=None, rest_layout=None):
-    """The pure step function shared by the per-step and folded paths.
+    """The pure step function (plain, or accumulating over micro-batches).
 
     ``layout`` (a ``specs.state_layout`` dict) is required when
     ``MESH.ZERO`` is on: the gradient is constrained to the ZeRO layout
@@ -451,28 +451,6 @@ def make_train_step(model, optimizer, topk: int, accum_steps: int = 1,
     )
 
 
-def make_scan_train_step(model, optimizer, topk: int, fold: int,
-                         accum_steps: int = 1, layout=None,
-                         rest_layout=None):
-    """``fold`` optimizer steps in ONE compiled call via ``lax.scan``.
-
-    Same math as ``fold`` sequential ``make_train_step`` calls (same body,
-    same per-step RNG folding via ``state.step``; results agree up to XLA
-    fusion-order float drift). The difference is dispatch: one host→device
-    launch per ``fold`` steps, so the per-step host overhead amortizes
-    away (its size on this installation is not measured — PERF.md).
-    Takes a stacked batch pytree with leading dim ``fold`` (leaf shape
-    ``(fold, batch, ...)``) and returns stacked per-step metrics ``(fold,)``.
-    """
-    body = train_step_body(model, optimizer, topk, accum_steps, layout=layout,
-                           rest_layout=rest_layout)
-
-    def scan_steps(state: TrainState, stacked_batch):
-        return jax.lax.scan(body, state, stacked_batch, length=fold)
-
-    return jax.jit(scan_steps, donate_argnums=0)
-
-
 def make_eval_step(model, topk: int, layout=None):
     """Masked eval step: per-batch metric sums + valid count
     (≙ validate body, ref: trainer.py:77-89).
@@ -554,9 +532,7 @@ class Lowered:
     step_layout: dict | None  # layout when a ZeRO stage is on, else None
     train_step: Any
     eval_step: Any
-    scan_step: Any = None  # folded step when fold > 1
     accum: int = 1
-    fold: int = 1
     model: Any = None
     optimizer: Any = None  # kept so abstract_args can shape the opt state
     im_size: int = 32
@@ -576,14 +552,6 @@ class Lowered:
                 self.mesh, host_batch, self.accum
             )
         return sharding_lib.shard_batch(self.mesh, host_batch)
-
-    def put_stacked(self, host_stacked):
-        """Place a fold-stacked host batch per the declared batch specs."""
-        if self.accum > 1:
-            return sharding_lib.shard_stacked_micro_batch(
-                self.mesh, host_stacked, self.accum
-            )
-        return sharding_lib.shard_stacked_batch(self.mesh, host_stacked)
 
     def abstract_args(self, batch_size: int | None = None, *,
                       with_mask: bool = False):
@@ -664,8 +632,8 @@ class Lowered:
 
 @telemetry_spans.setup_timer("lower")
 def lower(model, optimizer, topk: int, *, mesh, topology, im_size: int,
-          fold: int = 1, accum: int = 1) -> Lowered:
-    """Build the train/eval(/folded) step for ANY validated topology from
+          accum: int = 1) -> Lowered:
+    """Build the train/eval step for ANY validated topology from
     the declared specs — the single code path the trainer's per-topology
     case analysis collapsed into.
 
@@ -682,17 +650,11 @@ def lower(model, optimizer, topk: int, *, mesh, topology, im_size: int,
         model, optimizer, topk, accum_steps=accum, layout=step_layout,
         rest_layout=layout,
     )
-    scan_step = None
-    if fold > 1:
-        scan_step = make_scan_train_step(
-            model, optimizer, topk, fold, accum_steps=accum,
-            layout=step_layout, rest_layout=layout,
-        )
     return Lowered(
         mesh=mesh, topology=topology, layout=layout, step_layout=step_layout,
         train_step=train_step,
         eval_step=make_eval_step(model, topk, layout=step_layout),
-        scan_step=scan_step, accum=max(1, accum), fold=max(1, fold),
+        accum=max(1, accum),
         model=model, optimizer=optimizer, im_size=im_size,
     )
 

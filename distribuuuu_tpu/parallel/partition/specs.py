@@ -388,7 +388,7 @@ def apply_spec_table(base, table: SpecTable, mesh: Mesh):
 
 def batch_spec(key: str, *, leading_dims: int = 0) -> P:
     """Spec for batch leaf ``key`` with ``leading_dims`` extra leading
-    dims (fold / accum stacking) before the batch dim."""
+    dims (accum stacking) before the batch dim."""
     base = BATCH_TABLE.spec_for(key)
     return P(*([None] * leading_dims + list(tuple(base))))
 

@@ -93,27 +93,18 @@ def shard_batch(mesh: Mesh, batch):
     return _put_tree(mesh, batch, batch_dim=0)
 
 
-def shard_stacked_batch(mesh: Mesh, stacked):
-    """Place a host-local *stack* of batches (leading dim = fold size,
-    second dim = batch) sharded on ``data`` along the batch dim — the input
-    layout for the folded ``lax.scan`` train step."""
-    return _put_tree(mesh, stacked, batch_dim=1)
-
-
-def _micro_split(tree, accum: int, batch_axis: int):
-    """Zero-copy view splitting dim ``batch_axis`` (size B) into
+def _micro_split(tree, accum: int):
+    """Zero-copy view splitting the batch dim (size B) into
     ``(accum, B/accum)``; raises with per-axis numbers if indivisible."""
 
     def _split(x):
         x = np.asarray(x)
-        b = x.shape[batch_axis]
+        b = x.shape[0]
         if b % accum:
             raise ValueError(
                 f"batch dim {b} not divisible by GRAD_ACCUM_STEPS={accum}"
             )
-        return x.reshape(
-            x.shape[:batch_axis] + (accum, b // accum) + x.shape[batch_axis + 1:]
-        )
+        return x.reshape((accum, b // accum) + x.shape[1:])
 
     return jax.tree.map(_split, tree)
 
@@ -122,10 +113,4 @@ def shard_micro_batch(mesh: Mesh, batch, accum: int):
     """Split a host batch into ``(accum, micro_batch, ...)`` (zero-copy) and
     place it with the micro_batch dim on ``data`` — the input layout for the
     gradient-accumulation train step (TRAIN.GRAD_ACCUM_STEPS)."""
-    return _put_tree(mesh, _micro_split(batch, accum, 0), batch_dim=1)
-
-
-def shard_stacked_micro_batch(mesh: Mesh, stacked, accum: int):
-    """Folded + accumulated: ``(fold, accum, micro_batch, ...)`` with the
-    micro_batch dim on ``data``."""
-    return _put_tree(mesh, _micro_split(stacked, accum, 1), batch_dim=2)
+    return _put_tree(mesh, _micro_split(batch, accum), batch_dim=1)

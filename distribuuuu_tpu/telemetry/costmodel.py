@@ -263,25 +263,20 @@ def build_ledger(
     memory: dict | None,
     *,
     images: int,
-    steps_per_call: int = 1,
     arch: str | None = None,
     peaks: dict | None = None,
     n_devices: int = 1,
 ) -> dict:
     """Assemble the three record payloads (``step`` / ``memory`` /
-    ``roofline``) from normalized analyses. ``cost``/``memory`` are
-    whole-call numbers; the records carry PER-STEP values
-    (÷ steps_per_call for a folded program). Pure — the shared core of
-    the trainer/serve hooks, bench.py, and tools/costmodel_report.py."""
-    spc = max(1, int(steps_per_call))
+    ``roofline``) from normalized analyses of one step's program. Pure —
+    the shared core of the trainer/serve hooks, bench.py, and
+    tools/costmodel_report.py."""
     source = "xla"
     flops = bytes_acc = transc = None
     if cost is not None:
-        flops = cost["flops"] / spc
-        bytes_acc = (
-            cost["bytes_accessed"] / spc if cost.get("bytes_accessed") else None
-        )
-        transc = cost.get("transcendentals", 0.0) / spc
+        flops = cost["flops"]
+        bytes_acc = cost.get("bytes_accessed") or None
+        transc = cost.get("transcendentals", 0.0)
     else:
         source = "analytic"
         flops = analytic_step_flops(
@@ -298,7 +293,7 @@ def build_ledger(
         "bytes_accessed": round(bytes_acc, 1) if bytes_acc else None,
         "transcendentals": round(transc, 1) if transc else 0.0,
         "images": int(images),
-        "steps_per_call": spc,
+        "steps_per_call": 1,  # every program is one step (ROADMAP D8)
         "devices": int(n_devices),
         "device_kind": peaks["kind"] if peaks else None,
         "peak_flops": total_peak,
@@ -520,17 +515,16 @@ def set_mesh_extras(extras: dict | None) -> None:
     _mesh_extras = dict(extras) if extras else None
 
 
-def _emit(ledger: dict, parts=("step", "memory", "roofline")) -> None:
+def _emit(ledger: dict) -> None:
     from distribuuuu_tpu.telemetry import spans
 
-    if "step" in parts:
-        step = dict(ledger["step"])
-        if _mesh_extras:
-            step.update(_mesh_extras)
-        spans.emit_event("cost.step", **step)
-    if "memory" in parts and ledger["memory"] is not None:
+    step = dict(ledger["step"])
+    if _mesh_extras:
+        step.update(_mesh_extras)
+    spans.emit_event("cost.step", **step)
+    if ledger["memory"] is not None:
         spans.emit_event("cost.memory", **ledger["memory"])
-    if "roofline" in parts and ledger["roofline"] is not None:
+    if ledger["roofline"] is not None:
         spans.emit_event("cost.roofline", **ledger["roofline"])
 
 
@@ -541,29 +535,18 @@ def capture_step(
     label: str,
     phase: str,
     images: int,
-    steps_per_call: int = 1,
     arch: str | None = None,
     with_memory: bool = False,
-    memory_only: bool = False,
 ) -> dict | None:
     """The trainer hook: once per ``label`` per process, lower ``fn``
     against the live ``(state, batch)`` (pre-donation — lowering reads
     shapes, not buffers), emit the cost/memory/roofline records into the
     per-rank sink, and return the ledger. Never raises and no-ops while
-    telemetry is off — attribution must not take a run down.
-
-    ``memory_only`` emits just the cost.memory record: the folded
-    (lax.scan) step program's HBM footprint is real, but XLA's cost
-    analysis counts a scan BODY once regardless of trip count, so its
-    flops cannot source the per-step ledger — the trainer captures
-    flops from the per-step program and memory from the program that
-    actually runs."""
+    telemetry is off — attribution must not take a run down."""
     from distribuuuu_tpu.telemetry import spans
 
     if not spans.enabled() or label in _seen_labels:
         return None
-    if memory_only and not with_memory:
-        return None  # nothing to emit without the memory analysis
     _seen_labels.add(label)
     try:
         analyses = analyze_jitted(fn, args, with_memory=with_memory)
@@ -571,11 +554,10 @@ def capture_step(
 
         ledger = build_ledger(
             label, phase, analyses["cost"], analyses["memory"],
-            images=images, steps_per_call=steps_per_call, arch=arch,
+            images=images, arch=arch,
             peaks=peaks_for(), n_devices=len(jax.devices()),
         )
-        _emit(ledger, parts=("memory",) if memory_only
-              else ("step", "memory", "roofline"))
+        _emit(ledger)
         return ledger
     except Exception as e:  # noqa: BLE001 — observability never fatal
         try:

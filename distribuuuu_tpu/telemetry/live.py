@@ -238,8 +238,6 @@ class _RankWindow:
 
     def __init__(self):
         self.step_durs: list[float] = []
-        self.fold_durs: list[float] = []  # already ÷ n (per-step seconds)
-        self.steps = 0  # true optimizer steps (a fold span counts its n)
         self.images = 0
         self.wait_s = 0.0
         self.span_t0 = None  # pipeline-track coverage for wait fraction
@@ -358,51 +356,32 @@ class LiveAggregator:
             )
         if name == "step":
             win.step_durs.append(dur)
-            win.steps += 1
             win.images += int(rec.get("n", 0))
             self.totals["steps"] += 1
             self.totals["images"] += int(rec.get("n", 0))
-        elif name == "fold_window":
-            # a fold span's ``n`` is the STEP count of the window (the
-            # batch size is not recorded there), so folded runs get
-            # per-step time but no image throughput — img_per_sec stays
-            # None and rate rules sit out via min_steps
-            n = max(1, int(rec.get("n", 1)))
-            win.fold_durs.append(dur / n)
-            win.steps += n
-            self.totals["steps"] += n
-        elif name == "wait":
-            win.wait_s += dur
-            return
-        else:
-            return
-        if name in ("step", "fold_window"):
             win.step_t0 = t0 if win.step_t0 is None else min(win.step_t0, t0)
             win.step_t1 = (
                 t0 + dur if win.step_t1 is None
                 else max(win.step_t1, t0 + dur)
             )
+        elif name == "wait":
+            win.wait_s += dur
 
     def snapshot(self, window_s: float, serve: dict | None = None,
                  tail: dict | None = None) -> dict:
         """Close the current window into one aggregate dict and reset the
         window accumulators (run-scope ``totals`` roll on)."""
-        # step percentiles: step spans when the window has any, else the
-        # fold_window-derived per-step durations (run_report's rule)
         pooled: list[float] = []
         per_rank_p50: dict[str, float] = {}
         images = 0
-        true_steps = 0  # optimizer steps (fold spans count their n)
         active_t0, active_t1 = None, None
         wait_fracs: list[float] = []
         for rank, win in sorted(self._win.items()):
-            durs = win.step_durs or win.fold_durs
             images += win.images
-            true_steps += win.steps
-            if durs:
-                pooled.extend(durs)
+            if win.step_durs:
+                pooled.extend(win.step_durs)
                 per_rank_p50[str(rank)] = round(
-                    percentile(sorted(durs), 0.50) * 1e3, 3
+                    percentile(sorted(win.step_durs), 0.50) * 1e3, 3
                 )
             if win.span_t0 is not None and win.span_t1 > win.span_t0:
                 wait_fracs.append(win.wait_s / (win.span_t1 - win.span_t0))
@@ -433,11 +412,11 @@ class LiveAggregator:
         # and a known device peak have been seen.
         mfu = None
         if (
-            self._flops_per_step and self._peak_flops and true_steps
+            self._flops_per_step and self._peak_flops and pooled
             and active_t1 is not None and active_t1 > active_t0
         ):
             mfu = round(
-                self._flops_per_step * true_steps
+                self._flops_per_step * len(pooled)
                 / (active_t1 - active_t0) / self._peak_flops, 4
             )
         headroom = (

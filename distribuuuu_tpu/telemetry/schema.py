@@ -213,7 +213,7 @@ KINDS: dict[str, frozenset] = {
 # twin of the same interval is the ``jax.profiler.TraceAnnotation``
 # ``dtpu.<layer>.<name>`` (``ANNOTATIONS``), which lands in any profiler
 # capture on the device's clock. Names measured after the fact by
-# ``emit_span`` alone (decode, assemble, fold_window) have no annotation
+# ``emit_span`` alone (decode, assemble) have no annotation
 # site and stay JSONL-only. PERF.md "spans and counters" says which
 # metric reads each.
 ANNOTATION_PREFIX = "dtpu."
@@ -223,7 +223,6 @@ SPANS: dict[str, str] = {
     "h2d": "trainer",
     "step": "trainer",
     "metrics_fetch": "trainer",
-    "fold_window": "trainer",
     # loader worker threads (data/loader.py)
     "decode": "loader",
     "assemble": "loader",

@@ -57,7 +57,6 @@ from distribuuuu_tpu.parallel.partition import (
 from distribuuuu_tpu.parallel.partition.lowering import (  # noqa: F401
     TrainState,
     make_eval_step,
-    make_scan_train_step,
     make_train_step,
 )
 from distribuuuu_tpu import asyncplane
@@ -355,8 +354,8 @@ class _ProfilerWindow:
             self.last = cfg.PROF.START_STEP + cfg.PROF.NUM_STEPS
 
     def begin(self, it):
-        # >= not ==: in folded mode ``it`` advances in fold-sized jumps, so
-        # the window opens at the first call boundary at/after START_STEP
+        # >= not ==: a resumed epoch starts at its restored cursor, so the
+        # window opens at the first step at/after START_STEP
         if self.enabled and not self.started and it >= self.first:
             jax.profiler.start_trace(self.trace_dir)
             self.active = self.started = True
@@ -369,7 +368,7 @@ class _ProfilerWindow:
         get_logger().info("profiler trace written to %s", self.trace_dir)
 
     def end(self, it, state):
-        # >= not ==: close at the first call boundary covering the window end
+        # >= not ==: close at the first step covering the window end
         if self.active and it + 1 >= self.last:
             self._stop(state)
 
@@ -417,9 +416,8 @@ def _step_spans_on() -> bool:
     return telemetry_spans.enabled() and cfg.TELEMETRY.STEP_SPANS
 
 
-def _capture_step_cost(step_fn, state, batch, *, label: str, phase: str,
-                       steps_per_call: int = 1, with_memory: bool | None = None,
-                       memory_only: bool = False) -> None:
+def _capture_step_cost(step_fn, state, batch, *, label: str,
+                       phase: str) -> None:
     """XLA cost-model ledger for one step program (telemetry/costmodel.py):
     at the FIRST dispatch — state not yet donated, the live (state, batch)
     supply exact shapes/shardings — lower the jitted step and emit
@@ -427,40 +425,30 @@ def _capture_step_cost(step_fn, state, batch, *, label: str, phase: str,
     process (costmodel dedups); never raises."""
     if not (telemetry_spans.enabled() and cfg.TELEMETRY.COSTMODEL):
         return
-    # every leading dim of the image leaf is batch-like: (batch,...) /
-    # (fold, batch, ...) / (fold, accum, micro, ...) — their product is
-    # the examples per compiled call. Token batches (the LM — integer
-    # [..., seq]) have ONE trailing payload dim instead of the image's
-    # three; "images" then counts sequences (run_report's lm section
-    # multiplies by seq len for tokens/s).
+    # every leading dim of the image leaf is batch-like: (batch, ...) /
+    # (accum, micro, ...) — their product is the examples per step. Token
+    # batches (the LM — integer [..., seq]) have ONE trailing payload dim
+    # instead of the image's three; "images" then counts sequences
+    # (run_report's lm section multiplies by seq len for tokens/s).
     img = batch["image"]
     lead = (
         img.shape[:-1]
         if jnp.issubdtype(img.dtype, jnp.integer)
         else img.shape[:-3]
     )
-    images_per_call = 1
+    images = 1
     for d in lead:
-        images_per_call *= int(d)
-    if with_memory is None:
-        with_memory = cfg.TELEMETRY.COSTMODEL_MEMORY
+        images *= int(d)
     costmodel.capture_step(
         step_fn, (state, batch), label=label, phase=phase,
-        images=max(1, images_per_call // max(1, steps_per_call)),
-        steps_per_call=steps_per_call, arch=cfg.MODEL.ARCH,
-        with_memory=with_memory, memory_only=memory_only,
+        images=max(1, images), arch=cfg.MODEL.ARCH,
+        with_memory=cfg.TELEMETRY.COSTMODEL_MEMORY,
     )
 
 
 def train_epoch(loader, mesh, state, train_step, epoch: int, logger,
-                first_epoch: int = 0, scan_step=None):
+                first_epoch: int = 0):
     """One epoch of the hot loop (ref: trainer.py:14-64).
-
-    With ``TRAIN.STEPS_PER_CALL > 1`` (``scan_step`` provided) full groups of
-    batches dispatch as one compiled ``lax.scan`` call; the ragged tail falls
-    back to ``train_step``. Metric fetch still happens at PRINT_FREQ batch
-    granularity (rounded up to the fold size); the profiler window rounds to
-    call boundaries.
 
     Returns ``(state, interrupted, batches_done)``: with
     ``TRAIN.PREEMPT_SAVE`` on, a SIGTERM (utils/preempt.py) ends the epoch
@@ -494,7 +482,6 @@ def train_epoch(loader, mesh, state, train_step, epoch: int, logger,
     # local bool — free, so check every window.
     preempt_check_every = 1 if jax.process_count() == 1 else 8
     windows_seen = 0
-    fold = max(1, cfg.TRAIN.STEPS_PER_CALL) if scan_step is not None else 1
     accum = max(1, cfg.TRAIN.GRAD_ACCUM_STEPS)
 
     def put_batch(hb):
@@ -502,16 +489,11 @@ def train_epoch(loader, mesh, state, train_step, epoch: int, logger,
             return sharding_lib.shard_micro_batch(mesh, hb, accum)
         return sharding_lib.shard_batch(mesh, hb)
 
-    def put_stacked(hb):
-        if accum > 1:
-            return sharding_lib.shard_stacked_micro_batch(mesh, hb, accum)
-        return sharding_lib.shard_stacked_batch(mesh, hb)
     batch_time, data_time, losses, top1, topk_m, progress = construct_meters(
         num_batches, f"Epoch[{epoch + 1}/{cfg.OPTIM.MAX_EPOCH}]", effective_topk()
     )
     prof = _ProfilerWindow(epoch, first_epoch)
-    pending = []  # (n_steps, device metrics) awaiting async fetch
-    n_buffered = 0  # fold slots filled since the last dispatch
+    pending = []  # each step's device metrics, awaiting the async fetch
     done = start_batch  # absolute batches dispatched (incl. skipped prefix)
 
     # dispatch-MoE only: fraction of routed assignments lost to capacity
@@ -533,37 +515,20 @@ def train_epoch(loader, mesh, state, train_step, epoch: int, logger,
         # the float() reads below are the loop's only fence on the device:
         # device-idle gaps under this span are the print interval's price
         with telemetry_spans.span("metrics_fetch", track="pipeline"):
-            for n, m in pending:
-                if n == 1:
-                    if nf_mon.observe(
-                        float(m["loss"]), float(m.get("nonfinite", 0.0)), done
-                    ):
-                        continue  # skipped in-graph — keep it out of the meters
-                    losses.update(float(m["loss"]))
-                    top1.update(float(m["top1"]))
-                    topk_m.update(float(m["topk"]))
-                    if "moe_dropped" in m:
-                        moe_dropped.update(float(m["moe_dropped"]))
-                else:  # stacked (fold,) metrics from a scan call
-                    nfs = np.asarray(
-                        m.get("nonfinite", np.zeros(n))
-                    ).reshape(-1)
-                    for j, (ls, t1, tk) in enumerate(zip(
-                        np.asarray(m["loss"]), np.asarray(m["top1"]),
-                        np.asarray(m["topk"]),
-                    )):
-                        if nf_mon.observe(float(ls), float(nfs[j]), done):
-                            continue
-                        losses.update(float(ls))
-                        top1.update(float(t1))
-                        topk_m.update(float(tk))
-                    if "moe_dropped" in m:
-                        for dv in np.asarray(m["moe_dropped"]).reshape(-1):
-                            moe_dropped.update(float(dv))
+            for m in pending:
+                if nf_mon.observe(
+                    float(m["loss"]), float(m.get("nonfinite", 0.0)), done
+                ):
+                    continue  # skipped in-graph — keep it out of the meters
+                losses.update(float(m["loss"]))
+                top1.update(float(m["top1"]))
+                topk_m.update(float(m["topk"]))
+                if "moe_dropped" in m:
+                    moe_dropped.update(float(m["moe_dropped"]))
         pending.clear()
 
     def maybe_print():
-        if done % cfg.TRAIN.PRINT_FREQ < fold or done == num_batches:
+        if done % cfg.TRAIN.PRINT_FREQ == 0 or done == num_batches:
             flush_pending()
             if mesh_lib.is_primary():
                 eta = progress.get_eta(
@@ -609,173 +574,48 @@ def train_epoch(loader, mesh, state, train_step, epoch: int, logger,
     emit_timeline = cfg.TRAIN.TIMELINE and mesh_lib.is_primary()
     emit_spans = _step_spans_on()
     try:
-        if fold > 1:
-            # Two preallocated (fold, batch, ...) host buffers, ping-ponged per
-            # dispatch: device_put may still be reading buffer A asynchronously
-            # while the next fold fills buffer B. Before REFILLING a buffer,
-            # fence on the device batch previously created from it — readiness
-            # implies the H2D transfer has consumed the host memory (near-zero
-            # cost in steady state; without it a deep dispatch backlog could
-            # overwrite a buffer a pending transfer is still reading, silently
-            # corrupting a batch). No per-batch timeline records in this mode
-            # (stage boundaries are fold-granular); STEPS_PER_CALL 1 is the
-            # attribution mode.
-            stack_bufs, buf_idx = None, 0
-            inflight = [None, None]  # device batch last created from each buffer
-            end = time.perf_counter()
-            win_start = end  # start of the current fold window (incl. buffering)
-            for it, host_batch in enumerate(loader):
-                abs_it = start_batch + it  # loader skipped the resumed prefix
-                heartbeat.beat(f"epoch {epoch + 1} batch {abs_it}")
-                faults.maybe_stall(epoch, abs_it)  # injection no-ops (FAULTS.*)
-                faults.maybe_kill(epoch, abs_it)
-                faults.maybe_preempt(epoch, abs_it)
-                faults.maybe_recompile(epoch, abs_it)
-                faults.maybe_slowdown(epoch, abs_it)
-                data_time.update(time.perf_counter() - end)
-                is_last = abs_it + 1 == num_batches
-                # copy into the preallocated fold slot NOW (spreads the host
-                # memcpy across the fold window, overlapped with the device
-                # executing the previous call) instead of np.stack-ing the
-                # whole fold on the dispatch iteration
-                if stack_bufs is None:
-                    stack_bufs = [
-                        jax.tree.map(
-                            lambda x: np.empty(
-                                (fold,) + np.shape(x), np.asarray(x).dtype
-                            ),
-                            host_batch,
-                        )
-                        for _ in range(2)
-                    ]
-                stack_buf = stack_bufs[buf_idx]
-                if n_buffered == 0 and inflight[buf_idx] is not None:
-                    jax.block_until_ready(inflight[buf_idx])
-                    inflight[buf_idx] = None
-                jax.tree.map(
-                    lambda buf, x: buf.__setitem__(n_buffered, x),
-                    stack_buf, host_batch,
+        # Per-step dispatch through the device-side prefetch ring
+        # (data/loader.device_prefetch): the H2D transfer of batches
+        # it+1..it+depth is dispatched while the step for batch `it` runs,
+        # so transfer never serializes behind the step; depth 0 restores
+        # the serial put-then-step order. Results are value-bit-identical
+        # at every depth (same put/step order — tests/test_overlap.py).
+        # Each dispatched batch leaves one kind="timeline" record with its
+        # stage-boundary timestamps (tools/overlap_report.py attributes
+        # the epoch wall from them).
+        depth = max(0, cfg.TRAIN.PREFETCH_DEVICE)
+        end = time.perf_counter()
+        for it, batch, tl in device_prefetch(loader, put_batch, depth):
+            abs_it = start_batch + it  # loader skipped the resumed prefix
+            heartbeat.beat(f"epoch {epoch + 1} batch {abs_it}")
+            faults.maybe_stall(epoch, abs_it)  # injection no-ops (FAULTS.*)
+            faults.maybe_kill(epoch, abs_it)
+            faults.maybe_preempt(epoch, abs_it)
+            faults.maybe_recompile(epoch, abs_it)
+            faults.maybe_slowdown(epoch, abs_it)
+            data_time.update(tl["get1"] - tl["get0"])
+            _capture_step_cost(
+                train_step, state, batch, label="train_step", phase="train"
+            )
+            prof.begin(abs_it)
+            tl["step0"] = time.perf_counter()
+            with telemetry_spans.annotate("step"):
+                state, metrics = sequencer.dispatch(
+                    sequencer.TRAIN_STREAM, train_step, state, batch
                 )
-                n_buffered += 1
-                if n_buffered < fold and not is_last:
-                    end = time.perf_counter()
-                    continue
-                n = n_buffered
-                if n == fold:
-                    batch = put_stacked(stack_buf)
-                    inflight[buf_idx] = batch
-                    if "train_step" not in costmodel._seen_labels:
-                        # flops from the PER-STEP program (XLA cost
-                        # analysis counts a lax.scan body once regardless
-                        # of trip count — the folded program cannot
-                        # source per-step flops); lower-only, no compile
-                        _capture_step_cost(
-                            train_step, state,
-                            put_batch(jax.tree.map(
-                                lambda buf: buf[0], stack_buf
-                            )),
-                            label="train_step", phase="train",
-                            with_memory=False,
-                        )
-                    # HBM footprint of the folded program actually
-                    # running (memory_analysis is per-executable — real)
-                    _capture_step_cost(
-                        scan_step, state, batch, label="train_fold",
-                        phase="train", steps_per_call=fold,
-                        memory_only=True,
-                    )
-                    prof.begin(done)
-                    # token-ordered when a second dispatch stream is
-                    # active (asyncplane/sequencer.py); pass-through with
-                    # one attribute read otherwise
-                    with telemetry_spans.annotate("step"):
-                        state, metrics = sequencer.dispatch(
-                            sequencer.TRAIN_STREAM, scan_step, state, batch
-                        )
-                    prof.end(done + fold - 1, state)
-                    pending.append((fold, metrics))
-                else:  # ragged tail: per-step dispatch
-                    for i in range(n):
-                        hb = jax.tree.map(lambda buf: buf[i], stack_buf)
-                        b = put_batch(hb)
-                        _capture_step_cost(
-                            train_step, state, b, label="train_step",
-                            phase="train",
-                        )
-                        prof.begin(done + i)
-                        with telemetry_spans.annotate("step"):
-                            state, metrics = sequencer.dispatch(
-                                sequencer.TRAIN_STREAM, train_step, state, b
-                            )
-                        prof.end(done + i, state)
-                        pending.append((1, metrics))
-                done += n
-                n_buffered = 0
-                buf_idx ^= 1
-                # per-BATCH time over the whole window (incl. the buffering
-                # iterations) so display/ETA keep their per-batch meaning
-                now = time.perf_counter()
-                if emit_spans:
-                    # folded dispatch has no per-step stamps; one span per
-                    # window (n steps) — run_report derives per-step time
-                    # as dur/n when a run has only fold_window spans
-                    telemetry_spans.emit_span(
-                        "fold_window", win_start, now, track="pipeline",
-                        phase="train", epoch=epoch + 1,
-                        batch=done - n, n=n,
-                    )
-                batch_time.update((now - win_start) / n, n=n)
-                win_start = now
-                end = time.perf_counter()
-                maybe_print()
-                if preempt_break(done):
-                    break
-        else:
-            # Per-step dispatch through the device-side prefetch ring
-            # (data/loader.device_prefetch): the H2D transfer of batches
-            # it+1..it+depth is dispatched while the step for batch `it` runs,
-            # so transfer never serializes behind the step; depth 0 restores
-            # the serial put-then-step order. Results are value-bit-identical
-            # at every depth (same put/step order — tests/test_overlap.py).
-            # Each dispatched batch leaves one kind="timeline" record with its
-            # stage-boundary timestamps (tools/overlap_report.py attributes
-            # the epoch wall from them).
-            depth = max(0, cfg.TRAIN.PREFETCH_DEVICE)
+            tl["step1"] = time.perf_counter()
+            prof.end(abs_it, state)
+            pending.append(metrics)
+            done += 1
+            batch_time.update(time.perf_counter() - end)
             end = time.perf_counter()
-            for it, batch, tl in device_prefetch(loader, put_batch, depth):
-                abs_it = start_batch + it  # loader skipped the resumed prefix
-                heartbeat.beat(f"epoch {epoch + 1} batch {abs_it}")
-                faults.maybe_stall(epoch, abs_it)  # injection no-ops (FAULTS.*)
-                faults.maybe_kill(epoch, abs_it)
-                faults.maybe_preempt(epoch, abs_it)
-                faults.maybe_recompile(epoch, abs_it)
-                faults.maybe_slowdown(epoch, abs_it)
-                data_time.update(tl["get1"] - tl["get0"])
-                _capture_step_cost(
-                    train_step, state, batch, label="train_step",
-                    phase="train",
-                )
-                prof.begin(abs_it)
-                tl["step0"] = time.perf_counter()
-                with telemetry_spans.annotate("step"):
-                    state, metrics = sequencer.dispatch(
-                        sequencer.TRAIN_STREAM, train_step, state, batch
-                    )
-                tl["step1"] = time.perf_counter()
-                prof.end(abs_it, state)
-                pending.append((1, metrics))
-                done += 1
-                batch_time.update(time.perf_counter() - end)
-                end = time.perf_counter()
-                if emit_spans:
-                    _emit_batch_spans("train", epoch + 1, abs_it, tl)
-                if emit_timeline:
-                    timeline_log(
-                        "train", epoch + 1, abs_it, tl.pop("n", 0), **tl
-                    )
-                maybe_print()
-                if preempt_break(done):
-                    break
+            if emit_spans:
+                _emit_batch_spans("train", epoch + 1, abs_it, tl)
+            if emit_timeline:
+                timeline_log("train", epoch + 1, abs_it, tl.pop("n", 0), **tl)
+            maybe_print()
+            if preempt_break(done):
+                break
         prof.finish(state)
     finally:
         heartbeat.stop()
@@ -1221,8 +1061,7 @@ def train_model():
     model = build_model_from_cfg(topo)
     lowered = partition_lowering.lower(
         model, construct_optimizer(), effective_topk(), mesh=mesh,
-        topology=topo, im_size=cfg.TRAIN.IM_SIZE,
-        fold=max(1, cfg.TRAIN.STEPS_PER_CALL), accum=accum,
+        topology=topo, im_size=cfg.TRAIN.IM_SIZE, accum=accum,
     )
     layout = lowered.layout
     state = create_train_state(model, key, mesh, cfg.TRAIN.IM_SIZE, layout=layout)
@@ -1236,7 +1075,6 @@ def train_model():
     train_loader = construct_train_loader()
     val_loader = construct_val_loader()
     train_step = lowered.train_step
-    scan_step = lowered.scan_step
     eval_step = lowered.eval_step
 
     start_epoch, best_acc1, pending_eval = 0, 0.0, None
@@ -1481,7 +1319,7 @@ def train_model():
                 state, interrupted, batches_done = train_epoch(
                     loader=train_loader, mesh=mesh, state=state,
                     train_step=train_step, epoch=epoch, logger=logger,
-                    first_epoch=start_epoch, scan_step=scan_step)
+                    first_epoch=start_epoch)
             except supervisor.NonFiniteLossError as e:
                 # TRAIN.NONFINITE=rollback: reload the last intact checkpoint
                 # and re-run from there — the transient-corruption recovery.

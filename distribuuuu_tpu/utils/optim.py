@@ -26,8 +26,7 @@ def _momentum_dtype():
     keeps fp32 master params but halves the momentum buffer's HBM
     footprint and read+write traffic (~200 MB/step on ResNet-50) — a
     mixed-precision-optimizer configuration the reference cannot express.
-    ``DISTRIBUUUU_MOMENTUM_DTYPE`` overrides at trace time (ab_bench
-    knob)."""
+    ``DISTRIBUUUU_MOMENTUM_DTYPE`` overrides at trace time."""
     mode = os.environ.get(
         "DISTRIBUUUU_MOMENTUM_DTYPE", cfg.OPTIM.MOMENTUM_DTYPE
     )

@@ -11,30 +11,70 @@ from distribuuuu_tpu.models.layers import ConvBN
 import pytest
 
 
-def _conv_bn(groups, features=256):
+def _conv_bn(groups, features=256, stride=1):
     return ConvBN(
-        features, (3, 3), 1, groups=groups, use_bn=False, dtype=jnp.float32
+        features, (3, 3), stride, groups=groups, use_bn=False,
+        dtype=jnp.float32,
     )
 
 
-def test_unrolled_matches_fused_lowering():
-    mod = _conv_bn(groups=4)  # 256/4 = 64 per group → unrolled path
-    x = jnp.asarray(
-        np.random.default_rng(0).standard_normal((2, 8, 8, 256)), jnp.float32
-    )
-    variables = mod.init(jax.random.key(0), x)
-    out = mod.apply(variables, x)
-
+def _kernel_of(variables):
     kernel = variables["params"]["Conv_0"]["kernel"]
-    kernel = getattr(kernel, "unbox", lambda: kernel)()
-    assert kernel.shape == (3, 3, 64, 256)  # (kh, kw, in/G, out) — fused shape
-    ref = lax.conv_general_dilated(
-        x, kernel, (1, 1), [(1, 1), (1, 1)], feature_group_count=4,
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-    )
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5
-    )
+    return getattr(kernel, "unbox", lambda: kernel)()
+
+
+@pytest.mark.parametrize("what", ["output", "grads"])
+@pytest.mark.parametrize(
+    "stride,groups,width",
+    [
+        (1, 4, 64),
+        (2, 2, 128),
+        (2, 2, 112),  # RegNetY-16GF's group width; its stride-2 backward
+                      # is the hot op of regnety_160.train (ROADMAP S7)
+    ],
+)
+def test_unrolled_matches_feature_group_count(stride, groups, width, what):
+    """The path ``regnety_160.train`` runs — ConvBN's per-group slice loop
+    over ONE canonical ``(kh, kw, in/G, out)`` kernel — against
+    ``lax.conv_general_dilated(feature_group_count=G)`` on the same
+    kernel: the output, and the gradients to input and kernel (so the
+    same variables and checkpoints drive either lowering)."""
+    C = groups * width
+    mod = _conv_bn(groups, features=C, stride=stride)
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.standard_normal((2, 8, 8, C)), jnp.float32)
+    variables = mod.init(jax.random.key(0), x)
+    kernel = _kernel_of(variables)
+    assert kernel.shape == (3, 3, width, C)
+
+    def unrolled(xx, kk):
+        boxed = jax.tree.map(lambda _: kk, variables)
+        return mod.apply(boxed, xx)
+
+    def fused(xx, kk):
+        return lax.conv_general_dilated(
+            xx, kk, (stride, stride), [(1, 1), (1, 1)],
+            feature_group_count=groups,
+            dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        )
+
+    if what == "output":
+        got, ref = [unrolled(x, kernel)], [fused(x, kernel)]
+        assert got[0].shape == (2, 8 // stride, 8 // stride, C)
+    else:
+        ct = jnp.asarray(
+            rng.standard_normal((2, 8 // stride, 8 // stride, C)), jnp.float32
+        )
+        got, ref = (
+            jax.grad(lambda xx, kk: jnp.sum(f(xx, kk) * ct), argnums=(0, 1))(
+                x, kernel
+            )
+            for f in (unrolled, fused)
+        )
+    for a, b in zip(got, ref):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4
+        )
 
 
 def test_width_gate_selects_the_right_path():
@@ -58,30 +98,8 @@ def test_width_gate_selects_the_right_path():
     # and the narrow path still runs
     mod = _conv_bn(groups=32)
     variables = mod.init(jax.random.key(0), x_narrow)
-    kernel = variables["params"]["Conv_0"]["kernel"]
-    kernel = getattr(kernel, "unbox", lambda: kernel)()
-    assert kernel.shape == (3, 3, 8, 256)
+    assert _kernel_of(variables).shape == (3, 3, 8, 256)
     assert mod.apply(variables, x_narrow).shape == (1, 4, 4, 256)
-
-
-def test_group_conv_checkpoint_compatible_across_widths():
-    """The same variables drive both paths — param tree does not depend on
-    which compute path ConvBN picks (verified by cross-applying)."""
-    wide = _conv_bn(groups=2)    # unrolled
-    x = jnp.asarray(
-        np.random.default_rng(1).standard_normal((2, 8, 8, 256)), jnp.float32
-    )
-    variables = wide.init(jax.random.key(0), x)
-    kernel = variables["params"]["Conv_0"]["kernel"]
-    kernel = getattr(kernel, "unbox", lambda: kernel)()
-    ref = lax.conv_general_dilated(
-        x, kernel, (1, 1), [(1, 1), (1, 1)], feature_group_count=2,
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-    )
-    np.testing.assert_allclose(
-        np.asarray(wide.apply(variables, x)), np.asarray(ref),
-        rtol=1e-5, atol=1e-5,
-    )
 
 
 @pytest.mark.slow  # dominates the fast tier; full tier covers it
@@ -128,123 +146,3 @@ def test_regnet_forward_still_correct():
     )
     m_params, _ = count_parameters(variables["params"])
     assert abs(m_params - 83.590) < 0.01
-
-
-class TestPallasGroupConv:
-    """ops/group_conv.py — the hand-tiled grouped 3×3 kernel (interpret
-    mode on the CPU mesh; the compiled path is exercised on hardware by
-    the PERF.md r5 A/B runs). Exactness vs the unrolled formulation for
-    fwd AND both grads, stride 1 and 2, odd group counts (the bf16
-    sublane-packing case that forced the static in-kernel group loop)."""
-
-    @pytest.mark.parametrize(
-        "shape",
-        [
-            (4, 14, 14, 33, 3, 1),   # odd G
-            (2, 8, 8, 16, 4, 1),
-            pytest.param(
-                (2, 16, 16, 22, 11, 2),  # stride 2, G=11
-                marks=pytest.mark.slow,  # 17s interpret run
-            ),
-            (4, 8, 8, 16, 2, 2),
-        ],
-    )
-    def test_exactness_and_grads(self, shape):
-        from distribuuuu_tpu.ops.group_conv import (
-            _xla_unrolled, group_conv3x3,
-        )
-
-        B, H, W, C, G, s = shape
-        rng = np.random.default_rng(0)
-        x = jnp.asarray(rng.standard_normal((B, H, W, C)), jnp.float32)
-        k = jnp.asarray(
-            rng.standard_normal((3, 3, C // G, C)) * 0.1, jnp.float32
-        )
-        ref = _xla_unrolled(x, k, s, G)
-        got = group_conv3x3(x, k, s, G, True)
-        np.testing.assert_allclose(
-            np.asarray(got), np.asarray(ref), rtol=1e-4, atol=1e-4
-        )
-        g_ref = jax.grad(
-            lambda xx, kk: jnp.sum(_xla_unrolled(xx, kk, s, G) ** 2),
-            argnums=(0, 1),
-        )(x, k)
-        g_got = jax.grad(
-            lambda xx, kk: jnp.sum(group_conv3x3(xx, kk, s, G, True) ** 2),
-            argnums=(0, 1),
-        )(x, k)
-        for a, b in zip(g_got, g_ref):
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4
-            )
-
-    def test_convbn_pallas_knob_routes_and_matches(self, monkeypatch):
-        """DISTRIBUUUU_GROUP_CONV=pallas actually takes the kernel path
-        (interpret mode off-TPU) with the SAME canonical param and the
-        same outputs as the default path — a routing-gate regression
-        (e.g. the strides/padding normalization) breaks this."""
-        mod = _conv_bn(groups=4)
-        x = jnp.asarray(
-            np.random.default_rng(0).standard_normal((2, 8, 8, 256)),
-            jnp.float32,
-        )
-        monkeypatch.delenv("DISTRIBUUUU_GROUP_CONV", raising=False)
-        variables = mod.init(jax.random.key(0), x)
-        ref = mod.apply(variables, x)
-        kernel = variables["params"]["Conv_0"]["kernel"]
-        kernel = getattr(kernel, "unbox", lambda: kernel)()
-        assert kernel.shape == (3, 3, 64, 256)
-
-        monkeypatch.setenv("DISTRIBUUUU_GROUP_CONV", "pallas")
-        got = mod.apply(variables, x)  # same variables → same param tree
-        np.testing.assert_allclose(
-            np.asarray(got), np.asarray(ref), rtol=1e-4, atol=1e-4
-        )
-
-    def test_pick_bb_counts_all_group_accumulators(self):
-        """ADVICE r5: the VMEM sizing model must count all G live group
-        accumulators (bb·ho·wo·G·fg fp32 — _kernel_s1 holds every group's
-        result until the final concatenate) plus the concatenated output
-        temp, not one group's. Checked two ways: every chosen bb respects
-        the corrected budget, and the regnety stage-3 shape where the old
-        one-group model over-picked now tiles smaller."""
-        from distribuuuu_tpu.ops import group_conv as gc
-
-        def corrected_need(bb, hp, wp, c_all, ho, wo, cg, fg, G, isz):
-            return (bb * hp * wp * c_all * isz
-                    + bb * ho * wo * G * fg * isz      # output block
-                    + bb * ho * wo * G * fg * 4        # all G fp32 accums
-                    + bb * ho * wo * G * fg * isz      # concat temp
-                    + bb * hp * wp * cg * isz * 2)     # gather + taps
-
-        def old_need(bb, hp, wp, c_all, ho, wo, cg, fg, G, isz):
-            # the pre-fix model: ONE group's accumulator (and ho·wp at that)
-            return (bb * hp * wp * c_all * isz
-                    + bb * ho * wo * G * fg * isz
-                    + bb * ho * wp * fg * 4
-                    + bb * hp * wp * cg * isz * 2)
-
-        cases = [
-            # (batch, hp, wp, c_all, ho, wo, cg, fg, G, itemsize)
-            (64, 16, 16, 1232, 14, 14, 112, 112, 11, 2),  # regnety_160 s3
-            (64, 16, 16, 1232, 14, 14, 112, 112, 11, 4),
-            (32, 30, 30, 512, 28, 28, 64, 64, 8, 2),
-            (8, 9, 9, 33, 7, 7, 11, 11, 3, 4),
-        ]
-        for shape in cases:
-            batch = shape[0]
-            bb = gc._pick_bb(*shape)
-            assert batch % bb == 0
-            assert bb == 1 or corrected_need(bb, *shape[1:]) <= gc._VMEM_BUDGET
-            # maximality: the next larger divisor tile must NOT fit
-            larger = [b for b in (32, 16, 8, 4, 2) if b > bb and batch % b == 0]
-            if larger:
-                assert corrected_need(min(larger), *shape[1:]) > gc._VMEM_BUDGET
-
-        # regression: the stage-3 shape the advice targeted — the old model
-        # accepted bb=4 (its peak under the corrected accounting exceeds
-        # the budget); the fixed model must shrink the tile
-        s3 = (64, 16, 16, 1232, 14, 14, 112, 112, 11, 2)
-        assert old_need(4, *s3[1:]) <= gc._VMEM_BUDGET
-        assert corrected_need(4, *s3[1:]) > gc._VMEM_BUDGET
-        assert gc._pick_bb(*s3) < 4

@@ -193,22 +193,6 @@ def test_aggregator_matches_run_report_on_same_fixture(tmp_path):
     assert snap["events"]["stall"] == rep["events"]["stall"] == 1
 
 
-def test_aggregator_fold_window_fallback_matches_run_report(tmp_path):
-    path = _rank_path(tmp_path, 0)
-    recs = [{"kind": "clock", "rank": 0, "t": 0.0, "unix": 0.0, "mono": 0.0}]
-    for i in range(4):
-        recs.append(_span(0, "fold_window", i * 1.0, 0.8, batch=i * 8, n=8))
-    _jl(path, recs, mode="w")
-    rep = run_report.build_report(str(tmp_path))
-    agg = live.LiveAggregator()
-    rt = live.RunTailer(str(tmp_path))
-    agg.consume(*rt.poll())
-    snap = agg.snapshot(window_s=4.0)
-    assert rep["step_source"] == "fold_window"
-    assert snap["steps"] == rep["step"]["count"] == 4
-    assert snap["step"]["p50_ms"] == rep["step"]["p50_ms"] == 100.0
-
-
 def test_aggregator_windows_reset_but_totals_roll(tmp_path):
     _write_rank(tmp_path, 0, [100.0] * 4)
     agg = live.LiveAggregator()

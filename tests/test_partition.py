@@ -43,8 +43,8 @@ def test_batch_table_covers_loader_keys_and_refuses_strangers():
         assert specs.BATCH_TABLE.spec_for(f"['{key}']") == P("data")
     with pytest.raises(specs.UnknownLeafError):
         specs.BATCH_TABLE.spec_for("['surprise_key']")
-    # fold/accum stacking shifts the batch dim right
-    assert specs.batch_spec("image", leading_dims=2) == P(None, None, "data")
+    # accum stacking shifts the batch dim right
+    assert specs.batch_spec("image", leading_dims=1) == P(None, "data")
 
 
 def test_validate_leaf_spec_conflicting_axes():
@@ -174,7 +174,7 @@ def test_enumeration_contains_legacy_matrix():
     assert "dp2·tp2·ep2·zero1[vit_tiny_moe]" in core
     # legacy ride-along variants survive as generated extras
     by_name = {c["name"]: c for c in cases}
-    assert "fold_accum" in by_name["dp4·tp2[resnet18]"]["extras"]
+    assert "accum" in by_name["dp4·tp2[resnet18]"]["extras"]
     assert "aux_check" in by_name["dp2·tp2·pp2[vit_tiny_moe]"]["extras"]
     assert "flash" in by_name["dp2·pp4[vit_tiny]"]["extras"]
 

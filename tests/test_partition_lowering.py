@@ -228,9 +228,9 @@ def test_zero3_pp_trajectory_matches_stage0():
     _assert_lockstep(traj, base)
 
 
-def test_lowered_fold_and_accum_paths_build():
-    """The folded/accumulated variants build through the same lowering
-    (fold>1 → scan_step; accum routes put_batch to the micro split)."""
+def test_lowered_accum_path_builds():
+    """The accumulating variant builds through the same lowering (accum
+    routes put_batch to the micro split) and is one optimizer step."""
     config.reset_cfg()
     cfg.MODEL.ARCH = "resnet18"
     cfg.MODEL.NUM_CLASSES = 10
@@ -241,14 +241,14 @@ def test_lowered_fold_and_accum_paths_build():
     model = trainer.build_model_from_cfg(topo)
     low = lowering.lower(
         model, construct_optimizer(), 5, mesh=mesh, topology=topo,
-        im_size=32, fold=2, accum=2,
+        im_size=32, accum=2,
     )
-    assert low.scan_step is not None
+    assert low.accum == 2
     state = trainer.create_train_state(
         model, jax.random.key(0), mesh, 32, layout=low.layout
     )
     host = stream_batch(0)
-    stacked = {k: np.stack([v, v]) for k, v in host.items()}
-    state, metrics = low.scan_step(state, low.put_stacked(stacked))
-    losses = np.asarray(metrics["loss"])
-    assert losses.shape == (2,) and np.isfinite(losses).all()
+    batch = low.put_batch(host)
+    assert batch["image"].shape[:2] == (2, host["image"].shape[0] // 2)
+    state, metrics = low.train_step(state, batch)
+    assert np.isfinite(float(metrics["loss"])) and int(state.step) == 1

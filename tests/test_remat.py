@@ -2,7 +2,8 @@
 remat-for-traffic roofline lever, VERDICT r5 #3): ``nn.remat`` changes
 only what is stored vs recomputed for the backward, never the math or the
 param tree, so the train step must be equivalent with the knob on or off.
-The A/B throughput preset is ``tools/ab_bench.py --preset remat``.
+The A/B on the chip is ``benchmark/run.py --workload resnet50.train --set
+program.overrides=…`` with ``TRAIN.REMAT`` in the overrides.
 """
 
 import jax

@@ -35,6 +35,7 @@ from distribuuuu_tpu.serve import protocol
 
 IM = 16
 NC = 10
+MAX_WAIT_MS = 250.0  # the shared engine's batching window
 
 
 def _tiny_cfg():
@@ -66,7 +67,7 @@ def engine(served):
     model, variables = served
     eng = Engine(
         model, variables, IM,
-        max_batch=4, max_wait_ms=250.0, max_queue=32,
+        max_batch=4, max_wait_ms=MAX_WAIT_MS, max_queue=32,
         input_dtype=np.float32,
     )
     eng.start()
@@ -145,32 +146,37 @@ def test_padded_logits_masked_and_match_eval(served, engine):
     assert (got == out_zero[:3]).all()
 
 
-def test_flush_on_full_vs_flush_on_timeout(engine):
-    # full: MAX_BATCH requests flush immediately, far under MAX_WAIT_MS
+def _queue_wait_ms(engine, images):
+    """Serve ``images`` as one window and return ``(snapshot, wait)``: the
+    oldest request's submit-to-dispatch wait by the engine's own metrics
+    (its latency, the window's largest, less the batch's service time)."""
     engine.metrics = ServeMetrics()
-    t0 = time.perf_counter()
-    futs = [engine.submit(img) for img in _float_images(4, seed=2)]
-    for f in futs:
+    for f in [engine.submit(img) for img in images]:
         f.result()
-    full_elapsed = time.perf_counter() - t0
-    assert full_elapsed < 0.2, f"flush-on-full waited {full_elapsed:.3f}s"
     snap = engine.metrics.snapshot()
+    return snap, snap["p99_ms"] - snap["mean_batch_ms"]
+
+
+def test_flush_on_full_vs_flush_on_timeout(engine):
+    """What tells the two flushes apart is how long the batch queued, not
+    how long the forward pass took: a timer flush cannot leave before
+    MAX_WAIT_MS, a full batch does not wait for the timer."""
+    _queue_wait_ms(engine, _float_images(4, seed=1))  # the program's first run
+
+    # full: MAX_BATCH requests flush at once, before the window closes
+    snap, full_wait = _queue_wait_ms(engine, _float_images(4, seed=2))
     assert snap["batches"] == 1 and snap["batch_occupancy"] == 1.0
+    assert full_wait < MAX_WAIT_MS, f"flush-on-full queued {full_wait:.1f} ms"
 
     # timeout: a partial batch waits out MAX_WAIT_MS then flushes padded
-    engine.metrics = ServeMetrics()
-    t0 = time.perf_counter()
-    futs = [engine.submit(img) for img in _float_images(3, seed=3)]
-    for f in futs:
-        f.result()
-    partial_elapsed = time.perf_counter() - t0
-    assert partial_elapsed >= 0.2, (
-        f"partial batch flushed after {partial_elapsed:.3f}s — "
-        "before the 250 ms window"
-    )
-    snap = engine.metrics.snapshot()
+    snap, partial_wait = _queue_wait_ms(engine, _float_images(3, seed=3))
     assert snap["batches"] == 1
     assert snap["batch_occupancy"] == pytest.approx(3 / 4)
+    assert partial_wait >= MAX_WAIT_MS - 0.01, (  # the snapshot rounds to µs
+        f"partial batch flushed after {partial_wait:.1f} ms — "
+        f"before the {MAX_WAIT_MS:.0f} ms window"
+    )
+    assert full_wait < partial_wait
 
 
 def test_backpressure_rejects_at_max_queue(served):

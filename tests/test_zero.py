@@ -122,10 +122,6 @@ def test_zero_step_without_layout_refused():
     model = trainer.build_model_from_cfg()
     with pytest.raises(ValueError, match="ZeRO state layout"):
         trainer.make_train_step(model, construct_optimizer(), topk=5)
-    with pytest.raises(ValueError, match="ZeRO state layout"):
-        trainer.make_scan_train_step(
-            model, construct_optimizer(), topk=5, fold=2
-        )
     config.reset_cfg()
 
 

@@ -5,7 +5,7 @@ matrix (r1-r5: every new parallelism form appended another bespoke
 stanza) with a sweep GENERATED from the partition-layer topology
 registry (parallel/partition/topology.enumerate_topologies): every valid
 (mesh shape × ZeRO stage × representative arch) class on the attached
-device count, each executed as one (or a folded/accumulated) train step
+device count, each executed as one (or an accumulated) train step
 through the ONE partition lowering — built from a YAML mesh stanza
 alone, exactly the way ``train_net.py --cfg`` would.
 
@@ -49,7 +49,7 @@ def legacy_matrix(n_devices: int) -> list[dict]:
     dp = n_devices // tp
     pipe = 4 if n_devices % 4 == 0 else 2
     return [
-        # dp×tp at ZeRO 0/1/3 (resnet18) + fold×accum on the stage-0 case
+        # dp×tp at ZeRO 0/1/3 (resnet18) + accumulation on the stage-0 case
         {"axes": {"data": dp, "model": tp}, "zero": 0, "arch": "resnet18"},
         {"axes": {"data": dp, "model": tp}, "zero": 1, "arch": "resnet18"},
         {"axes": {"data": dp, "model": tp}, "zero": 3, "arch": "resnet18"},
@@ -165,7 +165,7 @@ def _case_extras(topo, arch, zero) -> list[str]:
     the case class instead of hand-listed."""
     extras = []
     if arch == "resnet18" and zero == 0 and topo.model > 1:
-        extras.append("fold_accum")  # folded dispatch + grad accumulation
+        extras.append("accum")  # gradient accumulation
     if arch.endswith("_moe"):
         extras.append("dispatch")  # switch all_to_all strategy
         if topo.pipe > 1:
@@ -298,23 +298,20 @@ def run_trainer_case(case: dict, rng) -> dict:
 
         # extras preserved from the legacy matrix
         extras_run = []
-        if "fold_accum" in case["extras"]:
-            fold_low = lowering.lower(
+        if "accum" in case["extras"]:
+            accum_low = lowering.lower(
                 model, construct_optimizer(), 5, mesh=mesh, topology=topo,
-                im_size=im, fold=2, accum=2,
+                im_size=im, accum=2,
             )
-            stacked = {k: np.stack([v, v]) for k, v in host.items()}
-            fstate, fmetrics = fold_low.scan_step(
+            _, ametrics = accum_low.train_step(
                 trainer.create_train_state(
                     model, jax.random.key(1), mesh, im, layout=low.layout
                 ),
-                fold_low.put_stacked(stacked),
+                accum_low.put_batch(host),
             )
-            jax.block_until_ready(fmetrics["loss"])
-            checks["fold_accum_finite"] = bool(
-                np.isfinite(np.asarray(fmetrics["loss"])).all()
-            )
-            extras_run.append("fold_accum")
+            jax.block_until_ready(ametrics["loss"])
+            checks["accum_finite"] = bool(np.isfinite(float(ametrics["loss"])))
+            extras_run.append("accum")
         if "aux_check" in case["extras"]:
             # a large balancing-aux weight must move the pipelined loss
             cfg.MODEL.MOE.AUX_WEIGHT = 10.0

@@ -77,7 +77,7 @@ def attribute(recs: list[dict], phase: str = "train",
     """Attribution over one phase (and optionally one epoch) of timeline
     records. ``epoch=None`` selects the LAST epoch present — the steady
     state (earlier epochs pay compile). Raises ValueError when no records
-    match (e.g. a folded-dispatch run, which emits none)."""
+    match."""
     recs = [r for r in recs if r.get("phase") == phase]
     if epoch is None and recs:
         epoch = max(r["epoch"] for r in recs)
@@ -85,7 +85,6 @@ def attribute(recs: list[dict], phase: str = "train",
     if not recs:
         raise ValueError(
             f"no timeline records for phase={phase!r} epoch={epoch!r} — "
-            "was the run folded (TRAIN.STEPS_PER_CALL > 1) or "
             "TRAIN.TIMELINE off?"
         )
     recs = sorted(recs, key=lambda r: r["batch"])

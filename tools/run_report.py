@@ -16,8 +16,8 @@ machinery fire — printed as a table and written as ``RUN_REPORT.json``.
 Metrics:
 
 * **step time** — per-rank p50/p90/p99/mean from the per-rank ``step``
-  spans (or ``fold_window`` spans ÷ steps for folded runs); straggler
-  skew = slowest rank p50 / fastest rank p50 (1.0 = lockstep).
+  spans; straggler skew = slowest rank p50 / fastest rank p50 (1.0 =
+  lockstep).
 * **data-wait fraction** — tools/overlap_report.py's exact attribution
   when timeline records exist (reused, not reimplemented); otherwise the
   per-rank ``wait`` span fraction of the pipeline wall.
@@ -85,17 +85,9 @@ def _spans(recs: list[dict], name: str, phase: str | None = None) -> list[dict]:
     return out
 
 
-def _step_durs(recs: list[dict], phase: str) -> tuple[list[float], str]:
-    """Per-step durations (seconds) for one rank: ``step`` spans when the
-    run dispatched per-step; ``fold_window`` spans ÷ steps otherwise."""
-    steps = _spans(recs, "step", phase)
-    if steps:
-        return [float(r["dur"]) for r in steps], "step"
-    folds = _spans(recs, "fold_window", phase)
-    return (
-        [float(r["dur"]) / max(1, int(r.get("n", 1))) for r in folds],
-        "fold_window",
-    )
+def _step_durs(recs: list[dict], phase: str) -> list[float]:
+    """Per-step durations (seconds) for one rank, from its ``step`` spans."""
+    return [float(r["dur"]) for r in _spans(recs, "step", phase)]
 
 
 def _summary_ms(durs: list[float]) -> dict:
@@ -459,12 +451,11 @@ def build_report(run_dir: str, phase: str = "train") -> dict:
         )
 
     # -- cross-rank step time + straggler skew ---------------------------
-    per_rank, pooled, source = {}, [], "step"
+    per_rank, pooled = {}, []
     for rank, recs in sorted(ranks.items()):
-        durs, src = _step_durs(recs, phase)
+        durs = _step_durs(recs, phase)
         if not durs:
             continue
-        source = src
         per_rank[str(rank)] = _summary_ms(durs)
         pooled.extend(durs)
     rank_p50s = [s["p50_ms"] for s in per_rank.values() if s["count"]]
@@ -655,7 +646,6 @@ def build_report(run_dir: str, phase: str = "train") -> dict:
         "run_dir": os.path.abspath(run_dir),
         "phase": phase,
         "n_ranks": len(ranks),
-        "step_source": source,
         "step": step_summary,
         "per_rank_step": per_rank,
         "straggler_skew": straggler,
@@ -763,7 +753,7 @@ def compare(current: dict, baseline: dict, tol_pct: float,
 # ---------------------------------------------------------------- output
 def _print_report(rep: dict) -> None:
     print(f"run {rep['run_dir']}  phase={rep['phase']}  "
-          f"ranks={rep['n_ranks']}  (step spans: {rep['step_source']})")
+          f"ranks={rep['n_ranks']}")
     s = rep["step"]
     print(f"{'step time':<24}{'count':>8}{'mean':>10}{'p50':>10}"
           f"{'p90':>10}{'p99':>10}{'max':>10}   (ms)")
