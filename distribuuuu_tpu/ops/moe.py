@@ -23,11 +23,17 @@ Paths:
   capacity is ample.
 - ``moe_ffn_sorted``: dropless, O(top_k) expert rows a token, for experts
   that are NOT sharded (one chip, or every data rank holding all of them):
-  the (token, slot) assignments are sorted by expert, each projection is one
-  grouped matmul over the sorted rows (``jax.lax.ragged_dot``), and the
-  rows are gathered back to their tokens, weighted and summed. No capacity,
-  nothing dropped. It runs the gated three-matrix bias-free expert
-  (``silu(x W_gate) * (x W_up)) W_down``); ``models/olmoe.py`` is its caller.
+  the (token, slot) assignments are sorted by expert, the gated three-matrix
+  bias-free expert (``silu(x W_gate) * (x W_up)) W_down``) runs on each
+  expert's group of rows, and the rows are gathered back to their tokens,
+  weighted and summed. No capacity, nothing dropped. On the TPU every group
+  starts on a row-tile boundary and the expert is the repo's own Pallas
+  grouped matmuls (``ops/pallas/moe_gmm.py``: six calls forward and
+  backward, the activation and the float32 dW in their epilogues); off the
+  TPU, and where the widths or the rows an expert leave no tile, each
+  projection is one ``jax.lax.ragged_dot`` over the rows back to back,
+  which is also the oracle the kernels are tested against.
+  ``models/olmoe.py`` is its caller.
 
 Gating: ``top_k_from_probs`` renormalizes the selected probabilities to sum
 to 1 (the switch/mixtral convention; ``gpt_nano_moe``, ``vit_tiny_moe``).
@@ -50,6 +56,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from distribuuuu_tpu.ops import pallas as kernel_tier
+from distribuuuu_tpu.ops.pallas import moe_gmm
 
 
 def init_moe_params(key, d_model: int, d_ff: int, num_experts: int):
@@ -504,66 +512,132 @@ def load_max_over_mean(counts):
     return counts.max() / jnp.maximum(counts.mean(), 1e-9)
 
 
+def _pick(table, ids):
+    """``table[ids]`` for a table of one entry an expert, as a compare and a
+    sum: XLA:TPU unrolls a gather from a small table into one slice an
+    index (576 of them for the tile table of ``olmoe_1b_7b``)."""
+    hot = ids[..., None] == jnp.arange(table.shape[0], dtype=ids.dtype)
+    return jnp.where(hot, table, 0).sum(axis=-1, dtype=table.dtype)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _take_sorted(x, order, inverse, top_k: int):
-    """Row ``i`` of the result is token ``order[i] // top_k``: the tokens
-    repeated ``top_k`` times and permuted into expert order. The backward
-    is a gather through the inverse permutation and a sum over the slots,
-    not the scatter-add a plain ``x[ids]`` transposes to."""
-    return x[order // top_k]
+def _take_sorted(x, src, dst, top_k: int):
+    """Row ``i`` of the result is token ``src[i] // top_k``: the tokens
+    repeated ``top_k`` times and permuted into expert order. A ``src`` of
+    ``tokens * top_k`` is a pad row and reads zeros (one row of them behind
+    ``x``: a select over the gathered rows would be a pass of its own). The
+    backward is a gather through ``dst`` (where each (token, slot) went) and
+    a sum over the slots, not the scatter-add a plain ``x[ids]`` transposes
+    to."""
+    pads = src.shape[0] > x.shape[0] * top_k
+    if pads:
+        x = jnp.concatenate([x, jnp.zeros_like(x[:1])])
+    return x[src // top_k]
 
 
-def _take_sorted_fwd(x, order, inverse, top_k):
-    return x[order // top_k], (inverse, x.shape[0])
+def _take_sorted_fwd(x, src, dst, top_k):
+    return _take_sorted(x, src, dst, top_k), (dst, x.shape[0])
 
 
 def _take_sorted_bwd(top_k, res, g):
-    inverse, tokens = res
-    return g[inverse].reshape(tokens, top_k, -1).sum(axis=1), None, None
+    dst, tokens = res
+    return g[dst].reshape(tokens, top_k, -1).sum(axis=1), None, None
 
 
 _take_sorted.defvjp(_take_sorted_fwd, _take_sorted_bwd)
 
 
 @jax.custom_vjp
-def _permute_rows(y, perm, inverse):
-    """``y[perm]`` for a permutation; the backward gathers by ``inverse``."""
-    return y[perm]
+def _rows_back(y, dst, src):
+    """``y[dst]``: every (token, slot)'s row out of the sorted buffer. The
+    backward gathers by ``src``; a pad row gets some real row's cotangent,
+    which nothing reads as the expert's zeros on that row multiply it."""
+    return y[dst]
 
 
-_permute_rows.defvjp(
-    lambda y, perm, inverse: (y[perm], inverse),
-    lambda inverse, g: (g[inverse], None, None),
+_rows_back.defvjp(
+    lambda y, dst, src: (y[dst], (src, dst.shape[0])),
+    lambda res, g: (g[jnp.minimum(res[0], res[1] - 1)], None, None),
 )
 
 
-def sorted_experts(params, x, weights, indices):
+def _gmm_row_tile(rows: int, E: int, d: int, f: int, interpret):
+    """The row tile of the Pallas grouped matmul (``ops/pallas/moe_gmm.py``)
+    for ``rows`` sorted rows, or None where ``lax.ragged_dot`` runs: off the
+    TPU, in a program that may span devices, where a width is no multiple
+    of the 128 lanes, or where an expert averages under one row tile.
+    ``interpret`` not None forces the kernel (the tests). Says which ran,
+    and why, in a ``kernel.select``/``kernel.fallback`` record."""
+    tm = moe_gmm.row_tile(rows, E)
+    reason = ""
+    if d % 128 or f % 128:
+        reason = f"widths {d} and {f}: not both multiples of the 128 lanes"
+    elif tm is None:
+        reason = (f"{rows // E} rows an expert on average: under one row "
+                  f"tile of {moe_gmm.ROW_TILE}")
+    impl = kernel_tier.select(
+        "moe_gmm", supported=not reason, reason=reason,
+        forced=interpret is not None, tm=tm, tk=d, tn=f,
+        pad_row_share=round(E * (tm or 0) / rows, 4),
+        calls_a_step=moe_gmm.CALLS_A_STEP,
+    )
+    return tm if impl == "pallas" else None
+
+
+def sorted_experts(params, x, weights, indices, *, interpret=None):
     """The sorted path's body on ``x`` [T, d] with the router's verdict
-    ``weights``/``indices`` [T, k] already taken: sort, three grouped
-    matmuls, gather back, weight, sum. ``params`` holds ``w_gate``/``w_up``
-    [E, d, f] and ``w_down`` [E, f, d]; the matmuls run in ``x.dtype``."""
+    ``weights``/``indices`` [T, k] already taken: sort, the gated expert on
+    each group, gather back, weight, sum. ``params`` holds ``w_gate``/
+    ``w_up`` [E, d, f] and ``w_down`` [E, f, d]; the matmuls run in
+    ``x.dtype``. On the TPU the groups are laid out on row-tile boundaries
+    and the expert is ``ops/pallas/moe_gmm.expert_ffn``; elsewhere, and for
+    shapes without a tile (:func:`_gmm_row_tile`), three ``lax.ragged_dot``.
+    ``interpret`` True/False forces the kernel, interpreted or compiled."""
     T, k = indices.shape
-    E = params["w_gate"].shape[0]
+    E, d, f = params["w_gate"].shape
+    tm = _gmm_row_tile(T * k, E, d, f, interpret)
     with jax.named_scope("moe_route"):
         flat = indices.reshape(T * k)
         order = jnp.argsort(flat, stable=True).astype(jnp.int32)
         inverse = jnp.argsort(order).astype(jnp.int32)
         sizes = expert_counts(flat, E)
-        rows = _take_sorted(x, order, inverse, k)  # [T*k, d]
+        if tm is None:  # sorted rows, back to back
+            src, dst = order, inverse
+        else:  # every group from a row-tile boundary, zeros in between
+            expert, n_live, starts = moe_gmm.tile_table(sizes, T * k, tm)
+            offsets = jnp.cumsum(sizes) - sizes  # the groups back to back
+            # row r of the buffer, in a tile of group e, is row r - starts[e]
+            # of the group if the group is that long, else a pad row
+            row = jnp.arange(expert.shape[0] * tm, dtype=jnp.int32)
+            within = row.reshape(-1, tm) - _pick(starts, expert)[:, None]
+            pos = _pick(offsets, expert)[:, None] + within
+            src = jnp.where(within < _pick(sizes, expert)[:, None],
+                            order[jnp.minimum(pos, T * k - 1)], T * k)
+            src = src.reshape(-1)
+            dst = inverse + _pick(starts - offsets, flat)
+        rows = _take_sorted(x, src, dst, k)  # [T*k (+ pads), d]
     with jax.named_scope("moe_experts"):
-        w_gate, w_up, w_down = (
-            params[name].astype(x.dtype) for name in ("w_gate", "w_up", "w_down")
-        )
-        hidden = jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, sizes))
-        hidden = hidden * jax.lax.ragged_dot(rows, w_up, sizes)
-        rows = jax.lax.ragged_dot(hidden, w_down, sizes)  # [T*k, d]
+        if tm is None:
+            w_gate, w_up, w_down = (
+                params[name].astype(x.dtype)
+                for name in ("w_gate", "w_up", "w_down")
+            )
+            hidden = jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, sizes))
+            hidden = hidden * jax.lax.ragged_dot(rows, w_up, sizes)
+            rows = jax.lax.ragged_dot(hidden, w_down, sizes)
+        else:
+            rows = moe_gmm.expert_ffn(
+                rows, params["w_gate"], params["w_up"], params["w_down"],
+                expert, n_live, tm,
+                kernel_tier.interpret_mode() if interpret is None else interpret,
+            )
     with jax.named_scope("moe_route"):
-        rows = _permute_rows(rows, inverse, order).reshape(T, k, -1)
+        rows = _rows_back(rows, dst, src).reshape(T, k, -1)
         return (rows * weights[..., None].astype(rows.dtype)).sum(axis=1)
 
 
 def moe_ffn_sorted(params, x, *, top_k: int, mesh=None, data_axis: str = "data",
-                   router_x=None):
+                   router_x=None, interpret=None):
     """Dropless top-k MoE with unsharded gated experts (module docstring).
 
     ``x``: [B, S, d]. ``params``: ``router`` [d, E] and the three expert
@@ -575,7 +649,8 @@ def moe_ffn_sorted(params, x, *, top_k: int, mesh=None, data_axis: str = "data",
     is what the router reads where that is not ``x`` (the same activations
     before their rounding to the compute dtype). On a mesh
     whose ``data_axis`` is populated every data rank sorts its own tokens
-    (``shard_map`` over the batch dim); nothing crosses ranks."""
+    (``shard_map`` over the batch dim); nothing crosses ranks. ``interpret``
+    is :func:`sorted_experts`'s."""
     B, S, d = x.shape
     E = params["router"].shape[-1]
     with jax.named_scope("moe_route"):
@@ -589,7 +664,7 @@ def moe_ffn_sorted(params, x, *, top_k: int, mesh=None, data_axis: str = "data",
         b = x.shape[0]
         out = sorted_experts(
             experts, x.reshape(b * S, d), weights.reshape(b * S, top_k),
-            indices.reshape(b * S, top_k),
+            indices.reshape(b * S, top_k), interpret=interpret,
         )
         return out.reshape(b, S, d)
 
@@ -597,8 +672,13 @@ def moe_ffn_sorted(params, x, *, top_k: int, mesh=None, data_axis: str = "data",
     indices = indices.reshape(B, S, top_k)
     shards = int(dict(mesh.shape).get(data_axis, 1)) if mesh is not None else 1
     if shards > 1 and B % shards == 0:
+        def per_rank(*args, body=body):
+            # one device's tokens: the kernel tier may engage
+            with kernel_tier.single_device_program():
+                return body(*args)
+
         body = jax.shard_map(
-            body, mesh=mesh,
+            per_rank, mesh=mesh,
             in_specs=(P(), P(data_axis), P(data_axis), P(data_axis)),
             out_specs=P(data_axis), check_vma=False,
         )

@@ -1,7 +1,7 @@
 """The Pallas kernel tier (ISSUE 13): fused kernels for the memory-bound
 programs the cost ledger pinned, as ONE subsystem instead of one-offs.
 
-Three kernels, one discipline:
+Four kernels, one discipline:
 
 * ``opt_update``     — fused optimizer update (opt_update.py): ONE HBM
                        pass over params+grads+moments for SGD-momentum
@@ -17,6 +17,14 @@ Three kernels, one discipline:
                        program, online softmax over cache blocks, no
                        [B,H,T,C] logits materialization and no fp32
                        cache copy.
+* ``moe_gmm``        — the sorted experts' grouped matmuls on tile-
+                       aligned groups (moe_gmm.py), with the gated
+                       activation, its backward and the float32 dW in
+                       their epilogues: six calls where XLA's
+                       ``ragged_dot`` ran nine at 53 % of the MXU. It has
+                       NO knob: it is ``auto`` always, its tiles follow
+                       the shapes, and ``ragged_dot`` stays only where no
+                       tile fits (and as the tests' oracle).
 
 Tier discipline (every kernel, no exceptions):
 
@@ -25,11 +33,14 @@ Tier discipline (every kernel, no exceptions):
   ``auto`` engages the kernel on the TPU backend for supported shapes
   and stays on XLA elsewhere; ``pallas`` forces it (interpret mode
   off-TPU — the exact-but-slow CPU test path); ``xla`` is the
-  always-available escape hatch.
+  always-available escape hatch. An op without a knob (``moe_gmm``) is
+  ``auto``, and ``pallas`` where its caller's test forces the kernel.
 * every resolution emits a ``kernel.select`` telemetry record and every
   forced-but-unsupported resolution a ``kernel.fallback`` record with
   the reason (run_report's ``kernels`` section reads both), with a
-  warn-once log so a silently-ignored knob cannot happen.
+  warn-once log so a silently-ignored knob cannot happen. A knobless op
+  has no knob to ask for XLA with, so its ``kernel.fallback`` says every
+  time why XLA ran: the platform, or the shape.
 * every kernel has an interpret-mode CPU path (this repo's tier-1 story
   — the same ``pallas_call`` with ``interpret=True``) and a pinned
   bit-exactness or tolerance A/B test against the XLA reference
@@ -69,8 +80,13 @@ KNOBS = {
     "decode_attn": "DECODE_ATTN",
 }
 
-# ops whose call sites have no mesh to shard_map over (module docstring)
-_NO_SHARD_MAP = ("conv_epilogue", "decode_attn")
+# ops without a knob: ``auto``, or ``pallas`` where the caller forces it
+KNOBLESS = ("moe_gmm",)
+
+# ops that have no shard_map of their own: they engage in a program their
+# caller declared one-device (module docstring; ``moe_gmm``'s caller
+# declares it inside its shard_map over the data axis)
+_NO_SHARD_MAP = ("conv_epilogue", "decode_attn", "moe_gmm")
 
 # process-lifetime emission/warn dedup: one kernel.select per (op, impl,
 # requested) resolution, one kernel.fallback + warning per (op, reason)
@@ -162,16 +178,15 @@ def _emit_once(key, kind: str, **fields) -> None:
     _emitted.add(key)
     from distribuuuu_tpu.telemetry import spans
 
+    # a literal kind a call: the static schema check reads the call sites
     if kind == "kernel.select":
-        spans.emit_event("kernel.select", op=fields["op"],
-                         impl=fields["impl"], requested=fields["requested"])
+        spans.emit_event("kernel.select", **fields)
     else:
-        spans.emit_event("kernel.fallback", op=fields["op"],
-                         requested=fields["requested"],
-                         reason=fields["reason"])
+        spans.emit_event("kernel.fallback", **fields)
 
 
-def select(op: str, *, supported: bool = True, reason: str = "") -> str:
+def select(op: str, *, supported: bool = True, reason: str = "",
+           forced: bool = False, **detail) -> str:
     """Resolve which impl runs for ``op`` right now: ``"pallas"`` or
     ``"xla"``. The ONE policy point of the tier:
 
@@ -183,17 +198,26 @@ def select(op: str, *, supported: bool = True, reason: str = "") -> str:
       CPU/test backends stay on XLA (interpret mode is exact but orders
       of magnitude slower — it is the *test* path, not the auto path).
 
+    An op in ``KNOBLESS`` requests ``pallas`` where its caller ``forced``
+    the kernel (a test) and ``auto`` otherwise, and says in a
+    ``kernel.fallback`` record whenever XLA runs in its place.
+
     Every resolution emits ``kernel.select`` once per process (the
-    run_report ``kernels`` section's source).
+    run_report ``kernels`` section's source), with ``detail`` (the tiles
+    a kernel chose) beside the impl.
     """
-    if op not in KNOBS:
-        raise ValueError(f"unknown kernel op {op!r} — one of {list(KNOBS)}")
+    if op not in KNOBS and op not in KNOBLESS:
+        raise ValueError(
+            f"unknown kernel op {op!r} — one of {list(KNOBS) + list(KNOBLESS)}")
     if supported and op in _NO_SHARD_MAP and compiled_across_devices():
         supported, reason = False, (
             "the program may span several devices, GSPMD cannot partition "
             "a Mosaic call, and this call site has no shard_map"
         )
-    req = requested(op)
+    if op in KNOBLESS:
+        req = "pallas" if forced else "auto"
+    else:
+        req = requested(op)
     if req == "xla":
         impl = "xla"
     elif req == "pallas":
@@ -207,12 +231,25 @@ def select(op: str, *, supported: bool = True, reason: str = "") -> str:
                 from distribuuuu_tpu.utils.logger import get_logger
 
                 get_logger().warning(
-                    "KERNELS.%s=pallas requested but unsupported here "
+                    "%s: pallas requested but unsupported here "
                     "(%s): falling back to the XLA reference path",
-                    KNOBS[op], reason or "unsupported shape",
+                    f"KERNELS.{KNOBS[op]}" if op in KNOBS else op,
+                    reason or "unsupported shape",
                 )
     else:  # auto
-        impl = "pallas" if (supported and not interpret_mode()) else "xla"
-    _emit_once(("sel", op, impl, req), "kernel.select", op=op, impl=impl,
-               requested=req)
+        if supported and interpret_mode():
+            import jax
+
+            supported, reason = False, (
+                f"platform {jax.default_backend()}: off the TPU the kernel "
+                "runs only in the interpreter, which is the tests' path"
+            )
+        impl = "pallas" if supported else "xla"
+        if impl == "xla" and op in KNOBLESS:
+            _emit_once(("fb", op, reason), "kernel.fallback", op=op,
+                       requested=req, reason=reason)
+    if impl == "xla":
+        detail = {}
+    _emit_once(("sel", op, impl, req, *sorted(detail.items())),
+               "kernel.select", op=op, impl=impl, requested=req, **detail)
     return impl

@@ -242,8 +242,8 @@ ANNOTATIONS: dict[str, str] = {
 # traced under (HLO ``op_name`` metadata; the backward carries the forward's
 # scopes under ``bwd``) and the ``name=`` of the Pallas calls, each with the
 # layer of PERF.md's table whose metrics read it. XLA:TPU's own kernels
-# (``ragged-dot-*``, the grouped expert matmuls) drop their scope and are
-# found by name.
+# (``ragged-dot-*``, the grouped expert matmuls where no tile of
+# ``dtpu_moe_gmm_*`` fits) drop their scope and are found by name.
 DEVICE_SCOPES: dict[str, str] = {
     # parallel/partition/lowering.py
     "fwd": "models",
@@ -264,6 +264,9 @@ KERNEL_NAMES: tuple[str, ...] = (
     "dtpu_opt_update_sgd", "dtpu_opt_update_sgd_plain", "dtpu_opt_update_adamw",
     "dtpu_conv_epilogue", "dtpu_decode_attn",
     "dtpu_flash_fwd", "dtpu_flash_dq", "dtpu_flash_dkdv",
+    # ops/pallas/moe_gmm.py, one prefix: _gate_up, _fwd, _act_bwd,
+    # _dx_gate_up, _dw_down, _dw_gate_up (and _dx, _dw of the bare calls)
+    "dtpu_moe_gmm",
 )
 
 

@@ -171,6 +171,21 @@ def test_pallas_calls_have_stable_names_in_the_tpu_lowering():
         jax.ShapeDtypeStruct((2,), jnp.int32),
     )
     assert "dtpu_decode_attn" in attn
+    from distribuuuu_tpu.ops import moe as moe_ops
+
+    experts, width = jax.ShapeDtypeStruct((4, 128, 128), bf16), 128
+    moe = _tpu_text(
+        lambda params, x, w, i: jax.value_and_grad(
+            lambda p, x: moe_ops.sorted_experts(
+                p, x, w, i, interpret=False).astype(jnp.float32).sum(),
+            argnums=(0, 1))(params, x),
+        {"w_gate": experts, "w_up": experts, "w_down": experts},
+        jax.ShapeDtypeStruct((1024, width), bf16), _f32(1024, 2),
+        jax.ShapeDtypeStruct((1024, 2), jnp.int32),
+    )
+    for call in ("gate_up", "fwd", "act_bwd", "dx_gate_up", "dw_down",
+                 "dw_gate_up"):
+        assert f"dtpu_moe_gmm_{call}" in moe, call
 
 
 def _run(n_steps=3):

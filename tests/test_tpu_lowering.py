@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import pytest
 
 from distribuuuu_tpu.ops import flash_attention as fa
+from distribuuuu_tpu.ops import moe as moe_ops
 from distribuuuu_tpu.ops.pallas import conv_epilogue, decode_attn, opt_update
 
 
@@ -180,3 +181,70 @@ def test_flash_fwd_bwd_lowers(data):
         ).astype(jnp.float32).sum()
 
     _lowers_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
+
+
+def _olmoe_experts(tokens=16384, d=2048, f=1024, experts=64, top=8):
+    """``sorted_experts`` at the widths of ``olmoe_1b_7b.train_seq4096``
+    (4 x 4096 tokens a step), the kernel arm forced compiled."""
+    bf16 = jnp.bfloat16
+    avals = (
+        {"w_gate": _f32(experts, d, f), "w_up": _f32(experts, d, f),
+         "w_down": _f32(experts, f, d)},
+        jax.ShapeDtypeStruct((tokens, d), bf16), _f32(tokens, top),
+        jax.ShapeDtypeStruct((tokens, top), jnp.int32),
+    )
+
+    def forward(params, x, weights, indices):
+        return moe_ops.sorted_experts(
+            params, x, weights, indices, interpret=False)
+
+    return forward, avals
+
+
+@pytest.mark.parametrize("direction", ["forward", "gradient"])
+def test_moe_gmm_lowers_at_the_cells_shapes(direction):
+    forward, avals = _olmoe_experts()
+    fn = forward
+    if direction == "gradient":
+        def fn(params, x, weights, indices):
+            return jax.grad(
+                lambda *a: forward(*a, indices).astype(jnp.float32).sum(),
+                argnums=(0, 1, 2))(params, x, weights)
+
+    _lowers_for_tpu(fn, *avals)
+
+
+def test_moe_gmm_compiles_for_the_v5e_under_its_scope(v5e_chip):
+    """Mosaic takes all six calls at the cell's shapes (the resident weight
+    blocks need more than a call's default 16 MiB of VMEM), and each, the
+    backward's too, carries ``moe_experts`` in its ``op_name``: the
+    benchmark's two MoE readers sum that scope."""
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmark.harness.trace import in_scope
+    from distribuuuu_tpu.ops.pallas import moe_gmm
+
+    forward, avals = _olmoe_experts()
+    chip = SingleDeviceSharding(v5e_chip)
+    avals = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), avals)
+
+    def step(params, x, weights, indices):
+        return jax.value_and_grad(
+            lambda *a: forward(*a, indices).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(params, x, weights)
+
+    text = jax.jit(step).lower(*avals).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and moe_gmm.NAME in line]
+    names = {line.split(" = ")[0].split()[-1].lstrip("%").rsplit(".", 1)[0]
+             for line in calls}
+    assert names == {
+        f"{moe_gmm.NAME}_{part}" for part in (
+            "gate_up", "fwd", "act_bwd", "dx_gate_up", "dw_down", "dw_gate_up")
+    }, names
+    assert len(calls) == moe_gmm.CALLS_A_STEP
+    for line in calls:
+        op_name = line.split('op_name="')[1].split('"')[0]
+        assert in_scope(op_name, "moe_experts"), op_name
+    assert "ragged-dot" not in text
