@@ -38,6 +38,12 @@ SAFE_SCOPES = (
     r"resilience/supervisor\.py",  # non-finite guard reads the f32 loss
     r"normalize_in_graph|transforms\.py",  # device-side normalization
     r"moe\.py|router",     # MoE router runs its softmax in f32 by design
+    # the olmoe_*/ouro_* decoders keep a float32 residual stream by design
+    # (models/olmoe.py, models/ouro.py docstrings): norms, rotary and the
+    # stream's additions are float32, and every bf16 branch is upcast into
+    # it, forward and transposed. A block under nn.remat resolves to the
+    # line that applies it, so the file is the finest site there is
+    r"models/(olmoe|ouro)\.py",
     # the self-declaration convention: a DELIBERATE f32 region wraps
     # itself in jax.named_scope("<name>_fp32") at the promotion site
     # (attn_softmax_fp32, se_squeeze_fp32, …) — the code states the

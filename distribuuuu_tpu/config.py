@@ -229,6 +229,10 @@ _C.MODEL.MOE.CAPACITY_FACTOR = 2.0
 # Weight of the router z-loss mean(logsumexp(router logits)^2) that the
 # olmoe_* archs sow (ops/moe.router_z_loss); 0 disables.
 _C.MODEL.MOE.Z_WEIGHT = 0.0
+# beta of the ouro_* archs' expected-exit loss (models/ouro.py):
+# mean[sum_t p_t nll_t - beta H(p)] over the exit distribution p the model's
+# gate emits; the entropy term keeps the gate from collapsing onto one pass.
+_C.MODEL.EXIT_ENTROPY_WEIGHT = 0.05
 
 # ------------------------------- training ----------------------------------
 _C.TRAIN = CfgNode()
@@ -350,9 +354,9 @@ _C.LM = CfgNode()
 # construction with the repack command. Also the learned-position table
 # size, so generation prompts + new tokens must fit under it.
 _C.LM.SEQ_LEN = 256
-# Depth override for archs whose depth is a knob (olmoe_*): 0 keeps the
-# arch's own. One chip holds 1 of OLMoE-1B-7B's 16 layers with its
-# optimizer state (PERF.md section 4).
+# Depth override for archs whose depth is a knob (olmoe_*, ouro_*): 0 keeps
+# the arch's own. One chip holds 1 of OLMoE-1B-7B's 16 layers, or 8 of
+# Ouro-2.6B's 48, with its optimizer state (PERF.md section 4).
 _C.LM.LAYERS = 0
 # -------------------------------- generation --------------------------------
 # Autoregressive serving (lm/generate.py): paged per-request KV cache,

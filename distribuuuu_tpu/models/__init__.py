@@ -40,6 +40,7 @@ from distribuuuu_tpu.models.vit import (  # noqa: F401
 )
 from distribuuuu_tpu.models.gpt import gpt_nano, gpt_nano_moe  # noqa: F401
 from distribuuuu_tpu.models.olmoe import olmoe_1b_7b, olmoe_tiny  # noqa: F401
+from distribuuuu_tpu.models.ouro import ouro_2_6b, ouro_tiny  # noqa: F401
 from distribuuuu_tpu.models.traits import ArchTraits
 
 _REGISTRY = {}
@@ -82,6 +83,10 @@ for _fn in (
     # dropless sorted top-k gated experts, at the published sizes and tiny
     olmoe_1b_7b,
     olmoe_tiny,
+    # Ouro (models/ouro.py): a stack of sandwich-normed dense blocks run
+    # four times over shared weights, a learned exit gate and its loss
+    ouro_2_6b,
+    ouro_tiny,
 ):
     register_model(_fn)
 

@@ -107,6 +107,7 @@ class Attention(nn.Module):
     dtype: Any
     attn_impl: str = "auto"
     mesh: Any = None
+    qk_norm: bool = True  # models/ouro.py's sandwich norms stand in its place
 
     @nn.compact
     def __call__(self, x, positions):
@@ -123,8 +124,12 @@ class Attention(nn.Module):
             return t.reshape(B, S, H, D).transpose(0, 2, 1, 3)
 
         x = x.astype(self.dtype)
-        q = RMSNorm(self.eps, name="q_norm")(proj("q_proj")(x))
-        k = RMSNorm(self.eps, name="k_norm")(proj("k_proj")(x))
+
+        def normed(name):
+            t = proj(f"{name}_proj")(x)
+            return RMSNorm(self.eps, name=f"{name}_norm")(t) if self.qk_norm else t
+
+        q, k = normed("q"), normed("k")
         v = heads(proj("v_proj")(x))
         q = rotary(heads(q), positions, self.rope_theta).astype(self.dtype)
         k = rotary(heads(k), positions, self.rope_theta).astype(self.dtype)
@@ -311,18 +316,16 @@ def olmoe_tiny(num_classes=512, **kw):
     return OLMoE(vocab_size=num_classes, **kw)
 
 
-def kwargs_from_cfg(cfg, topology) -> dict:
+def decoder_kwargs_from_cfg(cfg, topology) -> dict:
     """The widths are the arch's own (``config.json``'s): the config sizes
-    context, depth and the attention entry, nothing else."""
+    context, depth and the attention entry, nothing else. Shared with
+    ``models/ouro.py``."""
     if cfg.DEVICE.ATTN_IMPL not in ("auto", "xla", "flash"):
         raise ValueError(
-            f"DEVICE.ATTN_IMPL={cfg.DEVICE.ATTN_IMPL!r}: the olmoe archs "
-            "accept 'auto', 'xla' or 'flash'"
+            f"DEVICE.ATTN_IMPL={cfg.DEVICE.ATTN_IMPL!r}: {cfg.MODEL.ARCH} "
+            "accepts 'auto', 'xla' or 'flash'"
         )
-    kwargs = {
-        "seq_len": int(cfg.LM.SEQ_LEN), "attn_impl": cfg.DEVICE.ATTN_IMPL,
-        "moe_axis": topology.moe_axis(),
-    }
+    kwargs = {"seq_len": int(cfg.LM.SEQ_LEN), "attn_impl": cfg.DEVICE.ATTN_IMPL}
     if int(cfg.LM.LAYERS) > 0:
         kwargs["depth"] = int(cfg.LM.LAYERS)
     if topology.data > 1:
@@ -330,6 +333,10 @@ def kwargs_from_cfg(cfg, topology) -> dict:
 
         kwargs["mesh"] = mesh_lib.mesh_from_cfg(cfg)
     return kwargs
+
+
+def kwargs_from_cfg(cfg, topology) -> dict:
+    return {**decoder_kwargs_from_cfg(cfg, topology), "moe_axis": topology.moe_axis()}
 
 
 olmoe_1b_7b.traits = olmoe_tiny.traits = ArchTraits(

@@ -1,0 +1,305 @@
+"""Ouro (arXiv:2510.25741, "Scaling Latent Reasoning via Looped Language
+Models"; HF ``model_type`` ``ouro``): a decoder whose stack of L blocks runs
+R = ``total_ut_steps`` times over ONE set of parameters, with a learned gate
+that says after which pass a token may leave.
+
+    h(0) = Embed(tokens)
+    for pass t = 1..R:                      the same blocks in every pass
+        u = h(t-1)
+        for every block:
+            u = u + RMSNorm(Attn(RMSNorm(u)))       attn_norm, attn_post_norm
+            u = u + RMSNorm(MLP(RMSNorm(u)))        mlp_norm, mlp_post_norm
+        h(t)   = RMSNorm(u; final_norm)     the head reads it, pass t+1 starts from it
+        z(t)   = h(t) . w_exit + b_exit     one number a token a pass
+    lam = sigmoid(z);  p_1 = lam_1,  p_t = lam_t prod_{j<t}(1 - lam_j),
+    p_R = prod_{j<R}(1 - lam_j):            after which pass the token exits
+    loss = mean over tokens of [sum_t p_t nll_t - beta H(p)]   (the paper's Stage I)
+
+* the four norms of a block are HF's ``input_layernorm``,
+  ``input_layernorm_2``, ``post_attention_layernorm`` and
+  ``post_attention_layernorm_2``, in that order; ``RMSNorm``, ``rotary`` and
+  the attention entry are ``models/olmoe.py``'s, without its QK-norm.
+* ``MLP(n) = W_down(silu(W_gate n) * (W_up n))``, bias-free, dense.
+* parameters and the residual stream are float32, the matmuls read
+  ``dtype``; norms, gate, exit distribution and loss are float32.
+* every block application is recomputed in the backward from its float32
+  input, which is all it keeps (``recompute``): R x L applications hold R
+  times the activations a parameter, and 32 of them at 4096 tokens do not
+  fit a 16 GB chip beside the AdamW state otherwise (PERF.md section 4).
+  Passes and layers are Python loops: a ``while`` shows in a device trace
+  as one operation AND its body's, and every scope sum would count twice.
+
+As ``models/olmoe.py`` the head is left to the step: ``hidden_only=True``
+returns ``(h [B, R, S, d], z [B, R, S])``, and the step's loss is
+:meth:`Ouro.head_loss`, which stacks the R states along the batch for ONE
+walk of ``ops/token_head.weighted_loss`` (one ``[d, V]`` gradient buffer,
+not R). A plain call returns the last pass's ``[B, S, V]`` logits.
+"""
+
+from __future__ import annotations
+
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from distribuuuu_tpu.models.layers import head_dtype
+from distribuuuu_tpu.models.olmoe import (
+    Attention,
+    RMSNorm,
+    _normal,
+    decoder_kwargs_from_cfg,
+)
+from distribuuuu_tpu.models.traits import ArchTraits
+from distribuuuu_tpu.ops import token_head
+
+
+class MLP(nn.Module):
+    dim: int
+    hidden: int
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        def proj(name, width):
+            return nn.Dense(
+                width, use_bias=False, dtype=self.dtype,
+                param_dtype=jnp.float32, kernel_init=_normal(), name=name,
+            )
+
+        x = x.astype(self.dtype)
+        gated = nn.silu(proj("gate_proj", self.hidden)(x)) * proj("up_proj", self.hidden)(x)
+        return proj("down_proj", self.dim)(gated)
+
+
+class Block(nn.Module):
+    dim: int
+    num_heads: int
+    mlp_hidden: int
+    eps: float
+    rope_theta: float
+    dtype: Any
+    attn_impl: str
+    mesh: Any
+
+    @nn.compact
+    def __call__(self, x, positions):
+        """``x`` is the float32 residual stream; each branch is normed on
+        its way in AND on its way out (the sandwich)."""
+
+        def norm(name):
+            return RMSNorm(self.eps, name=name)
+
+        with jax.named_scope("attn"):
+            x = x + norm("attn_post_norm")(Attention(
+                self.dim, self.num_heads, self.eps, self.rope_theta, self.dtype,
+                self.attn_impl, self.mesh, qk_norm=False, name="attn",
+            )(norm("attn_norm")(x), positions))
+        with jax.named_scope("mlp"):
+            x = x + norm("mlp_post_norm")(
+                MLP(self.dim, self.mlp_hidden, self.dtype, name="mlp")(
+                    norm("mlp_norm")(x)))
+        return x
+
+
+class ExitGate(nn.Module):
+    """``h . w + b`` a token, float32 on the VPU (a [d, 1] matmul would run
+    at the MXU's default precision for 2 d operations a token)."""
+
+    @nn.compact
+    def __call__(self, h):
+        kernel = self.param("kernel", _normal(), (h.shape[-1], 1), jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros, (1,), jnp.float32)
+        return (h * kernel[:, 0]).sum(-1) + bias[0]
+
+
+def exit_log_probs(z):
+    """``log p`` of the exit distribution over the R passes (axis 1 of ``z
+    [B, R, S]``) from the gates' logits, through ``log_sigmoid``: finite,
+    with a finite gradient, however far a gate has gone to 0 or to 1."""
+    log_stay = jnp.cumsum(jax.nn.log_sigmoid(-z), axis=1)  # log prod_{j<=t}(1 - lam_j)
+    stayed = jnp.pad(log_stay[:, :-1], ((0, 0), (1, 0), (0, 0)))  # ... j < t
+    log_exit = stayed[:, :-1] + jax.nn.log_sigmoid(z[:, :-1])
+    return jnp.concatenate([log_exit, stayed[:, -1:]], axis=1)  # the last takes the rest
+
+
+_planned: set = set()
+
+
+def _say_plan(model, batch: int, seq: int) -> None:
+    """One ``loop.plan`` record a shape, at trace time, beside
+    ``kernel.select``: what the loop keeps for the backward and what it
+    computes again."""
+    key = (model.depth, model.passes, batch, seq, model.dim, model.recompute)
+    if key in _planned:
+        return
+    _planned.add(key)
+    from distribuuuu_tpu.telemetry import spans
+
+    applications = model.depth * model.passes
+    spans.emit_event(
+        "loop.plan", layers=model.depth, passes=model.passes,
+        block_applications=applications,
+        kept_bytes=applications * batch * seq * model.dim * 4 if model.recompute else None,
+        recomputed="every block application, from its float32 input"
+        if model.recompute else "nothing",
+    )
+
+
+class Ouro(nn.Module):
+    """Defaults are ``config.json``'s of ByteDance/Ouro-2.6B."""
+
+    vocab_size: int = 49152
+    seq_len: int = 4096  # the paper's Stage-I context; config.json allows 65,536 positions
+    dim: int = 2048
+    depth: int = 48
+    passes: int = 4  # total_ut_steps
+    num_heads: int = 16
+    mlp_hidden: int = 5632
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1e6
+    exit_beta: float = 0.05  # MODEL.EXIT_ENTROPY_WEIGHT
+    dtype: Any = jnp.bfloat16
+    attn_impl: str = "auto"
+    mesh: Any = None
+    # a block application keeps its input and nothing else (see above)
+    recompute: bool = True
+    # positions of every row the head takes at a time; its rows are the
+    # batch's sequences R times over
+    head_chunk: int = 512
+
+    @nn.compact
+    def __call__(self, tokens, train: bool = False, hidden_only: bool = False):
+        B, S = tokens.shape
+        if S > self.seq_len:
+            raise ValueError(
+                f"input length {S} exceeds the context LM.SEQ_LEN={self.seq_len}"
+            )
+        _say_plan(self, B, S)
+        x = nn.Embed(
+            self.vocab_size, self.dim, name="tok_embed",
+            dtype=head_dtype(self.dtype), param_dtype=jnp.float32,
+            embedding_init=_normal(),
+        )(tokens)
+        positions = jnp.arange(S, dtype=jnp.int32)
+        block = nn.remat(Block) if self.recompute else Block
+        blocks = [
+            block(
+                self.dim, self.num_heads, self.mlp_hidden, self.rms_norm_eps,
+                self.rope_theta, self.dtype, self.attn_impl, self.mesh,
+                name=f"Block_{i}",
+            ) for i in range(self.depth)
+        ]
+        final_norm = RMSNorm(self.rms_norm_eps, name="final_norm")
+        exit_gate = ExitGate(name="exit_gate")
+        states, gates = [], []
+        for _ in range(self.passes):
+            with jax.named_scope("loop_pass"):
+                for apply_block in blocks:
+                    x = apply_block(x, positions)
+                x = final_norm(x)
+            states.append(x.astype(self.dtype))
+            with jax.named_scope("exit_gate"):
+                gates.append(exit_gate(x))
+        kernel = self.param(
+            "head", _normal(), (self.dim, self.vocab_size), jnp.float32
+        )
+        if hidden_only:
+            return jnp.stack(states, axis=1), jnp.stack(gates, axis=1)
+        return jnp.einsum(
+            "bsd,dv->bsv", states[-1], kernel.astype(self.dtype),
+            preferred_element_type=head_dtype(self.dtype),
+        )
+
+    # ------------------------------------------------ partition-layer hooks
+    @staticmethod
+    def head_kernel(params):
+        return params["head"]
+
+    @staticmethod
+    def eval_hidden(outputs):
+        """What evaluation's head reads: the last pass."""
+        return outputs[0][:, -1]
+
+    def head_loss(self, outputs, kernel, labels, *, topk):
+        """``(loss, hits, step metrics)`` of the expected-exit loss.
+
+        The R states go through the head as R x B rows of ONE
+        ``weighted_loss`` walk, each token weighted by ``p_t / N`` as a
+        constant: that carries the gradient to the stack and the head. The
+        gate receives ``nll_t`` as its cotangent through ``sum((p - sg(p))
+        * nll) / N``, whose value is 0."""
+        states, z = outputs
+        B, R, S, d = states.shape
+        n = labels.size
+        with jax.named_scope("exit_gate"):
+            log_p = exit_log_probs(z)
+            p = jnp.exp(log_p)
+            constant = jax.lax.stop_gradient(p)
+        with jax.named_scope("lm_head"):
+            ce, (nll, rank) = token_head.weighted_loss(
+                states.reshape(B * R, S, d), kernel, jnp.repeat(labels, R, axis=0),
+                constant.reshape(B * R, S) / n, chunk=self.head_chunk,
+            )
+        with jax.named_scope("exit_gate"):
+            nll = nll.reshape(B, R, S)
+            entropy = -(p * log_p).sum(1).mean()
+            loss = ce + ((p - constant) * nll).sum() / n - self.exit_beta * entropy
+            steps = jnp.arange(1, R + 1, dtype=p.dtype)[None, :, None]
+            extra = {
+                "ce": ce, "exit_entropy": entropy,
+                "exit_step_mean": (p * steps).sum(1).mean(),
+                **{f"ce_pass_{t}": nll[:, t].mean() for t in range(R)},
+            }
+        last = rank.reshape(B, R, S)[:, -1]
+        hits = [(last < k).mean(dtype=jnp.float32) * 100.0 for k in topk]
+        return loss, hits, extra
+
+    def dummy_input(self):
+        return jnp.zeros((2, min(8, self.seq_len)), jnp.int32)
+
+    def param_spec_table(self):
+        from distribuuuu_tpu.parallel.partition import specs
+
+        return specs.lm_spec_table()
+
+    def batch_spec_table(self):
+        from distribuuuu_tpu.parallel.partition import specs
+
+        return specs.TOKEN_BATCH_TABLE
+
+
+def ouro_2_6b(num_classes=49152, **kw):
+    """Ouro-2.6B at its published sizes (2.67 B parameters at depth 48, run
+    four times; ``depth`` is the one knob a single chip has to turn down)."""
+    return Ouro(vocab_size=num_classes, **kw)
+
+
+def ouro_tiny(num_classes=512, **kw):
+    """The same loop at a size the CPU tests run: 64 wide, 4 heads of 16, an
+    MLP of 176, 3 layers run 4 times; the head in chunks of 48, which do not
+    divide its 128 positions."""
+    for key, value in dict(
+        seq_len=128, dim=64, depth=3, num_heads=4, mlp_hidden=176, head_chunk=48,
+    ).items():
+        kw.setdefault(key, value)
+    return Ouro(vocab_size=num_classes, **kw)
+
+
+def _kwargs_from_cfg(cfg, topology) -> dict:
+    """``models/olmoe.py``'s (context, depth, attention entry, mesh) and the
+    recipe's entropy weight; the pass count is the arch's own."""
+    return {**decoder_kwargs_from_cfg(cfg, topology),
+            "exit_beta": float(cfg.MODEL.EXIT_ENTROPY_WEIGHT)}
+
+
+ouro_2_6b.traits = ouro_tiny.traits = ArchTraits(
+    token_batch=True, batch_norm=False,
+    mesh_axes=("data",),  # attention per device, as models/olmoe.py
+    kwargs_from_cfg=_kwargs_from_cfg,
+    serve_refusal=(
+        "trains only: serving a looped stack takes a key/value cache a pass "
+        "and the exit gate in the decode loop, which lm/generate.py lacks"
+    ),
+)

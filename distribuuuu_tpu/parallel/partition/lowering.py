@@ -316,9 +316,21 @@ def train_step_body(model, optimizer, topk: int, accum_steps: int = 1,
     # ``head_kernel``, ``head_chunk``): head, loss and hits ``head_chunk``
     # positions at a time (ops/token_head.py), never the [B, S, V] logits.
     # Image models and the gpt_* archs have no such hook and keep the
-    # program they had.
+    # program they had. Where the loss is more than one cross-entropy over
+    # one head (models/ouro.py: a head a pass, weighted by an exit
+    # distribution the model emits), the model's ``head_loss`` takes what
+    # ``hidden_only`` returned and gives (loss, hits, step metrics).
     head_kernel = getattr(model, "head_kernel", None)
     loss_chunk = getattr(model, "head_chunk", 0)
+
+    def one_head_loss(hidden, kernel, labels, *, topk):
+        with jax.named_scope("lm_head"):
+            loss, hits = token_head.loss_and_accuracy(
+                hidden, kernel, labels, topk=topk, chunk=loss_chunk,
+            )
+        return loss, hits, {"ce": loss}
+
+    head_loss = getattr(model, "head_loss", one_head_loss)
     # FAULTS.NAN_STEP (utils/faults.py): trace-time gate — None (the
     # common case) compiles nothing in; an int multiplies the loss by
     # where(step==k, NaN, 1), poisoning loss AND grads at exactly step k.
@@ -342,14 +354,11 @@ def train_step_body(model, optimizer, topk: int, accum_steps: int = 1,
             )
             if head_kernel is not None:
                 # the hits take the logits' place on the way to step_metrics
-                with jax.named_scope("lm_head"):
-                    loss, logits = token_head.loss_and_accuracy(
-                        logits, head_kernel(params), labels,
-                        topk=(1, topk), chunk=loss_chunk,
-                    )
+                loss, logits, extra = head_loss(
+                    logits, head_kernel(params), labels, topk=(1, topk)
+                )
         if head_kernel is None:
-            loss = cross_entropy(logits, labels)
-        extra = {} if head_kernel is None else {"ce": loss}
+            loss, extra = cross_entropy(logits, labels), {}
         aux = jax.tree.leaves(mutated.get("intermediates", {}))
         if aux and moe_aux_weight:
             loss = loss + moe_aux_weight * sum(aux) / len(aux)
@@ -467,6 +476,9 @@ def make_eval_step(model, topk: int, layout=None):
 
     head_kernel = getattr(model, "head_kernel", None)
     loss_chunk = getattr(model, "head_chunk", 0)
+    # of what ``hidden_only`` returned, the state the head evaluates
+    # (models/ouro.py: the last pass's)
+    eval_hidden = getattr(model, "eval_hidden", lambda hidden: hidden)
 
     def eval_step(state: TrainState, batch):
         params = gather_entry(state.params)
@@ -477,6 +489,8 @@ def make_eval_step(model, topk: int, layout=None):
                 train=False,
                 **({} if head_kernel is None else {"hidden_only": True}),
             )
+            if head_kernel is not None:
+                logits = eval_hidden(logits)
         mask = batch["mask"]
         labels = batch["label"]
         if head_kernel is not None:

@@ -314,6 +314,12 @@ def lm_spec_table(moe_axis: str = "model") -> SpecTable:
             SpecRule(r"moe/router$", P()),
             SpecRule(r"(_norm|/[qk]_norm)/scale$", P()),
             SpecRule(r"^head$", P(None, "model")),
+            # the ouro_* family (models/ouro.py): attention, norm scales
+            # and head by the rules above; the dense gated MLP column-
+            # parallel in and row-parallel out; the exit gate replicated
+            SpecRule(r"mlp/(gate|up)_proj/kernel$", P(None, "model")),
+            SpecRule(r"mlp/down_proj/kernel$", P("model")),
+            SpecRule(r"exit_gate/(kernel|bias)$", P()),
         ),
         default=None,  # unmatched leaves keep their annotation/replication
         strict=False,

@@ -146,6 +146,12 @@ KINDS: dict[str, frozenset] = {
     # a forced-but-unsupported site degrading to the XLA reference, with
     # the disqualifying reason (also warn-once logged)
     "kernel.fallback": frozenset({"op", "requested", "reason"}),
+    # one per traced shape of a looped stack (models/ouro.py): R passes over
+    # L blocks, what the R x L block applications keep for the backward
+    # (bytes a step) and what the backward computes again
+    "loop.plan": frozenset(
+        {"layers", "passes", "block_applications", "kept_bytes", "recomputed"}
+    ),
     # -- live observability plane (telemetry/live.py, tools/monitor.py) --
     # one windowed aggregate per monitor tick (MONITOR.jsonl)
     "monitor.snapshot": frozenset(
@@ -256,6 +262,10 @@ DEVICE_SCOPES: dict[str, str] = {
     "moe": "models",
     "moe_route": "models",
     "moe_experts": "kernels",
+    # models/ouro.py (``attn`` and ``lm_head`` as above)
+    "mlp": "models",
+    "exit_gate": "models",
+    "loop_pass": "models",
     # ops/pallas/opt_update.py
     "opt_tile": "kernels",
     "opt_kernel": "kernels",

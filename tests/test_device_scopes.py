@@ -223,3 +223,51 @@ def test_vjp_form_is_bit_identical_to_value_and_grad(monkeypatch):
     for a, b in zip(jax.tree.leaves(scoped), jax.tree.leaves(reference)):
         np.testing.assert_array_equal(a, b)
     assert not np.array_equal(scoped[2][0], scoped[2][-1])  # it trained
+
+
+def test_the_looped_decoders_step_carries_its_scopes():
+    """``ouro_tiny``'s step through ``lower`` (``models/ouro.py``): every
+    scope ``telemetry/schema.py`` declares for it is in the compiled
+    program, the backward's second run of the blocks carries
+    ``rematted_computation`` (what ``models.recompute_ms_per_step`` sums) and
+    holds attention and MLP but neither the head nor the gate, and the gate's
+    scope covers both its projection (the model) and its distribution (the
+    loss hook)."""
+    import distribuuuu_tpu.config as config
+    from distribuuuu_tpu.telemetry import schema
+
+    config.reset_cfg()
+    cfg.MODEL.ARCH, cfg.MODEL.NUM_CLASSES = "ouro_tiny", 512
+    cfg.LM.SEQ_LEN, cfg.DEVICE.COMPUTE_DTYPE = 64, "float32"
+    cfg.OPTIM.OPTIMIZER = "adamw"
+    cfg.KERNELS.OPT_UPDATE = "pallas"
+    try:
+        mesh = mesh_lib.build_mesh()
+        topology = topo_lib.from_cfg(cfg)
+        lowered = lowering.lower(
+            trainer.build_model_from_cfg(topology), construct_optimizer(), topk=5,
+            mesh=mesh, topology=topology, im_size=IM,
+        )
+        state, batch = lowered.abstract_args(8)
+    finally:
+        config.reset_cfg()
+    batch = {k: jax.ShapeDtypeStruct((8, 64), jnp.int32, sharding=v.sharding)
+             for k, v in batch.items()}
+    paths = list(trace.op_names_from_hlo(
+        lowered.train_step.lower(state, batch).compile().as_text()).values())
+
+    def among(items, *scopes):
+        return [p for p in items if all(trace.in_scope(p, s) for s in scopes)]
+
+    for scope in ("fwd", "bwd", "lm_head", "optimizer_update", "opt_kernel",
+                  "attn", "mlp", "exit_gate", "loop_pass"):
+        assert scope in schema.DEVICE_SCOPES and among(paths, scope), scope
+    again = among(paths, "rematted_computation")
+    assert again == among(again, "bwd", "loop_pass")
+    assert among(again, "attn") and among(again, "mlp")
+    assert not among(again, "lm_head") and not among(again, "exit_gate")
+    assert among(paths, "exit_gate", "Ouro") and among(paths, "exit_gate", "Ouro.head_loss")
+    # the blocks' own backward is under ``checkpoint`` and not recomputed
+    assert len(among(paths, "bwd", "mlp")) > len(among(again, "mlp"))
+    # the head's one walk sits outside every pass
+    assert not among(paths, "lm_head", "loop_pass")

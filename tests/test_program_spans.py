@@ -27,6 +27,9 @@ sys.path.insert(0, os.path.join(REPO, "tools"))
 
 @pytest.fixture
 def registry():
+    # a sink that an earlier test of this worker left open (any test that
+    # runs ``trainer.train_model`` does) is not this file's to assert on
+    spans.close_telemetry()
     reg = telemetry.get_registry()
     reg.reset()
     yield reg

@@ -248,3 +248,68 @@ def test_moe_gmm_compiles_for_the_v5e_under_its_scope(v5e_chip):
         op_name = line.split('op_name="')[1].split('"')[0]
         assert in_scope(op_name, "moe_experts"), op_name
     assert "ragged-dot" not in text
+
+
+def test_ouro_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
+    """The step of ``ouro_2_6b.train_seq4096`` (``config/ouro_2_6b.yaml``:
+    published widths, 4096 tokens, all four passes) compiled for the chip at
+    1 of the cell's 8 layers (8 compile in five minutes here, 1 in under
+    one). What the benchmark's readers find in it: every scope they sum, the
+    three flash kernels by name, the recomputed forward by the ``op_name``
+    ``jax.checkpoint``'s transpose gives it, and no ``while`` (a loop in a
+    device trace is one operation AND its body's)."""
+    from jax.sharding import SingleDeviceSharding
+
+    import distribuuuu_tpu.config as config
+    from benchmark.harness.trace import in_scope, op_names_from_hlo
+    from distribuuuu_tpu import trainer
+    from distribuuuu_tpu.config import cfg
+    from distribuuuu_tpu.ops import pallas as kernel_tier
+    from distribuuuu_tpu.parallel import mesh as mesh_lib
+    from distribuuuu_tpu.parallel.partition import lowering, topology
+    from distribuuuu_tpu.utils.optim import construct_optimizer
+
+    # the tier asks the live backend (a CPU with 8 devices here): steer it to
+    # what it resolves to on one chip
+    monkeypatch.setattr(kernel_tier, "interpret_mode", lambda: False)
+    monkeypatch.setattr(kernel_tier, "compiled_across_devices", lambda: False)
+    config.reset_cfg()
+    config.merge_from_file("config/ouro_2_6b.yaml")
+    cfg.LM.LAYERS, cfg.MESH.DATA, cfg.KERNELS.OPT_UPDATE = 1, 1, "pallas"
+    try:
+        layout = topology.from_cfg(cfg, n_devices=1)
+        lowered = lowering.lower(
+            trainer.build_model_from_cfg(layout), construct_optimizer(), 5,
+            mesh=mesh_lib.build_mesh(data=1, devices=[v5e_chip]),
+            topology=layout, im_size=cfg.TRAIN.IM_SIZE,
+        )
+        state, batch = lowered.abstract_args(1)
+    finally:
+        config.reset_cfg()
+    chip = SingleDeviceSharding(v5e_chip)
+    batch = {k: jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=chip)
+             for k in batch}
+    text = lowered.train_step.lower(state, batch).compile().as_text()
+    assert " while(" not in text and " conditional(" not in text
+    paths = list(op_names_from_hlo(text).values())
+    for scope in ("fwd", "bwd", "attn", "mlp", "exit_gate", "lm_head",
+                  "optimizer_update", "opt_kernel", "loop_pass"):
+        assert any(in_scope(p, scope) for p in paths), scope
+    calls = {}
+    for line in text.splitlines():
+        if "custom-call(" in line and "dtpu_" in line:
+            name = line.split(" = ")[0].split()[-1].lstrip("%").rsplit(".", 1)[0]
+            calls.setdefault(name, []).append(line.split('op_name="')[1].split('"')[0])
+    # 4 block applications: the forward kernel runs in the forward and again
+    # in the backward's recomputation, the two backward kernels once
+    assert {k: len(v) for k, v in calls.items() if "flash" in k} == {
+        "dtpu_flash_fwd": 8, "dtpu_flash_dq": 4, "dtpu_flash_dkdv": 4}
+    assert len(calls["dtpu_opt_update_adamw"]) == len(jax.tree.leaves(state.params))
+    again = [p for p in calls["dtpu_flash_fwd"] if in_scope(p, "rematted_computation")]
+    assert len(again) == 4 and all(in_scope(p, "bwd") and in_scope(p, "attn") for p in again)
+    for kernel in ("dtpu_flash_dq", "dtpu_flash_dkdv"):
+        assert not any(in_scope(p, "rematted_computation") for p in calls[kernel])
+    # the recomputed forward is the blocks' alone: attention and MLP, no head
+    recomputed = [p for p in paths if in_scope(p, "rematted_computation")]
+    assert any(in_scope(p, "mlp") for p in recomputed)
+    assert not any(in_scope(p, "lm_head") or in_scope(p, "exit_gate") for p in recomputed)
