@@ -33,8 +33,10 @@ Tier discipline (every kernel, no exceptions):
   ``auto`` engages the kernel on the TPU backend for supported shapes
   and stays on XLA elsewhere; ``pallas`` forces it (interpret mode
   off-TPU — the exact-but-slow CPU test path); ``xla`` is the
-  always-available escape hatch. An op without a knob (``moe_gmm``) is
-  ``auto``, and ``pallas`` where its caller's test forces the kernel.
+  always-available escape hatch. An op without a knob (``moe_gmm``, and
+  ``flash_attn``: ops/flash_attention.py, whose callers choose it by
+  ``attn_impl``) is ``auto``, and ``pallas`` where its caller forces the
+  kernel (``interpret=``: the tests).
 * every resolution emits a ``kernel.select`` telemetry record and every
   forced-but-unsupported resolution a ``kernel.fallback`` record with
   the reason (run_report's ``kernels`` section reads both), with a
@@ -81,12 +83,15 @@ KNOBS = {
 }
 
 # ops without a knob: ``auto``, or ``pallas`` where the caller forces it
-KNOBLESS = ("moe_gmm",)
+# (``flash_attn`` is ops/flash_attention.py's two kernels, outside this
+# package; it resolves here so that its record sits beside the others)
+KNOBLESS = ("moe_gmm", "flash_attn")
 
 # ops that have no shard_map of their own: they engage in a program their
 # caller declared one-device (module docstring; ``moe_gmm``'s caller
-# declares it inside its shard_map over the data axis)
-_NO_SHARD_MAP = ("conv_epilogue", "decode_attn", "moe_gmm")
+# declares it inside its shard_map over the data axis, as ``flash_attn``'s
+# does where it was handed a mesh)
+_NO_SHARD_MAP = ("conv_epilogue", "decode_attn", "moe_gmm", "flash_attn")
 
 # process-lifetime emission/warn dedup: one kernel.select per (op, impl,
 # requested) resolution, one kernel.fallback + warning per (op, reason)

@@ -102,7 +102,8 @@ def _ring_flash_fits(q, k):
 
     d = q.shape[-1]
     L = q.shape[2]
-    return d <= 128 and k.shape[2] == L and fa.fits_vmem(L, d)
+    # itemsize 4: _flash_state_update hands v over in float32
+    return d <= 128 and k.shape[2] == L and fa.fits_vmem(L, d, 4)
 
 
 def ring_self_attention(

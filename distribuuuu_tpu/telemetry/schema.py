@@ -141,7 +141,11 @@ KINDS: dict[str, frozenset] = {
     # -- Pallas kernel tier (ops/pallas/, ISSUE 13) ----------------------
     # one per kernel-impl resolution (ops.pallas.select): which impl
     # actually runs for an op vs what KERNELS.* requested — the source
-    # of run_report's `kernels` section
+    # of run_report's `kernels` section. Where the kernel runs, a knobless
+    # op adds what it chose, once a traced shape: `moe_gmm` tm, tk, tn,
+    # pad_row_share, calls_a_step; `flash_attn` L, d, causal, blk_q, blk_k,
+    # and a sequence's tiles_visited, tiles_crossed (by the diagonal or the
+    # padding), tiles_masked (those that run the mask), bwd_matmuls_a_tile
     "kernel.select": frozenset({"op", "impl", "requested"}),
     # a forced-but-unsupported site degrading to the XLA reference, with
     # the disqualifying reason (also warn-once logged)
@@ -273,7 +277,10 @@ DEVICE_SCOPES: dict[str, str] = {
 KERNEL_NAMES: tuple[str, ...] = (
     "dtpu_opt_update_sgd", "dtpu_opt_update_sgd_plain", "dtpu_opt_update_adamw",
     "dtpu_conv_epilogue", "dtpu_decode_attn",
-    "dtpu_flash_fwd", "dtpu_flash_dq", "dtpu_flash_dkdv",
+    "dtpu_flash_fwd", "dtpu_flash_bwd",
+    # empty calls, kept for the benchmark's ``trace_kernels`` alone
+    # (ops/flash_attention._under_the_old_name)
+    "dtpu_flash_dq", "dtpu_flash_dkdv",
     # ops/pallas/moe_gmm.py, one prefix: _gate_up, _fwd, _act_bwd,
     # _dx_gate_up, _dw_down, _dw_gate_up (and _dx, _dw of the bare calls)
     "dtpu_moe_gmm",

@@ -1,15 +1,16 @@
 """Pallas flash attention (ops/flash_attention.py) — VERDICT r1 item 4.
 
 Correctness on the CPU mesh runs the kernels through the Pallas interpreter
-(``interpret=True``) against the dense reference — forward AND both backward
-kernels (dq, dk/dv), including the padded (L not a block multiple) case
-whose masked rows/keys are the easy thing to get wrong.
+(``interpret=True``) against the dense float32 reference — the forward AND
+the fused backward (dq, dk, dv from one walk), including the padded (L not a
+block multiple) case whose masked keys are the easy thing to get wrong, and
+the split of every walk into masked and wholly kept tiles.
 
-The performance claim (≥1.2× over the lax.scan blockwise path at
-[4, 3, 4096, 64] on a v5e — measured 1.23× fwd+bwd with the DCE-safe
-harness, tools/flash_bench.py / PERF.md) is hardware-gated and not
+Speed (tools/flash_bench.py on a v5e, PERF.md) is hardware-gated and not
 asserted here.
 """
+
+import json
 
 import numpy as np
 import jax
@@ -41,23 +42,44 @@ def test_forward_matches_reference(B, H, L, D):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
-@pytest.mark.parametrize("L", [512, 300])
-def test_gradients_match_reference(L):
-    rng = np.random.default_rng(1)
+def _grads(fn, q, k, v, w):
+    return jax.grad(
+        lambda q, k, v: jnp.sum(fn(q, k, v) * w), argnums=(0, 1, 2)
+    )(q, k, v)
+
+
+@pytest.mark.parametrize(
+    "B,H,L,D,causal,blk_q,blk_k",
+    [
+        (1, 2, 512, 64, False, 256, 256),   # block multiple
+        (1, 2, 300, 64, False, 128, 128),   # padded keys
+        (1, 2, 512, 64, True, 256, 256),    # diagonal tiles and kept tiles
+        (1, 2, 300, 64, True, 384, 128),    # causal and padding compose
+        (2, 2, 512, 128, True, 256, 128),   # blk_q > blk_k; dq zeroed a head
+        (2, 2, 512, 128, True, 128, 256),   # blk_q < blk_k
+        (1, 2, 257, 128, True, 128, 384),   # a class token's padding: lp 384
+        (1, 2, 257, 64, False, 384, 128),
+        (2, 3, 384, 128, False, 128, 384),  # several heads, no mask at all
+    ],
+)
+def test_gradients_match_reference(B, H, L, D, causal, blk_q, blk_k):
+    """The fused backward (one walk: the scores, ``exp`` and ``dp`` once a
+    tile; dq accumulated across the key blocks of each batch·head) against
+    the dense float32 reference."""
+    rng = np.random.default_rng(1 + causal)
     q, k, v = (
-        jnp.asarray(rng.standard_normal((1, 2, L, 64)), jnp.float32)
+        jnp.asarray(rng.standard_normal((B, H, L, D)), jnp.float32)
         for _ in range(3)
     )
-    w = jnp.asarray(rng.standard_normal((64,)), jnp.float32)
-
-    def loss(fn):
-        return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
-
-    gf = jax.grad(
-        loss(lambda q, k, v: fa.flash_attention(q, k, v, interpret=True, **BLK)),
-        argnums=(0, 1, 2),
-    )(q, k, v)
-    gr = jax.grad(loss(reference_attention), argnums=(0, 1, 2))(q, k, v)
+    w = jnp.asarray(rng.standard_normal((D,)), jnp.float32)
+    assert fa._resolve_blocks(L, blk_q, blk_k)[:2] == (blk_q, blk_k)
+    gf = _grads(
+        lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=causal, interpret=True, blk_q=blk_q, blk_k=blk_k),
+        q, k, v, w,
+    )
+    gr = _grads(
+        lambda q, k, v: reference_attention(q, k, v, causal=causal), q, k, v, w)
     for a, b, name in zip(gf, gr, ("dq", "dk", "dv")):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=5e-5, err_msg=name
@@ -96,36 +118,6 @@ def test_causal_forward_matches_reference(B, H, L, D):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
-@pytest.mark.parametrize("L", [512, 300])
-def test_causal_gradients_match_reference(L):
-    """All three causal backward paths (dq block-skip, dk/dv start-offset,
-    diagonal masks) against the dense causal reference."""
-    rng = np.random.default_rng(5)
-    q, k, v = (
-        jnp.asarray(rng.standard_normal((1, 2, L, 64)), jnp.float32)
-        for _ in range(3)
-    )
-    w = jnp.asarray(rng.standard_normal((64,)), jnp.float32)
-
-    def loss(fn):
-        return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
-
-    gf = jax.grad(
-        loss(lambda q, k, v: fa.flash_attention(
-            q, k, v, causal=True, interpret=True, **BLK
-        )),
-        argnums=(0, 1, 2),
-    )(q, k, v)
-    gr = jax.grad(
-        loss(lambda q, k, v: reference_attention(q, k, v, causal=True)),
-        argnums=(0, 1, 2),
-    )(q, k, v)
-    for a, b, name in zip(gf, gr, ("dq", "dk", "dv")):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), atol=5e-5, err_msg=name
-        )
-
-
 def test_causal_matches_blockwise_scan():
     """The causal kernel against the scan path it previously fell back to
     (the VERDICT r3 #4 'exactness test vs the causal blockwise path')."""
@@ -141,43 +133,48 @@ def test_causal_matches_blockwise_scan():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
-def test_with_lse_matches_and_differentiates():
+@pytest.mark.parametrize("L,causal", [(256, False), (300, True)])
+def test_with_lse_matches_and_differentiates(L, causal):
     """flash_attention_with_lse: the lse output equals the dense
     log-sum-exp, and a loss that consumes BOTH outputs gets exact
-    gradients (the lse cotangent folds into the kernels' delta — the
-    property ring attention's flash block updates rely on)."""
+    gradients (the non-zero lse cotangent folds into the fused backward's
+    delta — the property ring attention's flash block updates rely on),
+    padded and causal too."""
     rng = np.random.default_rng(7)
-    B, H, L, D = 1, 2, 256, 32
+    B, H, D = 1, 2, 32
     q, k, v = (
         jnp.asarray(rng.standard_normal((B, H, L, D)), jnp.float32)
         for _ in range(3)
     )
     scale = D ** -0.5
 
-    o, lse = fa.flash_attention_with_lse(q, k, v, interpret=True, **BLK)
-    s = jnp.einsum(
-        "bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32
-    ) * scale
+    def scores(q, k):
+        s = jnp.einsum(
+            "bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32
+        ) * scale
+        return jnp.where(jnp.tril(jnp.ones((L, L), bool)), s, -1e30) if causal else s
+
+    o, lse = fa.flash_attention_with_lse(
+        q, k, v, causal=causal, interpret=True, **BLK)
     np.testing.assert_allclose(
-        np.asarray(lse), np.asarray(jax.nn.logsumexp(s, axis=-1)),
+        np.asarray(lse), np.asarray(jax.nn.logsumexp(scores(q, k), axis=-1)),
         atol=2e-5,
     )
     np.testing.assert_allclose(
-        np.asarray(o), np.asarray(reference_attention(q, k, v)), atol=2e-5
+        np.asarray(o), np.asarray(reference_attention(q, k, v, causal=causal)),
+        atol=2e-5,
     )
 
     wo = jnp.asarray(rng.standard_normal((D,)), jnp.float32)
 
     def loss_flash(q, k, v):
         o, lse = fa.flash_attention_with_lse(
-            q, k, v, interpret=True, **BLK
+            q, k, v, causal=causal, interpret=True, **BLK
         )
         return jnp.sum(o * wo) + jnp.sum(jnp.sin(lse))
 
     def loss_ref(q, k, v):
-        s = jnp.einsum(
-            "bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32
-        ) * scale
+        s = scores(q, k)
         o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
         return jnp.sum(o * wo) + jnp.sum(jnp.sin(jax.nn.logsumexp(s, -1)))
 
@@ -187,6 +184,101 @@ def test_with_lse_matches_and_differentiates():
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=5e-5, err_msg=name
         )
+
+
+def _kept_keys(lp, L, causal):
+    """What the mask means, a query row at a time: row i keeps the keys
+    ``[0, end[i]]``."""
+    return np.minimum(np.arange(lp), L - 1) if causal else np.full(lp, L - 1)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_the_kept_key_intervals_are_the_kernels_mask(causal):
+    """``_kept_keys`` (the next test's yardstick) against ``_keep``, the
+    expression both kernels mask with, score by score."""
+    for L in (1, 127, 128, 129, 300, 384):
+        lp = fa._round_up(L, 128)
+        keep = np.asarray(fa._keep(
+            jnp.arange(lp)[:, None], jnp.arange(lp)[None, :], L, causal))
+        end = _kept_keys(lp, L, causal)
+        assert (keep == (np.arange(lp)[None, :] <= end[:, None])).all(), L
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_wholly_kept_tiles_are_exactly_those_the_mask_leaves_untouched(causal):
+    """Every ``(L, blk_q, blk_k)`` the block-choosing function can return
+    up to L = 8192 (each 128-multiple and its neighbours, d = 64 and 128),
+    and some it cannot: the forward's walk (``_key_tiles``) and the
+    backward's (``_first_query_tile``) visit exactly the tiles that keep a
+    score, the tiles counted as wholly kept are exactly those that drop
+    none, and ``tile_counts`` counts both."""
+    shapes = {
+        (L, *fa._resolve_blocks(L, *fa.choose_blocks(L, d, causal))[:2])
+        for m in range(128, 8193, 128) for L in (m - 1, m, m + 1)
+        for d in (64, 128) if L <= 8192
+    } | {(300, 128, 384), (300, 384, 128), (1024, 256, 512), (1000, 512, 128),
+         (257, 128, 128), (4097, 384, 128)}
+    assert len(shapes) > 190
+    for L, blk_q, blk_k in sorted(shapes):
+        lp = fa._round_up(L, 128)
+        nq, nk = lp // blk_q, lp // blk_k
+        end = _kept_keys(lp, L, causal).reshape(nq, blk_q)
+        first = np.arange(nk) * blk_k  # a key tile's first and last key
+        last = first + blk_k - 1
+        any_kept = first[None, :] <= end.max(axis=1)[:, None]  # [nq, nk]
+        all_kept = last[None, :] <= end.min(axis=1)[:, None]
+        assert any_kept[:, 0].all()  # every row sees key 0
+        for j in range(nq):
+            full, hi = fa._key_tiles(j, blk_q, blk_k, lp, L, causal)
+            assert (any_kept[j] == (np.arange(nk) < hi)).all(), (L, blk_q, blk_k, j)
+            assert (all_kept[j] == (np.arange(nk) < full)).all(), (L, blk_q, blk_k, j)
+        for j in range(nk):
+            lo = fa._first_query_tile(j, blk_q, blk_k, causal)
+            assert (any_kept[:, j] == (np.arange(nq) >= lo)).all(), (L, blk_q, blk_k, j)
+        assert fa.tile_counts(L, blk_q, blk_k, causal) == (
+            any_kept.sum(), (any_kept & ~all_kept).sum())
+
+
+def test_select_and_fallback_say_what_ran_with_which_tiles(tmp_path):
+    """``kernel.select`` op ``flash_attn`` once a traced shape, with the
+    blocks and tile counts; ``kernel.fallback`` with the reason where
+    ``blockwise_attention`` runs instead."""
+    from distribuuuu_tpu.ops import pallas as tier
+    from distribuuuu_tpu.telemetry import schema, spans
+
+    q = jax.ShapeDtypeStruct((1, 16, 4096, 128), jnp.bfloat16)
+    long = jax.ShapeDtypeStruct((1, 1, 65536, 128), jnp.bfloat16)
+    tier.reset_selection()
+    path = spans.setup_telemetry(str(tmp_path), rank=0)
+    try:
+        for interpret, x in ((None, q), (True, q), (True, q), (False, long)):
+            jax.eval_shape(lambda q: fa.flash_attention(
+                q, q, q, causal=True, interpret=interpret), x)
+    finally:
+        spans.close_telemetry()
+        tier.reset_selection()
+    records = [json.loads(line) for line in open(path)]
+    for record in records:
+        if record.get("kind", "").startswith("kernel."):
+            schema.validate_record(record)
+    selected = [r for r in records if r.get("kind") == "kernel.select"]
+    fell = [r for r in records if r.get("kind") == "kernel.fallback"]
+    assert [(r["op"], r["impl"], r["requested"]) for r in selected] == [
+        ("flash_attn", "xla", "auto"), ("flash_attn", "pallas", "pallas"),
+        ("flash_attn", "xla", "pallas")]
+    blk_q, blk_k, _ = fa._resolve_blocks(4096, *fa.choose_blocks(4096, 128, True))
+    visited, crossed = fa.tile_counts(4096, blk_q, blk_k, True)
+    detail = {k: selected[1][k] for k in (
+        "L", "d", "causal", "blk_q", "blk_k", "tiles_visited", "tiles_crossed",
+        "tiles_masked", "bwd_matmuls_a_tile")}
+    assert detail == {
+        "L": 4096, "d": 128, "causal": True, "blk_q": blk_q, "blk_k": blk_k,
+        "tiles_visited": visited, "tiles_crossed": crossed,
+        "tiles_masked": visited, "bwd_matmuls_a_tile": 5}
+    assert crossed < visited < (4096 // blk_q) * (4096 // blk_k)
+    assert "blk_q" not in selected[0]
+    assert ["platform cpu" in r["reason"] for r in fell] == [True, False]
+    assert "fits_vmem" in fell[1]["reason"]
 
 
 def test_auto_resolution_threshold():

@@ -699,6 +699,10 @@ def test_run_report_kernels_section(tmp_path):
         {"kind": "clock", "rank": 0, "t": 0.0, "unix": 0.0, "mono": 0.0},
         {"kind": "kernel.select", "rank": 0, "t": 1.0, "op": "opt_update",
          "impl": "pallas", "requested": "auto"},
+        {"kind": "kernel.select", "rank": 0, "t": 1.0, "op": "flash_attn",
+         "impl": "pallas", "requested": "auto", "L": 4096, "d": 128,
+         "causal": True, "blk_q": 512, "blk_k": 512, "tiles_visited": 36,
+         "tiles_crossed": 8, "tiles_masked": 36, "bwd_matmuls_a_tile": 5},
         {"kind": "kernel.fallback", "rank": 0, "t": 1.0,
          "op": "conv_epilogue", "requested": "pallas",
          "reason": "kernel (3, 3) is not pointwise (1, 1)"},
@@ -710,7 +714,13 @@ def test_run_report_kernels_section(tmp_path):
             f.write(json.dumps(r) + "\n")
     rep = run_report.build_report(str(tmp_path))
     kern = rep["kernels"]
-    assert kern["selected"]["opt_update"]["impl"] == "pallas"
+    assert kern["selected"]["opt_update"] == {
+        "impl": "pallas", "requested": "auto"}
+    # a knobless op's record carries what it chose
+    flash = kern["selected"]["flash_attn"]
+    assert (flash["impl"], flash["blk_q"], flash["blk_k"]) == ("pallas", 512, 512)
+    assert (flash["tiles_visited"], flash["tiles_crossed"]) == (36, 8)
+    assert flash["bwd_matmuls_a_tile"] == 5 and "rank" not in flash
     assert kern["fallbacks"][0]["op"] == "conv_epilogue"
 
 

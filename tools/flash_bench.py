@@ -20,11 +20,20 @@ Methodology (both hazards burned earlier rounds):
    the reported ratio is the MEDIAN of per-round ratios (paired samples),
    with per-path median ± [min, max] spread printed alongside.
 
-Usage (defaults are the canonical ViT-Ti/1024px shape [4, 3, 4096, 64]):
+Usage (defaults are the ViT-Ti/1024px shape [4, 3, 4096, 64], non-causal;
+the token decoders' is ``--heads 16 --dim 128 --causal``, [4, 16, 4096, 128]
+for OLMoE's cell and ``--batch 1`` for Ouro's):
 
     python tools/flash_bench.py [--batch 4] [--heads 3] [--seq 4096]
-        [--dim 64] [--iters 20] [--rounds 5] [--skip-dense]
-        [--blk-q 1024] [--blk-k 1024]
+        [--dim 64] [--causal] [--iters 20] [--rounds 5] [--skip-dense]
+        [--blk-q N] [--blk-k N] [--sweep] [--baseline FILE]
+
+``--blk-q``/``--blk-k`` default to what ``flash_attention.choose_blocks``
+picks for the shape (printed). ``--sweep`` times the flash path alone at
+every pair of {256, 512, 1024}, forward and forward+backward, beside the
+chosen pair: what ``choose_blocks`` was set from. ``--baseline FILE`` loads
+another checkout's ``ops/flash_attention.py`` as path ``base`` (its own
+default blocks) into the same interleaved rounds.
 
 ``--kernel decode`` (ISSUE 13) switches the harness to the kernel
 tier's fused decode attention (ops/pallas/decode_attn.py) vs the dense
@@ -113,27 +122,19 @@ def report(tag: str, times: dict, flops: float | None = None):
             f" ({flops / med[name] / 1e12:5.1f} TFLOP/s)" if flops else ""
         )
         print(
-            f"{tag} {name:5s}: median {med[name] * 1e3:7.3f} ms "
+            f"{tag} {name:9s}: median {med[name] * 1e3:7.3f} ms "
             f"[{min(ts) * 1e3:.3f}, {max(ts) * 1e3:.3f}]{extra}"
         )
-    if "flash" in times and "scan" in times:
-        ratios = sorted(
-            s / f for s, f in zip(times["scan"], times["flash"])
-        )
-        print(
-            f"{tag} flash-vs-scan per-round ratios: "
-            f"median {statistics.median(ratios):.2f}x "
-            f"[{ratios[0]:.2f}, {ratios[-1]:.2f}]"
-        )
-    if "flash" in times and "dense" in times:
-        ratios = sorted(
-            d / f for d, f in zip(times["dense"], times["flash"])
-        )
-        print(
-            f"{tag} flash-vs-dense per-round ratios: "
-            f"median {statistics.median(ratios):.2f}x "
-            f"[{ratios[0]:.2f}, {ratios[-1]:.2f}]"
-        )
+    for other in ("scan", "dense", "base"):
+        if "flash" in times and other in times:
+            ratios = sorted(
+                o / f for o, f in zip(times[other], times["flash"])
+            )
+            print(
+                f"{tag} flash-vs-{other} per-round ratios: "
+                f"median {statistics.median(ratios):.2f}x "
+                f"[{ratios[0]:.2f}, {ratios[-1]:.2f}]"
+            )
     return med
 
 
@@ -216,13 +217,20 @@ def main():
                          "windows under-amortize the dispatch floor)")
     ap.add_argument("--rounds", type=int, default=5,
                     help="interleaved timing rounds (paired ratios)")
-    ap.add_argument("--blk-q", type=int, default=None)
+    ap.add_argument("--blk-q", type=int, default=None,
+                    help="default: what choose_blocks picks for the shape")
     ap.add_argument("--blk-k", type=int, default=None)
+    ap.add_argument("--sweep", action="store_true",
+                    help="the flash path alone over every pair of "
+                         "{256, 512, 1024}, beside the chosen pair")
+    ap.add_argument("--baseline", default=None, metavar="FILE",
+                    help="another checkout's ops/flash_attention.py, timed "
+                         "as path 'base' in the same rounds")
     ap.add_argument("--skip-dense", action="store_true",
                     help="skip the O(L²)-memory dense baseline")
     ap.add_argument("--causal", action="store_true",
-                    help="benchmark the causal paths (r4 kernels with "
-                         "block-skip vs causal scan/dense)")
+                    help="the causal paths: the kernels' diagonal walk "
+                         "against the causal scan and dense")
     args = ap.parse_args()
 
     from distribuuuu_tpu.config import cfg
@@ -253,21 +261,49 @@ def main():
     # causal touches only the lower triangle — half the score/PV work
     flops = 2 * 2 * B * H * L * L * D * (0.5 if args.causal else 1.0)
 
-    fkw = {"causal": args.causal}
-    if args.blk_q:
-        fkw["blk_q"] = args.blk_q
-    if args.blk_k:
-        fkw["blk_k"] = args.blk_k
-    paths = {
-        "flash": lambda q, k, v: fa.flash_attention(q, k, v, **fkw),
-        "scan": lambda q, k, v: ra.blockwise_attention(
-            q, k, v, causal=args.causal
-        ),
-    }
-    if not args.skip_dense:
-        paths["dense"] = lambda q, k, v: ra.reference_attention(
-            q, k, v, causal=args.causal
-        )
+    chosen = fa.choose_blocks(L, D, args.causal)
+    blk_q, blk_k = args.blk_q or chosen[0], args.blk_k or chosen[1]
+    print(f"blocks: choose_blocks({L}, {D}, causal={args.causal}) = {chosen}; "
+          f"running blk_q={blk_q} blk_k={blk_k}, resolved "
+          f"{fa._resolve_blocks(L, blk_q, blk_k)[:2]}")
+
+    def flash(blk_q, blk_k, module=fa):
+        return lambda q, k, v: module.flash_attention(
+            q, k, v, causal=args.causal, blk_q=blk_q, blk_k=blk_k)
+
+    def scan(q, k, v):
+        return ra.blockwise_attention(q, k, v, causal=args.causal)
+
+    paths = {"flash": flash(blk_q, blk_k)}
+    if args.baseline:
+        import importlib.util
+
+        spec = importlib.util.spec_from_file_location("flash_base", args.baseline)
+        base = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(base)
+        paths["base"] = lambda q, k, v: base.flash_attention(
+            q, k, v, causal=args.causal)
+    if args.sweep:
+        sizes = (256, 512, 1024)
+        paths.update({f"{a}x{b}": flash(a, b) for a in sizes for b in sizes})
+    else:
+        paths["scan"] = scan
+        if not args.skip_dense:
+            paths["dense"] = lambda q, k, v: ra.reference_attention(
+                q, k, v, causal=args.causal
+            )
+
+    # the kernels against the scan on this device, once: output and all
+    # three gradients (bf16 in, so ~1e-2 of the largest value is rounding)
+    def out_and_grads(fn):
+        loss = lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) ** 2)  # noqa: E731
+        return (fn(q, k, v), *jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v))
+
+    for name, a, b in zip(("o", "dq", "dk", "dv"), out_and_grads(paths["flash"]),
+                          out_and_grads(scan)):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        print(f"check   flash-vs-scan {name}: max|d| {float(jnp.abs(a - b).max()):.3e} "
+              f"of max|ref| {float(jnp.abs(b).max()):.3e}")
 
     fwd_runners = {
         n: make_fwd_runner(fn, q, k, v, args.iters)
@@ -279,7 +315,8 @@ def main():
         n: make_bwd_runner(fn, q, k, v, args.iters)
         for n, fn in paths.items()
     }
-    report("fwd+bwd", interleaved(bwd_runners, args.rounds))
+    # useful work: the forward's two matmuls and the backward's four
+    report("fwd+bwd", interleaved(bwd_runners, args.rounds), 3 * flops)
 
 
 if __name__ == "__main__":

@@ -401,9 +401,15 @@ def _campaign_section(ranks: dict[int, list[dict]]) -> dict | None:
     }
 
 
+# what every kernel.select record holds; the rest is the op's own detail
+_RECORD_KEYS = ("kind", "rank", "t", "v", "op", "impl", "requested")
+
+
 def _kernels_section(ranks: dict[int, list[dict]]) -> dict | None:
     """The Pallas kernel tier (ops/pallas/): which impl actually ran per
-    op (``kernel.select``), every forced-but-unsupported fallback with
+    op (``kernel.select``; with what a knobless op says it chose: the tiles
+    of ``moe_gmm``, the blocks and tile counts of ``flash_attn``, the
+    record traced last), every forced-but-unsupported fallback with
     its reason (``kernel.fallback``), and — when the run carried
     ``kernel_*``-labeled cost records (tools/kernel_bench.py emits them)
     — the per-kernel A/B deltas. None when the run never consulted the
@@ -418,6 +424,7 @@ def _kernels_section(ranks: dict[int, list[dict]]) -> dict | None:
                 op = str(r.get("op"))
                 selected[op] = {
                     "impl": r.get("impl"), "requested": r.get("requested"),
+                    **{k: v for k, v in r.items() if k not in _RECORD_KEYS},
                 }
             elif kind == "kernel.fallback":
                 fallbacks.append({
