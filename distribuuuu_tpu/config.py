@@ -354,10 +354,22 @@ _C.LM = CfgNode()
 # construction with the repack command. Also the learned-position table
 # size, so generation prompts + new tokens must fit under it.
 _C.LM.SEQ_LEN = 256
-# Depth override for archs whose depth is a knob (olmoe_*, ouro_*): 0 keeps
-# the arch's own. One chip holds 1 of OLMoE-1B-7B's 16 layers, or 8 of
-# Ouro-2.6B's 48, with its optimizer state (PERF.md section 4).
+# Depth override for archs whose depth is a knob (olmoe_*, ouro_*, glm_*): 0
+# keeps the arch's own. One chip holds 1 of OLMoE-1B-7B's 16 layers, 8 of
+# Ouro-2.6B's 48, or 1 + 4 of GLM-4.7-Flash's 47 as an eighth of each, with
+# its optimizer state (PERF.md section 4).
 _C.LM.LAYERS = 0
+# One chip's share of an expert-parallel group (the glm_* archs,
+# models/glm_moe.py): SHARE_CHIPS chips share every layer and this program
+# is rank SHARE_RANK of them. It holds 1/SHARE_CHIPS of each layer's routed
+# experts and of the embedding's and head's MODEL.NUM_CLASSES rows (token
+# ids must lie in its rows); attention and the shared expert are whole. The
+# exchange of tokens across the group's chips is not run. 0 keeps the arch's
+# own (1: the whole model).
+_C.LM.SHARE_CHIPS = 0
+# Which of the LM.SHARE_CHIPS chips that share a layer this program is: it
+# holds that rank's block of the experts and of the vocabulary's rows.
+_C.LM.SHARE_RANK = 0
 # -------------------------------- generation --------------------------------
 # Autoregressive serving (lm/generate.py): paged per-request KV cache,
 # prefill/decode split, continuous batching. The serve engine's AOT-bucket

@@ -41,6 +41,7 @@ from distribuuuu_tpu.models.vit import (  # noqa: F401
 from distribuuuu_tpu.models.gpt import gpt_nano, gpt_nano_moe  # noqa: F401
 from distribuuuu_tpu.models.olmoe import olmoe_1b_7b, olmoe_tiny  # noqa: F401
 from distribuuuu_tpu.models.ouro import ouro_2_6b, ouro_tiny  # noqa: F401
+from distribuuuu_tpu.models.glm_moe import glm_4_7_flash, glm_moe_tiny  # noqa: F401
 from distribuuuu_tpu.models.traits import ArchTraits
 
 _REGISTRY = {}
@@ -87,6 +88,11 @@ for _fn in (
     # four times over shared weights, a learned exit gate and its loss
     ouro_2_6b,
     ouro_tiny,
+    # GLM-4.7-Flash (models/glm_moe.py): latent attention, a sigmoid router
+    # with a balancing bias, a shared expert, the MTP module; one chip's
+    # share of an expert-parallel group
+    glm_4_7_flash,
+    glm_moe_tiny,
 ):
     register_model(_fn)
 

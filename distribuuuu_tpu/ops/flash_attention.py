@@ -28,16 +28,20 @@ multiply of the float32 ``dq``/``dk`` sums.
 
 Block sizes come from the shape (:func:`choose_blocks`, swept on a v5e
 with ``tools/flash_bench.py``); L is padded to the 128 lanes internally
-with masked keys. Head dim ≤ 128. The backward's resident set
-(:func:`_vmem_bytes`: q, dO, dq and its accumulator whole) bounds the
-length: ~19k tokens at D ≤ 128 in bf16, half that in float32. Longer
-sequences, any off-TPU call and a program that may span devices route to
-``blockwise_attention`` — same exact-softmax math from HBM-resident
-tensors — and say so in a ``kernel.fallback`` record; the kernel path says
-its blocks and tile counts in ``kernel.select`` (op ``flash_attn``).
+with masked keys. Head dim: any up to 128 (padded to the lanes in VMEM), and
+whole lane tiles past it (256: latent attention's score and value dim; q, k
+and v share one shape). The backward's resident set (:func:`_vmem_bytes`: q,
+dO, dq and its accumulator whole) bounds the length: ~19k tokens at D ≤ 128
+in bf16, ~9.2k at D = 256 (8192 tokens hold 41.0 of the 48 MiB budget at
+512² blocks), half that in float32. Longer sequences, any off-TPU call and
+a program that may span devices route to ``blockwise_attention`` — same
+exact-softmax math from HBM-resident tensors — and say so in a
+``kernel.fallback`` record; the kernel path says its blocks and tile counts
+in ``kernel.select`` (op ``flash_attn``).
 
 Shapes it runs at: the token decoders' ``[B, 16, 4096, 128]`` causal
-(``models/olmoe.py``, and Ouro's blocks through it) and ViT-Ti at 1024px
+(``models/olmoe.py``, and Ouro's blocks through it), latent attention's
+``[1, 20, 8192, 256]`` causal (``models/glm_moe.py``) and ViT-Ti at 1024px
 ``[B, 3, 4096, 64]``, non-causal.
 """
 
@@ -93,7 +97,13 @@ def choose_blocks(L: int, d: int, causal: bool, itemsize: int = 2):
     (a 256 on either side costs 17–66 %, a 1024 6–9 %: the diagonal tiles'
     waste grows with them); at ``[4, 3, 4096, 64]`` non-causal 1024² is
     (4.6 % under 512², no diagonal to waste), while its float32 tiles fit
-    beside the sequence. Causal at d ≤ 64 was not swept and takes 512²."""
+    beside the sequence. Causal at d ≤ 64 was not swept and takes 512².
+    At ``[1, 20, 8192, 256]`` causal (PERF.md section 6, PR 32) 512² is
+    within 0.5 % of the best forward (5.60 ms against 256 × 1024's 5.57) and
+    the best forward + backward whose resident set fits the budget (17.12
+    ms; 1024² is 1.4 % under it at 61 MiB by :func:`_vmem_bytes`, past the 48
+    a sequence may hold; a 256 on either side costs 6–21 %): d = 256 takes
+    512² by the same line as d = 128."""
     big = (1024, 1024)
     if not causal and d <= 64 and _vmem_bytes(
             _round_up(L, 128), d, itemsize, *big) <= _VMEM_BUDGET:
@@ -475,6 +485,14 @@ def _blocks(q, k, v, causal, blk_q, blk_k):
     return blk_q or chosen[0], blk_k or chosen[1], itemsize
 
 
+def _check_head_dim(d: int) -> None:
+    """Up to 128 any head dim runs (it is padded to the lanes in VMEM); past
+    that whole lane tiles only, as the blocks' last dimension."""
+    if d > 128 and d % 128:
+        raise ValueError(
+            f"head_dim {d} > 128 is no multiple of the 128 lanes: not supported")
+
+
 def flash_attention(
     q, k, v, *, scale: float | None = None, causal: bool = False,
     interpret: bool | None = None, blk_q: int | None = None,
@@ -504,8 +522,7 @@ def flash_attention(
     record once a traced shape.
     """
     b, _, L, d = q.shape
-    if d > 128:
-        raise ValueError(f"head_dim {d} > 128: lane tiling not supported")
+    _check_head_dim(d)
     scale = d ** -0.5 if scale is None else scale
     shards = int(dict(mesh.shape).get("data", 1)) if mesh is not None else 1
     if shards > 1 and b % shards == 0:
@@ -570,8 +587,7 @@ def flash_attention_with_lse(
     path); off-TPU with ``interpret=None`` runs the Pallas interpreter.
     """
     d = q.shape[-1]
-    if d > 128:
-        raise ValueError(f"head_dim {d} > 128: lane tiling not supported")
+    _check_head_dim(d)
     scale = d ** -0.5 if scale is None else scale
     if interpret is None:
         interpret = jax.default_backend() != "tpu"

@@ -4,7 +4,7 @@ Beyond the reference's capability set (DDP-only, SURVEY.md §2.3) — expert
 parallelism completes the framework's parallelism matrix (DP/TP/SP/PP/EP)
 because distributed scale is a first-class goal here.
 
-Three execution paths and two gating conventions.
+Three execution paths and three gating conventions.
 
 Paths:
 
@@ -22,23 +22,32 @@ Paths:
   (standard switch semantics), so it matches the exact path only when
   capacity is ample.
 - ``moe_ffn_sorted``: dropless, O(top_k) expert rows a token, for experts
-  that are NOT sharded (one chip, or every data rank holding all of them):
-  the (token, slot) assignments are sorted by expert, the gated three-matrix
-  bias-free expert (``silu(x W_gate) * (x W_up)) W_down``) runs on each
-  expert's group of rows, and the rows are gathered back to their tokens,
-  weighted and summed. No capacity, nothing dropped. On the TPU every group
-  starts on a row-tile boundary and the expert is the repo's own Pallas
-  grouped matmuls (``ops/pallas/moe_gmm.py``: six calls forward and
+  that no mesh axis shards (one chip, or every data rank holding the same
+  ones): the (token, slot) assignments are sorted by expert, the gated
+  three-matrix bias-free expert (``silu(x W_gate) * (x W_up)) W_down``) runs
+  on each expert's group of rows, and the rows are gathered back to their
+  tokens, weighted and summed. No capacity, nothing dropped. On the TPU
+  every group starts on a row-tile boundary and the expert is the repo's own
+  Pallas grouped matmuls (``ops/pallas/moe_gmm.py``: six calls forward and
   backward, the activation and the float32 dW in their epilogues); off the
   TPU, and where the widths or the rows an expert leave no tile, each
   projection is one ``jax.lax.ragged_dot`` over the rows back to back,
-  which is also the oracle the kernels are tested against.
-  ``models/olmoe.py`` is its caller.
+  which is also the oracle the kernels are tested against. The experts may
+  be ALL the router ranges over (``models/olmoe.py``) or one chip's share
+  of an expert-parallel layer (``held=(first, total)``, ``models/
+  glm_moe.py``): the router still scores all ``total`` and takes its
+  ``top_k``, only the rows whose expert is held enter the sorted buffer
+  (sized for all T * k: how many land here is the data's to say), and the
+  result is that chip's PART of the layer's sum. The exchange that would
+  bring the other chips' parts is not here (ROADMAP R2).
 
 Gating: ``top_k_from_probs`` renormalizes the selected probabilities to sum
 to 1 (the switch/mixtral convention; ``gpt_nano_moe``, ``vit_tiny_moe``).
 ``top_k_as_is`` uses them as they come out of the softmax (OLMoE,
-``norm_topk_prob`` false).
+``norm_topk_prob`` false). ``top_k_biased`` takes sigmoid scores
+(``gating_scores``), chooses by ``score + bias`` and weighs by the unbiased
+scores normalised over the chosen, times a scale (DeepSeek-V3's and
+GLM-4.x's ``noaux_tc``); ``bias_after`` is the rule that moves the bias.
 
 Parameters (functional, like ops/ring_attention.py):
   gate  [d, E]              (replicated)
@@ -114,6 +123,50 @@ def top_k_as_is(probs, top_k: int):
     false). Returns (weights [T, k] f32, indices [T, k] i32)."""
     weights, indices = jax.lax.top_k(probs, top_k)
     return weights, indices.astype(jnp.int32)
+
+
+def gating_scores(x, gate_w):
+    """Router scores ``sigmoid(x @ gate)`` in float32, [T, E]: each expert's
+    affinity on its own, not a distribution over the experts (DeepSeek-V3's
+    and GLM-4.x's ``noaux_tc`` router)."""
+    return jax.nn.sigmoid(x.astype(jnp.float32) @ gate_w.astype(jnp.float32))
+
+
+def top_k_biased(scores, bias, top_k: int, scale: float = 1.0):
+    """The verdict of a router that balances by a bias (arXiv:2412.19437
+    section 2.1.2): the ``top_k`` experts by ``scores + bias``, each weighted
+    by its UNBIASED score over the chosen ones' sum, times ``scale``
+    (``norm_topk_prob`` with ``routed_scaling_factor``). The bias decides who
+    is chosen and nothing else: no gradient reaches it. Returns (weights
+    [T, k] f32, indices [T, k] i32)."""
+    _, indices = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+    chosen = jnp.take_along_axis(scores, indices, axis=-1)
+    weights = scale * chosen / (chosen.sum(axis=-1, keepdims=True) + 1e-20)
+    return weights, indices.astype(jnp.int32)
+
+
+def bias_after(bias, counts, rate: float):
+    """The balancing bias after a step that routed ``counts`` [E] (token,
+    slot) choices: up by ``rate`` for an expert under the mean load, down
+    for one over it. A rule, not a gradient step."""
+    counts = counts.astype(jnp.float32)
+    return bias + rate * jnp.sign(counts.mean() - counts)
+
+
+def softmax_route(x, gate_w, top_k: int):
+    """``(probs, weights, indices)`` of the softmax router whose weights are
+    the probabilities as they are: :func:`moe_ffn_sorted`'s default."""
+    probs = gating_probs(x, gate_w)
+    return (probs, *top_k_as_is(probs, top_k))
+
+
+def sigmoid_route(x, gate_w, top_k: int, *, bias, scale: float):
+    """``(probs, weights, indices)`` of the sigmoid router with a balancing
+    bias; ``probs`` are the scores normalised over ALL experts, what the
+    balancing loss reads (:func:`balance_stats`' ``p``)."""
+    scores = gating_scores(x, gate_w)
+    probs = scores / (scores.sum(axis=-1, keepdims=True) + 1e-20)
+    return (probs, *top_k_biased(scores, bias, top_k, scale))
 
 
 def top_k_gating(x, gate_w, top_k: int):
@@ -520,28 +573,34 @@ def _pick(table, ids):
     return jnp.where(hot, table, 0).sum(axis=-1, dtype=table.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _take_sorted(x, src, dst, top_k: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _take_sorted(x, src, dst, top_k: int, partial: bool = False):
     """Row ``i`` of the result is token ``src[i] // top_k``: the tokens
     repeated ``top_k`` times and permuted into expert order. A ``src`` of
     ``tokens * top_k`` is a pad row and reads zeros (one row of them behind
     ``x``: a select over the gathered rows would be a pass of its own). The
     backward is a gather through ``dst`` (where each (token, slot) went) and
     a sum over the slots, not the scatter-add a plain ``x[ids]`` transposes
-    to."""
-    pads = src.shape[0] > x.shape[0] * top_k
+    to. ``partial``: some (token, slot) went nowhere (its expert is not
+    held here); its ``dst`` is past the buffer and its cotangent zeros."""
+    pads = partial or src.shape[0] > x.shape[0] * top_k
     if pads:
         x = jnp.concatenate([x, jnp.zeros_like(x[:1])])
     return x[src // top_k]
 
 
-def _take_sorted_fwd(x, src, dst, top_k):
-    return _take_sorted(x, src, dst, top_k), (dst, x.shape[0])
+def _take_sorted_fwd(x, src, dst, top_k, partial):
+    return _take_sorted(x, src, dst, top_k, partial), (dst, x.shape[0])
 
 
-def _take_sorted_bwd(top_k, res, g):
+def _take_sorted_bwd(top_k, partial, res, g):
     dst, tokens = res
-    return g[dst].reshape(tokens, top_k, -1).sum(axis=1), None, None
+    if partial:
+        rows = jnp.where((dst < g.shape[0])[:, None],
+                         g[jnp.minimum(dst, g.shape[0] - 1)], 0)
+    else:
+        rows = g[dst]
+    return rows.reshape(tokens, top_k, -1).sum(axis=1), None, None
 
 
 _take_sorted.defvjp(_take_sorted_fwd, _take_sorted_bwd)
@@ -561,30 +620,36 @@ _rows_back.defvjp(
 )
 
 
-def _gmm_row_tile(rows: int, E: int, d: int, f: int, interpret):
+def _gmm_row_tile(rows: int, E: int, d: int, f: int, interpret, total: int):
     """The row tile of the Pallas grouped matmul (``ops/pallas/moe_gmm.py``)
-    for ``rows`` sorted rows, or None where ``lax.ragged_dot`` runs: off the
+    for ``rows`` (token, slot) rows routed over ``total`` experts of which
+    ``E`` are held here, or None where ``lax.ragged_dot`` runs: off the
     TPU, in a program that may span devices, where a width is no multiple
-    of the 128 lanes, or where an expert averages under one row tile.
-    ``interpret`` not None forces the kernel (the tests). Says which ran,
-    and why, in a ``kernel.select``/``kernel.fallback`` record."""
-    tm = moe_gmm.row_tile(rows, E)
+    of the 128 lanes, or where an expert averages under one row tile. The
+    rows that land here depend on the data; the tile is decided on the
+    EXPECTED ``rows * E / total`` (all of them where every expert is held),
+    the buffer is sized for ``rows`` (``rows_bound``). ``interpret`` not
+    None forces the kernel (the tests). Says which ran, and why, in a
+    ``kernel.select``/``kernel.fallback`` record."""
+    expected = rows * E // total
+    tm = moe_gmm.row_tile(expected, E)
     reason = ""
     if d % 128 or f % 128:
         reason = f"widths {d} and {f}: not both multiples of the 128 lanes"
     elif tm is None:
-        reason = (f"{rows // E} rows an expert on average: under one row "
+        reason = (f"{expected // E} rows an expert on average: under one row "
                   f"tile of {moe_gmm.ROW_TILE}")
     impl = kernel_tier.select(
         "moe_gmm", supported=not reason, reason=reason,
         forced=interpret is not None, tm=tm, tk=d, tn=f,
-        pad_row_share=round(E * (tm or 0) / rows, 4),
+        pad_row_share=round(E * (tm or 0) / max(expected, 1), 4),
         calls_a_step=moe_gmm.CALLS_A_STEP,
+        experts_held=E, experts_total=total, rows_bound=rows,
     )
     return tm if impl == "pallas" else None
 
 
-def sorted_experts(params, x, weights, indices, *, interpret=None):
+def sorted_experts(params, x, weights, indices, *, held=None, interpret=None):
     """The sorted path's body on ``x`` [T, d] with the router's verdict
     ``weights``/``indices`` [T, k] already taken: sort, the gated expert on
     each group, gather back, weight, sum. ``params`` holds ``w_gate``/
@@ -592,17 +657,35 @@ def sorted_experts(params, x, weights, indices, *, interpret=None):
     ``x.dtype``. On the TPU the groups are laid out on row-tile boundaries
     and the expert is ``ops/pallas/moe_gmm.expert_ffn``; elsewhere, and for
     shapes without a tile (:func:`_gmm_row_tile`), three ``lax.ragged_dot``.
-    ``interpret`` True/False forces the kernel, interpreted or compiled."""
+    ``interpret`` True/False forces the kernel, interpreted or compiled.
+
+    ``held = (first, total)``: the E experts of ``params`` are experts
+    ``first .. first + E - 1`` of the ``total`` that ``indices`` range over
+    (one chip's share of an expert-parallel layer). A (token, slot) whose
+    expert is not held never enters the sorted buffer and adds nothing to
+    the result: the sum is this chip's PART of the layer's. How many rows
+    land here is the data's to say, so the buffer keeps the static height
+    of all T * k (the kernels skip the tiles past the live ones) and nothing
+    is ever dropped. ``None``: every expert is held, today's program."""
     T, k = indices.shape
     E, d, f = params["w_gate"].shape
-    tm = _gmm_row_tile(T * k, E, d, f, interpret)
+    first, total = (0, E) if held is None else held
+    partial = total != E
+    tm = _gmm_row_tile(T * k, E, d, f, interpret, total)
     with jax.named_scope("moe_route"):
         flat = indices.reshape(T * k)
+        if partial:  # the absent sort behind every held group
+            flat = flat - first
+            present = (flat >= 0) & (flat < E)
+            flat = jnp.where(present, flat, E)
         order = jnp.argsort(flat, stable=True).astype(jnp.int32)
         inverse = jnp.argsort(order).astype(jnp.int32)
         sizes = expert_counts(flat, E)
         if tm is None:  # sorted rows, back to back
             src, dst = order, inverse
+            if partial:  # behind the last group: zeros
+                src = jnp.where(
+                    jnp.arange(T * k, dtype=jnp.int32) < sizes.sum(), order, T * k)
         else:  # every group from a row-tile boundary, zeros in between
             expert, n_live, starts = moe_gmm.tile_table(sizes, T * k, tm)
             offsets = jnp.cumsum(sizes) - sizes  # the groups back to back
@@ -615,7 +698,9 @@ def sorted_experts(params, x, weights, indices, *, interpret=None):
                             order[jnp.minimum(pos, T * k - 1)], T * k)
             src = src.reshape(-1)
             dst = inverse + _pick(starts - offsets, flat)
-        rows = _take_sorted(x, src, dst, k)  # [T*k (+ pads), d]
+        if partial:  # an absent (token, slot) lies past the buffer
+            dst = jnp.where(present, dst, src.shape[0])
+        rows = _take_sorted(x, src, dst, k, partial)  # [T*k (+ pads), d]
     with jax.named_scope("moe_experts"):
         if tm is None:
             w_gate, w_up, w_down = (
@@ -632,22 +717,31 @@ def sorted_experts(params, x, weights, indices, *, interpret=None):
                 kernel_tier.interpret_mode() if interpret is None else interpret,
             )
     with jax.named_scope("moe_route"):
+        if partial:
+            dst = jnp.minimum(dst, rows.shape[0] - 1)
         rows = _rows_back(rows, dst, src).reshape(T, k, -1)
-        return (rows * weights[..., None].astype(rows.dtype)).sum(axis=1)
+        rows = rows * weights[..., None].astype(rows.dtype)
+        if partial:  # whatever row an absent slot read, it adds nothing
+            rows = jnp.where(present.reshape(T, k, 1), rows, 0)
+        return rows.sum(axis=1)
 
 
 def moe_ffn_sorted(params, x, *, top_k: int, mesh=None, data_axis: str = "data",
-                   router_x=None, interpret=None):
-    """Dropless top-k MoE with unsharded gated experts (module docstring).
+                   router_x=None, route=softmax_route, held=None, interpret=None):
+    """Dropless top-k MoE with gated experts that no mesh axis shards
+    (module docstring).
 
     ``x``: [B, S, d]. ``params``: ``router`` [d, E] and the three expert
-    tensors. Returns ``(out [B, S, d], route)`` with ``route`` the router's
-    verdict: ``probs`` [B*S, E] f32 (for the balancing loss), ``indices``
+    tensors. Returns ``(out [B, S, d], verdict)`` with ``verdict`` the
+    router's: ``probs`` [B*S, E] f32 (for the balancing loss), ``indices``
     [B, S, k] (the experts chosen) and ``counts`` [E] (rows an expert
-    received). Router, softmax and top-k run in float32; the weights
-    are the probabilities as they are (:func:`top_k_as_is`); ``router_x``
-    is what the router reads where that is not ``x`` (the same activations
-    before their rounding to the compute dtype). On a mesh
+    received). ``route(tokens [T, d], router, top_k) -> (probs, weights,
+    indices)`` is the router, in float32: :func:`softmax_route` (the
+    probabilities as they are) unless the caller has another
+    (:func:`sigmoid_route`); ``router_x`` is what it reads where that is
+    not ``x`` (the same activations before their rounding to the compute
+    dtype). ``held`` is :func:`sorted_experts`': the expert tensors are one
+    chip's share of the router's E, and ``out`` that chip's part. On a mesh
     whose ``data_axis`` is populated every data rank sorts its own tokens
     (``shard_map`` over the batch dim); nothing crosses ranks. ``interpret``
     is :func:`sorted_experts`'s."""
@@ -655,8 +749,8 @@ def moe_ffn_sorted(params, x, *, top_k: int, mesh=None, data_axis: str = "data",
     E = params["router"].shape[-1]
     with jax.named_scope("moe_route"):
         routed = x if router_x is None else router_x
-        probs = gating_probs(routed.reshape(B * S, d), params["router"])
-        weights, indices = top_k_as_is(probs, top_k)
+        probs, weights, indices = route(
+            routed.reshape(B * S, d), params["router"], top_k)
         counts = expert_counts(indices, E)
     experts = {name: params[name] for name in ("w_gate", "w_up", "w_down")}
 
@@ -664,7 +758,7 @@ def moe_ffn_sorted(params, x, *, top_k: int, mesh=None, data_axis: str = "data",
         b = x.shape[0]
         out = sorted_experts(
             experts, x.reshape(b * S, d), weights.reshape(b * S, top_k),
-            indices.reshape(b * S, top_k), interpret=interpret,
+            indices.reshape(b * S, top_k), held=held, interpret=interpret,
         )
         return out.reshape(b, S, d)
 
@@ -682,8 +776,8 @@ def moe_ffn_sorted(params, x, *, top_k: int, mesh=None, data_axis: str = "data",
             in_specs=(P(), P(data_axis), P(data_axis), P(data_axis)),
             out_specs=P(data_axis), check_vma=False,
         )
-    route = {"probs": probs, "indices": indices, "counts": counts}
-    return body(experts, x, weights, indices), route
+    verdict = {"probs": probs, "indices": indices, "counts": counts}
+    return body(experts, x, weights, indices), verdict
 
 
 def router_z_loss(x, router_w):

@@ -479,6 +479,10 @@ def make_eval_step(model, topk: int, layout=None):
     # of what ``hidden_only`` returned, the state the head evaluates
     # (models/ouro.py: the last pass's)
     eval_hidden = getattr(model, "eval_hidden", lambda hidden: hidden)
+    # ... and the head's column for a token id, where the head holds a slice
+    # of the vocabulary's rows (models/glm_moe.py; its ``head_loss`` does the
+    # same in training)
+    head_labels = getattr(model, "head_labels", lambda labels: labels)
 
     def eval_step(state: TrainState, batch):
         params = gather_entry(state.params)
@@ -494,6 +498,7 @@ def make_eval_step(model, topk: int, layout=None):
         mask = batch["mask"]
         labels = batch["label"]
         if head_kernel is not None:
+            labels = head_labels(labels)
             # the head in chunks, as in the train step (ops/token_head.py)
             nll, rank = token_head.head_stats(
                 logits, head_kernel(params), labels, chunk=loss_chunk

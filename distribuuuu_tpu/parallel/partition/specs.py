@@ -320,6 +320,17 @@ def lm_spec_table(moe_axis: str = "model") -> SpecTable:
             SpecRule(r"mlp/(gate|up)_proj/kernel$", P(None, "model")),
             SpecRule(r"mlp/down_proj/kernel$", P("model")),
             SpecRule(r"exit_gate/(kernel|bias)$", P()),
+            # the glm_* family (models/glm_moe.py): norm scales, output
+            # projection, dense MLP, router, held experts and head by the
+            # rules above; latent attention's down-projections replicated
+            # (a latent is shared by all heads) and its up-projections split
+            # by head; the shared expert as a dense MLP; the MTP module's
+            # projection replicated
+            SpecRule(r"attn/(q_a|kv_a)_proj/kernel$", P()),
+            SpecRule(r"attn/(q_b|kv_b)_proj/kernel$", P(None, "model")),
+            SpecRule(r"moe/shared/(gate|up)_proj/kernel$", P(None, "model")),
+            SpecRule(r"moe/shared/down_proj/kernel$", P("model")),
+            SpecRule(r"mtp_proj/kernel$", P()),
         ),
         default=None,  # unmatched leaves keep their annotation/replication
         strict=False,

@@ -143,7 +143,10 @@ KINDS: dict[str, frozenset] = {
     # actually runs for an op vs what KERNELS.* requested — the source
     # of run_report's `kernels` section. Where the kernel runs, a knobless
     # op adds what it chose, once a traced shape: `moe_gmm` tm, tk, tn,
-    # pad_row_share, calls_a_step; `flash_attn` L, d, causal, blk_q, blk_k,
+    # pad_row_share, calls_a_step, experts_held (of experts_total the router
+    # ranges over), rows_bound (the rows the buffer is sized for; the tile
+    # is decided on the expected rows * held / total); `flash_attn` L, d,
+    # causal, blk_q, blk_k,
     # and a sequence's tiles_visited, tiles_crossed (by the diagonal or the
     # padding), tiles_masked (those that run the mask), bwd_matmuls_a_tile
     "kernel.select": frozenset({"op", "impl", "requested"}),
@@ -155,6 +158,14 @@ KINDS: dict[str, frozenset] = {
     # (bytes a step) and what the backward computes again
     "loop.plan": frozenset(
         {"layers", "passes", "block_applications", "kept_bytes", "recomputed"}
+    ),
+    # one per traced shape of a model that is one chip's share of an
+    # expert-parallel group (models/glm_moe.py): how many chips share each
+    # layer and which of them this is, what it holds of the routed experts
+    # and of the vocabulary's rows, what its backward computes again
+    "share.plan": frozenset(
+        {"share_chips", "share_rank", "experts_held", "experts_total", "vocab_held",
+         "vocab_total", "recomputed"}
     ),
     # -- live observability plane (telemetry/live.py, tools/monitor.py) --
     # one windowed aggregate per monitor tick (MONITOR.jsonl)
@@ -270,6 +281,12 @@ DEVICE_SCOPES: dict[str, str] = {
     "mlp": "models",
     "exit_gate": "models",
     "loop_pass": "models",
+    # models/glm_moe.py (``attn``, ``moe``, ``mlp`` and ``lm_head`` as
+    # above): latent attention's projections, norms and rotary inside
+    # ``attn``; the shared expert inside ``moe``; the MTP module
+    "mla_latent": "models",
+    "moe_shared": "models",
+    "mtp": "models",
     # ops/pallas/opt_update.py
     "opt_tile": "kernels",
     "opt_kernel": "kernels",

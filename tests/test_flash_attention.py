@@ -29,6 +29,7 @@ BLK = dict(blk_q=256, blk_k=256)
         (2, 3, 512, 64),   # block multiple
         (1, 2, 300, 64),   # padded L (masked keys + padded q rows)
         (2, 2, 640, 32),   # L > blk, not a multiple; small head dim
+        (1, 2, 512, 256),  # two lane tiles a head: latent attention's dim
     ],
 )
 def test_forward_matches_reference(B, H, L, D):
@@ -60,6 +61,9 @@ def _grads(fn, q, k, v, w):
         (1, 2, 257, 128, True, 128, 384),   # a class token's padding: lp 384
         (1, 2, 257, 64, False, 384, 128),
         (2, 3, 384, 128, False, 128, 384),  # several heads, no mask at all
+        (1, 2, 512, 256, True, 256, 256),   # head dim 256: models/glm_moe.py
+        (1, 2, 300, 256, True, 128, 384),   # ... with padded keys, blk_q < blk_k
+        (1, 2, 384, 256, False, 384, 128),  # ... and no mask at all
     ],
 )
 def test_gradients_match_reference(B, H, L, D, causal, blk_q, blk_k):
@@ -84,6 +88,18 @@ def test_gradients_match_reference(B, H, L, D, causal, blk_q, blk_k):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=5e-5, err_msg=name
         )
+
+
+def test_a_head_dim_past_128_runs_in_whole_lane_tiles_only():
+    """256 runs (the cases above); 192 is refused by every entry, and the
+    residency bound halves with the dim: 8192 tokens fit at 256, 16,384 at
+    128, neither at twice that."""
+    q = jnp.zeros((1, 1, 256, 192), jnp.float32)
+    for entry in (fa.flash_attention, fa.flash_attention_with_lse):
+        with pytest.raises(ValueError, match="no multiple of the 128 lanes"):
+            entry(q, q, q, interpret=True)
+    assert fa.fits_vmem(8192, 256) and not fa.fits_vmem(16384, 256)
+    assert fa.fits_vmem(16384, 128) and not fa.fits_vmem(32768, 128)
 
 
 def test_cpu_fallback_is_blockwise():

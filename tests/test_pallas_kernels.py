@@ -724,6 +724,41 @@ def test_run_report_kernels_section(tmp_path):
     assert kern["fallbacks"][0]["op"] == "conv_epilogue"
 
 
+def test_run_report_prints_a_shares_experts_and_its_plan(tmp_path, capsys):
+    """A chip's share of an expert-parallel layer in the ``kernels``
+    section: ``moe_gmm``'s record with the experts held of those routed over
+    and the rows its buffer is sized for, and the ``share.plan`` record."""
+    import run_report
+
+    tdir = tmp_path / "telemetry"
+    os.makedirs(tdir)
+    recs = [
+        {"kind": "clock", "rank": 0, "t": 0.0, "unix": 0.0, "mono": 0.0},
+        {"kind": "kernel.select", "rank": 0, "t": 1.0, "op": "moe_gmm",
+         "impl": "pallas", "requested": "auto", "tm": 256, "tk": 2048, "tn": 1536,
+         "pad_row_share": 0.5, "calls_a_step": 6, "experts_held": 8,
+         "experts_total": 64, "rows_bound": 32768},
+        {"kind": "share.plan", "rank": 0, "t": 1.0, "share_chips": 8, "share_rank": 0,
+         "experts_held": 8, "experts_total": 64, "vocab_held": 19360,
+         "vocab_total": 154880, "recomputed": "every block"},
+        {"kind": "span", "rank": 0, "t": 1.0, "v": 1, "name": "step",
+         "t0": 0.0, "dur": 0.01, "track": "pipeline", "phase": "train"},
+    ]
+    with open(tdir / "rank00000.jsonl", "w") as f:
+        for r in recs:
+            f.write(json.dumps(r) + "\n")
+    rep = run_report.build_report(str(tmp_path))
+    gmm = rep["kernels"]["selected"]["moe_gmm"]
+    assert (gmm["experts_held"], gmm["experts_total"], gmm["rows_bound"]) == (8, 64, 32768)
+    plan = rep["kernels"]["share_plan"]
+    assert (plan["share_chips"], plan["vocab_held"], plan["vocab_total"]) == (8, 19360, 154880)
+    run_report._print_report(rep)
+    printed = capsys.readouterr().out
+    assert "moe_gmm: " in printed and "experts_held=8" in printed
+    assert "experts_total=64" in printed and "rows_bound=32768" in printed
+    assert "of 8 chips holds 8 of 64 experts and 19360 of 154880 vocabulary rows" in printed
+
+
 def test_bench_index_kernel_series_and_resnet50_reference(chip_bench_root):
     """BENCH_r09's kernel_* series must ride the index WITHOUT touching
     the img/s regression reference (the PR 8 clobbering lesson): the

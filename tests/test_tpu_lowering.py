@@ -186,6 +186,7 @@ def test_flash_fwd_bwd_lowers(data):
 @pytest.mark.parametrize("shape,causal", [
     ((1, 16, 4096, 128), True),    # ouro_2_6b.train_seq4096
     ((4, 16, 4096, 128), True),    # olmoe_1b_7b.train_seq4096
+    ((1, 20, 8192, 256), True),    # glm_4_7_flash.train_seq8192: two lane tiles a head
     ((4, 3, 4096, 64), False),     # ViT-Ti at 1024px
     ((1, 3, 4097, 64), False),     # ... with a class token: padded keys
 ])
@@ -347,3 +348,78 @@ def test_ouro_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
     recomputed = [p for p in paths if in_scope(p, "rematted_computation")]
     assert any(in_scope(p, "mlp") for p in recomputed)
     assert not any(in_scope(p, "lm_head") or in_scope(p, "exit_gate") for p in recomputed)
+
+
+def test_glm_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
+    """The step of ``glm_4_7_flash.train_seq8192`` (``config/glm_4_7_flash.yaml``:
+    published widths, 8192 tokens, 8 of 64 experts and 19,360 vocabulary rows
+    held) compiled for the chip at the dense layer, one mixture layer and the
+    MTP module (the cell's 1 + 4 compile in a minute here). What the
+    benchmark's readers find in it: every scope they sum, the flash kernels
+    at head dim 256 and the six grouped matmuls on the held experts by name,
+    the recomputed forward, and no ``while``."""
+    from jax.sharding import SingleDeviceSharding
+
+    import distribuuuu_tpu.config as config
+    from benchmark.harness.trace import in_scope, op_names_from_hlo
+    from distribuuuu_tpu import trainer
+    from distribuuuu_tpu.config import cfg
+    from distribuuuu_tpu.ops import pallas as kernel_tier
+    from distribuuuu_tpu.parallel import mesh as mesh_lib
+    from distribuuuu_tpu.parallel.partition import lowering, topology
+    from distribuuuu_tpu.utils.optim import construct_optimizer
+
+    monkeypatch.setattr(kernel_tier, "interpret_mode", lambda: False)
+    monkeypatch.setattr(kernel_tier, "compiled_across_devices", lambda: False)
+    config.reset_cfg()
+    config.merge_from_file("config/glm_4_7_flash.yaml")
+    cfg.LM.LAYERS, cfg.MESH.DATA, cfg.KERNELS.OPT_UPDATE = 2, 1, "pallas"
+    try:
+        layout = topology.from_cfg(cfg, n_devices=1)
+        lowered = lowering.lower(
+            trainer.build_model_from_cfg(layout), construct_optimizer(), 5,
+            mesh=mesh_lib.build_mesh(data=1, devices=[v5e_chip]),
+            topology=layout, im_size=cfg.TRAIN.IM_SIZE,
+        )
+        state, batch = lowered.abstract_args(1)
+    finally:
+        config.reset_cfg()
+    assert lowered.model.held == (0, 8) and lowered.model.vocab_held == 19360
+    chip = SingleDeviceSharding(v5e_chip)
+    batch = {k: jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=chip)
+             for k in batch}
+    compiled = lowered.train_step.lower(state, batch).compile()
+    text = compiled.as_text()
+    assert " while(" not in text and " conditional(" not in text
+    assert "ragged-dot" not in text  # the held experts run the Pallas kernels
+    paths = list(op_names_from_hlo(text).values())
+    for scope in ("fwd", "bwd", "attn", "mla_latent", "mlp", "moe", "moe_route",
+                  "moe_experts", "moe_shared", "mtp", "lm_head", "optimizer_update",
+                  "opt_kernel", "rematted_computation"):
+        assert any(in_scope(p, scope) for p in paths), scope
+    calls = {}
+    for line in text.splitlines():
+        if "custom-call(" in line and "dtpu_" in line:
+            name = line.split(" = ")[0].split()[-1].lstrip("%").rsplit(".", 1)[0]
+            calls.setdefault(name, []).append(line.split('op_name="')[1].split('"')[0])
+    # 3 blocks: the forward kernel in the forward and again in the backward's
+    # recomputation, the one backward kernel once
+    flash = {k: len(v) for k, v in calls.items() if "flash" in k}
+    assert (flash["dtpu_flash_fwd"], flash["dtpu_flash_bwd"]) == (6, 3)
+    assert all(in_scope(p, "attn") and not in_scope(p, "mla_latent")
+               for k in ("dtpu_flash_fwd", "dtpu_flash_bwd") for p in calls[k])
+    # 2 mixtures: gate_up and fwd run forward and again, the four backward
+    # kernels once; all under moe_experts, none under moe_shared
+    gmm = {k: len(v) for k, v in calls.items() if "moe_gmm" in k}
+    assert gmm == {
+        "dtpu_moe_gmm_gate_up": 4, "dtpu_moe_gmm_fwd": 4, "dtpu_moe_gmm_act_bwd": 2,
+        "dtpu_moe_gmm_dx_gate_up": 2, "dtpu_moe_gmm_dw_down": 2,
+        "dtpu_moe_gmm_dw_gate_up": 2}
+    for name in gmm:
+        assert all(in_scope(p, "moe_experts") and not in_scope(p, "moe_shared")
+                   for p in calls[name])
+    assert any(in_scope(p, "mtp") for p in calls["dtpu_moe_gmm_fwd"])
+    assert len(calls["dtpu_opt_update_adamw"]) == len(jax.tree.leaves(state.params))
+    # it fits the chip with room for the cell's two more mixture layers
+    m = compiled.memory_analysis()
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes) < 12 * 2**30

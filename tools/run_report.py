@@ -412,11 +412,14 @@ def _kernels_section(ranks: dict[int, list[dict]]) -> dict | None:
     record traced last), every forced-but-unsupported fallback with
     its reason (``kernel.fallback``), and — when the run carried
     ``kernel_*``-labeled cost records (tools/kernel_bench.py emits them)
-    — the per-kernel A/B deltas. None when the run never consulted the
-    tier (pre-tier runs are untouched)."""
+    — the per-kernel A/B deltas. Beside them ``share_plan``: what of each
+    layer a chip of an expert-parallel group holds (``share.plan``,
+    models/glm_moe.py; the record traced last). None when the run never
+    consulted the tier (pre-tier runs are untouched)."""
     selected: dict[str, dict] = {}
     fallbacks: list[dict] = []
     ab: dict[str, dict] = {}
+    share_plan = None
     for recs in ranks.values():
         for r in recs:
             kind = r.get("kind")
@@ -426,6 +429,9 @@ def _kernels_section(ranks: dict[int, list[dict]]) -> dict | None:
                     "impl": r.get("impl"), "requested": r.get("requested"),
                     **{k: v for k, v in r.items() if k not in _RECORD_KEYS},
                 }
+            elif kind == "share.plan":
+                share_plan = {
+                    k: v for k, v in r.items() if k not in _RECORD_KEYS}
             elif kind == "kernel.fallback":
                 fallbacks.append({
                     "op": r.get("op"), "requested": r.get("requested"),
@@ -444,6 +450,7 @@ def _kernels_section(ranks: dict[int, list[dict]]) -> dict | None:
         "selected": selected,
         "fallbacks": fallbacks,
         "ab": ab or None,
+        "share_plan": share_plan,
     }
 
 
@@ -892,8 +899,21 @@ def _print_report(rep: dict) -> None:
         print(f"kernel tier: {chosen or 'no selections'}"
               + (f", {len(kern['fallbacks'])} fallback(s)"
                  if kern["fallbacks"] else ""))
+        for op, row in sorted(kern["selected"].items()):
+            detail = {k: v for k, v in row.items()
+                      if k not in ("impl", "requested")}
+            if detail:  # what a knobless op says it chose
+                print(f"  {op}: " + ", ".join(
+                    f"{k}={v}" for k, v in sorted(detail.items())))
         for fb in kern["fallbacks"]:
             print(f"  fallback {fb['op']}: {fb['reason']}")
+        plan = kern.get("share_plan")
+        if plan:
+            print(f"  share of a layer: rank {plan['share_rank']} of "
+                  f"{plan['share_chips']} chips holds {plan['experts_held']} of "
+                  f"{plan['experts_total']} experts and {plan['vocab_held']} "
+                  f"of {plan['vocab_total']} vocabulary rows; recomputed: "
+                  f"{plan['recomputed']}")
         if kern.get("ab"):
             for label, row in sorted(kern["ab"].items()):
                 ba = row.get("bytes_accessed")
