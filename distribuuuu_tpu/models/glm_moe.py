@@ -44,9 +44,10 @@ the mixture in every later one.
   target at t is ``label[t + 1]``.
 * parameters and the residual stream are float32, the matmuls read
   ``dtype``; norms, router, softmaxes and loss are float32.
-* every block is recomputed in the backward from its float32 input
-  (``recompute``), as ``models/ouro.py``'s: six blocks' activations at 8192
-  tokens do not fit beside 11.3 GB of parameters and AdamW state.
+* every block is recomputed in the backward from its float32 input and
+  the flash kernel's output and log-sum-exp (``recompute``), as
+  ``models/ouro.py``'s: six blocks' activations at 8192 tokens do not fit
+  beside 11.3 GB of parameters and AdamW state.
 
 As ``models/ouro.py`` the head is left to the step: ``hidden_only=True``
 returns ``(states [B, 2, S, d], statistics)`` (the trunk's and the MTP
@@ -73,7 +74,7 @@ from distribuuuu_tpu.models.olmoe import (
     decoder_kwargs_from_cfg,
     rotary,
 )
-from distribuuuu_tpu.models.ouro import MLP
+from distribuuuu_tpu.models.ouro import MLP, kept_plan
 from distribuuuu_tpu.models.traits import ArchTraits
 from distribuuuu_tpu.models.vit import Attention as VitAttention
 from distribuuuu_tpu.ops import token_head
@@ -253,8 +254,9 @@ def _say_plan(model, batch: int, seq: int) -> None:
         "share.plan", share_chips=model.share_chips, share_rank=model.share_rank,
         experts_held=model.held[1], experts_total=model.num_experts,
         vocab_held=model.vocab_held, vocab_total=model.vocab_size,
-        recomputed="every block, the MTP module's too, from its float32 input"
-        if model.recompute else "nothing",
+        **kept_plan(
+            model, model.depth + model.mtp_layers, batch, seq, model.v_head_dim,
+            "every block, the MTP module's too"),
     )
 
 
@@ -289,7 +291,8 @@ class GLMMoE(nn.Module):
     dtype: Any = jnp.bfloat16
     attn_impl: str = "auto"
     mesh: Any = None
-    # a block keeps its float32 input and nothing else (see above)
+    # a block keeps its float32 input and the flash kernel's output and
+    # log-sum-exp, nothing else (see above)
     recompute: bool = True
     # positions of every row the head takes at a time; its rows are the
     # batch's sequences twice over (the trunk's and the MTP module's)
@@ -344,7 +347,11 @@ class GLMMoE(nn.Module):
             self.shared_experts, self.routed_scale, self.bias_rate, self.held,
             self.dtype, train, self.mesh,
         )
-        block = nn.remat(Block) if self.recompute else Block
+        from distribuuuu_tpu.ops.flash_attention import KEPT_UNDER_REMAT
+
+        block = nn.remat(
+            Block, policy=jax.checkpoint_policies.save_only_these_names(
+                *KEPT_UNDER_REMAT)) if self.recompute else Block
 
         def make(name, dense):
             return block(

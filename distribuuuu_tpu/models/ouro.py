@@ -23,7 +23,10 @@ that says after which pass a token may leave.
 * parameters and the residual stream are float32, the matmuls read
   ``dtype``; norms, gate, exit distribution and loss are float32.
 * every block application is recomputed in the backward from its float32
-  input, which is all it keeps (``recompute``): R x L applications hold R
+  input and, where the flash kernel ran, its output and log-sum-exp
+  (``ops/flash_attention.KEPT_UNDER_REMAT``: 16.3 MiB beside the input's 32
+  at the cell's shape, so the recomputation never runs the forward kernel
+  again); that is all it keeps (``recompute``): R x L applications hold R
   times the activations a parameter, and 32 of them at 4096 tokens do not
   fit a 16 GB chip beside the AdamW state otherwise (PERF.md section 4).
   Passes and layers are Python loops: a ``while`` shows in a device trace
@@ -141,10 +144,35 @@ def _say_plan(model, batch: int, seq: int) -> None:
     spans.emit_event(
         "loop.plan", layers=model.depth, passes=model.passes,
         block_applications=applications,
-        kept_bytes=applications * batch * seq * model.dim * 4 if model.recompute else None,
-        recomputed="every block application, from its float32 input"
-        if model.recompute else "nothing",
+        **kept_plan(
+            model, applications, batch, seq, model.dim // model.num_heads,
+            "every block application"),
     )
+
+
+def kept_plan(model, blocks: int, batch: int, seq: int, head_dim: int,
+              what: str) -> dict:
+    """The fields of a plan record (``loop.plan``; ``share.plan`` of
+    ``models/glm_moe.py``) that say what ``blocks`` recomputed blocks keep a
+    step: ``kept_bytes`` (their float32 inputs and ``kept_flash_bytes``),
+    ``kept_flash_bytes`` (the flash kernel's output and log-sum-exp; 0 where
+    attention takes another path, which names nothing) and ``recomputed``."""
+    if not model.recompute:
+        return {"kept_bytes": None, "kept_flash_bytes": None, "recomputed": "nothing"}
+    from distribuuuu_tpu.models.vit import Attention as VitAttention
+    from distribuuuu_tpu.ops.flash_attention import kept_under_remat_bytes
+
+    flash = 0
+    if VitAttention.resolve_impl(model.attn_impl, seq, 0.0) == "flash":
+        flash = blocks * kept_under_remat_bytes(
+            (batch, model.num_heads, seq, head_dim),
+            jnp.dtype(model.dtype).itemsize, model.mesh)
+    return {
+        "kept_bytes": blocks * batch * seq * model.dim * 4 + flash,
+        "kept_flash_bytes": flash,
+        "recomputed": f"{what}, from its float32 input" + (
+            " and the flash kernel's output and log-sum-exp" if flash else ""),
+    }
 
 
 class Ouro(nn.Module):
@@ -163,7 +191,8 @@ class Ouro(nn.Module):
     dtype: Any = jnp.bfloat16
     attn_impl: str = "auto"
     mesh: Any = None
-    # a block application keeps its input and nothing else (see above)
+    # a block application keeps its input and the flash kernel's output and
+    # log-sum-exp, nothing else (see above)
     recompute: bool = True
     # positions of every row the head takes at a time; its rows are the
     # batch's sequences R times over
@@ -183,7 +212,11 @@ class Ouro(nn.Module):
             embedding_init=_normal(),
         )(tokens)
         positions = jnp.arange(S, dtype=jnp.int32)
-        block = nn.remat(Block) if self.recompute else Block
+        from distribuuuu_tpu.ops.flash_attention import KEPT_UNDER_REMAT
+
+        block = nn.remat(
+            Block, policy=jax.checkpoint_policies.save_only_these_names(
+                *KEPT_UNDER_REMAT)) if self.recompute else Block
         blocks = [
             block(
                 self.dim, self.num_heads, self.mlp_hidden, self.rms_norm_eps,

@@ -51,6 +51,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -58,6 +59,13 @@ from distribuuuu_tpu.ops import pallas as kernel_tier
 from distribuuuu_tpu.ops.pallas.moe_gmm import _dot  # a · b over (dim, dim), f32
 
 _NEG_BIG = -0.7 * float(jnp.finfo(jnp.float32).max)
+
+# The names of the forward kernel's output and log-sum-exp in the forward
+# rules (``_residuals``). A block under ``jax.checkpoint``/``nn.remat`` whose
+# policy is ``save_only_these_names(*KEPT_UNDER_REMAT)`` keeps the two and
+# its recomputation has no use for ``dtpu_flash_fwd``; under no policy, or
+# under no checkpoint at all, the names lower to nothing.
+KEPT_UNDER_REMAT = ("flash_o", "flash_lse")
 
 # A v5e core has 128 MiB of VMEM and Mosaic's scoped default is 16 MiB, which
 # the fused backward's resident set passes at 4096 tokens: the calls ask for
@@ -432,11 +440,25 @@ def _flash_attention(q, k, v, scale, interpret, blk_q, blk_k, causal):
     return o
 
 
-def _fa_fwd(q, k, v, scale, interpret, blk_q, blk_k, causal):
+def _residuals(q, k, v, scale, interpret, blk_q, blk_k, causal):
+    """``(o, lse, residuals)`` of a forward rule, ``o`` and ``lse`` NAMED
+    (:data:`KEPT_UNDER_REMAT`). The rule's primal output must be this named
+    ``o`` too, not only the residual: a recomputation that kept the residual
+    would still run the kernel for the un-named ``o`` that ``W_o`` reads.
+    ``qf``, ``kf``, ``vf`` are not named: a recomputed block computes q, k
+    and v again for the backward kernel (its projections, norms, rotary and
+    head transposes: PERF.md section 6, PR 34, sizes what keeping them buys)."""
     o, lse, (qf, kf, vf) = _flash_forward(
         q, k, v, scale, interpret, blk_q, blk_k, causal
     )
-    return o, (qf, kf, vf, lse, o, q.shape)
+    o = checkpoint_name(o, KEPT_UNDER_REMAT[0])
+    lse = checkpoint_name(lse, KEPT_UNDER_REMAT[1])
+    return o, lse, (qf, kf, vf, lse, o, q.shape)
+
+
+def _fa_fwd(q, k, v, scale, interpret, blk_q, blk_k, causal):
+    o, _, res = _residuals(q, k, v, scale, interpret, blk_q, blk_k, causal)
+    return o, res
 
 
 def _fa_bwd(scale, interpret, blk_q, blk_k, causal, res, g):
@@ -454,12 +476,9 @@ def _flash_attention_lse(q, k, v, scale, interpret, blk_q, blk_k, causal):
 
 
 def _fal_fwd(q, k, v, scale, interpret, blk_q, blk_k, causal):
-    o, lse, (qf, kf, vf) = _flash_forward(
-        q, k, v, scale, interpret, blk_q, blk_k, causal
-    )
+    o, lse, res = _residuals(q, k, v, scale, interpret, blk_q, blk_k, causal)
     b, h, L, _ = q.shape
-    out = (o, lse[:, 0, :L].reshape(b, h, L))
-    return out, (qf, kf, vf, lse, o, q.shape)
+    return (o, lse[:, 0, :L].reshape(b, h, L)), res
 
 
 def _fal_bwd(scale, interpret, blk_q, blk_k, causal, res, g):
@@ -493,6 +512,14 @@ def _check_head_dim(d: int) -> None:
             f"head_dim {d} > 128 is no multiple of the 128 lanes: not supported")
 
 
+def _data_ranks(mesh, batch: int) -> int:
+    """The data ranks of ``mesh`` that each run the kernel on their own
+    sequences under ``shard_map``; 1 where there is no such mesh or its data
+    axis does not divide the batch."""
+    ranks = int(dict(mesh.shape).get("data", 1)) if mesh is not None else 1
+    return ranks if batch % ranks == 0 else 1
+
+
 def flash_attention(
     q, k, v, *, scale: float | None = None, causal: bool = False,
     interpret: bool | None = None, blk_q: int | None = None,
@@ -524,8 +551,7 @@ def flash_attention(
     b, _, L, d = q.shape
     _check_head_dim(d)
     scale = d ** -0.5 if scale is None else scale
-    shards = int(dict(mesh.shape).get("data", 1)) if mesh is not None else 1
-    if shards > 1 and b % shards == 0:
+    if _data_ranks(mesh, b) > 1:
         def per_shard(q, k, v):
             # one device's sequences: the kernel tier may engage
             with kernel_tier.single_device_program():
@@ -566,6 +592,21 @@ def flash_attention(
     if interpret is None:
         interpret = False
     return _flash_attention(q, k, v, scale, interpret, blk_q, blk_k, causal)
+
+
+def kept_under_remat_bytes(q_shape, itemsize: int, mesh=None) -> int:
+    """Bytes of :data:`KEPT_UNDER_REMAT` one ``flash_attention`` call at
+    ``q_shape`` leaves a recomputed block that keeps them (``o`` as it is
+    returned, the float32 ``lse`` at the padded length); 0 where the call
+    takes the scan path, which names nothing. What a model's plan record
+    says (``loop.plan``, ``share.plan``): the questions are
+    ``flash_attention``'s own (the platform, one device or a ``shard_map``
+    a data rank, the VMEM bound) asked without a ``kernel.select`` record."""
+    b, h, L, d = q_shape
+    across = _data_ranks(mesh, b) == 1 and kernel_tier.compiled_across_devices()
+    if kernel_tier.interpret_mode() or across or not fits_vmem(L, d, itemsize):
+        return 0
+    return b * h * (L * d * itemsize + _round_up(L, 128) * 4)
 
 
 def flash_attention_with_lse(

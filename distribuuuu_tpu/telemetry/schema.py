@@ -155,17 +155,21 @@ KINDS: dict[str, frozenset] = {
     "kernel.fallback": frozenset({"op", "requested", "reason"}),
     # one per traced shape of a looped stack (models/ouro.py): R passes over
     # L blocks, what the R x L block applications keep for the backward
-    # (bytes a step) and what the backward computes again
+    # (bytes a step: their float32 inputs and kept_flash_bytes, the flash
+    # kernel's output and log-sum-exp, 0 where the scan path ran) and what
+    # the backward computes again
     "loop.plan": frozenset(
-        {"layers", "passes", "block_applications", "kept_bytes", "recomputed"}
+        {"layers", "passes", "block_applications", "kept_bytes",
+         "kept_flash_bytes", "recomputed"}
     ),
     # one per traced shape of a model that is one chip's share of an
     # expert-parallel group (models/glm_moe.py): how many chips share each
     # layer and which of them this is, what it holds of the routed experts
-    # and of the vocabulary's rows, what its backward computes again
+    # and of the vocabulary's rows, what its recomputed blocks keep (as
+    # loop.plan) and what its backward computes again
     "share.plan": frozenset(
         {"share_chips", "share_rank", "experts_held", "experts_total", "vocab_held",
-         "vocab_total", "recomputed"}
+         "vocab_total", "kept_bytes", "kept_flash_bytes", "recomputed"}
     ),
     # -- live observability plane (telemetry/live.py, tools/monitor.py) --
     # one windowed aggregate per monitor tick (MONITOR.jsonl)

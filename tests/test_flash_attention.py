@@ -10,7 +10,9 @@ Speed (tools/flash_bench.py on a v5e, PERF.md) is hardware-gated and not
 asserted here.
 """
 
+import functools
 import json
+import re
 
 import numpy as np
 import jax
@@ -200,6 +202,156 @@ def test_with_lse_matches_and_differentiates(L, causal):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=5e-5, err_msg=name
         )
+
+
+# ------------------------------------------- what a recomputed block keeps
+_SHAPE = (1, 2, 256, 64)
+
+
+def _loss(entry):
+    """A block's use of the kernel: a projection into it (so that the
+    recomputation has something of its own to run) and one out of it."""
+    def loss(x, w):
+        q = x * w
+        out = entry(q, 0.5 * q, q + 1, causal=True, interpret=True, **BLK)
+        return sum((t.astype(jnp.float32) ** 2).sum() for t in jax.tree.leaves(out))
+
+    return loss
+
+
+def _trapped(with_lse):
+    """A local copy of the forward rule that names ONLY the residuals: the
+    primal output is the kernel's own, un-named ``o``."""
+    args = (_SHAPE[-1] ** -0.5, True, 256, 256, True)
+
+    def primal(o, lse):
+        return (o, lse[:, 0].reshape(_SHAPE[:3])) if with_lse else o
+
+    @jax.custom_vjp
+    def attend(q, k, v):
+        return primal(*fa._flash_forward(q, k, v, *args)[:2])
+
+    def fwd(q, k, v):
+        o, lse, (qf, kf, vf) = fa._flash_forward(q, k, v, *args)
+        kept, lse = (fa.checkpoint_name(t, name)
+                     for t, name in zip((o, lse), fa.KEPT_UNDER_REMAT))
+        return primal(o, lse), (qf, kf, vf, lse, kept)
+
+    def bwd(res, g):
+        g_o, g_lse = g if with_lse else (g, None)
+        if with_lse:
+            g_lse = g_lse.astype(jnp.float32).reshape(res[3].shape)
+        return fa._flash_backward((*res, _SHAPE), g_o, *args, g_lse=g_lse)
+
+    attend.defvjp(fwd, bwd)
+    return lambda q, k, v, **kw: attend(q, k, v)
+
+
+def _forward_calls(fn, *args) -> int:
+    """``dtpu_flash_fwd`` calls in the gradient's jaxpr, forward and
+    backward together."""
+    text = str(jax.make_jaxpr(jax.grad(fn, argnums=(0, 1)))(*args))
+    assert text.count("name=dtpu_flash_bwd") == 1
+    return text.count("name=dtpu_flash_fwd")
+
+
+@pytest.mark.parametrize("with_lse", [False, True], ids=["o", "o_and_lse"])
+@pytest.mark.parametrize("arm,calls", [
+    ("kept", 1), ("plain_checkpoint", 2), ("only_the_residual_named", 2)])
+def test_a_checkpoint_that_keeps_the_names_runs_the_forward_kernel_once(
+        arm, calls, with_lse):
+    """Under ``save_only_these_names(*KEPT_UNDER_REMAT)`` the recomputation
+    has no use for ``dtpu_flash_fwd``; under a plain ``jax.checkpoint`` it
+    runs again; and it runs again too where the rule names its residual
+    ``o`` and returns the un-named one as the primal output (the trap: the
+    block's output projection reads THAT). The gradients are the same bits
+    whatever is kept."""
+    entry = fa.flash_attention_with_lse if with_lse else fa.flash_attention
+    policy = jax.checkpoint_policies.save_only_these_names(*fa.KEPT_UNDER_REMAT)
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.standard_normal(_SHAPE), jnp.float32)
+    w = jnp.asarray(1 + 0.1 * rng.standard_normal(_SHAPE[-1]), jnp.float32)
+    loss = _loss(entry)
+    wrapped = {
+        "kept": jax.checkpoint(loss, policy=policy),
+        "plain_checkpoint": jax.checkpoint(loss),
+        "only_the_residual_named": jax.checkpoint(
+            _loss(_trapped(with_lse)), policy=policy),
+    }[arm]
+    assert _forward_calls(loss, x, w) == 1
+    assert _forward_calls(wrapped, x, w) == calls
+    for got, want in zip(jax.grad(wrapped, argnums=(0, 1))(x, w),
+                         jax.grad(loss, argnums=(0, 1))(x, w)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_the_names_are_kept_through_a_data_ranks_shard_map():
+    """A model on a mesh hands it over and every data rank runs the kernel
+    under ``shard_map``: the policy sees the names inside it too, and the
+    recomputation has no forward kernel there either."""
+    from distribuuuu_tpu.parallel import mesh as mesh_lib
+
+    mesh = mesh_lib.build_mesh(data=8)
+    policy = jax.checkpoint_policies.save_only_these_names(*fa.KEPT_UNDER_REMAT)
+    loss = _loss(functools.partial(fa.flash_attention, mesh=mesh))
+    x = jnp.asarray(
+        np.random.default_rng(5).standard_normal((8, *_SHAPE[1:])), jnp.float32)
+    w = jnp.ones(_SHAPE[-1], jnp.float32)
+    assert "shard_map" in str(jax.make_jaxpr(loss)(x, w))
+    assert _forward_calls(jax.checkpoint(loss, policy=policy), x, w) == 1
+    assert _forward_calls(jax.checkpoint(loss), x, w) == 2
+    for got, want in zip(
+            jax.grad(jax.checkpoint(loss, policy=policy), argnums=(0, 1))(x, w),
+            jax.grad(loss, argnums=(0, 1))(x, w)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("with_lse", [False, True], ids=["o", "o_and_lse"])
+def test_the_names_are_no_operation_where_no_checkpoint_asks(monkeypatch, with_lse):
+    """A gradient through the kernels with no ``jax.checkpoint`` around them
+    (``olmoe_1b_7b.train_seq4096``'s case): the lowered text holds nothing
+    for the names, and is the text of rules that name nothing."""
+    entry = fa.flash_attention_with_lse if with_lse else fa.flash_attention
+    x, w = jnp.ones(_SHAPE, jnp.bfloat16), jnp.ones(_SHAPE[-1], jnp.bfloat16)
+
+    def lowered():
+        text = jax.jit(jax.grad(_loss(entry), argnums=(0, 1))).lower(x, w).as_text()
+        return re.sub(r"_\d+\b", "", text)  # the counters in private functions' names
+
+    named = lowered()
+    assert "name=dtpu_flash_fwd" not in named  # lowered: no jaxpr syntax
+    assert not any(name in named for name in fa.KEPT_UNDER_REMAT)
+    monkeypatch.setattr(fa, "checkpoint_name", lambda t, name: t)
+    assert lowered() == named
+
+
+def test_kept_bytes_are_the_two_arrays_where_the_kernel_runs(monkeypatch):
+    """``kept_under_remat_bytes`` at the two cells' shapes (bf16 ``o``, the
+    float32 ``lse`` at the padded length), and 0 wherever ``flash_attention``
+    itself takes the scan: off the TPU, in a program across devices with no
+    ``shard_map`` a data rank, past the VMEM bound."""
+    from distribuuuu_tpu.ops import pallas as tier
+    from distribuuuu_tpu.parallel import mesh as mesh_lib
+
+    ouro, glm = (1, 16, 4096, 128), (1, 20, 8192, 256)
+    assert fa.kept_under_remat_bytes(ouro, 2) == 0  # the CPU: the scan path
+    monkeypatch.setattr(tier, "interpret_mode", lambda: False)
+    mesh = mesh_lib.build_mesh(data=8)
+    assert jax.device_count() == 8 and fa.kept_under_remat_bytes(ouro, 2) == 0
+    assert fa.kept_under_remat_bytes(ouro, 2, mesh) == 0  # 1 sequence, 8 ranks
+    with tier.single_device_program():
+        assert fa.kept_under_remat_bytes(ouro, 2) == 16 * 4096 * (128 * 2 + 4)
+        assert fa.kept_under_remat_bytes(glm, 2) == 20 * 8192 * (256 * 2 + 4)
+        assert fa.kept_under_remat_bytes((1, 3, 300, 64), 4) == 3 * (300 * 64 * 4 + 384 * 4)
+        assert fa.kept_under_remat_bytes((1, 1, 65536, 128), 2) == 0  # fits_vmem
+    assert fa.kept_under_remat_bytes((8, *ouro[1:]), 2, mesh) == 8 * 16 * 4096 * 260
+    # the same answers as the routing itself
+    for shape, m in ((ouro, None), ((8, *ouro[1:]), mesh), (ouro, mesh)):
+        q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        text = str(jax.make_jaxpr(
+            lambda q: fa.flash_attention(q, q, q, causal=True, mesh=m))(q))
+        assert ("dtpu_flash_fwd" in text) == bool(fa.kept_under_remat_bytes(shape, 2, m))
+    tier.reset_selection()
 
 
 def _kept_keys(lp, L, causal):

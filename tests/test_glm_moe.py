@@ -5,6 +5,7 @@ dense MLP of 160, then 8 experts of 32 with 2 a token and a shared one, 1 + 2
 layers and the MTP module, vocab 512; two chips share each layer unless a
 test says otherwise."""
 
+import functools
 import importlib.util
 import os
 
@@ -343,6 +344,84 @@ def test_the_recomputing_step_equals_the_step_that_keeps_everything():
     (a, ga), (b, gb) = run(model), run(model.clone(recompute=False))
     np.testing.assert_allclose(a, b, rtol=1e-6)
     assert_trees_close(ga, gb, 1e-5)
+
+
+def test_what_the_recomputed_blocks_keep_of_the_flash_kernel_changes_no_bit(monkeypatch):
+    """With the kernels run (the interpreter, forced, where ``auto`` runs
+    them compiled on the chip) a recomputed block keeps their output and
+    log-sum-exp: the forward kernel runs once a block, the MTP module's too,
+    where a plain ``nn.remat`` (the policy keeping nothing) runs it twice,
+    and the loss and every gradient leaf are that step's bit for bit: what
+    is kept is what was recomputed. Against the step that recomputes nothing
+    the loss is the same bits and the gradients are as near as they were
+    before the kernel was kept (jax sums a value's several cotangents in
+    another order under a checkpoint)."""
+    from distribuuuu_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(
+        fa, "flash_attention", functools.partial(fa.flash_attention, interpret=True))
+    model = build(attn_impl="flash")
+    params, biases, tokens, labels = seeded(model, batch=1, seq=40)
+    blocks = model.depth + model.mtp_layers
+
+    def run(variant, forward_calls):
+        def loss(p):
+            return program_loss(variant, p, biases, tokens, labels)[0]
+
+        traced = jax.jit(jax.value_and_grad(loss)).trace(params)
+        text = str(traced.jaxpr)
+        assert text.count("name=dtpu_flash_fwd") == forward_calls
+        assert text.count("name=dtpu_flash_bwd") == blocks
+        return traced.lower().compile()(params)
+
+    kept = run(model, blocks)
+    nothing_recomputed = run(model.clone(recompute=False), blocks)
+    monkeypatch.setattr(
+        jax.checkpoint_policies, "save_only_these_names",
+        lambda *names: jax.checkpoint_policies.nothing_saveable)
+    plain = run(model, 2 * blocks)
+    assert float(kept[0]) == float(plain[0]) == float(nothing_recomputed[0])
+    flat = jax.tree_util.tree_leaves_with_path(kept[1])
+    for (path, got), want in zip(flat, jax.tree.leaves(plain[1]), strict=True):
+        assert float(jnp.abs(want).max()) > 0, jax.tree_util.keystr(path)
+        np.testing.assert_array_equal(got, want, err_msg=jax.tree_util.keystr(path))
+    assert_trees_close(kept[1], nothing_recomputed[1], 1e-5)
+
+
+@pytest.mark.parametrize("engaged", [True, False], ids=["kernel", "scan"])
+def test_the_plan_says_what_the_cells_blocks_keep(tmp_path, monkeypatch, engaged):
+    """``share.plan`` at ``glm_4_7_flash.train_seq8192``'s shape (1 + 4
+    layers and the MTP module, 1 x 8192 tokens, 20 heads of 256): six
+    float32 inputs of 64 MiB and, where the flash kernel runs, 6 x (80 MiB
+    of output + 0.625 MiB of log-sum-exp); nothing of it on the scan path."""
+    import json
+
+    from distribuuuu_tpu.ops import pallas as tier
+    from distribuuuu_tpu.telemetry import schema, spans
+
+    if engaged:  # what the tier answers on one chip
+        monkeypatch.setattr(tier, "interpret_mode", lambda: False)
+        monkeypatch.setattr(tier, "compiled_across_devices", lambda: False)
+    model = models.build_model(
+        "glm_4_7_flash", num_classes=154880, depth=5, share_chips=8)
+    glm_moe._planned.clear()
+    path = spans.setup_telemetry(str(tmp_path), rank=0)
+    try:
+        for _ in range(2):  # once a shape
+            glm_moe._say_plan(model, 1, 8192)
+    finally:
+        spans.close_telemetry()
+        glm_moe._planned.clear()
+    plans = [r for r in map(json.loads, open(path)) if r.get("kind") == "share.plan"]
+    assert len(plans) == 1
+    plan = plans[0]
+    schema.validate_record(plan)
+    assert (plan["experts_held"], plan["vocab_held"]) == (8, 19360)
+    assert plan["kept_flash_bytes"] == (507_248_640 if engaged else 0)
+    assert plan["kept_bytes"] == 6 * 8192 * 2048 * 4 + plan["kept_flash_bytes"]
+    said = "every block, the MTP module's too, from its float32 input"
+    assert plan["recomputed"] == said + (
+        " and the flash kernel's output and log-sum-exp" if engaged else "")
 
 
 def test_bfloat16_program_stays_near_the_reference_because_its_float32_parts_do():

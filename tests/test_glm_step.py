@@ -271,7 +271,11 @@ def test_train_net_trains_the_yaml_at_a_tiny_size_resumes_and_validates(
         "TRAIN.PRINT_FREQ", "2", "OUT_DIR", str(out_dir),
     ]
     from distribuuuu_tpu.telemetry import spans
+    from distribuuuu_tpu.utils import logger
 
+    # the log file of THIS run's OUT_DIR, whichever test of this worker
+    # process set the logger up first (it is set up once a process)
+    monkeypatch.setattr(logger, "_configured", False)
     try:
         for epochs in ("1", "2"):
             config.reset_cfg()

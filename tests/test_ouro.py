@@ -3,6 +3,7 @@
 of 16, an MLP of 176, 3 layers run 4 times, vocab 512, 128 tokens and a
 length that is no multiple of the loss chunk."""
 
+import functools
 import importlib.util
 import os
 
@@ -256,6 +257,88 @@ def test_the_recomputing_step_equals_the_step_that_keeps_everything():
     assert not any(s[-2:] == (S, S) for s in kept[True])
     assert kept[False].count(stream) > 10 * applications
     assert activations(kept[False], model.mlp_hidden) >= 3 * applications
+
+
+def test_what_the_recomputed_blocks_keep_of_the_flash_kernel_changes_no_bit(monkeypatch):
+    """With the kernels run (the interpreter, forced, where ``auto`` runs
+    them compiled on the chip) a recomputed block keeps their output and
+    log-sum-exp: the forward kernel runs once a block application,
+    where a plain ``nn.remat`` (the policy keeping nothing) runs it twice,
+    and the loss and every gradient leaf are that step's bit for bit: what
+    is kept is what was recomputed. Against the step that recomputes nothing
+    the loss is the same bits and the gradients are as near as they were
+    before the kernel was kept (jax sums a value's several cotangents in
+    another order under a checkpoint)."""
+    from distribuuuu_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(
+        fa, "flash_attention", functools.partial(fa.flash_attention, interpret=True))
+    model = build(depth=2, attn_impl="flash")
+    params, tokens, labels = seeded(model, batch=1, seq=40)
+    blocks = model.depth * model.passes
+
+    def run(variant, forward_calls):
+        def loss(p):
+            return program_loss(variant, p, tokens, labels)[0]
+
+        traced = jax.jit(jax.value_and_grad(loss)).trace(params)
+        text = str(traced.jaxpr)
+        assert text.count("name=dtpu_flash_fwd") == forward_calls
+        assert text.count("name=dtpu_flash_bwd") == blocks
+        return traced.lower().compile()(params)
+
+    kept = run(model, blocks)
+    nothing_recomputed = run(model.clone(recompute=False), blocks)
+    monkeypatch.setattr(
+        jax.checkpoint_policies, "save_only_these_names",
+        lambda *names: jax.checkpoint_policies.nothing_saveable)
+    plain = run(model, 2 * blocks)
+    assert float(kept[0]) == float(plain[0]) == float(nothing_recomputed[0])
+    flat = jax.tree_util.tree_leaves_with_path(kept[1])
+    for (path, got), want in zip(flat, jax.tree.leaves(plain[1]), strict=True):
+        assert float(jnp.abs(want).max()) > 0, jax.tree_util.keystr(path)
+        np.testing.assert_array_equal(got, want, err_msg=jax.tree_util.keystr(path))
+    assert_trees_close(kept[1], nothing_recomputed[1], 1e-5)
+
+
+@pytest.mark.parametrize("engaged", [True, False], ids=["kernel", "scan"])
+def test_the_plan_says_what_the_cells_block_applications_keep(
+        tmp_path, monkeypatch, engaged):
+    """``loop.plan`` at ``ouro_2_6b.train_seq4096``'s shape (8 layers, 4
+    passes, 1 x 4096 tokens): 32 float32 inputs of 32 MiB and, where the
+    flash kernel runs, 32 x (16 MiB of output + 0.25 MiB of log-sum-exp);
+    where the scan runs in its place nothing is named and nothing kept."""
+    import json
+
+    from distribuuuu_tpu.ops import pallas as tier
+    from distribuuuu_tpu.telemetry import schema, spans
+
+    if engaged:  # what the tier answers on one chip
+        monkeypatch.setattr(tier, "interpret_mode", lambda: False)
+        monkeypatch.setattr(tier, "compiled_across_devices", lambda: False)
+    model = models.build_model("ouro_2_6b", num_classes=49152, depth=8)
+    ouro._planned.clear()
+    path = spans.setup_telemetry(str(tmp_path), rank=0)
+    try:
+        for _ in range(2):  # once a shape
+            ouro._say_plan(model, 1, 4096)
+    finally:
+        spans.close_telemetry()
+        ouro._planned.clear()
+    plans = [r for r in map(json.loads, open(path)) if r.get("kind") == "loop.plan"]
+    assert len(plans) == 1
+    plan = plans[0]
+    schema.validate_record(plan)
+    inputs = 32 * 4096 * 2048 * 4
+    assert plan["block_applications"] == 32
+    assert plan["kept_flash_bytes"] == (545_259_520 if engaged else 0)
+    assert plan["kept_bytes"] == inputs + plan["kept_flash_bytes"]
+    said = "every block application, from its float32 input"
+    assert plan["recomputed"] == said + (
+        " and the flash kernel's output and log-sum-exp" if engaged else "")
+    nothing = ouro.kept_plan(model.clone(recompute=False), 32, 1, 4096, 128, "")
+    assert nothing == {
+        "kept_bytes": None, "kept_flash_bytes": None, "recomputed": "nothing"}
 
 
 def test_bfloat16_program_stays_near_the_reference_because_its_float32_parts_do():

@@ -759,6 +759,48 @@ def test_run_report_prints_a_shares_experts_and_its_plan(tmp_path, capsys):
     assert "of 8 chips holds 8 of 64 experts and 19360 of 154880 vocabulary rows" in printed
 
 
+@pytest.mark.parametrize("kind,where", [
+    ("loop.plan", "looped stack: 4 passes over 8 layers, 32 block applications"),
+    ("share.plan", "share of a layer: rank 0 of 8 chips"),
+])
+def test_run_report_prints_what_the_recomputed_blocks_keep(tmp_path, capsys, kind, where):
+    """Both plan records in the ``kernels`` section with what their
+    recomputed blocks keep a step: the bytes in all and, of them, the flash
+    kernel's output and log-sum-exp (``ouro_2_6b.train_seq4096``'s numbers)."""
+    import run_report
+
+    plan = {
+        "loop.plan": {"layers": 8, "passes": 4, "block_applications": 32},
+        "share.plan": {"share_chips": 8, "share_rank": 0, "experts_held": 8,
+                       "experts_total": 64, "vocab_held": 19360, "vocab_total": 154880},
+    }[kind]
+    tdir = tmp_path / "telemetry"
+    os.makedirs(tdir)
+    recs = [
+        {"kind": "clock", "rank": 0, "t": 0.0, "unix": 0.0, "mono": 0.0},
+        {"kind": "kernel.select", "rank": 0, "t": 1.0, "op": "flash_attn",
+         "impl": "pallas", "requested": "auto"},
+        {"kind": kind, "rank": 0, "t": 1.0, **plan,
+         "kept_bytes": 32 * 2**25 + 545259520, "kept_flash_bytes": 545259520,
+         "recomputed": "every block, from its float32 input and the flash "
+                       "kernel's output and log-sum-exp"},
+        {"kind": "span", "rank": 0, "t": 1.0, "v": 1, "name": "step",
+         "t0": 0.0, "dur": 0.01, "track": "pipeline", "phase": "train"},
+    ]
+    with open(tdir / "rank00000.jsonl", "w") as f:
+        for r in recs:
+            f.write(json.dumps(r) + "\n")
+    rep = run_report.build_report(str(tmp_path))
+    kern = rep["kernels"]
+    assert kern[kind.replace(".", "_")]["kept_flash_bytes"] == 545259520
+    assert kern["share_plan" if kind == "loop.plan" else "loop_plan"] is None
+    run_report._print_report(rep)
+    printed = capsys.readouterr().out
+    assert where in printed
+    assert "recomputed: every block, from its float32 input and the flash" in printed
+    assert "kept 1544.0 MiB a step, 520.0 of them the flash kernel's output" in printed
+
+
 def test_bench_index_kernel_series_and_resnet50_reference(chip_bench_root):
     """BENCH_r09's kernel_* series must ride the index WITHOUT touching
     the img/s regression reference (the PR 8 clobbering lesson): the

@@ -288,8 +288,9 @@ def test_ouro_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
     1 of the cell's 8 layers (8 compile in five minutes here, 1 in under
     one). What the benchmark's readers find in it: every scope they sum, the
     three flash kernels by name, the recomputed forward by the ``op_name``
-    ``jax.checkpoint``'s transpose gives it, and no ``while`` (a loop in a
-    device trace is one operation AND its body's)."""
+    ``jax.checkpoint``'s transpose gives it, with NO forward kernel in it,
+    and no ``while`` (a loop in a device trace is one operation AND its
+    body's)."""
     from jax.sharding import SingleDeviceSharding
 
     import distribuuuu_tpu.config as config
@@ -332,21 +333,26 @@ def test_ouro_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
         if "custom-call(" in line and "dtpu_" in line:
             name = line.split(" = ")[0].split()[-1].lstrip("%").rsplit(".", 1)[0]
             calls.setdefault(name, []).append(line.split('op_name="')[1].split('"')[0])
-    # 4 block applications: the forward kernel runs in the forward and again
-    # in the backward's recomputation, the one backward kernel once (and the
+    # 4 block applications: the forward kernel runs ONCE each, in the forward
+    # (the block keeps its output and log-sum-exp, so the backward's
+    # recomputation has no use for it), the one backward kernel once (and the
     # two empty calls under the names the benchmark's ``trace_kernels`` asks)
     assert {k: len(v) for k, v in calls.items() if "flash" in k} == {
-        "dtpu_flash_fwd": 8, "dtpu_flash_bwd": 4,
+        "dtpu_flash_fwd": 4, "dtpu_flash_bwd": 4,
         "dtpu_flash_dq": 4, "dtpu_flash_dkdv": 4}
     assert len(calls["dtpu_opt_update_adamw"]) == len(jax.tree.leaves(state.params))
-    again = [p for p in calls["dtpu_flash_fwd"] if in_scope(p, "rematted_computation")]
-    assert len(again) == 4 and all(in_scope(p, "bwd") and in_scope(p, "attn") for p in again)
+    assert all(in_scope(p, "fwd") and in_scope(p, "attn") and not in_scope(p, "bwd")
+               for p in calls["dtpu_flash_fwd"])
     for kernel in ("dtpu_flash_bwd", "dtpu_flash_dq", "dtpu_flash_dkdv"):
         assert all(in_scope(p, "bwd") and in_scope(p, "attn") for p in calls[kernel])
-        assert not any(in_scope(p, "rematted_computation") for p in calls[kernel])
-    # the recomputed forward is the blocks' alone: attention and MLP, no head
+    assert not any(in_scope(p, "rematted_computation")
+                   for kernel in calls if "flash" in kernel for p in calls[kernel])
+    # the recomputed forward is the blocks' alone, less the kernel: the MLP
+    # and attention's projections (what the backward kernel reads), no head
     recomputed = [p for p in paths if in_scope(p, "rematted_computation")]
     assert any(in_scope(p, "mlp") for p in recomputed)
+    for proj in ("q_proj", "k_proj", "v_proj"):
+        assert any(in_scope(p, "attn") and proj in p for p in recomputed), proj
     assert not any(in_scope(p, "lm_head") or in_scope(p, "exit_gate") for p in recomputed)
 
 
@@ -402,10 +408,12 @@ def test_glm_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
         if "custom-call(" in line and "dtpu_" in line:
             name = line.split(" = ")[0].split()[-1].lstrip("%").rsplit(".", 1)[0]
             calls.setdefault(name, []).append(line.split('op_name="')[1].split('"')[0])
-    # 3 blocks: the forward kernel in the forward and again in the backward's
-    # recomputation, the one backward kernel once
+    # 3 blocks: the forward kernel once each, in the forward alone (a block
+    # keeps its output and log-sum-exp), the one backward kernel once
     flash = {k: len(v) for k, v in calls.items() if "flash" in k}
-    assert (flash["dtpu_flash_fwd"], flash["dtpu_flash_bwd"]) == (6, 3)
+    assert (flash["dtpu_flash_fwd"], flash["dtpu_flash_bwd"]) == (3, 3)
+    assert not any(in_scope(p, "rematted_computation") or in_scope(p, "bwd")
+                   for p in calls["dtpu_flash_fwd"])
     assert all(in_scope(p, "attn") and not in_scope(p, "mla_latent")
                for k in ("dtpu_flash_fwd", "dtpu_flash_bwd") for p in calls[k])
     # 2 mixtures: gate_up and fwd run forward and again, the four backward

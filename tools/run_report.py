@@ -405,6 +405,18 @@ def _campaign_section(ranks: dict[int, list[dict]]) -> dict | None:
 _RECORD_KEYS = ("kind", "rank", "t", "v", "op", "impl", "requested")
 
 
+def _recomputed(plan: dict) -> str:
+    """What a ``share.plan``/``loop.plan`` record says its backward
+    computes again and, where the record has them, the bytes kept."""
+    said = f"recomputed: {plan['recomputed']}"
+    if plan.get("kept_bytes") is not None:
+        said += f"; kept {plan['kept_bytes'] / 2**20:.1f} MiB a step"
+        if plan.get("kept_flash_bytes") is not None:
+            said += (f", {plan['kept_flash_bytes'] / 2**20:.1f} of them the "
+                     "flash kernel's output and log-sum-exp")
+    return said
+
+
 def _kernels_section(ranks: dict[int, list[dict]]) -> dict | None:
     """The Pallas kernel tier (ops/pallas/): which impl actually ran per
     op (``kernel.select``; with what a knobless op says it chose: the tiles
@@ -414,12 +426,14 @@ def _kernels_section(ranks: dict[int, list[dict]]) -> dict | None:
     ``kernel_*``-labeled cost records (tools/kernel_bench.py emits them)
     — the per-kernel A/B deltas. Beside them ``share_plan``: what of each
     layer a chip of an expert-parallel group holds (``share.plan``,
-    models/glm_moe.py; the record traced last). None when the run never
-    consulted the tier (pre-tier runs are untouched)."""
+    models/glm_moe.py), and ``loop_plan``: what a looped stack runs
+    (``loop.plan``, models/ouro.py); both say what their recomputed blocks
+    keep (the record traced last). None when the run never consulted the
+    tier (pre-tier runs are untouched)."""
     selected: dict[str, dict] = {}
     fallbacks: list[dict] = []
     ab: dict[str, dict] = {}
-    share_plan = None
+    plans: dict[str, dict] = {}
     for recs in ranks.values():
         for r in recs:
             kind = r.get("kind")
@@ -429,8 +443,8 @@ def _kernels_section(ranks: dict[int, list[dict]]) -> dict | None:
                     "impl": r.get("impl"), "requested": r.get("requested"),
                     **{k: v for k, v in r.items() if k not in _RECORD_KEYS},
                 }
-            elif kind == "share.plan":
-                share_plan = {
+            elif kind in ("share.plan", "loop.plan"):
+                plans[kind] = {
                     k: v for k, v in r.items() if k not in _RECORD_KEYS}
             elif kind == "kernel.fallback":
                 fallbacks.append({
@@ -450,7 +464,8 @@ def _kernels_section(ranks: dict[int, list[dict]]) -> dict | None:
         "selected": selected,
         "fallbacks": fallbacks,
         "ab": ab or None,
-        "share_plan": share_plan,
+        "share_plan": plans.get("share.plan"),
+        "loop_plan": plans.get("loop.plan"),
     }
 
 
@@ -912,8 +927,13 @@ def _print_report(rep: dict) -> None:
             print(f"  share of a layer: rank {plan['share_rank']} of "
                   f"{plan['share_chips']} chips holds {plan['experts_held']} of "
                   f"{plan['experts_total']} experts and {plan['vocab_held']} "
-                  f"of {plan['vocab_total']} vocabulary rows; recomputed: "
-                  f"{plan['recomputed']}")
+                  f"of {plan['vocab_total']} vocabulary rows; "
+                  + _recomputed(plan))
+        plan = kern.get("loop_plan")
+        if plan:
+            print(f"  looped stack: {plan['passes']} passes over "
+                  f"{plan['layers']} layers, {plan['block_applications']} "
+                  "block applications; " + _recomputed(plan))
         if kern.get("ab"):
             for label, row in sorted(kern["ab"].items()):
                 ba = row.get("bytes_accessed")
