@@ -40,8 +40,8 @@ A wedged dispatcher (a thread that acquired the token and never
 completes its dispatch — hung storage under a fence, a stuck compile)
 surfaces through the same stall contract as everything else: the
 acquire/fence waits are wired through ``supervisor.watch_blocking`` and
-flag a ``kind="dispatch.wedge"`` record (+ the ``dispatch.wedges``
-counter and a log line) instead of hanging silently; the monitor's
+flag a ``kind="dispatch.wedge"`` record (+ a log line) instead of
+hanging silently; the monitor's
 ``dispatch-wedge`` rule (config/monitor_rules.yaml) alerts on it.
 ``FAULTS.WEDGE_DISPATCH`` injects exactly this failure for the
 ``dispatch_wedge_recovery`` drill.
@@ -112,10 +112,9 @@ class DispatchSequencer:
     # ------------------------------------------------------------ wedge
     def _flag_wedge(self, phase: str, age: float) -> None:
         """The stall-contract flag for a wedged dispatcher: log line +
-        counter + ``kind="dispatch.wedge"`` record (the monitor's
+        ``kind="dispatch.wedge"`` record (the monitor's
         dispatch-wedge rule input). One flag per excursion — the wait
         itself persists (flag, not kill)."""
-        from distribuuuu_tpu.telemetry import registry as telemetry_registry
         from distribuuuu_tpu.utils.jsonlog import metrics_log
 
         holder = self._holder or "?"
@@ -127,7 +126,6 @@ class DispatchSequencer:
             "dispatch sequencer'",
             phase, age, holder, self.wedge_timeout, holder,
         )
-        telemetry_registry.get_registry().counter("dispatch.wedges").inc(1)
         metrics_log(
             "dispatch.wedge", age_s=round(age, 3), holder=holder,
             phase=phase, count=self._wedges,

@@ -220,37 +220,45 @@ class Loader:
         """Returns ``(batch, timing)``: the batch dict plus the stage
         timestamps of its assembly (utils/jsonlog.TIMELINE_STAGES subset:
         submit/dec0/dec1/asm1 — all ``time.perf_counter`` values)."""
+        # the profiler's twins of the two stamped halves, on this worker
+        # thread's line (dtpu.loader.decode, dtpu.loader.assemble): whether
+        # the workers were busy or idle while the device waited under
+        # dtpu.trainer.wait is read off a capture from these
         dec0 = time.perf_counter()
-        images, labels = self._decode(idxs)
+        with telemetry_spans.annotate("decode"):
+            images, labels = self._decode(idxs)
         dec1 = time.perf_counter()
-        n = len(images)
-        images = np.asarray(images)
-        # DATA.DEVICE_NORMALIZE ships uint8 (4× fewer H2D bytes; the
-        # trainer normalizes in-graph); otherwise float32 as before. A
-        # dataset may pin the payload dtype instead (BATCH_DTYPE — the
-        # token species ships int32 ids that must NOT be float-cast or
-        # in-graph-normalized, data/shards/tokens.py).
-        img_dtype = getattr(self.dataset, "BATCH_DTYPE", None) or (
-            np.uint8 if images.dtype == np.uint8 else np.float32
-        )
-        batch = {
-            "image": images.astype(img_dtype, copy=False),
-            "label": labels.astype(np.int32),
-            "mask": np.ones((n,), np.float32),
-        }
-        if n < self.batch_size:  # pad ragged final eval batch, mask it out
-            pad = self.batch_size - n
-            batch["image"] = np.concatenate(
-                [batch["image"],
-                 np.zeros((pad,) + batch["image"].shape[1:], img_dtype)]
+        with telemetry_spans.annotate("assemble"):
+            n = len(images)
+            images = np.asarray(images)
+            # DATA.DEVICE_NORMALIZE ships uint8 (4× fewer H2D bytes; the
+            # trainer normalizes in-graph); otherwise float32 as before. A
+            # dataset may pin the payload dtype instead (BATCH_DTYPE — the
+            # token species ships int32 ids that must NOT be float-cast or
+            # in-graph-normalized, data/shards/tokens.py).
+            img_dtype = getattr(self.dataset, "BATCH_DTYPE", None) or (
+                np.uint8 if images.dtype == np.uint8 else np.float32
             )
-            # label shape is [B] for classification, [B, S] for the LM —
-            # pad shape-generically
-            batch["label"] = np.concatenate(
-                [batch["label"],
-                 np.zeros((pad,) + batch["label"].shape[1:], np.int32)]
-            )
-            batch["mask"] = np.concatenate([batch["mask"], np.zeros(pad, np.float32)])
+            batch = {
+                "image": images.astype(img_dtype, copy=False),
+                "label": labels.astype(np.int32),
+                "mask": np.ones((n,), np.float32),
+            }
+            if n < self.batch_size:  # pad ragged final eval batch, mask it out
+                pad = self.batch_size - n
+                batch["image"] = np.concatenate(
+                    [batch["image"],
+                     np.zeros((pad,) + batch["image"].shape[1:], img_dtype)]
+                )
+                # label shape is [B] for classification, [B, S] for the LM —
+                # pad shape-generically
+                batch["label"] = np.concatenate(
+                    [batch["label"],
+                     np.zeros((pad,) + batch["label"].shape[1:], np.int32)]
+                )
+                batch["mask"] = np.concatenate(
+                    [batch["mask"], np.zeros(pad, np.float32)]
+                )
         asm1 = time.perf_counter()
         if telemetry_spans.enabled() and cfg.TELEMETRY.STEP_SPANS:
             # worker-side halves of the batch timeline, per rank (the

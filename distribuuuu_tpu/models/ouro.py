@@ -228,10 +228,9 @@ class Ouro(nn.Module):
         exit_gate = ExitGate(name="exit_gate")
         states, gates = [], []
         for _ in range(self.passes):
-            with jax.named_scope("loop_pass"):
-                for apply_block in blocks:
-                    x = apply_block(x, positions)
-                x = final_norm(x)
+            for apply_block in blocks:
+                x = apply_block(x, positions)
+            x = final_norm(x)
             states.append(x.astype(self.dtype))
             with jax.named_scope("exit_gate"):
                 gates.append(exit_gate(x))

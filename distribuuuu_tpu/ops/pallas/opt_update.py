@@ -247,28 +247,6 @@ def adamw_leaf(p, g, mu, nu, lr, c1, c2, *, b1, b2, eps, wd, interpret,
     )
 
 
-def _gauge_plans(device, *trees) -> None:
-    """How the leaves of the update being traced go to the kernel, into the
-    telemetry registry next to ``setup.*``: ``opt_update.viewed_leaves`` /
-    ``viewed_bytes`` (every operand updated where it rests) and
-    ``copied_leaves`` / ``copied_bytes`` (an operand re-laid out for the call
-    and back), in parameter bytes.
-    Gauges of the update traced last, so a re-trace does not double them;
-    under ``shard_map`` the shapes, and so the bytes, are one shard's."""
-    from distribuuuu_tpu.telemetry import get_registry
-
-    tally = dict.fromkeys(
-        ("viewed_leaves", "viewed_bytes", "copied_leaves", "copied_bytes"), 0
-    )
-    for p, *rest in zip(*map(jax.tree.leaves, trees)):
-        copied = _plan(p.shape, [x.dtype for x in (p, *rest)], device)[3]
-        how = "copied" if copied else "viewed"
-        tally[f"{how}_leaves"] += 1
-        tally[f"{how}_bytes"] += p.size * p.dtype.itemsize
-    for name, value in tally.items():
-        get_registry().gauge(f"opt_update.{name}").set(value)
-
-
 # ------------------------------------------------- the optax-shaped update
 
 
@@ -314,7 +292,6 @@ def fused_optimizer_update(params, grads, opt_state, *, kind: str,
         found = _find_state(inner, "trace") if mom else None
         if found is not None:
             trace_state, rebuild = found
-            _gauge_plans(device, params, grads, trace_state.trace)
             out = jax.tree.map(
                 lambda p, g, t: sgd_leaf(
                     p, g, t, lr, wd=wd, mom=mom, nesterov=nesterov,
@@ -328,7 +305,6 @@ def fused_optimizer_update(params, grads, opt_state, *, kind: str,
             new_trace = jax.tree.map(lambda _, o: o[1], params, out)
             new_inner = rebuild(trace_state._replace(trace=new_trace))
         else:
-            _gauge_plans(device, params, grads)
             new_params = jax.tree.map(
                 lambda p, g: sgd_leaf(
                     p, g, None, lr, wd=wd, mom=0.0, nesterov=False,
@@ -342,7 +318,6 @@ def fused_optimizer_update(params, grads, opt_state, *, kind: str,
         count_inc = optax.safe_int32_increment(adam_state.count)
         c1 = 1 - b1 ** count_inc  # optax.tree_bias_correction's exact expr
         c2 = 1 - b2 ** count_inc
-        _gauge_plans(device, params, grads, adam_state.mu, adam_state.nu)
         out = jax.tree.map(
             lambda p, g, m, v: adamw_leaf(
                 p, g, m, v, lr, c1, c2, b1=b1, b2=b2, eps=eps, wd=wd,

@@ -237,13 +237,14 @@ KINDS: dict[str, frozenset] = {
 # telemetry.py). The JSONL record keeps the bare name; the profiler-side
 # twin of the same interval is the ``jax.profiler.TraceAnnotation``
 # ``dtpu.<layer>.<name>`` (``ANNOTATIONS``), which lands in any profiler
-# capture on the device's clock. Names measured after the fact by
-# ``emit_span`` alone (decode, assemble) have no annotation
-# site and stay JSONL-only. PERF.md "spans and counters" says which
-# metric reads each.
+# capture on the device's clock: every name has its annotation site, the
+# loader's worker threads (decode, assemble) included. PERF.md "spans,
+# counters and scopes" says which metric reads each.
 ANNOTATION_PREFIX = "dtpu."
 SPANS: dict[str, str] = {
-    # trainer loop (trainer.train_epoch, data/loader.device_prefetch)
+    # trainer loop (trainer.train_epoch and validate, data/loader.
+    # device_prefetch): ``epoch`` holds the other four on the loop's thread
+    "epoch": "trainer",
     "wait": "trainer",
     "h2d": "trainer",
     "step": "trainer",
@@ -284,7 +285,6 @@ DEVICE_SCOPES: dict[str, str] = {
     # models/ouro.py (``attn`` and ``lm_head`` as above)
     "mlp": "models",
     "exit_gate": "models",
-    "loop_pass": "models",
     # models/glm_moe.py (``attn``, ``moe``, ``mlp`` and ``lm_head`` as
     # above): latent attention's projections, norms and rotary inside
     # ``attn``; the shared expert inside ``moe``; the MTP module

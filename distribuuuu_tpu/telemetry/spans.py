@@ -143,7 +143,8 @@ def annotate(name: str):
     the enclosed statements as ``dtpu.<layer>.<name>`` in whatever profiler
     capture is open (none: a no-op). ``span()`` applies it itself; the
     sites that stamp first and ``emit_span`` later (``wait``, ``h2d``,
-    ``step``) wrap the stamped statements in it. jax is imported here, at
+    ``step`` on the loop's thread, ``decode`` and ``assemble`` on the
+    loader's workers) wrap the stamped statements in it. jax is imported here, at
     first use, so importing this module stays jax-free (the fleet router)."""
     global _TraceAnnotation
     if _TraceAnnotation is None:

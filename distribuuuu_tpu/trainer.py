@@ -357,7 +357,17 @@ class _ProfilerWindow:
         # >= not ==: a resumed epoch starts at its restored cursor, so the
         # window opens at the first step at/after START_STEP
         if self.enabled and not self.started and it >= self.first:
-            jax.profiler.start_trace(self.trace_dir)
+            # the Python tracer off (under the default one every call of the
+            # loop is an event); the host tracer keeps ``dtpu.*``. Necessary,
+            # not sufficient: PJRT lays a ``uint8`` NHWC batch out for the
+            # device tile by tile on the host, ~400,000 host events a batch of
+            # the annotations' own level, so a capture of a host-fed epoch runs
+            # about five times slower than the epoch whatever the options
+            # (PERF.md section 6, PR 35). Read device kernels off it; read the
+            # loop's shares off the ``trainer.*`` counters, which need none
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(self.trace_dir, profiler_options=options)
             self.active = self.started = True
 
     def _stop(self, state):
@@ -396,7 +406,8 @@ def _emit_batch_spans(phase: str, epoch: int, batch: int, tl: dict) -> None:
     ``kind="timeline"`` records, these land in EVERY rank's sink: the
     cross-rank step percentiles and straggler skew in
     tools/run_report.py come from exactly these spans."""
-    attrs = {"phase": phase, "epoch": epoch, "batch": batch}
+    attrs = {"phase": phase, "epoch": epoch, "batch": batch,
+             "parent": "epoch", "depth": 1}
     if "get0" in tl and "get1" in tl:
         telemetry_spans.emit_span(
             "wait", tl["get0"], tl["get1"], track="pipeline", **attrs
@@ -484,7 +495,16 @@ def train_epoch(loader, mesh, state, train_step, epoch: int, logger,
     windows_seen = 0
     accum = max(1, cfg.TRAIN.GRAD_ACCUM_STEPS)
 
+    # the loop's counts (PERF.md "spans, counters and scopes"): always
+    # counted, like ``setup.*`` and ``jit.*``, from the stamps the loop takes
+    # anyway; the train loop's alone (``validate`` counts nothing)
+    count = telemetry.get_registry().counter
+    n_steps, n_epochs = count("trainer.steps"), count("trainer.epochs")
+    wait_s, h2d_s = count("trainer.wait_s"), count("trainer.h2d_s")
+    h2d_bytes, fetch_s = count("trainer.h2d_bytes"), count("trainer.fetch_s")
+
     def put_batch(hb):
+        h2d_bytes.inc(sum(getattr(v, "nbytes", 0) for v in hb.values()))
         if accum > 1:
             return sharding_lib.shard_micro_batch(mesh, hb, accum)
         return sharding_lib.shard_batch(mesh, hb)
@@ -514,6 +534,7 @@ def train_epoch(loader, mesh, state, train_step, epoch: int, logger,
             return
         # the float() reads below are the loop's only fence on the device:
         # device-idle gaps under this span are the print interval's price
+        fetch0 = time.perf_counter()
         with telemetry_spans.span("metrics_fetch", track="pipeline"):
             for m in pending:
                 if nf_mon.observe(
@@ -525,6 +546,7 @@ def train_epoch(loader, mesh, state, train_step, epoch: int, logger,
                 topk_m.update(float(m["topk"]))
                 if "moe_dropped" in m:
                     moe_dropped.update(float(m["moe_dropped"]))
+        fetch_s.inc(time.perf_counter() - fetch0)
         pending.clear()
 
     def maybe_print():
@@ -574,49 +596,63 @@ def train_epoch(loader, mesh, state, train_step, epoch: int, logger,
     emit_timeline = cfg.TRAIN.TIMELINE and mesh_lib.is_primary()
     emit_spans = _step_spans_on()
     try:
-        # Per-step dispatch through the device-side prefetch ring
-        # (data/loader.device_prefetch): the H2D transfer of batches
-        # it+1..it+depth is dispatched while the step for batch `it` runs,
-        # so transfer never serializes behind the step; depth 0 restores
-        # the serial put-then-step order. Results are value-bit-identical
-        # at every depth (same put/step order — tests/test_overlap.py).
-        # Each dispatched batch leaves one kind="timeline" record with its
-        # stage-boundary timestamps (tools/overlap_report.py attributes
-        # the epoch wall from them).
-        depth = max(0, cfg.TRAIN.PREFETCH_DEVICE)
-        end = time.perf_counter()
-        for it, batch, tl in device_prefetch(loader, put_batch, depth):
-            abs_it = start_batch + it  # loader skipped the resumed prefix
-            heartbeat.beat(f"epoch {epoch + 1} batch {abs_it}")
-            faults.maybe_stall(epoch, abs_it)  # injection no-ops (FAULTS.*)
-            faults.maybe_kill(epoch, abs_it)
-            faults.maybe_preempt(epoch, abs_it)
-            faults.maybe_recompile(epoch, abs_it)
-            faults.maybe_slowdown(epoch, abs_it)
-            data_time.update(tl["get1"] - tl["get0"])
-            _capture_step_cost(
-                train_step, state, batch, label="train_step", phase="train"
-            )
-            prof.begin(abs_it)
-            tl["step0"] = time.perf_counter()
-            with telemetry_spans.annotate("step"):
-                state, metrics = sequencer.dispatch(
-                    sequencer.TRAIN_STREAM, train_step, state, batch
-                )
-            tl["step1"] = time.perf_counter()
-            prof.end(abs_it, state)
-            pending.append(metrics)
-            done += 1
-            batch_time.update(time.perf_counter() - end)
+        # ``epoch`` holds the whole dispatch loop: wait, h2d, step and
+        # metrics_fetch are its children on this thread, so its SELF time in
+        # a capture is the loop's own host work (heartbeat, fault hooks, cost
+        # capture, meters, log lines). A track of its own in the JSONL sink:
+        # run_report and live.py take the pipeline track's first start and
+        # last end for a window's wall, which an epoch-long record would
+        # stretch back to the epoch's start
+        with telemetry_spans.span(
+            "epoch", track="epoch", epoch=epoch + 1, phase="train"
+        ):
+            # Per-step dispatch through the device-side prefetch ring
+            # (data/loader.device_prefetch): the H2D transfer of batches
+            # it+1..it+depth is dispatched while the step for batch `it` runs,
+            # so transfer never serializes behind the step; depth 0 restores
+            # the serial put-then-step order. Results are value-bit-identical
+            # at every depth (same put/step order — tests/test_overlap.py).
+            # Each dispatched batch leaves one kind="timeline" record with its
+            # stage-boundary timestamps (tools/overlap_report.py attributes
+            # the epoch wall from them).
+            depth = max(0, cfg.TRAIN.PREFETCH_DEVICE)
             end = time.perf_counter()
-            if emit_spans:
-                _emit_batch_spans("train", epoch + 1, abs_it, tl)
-            if emit_timeline:
-                timeline_log("train", epoch + 1, abs_it, tl.pop("n", 0), **tl)
-            maybe_print()
-            if preempt_break(done):
-                break
-        prof.finish(state)
+            for it, batch, tl in device_prefetch(loader, put_batch, depth):
+                abs_it = start_batch + it  # loader skipped the resumed prefix
+                heartbeat.beat(f"epoch {epoch + 1} batch {abs_it}")
+                faults.maybe_stall(epoch, abs_it)  # injection no-ops (FAULTS.*)
+                faults.maybe_kill(epoch, abs_it)
+                faults.maybe_preempt(epoch, abs_it)
+                faults.maybe_recompile(epoch, abs_it)
+                faults.maybe_slowdown(epoch, abs_it)
+                data_time.update(tl["get1"] - tl["get0"])
+                wait_s.inc(tl["get1"] - tl["get0"])
+                h2d_s.inc(tl["put1"] - tl["put0"])
+                _capture_step_cost(
+                    train_step, state, batch, label="train_step", phase="train"
+                )
+                prof.begin(abs_it)
+                tl["step0"] = time.perf_counter()
+                with telemetry_spans.annotate("step"):
+                    state, metrics = sequencer.dispatch(
+                        sequencer.TRAIN_STREAM, train_step, state, batch
+                    )
+                tl["step1"] = time.perf_counter()
+                prof.end(abs_it, state)
+                pending.append(metrics)
+                done += 1
+                n_steps.inc(1)
+                batch_time.update(time.perf_counter() - end)
+                end = time.perf_counter()
+                if emit_spans:
+                    _emit_batch_spans("train", epoch + 1, abs_it, tl)
+                if emit_timeline:
+                    timeline_log("train", epoch + 1, abs_it, tl.pop("n", 0), **tl)
+                maybe_print()
+                if preempt_break(done):
+                    break
+            prof.finish(state)
+        n_epochs.inc(1)
     finally:
         heartbeat.stop()
     return state, interrupted, done
@@ -656,76 +692,80 @@ def validate(loader, mesh, state, eval_step, epoch: int, logger,
     emit_timeline = cfg.TRAIN.TIMELINE and mesh_lib.is_primary()
     emit_spans = _step_spans_on()
     depth = max(0, cfg.TRAIN.PREFETCH_DEVICE)
-    end = time.perf_counter()
-    for it, batch, tl in device_prefetch(
-        loader, functools.partial(sharding_lib.shard_batch, mesh), depth
+    # the same span as train_epoch's, told apart by ``phase``
+    with telemetry_spans.span(
+        "epoch", track="epoch", epoch=epoch + 1, phase="eval"
     ):
-        _capture_step_cost(
-            eval_step, state, batch, label="eval_step", phase="eval"
-        )
-        tl["step0"] = time.perf_counter()
-        # eval steps do not chain through data dependencies, so under
-        # the sequencer each one is dispatched fenced (outputs ready
-        # before the token releases) — the eval thread absorbs the wait,
-        # the train stream never fences on eval (asyncplane/sequencer.py
-        # has the dispatch-ordering story); pass-through when inactive
-        with telemetry_spans.annotate("step"):
-            m = sequencer.dispatch(
-                sequencer.EVAL_STREAM, eval_step, state, batch, fence=True
-            )
-        totals = (
-            m
-            if totals is None
-            else jax.tree.map(jnp.add, totals, m)
-        )
-        tl["step1"] = time.perf_counter()
-        if emit_spans:
-            _emit_batch_spans("eval", epoch + 1, it, tl)
-        if emit_timeline:
-            timeline_log("eval", epoch + 1, it, tl.pop("n", 0), **tl)
-        at_check_site = (
-            watch_preemption
-            and (it + 1) % cfg.TEST.PRINT_FREQ == 0
-            and it + 1 < num_batches
-        )
-        if at_check_site:
-            checks_seen += 1
-        if (
-            at_check_site
-            and checks_seen % preempt_check_every == 0
-            and preempt.requested_global()
+        end = time.perf_counter()
+        for it, batch, tl in device_prefetch(
+            loader, functools.partial(sharding_lib.shard_batch, mesh), depth
         ):
-            # deterministic check sites (same batch indices on every
-            # process) — abandon the eval; the caller saves and exits
-            if mesh_lib.is_primary():
-                logger.warning(
-                    "preemption signaled — abandoning eval at batch %d/%d",
-                    it + 1, num_batches,
+            _capture_step_cost(
+                eval_step, state, batch, label="eval_step", phase="eval"
+            )
+            tl["step0"] = time.perf_counter()
+            # eval steps do not chain through data dependencies, so under
+            # the sequencer each one is dispatched fenced (outputs ready
+            # before the token releases) — the eval thread absorbs the wait,
+            # the train stream never fences on eval (asyncplane/sequencer.py
+            # has the dispatch-ordering story); pass-through when inactive
+            with telemetry_spans.annotate("step"):
+                m = sequencer.dispatch(
+                    sequencer.EVAL_STREAM, eval_step, state, batch, fence=True
                 )
-            return None
-        if (it + 1) % cfg.TEST.PRINT_FREQ == 0 and mesh_lib.is_primary() \
-                and not quiet:
-            # async metric fetch (same treatment the train loop gives its
-            # metrics): start the host copy of THIS window's totals and log
-            # the PREVIOUS window's — already landed, so reading it costs
-            # nothing and eval batches keep dispatching back-to-back
-            # (the blocking fetch here was the last per-N-batches host sync)
-            for leaf in jax.tree.leaves(totals):
-                leaf.copy_to_host_async()
-            if pending_print is not None:
-                pit, ptot = pending_print
-                acc1_so_far = (
-                    float(ptot["correct1"]) / max(float(ptot["count"]), 1.0) * 100.0
-                )
-                window = time.perf_counter() - end
-                logger.info(
-                    "Eval[%d][%d/%d]  Time %6.3f (%.3f/batch)  "
-                    "Acc@1 %.3f (through batch %d)",
-                    epoch + 1, it + 1, num_batches,
-                    window, window / cfg.TEST.PRINT_FREQ, acc1_so_far, pit,
-                )
-            end = time.perf_counter()
-            pending_print = (it + 1, totals)
+            totals = (
+                m
+                if totals is None
+                else jax.tree.map(jnp.add, totals, m)
+            )
+            tl["step1"] = time.perf_counter()
+            if emit_spans:
+                _emit_batch_spans("eval", epoch + 1, it, tl)
+            if emit_timeline:
+                timeline_log("eval", epoch + 1, it, tl.pop("n", 0), **tl)
+            at_check_site = (
+                watch_preemption
+                and (it + 1) % cfg.TEST.PRINT_FREQ == 0
+                and it + 1 < num_batches
+            )
+            if at_check_site:
+                checks_seen += 1
+            if (
+                at_check_site
+                and checks_seen % preempt_check_every == 0
+                and preempt.requested_global()
+            ):
+                # deterministic check sites (same batch indices on every
+                # process) — abandon the eval; the caller saves and exits
+                if mesh_lib.is_primary():
+                    logger.warning(
+                        "preemption signaled — abandoning eval at batch %d/%d",
+                        it + 1, num_batches,
+                    )
+                return None
+            if (it + 1) % cfg.TEST.PRINT_FREQ == 0 and mesh_lib.is_primary() \
+                    and not quiet:
+                # async metric fetch (same treatment the train loop gives its
+                # metrics): start the host copy of THIS window's totals and log
+                # the PREVIOUS window's — already landed, so reading it costs
+                # nothing and eval batches keep dispatching back-to-back
+                # (the blocking fetch here was the last per-N-batches host sync)
+                for leaf in jax.tree.leaves(totals):
+                    leaf.copy_to_host_async()
+                if pending_print is not None:
+                    pit, ptot = pending_print
+                    acc1_so_far = (
+                        float(ptot["correct1"]) / max(float(ptot["count"]), 1.0) * 100.0
+                    )
+                    window = time.perf_counter() - end
+                    logger.info(
+                        "Eval[%d][%d/%d]  Time %6.3f (%.3f/batch)  "
+                        "Acc@1 %.3f (through batch %d)",
+                        epoch + 1, it + 1, num_batches,
+                        window, window / cfg.TEST.PRINT_FREQ, acc1_so_far, pit,
+                    )
+                end = time.perf_counter()
+                pending_print = (it + 1, totals)
     totals = jax.tree.map(float, totals)
     n = max(totals["count"], 1.0)
     top1 = totals["correct1"] / n * 100.0

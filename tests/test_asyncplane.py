@@ -516,11 +516,12 @@ def test_sequencer_wedge_flag_and_record(tmp_path):
     out = sequencer.dispatch("eval", lambda: 2)  # blocks behind the wedge
     t.join(timeout=30)
     assert out == 2  # the run survived the wedge
-    assert reg.snapshot()["counters"].get("dispatch.wedges", 0) >= 1
     spans.close_telemetry()
     recs = [json.loads(ln) for ln in open(path).read().splitlines()]
     wedge = [r for r in recs if r.get("kind") == "dispatch.wedge"]
     assert wedge and wedge[0]["holder"] == "train"
+    # the count of wedges lives in the record (live.py sums the records)
+    assert [r["count"] for r in wedge] == list(range(1, len(wedge) + 1))
     for r in wedge:
         schema.validate_record(r)
     sequencer.shutdown()
@@ -968,11 +969,11 @@ def test_ring_deadline_miss_flags_wedge_then_completes(tmp_path):
     assert follow_ring.wedged and not follow_ring.detached
     assert follow_ring.stats["deadline_misses"] == 1
     assert seq_f._ring_wedged  # the trainer's epoch-boundary signal
-    assert reg.snapshot()["counters"].get("dispatch.wedges", 0) >= 1
     spans.close_telemetry()
     recs = [json.loads(ln) for ln in open(path).read().splitlines()]
     wedge = [r for r in recs if r.get("kind") == "dispatch.wedge"]
     assert wedge and "ring slot 0" in wedge[0]["phase"]
+    assert [r["count"] for r in wedge] == list(range(1, len(wedge) + 1))
     for r in wedge:
         schema.validate_record(r)
 

@@ -19,7 +19,7 @@ from distribuuuu_tpu.parallel.partition import lowering, topology as topo_lib
 from distribuuuu_tpu.telemetry import runtime, schema, spans
 from distribuuuu_tpu.utils.optim import construct_optimizer
 
-from benchmark.harness import program_spans
+from benchmark.harness import loop_capture, program_spans
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "tools"))
@@ -163,3 +163,80 @@ def test_span_name_table_and_its_static_check(tmp_path):
     )
     violations, _ = checker.check_file(str(bad), "mod.py")
     assert len(violations) == 1 and "bogus_span" in violations[0]
+
+
+class _Pool:
+    """32 tiny uint8 images: four batches of eight (the 8-device mesh)."""
+
+    def __len__(self):
+        return 32
+
+    def __getitem__(self, i):
+        import numpy as np
+
+        return np.full((8, 8, 3), i, np.uint8), i % 4
+
+
+def test_a_capture_of_the_loop_holds_the_epoch_and_the_loaders_workers(
+        tmp_path, registry):
+    """``train_epoch`` over the program's ``Loader`` under a CPU capture with
+    the Python tracer off (as ``_ProfilerWindow`` and the benchmark's
+    ``loop_capture`` start it): ``dtpu.trainer.epoch`` encloses every
+    ``dtpu.trainer.step``, ``wait``, ``h2d`` and ``metrics_fetch`` on the
+    loop's thread, and ``dtpu.loader.decode`` / ``assemble`` lie on the
+    worker threads' lines, one pair a batch."""
+    import types
+
+    from distribuuuu_tpu.data.loader import Loader
+    from distribuuuu_tpu.utils.logger import get_logger
+
+    cfg.TRAIN.PRINT_FREQ = 2
+    loader = Loader(_Pool(), batch_size=8, shuffle=True, drop_last=True,
+                    workers=2, seed=3)
+    state = trainer.TrainState(
+        params={}, batch_stats={}, step=0, key=None,
+        opt_state=types.SimpleNamespace(hyperparams={}),
+    )
+    double = jax.jit(lambda x: (x.astype(jnp.float32) * 2).sum())
+    double(jnp.zeros((8, 8, 8, 3), jnp.uint8)).block_until_ready()
+
+    def step(state, batch):
+        loss = double(batch["image"])
+        return state.replace(step=state.step + 1), {
+            "loss": loss, "top1": loss, "topk": loss}
+
+    trace_dir = str(tmp_path / "profile")
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    try:
+        trainer.train_epoch(
+            loader=loader, mesh=mesh_lib.build_mesh(), state=state,
+            train_step=step, epoch=0, logger=get_logger(),
+        )
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
+    # one thread name a host line: the lines are all named after the process
+    found = program_spans.ProgramSpans(loop_capture.load_spans(path))
+    assert found.names() == [
+        "dtpu.loader.assemble", "dtpu.loader.decode", "dtpu.trainer.epoch",
+        "dtpu.trainer.h2d", "dtpu.trainer.metrics_fetch", "dtpu.trainer.step",
+        "dtpu.trainer.wait"]
+    (epoch,) = [s for s in found.spans if s["name"] == "dtpu.trainer.epoch"]
+    counts = {n: t["count"] for n, t in found.totals().items()}
+    assert counts["dtpu.trainer.step"] == counts["dtpu.trainer.h2d"] == 4
+    assert counts["dtpu.loader.decode"] == counts["dtpu.loader.assemble"] == 4
+    assert counts["dtpu.trainer.metrics_fetch"] == 2
+    for s in found.spans:
+        if s["name"].startswith("dtpu.trainer.") and s is not epoch:
+            assert s["thread"] == epoch["thread"]
+            assert epoch["start_ns"] <= s["start_ns"]
+            assert (s["start_ns"] + s["dur_ns"]
+                    <= epoch["start_ns"] + epoch["dur_ns"])
+        if s["name"].startswith("dtpu.loader."):
+            assert s["thread"] != epoch["thread"]
+    # the epoch's self time is what no child holds: positive, under its total
+    total = found.totals()["dtpu.trainer.epoch"]
+    assert 0 < total["self_s"] < total["total_s"]
+    assert registry.snapshot()["counters"]["trainer.steps"] == 4

@@ -326,7 +326,7 @@ def test_ouro_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
     assert " while(" not in text and " conditional(" not in text
     paths = list(op_names_from_hlo(text).values())
     for scope in ("fwd", "bwd", "attn", "mlp", "exit_gate", "lm_head",
-                  "optimizer_update", "opt_kernel", "loop_pass"):
+                  "optimizer_update", "opt_kernel"):
         assert any(in_scope(p, scope) for p in paths), scope
     calls = {}
     for line in text.splitlines():

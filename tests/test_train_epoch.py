@@ -86,3 +86,84 @@ def test_every_steps_metrics_reach_the_meters_once(print_freq, monkeypatch):
     for rec in printed:
         seen = [i + 1 for i in kept if i < rec["batch"]]
         assert rec["loss"] == pytest.approx(sum(seen) / len(seen))
+
+
+def _bare_state():
+    return trainer.TrainState(
+        params={}, batch_stats={}, step=0, key=None,
+        opt_state=types.SimpleNamespace(hyperparams={}),
+    )
+
+
+def test_the_loops_counters_count_with_every_sink_closed():
+    """``trainer.*`` registry counters after one CPU epoch with no JSONL
+    sink, no ``metrics.jsonl`` and no capture open: what the benchmark's
+    ``trainer.*`` readers find in a process that opened none."""
+    from distribuuuu_tpu import telemetry
+    from distribuuuu_tpu.telemetry import spans
+
+    config.reset_cfg()
+    cfg.TRAIN.PRINT_FREQ = 2
+    cfg.TRAIN.NONFINITE = "skip"  # the stub flags one step
+    spans.close_telemetry()
+    registry = telemetry.get_registry()
+    registry.reset()
+    try:
+        state, _, done = trainer.train_epoch(
+            loader=_Batches(), mesh=mesh_lib.build_mesh(), state=_bare_state(),
+            train_step=_stub_step, epoch=0, logger=get_logger(),
+        )
+        counters = registry.snapshot()["counters"]
+    finally:
+        registry.reset()
+    assert done == N_BATCHES
+    assert {n for n in counters if n.startswith("trainer.")} == {
+        "trainer.steps", "trainer.epochs", "trainer.wait_s", "trainer.h2d_s",
+        "trainer.h2d_bytes", "trainer.fetch_s"}
+    batch_bytes = 8 * 4 * 4 * 3 * 4 + 8 * 4  # one _Batches batch, as handed over
+    assert counters["trainer.steps"] == N_BATCHES
+    assert counters["trainer.epochs"] == 1
+    assert counters["trainer.h2d_bytes"] == N_BATCHES * batch_bytes
+    assert counters["trainer.wait_s"] > 0 and counters["trainer.h2d_s"] > 0
+    assert counters["trainer.fetch_s"] > 0
+
+
+def test_epoch_is_the_jsonl_parent_of_the_loops_four_spans(tmp_path):
+    """With the sink open the ``epoch`` span closes last, carries ``epoch``
+    and ``phase``, and ``wait``/``h2d``/``step``/``metrics_fetch`` name it
+    as their parent."""
+    import json
+
+    from distribuuuu_tpu.telemetry import schema, spans
+
+    assert schema.SPANS["epoch"] == "trainer"
+    assert schema.ANNOTATIONS["epoch"] == "dtpu.trainer.epoch"
+    config.reset_cfg()
+    cfg.TRAIN.PRINT_FREQ = 2
+    cfg.TRAIN.NONFINITE = "skip"  # the stub flags one step
+    sink = spans.setup_telemetry(str(tmp_path / "telemetry"))
+    try:
+        trainer.train_epoch(
+            loader=_Batches(), mesh=mesh_lib.build_mesh(), state=_bare_state(),
+            train_step=_stub_step, epoch=0, logger=get_logger(),
+        )
+    finally:
+        spans.close_telemetry()
+    with open(sink) as f:
+        records = [json.loads(line) for line in f]
+    for record in records:
+        schema.validate_record(record)
+    written = [r for r in records if r["kind"] == "span"]
+    assert written[-1]["name"] == "epoch"
+    epoch = written[-1]
+    # a track of its own: the pipeline track's extent is a window's wall
+    # for run_report and live.py
+    assert (epoch["epoch"], epoch["phase"], epoch["track"]) == (1, "train", "epoch")
+    assert "parent" not in epoch
+    children = written[:-1]
+    assert {r["name"] for r in children} == {"wait", "h2d", "step", "metrics_fetch"}
+    assert [r["name"] for r in children].count("step") == N_BATCHES
+    for r in children:
+        assert (r["parent"], r["depth"], r["track"]) == ("epoch", 1, "pipeline")
+        assert epoch["t0"] <= r["t0"]
+        assert r["t0"] + r["dur"] <= epoch["t0"] + epoch["dur"] + 1e-5
