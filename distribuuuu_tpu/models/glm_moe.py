@@ -45,7 +45,7 @@ the mixture in every later one.
 * parameters and the residual stream are float32, the matmuls read
   ``dtype``; norms, router, softmaxes and loss are float32.
 * every block is recomputed in the backward from its float32 input and
-  the flash kernel's output and log-sum-exp (``recompute``), as
+  the flash kernel's output, log-sum-exp, q, k and v (``recompute``), as
   ``models/ouro.py``'s: six blocks' activations at 8192 tokens do not fit
   beside 11.3 GB of parameters and AdamW state.
 
@@ -291,8 +291,8 @@ class GLMMoE(nn.Module):
     dtype: Any = jnp.bfloat16
     attn_impl: str = "auto"
     mesh: Any = None
-    # a block keeps its float32 input and the flash kernel's output and
-    # log-sum-exp, nothing else (see above)
+    # a block keeps its float32 input and what the flash backward kernel
+    # reads (the forward's output, log-sum-exp, q, k and v), nothing else
     recompute: bool = True
     # positions of every row the head takes at a time; its rows are the
     # batch's sequences twice over (the trunk's and the MTP module's)

@@ -23,12 +23,15 @@ that says after which pass a token may leave.
 * parameters and the residual stream are float32, the matmuls read
   ``dtype``; norms, gate, exit distribution and loss are float32.
 * every block application is recomputed in the backward from its float32
-  input and, where the flash kernel ran, its output and log-sum-exp
-  (``ops/flash_attention.KEPT_UNDER_REMAT``: 16.3 MiB beside the input's 32
-  at the cell's shape, so the recomputation never runs the forward kernel
-  again); that is all it keeps (``recompute``): R x L applications hold R
-  times the activations a parameter, and 32 of them at 4096 tokens do not
-  fit a 16 GB chip beside the AdamW state otherwise (PERF.md section 4).
+  input and, where the flash kernels ran, what the backward kernel reads:
+  the forward kernel's output and log-sum-exp and its q, k and v
+  (``ops/flash_attention.KEPT_UNDER_REMAT``: 16.3 + 48 MiB beside the
+  input's 32 at the cell's shape, so the recomputation never runs the
+  forward kernel, the three projections, rotary or the head layouts again:
+  it is the norms, ``W_o`` and the MLP); that is all it keeps
+  (``recompute``): R x L applications hold R times the activations a
+  parameter, and 32 of them at 4096 tokens do not fit a 16 GB chip beside
+  the AdamW state otherwise (PERF.md section 4).
   Passes and layers are Python loops: a ``while`` shows in a device trace
   as one operation AND its body's, and every scope sum would count twice.
 
@@ -155,8 +158,9 @@ def kept_plan(model, blocks: int, batch: int, seq: int, head_dim: int,
     """The fields of a plan record (``loop.plan``; ``share.plan`` of
     ``models/glm_moe.py``) that say what ``blocks`` recomputed blocks keep a
     step: ``kept_bytes`` (their float32 inputs and ``kept_flash_bytes``),
-    ``kept_flash_bytes`` (the flash kernel's output and log-sum-exp; 0 where
-    attention takes another path, which names nothing) and ``recomputed``."""
+    ``kept_flash_bytes`` (the flash kernel's output and log-sum-exp and its
+    q, k and v; 0 where attention takes another path, which names nothing)
+    and ``recomputed``."""
     if not model.recompute:
         return {"kept_bytes": None, "kept_flash_bytes": None, "recomputed": "nothing"}
     from distribuuuu_tpu.models.vit import Attention as VitAttention
@@ -171,7 +175,8 @@ def kept_plan(model, blocks: int, batch: int, seq: int, head_dim: int,
         "kept_bytes": blocks * batch * seq * model.dim * 4 + flash,
         "kept_flash_bytes": flash,
         "recomputed": f"{what}, from its float32 input" + (
-            " and the flash kernel's output and log-sum-exp" if flash else ""),
+            " and the flash kernel's output, log-sum-exp, q, k and v"
+            if flash else ""),
     }
 
 
@@ -191,8 +196,9 @@ class Ouro(nn.Module):
     dtype: Any = jnp.bfloat16
     attn_impl: str = "auto"
     mesh: Any = None
-    # a block application keeps its input and the flash kernel's output and
-    # log-sum-exp, nothing else (see above)
+    # a block application keeps its input and what the flash backward kernel
+    # reads (the forward's output, log-sum-exp, q, k and v), nothing else
+    # (see above)
     recompute: bool = True
     # positions of every row the head takes at a time; its rows are the
     # batch's sequences R times over

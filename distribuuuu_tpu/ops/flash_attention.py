@@ -60,12 +60,15 @@ from distribuuuu_tpu.ops.pallas.moe_gmm import _dot  # a · b over (dim, dim), f
 
 _NEG_BIG = -0.7 * float(jnp.finfo(jnp.float32).max)
 
-# The names of the forward kernel's output and log-sum-exp in the forward
-# rules (``_residuals``). A block under ``jax.checkpoint``/``nn.remat`` whose
-# policy is ``save_only_these_names(*KEPT_UNDER_REMAT)`` keeps the two and
-# its recomputation has no use for ``dtpu_flash_fwd``; under no policy, or
-# under no checkpoint at all, the names lower to nothing.
-KEPT_UNDER_REMAT = ("flash_o", "flash_lse")
+# The names the forward rules (``_residuals``) give the forward kernel's
+# output and log-sum-exp and its three inputs as BOTH kernels take them
+# (``[b·h, lp, d]``: projected, rotated, heads-major, padded). A block under
+# ``jax.checkpoint``/``nn.remat`` whose policy is
+# ``save_only_these_names(*KEPT_UNDER_REMAT)`` keeps the five: its
+# recomputation has no use for ``dtpu_flash_fwd`` nor for whatever made q, k
+# and v out of the block's input; under no policy, or under no checkpoint at
+# all, the names lower to nothing.
+KEPT_UNDER_REMAT = ("flash_o", "flash_lse", "flash_q", "flash_k", "flash_v")
 
 # A v5e core has 128 MiB of VMEM and Mosaic's scoped default is 16 MiB, which
 # the fused backward's resident set passes at 4096 tokens: the calls ask for
@@ -441,19 +444,21 @@ def _flash_attention(q, k, v, scale, interpret, blk_q, blk_k, causal):
 
 
 def _residuals(q, k, v, scale, interpret, blk_q, blk_k, causal):
-    """``(o, lse, residuals)`` of a forward rule, ``o`` and ``lse`` NAMED
-    (:data:`KEPT_UNDER_REMAT`). The rule's primal output must be this named
-    ``o`` too, not only the residual: a recomputation that kept the residual
-    would still run the kernel for the un-named ``o`` that ``W_o`` reads.
-    ``qf``, ``kf``, ``vf`` are not named: a recomputed block computes q, k
-    and v again for the backward kernel (its projections, norms, rotary and
-    head transposes: PERF.md section 6, PR 34, sizes what keeping them buys)."""
-    o, lse, (qf, kf, vf) = _flash_forward(
-        q, k, v, scale, interpret, blk_q, blk_k, causal
-    )
-    o = checkpoint_name(o, KEPT_UNDER_REMAT[0])
-    lse = checkpoint_name(lse, KEPT_UNDER_REMAT[1])
-    return o, lse, (qf, kf, vf, lse, o, q.shape)
+    """``(o, lse, residuals)`` of a forward rule, everything the backward
+    kernel reads NAMED (:data:`KEPT_UNDER_REMAT`): ``o``, ``lse`` and ``qf``,
+    ``kf``, ``vf`` as the kernels take them. The rule's primal output must be
+    this named ``o`` too, not only the residual: a recomputation that kept
+    the residual would still run the kernel for the un-named ``o`` that
+    ``W_o`` reads. The un-named ``qf``, ``kf``, ``vf`` feed the forward kernel
+    alone, so with its outputs kept nothing upstream of them (a block's
+    projections, rotary, head transposes, casts, the pad) is wanted again:
+    what a projection's own backward reads is its input, the norm's output
+    (pinned on the gradient's jaxpr in ``tests/test_flash_attention.py``)."""
+    o, lse, qkv = _flash_forward(q, k, v, scale, interpret, blk_q, blk_k, causal)
+    o, lse, *qkv = (
+        checkpoint_name(t, name)
+        for t, name in zip((o, lse, *qkv), KEPT_UNDER_REMAT))
+    return o, lse, (*qkv, lse, o, q.shape)
 
 
 def _fa_fwd(q, k, v, scale, interpret, blk_q, blk_k, causal):
@@ -597,16 +602,17 @@ def flash_attention(
 def kept_under_remat_bytes(q_shape, itemsize: int, mesh=None) -> int:
     """Bytes of :data:`KEPT_UNDER_REMAT` one ``flash_attention`` call at
     ``q_shape`` leaves a recomputed block that keeps them (``o`` as it is
-    returned, the float32 ``lse`` at the padded length); 0 where the call
-    takes the scan path, which names nothing. What a model's plan record
-    says (``loop.plan``, ``share.plan``): the questions are
+    returned, the float32 ``lse`` and q, k and v at the padded length); 0
+    where the call takes the scan path, which names nothing. What a model's
+    plan record says (``loop.plan``, ``share.plan``): the questions are
     ``flash_attention``'s own (the platform, one device or a ``shard_map``
     a data rank, the VMEM bound) asked without a ``kernel.select`` record."""
     b, h, L, d = q_shape
     across = _data_ranks(mesh, b) == 1 and kernel_tier.compiled_across_devices()
     if kernel_tier.interpret_mode() or across or not fits_vmem(L, d, itemsize):
         return 0
-    return b * h * (L * d * itemsize + _round_up(L, 128) * 4)
+    lp = _round_up(L, 128)
+    return b * h * (L * d * itemsize + lp * 4 + 3 * lp * d * itemsize)
 
 
 def flash_attention_with_lse(

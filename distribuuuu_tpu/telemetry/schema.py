@@ -156,8 +156,8 @@ KINDS: dict[str, frozenset] = {
     # one per traced shape of a looped stack (models/ouro.py): R passes over
     # L blocks, what the R x L block applications keep for the backward
     # (bytes a step: their float32 inputs and kept_flash_bytes, the flash
-    # kernel's output and log-sum-exp, 0 where the scan path ran) and what
-    # the backward computes again
+    # kernel's output, log-sum-exp, q, k and v, 0 where the scan path ran)
+    # and what the backward computes again
     "loop.plan": frozenset(
         {"layers", "passes", "block_applications", "kept_bytes",
          "kept_flash_bytes", "recomputed"}

@@ -21,6 +21,7 @@ import pytest
 
 from distribuuuu_tpu.ops import flash_attention as fa
 from distribuuuu_tpu.ops.ring_attention import reference_attention
+from test_ouro import forward_matmuls
 
 BLK = dict(blk_q=256, blk_k=256)
 
@@ -219,13 +220,15 @@ def _loss(entry):
     return loss
 
 
-def _trapped(with_lse):
-    """A local copy of the forward rule that names ONLY the residuals: the
-    primal output is the kernel's own, un-named ``o``."""
+def _local_rule(with_lse, trapped):
+    """A local copy of the forward rule that names the kernel's output and
+    log-sum-exp and NOT its inputs: the parent's rule (PR 34's), or with
+    ``trapped`` one that names only the residuals, its primal output the
+    kernel's own, un-named ``o``."""
     args = (_SHAPE[-1] ** -0.5, True, 256, 256, True)
 
     def primal(o, lse):
-        return (o, lse[:, 0].reshape(_SHAPE[:3])) if with_lse else o
+        return (o, lse[:, 0].reshape(-1, *_SHAPE[1:3])) if with_lse else o
 
     @jax.custom_vjp
     def attend(q, k, v):
@@ -235,13 +238,13 @@ def _trapped(with_lse):
         o, lse, (qf, kf, vf) = fa._flash_forward(q, k, v, *args)
         kept, lse = (fa.checkpoint_name(t, name)
                      for t, name in zip((o, lse), fa.KEPT_UNDER_REMAT))
-        return primal(o, lse), (qf, kf, vf, lse, kept)
+        return primal(o if trapped else kept, lse), (qf, kf, vf, lse, kept, q.shape)
 
     def bwd(res, g):
         g_o, g_lse = g if with_lse else (g, None)
         if with_lse:
             g_lse = g_lse.astype(jnp.float32).reshape(res[3].shape)
-        return fa._flash_backward((*res, _SHAPE), g_o, *args, g_lse=g_lse)
+        return fa._flash_backward(res, g_o, *args, g_lse=g_lse)
 
     attend.defvjp(fwd, bwd)
     return lambda q, k, v, **kw: attend(q, k, v)
@@ -276,7 +279,7 @@ def test_a_checkpoint_that_keeps_the_names_runs_the_forward_kernel_once(
         "kept": jax.checkpoint(loss, policy=policy),
         "plain_checkpoint": jax.checkpoint(loss),
         "only_the_residual_named": jax.checkpoint(
-            _loss(_trapped(with_lse)), policy=policy),
+            _loss(_local_rule(with_lse, trapped=True)), policy=policy),
     }[arm]
     assert _forward_calls(loss, x, w) == 1
     assert _forward_calls(wrapped, x, w) == calls
@@ -306,30 +309,135 @@ def test_the_names_are_kept_through_a_data_ranks_shard_map():
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+_DIM = 48  # a block's width: not H·d, so W_o's shape is not a projection's
+
+
+def _block(entry):
+    """A block's shape of work, ``projection → flash → W_o``, on ``x [B, L,
+    _DIM]``: each of q, k and v its own matmul and head layout."""
+    _, H, _, d = _SHAPE
+
+    def loss(x, wq, wk, wv, wo):
+        B, L, _ = x.shape
+
+        def heads(w):
+            return (x @ w).reshape(B, L, H, d).transpose(0, 2, 1, 3)
+
+        out = entry(heads(wq), heads(wk), heads(wv), causal=True,
+                    interpret=True, **BLK)
+        o, *lse = jax.tree.leaves(out)
+        y = o.transpose(0, 2, 1, 3).reshape(B, L, H * d) @ wo
+        return (y ** 2).sum() + sum((t ** 2).sum() for t in lse)
+
+    return loss
+
+
+def _block_args(batch=1, seed=7):
+    rng = np.random.default_rng(seed)
+    _, H, L, d = _SHAPE
+
+    def normal(*shape):
+        return jnp.asarray(0.2 * rng.standard_normal(shape), jnp.float32)
+
+    return (normal(batch, L, _DIM), *(normal(_DIM, H * d) for _ in range(3)),
+            normal(H * d, _DIM))
+
+
+def _projections(fn, *args) -> int:
+    """The FORWARD matmuls of q, k and v (``x [B, L, _DIM] · w [_DIM, H·d]``)
+    in the gradient's jaxpr, forward and backward together; the backward
+    kernel is there once."""
+    jaxpr = jax.make_jaxpr(jax.grad(fn, argnums=tuple(range(len(args)))))(*args)
+    assert str(jaxpr).count("name=dtpu_flash_bwd") == 1
+    return forward_matmuls(jaxpr.jaxpr, {args[1].shape})
+
+
+def _same_bits(got, want):
+    for a, b in zip(got, want, strict=True):
+        assert float(jnp.abs(b).max()) > 0
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 @pytest.mark.parametrize("with_lse", [False, True], ids=["o", "o_and_lse"])
-def test_the_names_are_no_operation_where_no_checkpoint_asks(monkeypatch, with_lse):
+@pytest.mark.parametrize("arm,projections", [
+    ("kept", 3), ("plain_checkpoint", 6), ("only_o_and_lse_named", 6)])
+def test_a_checkpoint_that_keeps_the_names_projects_q_k_and_v_once(
+        arm, projections, with_lse):
+    """Under ``save_only_these_names(*KEPT_UNDER_REMAT)`` the recomputation
+    of a block ``projection → flash → W_o`` makes no q, k or v again (the
+    backward kernel reads the kept ones, and a projection's own backward
+    reads its input); under a plain ``jax.checkpoint`` each projection runs
+    twice, and twice too under the policy where the rule names the kernel's
+    output and log-sum-exp alone (the parent's rule). The gradients are the
+    same bits whatever is kept."""
+    entry = fa.flash_attention_with_lse if with_lse else fa.flash_attention
+    policy = jax.checkpoint_policies.save_only_these_names(*fa.KEPT_UNDER_REMAT)
+    args = _block_args()
+    loss = _block(entry)
+    wrapped = {
+        "kept": jax.checkpoint(loss, policy=policy),
+        "plain_checkpoint": jax.checkpoint(loss),
+        "only_o_and_lse_named": jax.checkpoint(
+            _block(_local_rule(with_lse, trapped=False)), policy=policy),
+    }[arm]
+    assert _projections(loss, *args) == 3
+    assert _projections(wrapped, *args) == projections
+    assert _forward_calls(wrapped, *args) == (2 if arm == "plain_checkpoint" else 1)
+    everything = tuple(range(len(args)))
+    _same_bits(jax.grad(wrapped, argnums=everything)(*args),
+               jax.grad(jax.checkpoint(loss), argnums=everything)(*args))
+
+
+def test_q_k_and_v_are_kept_through_a_data_ranks_shard_map():
+    """The same block on a mesh, every data rank running the kernels on its
+    own sequences under ``shard_map``: the policy sees the three names
+    inside it, and no projection runs again."""
+    from distribuuuu_tpu.parallel import mesh as mesh_lib
+
+    mesh = mesh_lib.build_mesh(data=8)
+    policy = jax.checkpoint_policies.save_only_these_names(*fa.KEPT_UNDER_REMAT)
+    loss = _block(functools.partial(fa.flash_attention, mesh=mesh))
+    args = _block_args(batch=8)
+    assert "shard_map" in str(jax.make_jaxpr(loss)(*args))
+    assert _projections(loss, *args) == 3
+    assert _projections(jax.checkpoint(loss, policy=policy), *args) == 3
+    assert _projections(jax.checkpoint(loss), *args) == 6
+    everything = tuple(range(len(args)))
+    _same_bits(jax.grad(jax.checkpoint(loss, policy=policy), argnums=everything)(*args),
+               jax.grad(jax.checkpoint(loss), argnums=everything)(*args))
+
+
+@pytest.mark.parametrize("block", [False, True], ids=["elementwise", "projected"])
+@pytest.mark.parametrize("with_lse", [False, True], ids=["o", "o_and_lse"])
+def test_the_names_are_no_operation_where_no_checkpoint_asks(
+        monkeypatch, with_lse, block):
     """A gradient through the kernels with no ``jax.checkpoint`` around them
     (``olmoe_1b_7b.train_seq4096``'s case): the lowered text holds nothing
-    for the names, and is the text of rules that name nothing."""
+    for the five names, and is the text of rules that name nothing."""
     entry = fa.flash_attention_with_lse if with_lse else fa.flash_attention
-    x, w = jnp.ones(_SHAPE, jnp.bfloat16), jnp.ones(_SHAPE[-1], jnp.bfloat16)
+    if block:
+        loss, args = _block(entry), [t.astype(jnp.bfloat16) for t in _block_args()]
+    else:
+        loss = _loss(entry)
+        args = [jnp.ones(_SHAPE, jnp.bfloat16), jnp.ones(_SHAPE[-1], jnp.bfloat16)]
 
     def lowered():
-        text = jax.jit(jax.grad(_loss(entry), argnums=(0, 1))).lower(x, w).as_text()
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(*args).as_text()
         return re.sub(r"_\d+\b", "", text)  # the counters in private functions' names
 
     named = lowered()
     assert "name=dtpu_flash_fwd" not in named  # lowered: no jaxpr syntax
+    assert set(fa.KEPT_UNDER_REMAT) >= {"flash_o", "flash_lse", "flash_q", "flash_k", "flash_v"}
     assert not any(name in named for name in fa.KEPT_UNDER_REMAT)
     monkeypatch.setattr(fa, "checkpoint_name", lambda t, name: t)
     assert lowered() == named
 
 
-def test_kept_bytes_are_the_two_arrays_where_the_kernel_runs(monkeypatch):
+def test_kept_bytes_are_the_five_arrays_where_the_kernel_runs(monkeypatch):
     """``kept_under_remat_bytes`` at the two cells' shapes (bf16 ``o``, the
-    float32 ``lse`` at the padded length), and 0 wherever ``flash_attention``
-    itself takes the scan: off the TPU, in a program across devices with no
-    ``shard_map`` a data rank, past the VMEM bound."""
+    float32 ``lse`` and q, k and v at the padded length), and 0 wherever
+    ``flash_attention`` itself takes the scan: off the TPU, in a program
+    across devices with no ``shard_map`` a data rank, past the VMEM bound."""
     from distribuuuu_tpu.ops import pallas as tier
     from distribuuuu_tpu.parallel import mesh as mesh_lib
 
@@ -340,11 +448,16 @@ def test_kept_bytes_are_the_two_arrays_where_the_kernel_runs(monkeypatch):
     assert jax.device_count() == 8 and fa.kept_under_remat_bytes(ouro, 2) == 0
     assert fa.kept_under_remat_bytes(ouro, 2, mesh) == 0  # 1 sequence, 8 ranks
     with tier.single_device_program():
-        assert fa.kept_under_remat_bytes(ouro, 2) == 16 * 4096 * (128 * 2 + 4)
-        assert fa.kept_under_remat_bytes(glm, 2) == 20 * 8192 * (256 * 2 + 4)
-        assert fa.kept_under_remat_bytes((1, 3, 300, 64), 4) == 3 * (300 * 64 * 4 + 384 * 4)
+        # o and lse, then q, k and v: 16.25 + 48 MiB and 80.6 + 240 MiB a call
+        assert fa.kept_under_remat_bytes(ouro, 2) == 16 * 4096 * (128 * 2 + 4 + 3 * 128 * 2)
+        assert fa.kept_under_remat_bytes(ouro, 2) * 32 == 2_155_872_256
+        assert fa.kept_under_remat_bytes(glm, 2) == 20 * 8192 * (256 * 2 + 4 + 3 * 256 * 2)
+        assert fa.kept_under_remat_bytes(glm, 2) * 6 == 2_017_198_080
+        # o at its 300 rows; lse, q, k and v at the 384 the kernels take
+        assert fa.kept_under_remat_bytes((1, 3, 300, 64), 4) == 3 * (
+            300 * 64 * 4 + 384 * 4 + 3 * 384 * 64 * 4)
         assert fa.kept_under_remat_bytes((1, 1, 65536, 128), 2) == 0  # fits_vmem
-    assert fa.kept_under_remat_bytes((8, *ouro[1:]), 2, mesh) == 8 * 16 * 4096 * 260
+    assert fa.kept_under_remat_bytes((8, *ouro[1:]), 2, mesh) == 8 * 16 * 4096 * (260 + 768)
     # the same answers as the routing itself
     for shape, m in ((ouro, None), ((8, *ouro[1:]), mesh), (ouro, mesh)):
         q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)

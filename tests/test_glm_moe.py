@@ -18,6 +18,7 @@ import pytest
 from distribuuuu_tpu import models
 from distribuuuu_tpu.models import glm_moe
 from distribuuuu_tpu.ops import moe as moe_ops
+from test_ouro import forward_matmuls
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _spec = importlib.util.spec_from_file_location(
@@ -348,14 +349,16 @@ def test_the_recomputing_step_equals_the_step_that_keeps_everything():
 
 def test_what_the_recomputed_blocks_keep_of_the_flash_kernel_changes_no_bit(monkeypatch):
     """With the kernels run (the interpreter, forced, where ``auto`` runs
-    them compiled on the chip) a recomputed block keeps their output and
-    log-sum-exp: the forward kernel runs once a block, the MTP module's too,
-    where a plain ``nn.remat`` (the policy keeping nothing) runs it twice,
-    and the loss and every gradient leaf are that step's bit for bit: what
-    is kept is what was recomputed. Against the step that recomputes nothing
-    the loss is the same bits and the gradients are as near as they were
-    before the kernel was kept (jax sums a value's several cotangents in
-    another order under a checkpoint)."""
+    them compiled on the chip) a recomputed block keeps what the backward
+    kernel reads, the forward kernel's output and log-sum-exp and its q, k
+    and v: a block, the MTP module's too, runs the forward kernel and the
+    two projections out of the latents (``q_b_proj``, ``kv_b_proj``) once,
+    where a plain ``nn.remat`` (the policy keeping nothing) runs them
+    twice, and the loss and every gradient leaf are that step's bit for
+    bit: what is kept is what was recomputed. Against the step that
+    recomputes nothing the loss is the same bits and the gradients are as
+    near as they were before anything was kept (jax sums a value's several
+    cotangents in another order under a checkpoint)."""
     from distribuuuu_tpu.ops import flash_attention as fa
 
     monkeypatch.setattr(
@@ -363,8 +366,14 @@ def test_what_the_recomputed_blocks_keep_of_the_flash_kernel_changes_no_bit(monk
     model = build(attn_impl="flash")
     params, biases, tokens, labels = seeded(model, batch=1, seq=40)
     blocks = model.depth + model.mtp_layers
+    attn = params["Block_0"]["attn"]
+    out_of_the_latents = {
+        attn["q_b_proj"]["kernel"].shape, attn["kv_b_proj"]["kernel"].shape}
+    others = {leaf.shape for path, leaf in jax.tree_util.tree_leaves_with_path(params)
+              if "_b_proj" not in jax.tree_util.keystr(path)}
+    assert len(out_of_the_latents) == 2 and not out_of_the_latents & others
 
-    def run(variant, forward_calls):
+    def run(variant, forward_calls, projections):
         def loss(p):
             return program_loss(variant, p, biases, tokens, labels)[0]
 
@@ -372,14 +381,15 @@ def test_what_the_recomputed_blocks_keep_of_the_flash_kernel_changes_no_bit(monk
         text = str(traced.jaxpr)
         assert text.count("name=dtpu_flash_fwd") == forward_calls
         assert text.count("name=dtpu_flash_bwd") == blocks
+        assert forward_matmuls(traced.jaxpr.jaxpr, out_of_the_latents) == projections
         return traced.lower().compile()(params)
 
-    kept = run(model, blocks)
-    nothing_recomputed = run(model.clone(recompute=False), blocks)
+    kept = run(model, blocks, 2 * blocks)
+    nothing_recomputed = run(model.clone(recompute=False), blocks, 2 * blocks)
     monkeypatch.setattr(
         jax.checkpoint_policies, "save_only_these_names",
         lambda *names: jax.checkpoint_policies.nothing_saveable)
-    plain = run(model, 2 * blocks)
+    plain = run(model, 2 * blocks, 4 * blocks)
     assert float(kept[0]) == float(plain[0]) == float(nothing_recomputed[0])
     flat = jax.tree_util.tree_leaves_with_path(kept[1])
     for (path, got), want in zip(flat, jax.tree.leaves(plain[1]), strict=True):
@@ -393,7 +403,8 @@ def test_the_plan_says_what_the_cells_blocks_keep(tmp_path, monkeypatch, engaged
     """``share.plan`` at ``glm_4_7_flash.train_seq8192``'s shape (1 + 4
     layers and the MTP module, 1 x 8192 tokens, 20 heads of 256): six
     float32 inputs of 64 MiB and, where the flash kernel runs, 6 x (80 MiB
-    of output + 0.625 MiB of log-sum-exp); nothing of it on the scan path."""
+    of output + 0.625 MiB of log-sum-exp + 3 x 80 MiB of q, k and v);
+    nothing of it on the scan path."""
     import json
 
     from distribuuuu_tpu.ops import pallas as tier
@@ -417,11 +428,11 @@ def test_the_plan_says_what_the_cells_blocks_keep(tmp_path, monkeypatch, engaged
     plan = plans[0]
     schema.validate_record(plan)
     assert (plan["experts_held"], plan["vocab_held"]) == (8, 19360)
-    assert plan["kept_flash_bytes"] == (507_248_640 if engaged else 0)
+    assert plan["kept_flash_bytes"] == (2_017_198_080 if engaged else 0)
     assert plan["kept_bytes"] == 6 * 8192 * 2048 * 4 + plan["kept_flash_bytes"]
     said = "every block, the MTP module's too, from its float32 input"
     assert plan["recomputed"] == said + (
-        " and the flash kernel's output and log-sum-exp" if engaged else "")
+        " and the flash kernel's output, log-sum-exp, q, k and v" if engaged else "")
 
 
 def test_bfloat16_program_stays_near_the_reference_because_its_float32_parts_do():

@@ -19,6 +19,7 @@ from distribuuuu_tpu.config import cfg
 from distribuuuu_tpu.parallel import mesh as mesh_lib
 from distribuuuu_tpu.parallel.partition import lowering
 from test_glm_moe import CHUNK, REPO, VOCAB, architecture, build, mixture_biases, reference
+from test_ouro import walk
 
 YAML = os.path.join(REPO, "config", "glm_4_7_flash.yaml")
 
@@ -101,7 +102,7 @@ def test_the_step_through_lower_reports_the_references_terms_and_moves_the_bias(
         evaluated["loss_sum"] / evaluated["count"], want["ce"], rtol=1e-5)
 
 
-def test_the_lowered_step_holds_no_while_and_one_head_walk():
+def test_the_lowered_step_holds_no_while_and_one_headwalk():
     """Layers, the MTP module and the head's chunks are Python loops (a
     ``while`` in a device trace is one operation AND its body's); trunk and
     MTP module share ONE walk of the head: three vocabulary-wide matmuls a
@@ -116,24 +117,13 @@ def test_the_lowered_step_holds_no_while_and_one_head_walk():
     jaxpr = jax.make_jaxpr(low.train_step)(state, batch).jaxpr
     held = VOCAB // 2
     wide = [
-        eqn for eqn in _walk(jaxpr)
+        eqn for eqn in walk(jaxpr)
         if eqn.primitive.name == "dot_general" and any(
             held in getattr(v.aval, "shape", ())
             for v in list(eqn.invars) + list(eqn.outvars))
     ]
     assert len(wide) == 3 * -(-100 // CHUNK)
     assert any(tuple(e.outvars[0].aval.shape) == (8 * 2, CHUNK, held) for e in wide)
-
-
-def _walk(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs inside its equations."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for value in eqn.params.values():
-            for inner in value if isinstance(value, (list, tuple)) else [value]:
-                inner = getattr(inner, "jaxpr", inner)
-                if hasattr(inner, "eqns"):
-                    yield from _walk(inner)
 
 
 def test_lm_spec_table_places_every_leaf():

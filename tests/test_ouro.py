@@ -72,6 +72,28 @@ def program_loss(model, params, tokens, labels):
     return loss, (extra, hits, outputs)
 
 
+def walk(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside its equations."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from walk(inner)
+
+
+def forward_matmuls(jaxpr, kernels) -> int:
+    """``x [B, S, in] . W [in, out]`` with W's shape among ``kernels``, in a
+    jaxpr and the jaxprs inside it: a projection's FORWARD matmul, wherever
+    it runs (its dx contracts W's other dimension, its dW no W at all)."""
+    return sum(
+        eqn.primitive.name == "dot_general"
+        and tuple(eqn.invars[1].aval.shape) in kernels
+        and eqn.params["dimension_numbers"][0] == ((2,), (0,))
+        for eqn in walk(jaxpr))
+
+
 def assert_trees_close(got, want, tolerance):
     flat = jax.tree_util.tree_leaves_with_path(got)
     for (path, g), w in zip(flat, jax.tree.leaves(want), strict=True):
@@ -261,14 +283,16 @@ def test_the_recomputing_step_equals_the_step_that_keeps_everything():
 
 def test_what_the_recomputed_blocks_keep_of_the_flash_kernel_changes_no_bit(monkeypatch):
     """With the kernels run (the interpreter, forced, where ``auto`` runs
-    them compiled on the chip) a recomputed block keeps their output and
-    log-sum-exp: the forward kernel runs once a block application,
-    where a plain ``nn.remat`` (the policy keeping nothing) runs it twice,
-    and the loss and every gradient leaf are that step's bit for bit: what
-    is kept is what was recomputed. Against the step that recomputes nothing
-    the loss is the same bits and the gradients are as near as they were
-    before the kernel was kept (jax sums a value's several cotangents in
-    another order under a checkpoint)."""
+    them compiled on the chip) a recomputed block keeps what the backward
+    kernel reads, the forward kernel's output and log-sum-exp and its q, k
+    and v: a block application runs the forward kernel and the three
+    projections once (its four ``[dim, dim]`` matmuls and ``W_o`` again),
+    where a plain ``nn.remat`` (the policy keeping nothing) runs all of it
+    twice, and the loss and every gradient leaf are that step's bit for
+    bit: what is kept is what was recomputed. Against the step that
+    recomputes nothing the loss is the same bits and the gradients are as
+    near as they were before anything was kept (jax sums a value's several
+    cotangents in another order under a checkpoint)."""
     from distribuuuu_tpu.ops import flash_attention as fa
 
     monkeypatch.setattr(
@@ -276,8 +300,9 @@ def test_what_the_recomputed_blocks_keep_of_the_flash_kernel_changes_no_bit(monk
     model = build(depth=2, attn_impl="flash")
     params, tokens, labels = seeded(model, batch=1, seq=40)
     blocks = model.depth * model.passes
+    assert model.mlp_hidden != model.dim  # q, k, v and W_o alone are [dim, dim]
 
-    def run(variant, forward_calls):
+    def run(variant, forward_calls, projections):
         def loss(p):
             return program_loss(variant, p, tokens, labels)[0]
 
@@ -285,14 +310,16 @@ def test_what_the_recomputed_blocks_keep_of_the_flash_kernel_changes_no_bit(monk
         text = str(traced.jaxpr)
         assert text.count("name=dtpu_flash_fwd") == forward_calls
         assert text.count("name=dtpu_flash_bwd") == blocks
+        assert forward_matmuls(
+            traced.jaxpr.jaxpr, {(model.dim, model.dim)}) == projections
         return traced.lower().compile()(params)
 
-    kept = run(model, blocks)
-    nothing_recomputed = run(model.clone(recompute=False), blocks)
+    kept = run(model, blocks, 5 * blocks)
+    nothing_recomputed = run(model.clone(recompute=False), blocks, 4 * blocks)
     monkeypatch.setattr(
         jax.checkpoint_policies, "save_only_these_names",
         lambda *names: jax.checkpoint_policies.nothing_saveable)
-    plain = run(model, 2 * blocks)
+    plain = run(model, 2 * blocks, 8 * blocks)
     assert float(kept[0]) == float(plain[0]) == float(nothing_recomputed[0])
     flat = jax.tree_util.tree_leaves_with_path(kept[1])
     for (path, got), want in zip(flat, jax.tree.leaves(plain[1]), strict=True):
@@ -306,8 +333,9 @@ def test_the_plan_says_what_the_cells_block_applications_keep(
         tmp_path, monkeypatch, engaged):
     """``loop.plan`` at ``ouro_2_6b.train_seq4096``'s shape (8 layers, 4
     passes, 1 x 4096 tokens): 32 float32 inputs of 32 MiB and, where the
-    flash kernel runs, 32 x (16 MiB of output + 0.25 MiB of log-sum-exp);
-    where the scan runs in its place nothing is named and nothing kept."""
+    flash kernel runs, 32 x (16 MiB of output + 0.25 MiB of log-sum-exp +
+    3 x 16 MiB of q, k and v); where the scan runs in its place nothing is
+    named and nothing kept."""
     import json
 
     from distribuuuu_tpu.ops import pallas as tier
@@ -331,11 +359,11 @@ def test_the_plan_says_what_the_cells_block_applications_keep(
     schema.validate_record(plan)
     inputs = 32 * 4096 * 2048 * 4
     assert plan["block_applications"] == 32
-    assert plan["kept_flash_bytes"] == (545_259_520 if engaged else 0)
+    assert plan["kept_flash_bytes"] == (2_155_872_256 if engaged else 0)
     assert plan["kept_bytes"] == inputs + plan["kept_flash_bytes"]
     said = "every block application, from its float32 input"
     assert plan["recomputed"] == said + (
-        " and the flash kernel's output and log-sum-exp" if engaged else "")
+        " and the flash kernel's output, log-sum-exp, q, k and v" if engaged else "")
     nothing = ouro.kept_plan(model.clone(recompute=False), 32, 1, 4096, 128, "")
     assert nothing == {
         "kept_bytes": None, "kept_flash_bytes": None, "recomputed": "nothing"}

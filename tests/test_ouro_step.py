@@ -17,7 +17,7 @@ from distribuuuu_tpu import models, trainer
 from distribuuuu_tpu.config import cfg
 from distribuuuu_tpu.parallel import mesh as mesh_lib
 from distribuuuu_tpu.parallel.partition import lowering
-from test_ouro import BETA, CHUNK, REPO, VOCAB, architecture, build, reference, seeded
+from test_ouro import BETA, CHUNK, REPO, VOCAB, architecture, build, reference, seeded, walk
 
 
 def _lowered(seq_len=100, dtype="float32", chunk=CHUNK):
@@ -73,7 +73,7 @@ def test_the_step_through_lower_reports_the_references_terms():
         evaluated["loss_sum"] / evaluated["count"], want["ce_pass"][-1], rtol=1e-5)
 
 
-def test_the_lowered_step_holds_no_while_and_one_head_walk():
+def test_the_lowered_step_holds_no_while_and_one_headwalk():
     """Passes, layers and the head's chunks are Python loops (a ``while`` in
     a device trace is one operation AND its body's); the four passes share
     ONE walk of the head: three vocabulary-wide matmuls a chunk, not twelve."""
@@ -89,25 +89,14 @@ def test_the_lowered_step_holds_no_while_and_one_head_walk():
         eqn.primitive.name == "dot_general" and any(
             VOCAB in getattr(v.aval, "shape", ())
             for v in list(eqn.invars) + list(eqn.outvars))
-        for eqn in _walk(jaxpr)
+        for eqn in walk(jaxpr)
     )
     assert wide == 3 * -(-100 // CHUNK)
     # the head's rows are the batch's sequences, four times over
     assert any(
         eqn.primitive.name == "dot_general"
         and tuple(eqn.outvars[0].aval.shape) == (8 * 4, CHUNK, VOCAB)
-        for eqn in _walk(jaxpr))
-
-
-def _walk(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs inside its equations."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for value in eqn.params.values():
-            for inner in value if isinstance(value, (list, tuple)) else [value]:
-                inner = getattr(inner, "jaxpr", inner)
-                if hasattr(inner, "eqns"):
-                    yield from _walk(inner)
+        for eqn in walk(jaxpr))
 
 
 def test_lm_spec_table_places_every_leaf():

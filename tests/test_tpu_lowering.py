@@ -288,9 +288,9 @@ def test_ouro_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
     1 of the cell's 8 layers (8 compile in five minutes here, 1 in under
     one). What the benchmark's readers find in it: every scope they sum, the
     three flash kernels by name, the recomputed forward by the ``op_name``
-    ``jax.checkpoint``'s transpose gives it, with NO forward kernel in it,
-    and no ``while`` (a loop in a device trace is one operation AND its
-    body's)."""
+    ``jax.checkpoint``'s transpose gives it, with NO forward kernel and no
+    projection of q, k or v in it, and no ``while`` (a loop in a device trace
+    is one operation AND its body's)."""
     from jax.sharding import SingleDeviceSharding
 
     import distribuuuu_tpu.config as config
@@ -334,7 +334,7 @@ def test_ouro_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
             name = line.split(" = ")[0].split()[-1].lstrip("%").rsplit(".", 1)[0]
             calls.setdefault(name, []).append(line.split('op_name="')[1].split('"')[0])
     # 4 block applications: the forward kernel runs ONCE each, in the forward
-    # (the block keeps its output and log-sum-exp, so the backward's
+    # (the block keeps its output, log-sum-exp, q, k and v, so the backward's
     # recomputation has no use for it), the one backward kernel once (and the
     # two empty calls under the names the benchmark's ``trace_kernels`` asks)
     assert {k: len(v) for k, v in calls.items() if "flash" in k} == {
@@ -347,12 +347,17 @@ def test_ouro_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
         assert all(in_scope(p, "bwd") and in_scope(p, "attn") for p in calls[kernel])
     assert not any(in_scope(p, "rematted_computation")
                    for kernel in calls if "flash" in kernel for p in calls[kernel])
-    # the recomputed forward is the blocks' alone, less the kernel: the MLP
-    # and attention's projections (what the backward kernel reads), no head
+    # the recomputed forward is the blocks' alone, less the kernel AND what
+    # made its inputs (a block application keeps q, k and v as the kernels
+    # take them): the norms, W_o and the MLP, no projection of q, k or v
+    # (their weights' casts remain, for the projections' own dx), no head
     recomputed = [p for p in paths if in_scope(p, "rematted_computation")]
-    assert any(in_scope(p, "mlp") for p in recomputed)
+    assert any(in_scope(p, "mlp") and p.endswith("dot_general") for p in recomputed)
+    for still in ("attn_norm", "attn_post_norm", "o_proj/dot_general"):
+        assert any(in_scope(p, "attn") and still in p for p in recomputed), still
     for proj in ("q_proj", "k_proj", "v_proj"):
-        assert any(in_scope(p, "attn") and proj in p for p in recomputed), proj
+        assert any(f"{proj}/dot_general" in p for p in paths), proj
+        assert not any(f"{proj}/dot_general" in p for p in recomputed), proj
     assert not any(in_scope(p, "lm_head") or in_scope(p, "exit_gate") for p in recomputed)
 
 
@@ -409,13 +414,24 @@ def test_glm_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
             name = line.split(" = ")[0].split()[-1].lstrip("%").rsplit(".", 1)[0]
             calls.setdefault(name, []).append(line.split('op_name="')[1].split('"')[0])
     # 3 blocks: the forward kernel once each, in the forward alone (a block
-    # keeps its output and log-sum-exp), the one backward kernel once
+    # keeps its output, log-sum-exp, q, k and v), the one backward kernel once
     flash = {k: len(v) for k, v in calls.items() if "flash" in k}
     assert (flash["dtpu_flash_fwd"], flash["dtpu_flash_bwd"]) == (3, 3)
     assert not any(in_scope(p, "rematted_computation") or in_scope(p, "bwd")
                    for p in calls["dtpu_flash_fwd"])
     assert all(in_scope(p, "attn") and not in_scope(p, "mla_latent")
                for k in ("dtpu_flash_fwd", "dtpu_flash_bwd") for p in calls[k])
+    # the recomputation makes no q, k or v again: the two projections out of
+    # the latents run in the forward alone; those into them, their norms (what
+    # the former's own backward reads), W_o and the mixture still run again
+    recomputed = [p for p in paths if in_scope(p, "rematted_computation")]
+    for proj in ("q_b_proj", "kv_b_proj"):
+        assert any(f"{proj}/dot_general" in p for p in paths), proj
+        assert not any(f"{proj}/dot_general" in p for p in recomputed), proj
+    for still in ("q_a_proj/dot_general", "kv_a_proj/dot_general", "q_a_norm",
+                  "kv_a_norm", "attn_norm", "o_proj/dot_general"):
+        assert any(in_scope(p, "attn") and still in p for p in recomputed), still
+    assert any(in_scope(p, "moe_shared") for p in recomputed)
     # 2 mixtures: gate_up and fwd run forward and again, the four backward
     # kernels once; all under moe_experts, none under moe_shared
     gmm = {k: len(v) for k, v in calls.items() if "moe_gmm" in k}

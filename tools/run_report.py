@@ -413,7 +413,7 @@ def _recomputed(plan: dict) -> str:
         said += f"; kept {plan['kept_bytes'] / 2**20:.1f} MiB a step"
         if plan.get("kept_flash_bytes") is not None:
             said += (f", {plan['kept_flash_bytes'] / 2**20:.1f} of them the "
-                     "flash kernel's output and log-sum-exp")
+                     "flash kernel's output, log-sum-exp, q, k and v")
     return said
 
 
