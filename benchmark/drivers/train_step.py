@@ -113,7 +113,7 @@ def make_batch(seed: int, global_batch: int, im_size: int, num_classes: int,
             ),
         }
 
-    return jax.jit(draw, out_shardings=shardings)(np.int32(seed))
+    return jax.jit(draw, out_shardings=shardings)(np.int32(seed % 2**31))
 
 
 def reference_loss(run, state, batch, bn_group: int) -> float:

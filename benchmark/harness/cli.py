@@ -218,5 +218,13 @@ def main(argv, t_process_start: float) -> int:
     except DiscoveryError as e:
         raise Refused(str(e)) from e
     observation = driver.run(run)
-    print(json.dumps(result_line(run, observation)), flush=True)
+    line = result_line(run, observation)
+    if observation.compared:
+        # each number compared beside its limit: the last lines of standard
+        # error, and the last key of the result's line
+        line["compared"] = observation.compared
+        for name, c in observation.compared.items():
+            print(f"compared {name} {c['value']:.6g} limit {c['limit']:.6g}",
+                  file=sys.stderr, flush=True)
+    print(json.dumps(line), flush=True)
     return 0

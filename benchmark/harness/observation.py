@@ -25,6 +25,9 @@ class Observation:
     # JSON {HLO instruction name: op_name} of the traced program, from its
     # compiled HLO text: this installation's trace carries no name scopes
     trace_op_names_path: str | None = None
+    # what decided ``correct``, where the driver gives it: short plain name ->
+    # {"value", "limit"}; within its limit means value <= limit
+    compared: dict | None = None
 
 
 @dataclasses.dataclass

@@ -1,10 +1,13 @@
 """The flash attention kernels' share of the MXU's peak, in percent: the
-USEFUL operations of causal attention (``costs/olmoe.py``
-``attention_macs_per_token``: scores and values under the mask, forward once
-and backward twice; the scores the two backward kernels recompute and the
-masked half of the diagonal blocks are not counted) at the published bf16
-peak, over the device time of the ``dtpu_flash_fwd``, ``dtpu_flash_dq`` and
-``dtpu_flash_dkdv`` Pallas calls (``ops/flash_attention.py``). Nothing for a
+USEFUL operations of causal attention (the ``attention_macs_per_token`` of
+the configuration's own ``costs`` module, ``costs/olmoe.py`` or
+``costs/ouro.py``: scores and values under the mask, forward once and
+backward twice; the scores the backward kernel computes again and the masked
+half of the diagonal blocks are not counted) at the published bf16 peak,
+over the device time of every ``dtpu_flash_*`` Pallas call
+(``ops/flash_attention.py``: ``dtpu_flash_fwd`` and, since PR 31, the one
+backward kernel ``dtpu_flash_bwd``; the two empty calls that keep the names
+``dtpu_flash_dq`` and ``dtpu_flash_dkdv`` add 0.000 ms). Nothing for a
 program whose trace holds no such call."""
 
 METRIC = {"layer": "kernels", "unit": "%", "source": "device_trace",

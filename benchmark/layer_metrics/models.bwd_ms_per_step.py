@@ -2,7 +2,9 @@
 step's ``bwd`` named scope (the transposed pass of ``lowering.py``'s
 ``grad_fn``, the loss's backward included), collectives left out: the
 partitioner places the gradient all-reduce on a backward operation, and
-``partition.collective_ms_per_step`` has it."""
+``partition.collective_ms_per_step`` has it. Since PR 27 a decoder's head
+computes its gradients in the forward walk (``ops/token_head.py``): on those
+cells they are under ``models.fwd_ms_per_step``, not here."""
 
 from benchmark.harness.trace import in_scope, is_collective
 

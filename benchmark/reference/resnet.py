@@ -4,6 +4,8 @@ the 3x3 (v1.5), global average pool, linear head. Plain float32."""
 
 from __future__ import annotations
 
+import functools
+
 import jax
 
 from benchmark.reference import common
@@ -26,11 +28,14 @@ def _block(x, params, stats, kind: str, stride: int, **bn):
         x = common.conv_bn(
             x, params[shortcut], stats[shortcut], stride=stride, **bn
         )
-    return jax.nn.relu(out + x)
+    return common._held(jax.nn.relu(out + x))
 
 
 def logits(params, stats, images_u8, *, architecture: dict, train: bool,
-           bn_group: int = 0):
+           bn_group: int = 0, recompute: bool = False):
+    """``recompute``: a block's inner activations are computed again in the
+    backward pass (same values, a fraction of the memory), so that a float32
+    gradient at a training cell's batch fits beside nothing else."""
     bn = {"train": train, "bn_group": bn_group}
     x = common.normalize(images_u8)
     x = common.conv_bn(
@@ -42,6 +47,9 @@ def logits(params, stats, images_u8, *, architecture: dict, train: bool,
         for i in range(blocks):
             name = f"{kind}_{index}"
             stride = 2 if stage > 0 and i == 0 else 1
-            x = _block(x, params[name], stats[name], kind, stride, **bn)
+            block = functools.partial(_block, kind=kind, stride=stride, **bn)
+            if recompute:
+                block = jax.checkpoint(block)
+            x = block(x, params[name], stats[name])
             index += 1
     return common.head(x, params)

@@ -8,6 +8,7 @@ import os
 
 import pytest
 
+import test_benchmark_contract as contract
 from benchmark.harness.discovery import Catalog, DiscoveryError
 from benchmark_testlib import REPO, finish, make_root, pending_entries, start_run
 
@@ -103,6 +104,57 @@ def test_new_cell_needs_only_new_files_and_entries(tmp_path):
     assert "widgets_per_s" not in [
         m["name"] for m in catalog.cell("resnet50.train").end_to_end
     ]
+
+
+EIGHTH = {"name": "regnety_160.trainloop_hostfed", "config": "regnety_160",
+          "traffic": "train_loop_hostfed", "chips": 1,
+          "why": "made up by a test: an accepted configuration under an accepted traffic file"}
+EIGHTH_READS = ("train_items_per_s_per_chip", "models.mfu", "device.hbm_peak_frac",
+                "trainer.fetch_ms_per_step")
+
+
+def test_an_eighth_cell_is_appended_entries_and_nothing_else(tmp_path):
+    """What a PR of another kind does to add a cell of an accepted
+    configuration under an accepted traffic mix: one entry at the end of
+    ``workloads``, its name appended to the ``workloads`` of the metrics it
+    reports. No file is added or edited, no test pins how many cells there
+    are or where an entry stands, and the contract's checks pass."""
+    root = make_root(tmp_path)
+    before = _digests(root)
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path) as f:
+        benchmark = json.load(f)
+    had = [w["name"] for w in benchmark["workloads"]]
+    benchmark["workloads"].append(EIGHTH)
+    for m in benchmark["end_to_end"] + benchmark["per_layer"]:
+        if m["name"] in EIGHTH_READS:
+            m["workloads"].append(EIGHTH["name"])
+    with open(path, "w") as f:
+        json.dump(benchmark, f)
+    assert _digests(root) == before  # nothing under benchmark/ was touched
+
+    catalog = Catalog(root)
+    cell = catalog.cell(EIGHTH["name"])
+    assert (cell.config_name, cell.traffic_name, cell.chips) == (
+        "regnety_160", "train_loop_hostfed", 1)
+    assert cell.config["architecture"]["parameters"] == 83590140
+    assert callable(catalog.driver(cell.traffic["driver"]).run)
+    assert {m["name"] for m in cell.end_to_end} == {
+        "train_items_per_s_per_chip", "setup_s"}
+    assert {m["name"] for m in cell.per_layer} == {
+        "entry.compiles_in_window", *EIGHTH_READS[1:]}
+    for metric in cell.per_layer:
+        catalog.layer_metric(metric)
+    # the cells that were there report what they reported
+    accepted = Catalog(REPO)
+    for name in had:
+        assert [m["name"] for m in catalog.cell(name).per_layer] == [
+            m["name"] for m in accepted.cell(name).per_layer]
+    # the contract's own checks (the repo's files are the copy's)
+    contract.test_keys_sizes_and_names(benchmark)
+    contract.test_configs_and_cells(benchmark)
+    contract.test_metrics(benchmark)
+    contract.test_a_full_check_fits_its_time_limit(benchmark)
 
 
 def test_every_name_in_the_benchmark_leads_somewhere():

@@ -138,9 +138,13 @@ def test_the_cell_is_one_chip_and_reports_what_the_issue_lists():
         "models.bwd_ms_per_step",
         "kernels.opt_update_ms_per_step", "kernels.opt_update_roofline",
         "kernels.opt_kernel_ms_per_step", "entry.lower_s", "entry.init_state_s",
-        "entry.compiles_in_window", "device.idle_frac", "device.hbm_peak_frac"}
+        "entry.compiles_in_window", "device.idle_frac", "device.hbm_peak_frac",
+        # accepted readers that read this program as it stands (PR 40):
+        # `lm_head`, the leading dense layer's `mlp`, `rematted_computation`
+        "models.lm_head_ms_per_step", "models.mlp_ms_per_step",
+        "models.recompute_ms_per_step"}
     # the eight new ones list this cell; the next decoder of the kind may be
-    # appended (`in`, not `==`: PERF.md section 7 on the two older pins)
+    # appended (`in`, not `==`)
     for m in CATALOG.benchmark["per_layer"]:
         if m["name"] in NEW:
             assert CELL in m["workloads"]
@@ -149,9 +153,8 @@ def test_the_cell_is_one_chip_and_reports_what_the_issue_lists():
     for other in ("resnet50.train", "regnety_160.train", "resnet50.train_dp4",
                   "olmoe_1b_7b.train_seq4096", "ouro_2_6b.train_seq4096"):
         assert not {m["name"] for m in CATALOG.cell(other).per_layer} & set(NEW)
-    # 6 cells, one of them on four chips
-    chips = [w["chips"] for w in CATALOG.benchmark["workloads"]]
-    assert len(chips) == 6 and chips.count(4) == 1
+    # this cell is on one chip; how many cells there are, and how many of
+    # them may take four, is the contract's rule (test_benchmark_contract.py)
     why = [w for w in CATALOG.benchmark["workloads"] if w["name"] == CELL][0]["why"]
     assert len(why) <= 200 and "1/8" in why and "8x their share" in why
 

@@ -89,17 +89,20 @@ def test_the_cell_is_one_chip_and_reports_what_the_issue_lists():
             "kernels.opt_update_roofline", "kernels.opt_kernel_ms_per_step",
             "device.idle_frac",
             "device.hbm_peak_frac", "entry.lower_s", "entry.init_state_s",
-            "entry.compiles_in_window"} <= names
+            "entry.compiles_in_window",
+            # since PR 29 the experts are the repo's own grouped matmuls,
+            # which keep their scope forward and backward (XLA:TPU dropped
+            # it from its `ragged-dot` kernels, a quarter of `fwd_bwd`): the
+            # fwd/bwd split covers the step (PR 40)
+            "models.fwd_ms_per_step", "models.bwd_ms_per_step"} <= names
     # nothing runs under `opt_tile` here (13 leaves, each updated where it
-    # rests), so that reader finds nothing and the cell is not on its list;
-    # XLA:TPU drops the scope of its `ragged-dot` kernels, a quarter of
-    # `fwd_bwd`, so the fwd/bwd split would leave the experts out of both
-    assert not names & {"kernels.opt_tile_ms_per_step", "models.fwd_ms_per_step",
-                        "models.bwd_ms_per_step"}
-    # the six are this cell's alone: no other cell's line can gain or lose them
+    # rests), so that reader finds nothing and the cell is not on its list
+    assert "kernels.opt_tile_ms_per_step" not in names
+    # the six list this cell; a later decoder's cell may be appended (`in`,
+    # not `==`: Ouro's and GLM's cells read three of them since PR 40)
     for m in CATALOG.benchmark["per_layer"]:
         if m["name"] in NEW:
-            assert m["workloads"] == [CELL]
+            assert CELL in m["workloads"]
 
 
 def test_the_configuration_states_the_sizes_the_program_builds():

@@ -143,3 +143,22 @@ def test_opt_update_bytes_are_one_pass():
     # float32 parameters, gradients and one momentum: read 3, write 2
     assert costs.one_pass_bytes(400, 400, 400) == 5 * 400
     assert costs.roofline_seconds(819e9, {"hbm_bytes_per_s": 819e9}) == 1.0
+
+
+@pytest.mark.parametrize("seed", [7, 2**31 - 1, 2**31 + 7, 3_500_000_703])
+def test_the_device_batch_takes_any_seed_the_contract_allows(seed):
+    """``drivers/train_step.make_batch`` hands the seed to its jitted draw as
+    an int32; a seed of 2**31 or more (the driver's are large) overflowed it
+    and killed the run. It is taken modulo 2**31: a seed under 2**31 gives
+    the bits it gave, a larger one those of its remainder."""
+    train_step = CATALOG.driver("train_step")
+    here = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    batch = train_step.make_batch(seed, 4, 8, 10, {"image": here, "label": here})
+    assert batch["image"].shape == (4, 8, 8, 3) and batch["image"].dtype == jnp.uint8
+    as_before = seed if seed < 2**31 else seed - 2**31
+    k_img, k_lbl = jax.random.split(
+        jax.random.fold_in(jax.random.key(np.int32(as_before)), 1))
+    assert np.array_equal(batch["image"], jax.random.randint(
+        k_img, (4, 8, 8, 3), 0, 256, jnp.int32).astype(jnp.uint8))
+    assert np.array_equal(batch["label"], jax.random.randint(
+        k_lbl, (4,), 0, 10, jnp.int32))
