@@ -42,6 +42,7 @@ from distribuuuu_tpu.models.gpt import gpt_nano, gpt_nano_moe  # noqa: F401
 from distribuuuu_tpu.models.olmoe import olmoe_1b_7b, olmoe_tiny  # noqa: F401
 from distribuuuu_tpu.models.ouro import ouro_2_6b, ouro_tiny  # noqa: F401
 from distribuuuu_tpu.models.glm_moe import glm_4_7_flash, glm_moe_tiny  # noqa: F401
+from distribuuuu_tpu.models.lfm2_moe import lfm2_24b_a2b, lfm2_moe_tiny  # noqa: F401
 from distribuuuu_tpu.models.traits import ArchTraits
 
 _REGISTRY = {}
@@ -93,6 +94,12 @@ for _fn in (
     # share of an expert-parallel group
     glm_4_7_flash,
     glm_moe_tiny,
+    # LFM2-24B-A2B (models/lfm2_moe.py): a stack of two layer kinds by the
+    # published pattern (gated short convolutions and grouped-query
+    # attention), leading dense layers, GLM's mixture without a shared
+    # expert, a tied head; one chip's share of an expert-parallel group
+    lfm2_24b_a2b,
+    lfm2_moe_tiny,
 ):
     register_model(_fn)
 

@@ -146,7 +146,8 @@ class MLA(nn.Module):
 
 class Mixture(nn.Module):
     """Sigmoid router with a balancing bias, this chip's share of the routed
-    experts, the shared expert. Returns ``(out, statistics)``: ``aux`` (the
+    experts, the shared expert (none where ``shared_experts`` is 0:
+    ``models/lfm2_moe.py``). Returns ``(out, statistics)``: ``aux`` (the
     balancing term, ``ops/moe.balance_stats``' form on the scores normalised
     over all experts), ``load_max_over_mean`` (all E), ``held_row_share``
     (the share of the (token, slot) choices that fell on held experts) and
@@ -163,6 +164,9 @@ class Mixture(nn.Module):
     dtype: Any
     train: bool = False
     mesh: Any = None
+    # added to the chosen scores' sum where the weights are normalised
+    # (ops/moe.top_k_biased): 1e-20 is GLM's, models/lfm2_moe.py gives 1e-6
+    norm_eps: float = 1e-20
 
     @nn.compact
     def __call__(self, x):
@@ -188,10 +192,13 @@ class Mixture(nn.Module):
             params, x.astype(self.dtype), top_k=self.top_k, mesh=self.mesh,
             router_x=x, held=(first, E),
             route=functools.partial(
-                moe_ops.sigmoid_route, bias=bias.value, scale=self.scale),
+                moe_ops.sigmoid_route, bias=bias.value, scale=self.scale,
+                eps=self.norm_eps),
         )
-        with jax.named_scope("moe_shared"):
-            out = out + MLP(d, f * self.shared_experts, self.dtype, name="shared")(x)
+        if self.shared_experts:
+            with jax.named_scope("moe_shared"):
+                out = out + MLP(
+                    d, f * self.shared_experts, self.dtype, name="shared")(x)
         with jax.named_scope("moe_route"):
             counts = verdict["counts"]
             # read only by a caller that makes the collection mutable (the

@@ -154,13 +154,15 @@ def _say_plan(model, batch: int, seq: int) -> None:
 
 
 def kept_plan(model, blocks: int, batch: int, seq: int, head_dim: int,
-              what: str) -> dict:
+              what: str, flash_blocks: int | None = None) -> dict:
     """The fields of a plan record (``loop.plan``; ``share.plan`` of
-    ``models/glm_moe.py``) that say what ``blocks`` recomputed blocks keep a
-    step: ``kept_bytes`` (their float32 inputs and ``kept_flash_bytes``),
-    ``kept_flash_bytes`` (the flash kernel's output and log-sum-exp and its
-    q, k and v; 0 where attention takes another path, which names nothing)
-    and ``recomputed``."""
+    ``models/glm_moe.py`` and ``models/lfm2_moe.py``) that say what ``blocks``
+    recomputed blocks keep a step: ``kept_bytes`` (their float32 inputs and
+    ``kept_flash_bytes``), ``kept_flash_bytes`` (the flash kernel's output
+    and log-sum-exp and its q, k and v, of the ``flash_blocks`` blocks that
+    hold attention, every one unless given, k and v at ``model.kv_heads``
+    heads where the model has fewer of them; 0 where attention takes another
+    path, which names nothing) and ``recomputed``."""
     if not model.recompute:
         return {"kept_bytes": None, "kept_flash_bytes": None, "recomputed": "nothing"}
     from distribuuuu_tpu.models.vit import Attention as VitAttention
@@ -168,9 +170,11 @@ def kept_plan(model, blocks: int, batch: int, seq: int, head_dim: int,
 
     flash = 0
     if VitAttention.resolve_impl(model.attn_impl, seq, 0.0) == "flash":
-        flash = blocks * kept_under_remat_bytes(
-            (batch, model.num_heads, seq, head_dim),
-            jnp.dtype(model.dtype).itemsize, model.mesh)
+        flash = (blocks if flash_blocks is None else flash_blocks) * (
+            kept_under_remat_bytes(
+                (batch, model.num_heads, seq, head_dim),
+                jnp.dtype(model.dtype).itemsize, model.mesh,
+                kv_heads=getattr(model, "kv_heads", None)))
     return {
         "kept_bytes": blocks * batch * seq * model.dim * 4 + flash,
         "kept_flash_bytes": flash,

@@ -28,9 +28,17 @@ multiply of the float32 ``dq``/``dk`` sums.
 
 Block sizes come from the shape (:func:`choose_blocks`, swept on a v5e
 with ``tools/flash_bench.py``); L is padded to the 128 lanes internally
-with masked keys. Head dim: any up to 128 (padded to the lanes in VMEM), and
-whole lane tiles past it (256: latent attention's score and value dim; q, k
-and v share one shape). The backward's resident set (:func:`_vmem_bytes`: q,
+with masked keys. Head dim: any up to 128 (padded to the lanes in VMEM: at
+64 half of every tile and of every MXU pass is padding), and whole lane
+tiles past it (256: latent attention's score and value dim). k and v share
+one shape, q's but for the heads: they may have fewer, dividing q's (grouped
+queries; query head ``h`` reads key/value head ``h // group``). The K/V
+block of query program ``i`` is then row ``i // group`` of the ``[B·H_kv,
+Lp, D]`` tensors, so K and V are never repeated in HBM; the backward kernel
+keeps its grid over the QUERY heads and leaves dK and dV a query head, and
+one XLA reduction over ``[B·H_kv, group, Lp, D]`` (float32 sums) gives a
+key/value head's. With one head a head the index maps, and so the program,
+are what they were. The backward's resident set (:func:`_vmem_bytes`: q,
 dO, dq and its accumulator whole) bounds the length: ~19k tokens at D ≤ 128
 in bf16, ~9.2k at D = 256 (8192 tokens hold 41.0 of the 48 MiB budget at
 512² blocks), half that in float32. Longer sequences, any off-TPU call and
@@ -41,8 +49,9 @@ in ``kernel.select`` (op ``flash_attn``).
 
 Shapes it runs at: the token decoders' ``[B, 16, 4096, 128]`` causal
 (``models/olmoe.py``, and Ouro's blocks through it), latent attention's
-``[1, 20, 8192, 256]`` causal (``models/glm_moe.py``) and ViT-Ti at 1024px
-``[B, 3, 4096, 64]``, non-causal.
+``[1, 20, 8192, 256]`` causal (``models/glm_moe.py``), grouped queries'
+``[2, 32 on 8, 8192, 64]`` causal (``models/lfm2_moe.py``) and ViT-Ti at
+1024px ``[B, 3, 4096, 64]``, non-causal.
 """
 
 from __future__ import annotations
@@ -108,7 +117,14 @@ def choose_blocks(L: int, d: int, causal: bool, itemsize: int = 2):
     (a 256 on either side costs 17–66 %, a 1024 6–9 %: the diagonal tiles'
     waste grows with them); at ``[4, 3, 4096, 64]`` non-causal 1024² is
     (4.6 % under 512², no diagonal to waste), while its float32 tiles fit
-    beside the sequence. Causal at d ≤ 64 was not swept and takes 512².
+    beside the sequence. Causal at d = 64 with grouped queries, ``[2, 32 on 8,
+    8192, 64]`` (PERF.md section 6, PR 41; forward / forward + backward ms):
+    512² 10.25 / 29.05 is the fastest forward and within 1.2 % of the best
+    sum (1024² 10.61 / 28.71, whose forward is 3.5 % slower); 256 x 1024
+    10.43 / 32.50, 512 x 1024 10.45 / 29.54, 1024 x 512 11.32 / 29.69; a
+    256-wide key block costs 42-85 % in the forward: causal d ≤ 64 takes
+    512² like the rest. (At d = 64 half of each tile's lanes are padding:
+    53.7 and 56.8 TFLOP/s of useful work, 27-29 % of the MXU's peak.)
     At ``[1, 20, 8192, 256]`` causal (PERF.md section 6, PR 32) 512² is
     within 0.5 % of the best forward (5.60 ms against 256 × 1024's 5.57) and
     the best forward + backward whose resident set fits the budget (17.12
@@ -308,9 +324,12 @@ def _bwd_kernel(
         dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
-def _specs(lp, d, blk):
+def _specs(lp, d, blk, group: int = 1):
     """BlockSpecs for [BH, Lp, D] tensors and [BH, 1, Lp] row statistics
-    over a (BH, L-blocks) grid: a block of ``blk`` rows, or the sequence."""
+    over a (BH, L-blocks) grid: a block of ``blk`` rows, or the sequence.
+    ``kv_whole``/``kv_blocked`` are the same for K and V of a grouped call:
+    query program ``i`` reads key/value head ``i // group`` (``i = b H_q +
+    h`` and ``H_q = group H_kv``, so that is ``b H_kv + h // group``)."""
 
     def blocked():
         return pl.BlockSpec(
@@ -328,7 +347,18 @@ def _specs(lp, d, blk):
         return pl.BlockSpec(
             (1, 1, lp), lambda i, j: (i, 0, 0), memory_space=pltpu.VMEM)
 
-    return blocked, whole, vec_blocked, vec_whole
+    if group == 1:  # the index maps (and so the program) of an equal-head call
+        return blocked, whole, vec_blocked, vec_whole, whole, blocked
+
+    def kv_whole():
+        return pl.BlockSpec(
+            (1, lp, d), lambda i, j: (i // group, 0, 0), memory_space=pltpu.VMEM)
+
+    def kv_blocked():
+        return pl.BlockSpec(
+            (1, blk, d), lambda i, j: (i // group, j, 0), memory_space=pltpu.VMEM)
+
+    return blocked, whole, vec_blocked, vec_whole, kv_whole, kv_blocked
 
 
 def _under_the_old_name(t, name, interpret):
@@ -359,13 +389,13 @@ def _pad_lhd(t, lp):
 def _flash_forward(q, k, v, scale, interpret, blk_q, blk_k, causal):
     b, h, L, d = q.shape
     blk_q, blk_k, lp = _resolve_blocks(L, blk_q, blk_k)
-    bh = b * h
+    bh, group = b * h, h // k.shape[1]
 
     qf = _pad_lhd(q.reshape(bh, L, d), lp)
-    kf = _pad_lhd(k.reshape(bh, L, d), lp)
-    vf = _pad_lhd(v.reshape(bh, L, d), lp)
+    kf = _pad_lhd(k.reshape(bh // group, L, d), lp)
+    vf = _pad_lhd(v.reshape(bh // group, L, d), lp)
 
-    blocked, whole, vec_blocked, _ = _specs(lp, d, blk_q)
+    blocked, _, vec_blocked, _, kv_whole, _ = _specs(lp, d, blk_q, group)
     o, lse = pl.pallas_call(
         functools.partial(
             _fwd_kernel, scale=scale, length=L, blk_k=blk_k, causal=causal
@@ -375,7 +405,7 @@ def _flash_forward(q, k, v, scale, interpret, blk_q, blk_k, causal):
             jax.ShapeDtypeStruct((bh, 1, lp), jnp.float32),
         ),
         grid=(bh, lp // blk_q),
-        in_specs=[blocked(), whole(), whole()],
+        in_specs=[blocked(), kv_whole(), kv_whole()],
         out_specs=(blocked(), vec_blocked()),
         compiler_params=_PARAMS,
         interpret=interpret,
@@ -399,6 +429,7 @@ def _flash_backward(res, g, scale, interpret, blk_q, blk_k, causal,
     (qf, kf, vf, lse, o, q_shape) = res
     b, h, L, d = q_shape
     bh, lp, _ = qf.shape
+    group = bh // kf.shape[0]
     # same resolution as the forward (lp is already a multiple of both)
     blk_q, blk_k, _ = _resolve_blocks(L, blk_q, blk_k)
 
@@ -409,7 +440,7 @@ def _flash_backward(res, g, scale, interpret, blk_q, blk_k, causal,
     if g_lse is not None:
         delta = delta - g_lse
 
-    blocked_k, whole, _, vec_whole = _specs(lp, d, blk_k)
+    blocked_k, whole, _, vec_whole, _, kv_blocked = _specs(lp, d, blk_k, group)
     dq, dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_kernel, scale=scale, length=L, blk_q=blk_q, causal=causal
@@ -420,7 +451,7 @@ def _flash_backward(res, g, scale, interpret, blk_q, blk_k, causal,
             jax.ShapeDtypeStruct((bh, lp, d), vf.dtype),
         ),
         grid=(bh, lp // blk_k),
-        in_specs=[whole(), whole(), blocked_k(), blocked_k(),
+        in_specs=[whole(), whole(), kv_blocked(), kv_blocked(),
                   vec_whole(), vec_whole()],
         out_specs=(whole(), blocked_k(), blocked_k()),
         scratch_shapes=[pltpu.VMEM((lp, d), jnp.float32)],
@@ -432,8 +463,14 @@ def _flash_backward(res, g, scale, interpret, blk_q, blk_k, causal,
     dk = _under_the_old_name(dk, "dtpu_flash_dkdv", interpret)
 
     def unpad(t):
-        return t[:, :L].reshape(b, h, L, d)
+        return t[:, :L].reshape(b, -1, L, d)
 
+    if group > 1:
+        # the kernel leaves dK and dV a QUERY head; a key/value head's is the
+        # sum over its group, one XLA reduction (float32 sums, rounded once)
+        dk, dv = (
+            t.reshape(bh // group, group, lp, d).astype(jnp.float32).sum(1)
+            .astype(t.dtype) for t in (dk, dv))
     return unpad(dq), unpad(dk), unpad(dv)
 
 
@@ -517,6 +554,18 @@ def _check_head_dim(d: int) -> None:
             f"head_dim {d} > 128 is no multiple of the 128 lanes: not supported")
 
 
+def _kv_group(q, k, v) -> int:
+    """Query heads a key/value head (1: q, k and v of one shape). Query head
+    ``h`` reads key/value head ``h // group``."""
+    heads, kv_heads = q.shape[1], k.shape[1]
+    if k.shape != v.shape or heads % kv_heads or (
+            q.shape[:1] + q.shape[2:] != k.shape[:1] + k.shape[2:]):
+        raise ValueError(
+            f"q {q.shape}, k {k.shape}, v {v.shape}: k and v share one shape, "
+            "q's but for the heads, and their heads divide q's")
+    return heads // kv_heads
+
+
 def _data_ranks(mesh, batch: int) -> int:
     """The data ranks of ``mesh`` that each run the kernel on their own
     sequences under ``shard_map``; 1 where there is no such mesh or its data
@@ -532,8 +581,11 @@ def flash_attention(
 ):
     """Exact softmax attention, flash-tiled in Pallas.
 
-    q, k, v: [B, H, L, D]. Returns [B, H, L, D] in v.dtype. Differentiable
-    (flash backward: recompute from K/V blocks + saved log-sum-exp).
+    q: [B, H, L, D]; k, v: [B, H_kv, L, D] with H_kv dividing H (H_kv = H:
+    one head a head; fewer: grouped queries, head h reading key/value head h
+    // (H / H_kv), nothing repeated in HBM). Returns [B, H, L, D] in v.dtype.
+    Differentiable (flash backward: recompute from K/V blocks + saved
+    log-sum-exp; dK and dV come back [B, H_kv, L, D]).
 
     ``causal=True`` applies the autoregressive mask in-kernel: wholly
     masked tiles are never visited (the loop bounds shrink with the program
@@ -553,8 +605,9 @@ def flash_attention(
     with what blocks or why not, is a ``kernel.select``/``kernel.fallback``
     record once a traced shape.
     """
-    b, _, L, d = q.shape
+    b, h, L, d = q.shape
     _check_head_dim(d)
+    group = _kv_group(q, k, v)
     scale = d ** -0.5 if scale is None else scale
     if _data_ranks(mesh, b) > 1:
         def per_shard(q, k, v):
@@ -585,6 +638,7 @@ def flash_attention(
         tiles_crossed=crossed,
         tiles_masked=visited if causal or lp != L else 0,
         bwd_matmuls_a_tile=BWD_MATMULS_A_TILE,
+        **({"kv_group": group, "kv_heads": h // group} if group > 1 else {}),
     )
     if impl == "xla":
         # stream from HBM via the scan path: off the TPU (the interpreter is
@@ -593,16 +647,20 @@ def flash_attention(
         # the VMEM bound instead of failing at Mosaic compile time
         from distribuuuu_tpu.ops.ring_attention import blockwise_attention
 
+        # the scan takes q, k and v of one shape: the group's heads repeated
+        k, v = (jnp.repeat(t, group, axis=1) if group > 1 else t for t in (k, v))
         return blockwise_attention(q, k, v, causal=causal, scale=scale)
     if interpret is None:
         interpret = False
     return _flash_attention(q, k, v, scale, interpret, blk_q, blk_k, causal)
 
 
-def kept_under_remat_bytes(q_shape, itemsize: int, mesh=None) -> int:
+def kept_under_remat_bytes(q_shape, itemsize: int, mesh=None,
+                           kv_heads: int | None = None) -> int:
     """Bytes of :data:`KEPT_UNDER_REMAT` one ``flash_attention`` call at
     ``q_shape`` leaves a recomputed block that keeps them (``o`` as it is
-    returned, the float32 ``lse`` and q, k and v at the padded length); 0
+    returned, the float32 ``lse`` and q, k and v at the padded length, k and
+    v with ``kv_heads`` heads where they are fewer than q's); 0
     where the call takes the scan path, which names nothing. What a model's
     plan record says (``loop.plan``, ``share.plan``): the questions are
     ``flash_attention``'s own (the platform, one device or a ``shard_map``
@@ -612,7 +670,8 @@ def kept_under_remat_bytes(q_shape, itemsize: int, mesh=None) -> int:
     if kernel_tier.interpret_mode() or across or not fits_vmem(L, d, itemsize):
         return 0
     lp = _round_up(L, 128)
-    return b * h * (L * d * itemsize + lp * 4 + 3 * lp * d * itemsize)
+    kv = 2 * (h if kv_heads is None else kv_heads) * lp * d * itemsize
+    return b * (h * (L * d * itemsize + lp * 4 + lp * d * itemsize) + kv)
 
 
 def flash_attention_with_lse(
@@ -635,6 +694,7 @@ def flash_attention_with_lse(
     """
     d = q.shape[-1]
     _check_head_dim(d)
+    _kv_group(q, k, v)
     scale = d ** -0.5 if scale is None else scale
     if interpret is None:
         interpret = jax.default_backend() != "tpu"

@@ -132,16 +132,18 @@ def gating_scores(x, gate_w):
     return jax.nn.sigmoid(x.astype(jnp.float32) @ gate_w.astype(jnp.float32))
 
 
-def top_k_biased(scores, bias, top_k: int, scale: float = 1.0):
+def top_k_biased(scores, bias, top_k: int, scale: float = 1.0,
+                 eps: float = 1e-20):
     """The verdict of a router that balances by a bias (arXiv:2412.19437
     section 2.1.2): the ``top_k`` experts by ``scores + bias``, each weighted
-    by its UNBIASED score over the chosen ones' sum, times ``scale``
+    by its UNBIASED score over the chosen ones' sum plus ``eps`` (1e-20:
+    GLM's; LFM2's modeling file adds 1e-6), times ``scale``
     (``norm_topk_prob`` with ``routed_scaling_factor``). The bias decides who
     is chosen and nothing else: no gradient reaches it. Returns (weights
     [T, k] f32, indices [T, k] i32)."""
     _, indices = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
     chosen = jnp.take_along_axis(scores, indices, axis=-1)
-    weights = scale * chosen / (chosen.sum(axis=-1, keepdims=True) + 1e-20)
+    weights = scale * chosen / (chosen.sum(axis=-1, keepdims=True) + eps)
     return weights, indices.astype(jnp.int32)
 
 
@@ -160,13 +162,15 @@ def softmax_route(x, gate_w, top_k: int):
     return (probs, *top_k_as_is(probs, top_k))
 
 
-def sigmoid_route(x, gate_w, top_k: int, *, bias, scale: float):
+def sigmoid_route(x, gate_w, top_k: int, *, bias, scale: float,
+                  eps: float = 1e-20):
     """``(probs, weights, indices)`` of the sigmoid router with a balancing
     bias; ``probs`` are the scores normalised over ALL experts, what the
-    balancing loss reads (:func:`balance_stats`' ``p``)."""
+    balancing loss reads (:func:`balance_stats`' ``p``); ``eps`` is
+    :func:`top_k_biased`'s."""
     scores = gating_scores(x, gate_w)
     probs = scores / (scores.sum(axis=-1, keepdims=True) + 1e-20)
-    return (probs, *top_k_biased(scores, bias, top_k, scale))
+    return (probs, *top_k_biased(scores, bias, top_k, scale, eps))
 
 
 def top_k_gating(x, gate_w, top_k: int):

@@ -331,6 +331,14 @@ def lm_spec_table(moe_axis: str = "model") -> SpecTable:
             SpecRule(r"moe/shared/(gate|up)_proj/kernel$", P(None, "model")),
             SpecRule(r"moe/shared/down_proj/kernel$", P("model")),
             SpecRule(r"mtp_proj/kernel$", P()),
+            # the lfm2_* family (models/lfm2_moe.py): grouped-query
+            # attention, norm scales, dense MLP, router, held experts and the
+            # tied embedding by the rules above; the short convolution's
+            # projections as a dense pair (in by columns: the gates and the
+            # filter are per channel; out by rows), its filter by channel
+            SpecRule(r"short_conv/in_proj/kernel$", P(None, "model")),
+            SpecRule(r"short_conv/out_proj/kernel$", P("model")),
+            SpecRule(r"short_conv/filter$", P("model")),
         ),
         default=None,  # unmatched leaves keep their annotation/replication
         strict=False,

@@ -166,7 +166,9 @@ KINDS: dict[str, frozenset] = {
     # expert-parallel group (models/glm_moe.py): how many chips share each
     # layer and which of them this is, what it holds of the routed experts
     # and of the vocabulary's rows, what its recomputed blocks keep (as
-    # loop.plan) and what its backward computes again
+    # loop.plan) and what its backward computes again; models/lfm2_moe.py
+    # adds layer_kinds (layer_types' word for each layer it built, in order)
+    # and dense_layers (how many of them carry the dense MLP)
     "share.plan": frozenset(
         {"share_chips", "share_rank", "experts_held", "experts_total", "vocab_held",
          "vocab_total", "kept_bytes", "kept_flash_bytes", "recomputed"}
@@ -291,6 +293,11 @@ DEVICE_SCOPES: dict[str, str] = {
     "mla_latent": "models",
     "moe_shared": "models",
     "mtp": "models",
+    # models/lfm2_moe.py (``attn``, ``mlp``, ``moe`` and ``lm_head`` as
+    # above): the gated short-convolution mixer whole, and inside it all
+    # that is no matmul (the two gates and the filter; ops/short_conv.py)
+    "short_conv": "models",
+    "short_conv_gate": "kernels",
     # ops/pallas/opt_update.py
     "opt_tile": "kernels",
     "opt_kernel": "kernels",

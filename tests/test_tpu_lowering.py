@@ -215,6 +215,118 @@ def test_flash_compiles_for_the_v5e_at_the_blocks_the_shape_chooses(
     assert fa._vmem_bytes(lp, d, 2, blk_q, blk_k) < fa._VMEM_LIMIT
 
 
+def test_grouped_flash_compiles_for_the_v5e_without_a_repeated_key_or_value(v5e_chip):
+    """``lfm2_24b_a2b.train_seq8192``'s attention: 32 query heads on 8
+    key/value heads of 64 at 2 x 8192 tokens, causal. Mosaic takes both
+    kernels with K and V at their own 8 heads (no broadcast of them in the
+    program), and dK and dV come out a key/value head: one XLA reduction over
+    the group behind the backward kernel."""
+    from jax.sharding import SingleDeviceSharding
+
+    chip = SingleDeviceSharding(v5e_chip)
+    q = jax.ShapeDtypeStruct((2, 32, 8192, 64), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((2, 8, 8192, 64), jnp.bfloat16, sharding=chip)
+
+    def loss(q, k, v):
+        return fa.flash_attention(
+            q, k, v, causal=True, interpret=False).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "dtpu_flash_" in line]
+    assert len(calls) == 4 and sum("dtpu_flash_bwd" in c for c in calls) == 1
+    # the forward kernel's K and V operands are the 16 (batch, kv head) rows
+    forward = [c for c in calls if "dtpu_flash_fwd" in c][0]
+    assert forward.count("bf16[16,8192,64]") == 2 and forward.count("bf16[64,8192,64]") >= 2
+    assert [tuple(x.shape) for x in compiled.out_info] == [
+        (2, 32, 8192, 64), (2, 8, 8192, 64), (2, 8, 8192, 64)]
+    assert fa.fits_vmem(8192, 64)
+
+
+def test_lfm2_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
+    """The step of ``lfm2_24b_a2b.train_seq8192`` (``config/lfm2_24b_a2b.yaml``:
+    published widths, 2 x 8192 tokens, 8 of 64 experts and 8,192 vocabulary
+    rows held) compiled for the chip at the dense conv layer and the
+    attention mixture (published layers 1..2; the cell's 1..5 compile in a
+    minute here). What the benchmark's readers find in it: every scope they
+    sum, ``short_conv_gate`` inside ``short_conv`` and no matmul under it,
+    the grouped flash kernels and the six grouped matmuls by name, and no
+    ``while``; with ``LM.RECOMPUTE`` both block kinds run again in the
+    backward, without the flash forward kernel."""
+    from jax.sharding import SingleDeviceSharding
+
+    import distribuuuu_tpu.config as config
+    from benchmark.harness.trace import in_scope, op_names_from_hlo
+    from distribuuuu_tpu import trainer
+    from distribuuuu_tpu.config import cfg
+    from distribuuuu_tpu.ops import pallas as kernel_tier
+    from distribuuuu_tpu.parallel import mesh as mesh_lib
+    from distribuuuu_tpu.parallel.partition import lowering, topology
+    from distribuuuu_tpu.utils.optim import construct_optimizer
+
+    monkeypatch.setattr(kernel_tier, "interpret_mode", lambda: False)
+    monkeypatch.setattr(kernel_tier, "compiled_across_devices", lambda: False)
+    config.reset_cfg()
+    config.merge_from_file("config/lfm2_24b_a2b.yaml")
+    cfg.LM.FIRST_LAYER, cfg.LM.LAYERS, cfg.LM.RECOMPUTE = 1, 2, True
+    cfg.MESH.DATA, cfg.KERNELS.OPT_UPDATE = 1, "pallas"
+    try:
+        layout = topology.from_cfg(cfg, n_devices=1)
+        lowered = lowering.lower(
+            trainer.build_model_from_cfg(layout), construct_optimizer(), 5,
+            mesh=mesh_lib.build_mesh(data=1, devices=[v5e_chip]),
+            topology=layout, im_size=cfg.TRAIN.IM_SIZE,
+        )
+        state, batch = lowered.abstract_args(2)
+    finally:
+        config.reset_cfg()
+    model = lowered.model
+    assert model.layer_kinds == ("conv", "full_attention") and model.dense_here == 1
+    assert model.held == (0, 8) and model.vocab_held == 8192
+    chip = SingleDeviceSharding(v5e_chip)
+    batch = {k: jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=chip)
+             for k in batch}
+    text = lowered.train_step.lower(state, batch).compile().as_text()
+    assert " while(" not in text and " conditional(" not in text
+    assert "ragged-dot" not in text  # the held experts run the Pallas kernels
+    paths = list(op_names_from_hlo(text).values())
+    for scope in ("fwd", "bwd", "short_conv", "short_conv_gate", "attn", "mlp", "moe",
+                  "moe_route", "moe_experts", "lm_head", "optimizer_update",
+                  "opt_kernel", "rematted_computation"):
+        assert any(in_scope(p, scope) for p in paths), scope
+    gate = [p for p in paths if in_scope(p, "short_conv_gate")]
+    assert all(in_scope(p, "short_conv") for p in gate)
+    assert not any(p.endswith("dot_general") for p in gate)  # no matmul in it
+    assert any(in_scope(p, "short_conv") and p.endswith("in_proj/dot_general")
+               for p in paths)
+    calls = {}
+    for line in text.splitlines():
+        if "custom-call(" in line and "dtpu_" in line:
+            name = line.split(" = ")[0].split()[-1].lstrip("%").rsplit(".", 1)[0]
+            calls.setdefault(name, []).append(line.split('op_name="')[1].split('"')[0])
+    flash = {k: len(v) for k, v in calls.items() if "flash" in k}
+    assert (flash["dtpu_flash_fwd"], flash["dtpu_flash_bwd"]) == (1, 1)
+    assert not any(in_scope(p, "rematted_computation") or in_scope(p, "bwd")
+                   for p in calls["dtpu_flash_fwd"])
+    recomputed = [p for p in paths if in_scope(p, "rematted_computation")]
+    assert any(in_scope(p, "short_conv_gate") for p in recomputed)
+    assert any(in_scope(p, "moe_route") for p in recomputed)
+    # the kept q, k and v spare the recomputation v's projection, the rotary
+    # and the head layouts; q's and k's projections run again, because the
+    # per-head norm that follows them reads their output in its own backward
+    assert not any("v_proj/dot_general" in p for p in recomputed)
+    for proj in ("q_proj", "k_proj"):
+        assert any(f"{proj}/dot_general" in p for p in recomputed), proj
+    gmm = {k: len(v) for k, v in calls.items() if "moe_gmm" in k}
+    assert gmm == {
+        "dtpu_moe_gmm_gate_up": 2, "dtpu_moe_gmm_fwd": 2, "dtpu_moe_gmm_act_bwd": 1,
+        "dtpu_moe_gmm_dx_gate_up": 1, "dtpu_moe_gmm_dw_down": 1,
+        "dtpu_moe_gmm_dw_gate_up": 1}
+    # the tied embedding is ONE leaf and takes one AdamW call
+    assert len(calls["dtpu_opt_update_adamw"]) == len(jax.tree.leaves(state.params))
+
+
 def _olmoe_experts(tokens=16384, d=2048, f=1024, experts=64, top=8):
     """``sorted_experts`` at the widths of ``olmoe_1b_7b.train_seq4096``
     (4 x 4096 tokens a step), the kernel arm forced compiled."""

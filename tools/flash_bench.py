@@ -26,7 +26,7 @@ for OLMoE's cell and ``--batch 1`` for Ouro's):
 
     python tools/flash_bench.py [--batch 4] [--heads 3] [--seq 4096]
         [--dim 64] [--causal] [--iters 20] [--rounds 5] [--skip-dense]
-        [--blk-q N] [--blk-k N] [--sweep] [--baseline FILE]
+        [--blk-q N] [--blk-k N] [--sweep] [--baseline FILE] [--kv-heads N]
 
 ``--blk-q``/``--blk-k`` default to what ``flash_attention.choose_blocks``
 picks for the shape (printed). ``--sweep`` times the flash path alone at
@@ -34,6 +34,14 @@ every pair of {256, 512, 1024}, forward and forward+backward, beside the
 chosen pair: what ``choose_blocks`` was set from. ``--baseline FILE`` loads
 another checkout's ``ops/flash_attention.py`` as path ``base`` (its own
 default blocks) into the same interleaved rounds.
+
+``--kv-heads N`` gives k and v N heads where q has ``--heads`` (grouped
+queries; LFM2's attention is ``--batch 2 --heads 32 --kv-heads 8 --seq 8192
+--dim 64 --causal``): path ``flash`` hands the kernels k and v as they are,
+path ``repeat`` repeats them to q's heads in HBM first (what a caller had to
+do before the kernels took a group; its backward sums dK and dV over the
+group in autodiff's transpose of the repeat), and the scan and dense paths
+read the repeated heads.
 
 ``--kernel decode`` (ISSUE 13) switches the harness to the kernel
 tier's fused decode attention (ops/pallas/decode_attn.py) vs the dense
@@ -90,8 +98,13 @@ def make_bwd_runner(fn, q, k, v, iters: int):
     def run(q, k, v):
         def body(c, _):
             dq, dk, dv = grad(c, k, v)
-            # feedback must depend on ALL grads (hazard 1 in the docstring)
-            return (dq + dk + dv).astype(c.dtype), ()
+            # feedback must depend on ALL grads (hazard 1 in the docstring);
+            # grouped k and v have fewer heads than dq: their sum, scaled to
+            # nothing, one small reduction
+            if dk.shape == dq.shape:
+                return (dq + dk + dv).astype(c.dtype), ()
+            rest = (dk + dv).astype(jnp.float32).sum() * 1e-30
+            return (dq + rest.astype(dq.dtype)).astype(c.dtype), ()
 
         out, _ = jax.lax.scan(body, q, None, length=iters)
         return out
@@ -125,7 +138,7 @@ def report(tag: str, times: dict, flops: float | None = None):
             f"{tag} {name:9s}: median {med[name] * 1e3:7.3f} ms "
             f"[{min(ts) * 1e3:.3f}, {max(ts) * 1e3:.3f}]{extra}"
         )
-    for other in ("scan", "dense", "base"):
+    for other in ("scan", "dense", "base", "repeat"):
         if "flash" in times and other in times:
             ratios = sorted(
                 o / f for o, f in zip(times[other], times["flash"])
@@ -210,6 +223,9 @@ def main():
                          "attention (--seq = cache tile)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--heads", type=int, default=3)
+    ap.add_argument("--kv-heads", type=int, default=None,
+                    help="heads of k and v where fewer than q's (grouped "
+                         "queries); adds path 'repeat'")
     ap.add_argument("--seq", type=int, default=4096)
     ap.add_argument("--dim", type=int, default=64)
     ap.add_argument("--iters", type=int, default=20,
@@ -252,12 +268,23 @@ def main():
     B, H, L, D = args.batch, args.heads, args.seq, args.dim
     print(f"backend={jax.default_backend()} "
           f"device={jax.devices()[0].device_kind} shape=[{B},{H},{L},{D}] "
+          f"kv_heads={args.kv_heads or H} "
           f"iters={args.iters} rounds={args.rounds}")
     rng = np.random.default_rng(0)
+    kv_heads = args.kv_heads or H
+    group = H // kv_heads
     q, k, v = (
-        jnp.asarray(rng.standard_normal((B, H, L, D)), jnp.bfloat16)
-        for _ in range(3)
+        jnp.asarray(rng.standard_normal((B, heads, L, D)), jnp.bfloat16)
+        for heads in (H, kv_heads, kv_heads)
     )
+
+    def repeated(fn):
+        """``fn`` on k and v repeated to q's heads (a no-op at group 1)."""
+        if group == 1:
+            return fn
+        return lambda q, k, v: fn(
+            q, jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1))
+
     # causal touches only the lower triangle — half the score/PV work
     flops = 2 * 2 * B * H * L * L * D * (0.5 if args.causal else 1.0)
 
@@ -271,27 +298,29 @@ def main():
         return lambda q, k, v: module.flash_attention(
             q, k, v, causal=args.causal, blk_q=blk_q, blk_k=blk_k)
 
-    def scan(q, k, v):
-        return ra.blockwise_attention(q, k, v, causal=args.causal)
+    scan = repeated(
+        lambda q, k, v: ra.blockwise_attention(q, k, v, causal=args.causal))
 
     paths = {"flash": flash(blk_q, blk_k)}
+    if group > 1:
+        paths["repeat"] = repeated(flash(blk_q, blk_k))
     if args.baseline:
         import importlib.util
 
         spec = importlib.util.spec_from_file_location("flash_base", args.baseline)
         base = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(base)
-        paths["base"] = lambda q, k, v: base.flash_attention(
-            q, k, v, causal=args.causal)
+        paths["base"] = repeated(lambda q, k, v: base.flash_attention(
+            q, k, v, causal=args.causal))
     if args.sweep:
         sizes = (256, 512, 1024)
         paths.update({f"{a}x{b}": flash(a, b) for a in sizes for b in sizes})
     else:
         paths["scan"] = scan
         if not args.skip_dense:
-            paths["dense"] = lambda q, k, v: ra.reference_attention(
+            paths["dense"] = repeated(lambda q, k, v: ra.reference_attention(
                 q, k, v, causal=args.causal
-            )
+            ))
 
     # the kernels against the scan on this device, once: output and all
     # three gradients (bf16 in, so ~1e-2 of the largest value is rounding)
