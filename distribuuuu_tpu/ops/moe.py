@@ -40,6 +40,20 @@ Paths:
   (sized for all T * k: how many land here is the data's to say), and the
   result is that chip's PART of the layer's sum. The exchange that would
   bring the other chips' parts is not here (ROADMAP R2).
+  What moves the rows (:func:`_row_mover`): with every expert held every
+  row of the buffer is live, and XLA moves them: one gather of ``[T * k, d]``
+  into the buffer (``_take_sorted``) and one out of it (``_rows_back``),
+  a ``[T, k, d]`` product with the weights and a sum over the slots, and
+  their transposes. With a share held most row tiles of the buffer are
+  dead, and on the kernel path ``ops/pallas/moe_rows.py`` follows the tile
+  table as the grouped matmuls do: ``take`` copies the tokens' rows into
+  the LIVE tiles (a dead tile is never written, and holds whatever the
+  memory held: nothing outside the kernels may read it), ``combine`` sums
+  each token's PRESENT slots in float32 with the weights, and each is the
+  other's backward; no ``[T * k, d]`` or ``[T, k, d]`` array is read or
+  written whole. What stays in XLA is arithmetic on ``[T * k]`` integers
+  and floats (router, the two sorts, the tile table, each group's shifted
+  copy into the buffer's order, one gather of T * k floats in the backward).
 
 Gating: ``top_k_from_probs`` renormalizes the selected probabilities to sum
 to 1 (the switch/mixtral convention; ``gpt_nano_moe``, ``vit_tiny_moe``).
@@ -59,6 +73,7 @@ and for the sorted path ``w_gate``/``w_up`` [E, d, f], ``w_down`` [E, f, d].
 from __future__ import annotations
 
 import functools
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -66,7 +81,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distribuuuu_tpu.ops import pallas as kernel_tier
-from distribuuuu_tpu.ops.pallas import moe_gmm
+from distribuuuu_tpu.ops.pallas import moe_gmm, moe_rows
 
 
 def init_moe_params(key, d_model: int, d_ff: int, num_experts: int):
@@ -142,7 +157,11 @@ def top_k_biased(scores, bias, top_k: int, scale: float = 1.0,
     is chosen and nothing else: no gradient reaches it. Returns (weights
     [T, k] f32, indices [T, k] i32)."""
     _, indices = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
-    chosen = jnp.take_along_axis(scores, indices, axis=-1)
+    # scores[t, indices[t]] as a compare and a sum, as :func:`_pick`: XLA:TPU
+    # runs a gather of T * k floats (and its scatter back) an element at a
+    # time, 0.67 ms at 16,384 tokens (PERF.md section 6, PR 42)
+    hot = indices[..., None] == jnp.arange(scores.shape[-1], dtype=indices.dtype)
+    chosen = jnp.where(hot, scores[..., None, :], 0).sum(axis=-1)
     weights = scale * chosen / (chosen.sum(axis=-1, keepdims=True) + eps)
     return weights, indices.astype(jnp.int32)
 
@@ -653,6 +672,156 @@ def _gmm_row_tile(rows: int, E: int, d: int, f: int, interpret, total: int):
     return tm if impl == "pallas" else None
 
 
+class _Layout(NamedTuple):
+    """Where the (token, slot) rows sit in the sorted buffer
+    (:func:`_sorted_layout`)."""
+
+    src: jax.Array      # [rows of the buffer]: its (token, slot), T * k on a pad row
+    dst: jax.Array      # [T * k]: its row of the buffer, past it if absent
+    sizes: jax.Array    # [E]: rows an expert received
+    expert: Any         # [tiles] and [1]: moe_gmm.tile_table's, None without
+    n_live: Any         # a row tile (rows back to back)
+    present: Any        # [T * k] bool where some expert is not held, else None
+    flat: jax.Array     # [T * k]: its group, 0 .. E - 1 (E if absent)
+    starts: Any         # [E]: the buffer row each group starts at, or None
+    scale: Any          # [rows of the buffer]: its weight (a held share's movers)
+
+    @property
+    def tm(self) -> int:
+        """The row tile (where there is a tile table)."""
+        return self.src.shape[0] // self.expert.shape[0]
+
+
+def _aligned(compact, sizes, offsets, table, tm: int, pad):
+    """``compact`` [T * k], one entry a sorted (token, slot), the groups
+    back to back, as the buffer's rows hold them: group ``e`` from row
+    ``starts[e]``, ``pad`` on the pad rows. Each group is a SHIFTED COPY of
+    its run (a roll by ``starts[e] - offsets[e]``), one an expert held: XLA:TPU
+    runs the gather ``compact[pos]`` an element at a time, 0.48 ms for the
+    67,584 rows of 16,384 tokens (PERF.md section 6, PR 42)."""
+    expert, _, starts = table
+    tiles = expert.shape[0]
+    padded = jnp.concatenate([
+        compact, jnp.full((tiles * tm - compact.shape[0],), pad, compact.dtype)])
+    row = jnp.arange(tiles * tm, dtype=jnp.int32).reshape(tiles, tm)
+    real = row - _pick(starts, expert)[:, None] < _pick(sizes, expert)[:, None]
+    out = jnp.full((tiles, tm), pad, compact.dtype)
+    for e in range(sizes.shape[0]):
+        shifted = jnp.roll(padded, starts[e] - offsets[e]).reshape(tiles, tm)
+        out = jnp.where(real & (expert == e)[:, None], shifted, out)
+    return out.reshape(-1)
+
+
+def _sorted_layout(indices, E: int, first: int, total: int, tm,
+                   weights=None) -> _Layout:
+    """Sort the (token, slot) choices ``indices`` [T, k] by expert: the held
+    experts ``first .. first + E - 1`` of ``total`` in order, an absent
+    choice behind them all. With a row tile ``tm`` every group starts on a
+    tile boundary (``moe_gmm.tile_table``). ``weights`` [T, k] ride a held
+    share's sort, so that ``scale`` is each buffer row's weight."""
+    T, k = indices.shape
+    partial = total != E
+    flat, present = indices.reshape(T * k), None
+    expert = n_live = starts = scale = None
+    if partial:  # the absent sort behind every held group
+        flat = flat - first
+        present = (flat >= 0) & (flat < E)
+        flat = jnp.where(present, flat, E)
+    if partial and tm is not None and weights is not None:
+        _, order, by_row = jax.lax.sort(
+            (flat, jnp.arange(T * k, dtype=jnp.int32),
+             jax.lax.stop_gradient(weights).reshape(T * k).astype(jnp.float32)),
+            num_keys=1, is_stable=True)
+    else:
+        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    inverse = jnp.argsort(order).astype(jnp.int32)
+    sizes = expert_counts(flat, E)
+    if tm is None:  # sorted rows, back to back
+        src, dst = order, inverse
+        if partial:  # behind the last group: zeros
+            src = jnp.where(
+                jnp.arange(T * k, dtype=jnp.int32) < sizes.sum(), order, T * k)
+    else:  # every group from a row-tile boundary, zeros in between
+        table = moe_gmm.tile_table(sizes, T * k, tm)
+        expert, n_live, starts = table
+        offsets = jnp.cumsum(sizes) - sizes  # the groups back to back
+        if partial:  # a few groups: each a shifted copy of its run
+            src = _aligned(order, sizes, offsets, table, tm, T * k)
+            if weights is not None:
+                scale = _aligned(by_row, sizes, offsets, table, tm, 0.0)
+        else:
+            # row r of the buffer, in a tile of group e, is row r - starts[e]
+            # of the group if the group is that long, else a pad row
+            row = jnp.arange(expert.shape[0] * tm, dtype=jnp.int32)
+            within = row.reshape(-1, tm) - _pick(starts, expert)[:, None]
+            pos = _pick(offsets, expert)[:, None] + within
+            src = jnp.where(within < _pick(sizes, expert)[:, None],
+                            order[jnp.minimum(pos, T * k - 1)], T * k)
+            src = src.reshape(-1)
+        dst = inverse + _pick(starts - offsets, flat)
+    if partial:  # an absent (token, slot) lies past the buffer
+        dst = jnp.where(present, dst, src.shape[0])
+    return _Layout(src, dst, sizes, expert, n_live, present, flat, starts, scale)
+
+
+def _mover_tables(lay: _Layout, top_k: int):
+    """``(src, dst, bounds, scale, n_live)`` as ``ops/pallas/moe_rows``
+    takes them."""
+    bounds = moe_rows.block_bounds(lay.flat, lay.starts, lay.starts.shape[0], top_k)
+    return (lay.src.reshape(-1, lay.tm), lay.dst.reshape(-1, top_k), bounds,
+            lay.scale.reshape(-1, lay.tm), lay.n_live)
+
+
+def _rows_in(x, lay: _Layout, top_k: int, tables, interpreted: bool):
+    """The tokens ``x`` [T, d] into the sorted buffer: the row movers over
+    the live tiles (``tables``: :func:`_mover_tables`'; a dead tile is not
+    written), or, with none, XLA's gather of every row of the buffer."""
+    if tables is not None:
+        return moe_rows.take(x, *tables, interpreted)
+    return _take_sorted(x, lay.src, lay.dst, top_k, lay.present is not None)
+
+
+def _rows_out(rows, weights, lay: _Layout, tables, interpreted: bool):
+    """The experts' rows back to their tokens, weighted by ``weights``
+    [T, k] and summed: the row movers over the present slots, in float32,
+    or, without ``tables``, XLA's gather of all T * k and its ``[T, k, d]``
+    passes."""
+    T, k = weights.shape
+    if tables is not None:
+        return moe_rows.combine(rows, weights, *tables, interpreted)
+    dst = lay.dst
+    if lay.present is not None:
+        dst = jnp.minimum(dst, rows.shape[0] - 1)
+    rows = _rows_back(rows, dst, lay.src).reshape(T, k, -1)
+    rows = rows * weights[..., None].astype(rows.dtype)
+    if lay.present is not None:  # whatever row an absent slot read, it adds nothing
+        rows = jnp.where(lay.present.reshape(T, k, 1), rows, 0)
+    return rows.sum(axis=1)
+
+
+def _row_mover(T: int, k: int, d: int, dtype, tm, E: int, total: int,
+               interpret) -> bool:
+    """Whether ``ops/pallas/moe_rows`` moves the rows into and out of the
+    sorted buffer, following the tile table as the grouped matmuls do: where
+    the experts are a SHARE of the router's (most of the buffer's tiles are
+    dead) and the grouped matmuls run (there is a tile table). With every
+    expert held every row is live and XLA's gathers stay (PERF.md section
+    6, PR 42 has both sides' ns a row). Decided on what is static in the
+    call, no knob; says which ran, and why, as :func:`_gmm_row_tile`."""
+    if total == E:
+        reason = "every expert is held: every row of the buffer is live"
+    elif tm is None:
+        reason = "no tile table: the grouped matmuls run as lax.ragged_dot"
+    else:
+        reason = moe_rows.unsupported(T, d, dtype)
+    impl = kernel_tier.select(
+        "moe_rows", supported=not reason, reason=reason,
+        forced=interpret is not None, tm=tm, rows_bound=T * k,
+        experts_held=E, experts_total=total,
+    )
+    return impl == "pallas"
+
+
 def sorted_experts(params, x, weights, indices, *, held=None, interpret=None):
     """The sorted path's body on ``x`` [T, d] with the router's verdict
     ``weights``/``indices`` [T, k] already taken: sort, the gated expert on
@@ -674,60 +843,29 @@ def sorted_experts(params, x, weights, indices, *, held=None, interpret=None):
     T, k = indices.shape
     E, d, f = params["w_gate"].shape
     first, total = (0, E) if held is None else held
-    partial = total != E
     tm = _gmm_row_tile(T * k, E, d, f, interpret, total)
+    mover = _row_mover(T, k, d, x.dtype, tm, E, total, interpret)
+    interpreted = kernel_tier.interpret_mode() if interpret is None else interpret
     with jax.named_scope("moe_route"):
-        flat = indices.reshape(T * k)
-        if partial:  # the absent sort behind every held group
-            flat = flat - first
-            present = (flat >= 0) & (flat < E)
-            flat = jnp.where(present, flat, E)
-        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-        inverse = jnp.argsort(order).astype(jnp.int32)
-        sizes = expert_counts(flat, E)
-        if tm is None:  # sorted rows, back to back
-            src, dst = order, inverse
-            if partial:  # behind the last group: zeros
-                src = jnp.where(
-                    jnp.arange(T * k, dtype=jnp.int32) < sizes.sum(), order, T * k)
-        else:  # every group from a row-tile boundary, zeros in between
-            expert, n_live, starts = moe_gmm.tile_table(sizes, T * k, tm)
-            offsets = jnp.cumsum(sizes) - sizes  # the groups back to back
-            # row r of the buffer, in a tile of group e, is row r - starts[e]
-            # of the group if the group is that long, else a pad row
-            row = jnp.arange(expert.shape[0] * tm, dtype=jnp.int32)
-            within = row.reshape(-1, tm) - _pick(starts, expert)[:, None]
-            pos = _pick(offsets, expert)[:, None] + within
-            src = jnp.where(within < _pick(sizes, expert)[:, None],
-                            order[jnp.minimum(pos, T * k - 1)], T * k)
-            src = src.reshape(-1)
-            dst = inverse + _pick(starts - offsets, flat)
-        if partial:  # an absent (token, slot) lies past the buffer
-            dst = jnp.where(present, dst, src.shape[0])
-        rows = _take_sorted(x, src, dst, k, partial)  # [T*k (+ pads), d]
+        lay = _sorted_layout(indices, E, first, total, tm, weights)
+        tables = _mover_tables(lay, k) if mover else None
+        rows = _rows_in(x, lay, k, tables, interpreted)  # [T*k (+ pads), d]
     with jax.named_scope("moe_experts"):
         if tm is None:
             w_gate, w_up, w_down = (
                 params[name].astype(x.dtype)
                 for name in ("w_gate", "w_up", "w_down")
             )
-            hidden = jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, sizes))
-            hidden = hidden * jax.lax.ragged_dot(rows, w_up, sizes)
-            rows = jax.lax.ragged_dot(hidden, w_down, sizes)
+            hidden = jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, lay.sizes))
+            hidden = hidden * jax.lax.ragged_dot(rows, w_up, lay.sizes)
+            rows = jax.lax.ragged_dot(hidden, w_down, lay.sizes)
         else:
             rows = moe_gmm.expert_ffn(
                 rows, params["w_gate"], params["w_up"], params["w_down"],
-                expert, n_live, tm,
-                kernel_tier.interpret_mode() if interpret is None else interpret,
+                lay.expert, lay.n_live, tm, interpreted,
             )
     with jax.named_scope("moe_route"):
-        if partial:
-            dst = jnp.minimum(dst, rows.shape[0] - 1)
-        rows = _rows_back(rows, dst, src).reshape(T, k, -1)
-        rows = rows * weights[..., None].astype(rows.dtype)
-        if partial:  # whatever row an absent slot read, it adds nothing
-            rows = jnp.where(present.reshape(T, k, 1), rows, 0)
-        return rows.sum(axis=1)
+        return _rows_out(rows, weights, lay, tables, interpreted)
 
 
 def moe_ffn_sorted(params, x, *, top_k: int, mesh=None, data_axis: str = "data",

@@ -1,7 +1,7 @@
 """The Pallas kernel tier (ISSUE 13): fused kernels for the memory-bound
 programs the cost ledger pinned, as ONE subsystem instead of one-offs.
 
-Four kernels, one discipline:
+Five kernels, one discipline:
 
 * ``opt_update``     — fused optimizer update (opt_update.py): ONE HBM
                        pass over params+grads+moments for SGD-momentum
@@ -25,6 +25,13 @@ Four kernels, one discipline:
                        NO knob: it is ``auto`` always, its tiles follow
                        the shapes, and ``ragged_dot`` stays only where no
                        tile fits (and as the tests' oracle).
+* ``moe_rows``       — the row movers of a held SHARE of the experts
+                       (moe_rows.py): the tokens' rows into the live
+                       tiles of the sorted buffer and the present slots
+                       back out, weighted and summed, where XLA's gathers
+                       moved every row of a buffer that is three quarters
+                       dead. No knob either: it runs where ``moe_gmm``
+                       does and some expert is not held.
 
 Tier discipline (every kernel, no exceptions):
 
@@ -85,13 +92,13 @@ KNOBS = {
 # ops without a knob: ``auto``, or ``pallas`` where the caller forces it
 # (``flash_attn`` is ops/flash_attention.py's two kernels, outside this
 # package; it resolves here so that its record sits beside the others)
-KNOBLESS = ("moe_gmm", "flash_attn")
+KNOBLESS = ("moe_gmm", "moe_rows", "flash_attn")
 
 # ops that have no shard_map of their own: they engage in a program their
 # caller declared one-device (module docstring; ``moe_gmm``'s caller
 # declares it inside its shard_map over the data axis, as ``flash_attn``'s
 # does where it was handed a mesh)
-_NO_SHARD_MAP = ("conv_epilogue", "decode_attn", "moe_gmm", "flash_attn")
+_NO_SHARD_MAP = ("conv_epilogue", "decode_attn", "moe_gmm", "moe_rows", "flash_attn")
 
 # process-lifetime emission/warn dedup: one kernel.select per (op, impl,
 # requested) resolution, one kernel.fallback + warning per (op, reason)

@@ -145,7 +145,9 @@ KINDS: dict[str, frozenset] = {
     # op adds what it chose, once a traced shape: `moe_gmm` tm, tk, tn,
     # pad_row_share, calls_a_step, experts_held (of experts_total the router
     # ranges over), rows_bound (the rows the buffer is sized for; the tile
-    # is decided on the expected rows * held / total); `flash_attn` L, d,
+    # is decided on the expected rows * held / total); `moe_rows` (the row
+    # movers of a held share of the experts, ops/pallas/moe_rows.py) tm,
+    # rows_bound, experts_held, experts_total; `flash_attn` L, d,
     # causal, blk_q, blk_k,
     # and a sequence's tiles_visited, tiles_crossed (by the diagonal or the
     # padding), tiles_masked (those that run the mask), bwd_matmuls_a_tile
@@ -312,6 +314,9 @@ KERNEL_NAMES: tuple[str, ...] = (
     # ops/pallas/moe_gmm.py, one prefix: _gate_up, _fwd, _act_bwd,
     # _dx_gate_up, _dw_down, _dw_gate_up (and _dx, _dw of the bare calls)
     "dtpu_moe_gmm",
+    # ops/pallas/moe_rows.py, one prefix: _pack, _take, _combine (under
+    # ``moe_route``: the rows of the live tiles into and out of the buffer)
+    "dtpu_moe_rows",
 )
 
 

@@ -323,8 +323,23 @@ def test_lfm2_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
         "dtpu_moe_gmm_gate_up": 2, "dtpu_moe_gmm_fwd": 2, "dtpu_moe_gmm_act_bwd": 1,
         "dtpu_moe_gmm_dx_gate_up": 1, "dtpu_moe_gmm_dw_down": 1,
         "dtpu_moe_gmm_dw_gate_up": 1}
+    _movers_of_held_mixtures(calls, in_scope, mixtures=1)
     # the tied embedding is ONE leaf and takes one AdamW call
     assert len(calls["dtpu_opt_update_adamw"]) == len(jax.tree.leaves(state.params))
+
+
+def _movers_of_held_mixtures(calls, in_scope, mixtures: int):
+    """A recomputed held mixture moves its rows through ``ops/pallas/
+    moe_rows``: ``take`` forward, again under recomputation and as the
+    combine's backward; ``combine`` forward and as the take's backward (the
+    recomputation has no use for the mixture's output); a ``pack`` before
+    each; all under ``moe_route``, where the benchmark's readers sum them."""
+    movers = {k: len(v) // mixtures for k, v in calls.items() if "moe_rows" in k}
+    assert movers == {"dtpu_moe_rows_take": 3, "dtpu_moe_rows_combine": 2,
+                      "dtpu_moe_rows_pack": 5}, movers
+    for name in movers:
+        assert all(in_scope(p, "moe_route") and not in_scope(p, "moe_experts")
+                   for p in calls[name]), name
 
 
 def _olmoe_experts(tokens=16384, d=2048, f=1024, experts=64, top=8):
@@ -392,6 +407,50 @@ def test_moe_gmm_compiles_for_the_v5e_under_its_scope(v5e_chip):
         op_name = line.split('op_name="')[1].split('"')[0]
         assert in_scope(op_name, "moe_experts"), op_name
     assert "ragged-dot" not in text
+
+
+@pytest.mark.parametrize("tokens", [8192, 16384], ids=["glm", "lfm2"])
+def test_moe_rows_compile_for_the_v5e_under_moe_route(v5e_chip, tokens):
+    """The movers of a held share at the two cells' shapes (8 of 64 experts
+    of 2048 x 1536, 4 a token, bf16): Mosaic takes the one-tile row copies,
+    the SMEM index blocks and the combine's 1024-word windows, forward and
+    backward; each call carries ``moe_route`` and not ``moe_experts``; no
+    ``[T * k, d]`` gather is left beside them and no loop at the XLA level."""
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmark.harness.trace import in_scope
+    from distribuuuu_tpu.ops.pallas import moe_rows
+
+    chip = SingleDeviceSharding(v5e_chip)
+    d, f, held, total, top = 2048, 1536, 8, 64, 4
+    avals = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        ({"w_gate": _f32(held, d, f), "w_up": _f32(held, d, f),
+          "w_down": _f32(held, f, d)},
+         jax.ShapeDtypeStruct((tokens, d), jnp.bfloat16), _f32(tokens, top),
+         jax.ShapeDtypeStruct((tokens, top), jnp.int32)))
+
+    def step(params, x, weights, indices):
+        return jax.value_and_grad(
+            lambda *a: moe_ops.sorted_experts(
+                *a, indices, held=(0, total), interpret=False,
+            ).astype(jnp.float32).sum(), argnums=(0, 1, 2))(params, x, weights)
+
+    text = jax.jit(step).lower(*avals).compile().as_text()
+    calls = [line for line in text.splitlines() if "custom-call(" in line
+             and line.split(" = ")[0].split()[-1].lstrip("%").startswith(moe_rows.NAME)]
+    names = sorted(line.split(" = ")[0].split()[-1].lstrip("%").rsplit(".", 1)[0]
+                   for line in calls)
+    assert names == [f"{moe_rows.NAME}_combine"] * 2 + [
+        f"{moe_rows.NAME}_pack"] * 4 + [f"{moe_rows.NAME}_take"] * 2, names
+    for line in calls:
+        op_name = line.split('op_name="')[1].split('"')[0]
+        assert in_scope(op_name, "moe_route") and not in_scope(op_name, "moe_experts")
+    assert " while(" not in text and " conditional(" not in text
+    height = (tokens * top // 256 + held) * 256
+    gathers = [line for line in text.splitlines() if " gather(" in line]
+    assert not [g for g in gathers if f"bf16[{height},{d}]" in g.split(" gather(")[0]
+                or f"bf16[{tokens * top},{d}]" in g.split(" gather(")[0]], gathers
 
 
 def test_ouro_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
@@ -555,6 +614,7 @@ def test_glm_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
         assert all(in_scope(p, "moe_experts") and not in_scope(p, "moe_shared")
                    for p in calls[name])
     assert any(in_scope(p, "mtp") for p in calls["dtpu_moe_gmm_fwd"])
+    _movers_of_held_mixtures(calls, in_scope, mixtures=2)
     assert len(calls["dtpu_opt_update_adamw"]) == len(jax.tree.leaves(state.params))
     # it fits the chip with room for the cell's two more mixture layers
     m = compiled.memory_analysis()

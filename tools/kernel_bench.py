@@ -32,6 +32,7 @@ for honesty, never used for the roofline verdict.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import statistics
@@ -262,6 +263,123 @@ def bench_decode_attn(rounds: int, iters: int, peaks) -> dict:
 # ------------------------------------------------- in-context step ledgers
 
 
+
+MOE_ROWS_SHAPES = {  # T tokens a step; k = 4 of 64 experts, 8 held, d 2048
+    "glm_4_7_flash.train_seq8192": 8192,
+    "lfm2_24b_a2b.train_seq8192": 16384,
+}
+MOE_ROWS_SHARES = (0.125, 0.25, 1.0)
+
+
+def bench_moe_rows(rounds: int, iters: int, shapes=None,
+                   shares=MOE_ROWS_SHARES, d: int = 2048) -> dict:
+    """The held mixtures' row movements ALONE, XLA's gathers against the
+    row movers (``ops/pallas/moe_rows.py``), at the two cells' shapes and
+    three shares of the (token, slot) rows on held experts: ``take`` (token
+    -> sorted buffer), ``combine`` (buffer -> token, weighted, summed), and
+    both with their backward (the four movements of a mixture's step, no
+    expert between them), in ms and in ns a (token, slot) row of the
+    buffer's bound; beside them the movers' parts. Off the TPU: a toy
+    shape through the interpreter, which rehearses the path and times
+    nothing worth keeping."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distribuuuu_tpu.ops import moe as moe_ops
+    from distribuuuu_tpu.ops.pallas import moe_gmm, moe_rows
+
+    on_chip = jax.default_backend() == "tpu"
+    interpret = not on_chip
+    if shapes is None:
+        shapes = MOE_ROWS_SHAPES if on_chip else {"toy": 512}
+    if on_chip:  # a call is under a millisecond: amortise its dispatch
+        iters = max(iters, 20)
+    k, held, total, tm = 4, 8, 64, moe_gmm.ROW_TILE if on_chip else 128
+    dtype = jnp.bfloat16
+    table = {}
+    for cell, T in shapes.items():
+        for share in shares:
+            rng = np.random.default_rng(T + int(share * 1000))
+            present = rng.random((T, k)) < share
+            indices = jnp.asarray(np.where(
+                present, rng.integers(0, held, (T, k)),
+                rng.integers(held, total, (T, k))), jnp.int32)
+            w = jnp.asarray(rng.random((T, k)), jnp.float32)
+            lay = jax.jit(lambda i, w: moe_ops._sorted_layout(
+                i, held, 0, total, tm, w))(indices, w)
+            height = lay.src.shape[0]
+            x = jnp.asarray(rng.standard_normal((T, d)), dtype)
+            y = jnp.asarray(rng.standard_normal((height, d)), dtype)
+            g = jnp.asarray(rng.standard_normal((T, d)), dtype)
+            live = int(lay.n_live[0]) * tm
+
+            def arms(fn):  # XLA's gathers, and the movers with their tables
+                return {name: jax.jit(lambda *a, lay, mover=mover: fn(
+                    *a, lay, moe_ops._mover_tables(lay, k) if mover else None))
+                    for name, mover in (("xla", False), ("movers", True))}
+
+            def both(x, w, lay, tables):  # the four movements of a step
+                out, vjp = jax.vjp(lambda x, w: moe_ops._rows_out(
+                    moe_ops._rows_in(x, lay, k, tables, interpret), w, lay,
+                    tables, interpret), x, w)
+                return (out, *vjp(g))
+
+            timed = {
+                "take": (arms(lambda x, lay, tables: moe_ops._rows_in(
+                    x, lay, k, tables, interpret)), (x,)),
+                "combine": (arms(lambda y, w, lay, tables: moe_ops._rows_out(
+                    y, w, lay, tables, interpret)), (y, w)),
+                "take_combine_and_back": (arms(both), (x, w)),
+            }
+            row = {"tokens": T, "rows_bound": T * k, "buffer_rows": height,
+                   "live_rows": live, "share": share}
+            for name, (fns, args) in timed.items():
+                fns = {arm: functools.partial(fn, lay=lay) for arm, fn in fns.items()}
+                outs = {arm: fn(*args) for arm, fn in fns.items()}
+                worst = 0.0
+                for a, b in zip(jax.tree.leaves(outs["xla"]),
+                                jax.tree.leaves(outs["movers"])):
+                    a, b = (np.asarray(t, np.float32) for t in (a, b))
+                    if a.shape[0] == height:  # dead tiles: never written
+                        a, b = a[:live], b[:live]
+                    worst = max(worst, float(np.abs(a - b).max()
+                                             / max(np.abs(a).max(), 1e-9)))
+                row[name] = {"max_rel_diff": worst}
+                for arm, fn in fns.items():
+                    ms = _med_ms(fn, args, rounds, iters)
+                    row[name][f"{arm}_ms"] = ms
+                    row[name][f"{arm}_ns_a_row"] = round(ms * 1e6 / (T * k), 2)
+            n_live = lay.n_live
+            src2, _, bounds, _, _ = moe_ops._mover_tables(lay, k)
+            xw = moe_rows.pack(x, tm=moe_rows.TOKEN_TILE, interpret=interpret)
+            yw = moe_rows.pack(y, n_live, tm=tm, interpret=interpret)
+            parts = {
+                "pack_tokens": (lambda x: moe_rows.pack(
+                    x, tm=moe_rows.TOKEN_TILE, interpret=interpret), (x,)),
+                "pack_live_tiles": (lambda y, n: moe_rows.pack(
+                    y, n, tm=tm, interpret=interpret), (y, n_live)),
+                "take_packed": (lambda xw, s, n: moe_rows._take(
+                    xw, s // k, n, tokens=T, d=d, dtype=dtype,
+                    interpret=interpret), (xw, src2, n_live)),
+                "combine_packed": (lambda yw, src, w, bounds: moe_rows._combine(
+                    yw, src, w, bounds, experts=held, d=d, dtype=dtype,
+                    interpret=interpret), (yw, src2, w, bounds)),
+                "gather_of_floats": (lambda w, s: w.reshape(-1)[
+                    jnp.minimum(s, T * k - 1)], (w, lay.src)),
+            }
+            row["parts_ms"] = {name: _med_ms(jax.jit(fn), args, rounds, iters)
+                               for name, (fn, args) in parts.items()}
+            table[f"{cell}@{share}"] = row
+            print(f"moe_rows {cell} share {share}: " + "  ".join(
+                f"{name} {row[name]['xla_ms']} -> {row[name]['movers_ms']} ms "
+                f"({row[name]['xla_ns_a_row']} -> {row[name]['movers_ns_a_row']} "
+                f"ns a row, diff {row[name]['max_rel_diff']:.1e})"
+                for name in timed) + f"  parts {row['parts_ms']}", flush=True)
+    return {"dtype": "bfloat16", "d": d, "top_k": k, "experts_held": held,
+            "experts_total": total, "row_tile": tm, "on_chip": on_chip,
+            "cells": table}
+
 def _ledger_swap(step_bytes_xla, region_bytes_xla, region_bytes_kernel,
                  flops, peaks) -> dict:
     """The transparent swap arithmetic: whole-step bytes with the
@@ -423,6 +541,11 @@ def main(argv=None) -> int:
     ap.add_argument("--iters", type=int, default=3)
     ap.add_argument("--opt-params", type=int, default=2_000_000,
                     help="synthetic param count for the opt-update micro A/B")
+    ap.add_argument("--only", choices=["moe_rows"], default=None,
+                    help="run one entry alone and write it to --out as it "
+                         "is (moe_rows: the held mixtures' row movers "
+                         "against XLA's gathers; the chip's numbers are in "
+                         "PERF.md section 6, PR 42)")
     ap.add_argument("--quick", action="store_true",
                     help="skip the in-context step ledgers (traces of the "
                          "full efficientnet/gpt programs)")
@@ -436,6 +559,15 @@ def main(argv=None) -> int:
     from distribuuuu_tpu.asyncplane import compile_cache
 
     compile_cache.setup_from_cfg(cfg)  # on the chip: warm across processes
+    if args.only == "moe_rows":
+        doc = {"bench": BENCH_SCHEMA, "generated_by": "tools/kernel_bench.py",
+               "backend": jax.default_backend(),
+               "moe_rows": bench_moe_rows(args.rounds, args.iters)}
+        os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+        with open(args.out, "w") as f:
+            json.dump(doc, f, indent=1)
+        print(f"moe_rows -> {args.out}")
+        return 0
     peaks = costmodel.peaks_for()
     doc = {
         "bench": BENCH_SCHEMA,
