@@ -43,6 +43,7 @@ from distribuuuu_tpu.models.olmoe import olmoe_1b_7b, olmoe_tiny  # noqa: F401
 from distribuuuu_tpu.models.ouro import ouro_2_6b, ouro_tiny  # noqa: F401
 from distribuuuu_tpu.models.glm_moe import glm_4_7_flash, glm_moe_tiny  # noqa: F401
 from distribuuuu_tpu.models.lfm2_moe import lfm2_24b_a2b, lfm2_moe_tiny  # noqa: F401
+from distribuuuu_tpu.models.afmoe import afmoe_tiny, trinity_mini  # noqa: F401
 from distribuuuu_tpu.models.traits import ArchTraits
 
 _REGISTRY = {}
@@ -100,6 +101,12 @@ for _fn in (
     # expert, a tied head; one chip's share of an expert-parallel group
     lfm2_24b_a2b,
     lfm2_moe_tiny,
+    # Trinity-Mini (models/afmoe.py): window and full attention layers in
+    # one stack by the published pattern, a gated attention output, four
+    # norms a block, GLM's mixture with its shared expert, an untied head;
+    # one chip's share of an expert-parallel group
+    trinity_mini,
+    afmoe_tiny,
 ):
     register_model(_fn)
 

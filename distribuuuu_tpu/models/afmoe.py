@@ -1,0 +1,195 @@
+"""Trinity-Mini (``arcee-ai/Trinity-Mini``; HF ``model_type`` ``afmoe``,
+26B-A3B): a decoder whose every mixer is attention, of TWO kinds layer by
+layer from the published ``layer_types``, as ONE chip of an expert-parallel
+group holds it.
+
+    x0 = E[token] * sqrt(dim)                        (``mup_enabled``)
+    h = x + N2(attn_i(N1(x)))        y = h + N4(F_i(N3(h)))
+
+* ``attn_i``: ``num_attention_heads`` query heads on ``num_key_value_heads``
+  key/value heads of ``head_dim`` (query head ``h`` reading key/value head
+  ``h // group``, a group of 8), RMSNorm over each head's dims on q and on k,
+  and a gate on the output: ``out = (concat(heads) * sigmoid(x W_g)) W_o``.
+  Where ``layer_types[i]`` is ``sliding_attention``: rotary on q and k
+  (rotate-half over the whole head) and query t reads the keys ``t -
+  sliding_window < s <= t`` (the window in ``ops/flash_attention.py``'s
+  kernels, which skip the tiles behind it). Where it is ``full_attention``:
+  NO rotary, no position signal at all, every key ``s <= t``. Both are
+  ``models/lfm2_moe.Attention``, under the device scope ``attn``; a sliding
+  layer's also under ``attn_window``, the gate under ``attn_gate``.
+* ``F_i`` is a dense gated-SiLU MLP of ``intermediate_size`` in the first
+  ``num_dense_layers`` blocks and ``models/glm_moe.Mixture`` in every later
+  one: sigmoid scores in float32, the ``top_k`` experts by ``s + b``, weights
+  ``route_scale * s_i / (sum of the chosen s + 1e-20)``, ``b`` state a rule
+  moves, this chip's share of the routed experts, one shared expert on every
+  token.
+* four RMSNorms a block, one before and one after each of its two parts
+  (``models/share.Block`` with ``post_norms``); a final RMSNorm; an untied
+  head.
+* the stage (``first_layer``, ``depth`` of the published list), the chip's
+  share, the block and the recomputation policy are ``models/share.py``'s
+  (:class:`PatternStack`), as LFM2's; the float32 residual stream and
+  parameters ``models/glm_moe.py``'s.
+
+``config.json`` names the window, the pattern, the router's keys, the shared
+expert and ``mup_enabled``; the output gate, the per-head q/k norms, the four
+norms a block and that full layers carry no rotary are the published
+``afmoe`` model class's (``benchmark/configs/trinity_mini.json``,
+``assumed``).
+
+``hidden_only=True`` returns ``(states [B, S, d], the mixtures'
+statistics)`` and the step's loss is :meth:`ShareOfALayer.head_loss`; a plain
+call returns the ``[B, S, V/n]`` logits.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Any
+
+import flax.linen as nn
+import jax.numpy as jnp
+
+from distribuuuu_tpu.models.glm_moe import Mixture
+from distribuuuu_tpu.models.layers import head_dtype
+from distribuuuu_tpu.models.lfm2_moe import Attention
+from distribuuuu_tpu.models.olmoe import RMSNorm, _normal
+from distribuuuu_tpu.models.share import (
+    PatternStack,
+    pattern_kwargs_from_cfg,
+    run_blocks,
+    say_plan,
+    stacked,
+)
+from distribuuuu_tpu.models.traits import ArchTraits
+
+# config.json's layer_types: three sliding layers, then a full one, to 32
+LAYER_TYPES_MINI = (("sliding_attention",) * 3 + ("full_attention",)) * 8
+# the published class's names, in the block's order
+NORMS = ("input_norm", "post_attn_norm", "pre_mlp_norm", "post_mlp_norm")
+
+
+class AfMoE(PatternStack):
+    """Defaults are ``config.json``'s of arcee-ai/Trinity-Mini."""
+
+    # both kinds are attention: one params' name and device scope
+    KINDS = {"sliding_attention": "attn", "full_attention": "attn"}
+
+    vocab_size: int = 200192  # published; this chip holds vocab_size / share_chips rows
+    seq_len: int = 8192  # the training context here; config.json allows 131,072 positions
+    dim: int = 2048
+    layer_types: tuple = LAYER_TYPES_MINI  # the published list, whole
+    first_layer: int = 0  # the published layer this chip's stage starts at
+    depth: int = 0  # layers from first_layer on; 0: the rest of the list
+    dense_layers: int = 2  # num_dense_layers, counted from published layer 0
+    num_heads: int = 32
+    kv_heads: int = 4  # num_key_value_heads
+    head_dim: int = 128
+    sliding_window: int = 2048
+    mlp_hidden: int = 6144  # intermediate_size, the dense layers'
+    expert_hidden: int = 1024  # moe_intermediate_size
+    num_experts: int = 128
+    top_k: int = 8  # num_experts_per_tok
+    shared_experts: int = 1  # num_shared_experts
+    routed_scale: float = 2.826  # route_scale, with route_norm
+    mup: bool = True  # mup_enabled: the embedding times sqrt(dim)
+    norm_eps: float = 1e-5  # rms_norm_eps
+    rope_theta: float = 1e4
+    bias_rate: float = 0.001  # load_balance_coeff, read as the bias rule's rate
+    aux_weight: float = 1e-4  # MODEL.MOE.AUX_WEIGHT
+    share_chips: int = 1  # LM.SHARE_CHIPS: chips that share each layer
+    share_rank: int = 0  # LM.SHARE_RANK: which of them this is
+    dtype: Any = jnp.bfloat16
+    attn_impl: str = "auto"
+    mesh: Any = None
+    recompute: bool = True  # LM.RECOMPUTE
+    head_chunk: int = 512
+
+    @property
+    def attn_head_dim(self) -> int:
+        return self.head_dim
+
+    @nn.compact
+    def __call__(self, tokens, train: bool = False, hidden_only: bool = False):
+        B, S = tokens.shape
+        self._check_input(tokens)
+        say_plan(self, B, S)
+        embed = self._embedding()
+        tokens = tokens - self.share_rank * self.vocab_held
+        positions = jnp.arange(S, dtype=jnp.int32)
+        attention = functools.partial(
+            Attention, self.dim, self.num_heads, self.kv_heads, self.norm_eps,
+            self.rope_theta, self.dtype, self.attn_impl, self.mesh,
+            head_dim=self.head_dim, gated=True,
+        )
+        mixers = {
+            "sliding_attention": functools.partial(
+                attention, window=self.sliding_window),
+            "full_attention": functools.partial(attention, rotary=False),
+        }
+        mixture = functools.partial(
+            Mixture, self.dim, self.expert_hidden, self.num_experts, self.top_k,
+            self.shared_experts, self.routed_scale, self.bias_rate, self.held,
+            self.dtype, train, self.mesh,
+        )
+        x = embed(tokens)
+        if self.mup:
+            x = x * self.dim ** 0.5
+        x, stats = run_blocks(
+            self, x, positions, mixers, mixture,
+            norms=NORMS[0::2], post_norms=NORMS[1::2])
+        x = RMSNorm(self.norm_eps, name="final_norm")(x).astype(self.dtype)
+        kernel = self.param(
+            "head", _normal(), (self.dim, self.vocab_held), jnp.float32)
+        if hidden_only:
+            return x, stacked(stats)
+        return jnp.einsum(
+            "bsd,dv->bsv", x, kernel.astype(self.dtype),
+            preferred_element_type=head_dtype(self.dtype),
+        )
+
+    @staticmethod
+    def head_kernel(params):
+        return params["head"]
+
+
+def trinity_mini(num_classes=200192, **kw):
+    """Trinity-Mini at its published sizes (32 layers over 128 experts;
+    ``first_layer``, ``depth`` and the chips that share a layer are what one
+    chip turns)."""
+    return AfMoE(vocab_size=num_classes, **kw)
+
+
+def afmoe_tiny(num_classes=512, **kw):
+    """The same blocks at a size the CPU tests run: 64 wide, 4 query heads on
+    1 key/value head of 32 (so 4 x 32 = 128 is not the width, as published),
+    a window of 24, a dense MLP of 160 in the first 2 layers, then 8 experts
+    of 32 with 2 a token and a shared one, 6 layers by the pattern sliding,
+    sliding, sliding, full; two chips share a layer (4 experts and 256
+    vocabulary rows held); the head in chunks of 48, which do not divide its
+    128 positions."""
+    for key, value in dict(
+        seq_len=128, dim=64,
+        layer_types=("sliding_attention",) * 3 + ("full_attention",)
+        + ("sliding_attention",) * 2,
+        depth=6, num_heads=4, kv_heads=1, head_dim=32, sliding_window=24,
+        mlp_hidden=160, expert_hidden=32, num_experts=8, top_k=2, head_chunk=48,
+        share_chips=2,
+    ).items():
+        kw.setdefault(key, value)
+    return AfMoE(vocab_size=num_classes, **kw)
+
+
+trinity_mini.traits = afmoe_tiny.traits = ArchTraits(
+    token_batch=True, batch_norm=False,
+    # attention and the sorted experts per device, as models/glm_moe.py; the
+    # exchange of tokens across the chips that share a layer is ROADMAP R2
+    mesh_axes=("data",),
+    kwargs_from_cfg=pattern_kwargs_from_cfg,
+    serve_refusal=(
+        "trains only: serving a stack of window and full attention layers "
+        "takes a cache typed by layer (a ring of sliding_window keys and "
+        "values in the window layers beside the whole context's in the full "
+        "ones), which lm/generate.py lacks"
+    ),
+)

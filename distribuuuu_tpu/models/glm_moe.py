@@ -67,14 +67,13 @@ import jax
 import jax.numpy as jnp
 
 from distribuuuu_tpu.models.layers import head_dtype
-from distribuuuu_tpu.models.olmoe import (
-    RMSNorm,
-    _attend,
-    _normal,
-    decoder_kwargs_from_cfg,
-    rotary,
-)
+from distribuuuu_tpu.models.olmoe import RMSNorm, _attend, _normal, rotary
 from distribuuuu_tpu.models.ouro import MLP, kept_plan
+from distribuuuu_tpu.models.share import (
+    ShareOfALayer,
+    mixture_metrics,
+    share_kwargs_from_cfg,
+)
 from distribuuuu_tpu.models.traits import ArchTraits
 from distribuuuu_tpu.models.vit import Attention as VitAttention
 from distribuuuu_tpu.ops import token_head
@@ -267,7 +266,7 @@ def _say_plan(model, batch: int, seq: int) -> None:
     )
 
 
-class GLMMoE(nn.Module):
+class GLMMoE(ShareOfALayer):
     """Defaults are ``config.json``'s of zai-org/GLM-4.7-Flash."""
 
     vocab_size: int = 154880  # published; this chip holds vocab_size / share_chips rows
@@ -305,42 +304,17 @@ class GLMMoE(nn.Module):
     # batch's sequences twice over (the trunk's and the MTP module's)
     head_chunk: int = 512
 
-    @property
-    def held(self) -> tuple:
-        """(first, count) of the routed experts this chip holds."""
-        count = self.num_experts // self.share_chips
-        return self.share_rank * count, count
-
-    @property
-    def vocab_held(self) -> int:
-        return self.vocab_size // self.share_chips
-
     def _check_share(self) -> None:
-        n, r = self.share_chips, self.share_rank
-        if n < 1 or self.num_experts % n or self.vocab_size % n or not 0 <= r < n:
-            raise ValueError(
-                f"LM.SHARE_CHIPS={n}, LM.SHARE_RANK={r}: the chips that share "
-                f"a layer must divide its {self.num_experts} routed experts "
-                f"and the {self.vocab_size} vocabulary rows, and the rank "
-                "lie under them"
-            )
+        super()._check_share()
         if self.mtp_layers not in (0, 1):
             raise ValueError(f"mtp_layers={self.mtp_layers}: 0 or 1")
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, hidden_only: bool = False):
         B, S = tokens.shape
-        if S > self.seq_len:
-            raise ValueError(
-                f"input length {S} exceeds the context LM.SEQ_LEN={self.seq_len}"
-            )
-        self._check_share()
+        self._check_input(tokens)
         _say_plan(self, B, S)
-        embed = nn.Embed(
-            self.vocab_held, self.dim, name="tok_embed",
-            dtype=head_dtype(self.dtype), param_dtype=jnp.float32,
-            embedding_init=_normal(),
-        )
+        embed = self._embedding()
         tokens = tokens - self.share_rank * self.vocab_held
         positions = jnp.arange(S, dtype=jnp.int32)
         attention = functools.partial(
@@ -406,11 +380,6 @@ class GLMMoE(nn.Module):
         """What evaluation's head reads: the trunk's state."""
         return outputs[0][:, 0]
 
-    def head_labels(self, labels):
-        """The head's column for a token id: the head holds the rank's rows
-        of the vocabulary."""
-        return labels - self.share_rank * self.vocab_held
-
     def head_loss(self, outputs, kernel, labels, *, topk):
         """``(loss, hits, step metrics)``: next-token cross-entropy, the MTP
         module's on the token after it, the mixtures' balancing term.
@@ -442,30 +411,10 @@ class GLMMoE(nn.Module):
             extra = {"ce": nll[:, 0].mean()}
             if R > 1:
                 extra["ce_mtp"] = (nll[:, 1] * mtp_mean).sum()
-        extra.update({
-            "moe_aux": stats["aux"].mean(),
-            "moe_dropped": jnp.float32(0.0),  # no capacity: nothing can drop
-            "moe_load_max_over_mean": stats["load_max_over_mean"].max(),
-            "moe_held_row_share": stats["held_row_share"].mean(),
-            "router_bias_abs_max": stats["bias_abs_max"].max(),
-        })
+        extra.update(mixture_metrics(stats))
         first = rank.reshape(B, R, S)[:, 0]
         hits = [(first < k).mean(dtype=jnp.float32) * 100.0 for k in topk]
         return heads + self.aux_weight * extra["moe_aux"], hits, extra
-
-    def dummy_input(self):
-        return jnp.full(
-            (2, min(8, self.seq_len)), self.share_rank * self.vocab_held, jnp.int32)
-
-    def param_spec_table(self):
-        from distribuuuu_tpu.parallel.partition import specs
-
-        return specs.lm_spec_table()
-
-    def batch_spec_table(self):
-        from distribuuuu_tpu.parallel.partition import specs
-
-        return specs.TOKEN_BATCH_TABLE
 
 
 def glm_4_7_flash(num_classes=154880, **kw):
@@ -492,23 +441,12 @@ def glm_moe_tiny(num_classes=512, **kw):
     return GLMMoE(vocab_size=num_classes, **kw)
 
 
-def _kwargs_from_cfg(cfg, topology) -> dict:
-    """``models/olmoe.py``'s (context, depth, attention entry, mesh), the
-    share and the balancing term's weight; every width is the arch's own."""
-    kwargs = {**decoder_kwargs_from_cfg(cfg, topology),
-              "aux_weight": float(cfg.MODEL.MOE.AUX_WEIGHT)}
-    if int(cfg.LM.SHARE_CHIPS) > 0:  # 0 keeps the arch's own
-        kwargs.update(share_chips=int(cfg.LM.SHARE_CHIPS),
-                      share_rank=int(cfg.LM.SHARE_RANK))
-    return kwargs
-
-
 glm_4_7_flash.traits = glm_moe_tiny.traits = ArchTraits(
     token_batch=True, batch_norm=False,
     # attention and the sorted experts per device, as models/olmoe.py; the
     # exchange of tokens across the chips that share a layer is ROADMAP R2
     mesh_axes=("data",),
-    kwargs_from_cfg=_kwargs_from_cfg,
+    kwargs_from_cfg=share_kwargs_from_cfg,
     serve_refusal=(
         "trains only: serving latent attention takes a cache of the latents "
         "and the absorbed decode formulation (ROADMAP R3), which "

@@ -29,19 +29,22 @@ a decoder whose token mixer is chosen LAYER BY LAYER from the published
 * final RMSNorm; the head is the embedding (tied): ``head_kernel`` hands the
   step ``E^T``, so the embedding's gradient has two sources, the lookup and
   the head, which autodiff adds.
-* the chip's share (``share_chips``, ``share_rank``), the float32 residual
-  stream and parameters, and the recomputation policy (every block of either
-  kind keeps its float32 input and what the flash backward kernel reads,
-  ``KEPT_UNDER_REMAT``) are ``models/glm_moe.py``'s; ``recompute = False``
-  keeps every activation instead.
+* the chip's share (``share_chips``, ``share_rank``), the stage, the block
+  around a mixer and the recomputation policy (every block of either kind
+  keeps its float32 input and what the flash backward kernel reads,
+  ``KEPT_UNDER_REMAT``) are ``models/share.py``'s (:class:`PatternStack`,
+  ``Block``, ``run_blocks``), the float32 residual stream and parameters
+  ``models/glm_moe.py``'s; ``recompute = False`` keeps every activation
+  instead.
 
 ``hidden_only=True`` returns ``(states [B, S, d], the mixtures'
-statistics)`` and the step's loss is :meth:`LFM2MoE.head_loss`; a plain call
+statistics)`` and the step's loss is ``ShareOfALayer.head_loss``; a plain call
 returns the ``[B, S, V/n]`` logits.
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any
 
@@ -49,20 +52,22 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from distribuuuu_tpu.models.glm_moe import Mixture, _dense, _kwargs_from_cfg
+from distribuuuu_tpu.models.glm_moe import Mixture, _dense
 from distribuuuu_tpu.models.layers import head_dtype
 from distribuuuu_tpu.models.olmoe import RMSNorm, _attend, _normal, rotary
-from distribuuuu_tpu.models.ouro import MLP, kept_plan
+from distribuuuu_tpu.models.share import (
+    PatternStack,
+    pattern_kwargs_from_cfg,
+    run_blocks,
+    say_plan,
+    stacked,
+)
 from distribuuuu_tpu.models.traits import ArchTraits
 from distribuuuu_tpu.models.vit import Attention as VitAttention
-from distribuuuu_tpu.ops import token_head
 from distribuuuu_tpu.ops.short_conv import gated_short_conv
 
 # config.json's layer_types: conv, conv, attention, conv, and so on to 40
 LAYER_TYPES_24B = ("conv", "conv", "full_attention", "conv") * 10
-MIXER_SCOPE = {"conv": "short_conv", "full_attention": "attn"}
-
-
 class ShortConv(nn.Module):
     """The gated short-convolution mixer; ``filter`` is ``[H, L]``."""
 
@@ -79,7 +84,12 @@ class ShortConv(nn.Module):
 
 
 class Attention(nn.Module):
-    """Grouped-query attention with a per-head RMSNorm on q and on k."""
+    """Grouped-query attention with a per-head RMSNorm on q and on k. The
+    defaults of the last four are LFM2's; ``models/afmoe.py`` gives them:
+    heads of ``head_dim`` (0: ``dim / num_heads``), a sliding ``window``
+    (under the scope ``attn_window``), no ``rotary`` where a layer carries no
+    position signal, and ``gated``: ``out = (heads * sigmoid(x W_g)) W_o``
+    (under ``attn_gate``)."""
 
     dim: int
     num_heads: int
@@ -89,11 +99,16 @@ class Attention(nn.Module):
     dtype: Any
     attn_impl: str = "auto"
     mesh: Any = None
+    head_dim: int = 0
+    window: Any = None  # int: query t reads keys t - window < s <= t
+    rotary: bool = True
+    gated: bool = False
 
     @nn.compact
     def __call__(self, x, positions):
         B, S, _ = x.shape
-        H, G, D = self.num_heads, self.kv_heads, self.dim // self.num_heads
+        H, G = self.num_heads, self.kv_heads
+        D = self.head_dim or self.dim // H
         x = x.astype(self.dtype)
 
         def heads(name, n):  # x W -> [B, n, S, D]
@@ -102,74 +117,33 @@ class Attention(nn.Module):
 
         def normed(name, n):  # one scale of D for every head
             t = RMSNorm(self.eps, name=f"{name}_norm")(heads(name, n))
-            return rotary(t, positions, self.rope_theta).astype(self.dtype)
+            if self.rotary:
+                t = rotary(t, positions, self.rope_theta)
+            return t.astype(self.dtype)
 
-        q, k, v = normed("q", H), normed("k", G), heads("v", G)
-        impl = VitAttention.resolve_impl(self.attn_impl, S, 0.0)
-        if impl != "flash":  # the dense path takes q, k and v of one shape
-            k, v = (jnp.repeat(t, H // G, axis=1) for t in (k, v))
-        out = _attend(q, k, v, impl, self.dtype, self.mesh)
-        out = out.astype(self.dtype).transpose(0, 2, 1, 3).reshape(B, S, H * D)
-        return _dense(self.dim, self.dtype, "o_proj")(out)
-
-
-class Block(nn.Module):
-    """One pre-norm block: ``mixer`` builds its token mixer (the params'
-    name and the device scope follow ``kind``), ``mixture`` its
-    ``models/glm_moe.Mixture`` or is None for the dense MLP."""
-
-    mixer: Any  # () -> ShortConv or Attention
-    kind: str  # layer_types' word for it
-    mixture: Any  # () -> Mixture, or None
-    mlp_hidden: int
-    dim: int
-    eps: float
-    dtype: Any
-
-    @nn.compact
-    def __call__(self, x, positions):
-        scope = MIXER_SCOPE[self.kind]
-        with jax.named_scope(scope):
-            x = x + self.mixer(name=scope)(
-                RMSNorm(self.eps, name="operator_norm")(x), positions)
-        if self.mixture is None:
-            with jax.named_scope("mlp"):
-                x = x + MLP(self.dim, self.mlp_hidden, self.dtype, name="mlp")(
-                    RMSNorm(self.eps, name="ffn_norm")(x))
-            return x, {}
-        with jax.named_scope("moe"):
-            out, stats = self.mixture(name="moe")(RMSNorm(self.eps, name="ffn_norm")(x))
-        return x + out, stats
+        windowed = (jax.named_scope("attn_window") if self.window is not None
+                    else contextlib.nullcontext())
+        with windowed:
+            q, k, v = normed("q", H), normed("k", G), heads("v", G)
+            impl = VitAttention.resolve_impl(self.attn_impl, S, 0.0)
+            if impl != "flash":  # the dense path takes q, k and v of one shape
+                k, v = (jnp.repeat(t, H // G, axis=1) for t in (k, v))
+            out = _attend(q, k, v, impl, self.dtype, self.mesh, self.window)
+            out = out.astype(self.dtype).transpose(0, 2, 1, 3).reshape(B, S, H * D)
+            if self.gated:
+                with jax.named_scope("attn_gate"):
+                    gate = _dense(H * D, self.dtype, "gate_proj")(x)
+                    # float32 sigmoid and product, rounded once
+                    out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                        gate.astype(jnp.float32))).astype(self.dtype)
+            return _dense(self.dim, self.dtype, "o_proj")(out)
 
 
-_planned: set = set()
-
-
-def _say_plan(model, batch: int, seq: int) -> None:
-    """One ``share.plan`` record a shape, at trace time, as
-    ``models/glm_moe.py``'s, with the layer kinds the model built."""
-    kinds = model.layer_kinds
-    key = (model.share_chips, model.share_rank, kinds, model.dense_layers,
-           batch, seq, model.recompute)
-    if key in _planned:
-        return
-    _planned.add(key)
-    from distribuuuu_tpu.telemetry import spans
-
-    spans.emit_event(
-        "share.plan", share_chips=model.share_chips, share_rank=model.share_rank,
-        experts_held=model.held[1], experts_total=model.num_experts,
-        vocab_held=model.vocab_held, vocab_total=model.vocab_size,
-        layer_kinds=list(kinds), dense_layers=model.dense_here,
-        **kept_plan(
-            model, len(kinds), batch, seq, model.dim // model.num_heads,
-            "every block of either kind",
-            flash_blocks=kinds.count("full_attention")),
-    )
-
-
-class LFM2MoE(nn.Module):
+class LFM2MoE(PatternStack):
     """Defaults are ``config.json``'s of LiquidAI/LFM2-24B-A2B."""
+
+    # layer_types' word for a mixer -> its params' name and device scope
+    KINDS = {"conv": "short_conv", "full_attention": "attn"}
 
     vocab_size: int = 65536  # published; this chip holds vocab_size / share_chips rows
     seq_len: int = 8192  # the training context here; config.json allows 128,000 positions
@@ -199,64 +173,12 @@ class LFM2MoE(nn.Module):
     recompute: bool = True  # LM.RECOMPUTE
     head_chunk: int = 512
 
-    @property
-    def layer_kinds(self) -> tuple:
-        """``layer_types`` of the layers this model builds, in order."""
-        last = self.first_layer + self.depth if self.depth else len(self.layer_types)
-        kinds = tuple(self.layer_types[self.first_layer:last])
-        if len(kinds) != last - self.first_layer or not set(kinds) <= set(MIXER_SCOPE):
-            raise ValueError(
-                f"layers {self.first_layer}..{last - 1} of {len(self.layer_types)} "
-                f"layer_types {sorted(set(self.layer_types))}: the stage must lie "
-                f"inside the list, whose words are {sorted(MIXER_SCOPE)}"
-            )
-        return kinds
-
-    @property
-    def dense_here(self) -> int:
-        """How many of the built layers carry the dense MLP: the published
-        leading ones that fall into this stage."""
-        return max(0, min(self.dense_layers - self.first_layer, len(self.layer_kinds)))
-
-    @property
-    def held(self) -> tuple:
-        """(first, count) of the routed experts this chip holds."""
-        count = self.num_experts // self.share_chips
-        return self.share_rank * count, count
-
-    @property
-    def vocab_held(self) -> int:
-        return self.vocab_size // self.share_chips
-
-    def _check_share(self) -> None:
-        n, r = self.share_chips, self.share_rank
-        if n < 1 or self.num_experts % n or self.vocab_size % n or not 0 <= r < n:
-            raise ValueError(
-                f"LM.SHARE_CHIPS={n}, LM.SHARE_RANK={r}: the chips that share "
-                f"a layer must divide its {self.num_experts} routed experts "
-                f"and the {self.vocab_size} vocabulary rows, and the rank "
-                "lie under them"
-            )
-        if self.num_heads % self.kv_heads or self.dim % self.num_heads:
-            raise ValueError(
-                f"{self.num_heads} query heads on {self.kv_heads} key/value "
-                f"heads at width {self.dim}: each must divide the one before"
-            )
-
     @nn.compact
     def __call__(self, tokens, train: bool = False, hidden_only: bool = False):
         B, S = tokens.shape
-        if S > self.seq_len:
-            raise ValueError(
-                f"input length {S} exceeds the context LM.SEQ_LEN={self.seq_len}"
-            )
-        self._check_share()
-        _say_plan(self, B, S)
-        embed = nn.Embed(
-            self.vocab_held, self.dim, name="tok_embed",
-            dtype=head_dtype(self.dtype), param_dtype=jnp.float32,
-            embedding_init=_normal(),
-        )
+        self._check_input(tokens)
+        say_plan(self, B, S)
+        embed = self._embedding()
         tokens = tokens - self.share_rank * self.vocab_held
         positions = jnp.arange(S, dtype=jnp.int32)
         mixers = {
@@ -270,73 +192,21 @@ class LFM2MoE(nn.Module):
             0, self.routed_scale, self.bias_rate, self.held, self.dtype, train,
             self.mesh, self.route_norm_eps,
         )
-        from distribuuuu_tpu.ops.flash_attention import KEPT_UNDER_REMAT
-
-        block = nn.remat(
-            Block, policy=jax.checkpoint_policies.save_only_these_names(
-                *KEPT_UNDER_REMAT)) if self.recompute else Block
-        x, stats = embed(tokens), []
-        for i, kind in enumerate(self.layer_kinds):
-            x, s = block(
-                mixers[kind], kind, None if i < self.dense_here else mixture,
-                self.mlp_hidden, self.dim, self.norm_eps, self.dtype,
-                name=f"Block_{i}",
-            )(x, positions)
-            stats.append(s)
+        x, stats = run_blocks(
+            self, embed(tokens), positions, mixers, mixture,
+            norms=("operator_norm", "ffn_norm"))
         x = RMSNorm(self.norm_eps, name="final_norm")(x).astype(self.dtype)
         if hidden_only:
-            mixtures = [s for s in stats if s]
-            return x, {k: jnp.stack([s[k] for s in mixtures]) for k in mixtures[0]}
+            return x, stacked(stats)
         return jnp.einsum(
             "bsd,vd->bsv", x, embed.embedding.astype(self.dtype),
             preferred_element_type=head_dtype(self.dtype),
         )
 
-    # ------------------------------------------------ partition-layer hooks
     @staticmethod
     def head_kernel(params):
         """The head IS the embedding: ``[d, V/n]`` of it."""
         return params["tok_embed"]["embedding"].T
-
-    @staticmethod
-    def eval_hidden(outputs):
-        return outputs[0]
-
-    def head_labels(self, labels):
-        return labels - self.share_rank * self.vocab_held
-
-    def head_loss(self, outputs, kernel, labels, *, topk):
-        """``(loss, hits, step metrics)``: next-token cross-entropy and the
-        mixtures' balancing term."""
-        states, stats = outputs
-        with jax.named_scope("lm_head"):
-            ce, hits = token_head.loss_and_accuracy(
-                states, kernel, self.head_labels(labels), topk=topk,
-                chunk=self.head_chunk,
-            )
-        extra = {
-            "ce": ce,
-            "moe_aux": stats["aux"].mean(),
-            "moe_dropped": jnp.float32(0.0),  # no capacity: nothing can drop
-            "moe_load_max_over_mean": stats["load_max_over_mean"].max(),
-            "moe_held_row_share": stats["held_row_share"].mean(),
-            "router_bias_abs_max": stats["bias_abs_max"].max(),
-        }
-        return ce + self.aux_weight * extra["moe_aux"], hits, extra
-
-    def dummy_input(self):
-        return jnp.full(
-            (2, min(8, self.seq_len)), self.share_rank * self.vocab_held, jnp.int32)
-
-    def param_spec_table(self):
-        from distribuuuu_tpu.parallel.partition import specs
-
-        return specs.lm_spec_table()
-
-    def batch_spec_table(self):
-        from distribuuuu_tpu.parallel.partition import specs
-
-        return specs.TOKEN_BATCH_TABLE
 
 
 def lfm2_24b_a2b(num_classes=65536, **kw):
@@ -362,21 +232,12 @@ def lfm2_moe_tiny(num_classes=512, **kw):
     return LFM2MoE(vocab_size=num_classes, **kw)
 
 
-def _lfm2_kwargs_from_cfg(cfg, topology) -> dict:
-    """``models/glm_moe.py``'s (context, depth, attention entry, mesh, the
-    share, the balancing term's weight), the stage's first layer and whether
-    a block is recomputed; every width is the arch's own."""
-    return {**_kwargs_from_cfg(cfg, topology),
-            "first_layer": int(cfg.LM.FIRST_LAYER),
-            "recompute": bool(cfg.LM.RECOMPUTE)}
-
-
 lfm2_24b_a2b.traits = lfm2_moe_tiny.traits = ArchTraits(
     token_batch=True, batch_norm=False,
     # mixers and the sorted experts per device, as models/glm_moe.py; the
     # exchange of tokens across the chips that share a layer is ROADMAP R2
     mesh_axes=("data",),
-    kwargs_from_cfg=_lfm2_kwargs_from_cfg,
+    kwargs_from_cfg=pattern_kwargs_from_cfg,
     serve_refusal=(
         "trains only: serving a stack of two layer kinds takes a cache typed "
         "by layer (a convolution's last conv_L_cache - 1 gated inputs beside "

@@ -78,21 +78,24 @@ def rotary(x, positions, theta: float):
     return x * cos + rotated * sin
 
 
-def _attend(q, k, v, impl: str, dtype, mesh=None):
+def _attend(q, k, v, impl: str, dtype, mesh=None, window: int | None = None):
     """Causal softmax attention on ``[B, H, S, D]``; on a ``mesh`` whose data
-    axis is populated the flash kernel runs per data rank."""
+    axis is populated the flash kernel runs per data rank. With a ``window``
+    query t reads keys ``t - window < s <= t`` (``models/afmoe.py``)."""
     if impl == "flash":
         from distribuuuu_tpu.ops import flash_attention as fa
 
-        return fa.flash_attention(q, k, v, causal=True, mesh=mesh)
+        return fa.flash_attention(
+            q, k, v, causal=True, mesh=mesh, window=window)
     S = q.shape[2]
     with jax.named_scope("attn_softmax_fp32"):
         scores = jnp.einsum(
             "bhqd,bhkd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32)
         ) * q.shape[-1] ** -0.5
-        scores = jnp.where(
-            jnp.tril(jnp.ones((S, S), bool))[None, None], scores, jnp.float32(-1e30)
-        )
+        keep = jnp.tril(jnp.ones((S, S), bool))
+        if window is not None:  # and not the keys a window or more behind
+            keep = keep & ~jnp.tril(jnp.ones((S, S), bool), -window)
+        scores = jnp.where(keep[None, None], scores, jnp.float32(-1e30))
         out = jnp.einsum(
             "bhqk,bhkd->bhqd", jax.nn.softmax(scores, axis=-1), v.astype(jnp.float32)
         )

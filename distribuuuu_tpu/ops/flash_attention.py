@@ -17,8 +17,14 @@ statistics and accumulators in float32. Two kernels:
   grid axis is ``arbitrary``) and is written once, at the last.
 
 Causal calls never visit a tile the mask empties (the walks' bounds follow
-the program id: ~half the tiles at large L); every tile they do visit runs
-the mask. Masking only the tiles the diagonal or the padding crosses was
+the program id: ~half the tiles at large L), and with a sliding ``window``
+(query t reads the keys ``t - window < s <= t``) neither walk visits a tile
+wholly behind it: the forward's walk over key tiles starts at the first tile
+the block's FIRST row still sees, the backward's walk over query tiles ends
+at the last tile that still sees the key block (70 of causal's 136 tiles at
+8192 tokens, 512² blocks and a window of 2048; two tiles a row of blocks
+are then crossed, the diagonal's and the window's edge). Every tile they do
+visit runs the mask. Masking only the tiles the diagonal or the padding crosses was
 measured and is NOT done: a second loop body for them made the forward 5 %
 and the backward 2 % slower at d = 128 (the iotas, compare and select hide
 under the MXU; PERF.md section 6, PR 31). The score's ``* scale`` stays on
@@ -41,7 +47,10 @@ key/value head's. With one head a head the index maps, and so the program,
 are what they were. The backward's resident set (:func:`_vmem_bytes`: q,
 dO, dq and its accumulator whole) bounds the length: ~19k tokens at D ≤ 128
 in bf16, ~9.2k at D = 256 (8192 tokens hold 41.0 of the 48 MiB budget at
-512² blocks), half that in float32. Longer sequences, any off-TPU call and
+512² blocks), half that in float32. A window changes no block: K and V (the
+forward) and q, dO and dq (the backward) stay whole-sequence resident, so a
+windowed layer is bounded by the same length though it reads ``window`` keys
+a row (a K/V block spec that follows the window is PERF.md section 7's). Longer sequences, any off-TPU call and
 a program that may span devices route to ``blockwise_attention`` — same
 exact-softmax math from HBM-resident tensors — and say so in a
 ``kernel.fallback`` record; the kernel path says its blocks and tile counts
@@ -50,8 +59,10 @@ in ``kernel.select`` (op ``flash_attn``).
 Shapes it runs at: the token decoders' ``[B, 16, 4096, 128]`` causal
 (``models/olmoe.py``, and Ouro's blocks through it), latent attention's
 ``[1, 20, 8192, 256]`` causal (``models/glm_moe.py``), grouped queries'
-``[2, 32 on 8, 8192, 64]`` causal (``models/lfm2_moe.py``) and ViT-Ti at
-1024px ``[B, 3, 4096, 64]``, non-causal.
+``[2, 32 on 8, 8192, 64]`` causal (``models/lfm2_moe.py``), a group of
+eight with and without a window of 2048, ``[2, 32 on 4, 8192, 128]`` causal
+(``models/afmoe.py``'s sliding and full layers), and ViT-Ti at 1024px ``[B,
+3, 4096, 64]``, non-causal.
 """
 
 from __future__ import annotations
@@ -130,7 +141,16 @@ def choose_blocks(L: int, d: int, causal: bool, itemsize: int = 2):
     the best forward + backward whose resident set fits the budget (17.12
     ms; 1024² is 1.4 % under it at 61 MiB by :func:`_vmem_bytes`, past the 48
     a sequence may hold; a 256 on either side costs 6–21 %): d = 256 takes
-    512² by the same line as d = 128."""
+    512² by the same line as d = 128. With a window of 2048 at ``[2, 32 on 4,
+    8192, 128]`` causal (PERF.md section 6, PR 43; forward / forward +
+    backward ms; 70 tiles a sequence at 512² where causal visits 136, two of
+    them a row of blocks crossed): 512² 6.33 / 16.89 is the fastest in both;
+    256 x 512 6.35 / 19.41, 512 x 1024 7.31 / 19.02, 1024 x 512 7.55 / 19.06,
+    1024² 7.36 / 18.50, a 256-wide key block 8.78-9.68 / 20.67-25.90 (smaller
+    blocks waste less at the two crossed edges, and lose more than that to
+    the walk's overhead; larger ones visit whole tiles behind the window):
+    a windowed call takes 512² like the rest, and runs 1.69 x / 1.74 x the
+    speed of the same call without its window (10.74 / 29.42)."""
     big = (1024, 1024)
     if not causal and d <= 64 and _vmem_bytes(
             _round_up(L, 128), d, itemsize, *big) <= _VMEM_BUDGET:
@@ -180,6 +200,11 @@ def _min(a, b):
     return min(a, b) if both else jnp.minimum(a, b)
 
 
+def _max(a, b):
+    both = isinstance(a, int) and isinstance(b, int)
+    return max(a, b) if both else jnp.maximum(a, b)
+
+
 def _key_tiles(j, blk_q, blk_k, lp, length, causal):
     """Query block ``j``'s walk over key tiles: ``(full, hi)``. It visits
     ``[0, hi)``: from ``hi`` on every score is masked. Of those, ``[0,
@@ -200,20 +225,48 @@ def _first_query_tile(j, blk_q, blk_k, causal):
     return (j * blk_k) // blk_q if causal else 0
 
 
-def tile_counts(L: int, blk_q: int, blk_k: int, causal: bool):
+def _window_key_tiles(j, blk_q, blk_k, window):
+    """With a window, query block ``j``'s walk over key tiles starts at
+    ``lo`` (before it every score is masked: a tile's last key lies more than
+    ``window - 1`` before the block's FIRST row, whose window reaches
+    furthest back), and the window keeps a tile whole from ``whole`` on (its
+    first key is within ``window - 1`` of the block's LAST row): ``(lo,
+    whole)``. The tiles ``[lo, whole)`` are crossed by the window's edge."""
+    lo = _max(0, (j * blk_q - (window - 1)) // blk_k)
+    whole = _max(0, ((j + 1) * blk_q - window + blk_k - 1) // blk_k)
+    return lo, whole
+
+
+def _last_query_tile(j, blk_q, blk_k, lp, window):
+    """With a window, key block ``j``'s walk over query tiles ends before
+    this tile: the last row that still sees the block's last key is ``(j +
+    1)·blk_k - 1 + window - 1``."""
+    return _min(lp // blk_q, ((j + 1) * blk_k + window - 2) // blk_q + 1)
+
+
+def tile_counts(L: int, blk_q: int, blk_k: int, causal: bool,
+                window: int | None = None):
     """``(visited, crossed)`` score tiles of one sequence, ``blk_q``/``blk_k``
-    as :func:`_resolve_blocks` snapped them: what ``kernel.select`` says."""
+    as :func:`_resolve_blocks` snapped them: what ``kernel.select`` says.
+    With a ``window`` a row of blocks is crossed at both ends, by the
+    diagonal and by the window's edge."""
     lp = _round_up(L, 128)
     visited = crossed = 0
     for j in range(lp // blk_q):
         full, hi = _key_tiles(j, blk_q, blk_k, lp, L, causal)
-        visited, crossed = visited + hi, crossed + hi - full
+        lo = whole = 0
+        if window is not None:
+            lo, whole = _window_key_tiles(j, blk_q, blk_k, window)
+        kept = max(0, full - max(lo, whole))
+        visited, crossed = visited + hi - lo, crossed + hi - lo - kept
     return visited, crossed
 
 
-def _keep(qpos, kpos, length, causal):
+def _keep(qpos, kpos, length, causal, window=None):
     keep = kpos < length
-    return keep & (kpos <= qpos) if causal else keep
+    if causal:
+        keep = keep & (kpos <= qpos)
+    return keep if window is None else keep & (qpos - kpos < window)
 
 
 # ---------------------------------------------------------------------------
@@ -223,7 +276,8 @@ def _keep(qpos, kpos, length, causal):
 
 
 def _fwd_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, length, blk_k, causal
+    q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, length, blk_k, causal,
+    window=None,
 ):
     q = q_ref[0]  # [blk_q, D]
     blk_q, d = q.shape
@@ -241,7 +295,8 @@ def _fwd_kernel(
                 jnp.int32, (1, blk_k), 1)
             qpos = j * blk_q + jax.lax.broadcasted_iota(
                 jnp.int32, (blk_q, 1), 0)
-            s = jnp.where(_keep(qpos, kpos, length, causal), s, _NEG_BIG)
+            s = jnp.where(
+                _keep(qpos, kpos, length, causal, window), s, _NEG_BIG)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
@@ -250,14 +305,20 @@ def _fwd_kernel(
         return m_new, l, acc
 
     # causal block-skip: key tiles past this q block's last row are wholly
-    # masked and never visited. Every q row still sees key 0, so m/l are
-    # finite after the first tile.
+    # masked and never visited. Without a window every q row sees key 0, so
+    # m/l are finite after the first tile. With one the walk starts at the
+    # first tile the block's FIRST row still sees, which a later row may not
+    # see at all: that row leaves the tile with m = _NEG_BIG and p = 1
+    # everywhere, and the first tile that holds a key it keeps (its own, at
+    # the latest) wipes both through corr = exp(_NEG_BIG - m_new) = 0.
+    # Nothing divides before the walk ends.
     _, hi = _key_tiles(j, blk_q, blk_k, lp, length, causal)
+    lo = 0 if window is None else _window_key_tiles(j, blk_q, blk_k, window)[0]
     m0 = jnp.full((blk_q, 1), _NEG_BIG, jnp.float32)
     l0 = jnp.zeros((blk_q, 1), jnp.float32)
     a0 = jnp.zeros((blk_q, d), jnp.float32)
     # NOT unrolled: Mosaic keeps every unrolled iteration's float32 tile live
-    m, l, acc = jax.lax.fori_loop(0, hi, tile, (m0, l0, a0))
+    m, l, acc = jax.lax.fori_loop(lo, hi, tile, (m0, l0, a0))
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
     # the column of statistics leaves as a lane-major row: a [blk_q, 1]
@@ -273,7 +334,7 @@ def _fwd_kernel(
 
 def _bwd_kernel(
     q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
-    dq_acc, *, scale, length, blk_q, causal,
+    dq_acc, *, scale, length, blk_q, causal, window=None,
 ):
     """Everything is TRANSPOSED (``sᵀ = k qᵀ``, ``[blk_k, blk_q]``): ``lse``
     and ``delta`` broadcast from their lane-major rows as they lie, four of
@@ -302,7 +363,8 @@ def _bwd_kernel(
                 jnp.int32, (blk_k, 1), 0)
             qpos = t * blk_q + jax.lax.broadcasted_iota(
                 jnp.int32, (1, blk_q), 1)
-            s_t = jnp.where(_keep(qpos, kpos, length, causal), s_t, _NEG_BIG)
+            s_t = jnp.where(
+                _keep(qpos, kpos, length, causal, window), s_t, _NEG_BIG)
         p_t = jnp.exp(s_t - lse_ref[0, :, rows])  # lse: [1, blk_q]
         dv = dv + _dot(p_t.astype(dob.dtype), dob, (1, 0))  # [blk_k, D]
         dp_t = _dot(vb, dob, (1, 1))  # [blk_k, blk_q]
@@ -311,10 +373,13 @@ def _bwd_kernel(
         dq_acc[rows, :] += _dot(ds_t.astype(kb.dtype), kb, (0, 0))
         return dk, dv
 
-    # causal block-skip: start at the first q tile the key block reaches
+    # causal block-skip: start at the first q tile the key block reaches;
+    # with a window, end behind the last one that still sees it
     z = jnp.zeros((blk_k, d), jnp.float32)
+    last = lp // blk_q if window is None else _last_query_tile(
+        j, blk_q, blk_k, lp, window)
     dk, dv = jax.lax.fori_loop(
-        _first_query_tile(j, blk_q, blk_k, causal), lp // blk_q, tile, (z, z))
+        _first_query_tile(j, blk_q, blk_k, causal), last, tile, (z, z))
     # the score's scale, once on the float32 sums and not on every ds tile
     dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
@@ -381,12 +446,19 @@ def _under_the_old_name(t, name, interpret):
     )(t)
 
 
+def _windowed(window) -> dict:
+    """The kernels' ``window`` keyword, or none at all: a call without a
+    window builds the partial, and so the program, it always built."""
+    return {} if window is None else {"window": window}
+
+
 def _pad_lhd(t, lp):
     pad = lp - t.shape[1]
     return jnp.pad(t, ((0, 0), (0, pad), (0, 0))) if pad else t
 
 
-def _flash_forward(q, k, v, scale, interpret, blk_q, blk_k, causal):
+def _flash_forward(q, k, v, scale, interpret, blk_q, blk_k, causal,
+                   window=None):
     b, h, L, d = q.shape
     blk_q, blk_k, lp = _resolve_blocks(L, blk_q, blk_k)
     bh, group = b * h, h // k.shape[1]
@@ -398,7 +470,8 @@ def _flash_forward(q, k, v, scale, interpret, blk_q, blk_k, causal):
     blocked, _, vec_blocked, _, kv_whole, _ = _specs(lp, d, blk_q, group)
     o, lse = pl.pallas_call(
         functools.partial(
-            _fwd_kernel, scale=scale, length=L, blk_k=blk_k, causal=causal
+            _fwd_kernel, scale=scale, length=L, blk_k=blk_k, causal=causal,
+            **_windowed(window),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((bh, lp, d), v.dtype),
@@ -419,7 +492,7 @@ def _flash_forward(q, k, v, scale, interpret, blk_q, blk_k, causal):
 
 
 def _flash_backward(res, g, scale, interpret, blk_q, blk_k, causal,
-                    g_lse=None):
+                    g_lse=None, window=None):
     """dQ/dK/dV from the saved residuals. ``g_lse`` (padded [bh, 1, lp]) is
     the cotangent of the lse output when the caller exposed it
     (``flash_attention_with_lse``): dL/ds_ij gains the softmax term
@@ -443,7 +516,8 @@ def _flash_backward(res, g, scale, interpret, blk_q, blk_k, causal,
     blocked_k, whole, _, vec_whole, _, kv_blocked = _specs(lp, d, blk_k, group)
     dq, dk, dv = pl.pallas_call(
         functools.partial(
-            _bwd_kernel, scale=scale, length=L, blk_q=blk_q, causal=causal
+            _bwd_kernel, scale=scale, length=L, blk_q=blk_q, causal=causal,
+            **_windowed(window),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((bh, lp, d), qf.dtype),
@@ -474,13 +548,15 @@ def _flash_backward(res, g, scale, interpret, blk_q, blk_k, causal,
     return unpad(dq), unpad(dk), unpad(dv)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_attention(q, k, v, scale, interpret, blk_q, blk_k, causal):
-    o, _, _ = _flash_forward(q, k, v, scale, interpret, blk_q, blk_k, causal)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_attention(q, k, v, scale, interpret, blk_q, blk_k, causal,
+                     window=None):
+    o, _, _ = _flash_forward(
+        q, k, v, scale, interpret, blk_q, blk_k, causal, window)
     return o
 
 
-def _residuals(q, k, v, scale, interpret, blk_q, blk_k, causal):
+def _residuals(q, k, v, scale, interpret, blk_q, blk_k, causal, window=None):
     """``(o, lse, residuals)`` of a forward rule, everything the backward
     kernel reads NAMED (:data:`KEPT_UNDER_REMAT`): ``o``, ``lse`` and ``qf``,
     ``kf``, ``vf`` as the kernels take them. The rule's primal output must be
@@ -491,20 +567,23 @@ def _residuals(q, k, v, scale, interpret, blk_q, blk_k, causal):
     projections, rotary, head transposes, casts, the pad) is wanted again:
     what a projection's own backward reads is its input, the norm's output
     (pinned on the gradient's jaxpr in ``tests/test_flash_attention.py``)."""
-    o, lse, qkv = _flash_forward(q, k, v, scale, interpret, blk_q, blk_k, causal)
+    o, lse, qkv = _flash_forward(
+        q, k, v, scale, interpret, blk_q, blk_k, causal, window)
     o, lse, *qkv = (
         checkpoint_name(t, name)
         for t, name in zip((o, lse, *qkv), KEPT_UNDER_REMAT))
     return o, lse, (*qkv, lse, o, q.shape)
 
 
-def _fa_fwd(q, k, v, scale, interpret, blk_q, blk_k, causal):
-    o, _, res = _residuals(q, k, v, scale, interpret, blk_q, blk_k, causal)
+def _fa_fwd(q, k, v, scale, interpret, blk_q, blk_k, causal, window=None):
+    o, _, res = _residuals(
+        q, k, v, scale, interpret, blk_q, blk_k, causal, window)
     return o, res
 
 
-def _fa_bwd(scale, interpret, blk_q, blk_k, causal, res, g):
-    return _flash_backward(res, g, scale, interpret, blk_q, blk_k, causal)
+def _fa_bwd(scale, interpret, blk_q, blk_k, causal, window, res, g):
+    return _flash_backward(
+        res, g, scale, interpret, blk_q, blk_k, causal, window=window)
 
 
 _flash_attention.defvjp(_fa_fwd, _fa_bwd)
@@ -577,7 +656,7 @@ def _data_ranks(mesh, batch: int) -> int:
 def flash_attention(
     q, k, v, *, scale: float | None = None, causal: bool = False,
     interpret: bool | None = None, blk_q: int | None = None,
-    blk_k: int | None = None, mesh=None,
+    blk_k: int | None = None, mesh=None, window: int | None = None,
 ):
     """Exact softmax attention, flash-tiled in Pallas.
 
@@ -591,6 +670,13 @@ def flash_attention(
     masked tiles are never visited (the loop bounds shrink with the program
     id — ~2× fewer at large L). ``blk_q``/``blk_k`` default to
     :func:`choose_blocks`.
+
+    ``window`` (causal calls only): query t reads the keys s with ``t -
+    window < s <= t``, ``window`` of them, itself among them. Both kernels
+    then bound their walks at the other end too (the tiles wholly behind the
+    window are never visited: 70 of causal's 136 at 8192 tokens, 512² blocks
+    and a window of 2048). A window that reaches every key (``>= L``) is the
+    causal call, and ``None`` lowers to the program it always did.
 
     A caller that knows its ``mesh`` hands it over: where its ``data`` axis is
     populated (and divides the batch) every data rank runs the kernel on its
@@ -609,13 +695,20 @@ def flash_attention(
     _check_head_dim(d)
     group = _kv_group(q, k, v)
     scale = d ** -0.5 if scale is None else scale
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(
+                f"window={window}: a window takes a causal call and at "
+                "least the query's own key")
+        if window >= L:  # every key a row may read lies inside it
+            window = None
     if _data_ranks(mesh, b) > 1:
         def per_shard(q, k, v):
             # one device's sequences: the kernel tier may engage
             with kernel_tier.single_device_program():
                 return flash_attention(
                     q, k, v, scale=scale, causal=causal, interpret=interpret,
-                    blk_q=blk_q, blk_k=blk_k,
+                    blk_q=blk_q, blk_k=blk_k, window=window,
                 )
 
         rows = jax.sharding.PartitionSpec("data")
@@ -628,7 +721,7 @@ def flash_attention(
     rq, rk, lp = _resolve_blocks(L, blk_q, blk_k)
     # the interpreter has no VMEM budget
     fits = interpret is True or fits_vmem(L, d, itemsize)
-    visited, crossed = tile_counts(L, rq, rk, causal)
+    visited, crossed = tile_counts(L, rq, rk, causal, window)
     impl = kernel_tier.select(
         "flash_attn", supported=fits, forced=interpret is not None,
         reason="" if fits else (
@@ -639,6 +732,7 @@ def flash_attention(
         tiles_masked=visited if causal or lp != L else 0,
         bwd_matmuls_a_tile=BWD_MATMULS_A_TILE,
         **({"kv_group": group, "kv_heads": h // group} if group > 1 else {}),
+        **_windowed(window),
     )
     if impl == "xla":
         # stream from HBM via the scan path: off the TPU (the interpreter is
@@ -649,10 +743,12 @@ def flash_attention(
 
         # the scan takes q, k and v of one shape: the group's heads repeated
         k, v = (jnp.repeat(t, group, axis=1) if group > 1 else t for t in (k, v))
-        return blockwise_attention(q, k, v, causal=causal, scale=scale)
+        return blockwise_attention(
+            q, k, v, causal=causal, scale=scale, **_windowed(window))
     if interpret is None:
         interpret = False
-    return _flash_attention(q, k, v, scale, interpret, blk_q, blk_k, causal)
+    return _flash_attention(
+        q, k, v, scale, interpret, blk_q, blk_k, causal, window)
 
 
 def kept_under_remat_bytes(q_shape, itemsize: int, mesh=None,

@@ -292,7 +292,8 @@ def ulysses_attention(
 
 
 def blockwise_attention(q, k, v, *, chunk: int = 256, causal: bool = False,
-                        scale: float | None = None, remat: bool = True):
+                        scale: float | None = None, remat: bool = True,
+                        window: int | None = None):
     """Single-device flash-style attention: exact softmax in O(L·chunk)
     memory instead of the dense path's O(L²) logits (Rabe & Staats,
     arXiv:2112.05682; the single-chip sibling of ring attention — same
@@ -304,6 +305,11 @@ def blockwise_attention(q, k, v, *, chunk: int = 256, causal: bool = False,
     (hundreds of MB) while this keeps only the running (m, l, o) state plus
     one [L, chunk] block. ``remat=True`` recomputes each chunk's block in
     the backward pass, so autodiff never stores the probabilities either.
+
+    ``window`` (with ``causal``): query t reads the keys s with ``t - window
+    < s <= t``, as ``ops/flash_attention.py``'s kernels; every chunk is
+    still walked (the mask alone says it: this is the fallback, not the
+    fast path).
 
     q, k: [B, H, L, D]; v: [B, H, L, Dv]. Returns [B, H, L, Dv] in v.dtype.
     """
@@ -335,6 +341,8 @@ def blockwise_attention(q, k, v, *, chunk: int = 256, causal: bool = False,
             mask = jnp.broadcast_to((k_pos < L)[None, :], (L, chunk))
             if causal:
                 mask = mask & (q_pos[:, None] >= k_pos[None, :])
+            if window is not None:
+                mask = mask & (q_pos[:, None] - k_pos[None, :] < window)
         m, l, o = _block_update(
             qf, kb.astype(jnp.float32), vb, m, l, o, scale, mask
         )

@@ -339,6 +339,10 @@ def lm_spec_table(moe_axis: str = "model") -> SpecTable:
             SpecRule(r"short_conv/in_proj/kernel$", P(None, "model")),
             SpecRule(r"short_conv/out_proj/kernel$", P("model")),
             SpecRule(r"short_conv/filter$", P("model")),
+            # the afmoe family (models/afmoe.py): everything by the rules
+            # above (its four block norms end in ``_norm``); the attention
+            # output's gate by columns, as the query projection it multiplies
+            SpecRule(r"attn/gate_proj/kernel$", P(None, "model")),
         ),
         default=None,  # unmatched leaves keep their annotation/replication
         strict=False,

@@ -300,6 +300,12 @@ DEVICE_SCOPES: dict[str, str] = {
     # that is no matmul (the two gates and the filter; ops/short_conv.py)
     "short_conv": "models",
     "short_conv_gate": "kernels",
+    # models/afmoe.py (``attn``, ``mlp``, ``moe``, ``moe_shared`` and
+    # ``lm_head`` as above): inside ``attn``, a sliding-window layer's mixer
+    # whole (a full-attention layer's carries no second scope), and the
+    # attention output's gate (projection, sigmoid, product) of either kind
+    "attn_window": "models",
+    "attn_gate": "models",
     # ops/pallas/opt_update.py
     "opt_tile": "kernels",
     "opt_kernel": "kernels",

@@ -397,8 +397,13 @@ def test_generation_telemetry_and_run_report(f32, tmp_path):
     from distribuuuu_tpu import telemetry
     from distribuuuu_tpu.telemetry import schema
 
+    from distribuuuu_tpu.telemetry import costmodel
+
     cfg.OUT_DIR = str(tmp_path)
     telemetry.setup_from_cfg(cfg, rank=0)
+    # a label is captured once a PROCESS: whatever ran in this worker before
+    # (another file's generation under a sink) must not have used these up
+    costmodel.reset()
     try:
         model = _tiny_gpt(seq_len=32)
         params = _params(model)

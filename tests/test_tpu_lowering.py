@@ -328,15 +328,46 @@ def test_lfm2_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
     assert len(calls["dtpu_opt_update_adamw"]) == len(jax.tree.leaves(state.params))
 
 
-def _movers_of_held_mixtures(calls, in_scope, mixtures: int):
+def test_windowed_flash_compiles_for_the_v5e_at_a_group_of_eight(v5e_chip):
+    """``trinity_mini.train_seq8192``'s sliding layers: 32 query heads on 4
+    key/value heads of 128 at 2 x 8192 tokens, causal, a window of 2048.
+    Mosaic takes both kernels with the walks bounded at both ends (traced
+    loop bounds from the program id) and K and V at their own 4 heads; the
+    resident set is the causal call's (the window changes no block)."""
+    from jax.sharding import SingleDeviceSharding
+
+    chip = SingleDeviceSharding(v5e_chip)
+    q = jax.ShapeDtypeStruct((2, 32, 8192, 128), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((2, 4, 8192, 128), jnp.bfloat16, sharding=chip)
+
+    def loss(q, k, v):
+        return fa.flash_attention(
+            q, k, v, causal=True, interpret=False, window=2048,
+        ).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if "custom-call(" in line and "dtpu_flash_" in line]
+    assert len(calls) == 4 and sum("dtpu_flash_bwd" in c for c in calls) == 1
+    forward = [c for c in calls if "dtpu_flash_fwd" in c][0]
+    assert forward.count("bf16[8,8192,128]") == 2 and forward.count("bf16[64,8192,128]") >= 2
+    assert [tuple(x.shape) for x in compiled.out_info] == [
+        (2, 32, 8192, 128), (2, 4, 8192, 128), (2, 4, 8192, 128)]
+    assert fa.fits_vmem(8192, 128)
+    assert fa.tile_counts(8192, 512, 512, True, 2048) == (70, 28)
+
+
+def _movers_of_held_mixtures(calls, in_scope, mixtures: int, normed_after=False):
     """A recomputed held mixture moves its rows through ``ops/pallas/
     moe_rows``: ``take`` forward, again under recomputation and as the
     combine's backward; ``combine`` forward and as the take's backward (the
-    recomputation has no use for the mixture's output); a ``pack`` before
-    each; all under ``moe_route``, where the benchmark's readers sum them."""
+    recomputation has no use for the mixture's output, unless a norm follows
+    it, ``normed_after``: that norm's backward reads it, so the combine runs
+    a third time); a ``pack`` before each; all under ``moe_route``, where the
+    benchmark's readers sum them."""
     movers = {k: len(v) // mixtures for k, v in calls.items() if "moe_rows" in k}
-    assert movers == {"dtpu_moe_rows_take": 3, "dtpu_moe_rows_combine": 2,
-                      "dtpu_moe_rows_pack": 5}, movers
+    assert movers == {"dtpu_moe_rows_take": 3, "dtpu_moe_rows_combine": 2 + normed_after,
+                      "dtpu_moe_rows_pack": 5 + normed_after}, movers
     for name in movers:
         assert all(in_scope(p, "moe_route") and not in_scope(p, "moe_experts")
                    for p in calls[name]), name

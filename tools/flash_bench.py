@@ -27,6 +27,7 @@ for OLMoE's cell and ``--batch 1`` for Ouro's):
     python tools/flash_bench.py [--batch 4] [--heads 3] [--seq 4096]
         [--dim 64] [--causal] [--iters 20] [--rounds 5] [--skip-dense]
         [--blk-q N] [--blk-k N] [--sweep] [--baseline FILE] [--kv-heads N]
+        [--window W]
 
 ``--blk-q``/``--blk-k`` default to what ``flash_attention.choose_blocks``
 picks for the shape (printed). ``--sweep`` times the flash path alone at
@@ -42,6 +43,13 @@ path ``repeat`` repeats them to q's heads in HBM first (what a caller had to
 do before the kernels took a group; its backward sums dK and dV over the
 group in autodiff's transpose of the repeat), and the scan and dense paths
 read the repeated heads.
+
+``--window W`` (with ``--causal``) gives the flash, repeat, scan and sweep
+paths a sliding window of W keys (Trinity-Mini's window layers are ``--batch
+2 --heads 32 --kv-heads 4 --seq 8192 --dim 128 --causal --window 2048``);
+the useful work is then the (query, key) pairs the window keeps. Path
+``base`` (another checkout's kernels, which may know no window) and the
+dense path stay plain causal: beside them the window's skip shows.
 
 ``--kernel decode`` (ISSUE 13) switches the harness to the kernel
 tier's fused decode attention (ops/pallas/decode_attn.py) vs the dense
@@ -247,6 +255,8 @@ def main():
     ap.add_argument("--causal", action="store_true",
                     help="the causal paths: the kernels' diagonal walk "
                          "against the causal scan and dense")
+    ap.add_argument("--window", type=int, default=None,
+                    help="a sliding window of this many keys (with --causal)")
     args = ap.parse_args()
 
     from distribuuuu_tpu.config import cfg
@@ -285,8 +295,17 @@ def main():
         return lambda q, k, v: fn(
             q, jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1))
 
-    # causal touches only the lower triangle — half the score/PV work
-    flops = 2 * 2 * B * H * L * L * D * (0.5 if args.causal else 1.0)
+    # causal touches only the lower triangle — half the score/PV work; a
+    # window the pairs it keeps: the first W rows' triangle, then W a row
+    pairs = L * L * (0.5 if args.causal else 1.0)
+    windowed = {}
+    if args.window:
+        w = min(args.window, L)
+        pairs = w * (w + 1) / 2 + (L - w) * w
+        windowed = {"window": args.window}
+        print(f"window: {args.window} keys, {pairs / L:.1f} a query on average; "
+              f"tiles visited and crossed at the chosen blocks are below")
+    flops = 2 * 2 * B * H * pairs * D
 
     chosen = fa.choose_blocks(L, D, args.causal)
     blk_q, blk_k = args.blk_q or chosen[0], args.blk_k or chosen[1]
@@ -294,12 +313,18 @@ def main():
           f"running blk_q={blk_q} blk_k={blk_k}, resolved "
           f"{fa._resolve_blocks(L, blk_q, blk_k)[:2]}")
 
+    if args.window:
+        rq, rk, _ = fa._resolve_blocks(L, blk_q, blk_k)
+        print(f"tiles: visited, crossed = "
+              f"{fa.tile_counts(L, rq, rk, args.causal, args.window)} with the "
+              f"window, {fa.tile_counts(L, rq, rk, args.causal)} without")
+
     def flash(blk_q, blk_k, module=fa):
         return lambda q, k, v: module.flash_attention(
-            q, k, v, causal=args.causal, blk_q=blk_q, blk_k=blk_k)
+            q, k, v, causal=args.causal, blk_q=blk_q, blk_k=blk_k, **windowed)
 
-    scan = repeated(
-        lambda q, k, v: ra.blockwise_attention(q, k, v, causal=args.causal))
+    scan = repeated(lambda q, k, v: ra.blockwise_attention(
+        q, k, v, causal=args.causal, **windowed))
 
     paths = {"flash": flash(blk_q, blk_k)}
     if group > 1:
