@@ -7,7 +7,10 @@ a decoder whose token mixer is chosen LAYER BY LAYER from the published
 * ``mixer_i`` is a gated short convolution where ``layer_types[i]`` is
   ``conv`` (:class:`ShortConv`: ``[B, C, u] = split3(x W_in)``, ``g = B * u``,
   a depthwise causal filter of ``conv_L_cache`` taps over ``g``, ``out = (C *
-  c) W_out``; no bias, no activation; ``ops/short_conv.py``) and grouped-query
+  c) W_out``; no bias, no activation; ``ops/short_conv.py``: everything
+  between the two matmuls is one Pallas call each way in a one-device TPU
+  program, ``ops/pallas/short_conv.py``, and plain ``jax.numpy`` on the CPU
+  and in a program that may span devices) and grouped-query
   attention where it is ``full_attention`` (:class:`Attention`:
   ``num_attention_heads`` query heads on ``num_key_value_heads`` key/value
   heads, query head ``h`` reading key/value head ``h // group``; RMSNorm over

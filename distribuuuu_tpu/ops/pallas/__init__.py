@@ -1,7 +1,7 @@
 """The Pallas kernel tier (ISSUE 13): fused kernels for the memory-bound
 programs the cost ledger pinned, as ONE subsystem instead of one-offs.
 
-Five kernels, one discipline:
+Six kernels, one discipline:
 
 * ``opt_update``     — fused optimizer update (opt_update.py): ONE HBM
                        pass over params+grads+moments for SGD-momentum
@@ -32,6 +32,14 @@ Five kernels, one discipline:
                        moved every row of a buffer that is three quarters
                        dead. No knob either: it runs where ``moe_gmm``
                        does and some expert is not held.
+* ``short_conv``     — LFM2's gated short convolution (short_conv.py):
+                       gate -> filter -> gate as ONE call each way over
+                       ``x W_in`` as it came, the taps' shifts on the
+                       sublanes in VMEM and the filter's gradient summed
+                       in the call, where XLA ran six loop fusions and
+                       wrote shifted copies. No knob: it runs in a
+                       one-device TPU program where the channels fill
+                       the lanes and a sequence block divides the length.
 
 Tier discipline (every kernel, no exceptions):
 
@@ -92,13 +100,14 @@ KNOBS = {
 # ops without a knob: ``auto``, or ``pallas`` where the caller forces it
 # (``flash_attn`` is ops/flash_attention.py's two kernels, outside this
 # package; it resolves here so that its record sits beside the others)
-KNOBLESS = ("moe_gmm", "moe_rows", "flash_attn")
+KNOBLESS = ("moe_gmm", "moe_rows", "flash_attn", "short_conv")
 
 # ops that have no shard_map of their own: they engage in a program their
 # caller declared one-device (module docstring; ``moe_gmm``'s caller
 # declares it inside its shard_map over the data axis, as ``flash_attn``'s
 # does where it was handed a mesh)
-_NO_SHARD_MAP = ("conv_epilogue", "decode_attn", "moe_gmm", "moe_rows", "flash_attn")
+_NO_SHARD_MAP = ("conv_epilogue", "decode_attn", "moe_gmm", "moe_rows", "flash_attn",
+                 "short_conv")
 
 # process-lifetime emission/warn dedup: one kernel.select per (op, impl,
 # requested) resolution, one kernel.fallback + warning per (op, reason)

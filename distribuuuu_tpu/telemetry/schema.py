@@ -150,7 +150,9 @@ KINDS: dict[str, frozenset] = {
     # rows_bound, experts_held, experts_total; `flash_attn` L, d,
     # causal, blk_q, blk_k,
     # and a sequence's tiles_visited, tiles_crossed (by the diagonal or the
-    # padding), tiles_masked (those that run the mask), bwd_matmuls_a_tile
+    # padding), tiles_masked (those that run the mask), bwd_matmuls_a_tile;
+    # `short_conv` (ops/pallas/short_conv.py) seq_block, row_chunk,
+    # lane_chunk, taps, channels, tokens
     "kernel.select": frozenset({"op", "impl", "requested"}),
     # a forced-but-unsupported site degrading to the XLA reference, with
     # the disqualifying reason (also warn-once logged)
@@ -323,6 +325,9 @@ KERNEL_NAMES: tuple[str, ...] = (
     # ops/pallas/moe_rows.py, one prefix: _pack, _take, _combine (under
     # ``moe_route``: the rows of the live tiles into and out of the buffer)
     "dtpu_moe_rows",
+    # ops/pallas/short_conv.py: _fwd, _bwd (under ``short_conv_gate``: the
+    # two gates and the filter of a ``conv`` layer, one call each way)
+    "dtpu_short_conv",
 )
 
 

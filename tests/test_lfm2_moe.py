@@ -6,6 +6,7 @@ experts of 32 with 2 a token, 6 layers by the pattern conv, conv, attention,
 conv, vocab 512 with the head tied to the embedding; two chips share each
 layer unless a test says otherwise."""
 
+import functools
 import importlib.util
 import os
 
@@ -235,16 +236,24 @@ def _explicit_conv(bcu, w):
     return c * out
 
 
+# the jax.numpy path at a shape no kernel takes, and the two Pallas calls
+# (ops/pallas/short_conv.py) through the interpreter: (S, H, interpret)
+SHORT_CONV_PATHS = {"jax_numpy": (9, 8, None), "kernel": (16, 128, True)}
+
+
+@pytest.mark.parametrize("path", list(SHORT_CONV_PATHS))
 @pytest.mark.parametrize("taps", [3, 4])
-def test_short_conv_is_the_explicit_sum_value_and_gradient(taps):
+def test_short_conv_is_the_explicit_sum_value_and_gradient(taps, path):
     """``ops/short_conv`` against the sum written out, the first L - 1
     positions (which read zeros left of the sequence) included; its gradient
-    against a central difference of that sum."""
+    against a central difference of that sum; on either path."""
+    S, H, interpret = SHORT_CONV_PATHS[path]
+    gated = functools.partial(gated_short_conv, interpret=interpret)
     keys = jax.random.split(jax.random.key(taps), 3)
-    bcu = jax.random.normal(keys[0], (2, 9, 3 * 8))
-    w = jax.random.normal(keys[1], (8, taps))
+    bcu = jax.random.normal(keys[0], (2, S, 3 * H))
+    w = jax.random.normal(keys[1], (H, taps))
     want = _explicit_conv(bcu, w)
-    got = gated_short_conv(bcu, w)
+    got = gated(bcu, w)
     np.testing.assert_allclose(got, want, atol=1e-5)
     np.testing.assert_allclose(got[:, :taps - 1], want[:, :taps - 1], atol=1e-5)
     # position 0 reads the last tap alone
@@ -252,7 +261,7 @@ def test_short_conv_is_the_explicit_sum_value_and_gradient(taps):
     np.testing.assert_allclose(got[:, 0], c[:, 0] * w[:, -1] * b[:, 0] * u[:, 0], atol=1e-5)
     weights = np.asarray(jax.random.normal(keys[2], want.shape), np.float64)
     d_bcu, d_w = jax.grad(
-        lambda bcu, w: (gated_short_conv(bcu, w) * weights).sum(), argnums=(0, 1))(bcu, w)
+        lambda bcu, w: (gated(bcu, w) * weights).sum(), argnums=(0, 1))(bcu, w)
 
     def central(of, x, index, step=1e-3):
         hi, lo = np.array(x, np.float64), np.array(x, np.float64)
@@ -260,14 +269,16 @@ def test_short_conv_is_the_explicit_sum_value_and_gradient(taps):
         lo[index] -= step
         return ((of(hi) - of(lo)) * weights).sum() / (2 * step)
 
-    for index in [(0, 0, 0), (1, 0, 9), (0, 1, 17), (1, 8, 23), (0, 7, 3)]:
+    # B at the first position, C, u, the last element, B near the end
+    for index in [(0, 0, 0), (1, 0, H + 1), (0, 1, 2 * H + 1), (1, S - 1, 3 * H - 1),
+                  (0, S - 2, 3)]:
         np.testing.assert_allclose(
             d_bcu[index], central(lambda x: _explicit_conv(x, w), bcu, index), rtol=2e-3)
     for index in [(0, 0), (3, taps - 1), (7, 1)]:
         np.testing.assert_allclose(
             d_w[index], central(lambda x: _explicit_conv(bcu, x), w, index), rtol=2e-3)
     # in bfloat16 the arithmetic is float32 and the result rounded once
-    low = gated_short_conv(bcu.astype(jnp.bfloat16), w)
+    low = gated(bcu.astype(jnp.bfloat16), w)
     assert low.dtype == jnp.bfloat16
     exact = _explicit_conv(bcu.astype(jnp.bfloat16).astype(jnp.float32), w)
     np.testing.assert_allclose(low.astype(jnp.float32), exact, rtol=2 ** -7, atol=1e-6)

@@ -324,6 +324,13 @@ def test_lfm2_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
         "dtpu_moe_gmm_dx_gate_up": 1, "dtpu_moe_gmm_dw_down": 1,
         "dtpu_moe_gmm_dw_gate_up": 1}
     _movers_of_held_mixtures(calls, in_scope, mixtures=1)
+    # the conv layer's gates and filter: one call each way (the forward again
+    # in the recomputation), under the scope the benchmark's readers sum
+    conv = {k: v for k, v in calls.items() if "short_conv" in k}
+    assert {k: len(v) for k, v in conv.items()} == {
+        "dtpu_short_conv_fwd": 2, "dtpu_short_conv_bwd": 1}
+    assert all(in_scope(p, "short_conv_gate") and in_scope(p, "short_conv")
+               for v in conv.values() for p in v)
     # the tied embedding is ONE leaf and takes one AdamW call
     assert len(calls["dtpu_opt_update_adamw"]) == len(jax.tree.leaves(state.params))
 
@@ -438,6 +445,40 @@ def test_moe_gmm_compiles_for_the_v5e_under_its_scope(v5e_chip):
         op_name = line.split('op_name="')[1].split('"')[0]
         assert in_scope(op_name, "moe_experts"), op_name
     assert "ragged-dot" not in text
+
+
+def test_short_conv_compiles_for_the_v5e_whole_in_and_whole_out(v5e_chip):
+    """LFM2's gated short convolution at the cell's shape (``[2, 8192, 3 x
+    2048]`` bf16, 3 taps), forward and backward: Mosaic takes the blocks of
+    512 positions the whole ``3H`` wide, the halo tiles, the sublane rolls and
+    the resident float32 ``dw`` block in the VMEM the calls ask for; ``bcu``
+    reaches both calls as it came and ``dbcu`` leaves as ONE array: beside
+    the two calls the program holds nothing the size of an activation."""
+    from jax.sharding import SingleDeviceSharding
+
+    from distribuuuu_tpu.ops import short_conv as op
+
+    chip = SingleDeviceSharding(v5e_chip)
+    bcu = jax.ShapeDtypeStruct((2, 8192, 3 * 2048), jnp.bfloat16, sharding=chip)
+    w = jax.ShapeDtypeStruct((2048, 3), jnp.float32, sharding=chip)
+
+    dy = jax.ShapeDtypeStruct((2, 8192, 2048), jnp.bfloat16, sharding=chip)
+
+    def step(bcu, w, dy):
+        y, vjp = jax.vjp(lambda bcu, w: op.gated_short_conv(bcu, w, False), bcu, w)
+        return y, vjp(dy)
+
+    text = jax.jit(step).lower(bcu, w, dy).compile().as_text()
+    entry = [line.strip() for line in text[text.index("\nENTRY "):].splitlines()
+             if " = " in line]
+    calls = [line for line in entry if "custom-call(" in line]
+    assert sorted(line.split(" = ")[0].lstrip("%").rsplit(".", 1)[0] for line in calls) == [
+        "dtpu_short_conv_bwd", "dtpu_short_conv_fwd"]
+    activations = [line[:160] for line in entry
+                   if line.split(" = ")[1].startswith(("bf16[2,8192,", "f32[2,8192,"))
+                   and not any(f" {kind}(" in line for kind in (
+                       "custom-call", "get-tuple-element", "parameter"))]
+    assert not activations, activations
 
 
 @pytest.mark.parametrize("tokens", [8192, 16384], ids=["glm", "lfm2"])

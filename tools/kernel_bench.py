@@ -380,6 +380,101 @@ def bench_moe_rows(rounds: int, iters: int, shapes=None,
             "experts_total": total, "row_tile": tm, "on_chip": on_chip,
             "cells": table}
 
+
+SHORT_CONV_SHAPE = (2, 8192, 2048, 3)  # LFM2's cell: N, S, H channels, L taps
+
+
+def _short_conv_f64(bcu, w, dy):
+    """y, dB, dC, du and dw [H, L] of the gated short convolution in float64
+    numpy, from the inputs as the device holds them."""
+    import numpy as np
+
+    b, c, u = np.split(np.asarray(bcu, np.float64), 3, axis=-1)
+    w, dy = np.asarray(w, np.float64), np.asarray(dy, np.float64)
+    taps = w.shape[1]
+
+    def moved(x, k):  # x read k positions back (k < 0: ahead), zeros entering
+        out = np.zeros_like(x)
+        if k >= 0:
+            out[:, k:] = x[:, :x.shape[1] - k]
+        else:
+            out[:, :k] = x[:, -k:]
+        return out
+
+    g, e = b * u, dy * c
+    conv = sum(w[:, j] * moved(g, taps - 1 - j) for j in range(taps))
+    dg = sum(w[:, j] * moved(e, -(taps - 1 - j)) for j in range(taps))
+    dw = np.stack([(e * moved(g, taps - 1 - j)).sum((0, 1)) for j in range(taps)], -1)
+    return {"y": c * conv, "dB": dg * u, "dC": dy * conv, "du": dg * b, "dw": dw}
+
+
+def bench_short_conv(rounds: int, iters: int) -> dict:
+    """LFM2's gated short convolution ALONE at the cell's shape (``[2, 8192,
+    6144]`` bf16, L = 3), the ``jax.numpy`` path against the two Pallas calls
+    (``ops/pallas/short_conv.py``): forward and backward in ms and as a share
+    of the HBM's bandwidth on the bytes a perfect fusion moves (forward 4 H,
+    backward 7 H a token), and each path's y, dB, dC, du and dw a tap against
+    float64 numpy on the same bfloat16 inputs (largest error over the
+    reference's largest value). Off the TPU: a toy shape through the
+    interpreter, which rehearses the path and times nothing worth keeping."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distribuuuu_tpu.ops import short_conv as op
+    from distribuuuu_tpu.ops.pallas import short_conv as kernel
+    from distribuuuu_tpu.telemetry import costmodel
+
+    on_chip = jax.default_backend() == "tpu"
+    N, S, H, taps = SHORT_CONV_SHAPE if on_chip else (1, 64, 256, 3)
+    if on_chip:  # a call is under a millisecond: amortise its dispatch
+        iters = max(iters, 20)
+    dtype = jnp.bfloat16
+    keys = jax.random.split(jax.random.key(0), 3)
+    bcu = jax.random.normal(keys[0], (N, S, 3 * H)).astype(dtype)
+    w = jax.random.normal(keys[1], (H, taps)) * 0.5
+    dy = jax.random.normal(keys[2], (N, S, H)).astype(dtype)
+    paths = {
+        "xla": (jax.jit(op.forward_xla), jax.jit(op.backward_xla)),
+        "kernel": (functools.partial(kernel.forward, interpret=not on_chip),
+                   functools.partial(kernel.backward, interpret=not on_chip)),
+    }
+    peaks = costmodel.peaks_for()
+    itemsize = jnp.dtype(dtype).itemsize
+    moved = {"fwd": 4 * H * itemsize * N * S, "bwd": 7 * H * itemsize * N * S}
+    want = _short_conv_f64(bcu, w, dy)
+    scale = {name: np.abs(value).max(axis=0 if name == "dw" else None)
+             for name, value in want.items()}
+    ts = kernel.seq_block(S, H, taps, dtype)
+    out = {"shape": [N, S, 3 * H], "taps": taps, "dtype": "bfloat16",
+           "on_chip": on_chip, "seq_block": ts,
+           "chunk": list(kernel.chunks(ts, H, dtype)), "ideal_bytes": moved}
+    for name, (fwd, bwd) in paths.items():
+        y, (dbcu, dw) = fwd(bcu, w), bwd(bcu, w, dy)
+        db, dc, du = np.split(np.asarray(dbcu, np.float64), 3, axis=-1)
+        got = {"y": np.asarray(y, np.float64), "dB": db, "dC": dc, "du": du,
+               "dw": np.asarray(dw, np.float64)}
+        row = {"max_err_over_max": {
+            k: float(np.abs(got[k] - want[k]).max() / scale[k].max())
+            for k in ("y", "dB", "dC", "du")}}
+        # a tap of the filter's gradient on its own
+        row["max_err_over_max"]["dw_by_tap"] = [
+            float(np.abs(got["dw"][:, j] - want["dw"][:, j]).max() / scale["dw"][j])
+            for j in range(taps)]
+        for part, fn, args in (("fwd", fwd, (bcu, w)), ("bwd", bwd, (bcu, w, dy))):
+            ms = _med_ms(fn, args, rounds, iters)
+            row[f"{part}_ms"] = ms
+            if on_chip and peaks:
+                row[f"{part}_hbm_share"] = round(
+                    moved[part] / peaks["bytes_per_s"] / (ms / 1e3), 4)
+        out[name] = row
+        print(f"short_conv {name}: " + "  ".join(
+            f"{part} {row[f'{part}_ms']} ms ({row.get(f'{part}_hbm_share')})"
+            for part in ("fwd", "bwd")) + f"  against float64 {row['max_err_over_max']}",
+            flush=True)
+    return out
+
+
 def _ledger_swap(step_bytes_xla, region_bytes_xla, region_bytes_kernel,
                  flops, peaks) -> dict:
     """The transparent swap arithmetic: whole-step bytes with the
@@ -541,11 +636,13 @@ def main(argv=None) -> int:
     ap.add_argument("--iters", type=int, default=3)
     ap.add_argument("--opt-params", type=int, default=2_000_000,
                     help="synthetic param count for the opt-update micro A/B")
-    ap.add_argument("--only", choices=["moe_rows"], default=None,
+    ap.add_argument("--only", choices=["moe_rows", "short_conv"], default=None,
                     help="run one entry alone and write it to --out as it "
                          "is (moe_rows: the held mixtures' row movers "
-                         "against XLA's gathers; the chip's numbers are in "
-                         "PERF.md section 6, PR 42)")
+                         "against XLA's gathers, PERF.md section 6, PR 42; "
+                         "short_conv: LFM2's gated short convolution, the "
+                         "jax.numpy path against the two Pallas calls and "
+                         "both against float64, PR 44)")
     ap.add_argument("--quick", action="store_true",
                     help="skip the in-context step ledgers (traces of the "
                          "full efficientnet/gpt programs)")
@@ -559,14 +656,15 @@ def main(argv=None) -> int:
     from distribuuuu_tpu.asyncplane import compile_cache
 
     compile_cache.setup_from_cfg(cfg)  # on the chip: warm across processes
-    if args.only == "moe_rows":
+    if args.only:
+        bench = {"moe_rows": bench_moe_rows, "short_conv": bench_short_conv}[args.only]
         doc = {"bench": BENCH_SCHEMA, "generated_by": "tools/kernel_bench.py",
                "backend": jax.default_backend(),
-               "moe_rows": bench_moe_rows(args.rounds, args.iters)}
+               args.only: bench(args.rounds, args.iters)}
         os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
         with open(args.out, "w") as f:
             json.dump(doc, f, indent=1)
-        print(f"moe_rows -> {args.out}")
+        print(f"{args.only} -> {args.out}")
         return 0
     peaks = costmodel.peaks_for()
     doc = {
