@@ -58,7 +58,6 @@ from distribuuuu_tpu.models.share import (
     PatternStack,
     pattern_kwargs_from_cfg,
     run_blocks,
-    say_plan,
     stacked,
 )
 from distribuuuu_tpu.models.traits import ArchTraits
@@ -111,9 +110,8 @@ class AfMoE(PatternStack):
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, hidden_only: bool = False):
-        B, S = tokens.shape
+        S = tokens.shape[1]
         self._check_input(tokens)
-        say_plan(self, B, S)
         embed = self._embedding()
         tokens = tokens - self.share_rank * self.vocab_held
         positions = jnp.arange(S, dtype=jnp.int32)

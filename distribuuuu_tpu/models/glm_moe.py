@@ -44,10 +44,11 @@ the mixture in every later one.
   target at t is ``label[t + 1]``.
 * parameters and the residual stream are float32, the matmuls read
   ``dtype``; norms, router, softmaxes and loss are float32.
-* every block is recomputed in the backward from its float32 input and
-  the flash kernel's output, log-sum-exp, q, k and v (``recompute``), as
-  ``models/ouro.py``'s: six blocks' activations at 8192 tokens do not fit
-  beside 11.3 GB of parameters and AdamW state.
+* every block is recomputed in the backward from its float32 input, the
+  attention branch's output and the flash kernel's output, log-sum-exp, q, k
+  and v (``recompute``; ``models/ouro.recomputed``), as ``models/ouro.py``'s:
+  six blocks' activations at 8192 tokens do not fit beside 11.3 GB of
+  parameters and AdamW state.
 
 As ``models/ouro.py`` the head is left to the step: ``hidden_only=True``
 returns ``(states [B, 2, S, d], statistics)`` (the trunk's and the MTP
@@ -68,7 +69,7 @@ import jax.numpy as jnp
 
 from distribuuuu_tpu.models.layers import head_dtype
 from distribuuuu_tpu.models.olmoe import RMSNorm, _attend, _normal, rotary
-from distribuuuu_tpu.models.ouro import MLP, kept_plan
+from distribuuuu_tpu.models.ouro import MLP, branch_out, kept_plan, recomputed
 from distribuuuu_tpu.models.share import (
     ShareOfALayer,
     mixture_metrics,
@@ -218,7 +219,10 @@ class Mixture(nn.Module):
 
 class Block(nn.Module):
     """One pre-norm block. ``mixture`` None: the dense MLP of width
-    ``mlp_hidden``; else the :class:`Mixture` it builds."""
+    ``mlp_hidden``; else the :class:`Mixture` it builds. Each branch's output
+    is named (``models/ouro.branch_out``): recomputed, the block keeps the
+    attention's, which the sum the second norm reads is made of, and not the
+    FFN's, which nothing in the backward reads."""
 
     attention: Any  # () -> MLA
     mixture: Any  # () -> Mixture, or None
@@ -230,16 +234,17 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, positions):
         with jax.named_scope("attn"):
-            x = x + self.attention(name="attn")(
-                RMSNorm(self.eps, name="attn_norm")(x), positions)
+            x = x + branch_out(self.attention(name="attn")(
+                RMSNorm(self.eps, name="attn_norm")(x), positions))
         if self.mixture is None:
             with jax.named_scope("mlp"):
-                x = x + MLP(self.dim, self.mlp_hidden, self.dtype, name="mlp")(
-                    RMSNorm(self.eps, name="mlp_norm")(x))
+                x = x + branch_out(
+                    MLP(self.dim, self.mlp_hidden, self.dtype, name="mlp")(
+                        RMSNorm(self.eps, name="mlp_norm")(x)))
             return x, {}
         with jax.named_scope("moe"):
             out, stats = self.mixture(name="moe")(RMSNorm(self.eps, name="moe_norm")(x))
-        return x + out, stats
+        return x + branch_out(out), stats
 
 
 _planned: set = set()
@@ -262,7 +267,8 @@ def _say_plan(model, batch: int, seq: int) -> None:
         vocab_held=model.vocab_held, vocab_total=model.vocab_size,
         **kept_plan(
             model, model.depth + model.mtp_layers, batch, seq, model.v_head_dim,
-            "every block, the MTP module's too"),
+            "every block, the MTP module's too",
+            branches=model.depth + model.mtp_layers),  # the attention's
     )
 
 
@@ -297,8 +303,9 @@ class GLMMoE(ShareOfALayer):
     dtype: Any = jnp.bfloat16
     attn_impl: str = "auto"
     mesh: Any = None
-    # a block keeps its float32 input and what the flash backward kernel
-    # reads (the forward's output, log-sum-exp, q, k and v), nothing else
+    # a block keeps its float32 input, its attention's output and what the
+    # flash backward kernel reads (the forward's output, log-sum-exp, q, k and
+    # v), nothing else
     recompute: bool = True
     # positions of every row the head takes at a time; its rows are the
     # batch's sequences twice over (the trunk's and the MTP module's)
@@ -328,11 +335,7 @@ class GLMMoE(ShareOfALayer):
             self.shared_experts, self.routed_scale, self.bias_rate, self.held,
             self.dtype, train, self.mesh,
         )
-        from distribuuuu_tpu.ops.flash_attention import KEPT_UNDER_REMAT
-
-        block = nn.remat(
-            Block, policy=jax.checkpoint_policies.save_only_these_names(
-                *KEPT_UNDER_REMAT)) if self.recompute else Block
+        block = recomputed(Block) if self.recompute else Block
 
         def make(name, dense):
             return block(

@@ -34,11 +34,11 @@ a decoder whose token mixer is chosen LAYER BY LAYER from the published
   the head, which autodiff adds.
 * the chip's share (``share_chips``, ``share_rank``), the stage, the block
   around a mixer and the recomputation policy (every block of either kind
-  keeps its float32 input and what the flash backward kernel reads,
-  ``KEPT_UNDER_REMAT``) are ``models/share.py``'s (:class:`PatternStack`,
-  ``Block``, ``run_blocks``), the float32 residual stream and parameters
-  ``models/glm_moe.py``'s; ``recompute = False`` keeps every activation
-  instead.
+  keeps its float32 input, its mixer's output and what the flash backward
+  kernel reads: ``models/ouro.recomputed``) are ``models/share.py``'s
+  (:class:`PatternStack`, ``Block``, ``run_blocks``), the float32 residual
+  stream and parameters ``models/glm_moe.py``'s; ``recompute = False`` keeps
+  every activation instead, and the names lower to nothing.
 
 ``hidden_only=True`` returns ``(states [B, S, d], the mixtures'
 statistics)`` and the step's loss is ``ShareOfALayer.head_loss``; a plain call
@@ -62,7 +62,6 @@ from distribuuuu_tpu.models.share import (
     PatternStack,
     pattern_kwargs_from_cfg,
     run_blocks,
-    say_plan,
     stacked,
 )
 from distribuuuu_tpu.models.traits import ArchTraits
@@ -178,9 +177,8 @@ class LFM2MoE(PatternStack):
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, hidden_only: bool = False):
-        B, S = tokens.shape
+        S = tokens.shape[1]
         self._check_input(tokens)
-        say_plan(self, B, S)
         embed = self._embedding()
         tokens = tokens - self.share_rank * self.vocab_held
         positions = jnp.arange(S, dtype=jnp.int32)

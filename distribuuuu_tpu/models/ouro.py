@@ -22,16 +22,19 @@ that says after which pass a token may leave.
 * ``MLP(n) = W_down(silu(W_gate n) * (W_up n))``, bias-free, dense.
 * parameters and the residual stream are float32, the matmuls read
   ``dtype``; norms, gate, exit distribution and loss are float32.
-* every block application is recomputed in the backward from its float32
-  input and, where the flash kernels ran, what the backward kernel reads:
-  the forward kernel's output and log-sum-exp and its q, k and v
-  (``ops/flash_attention.KEPT_UNDER_REMAT``: 16.3 + 48 MiB beside the
-  input's 32 at the cell's shape, so the recomputation never runs the
-  forward kernel, the three projections, rotary or the head layouts again:
-  it is the norms, ``W_o`` and the MLP); that is all it keeps
-  (``recompute``): R x L applications hold R times the activations a
-  parameter, and 32 of them at 4096 tokens do not fit a 16 GB chip beside
-  the AdamW state otherwise (PERF.md section 4).
+* every block application is recomputed in the backward (``recompute``,
+  :func:`recomputed`) from its float32 input, each branch's output as the
+  norm after it reads it (``branch_out``: that norm's backward is the one
+  reader of a branch's last matmul) and, where the flash kernels ran, what
+  the backward kernel reads: the forward kernel's output and log-sum-exp and
+  its q, k and v (``ops/flash_attention.KEPT_UNDER_REMAT``). At the cell's
+  shape that is 2 x 16 MiB and 16.3 + 48 MiB beside the input's 32, and the
+  recomputation never runs the forward kernel, the three projections,
+  rotary, the head layouts, ``W_o`` or ``down_proj`` again: it is the norms,
+  ``gate_proj``, ``up_proj`` and the gated product. That is all it keeps:
+  R x L applications hold R times the activations a parameter, and 32 of
+  them at 4096 tokens do not fit a 16 GB chip beside the AdamW state
+  otherwise (PERF.md section 4).
   Passes and layers are Python loops: a ``while`` shows in a device trace
   as one operation AND its body's, and every scope sum would count twice.
 
@@ -49,6 +52,7 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from distribuuuu_tpu.models.layers import head_dtype
 from distribuuuu_tpu.models.olmoe import (
@@ -79,6 +83,30 @@ class MLP(nn.Module):
         return proj("down_proj", self.dim)(gated)
 
 
+# the name a block gives the value each of its branches returns, as the next
+# operation reads it: kept where the block is recomputed, nothing otherwise
+BRANCH_OUT = "branch_out"
+
+
+def branch_out(x):
+    """``x``, a branch's output, named for :func:`recomputed`'s policy."""
+    return checkpoint_name(x, BRANCH_OUT)
+
+
+def recomputed(block):
+    """``block`` (a module class) recomputed in the backward: THE definition
+    of what a recomputed block keeps beside its input, for every decoder
+    that recomputes (``models/glm_moe.py`` and ``models/share.py`` too): what
+    the flash backward kernel reads and each branch's output. The norm or the
+    residual add after a branch is the one reader of its last matmul's
+    result in the backward, so with that kept the second forward stops short
+    of it; where nothing reads it, ``jax.checkpoint`` keeps nothing."""
+    from distribuuuu_tpu.ops.flash_attention import KEPT_UNDER_REMAT
+
+    return nn.remat(block, policy=jax.checkpoint_policies.save_only_these_names(
+        *KEPT_UNDER_REMAT, BRANCH_OUT))
+
+
 class Block(nn.Module):
     dim: int
     num_heads: int
@@ -98,14 +126,14 @@ class Block(nn.Module):
             return RMSNorm(self.eps, name=name)
 
         with jax.named_scope("attn"):
-            x = x + norm("attn_post_norm")(Attention(
+            x = x + norm("attn_post_norm")(branch_out(Attention(
                 self.dim, self.num_heads, self.eps, self.rope_theta, self.dtype,
                 self.attn_impl, self.mesh, qk_norm=False, name="attn",
-            )(norm("attn_norm")(x), positions))
+            )(norm("attn_norm")(x), positions)))
         with jax.named_scope("mlp"):
-            x = x + norm("mlp_post_norm")(
+            x = x + norm("mlp_post_norm")(branch_out(
                 MLP(self.dim, self.mlp_hidden, self.dtype, name="mlp")(
-                    norm("mlp_norm")(x)))
+                    norm("mlp_norm")(x))))
         return x
 
 
@@ -149,38 +177,46 @@ def _say_plan(model, batch: int, seq: int) -> None:
         block_applications=applications,
         **kept_plan(
             model, applications, batch, seq, model.dim // model.num_heads,
-            "every block application"),
+            "every block application", branches=2 * applications),
     )
 
 
 def kept_plan(model, blocks: int, batch: int, seq: int, head_dim: int,
-              what: str, flash_blocks: int | None = None) -> dict:
+              what: str, branches: int, flash_blocks: int | None = None) -> dict:
     """The fields of a plan record (``loop.plan``; ``share.plan`` of
-    ``models/glm_moe.py`` and ``models/lfm2_moe.py``) that say what ``blocks``
-    recomputed blocks keep a step: ``kept_bytes`` (their float32 inputs and
-    ``kept_flash_bytes``), ``kept_flash_bytes`` (the flash kernel's output
-    and log-sum-exp and its q, k and v, of the ``flash_blocks`` blocks that
-    hold attention, every one unless given, k and v at ``model.kv_heads``
-    heads where the model has fewer of them; 0 where attention takes another
-    path, which names nothing) and ``recomputed``."""
+    ``models/glm_moe.py`` and ``models/share.py``) that say what ``blocks``
+    recomputed blocks keep a step (:func:`recomputed`): ``kept_bytes`` (their
+    float32 inputs, ``kept_branch_bytes`` and ``kept_flash_bytes``),
+    ``kept_branch_bytes`` (the outputs of the ``branches`` branches, over all
+    the blocks, that the backward reads again: ``[tokens, dim]`` each in the
+    compute dtype), ``kept_flash_bytes`` (the flash kernel's output and
+    log-sum-exp and its q, k and v, of the ``flash_blocks`` blocks that hold
+    attention, every one unless given, k and v at ``model.kv_heads`` heads
+    where the model has fewer of them; 0 where attention takes another path,
+    which names nothing) and ``recomputed``."""
     if not model.recompute:
-        return {"kept_bytes": None, "kept_flash_bytes": None, "recomputed": "nothing"}
+        return {"kept_bytes": None, "kept_branch_bytes": None,
+                "kept_flash_bytes": None, "recomputed": "nothing"}
     from distribuuuu_tpu.models.vit import Attention as VitAttention
     from distribuuuu_tpu.ops.flash_attention import kept_under_remat_bytes
 
+    itemsize = jnp.dtype(model.dtype).itemsize
     flash = 0
     if VitAttention.resolve_impl(model.attn_impl, seq, 0.0) == "flash":
         flash = (blocks if flash_blocks is None else flash_blocks) * (
             kept_under_remat_bytes(
-                (batch, model.num_heads, seq, head_dim),
-                jnp.dtype(model.dtype).itemsize, model.mesh,
+                (batch, model.num_heads, seq, head_dim), itemsize, model.mesh,
                 kv_heads=getattr(model, "kv_heads", None)))
+    branch = branches * batch * seq * model.dim * itemsize
     return {
-        "kept_bytes": blocks * batch * seq * model.dim * 4 + flash,
+        "kept_bytes": blocks * batch * seq * model.dim * 4 + branch + flash,
+        "kept_branch_bytes": branch,
         "kept_flash_bytes": flash,
-        "recomputed": f"{what}, from its float32 input" + (
-            " and the flash kernel's output, log-sum-exp, q, k and v"
-            if flash else ""),
+        "recomputed": (
+            f"{what}, from its float32 input, the outputs of its branches "
+            "that are read again (whose last matmuls run once)" + (
+                " and the flash kernel's output, log-sum-exp, q, k and v"
+                if flash else "")),
     }
 
 
@@ -200,9 +236,9 @@ class Ouro(nn.Module):
     dtype: Any = jnp.bfloat16
     attn_impl: str = "auto"
     mesh: Any = None
-    # a block application keeps its input and what the flash backward kernel
-    # reads (the forward's output, log-sum-exp, q, k and v), nothing else
-    # (see above)
+    # a block application keeps its input, its two branches' outputs and what
+    # the flash backward kernel reads (the forward's output, log-sum-exp, q, k
+    # and v), nothing else (see above)
     recompute: bool = True
     # positions of every row the head takes at a time; its rows are the
     # batch's sequences R times over
@@ -222,11 +258,7 @@ class Ouro(nn.Module):
             embedding_init=_normal(),
         )(tokens)
         positions = jnp.arange(S, dtype=jnp.int32)
-        from distribuuuu_tpu.ops.flash_attention import KEPT_UNDER_REMAT
-
-        block = nn.remat(
-            Block, policy=jax.checkpoint_policies.save_only_these_names(
-                *KEPT_UNDER_REMAT)) if self.recompute else Block
+        block = recomputed(Block) if self.recompute else Block
         blocks = [
             block(
                 self.dim, self.num_heads, self.mlp_hidden, self.rms_norm_eps,

@@ -13,7 +13,9 @@ declare the same way (``models/glm_moe.py``, ``models/lfm2_moe.py``,
   around a mixer the model hands over, with a norm before each of its two
   parts and, where the model names them, one after each;
   :func:`run_blocks`, the stage under the recomputation policy
-  (``KEPT_UNDER_REMAT``); :func:`say_plan`, its ``share.plan`` record; and
+  (``models/ouro.recomputed``: a block keeps its float32 input, what the
+  flash backward kernel reads and each branch's output that the backward
+  reads again) and its ``share.plan`` record (:func:`say_plan`); and
   :func:`pattern_kwargs_from_cfg`.
 
 The mixture itself is ``models/glm_moe.Mixture`` and the mixers are the
@@ -30,7 +32,7 @@ import jax.numpy as jnp
 
 from distribuuuu_tpu.models.layers import head_dtype
 from distribuuuu_tpu.models.olmoe import RMSNorm, _normal, decoder_kwargs_from_cfg
-from distribuuuu_tpu.models.ouro import MLP, kept_plan
+from distribuuuu_tpu.models.ouro import MLP, branch_out, kept_plan, recomputed
 from distribuuuu_tpu.ops import token_head
 
 
@@ -141,7 +143,10 @@ class Block(nn.Module):
     None for the dense MLP. ``norms`` names the norm BEFORE each of the two
     parts; ``post_norms`` the one AFTER each, where the model has them (``h =
     x + N2(mixer(N1(x)))``, ``y = h + N4(F(N3(h)))``: ``models/afmoe.py``),
-    None where it has none."""
+    None where it has none. Each part's output is named
+    (``models/ouro.branch_out``): recomputed, the block keeps the mixer's
+    (the norm after it reads it, or the sum the FFN's norm reads is made of
+    it) and the FFN's where a norm follows it."""
 
     mixer: Any  # () -> the model's mixer for this layer
     mixer_scope: str  # the mixer's params' name and device scope
@@ -162,26 +167,27 @@ class Block(nn.Module):
             return t if name is None else RMSNorm(self.eps, name=name)(t)
 
         with jax.named_scope(self.mixer_scope):
-            x = x + norm(after_mixer, self.mixer(name=self.mixer_scope)(
-                norm(before_mixer, x), positions))
+            x = x + norm(after_mixer, branch_out(
+                self.mixer(name=self.mixer_scope)(norm(before_mixer, x), positions)))
         if self.mixture is None:
             with jax.named_scope("mlp"):
-                x = x + norm(after_ffn, MLP(
+                x = x + norm(after_ffn, branch_out(MLP(
                     self.dim, self.mlp_hidden, self.dtype, name="mlp")(
-                        norm(before_ffn, x)))
+                        norm(before_ffn, x))))
             return x, {}
         with jax.named_scope("moe"):
             out, stats = self.mixture(name="moe")(norm(before_ffn, x))
-            out = norm(after_ffn, out)
+            out = norm(after_ffn, branch_out(out))
         return x + out, stats
 
 
 _planned: set = set()
 
 
-def say_plan(model, batch: int, seq: int) -> None:
+def say_plan(model, batch: int, seq: int, post_norms: tuple) -> None:
     """One ``share.plan`` record a shape, at trace time, as
-    ``models/glm_moe.py``'s, with the layer kinds the model built."""
+    ``models/glm_moe.py``'s, with the layer kinds the model built
+    (``post_norms``: :class:`Block`'s)."""
     kinds = model.layer_kinds
     key = (type(model).__name__, model.share_chips, model.share_rank, kinds,
            model.dense_layers, batch, seq, model.recompute)
@@ -198,6 +204,8 @@ def say_plan(model, batch: int, seq: int) -> None:
         **kept_plan(
             model, len(kinds), batch, seq, model.attn_head_dim,
             "every block of either kind",
+            # the mixer's output, and the FFN's where a norm reads it
+            branches=len(kinds) * (1 + (post_norms[1] is not None)),
             flash_blocks=sum(model.KINDS[kind] == "attn" for kind in kinds)),
     )
 
@@ -207,14 +215,11 @@ def run_blocks(model, x, positions, mixers: dict, mixture, norms,
     """``x`` through the blocks of ``model``'s stage (a :class:`PatternStack`,
     inside its compact ``__call__``; ``mixers``: ``layer_types``' word -> the
     mixer's factory; ``norms`` and ``post_norms`` are :class:`Block`'s), each
-    recomputed in the backward under ``KEPT_UNDER_REMAT`` where
-    ``model.recompute``: ``(x, the mixtures' statistics, one dict a
-    mixture)``."""
-    from distribuuuu_tpu.ops.flash_attention import KEPT_UNDER_REMAT
-
-    block = nn.remat(
-        Block, policy=jax.checkpoint_policies.save_only_these_names(
-            *KEPT_UNDER_REMAT)) if model.recompute else Block
+    recomputed in the backward (``models/ouro.recomputed``) where
+    ``model.recompute``, and said once a shape (:func:`say_plan`): ``(x, the
+    mixtures' statistics, one dict a mixture)``."""
+    say_plan(model, *x.shape[:2], post_norms)
+    block = recomputed(Block) if model.recompute else Block
     stats = []
     for i, kind in enumerate(model.layer_kinds):
         x, s = block(

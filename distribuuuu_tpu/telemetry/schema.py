@@ -159,12 +159,13 @@ KINDS: dict[str, frozenset] = {
     "kernel.fallback": frozenset({"op", "requested", "reason"}),
     # one per traced shape of a looped stack (models/ouro.py): R passes over
     # L blocks, what the R x L block applications keep for the backward
-    # (bytes a step: their float32 inputs and kept_flash_bytes, the flash
+    # (bytes a step: their float32 inputs, kept_branch_bytes, the outputs of
+    # the branches the backward reads again, and kept_flash_bytes, the flash
     # kernel's output, log-sum-exp, q, k and v, 0 where the scan path ran)
     # and what the backward computes again
     "loop.plan": frozenset(
         {"layers", "passes", "block_applications", "kept_bytes",
-         "kept_flash_bytes", "recomputed"}
+         "kept_branch_bytes", "kept_flash_bytes", "recomputed"}
     ),
     # one per traced shape of a model that is one chip's share of an
     # expert-parallel group (models/glm_moe.py): how many chips share each
@@ -175,7 +176,8 @@ KINDS: dict[str, frozenset] = {
     # and dense_layers (how many of them carry the dense MLP)
     "share.plan": frozenset(
         {"share_chips", "share_rank", "experts_held", "experts_total", "vocab_held",
-         "vocab_total", "kept_bytes", "kept_flash_bytes", "recomputed"}
+         "vocab_total", "kept_bytes", "kept_branch_bytes", "kept_flash_bytes",
+         "recomputed"}
     ),
     # -- live observability plane (telemetry/live.py, tools/monitor.py) --
     # one windowed aggregate per monitor tick (MONITOR.jsonl)

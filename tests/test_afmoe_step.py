@@ -234,7 +234,10 @@ def test_the_model_says_its_layer_kinds_and_the_windowed_flash_its_window_once_a
            "layer_kinds": ["sliding_attention", "sliding_attention", "full_attention"],
            "dense_layers": 1}
     assert "every block of either kind" in plans[0]["recomputed"]
-    assert plans[0]["kept_bytes"] == 3 * 3 * 24 * 64 * 4  # the scan path names nothing
+    # the scan path names nothing; a block keeps its float32 input and both
+    # its branches' outputs (float32 here): a norm follows each
+    assert plans[0]["kept_branch_bytes"] == 2 * 3 * 3 * 24 * 64 * 4
+    assert plans[0]["kept_bytes"] == 3 * 3 * 3 * 24 * 64 * 4
     chose = [r for r in lines if r.get("kind") == "kernel.select"
              and r["op"] == "flash_attn" and r["impl"] == "pallas"]
     assert chose and (chose[-1]["window"], chose[-1]["kv_group"]) == (24, 4)
@@ -250,8 +253,11 @@ def test_the_model_says_its_layer_kinds_and_the_windowed_flash_its_window_once_a
         one = fa.kept_under_remat_bytes((2, 32, 8192, 128), 2, kv_heads=4)
         from distribuuuu_tpu.models.ouro import kept_plan
 
-        kept = kept_plan(cell, 5, 2, 8192, cell.attn_head_dim, "x", flash_blocks=5)
+        kept = kept_plan(
+            cell, 5, 2, 8192, cell.attn_head_dim, "x", branches=10, flash_blocks=5)
     assert kept["kept_flash_bytes"] == 5 * one
+    assert kept["kept_branch_bytes"] == 10 * 2 * 8192 * 2048 * 2  # 0.625 GiB
+    assert kept["kept_bytes"] == 5 * 2 * 8192 * 2048 * (4 + 2 * 2) + 5 * one
     assert one == 2 * (32 * (8192 * 128 * 2 + 8192 * 4 + 8192 * 128 * 2)
                        + 2 * 4 * 8192 * 128 * 2)
 

@@ -351,9 +351,10 @@ def test_what_the_recomputed_blocks_keep_of_the_flash_kernel_changes_no_bit(monk
     """With the kernels run (the interpreter, forced, where ``auto`` runs
     them compiled on the chip) a recomputed block keeps what the backward
     kernel reads, the forward kernel's output and log-sum-exp and its q, k
-    and v: a block, the MTP module's too, runs the forward kernel and the
-    two projections out of the latents (``q_b_proj``, ``kv_b_proj``) once,
-    where a plain ``nn.remat`` (the policy keeping nothing) runs them
+    and v, and the attention branch's output: a block, the MTP module's
+    too, runs the forward kernel, the two projections out of the latents
+    (``q_b_proj``, ``kv_b_proj``) and ``o_proj`` once, where a plain
+    ``nn.remat`` (the policy keeping nothing) runs them
     twice, and the loss and every gradient leaf are that step's bit for
     bit: what is kept is what was recomputed. Against the step that
     recomputes nothing the loss is the same bits and the gradients are as
@@ -372,8 +373,13 @@ def test_what_the_recomputed_blocks_keep_of_the_flash_kernel_changes_no_bit(monk
     others = {leaf.shape for path, leaf in jax.tree_util.tree_leaves_with_path(params)
               if "_b_proj" not in jax.tree_util.keystr(path)}
     assert len(out_of_the_latents) == 2 and not out_of_the_latents & others
+    # W_o's shape is the MTP module's ``mtp_proj``'s too, which no block holds
+    w_o = {attn["o_proj"]["kernel"].shape}
+    assert [jax.tree_util.keystr(path)[-30:] for path, leaf in
+            jax.tree_util.tree_leaves_with_path(params)
+            if leaf.shape in w_o].count("['mtp_proj']['kernel']") == 1
 
-    def run(variant, forward_calls, projections):
+    def run(variant, forward_calls, projections, outputs):
         def loss(p):
             return program_loss(variant, p, biases, tokens, labels)[0]
 
@@ -382,14 +388,15 @@ def test_what_the_recomputed_blocks_keep_of_the_flash_kernel_changes_no_bit(monk
         assert text.count("name=dtpu_flash_fwd") == forward_calls
         assert text.count("name=dtpu_flash_bwd") == blocks
         assert forward_matmuls(traced.jaxpr.jaxpr, out_of_the_latents) == projections
+        assert forward_matmuls(traced.jaxpr.jaxpr, w_o) == outputs + 1
         return traced.lower().compile()(params)
 
-    kept = run(model, blocks, 2 * blocks)
-    nothing_recomputed = run(model.clone(recompute=False), blocks, 2 * blocks)
+    kept = run(model, blocks, 2 * blocks, blocks)
+    nothing_recomputed = run(model.clone(recompute=False), blocks, 2 * blocks, blocks)
     monkeypatch.setattr(
         jax.checkpoint_policies, "save_only_these_names",
         lambda *names: jax.checkpoint_policies.nothing_saveable)
-    plain = run(model, 2 * blocks, 4 * blocks)
+    plain = run(model, 2 * blocks, 4 * blocks, 2 * blocks)
     assert float(kept[0]) == float(plain[0]) == float(nothing_recomputed[0])
     flat = jax.tree_util.tree_leaves_with_path(kept[1])
     for (path, got), want in zip(flat, jax.tree.leaves(plain[1]), strict=True):
@@ -402,9 +409,10 @@ def test_what_the_recomputed_blocks_keep_of_the_flash_kernel_changes_no_bit(monk
 def test_the_plan_says_what_the_cells_blocks_keep(tmp_path, monkeypatch, engaged):
     """``share.plan`` at ``glm_4_7_flash.train_seq8192``'s shape (1 + 4
     layers and the MTP module, 1 x 8192 tokens, 20 heads of 256): six
-    float32 inputs of 64 MiB and, where the flash kernel runs, 6 x (80 MiB
-    of output + 0.625 MiB of log-sum-exp + 3 x 80 MiB of q, k and v);
-    nothing of it on the scan path."""
+    float32 inputs of 64 MiB, the six attention branches' outputs of 32 MiB
+    (bfloat16; the FFN branches' are read by nothing) and, where the flash
+    kernel runs, 6 x (80 MiB of output + 0.625 MiB of log-sum-exp + 3 x 80
+    MiB of q, k and v); nothing of the kernel's on the scan path."""
     import json
 
     from distribuuuu_tpu.ops import pallas as tier
@@ -429,8 +437,10 @@ def test_the_plan_says_what_the_cells_blocks_keep(tmp_path, monkeypatch, engaged
     schema.validate_record(plan)
     assert (plan["experts_held"], plan["vocab_held"]) == (8, 19360)
     assert plan["kept_flash_bytes"] == (2_017_198_080 if engaged else 0)
-    assert plan["kept_bytes"] == 6 * 8192 * 2048 * 4 + plan["kept_flash_bytes"]
-    said = "every block, the MTP module's too, from its float32 input"
+    assert plan["kept_branch_bytes"] == 6 * 8192 * 2048 * 2
+    assert plan["kept_bytes"] == 6 * 8192 * 2048 * (4 + 2) + plan["kept_flash_bytes"]
+    said = ("every block, the MTP module's too, from its float32 input, the outputs "
+            "of its branches that are read again (whose last matmuls run once)")
     assert plan["recomputed"] == said + (
         " and the flash kernel's output, log-sum-exp, q, k and v" if engaged else "")
 

@@ -222,6 +222,10 @@ def test_the_share_says_its_plan_and_the_experts_their_bound_once_a_shape(tmp_pa
     )} == {"share_chips": 2, "share_rank": 1, "experts_held": 4, "experts_total": 8,
            "vocab_held": 256, "vocab_total": 512}
     assert "every block" in plans[0]["recomputed"]
+    # 2 blocks and the MTP module's, 3 x 24 tokens, float32 here: an input
+    # and the attention's output a block
+    assert plans[0]["kept_branch_bytes"] == 3 * 3 * 24 * 64 * 4
+    assert plans[0]["kept_bytes"] == 2 * 3 * 3 * 24 * 64 * 4
     # the experts' record: a share's fields beside the tiles
     chose = [r for r in lines if r.get("kind") == "kernel.select" and r["op"] == "moe_gmm"]
     assert chose and chose[-1]["impl"] == "xla"  # the CPU: ragged_dot

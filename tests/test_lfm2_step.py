@@ -227,7 +227,10 @@ def test_the_model_says_its_layer_kinds_and_the_grouped_flash_its_group_once_a_s
            "vocab_held": 256, "vocab_total": 512,
            "layer_kinds": ["conv", "full_attention", "conv"], "dense_layers": 1}
     assert "every block of either kind" in plans[0]["recomputed"]
-    assert plans[0]["kept_bytes"] == 3 * 3 * 24 * 64 * 4  # the scan path names nothing
+    # the scan path names nothing; a block keeps its float32 input and its
+    # mixer's output (float32 here), not its FFN's: no norm follows it
+    assert plans[0]["kept_branch_bytes"] == 3 * 3 * 24 * 64 * 4
+    assert plans[0]["kept_bytes"] == 2 * 3 * 3 * 24 * 64 * 4
     chose = [r for r in lines if r.get("kind") == "kernel.select"
              and r["op"] == "flash_attn" and r["impl"] == "pallas"]
     assert chose and (chose[-1]["kv_group"], chose[-1]["kv_heads"]) == (2, 2)

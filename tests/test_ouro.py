@@ -254,7 +254,7 @@ def test_the_recomputing_step_equals_the_step_that_keeps_everything():
 
     model = build(depth=2)
     params, tokens, labels = seeded(model)
-    out, kept = {}, {}
+    out, kept, named = {}, {}, {}
     for recompute in (True, False):
         variant = model.clone(recompute=recompute)
 
@@ -262,19 +262,25 @@ def test_the_recomputing_step_equals_the_step_that_keeps_everything():
             return program_loss(variant, p, tokens, labels)[0]
 
         out[recompute] = jax.value_and_grad(loss)(params)
-        kept[recompute] = [tuple(aval.shape) for aval, _ in saved_residuals(loss, params)]
+        residuals = saved_residuals(loss, params)
+        kept[recompute] = [tuple(aval.shape) for aval, _ in residuals]
+        named[recompute] = [tuple(aval.shape) for aval, why in residuals
+                            if ouro.BRANCH_OUT in why]
     np.testing.assert_allclose(out[True][0], out[False][0], rtol=1e-6)
     assert_trees_close(out[True][1], out[False][1], 1e-5)
     # what the forward keeps for the backward: with recomputation an input a
-    # block application (and the few states between passes), nothing of the
-    # MLP's width and no attention scores; without it, all of them
+    # block application, its two branches' outputs as the post-norms read
+    # them (and the few states between passes), nothing of the MLP's width
+    # and no attention scores; without it, all of them
     B, S = tokens.shape
     stream, applications = (B, S, model.dim), model.depth * model.passes
 
     def activations(shapes, width):
         return sum(s == (B, S, width) for s in shapes)
 
-    assert applications <= kept[True].count(stream) <= applications + 4 * model.passes + 1
+    assert named[True] == [stream] * 2 * applications
+    assert 3 * applications <= kept[True].count(stream) <= (
+        3 * applications + 4 * model.passes + 1)
     assert activations(kept[True], model.mlp_hidden) == 0
     assert not any(s[-2:] == (S, S) for s in kept[True])
     assert kept[False].count(stream) > 10 * applications
@@ -285,10 +291,11 @@ def test_what_the_recomputed_blocks_keep_of_the_flash_kernel_changes_no_bit(monk
     """With the kernels run (the interpreter, forced, where ``auto`` runs
     them compiled on the chip) a recomputed block keeps what the backward
     kernel reads, the forward kernel's output and log-sum-exp and its q, k
-    and v: a block application runs the forward kernel and the three
-    projections once (its four ``[dim, dim]`` matmuls and ``W_o`` again),
-    where a plain ``nn.remat`` (the policy keeping nothing) runs all of it
-    twice, and the loss and every gradient leaf are that step's bit for
+    and v, and each branch's output: a block application runs the forward
+    kernel, the three projections and ``W_o`` once (its four ``[dim, dim]``
+    matmuls) and the MLP's ``down_proj`` once, where a plain ``nn.remat``
+    (the policy keeping nothing) runs all of it twice, and the loss and
+    every gradient leaf are that step's bit for
     bit: what is kept is what was recomputed. Against the step that
     recomputes nothing the loss is the same bits and the gradients are as
     near as they were before anything was kept (jax sums a value's several
@@ -302,7 +309,7 @@ def test_what_the_recomputed_blocks_keep_of_the_flash_kernel_changes_no_bit(monk
     blocks = model.depth * model.passes
     assert model.mlp_hidden != model.dim  # q, k, v and W_o alone are [dim, dim]
 
-    def run(variant, forward_calls, projections):
+    def run(variant, forward_calls, projections, down_projs):
         def loss(p):
             return program_loss(variant, p, tokens, labels)[0]
 
@@ -312,14 +319,16 @@ def test_what_the_recomputed_blocks_keep_of_the_flash_kernel_changes_no_bit(monk
         assert text.count("name=dtpu_flash_bwd") == blocks
         assert forward_matmuls(
             traced.jaxpr.jaxpr, {(model.dim, model.dim)}) == projections
+        assert forward_matmuls(
+            traced.jaxpr.jaxpr, {(model.mlp_hidden, model.dim)}) == down_projs
         return traced.lower().compile()(params)
 
-    kept = run(model, blocks, 5 * blocks)
-    nothing_recomputed = run(model.clone(recompute=False), blocks, 4 * blocks)
+    kept = run(model, blocks, 4 * blocks, blocks)
+    nothing_recomputed = run(model.clone(recompute=False), blocks, 4 * blocks, blocks)
     monkeypatch.setattr(
         jax.checkpoint_policies, "save_only_these_names",
         lambda *names: jax.checkpoint_policies.nothing_saveable)
-    plain = run(model, 2 * blocks, 8 * blocks)
+    plain = run(model, 2 * blocks, 8 * blocks, 2 * blocks)
     assert float(kept[0]) == float(plain[0]) == float(nothing_recomputed[0])
     flat = jax.tree_util.tree_leaves_with_path(kept[1])
     for (path, got), want in zip(flat, jax.tree.leaves(plain[1]), strict=True):
@@ -332,10 +341,11 @@ def test_what_the_recomputed_blocks_keep_of_the_flash_kernel_changes_no_bit(monk
 def test_the_plan_says_what_the_cells_block_applications_keep(
         tmp_path, monkeypatch, engaged):
     """``loop.plan`` at ``ouro_2_6b.train_seq4096``'s shape (8 layers, 4
-    passes, 1 x 4096 tokens): 32 float32 inputs of 32 MiB and, where the
-    flash kernel runs, 32 x (16 MiB of output + 0.25 MiB of log-sum-exp +
-    3 x 16 MiB of q, k and v); where the scan runs in its place nothing is
-    named and nothing kept."""
+    passes, 1 x 4096 tokens): 32 float32 inputs of 32 MiB, 2 x 32 branch
+    outputs of 16 MiB (bfloat16) and, where the flash kernel runs, 32 x (16
+    MiB of output + 0.25 MiB of log-sum-exp + 3 x 16 MiB of q, k and v);
+    where the scan runs in its place the kernel names nothing and nothing of
+    it is kept."""
     import json
 
     from distribuuuu_tpu.ops import pallas as tier
@@ -360,13 +370,17 @@ def test_the_plan_says_what_the_cells_block_applications_keep(
     inputs = 32 * 4096 * 2048 * 4
     assert plan["block_applications"] == 32
     assert plan["kept_flash_bytes"] == (2_155_872_256 if engaged else 0)
-    assert plan["kept_bytes"] == inputs + plan["kept_flash_bytes"]
-    said = "every block application, from its float32 input"
+    assert plan["kept_branch_bytes"] == 2 * 32 * 4096 * 2048 * 2 == 2**30
+    assert plan["kept_bytes"] == inputs + 2**30 + plan["kept_flash_bytes"]
+    said = ("every block application, from its float32 input, the outputs of "
+            "its branches that are read again (whose last matmuls run once)")
     assert plan["recomputed"] == said + (
         " and the flash kernel's output, log-sum-exp, q, k and v" if engaged else "")
-    nothing = ouro.kept_plan(model.clone(recompute=False), 32, 1, 4096, 128, "")
+    nothing = ouro.kept_plan(
+        model.clone(recompute=False), 32, 1, 4096, 128, "", branches=64)
     assert nothing == {
-        "kept_bytes": None, "kept_flash_bytes": None, "recomputed": "nothing"}
+        "kept_bytes": None, "kept_branch_bytes": None, "kept_flash_bytes": None,
+        "recomputed": "nothing"}
 
 
 def test_bfloat16_program_stays_near_the_reference_because_its_float32_parts_do():

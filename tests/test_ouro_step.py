@@ -170,12 +170,14 @@ def test_the_loop_says_its_plan_once_a_shape(tmp_path):
         spans.close_telemetry()
     records = [json.loads(line) for name in os.listdir(tmp_path)
                for line in open(tmp_path / name) if '"loop.plan"' in line]
-    records = [r for r in records if r["kept_bytes"] != 8 * 1 * 8 * 64 * 4]  # init's
+    records = [r for r in records if r["kept_bytes"] != 3 * 8 * 1 * 8 * 64 * 4]  # init's
     assert len(records) == 1
     schema.check_fields("loop.plan", records[0])
     assert (records[0]["layers"], records[0]["passes"],
             records[0]["block_applications"]) == (2, 4, 8)
-    assert records[0]["kept_bytes"] == 8 * 3 * 24 * 64 * 4
+    # float32 here: an input and two branches' outputs a block application
+    assert records[0]["kept_branch_bytes"] == 2 * 8 * 3 * 24 * 64 * 4
+    assert records[0]["kept_bytes"] == 3 * 8 * 3 * 24 * 64 * 4
 
 
 def test_train_net_trains_the_yaml_at_a_tiny_size_resumes_and_validates(

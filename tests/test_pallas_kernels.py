@@ -766,8 +766,8 @@ def test_run_report_prints_a_shares_experts_and_its_plan(tmp_path, capsys):
 def test_run_report_prints_what_the_recomputed_blocks_keep(tmp_path, capsys, kind, where):
     """Both plan records in the ``kernels`` section with what their
     recomputed blocks keep a step: the bytes in all and, of them, the flash
-    kernel's output, log-sum-exp, q, k and v (``ouro_2_6b.train_seq4096``'s
-    numbers)."""
+    kernel's output, log-sum-exp, q, k and v and the branches' outputs
+    (``ouro_2_6b.train_seq4096``'s numbers)."""
     import run_report
 
     plan = {
@@ -782,7 +782,8 @@ def test_run_report_prints_what_the_recomputed_blocks_keep(tmp_path, capsys, kin
         {"kind": "kernel.select", "rank": 0, "t": 1.0, "op": "flash_attn",
          "impl": "pallas", "requested": "auto"},
         {"kind": kind, "rank": 0, "t": 1.0, **plan,
-         "kept_bytes": 32 * 2**25 + 2155872256, "kept_flash_bytes": 2155872256,
+         "kept_bytes": 32 * 2**25 + 2**30 + 2155872256, "kept_branch_bytes": 2**30,
+         "kept_flash_bytes": 2155872256,
          "recomputed": "every block, from its float32 input and the flash "
                        "kernel's output, log-sum-exp, q, k and v"},
         {"kind": "span", "rank": 0, "t": 1.0, "v": 1, "name": "step",
@@ -799,8 +800,8 @@ def test_run_report_prints_what_the_recomputed_blocks_keep(tmp_path, capsys, kin
     printed = capsys.readouterr().out
     assert where in printed
     assert "recomputed: every block, from its float32 input and the flash" in printed
-    assert ("kept 3080.0 MiB a step, 2056.0 of them the flash kernel's output, "
-            "log-sum-exp, q, k and v") in printed
+    assert ("kept 4104.0 MiB a step, 2056.0 of them the flash kernel's output, "
+            "log-sum-exp, q, k and v, 1024.0 the branches' outputs") in printed
 
 
 def test_bench_index_kernel_series_and_resnet50_reference(chip_bench_root):

@@ -244,6 +244,55 @@ def test_grouped_flash_compiles_for_the_v5e_without_a_repeated_key_or_value(v5e_
     assert fa.fits_vmem(8192, 64)
 
 
+def _step_for_the_v5e(v5e_chip, monkeypatch, yaml: str, batch: tuple, **lm):
+    """``config/<yaml>.yaml``'s train step at ``batch`` tokens (sequences,
+    length) with the ``LM`` keys ``lm`` over it, compiled for the chip:
+    ``(what lowering.lower returned, the abstract state, the executable)``."""
+    from jax.sharding import SingleDeviceSharding
+
+    import distribuuuu_tpu.config as config
+    from distribuuuu_tpu import trainer
+    from distribuuuu_tpu.config import cfg
+    from distribuuuu_tpu.ops import pallas as kernel_tier
+    from distribuuuu_tpu.parallel import mesh as mesh_lib
+    from distribuuuu_tpu.parallel.partition import lowering, topology
+    from distribuuuu_tpu.utils.optim import construct_optimizer
+
+    # the tier asks the live backend (a CPU with 8 devices here): steer it to
+    # what it resolves to on one chip
+    monkeypatch.setattr(kernel_tier, "interpret_mode", lambda: False)
+    monkeypatch.setattr(kernel_tier, "compiled_across_devices", lambda: False)
+    config.reset_cfg()
+    config.merge_from_file(f"config/{yaml}.yaml")
+    for key, value in lm.items():
+        setattr(cfg.LM, key, value)
+    cfg.MESH.DATA, cfg.KERNELS.OPT_UPDATE = 1, "pallas"
+    try:
+        layout = topology.from_cfg(cfg, n_devices=1)
+        lowered = lowering.lower(
+            trainer.build_model_from_cfg(layout), construct_optimizer(), 5,
+            mesh=mesh_lib.build_mesh(data=1, devices=[v5e_chip]),
+            topology=layout, im_size=cfg.TRAIN.IM_SIZE,
+        )
+        state, avals = lowered.abstract_args(batch[0])
+    finally:
+        config.reset_cfg()
+    chip = SingleDeviceSharding(v5e_chip)
+    avals = {k: jax.ShapeDtypeStruct(batch, jnp.int32, sharding=chip) for k in avals}
+    return lowered, state, lowered.train_step.lower(state, avals).compile()
+
+
+def _dtpu_calls(text: str) -> dict:
+    """Kernel name -> the ``op_name`` of each of its calls in a compiled
+    program's text."""
+    calls = {}
+    for line in text.splitlines():
+        if "custom-call(" in line and "dtpu_" in line:
+            name = line.split(" = ")[0].split()[-1].lstrip("%").rsplit(".", 1)[0]
+            calls.setdefault(name, []).append(line.split('op_name="')[1].split('"')[0])
+    return calls
+
+
 def test_lfm2_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
     """The step of ``lfm2_24b_a2b.train_seq8192`` (``config/lfm2_24b_a2b.yaml``:
     published widths, 2 x 8192 tokens, 8 of 64 experts and 8,192 vocabulary
@@ -254,40 +303,15 @@ def test_lfm2_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
     the grouped flash kernels and the six grouped matmuls by name, and no
     ``while``; with ``LM.RECOMPUTE`` both block kinds run again in the
     backward, without the flash forward kernel."""
-    from jax.sharding import SingleDeviceSharding
-
-    import distribuuuu_tpu.config as config
     from benchmark.harness.trace import in_scope, op_names_from_hlo
-    from distribuuuu_tpu import trainer
-    from distribuuuu_tpu.config import cfg
-    from distribuuuu_tpu.ops import pallas as kernel_tier
-    from distribuuuu_tpu.parallel import mesh as mesh_lib
-    from distribuuuu_tpu.parallel.partition import lowering, topology
-    from distribuuuu_tpu.utils.optim import construct_optimizer
 
-    monkeypatch.setattr(kernel_tier, "interpret_mode", lambda: False)
-    monkeypatch.setattr(kernel_tier, "compiled_across_devices", lambda: False)
-    config.reset_cfg()
-    config.merge_from_file("config/lfm2_24b_a2b.yaml")
-    cfg.LM.FIRST_LAYER, cfg.LM.LAYERS, cfg.LM.RECOMPUTE = 1, 2, True
-    cfg.MESH.DATA, cfg.KERNELS.OPT_UPDATE = 1, "pallas"
-    try:
-        layout = topology.from_cfg(cfg, n_devices=1)
-        lowered = lowering.lower(
-            trainer.build_model_from_cfg(layout), construct_optimizer(), 5,
-            mesh=mesh_lib.build_mesh(data=1, devices=[v5e_chip]),
-            topology=layout, im_size=cfg.TRAIN.IM_SIZE,
-        )
-        state, batch = lowered.abstract_args(2)
-    finally:
-        config.reset_cfg()
+    lowered, state, compiled = _step_for_the_v5e(
+        v5e_chip, monkeypatch, "lfm2_24b_a2b", (2, 8192),
+        FIRST_LAYER=1, LAYERS=2, RECOMPUTE=True)
     model = lowered.model
     assert model.layer_kinds == ("conv", "full_attention") and model.dense_here == 1
     assert model.held == (0, 8) and model.vocab_held == 8192
-    chip = SingleDeviceSharding(v5e_chip)
-    batch = {k: jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=chip)
-             for k in batch}
-    text = lowered.train_step.lower(state, batch).compile().as_text()
+    text = compiled.as_text()
     assert " while(" not in text and " conditional(" not in text
     assert "ragged-dot" not in text  # the held experts run the Pallas kernels
     paths = list(op_names_from_hlo(text).values())
@@ -300,11 +324,7 @@ def test_lfm2_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
     assert not any(p.endswith("dot_general") for p in gate)  # no matmul in it
     assert any(in_scope(p, "short_conv") and p.endswith("in_proj/dot_general")
                for p in paths)
-    calls = {}
-    for line in text.splitlines():
-        if "custom-call(" in line and "dtpu_" in line:
-            name = line.split(" = ")[0].split()[-1].lstrip("%").rsplit(".", 1)[0]
-            calls.setdefault(name, []).append(line.split('op_name="')[1].split('"')[0])
+    calls = _dtpu_calls(text)
     flash = {k: len(v) for k, v in calls.items() if "flash" in k}
     assert (flash["dtpu_flash_fwd"], flash["dtpu_flash_bwd"]) == (1, 1)
     assert not any(in_scope(p, "rematted_computation") or in_scope(p, "bwd")
@@ -314,8 +334,13 @@ def test_lfm2_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
     assert any(in_scope(p, "moe_route") for p in recomputed)
     # the kept q, k and v spare the recomputation v's projection, the rotary
     # and the head layouts; q's and k's projections run again, because the
-    # per-head norm that follows them reads their output in its own backward
-    assert not any("v_proj/dot_general" in p for p in recomputed)
+    # per-head norm that follows them reads their output in its own backward;
+    # the kept mixers' outputs spare it the conv's out_proj and W_o, and what
+    # nothing reads (the FFNs' outputs: no norm follows them) is not made
+    # again either: the dense MLP's down_proj and the combine
+    for last in ("v_proj", "out_proj", "o_proj", "down_proj"):
+        assert any(f"{last}/dot_general" in p for p in paths), last
+        assert not any(f"{last}/dot_general" in p for p in recomputed), last
     for proj in ("q_proj", "k_proj"):
         assert any(f"{proj}/dot_general" in p for p in recomputed), proj
     gmm = {k: len(v) for k, v in calls.items() if "moe_gmm" in k}
@@ -367,17 +392,21 @@ def test_windowed_flash_compiles_for_the_v5e_at_a_group_of_eight(v5e_chip):
 def _movers_of_held_mixtures(calls, in_scope, mixtures: int, normed_after=False):
     """A recomputed held mixture moves its rows through ``ops/pallas/
     moe_rows``: ``take`` forward, again under recomputation and as the
-    combine's backward; ``combine`` forward and as the take's backward (the
-    recomputation has no use for the mixture's output, unless a norm follows
-    it, ``normed_after``: that norm's backward reads it, so the combine runs
-    a third time); a ``pack`` before each; all under ``moe_route``, where the
-    benchmark's readers sum them."""
+    combine's backward; ``combine`` forward and as the take's backward, and
+    never under recomputation: it has no use for the mixture's output, and
+    where a norm follows the mixture (``normed_after``, Trinity-Mini's), whose
+    backward reads that output, the block keeps it (``models/ouro.recomputed``;
+    before PR 45 the combine ran a third time there); a ``pack`` before each;
+    all under ``moe_route``, where the benchmark's readers sum them."""
+    del normed_after  # the same calls either way
     movers = {k: len(v) // mixtures for k, v in calls.items() if "moe_rows" in k}
-    assert movers == {"dtpu_moe_rows_take": 3, "dtpu_moe_rows_combine": 2 + normed_after,
-                      "dtpu_moe_rows_pack": 5 + normed_after}, movers
+    assert movers == {"dtpu_moe_rows_take": 3, "dtpu_moe_rows_combine": 2,
+                      "dtpu_moe_rows_pack": 5}, movers
     for name in movers:
         assert all(in_scope(p, "moe_route") and not in_scope(p, "moe_experts")
                    for p in calls[name]), name
+    assert not any(in_scope(p, "rematted_computation")
+                   for p in calls["dtpu_moe_rows_combine"])
 
 
 def _olmoe_experts(tokens=16384, d=2048, f=1024, experts=64, top=8):
@@ -531,51 +560,21 @@ def test_ouro_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
     1 of the cell's 8 layers (8 compile in five minutes here, 1 in under
     one). What the benchmark's readers find in it: every scope they sum, the
     three flash kernels by name, the recomputed forward by the ``op_name``
-    ``jax.checkpoint``'s transpose gives it, with NO forward kernel and no
-    projection of q, k or v in it, and no ``while`` (a loop in a device trace
-    is one operation AND its body's)."""
-    from jax.sharding import SingleDeviceSharding
-
-    import distribuuuu_tpu.config as config
+    ``jax.checkpoint``'s transpose gives it, with NO forward kernel, no
+    projection of q, k or v and neither branch's last matmul (``o_proj``,
+    ``down_proj``: the block keeps what they made) in it, and no ``while`` (a
+    loop in a device trace is one operation AND its body's)."""
     from benchmark.harness.trace import in_scope, op_names_from_hlo
-    from distribuuuu_tpu import trainer
-    from distribuuuu_tpu.config import cfg
-    from distribuuuu_tpu.ops import pallas as kernel_tier
-    from distribuuuu_tpu.parallel import mesh as mesh_lib
-    from distribuuuu_tpu.parallel.partition import lowering, topology
-    from distribuuuu_tpu.utils.optim import construct_optimizer
 
-    # the tier asks the live backend (a CPU with 8 devices here): steer it to
-    # what it resolves to on one chip
-    monkeypatch.setattr(kernel_tier, "interpret_mode", lambda: False)
-    monkeypatch.setattr(kernel_tier, "compiled_across_devices", lambda: False)
-    config.reset_cfg()
-    config.merge_from_file("config/ouro_2_6b.yaml")
-    cfg.LM.LAYERS, cfg.MESH.DATA, cfg.KERNELS.OPT_UPDATE = 1, 1, "pallas"
-    try:
-        layout = topology.from_cfg(cfg, n_devices=1)
-        lowered = lowering.lower(
-            trainer.build_model_from_cfg(layout), construct_optimizer(), 5,
-            mesh=mesh_lib.build_mesh(data=1, devices=[v5e_chip]),
-            topology=layout, im_size=cfg.TRAIN.IM_SIZE,
-        )
-        state, batch = lowered.abstract_args(1)
-    finally:
-        config.reset_cfg()
-    chip = SingleDeviceSharding(v5e_chip)
-    batch = {k: jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=chip)
-             for k in batch}
-    text = lowered.train_step.lower(state, batch).compile().as_text()
+    _, state, compiled = _step_for_the_v5e(
+        v5e_chip, monkeypatch, "ouro_2_6b", (1, 4096), LAYERS=1)
+    text = compiled.as_text()
     assert " while(" not in text and " conditional(" not in text
     paths = list(op_names_from_hlo(text).values())
     for scope in ("fwd", "bwd", "attn", "mlp", "exit_gate", "lm_head",
                   "optimizer_update", "opt_kernel"):
         assert any(in_scope(p, scope) for p in paths), scope
-    calls = {}
-    for line in text.splitlines():
-        if "custom-call(" in line and "dtpu_" in line:
-            name = line.split(" = ")[0].split()[-1].lstrip("%").rsplit(".", 1)[0]
-            calls.setdefault(name, []).append(line.split('op_name="')[1].split('"')[0])
+    calls = _dtpu_calls(text)
     # 4 block applications: the forward kernel runs ONCE each, in the forward
     # (the block keeps its output, log-sum-exp, q, k and v, so the backward's
     # recomputation has no use for it), the one backward kernel once (and the
@@ -590,15 +589,20 @@ def test_ouro_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
         assert all(in_scope(p, "bwd") and in_scope(p, "attn") for p in calls[kernel])
     assert not any(in_scope(p, "rematted_computation")
                    for kernel in calls if "flash" in kernel for p in calls[kernel])
-    # the recomputed forward is the blocks' alone, less the kernel AND what
-    # made its inputs (a block application keeps q, k and v as the kernels
-    # take them): the norms, W_o and the MLP, no projection of q, k or v
-    # (their weights' casts remain, for the projections' own dx), no head
+    # the recomputed forward is the blocks' alone, less the kernel, what made
+    # its inputs (a block application keeps q, k and v as the kernels take
+    # them) AND the last matmul of each branch (it keeps the branch's output,
+    # which the post-norm's backward alone reads): the four norms, gate_proj,
+    # up_proj and the gated product; no projection of q, k or v (their
+    # weights' casts remain, for the projections' own dx), no W_o, no
+    # down_proj, no head
     recomputed = [p for p in paths if in_scope(p, "rematted_computation")]
-    assert any(in_scope(p, "mlp") and p.endswith("dot_general") for p in recomputed)
-    for still in ("attn_norm", "attn_post_norm", "o_proj/dot_general"):
+    for still in ("attn_norm", "attn_post_norm"):
         assert any(in_scope(p, "attn") and still in p for p in recomputed), still
-    for proj in ("q_proj", "k_proj", "v_proj"):
+    for still in ("mlp_norm", "mlp_post_norm", "gate_proj/dot_general",
+                  "up_proj/dot_general"):
+        assert any(in_scope(p, "mlp") and still in p for p in recomputed), still
+    for proj in ("q_proj", "k_proj", "v_proj", "o_proj", "down_proj"):
         assert any(f"{proj}/dot_general" in p for p in paths), proj
         assert not any(f"{proj}/dot_general" in p for p in recomputed), proj
     assert not any(in_scope(p, "lm_head") or in_scope(p, "exit_gate") for p in recomputed)
@@ -612,37 +616,11 @@ def test_glm_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
     benchmark's readers find in it: every scope they sum, the flash kernels
     at head dim 256 and the six grouped matmuls on the held experts by name,
     the recomputed forward, and no ``while``."""
-    from jax.sharding import SingleDeviceSharding
-
-    import distribuuuu_tpu.config as config
     from benchmark.harness.trace import in_scope, op_names_from_hlo
-    from distribuuuu_tpu import trainer
-    from distribuuuu_tpu.config import cfg
-    from distribuuuu_tpu.ops import pallas as kernel_tier
-    from distribuuuu_tpu.parallel import mesh as mesh_lib
-    from distribuuuu_tpu.parallel.partition import lowering, topology
-    from distribuuuu_tpu.utils.optim import construct_optimizer
 
-    monkeypatch.setattr(kernel_tier, "interpret_mode", lambda: False)
-    monkeypatch.setattr(kernel_tier, "compiled_across_devices", lambda: False)
-    config.reset_cfg()
-    config.merge_from_file("config/glm_4_7_flash.yaml")
-    cfg.LM.LAYERS, cfg.MESH.DATA, cfg.KERNELS.OPT_UPDATE = 2, 1, "pallas"
-    try:
-        layout = topology.from_cfg(cfg, n_devices=1)
-        lowered = lowering.lower(
-            trainer.build_model_from_cfg(layout), construct_optimizer(), 5,
-            mesh=mesh_lib.build_mesh(data=1, devices=[v5e_chip]),
-            topology=layout, im_size=cfg.TRAIN.IM_SIZE,
-        )
-        state, batch = lowered.abstract_args(1)
-    finally:
-        config.reset_cfg()
+    lowered, state, compiled = _step_for_the_v5e(
+        v5e_chip, monkeypatch, "glm_4_7_flash", (1, 8192), LAYERS=2)
     assert lowered.model.held == (0, 8) and lowered.model.vocab_held == 19360
-    chip = SingleDeviceSharding(v5e_chip)
-    batch = {k: jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=chip)
-             for k in batch}
-    compiled = lowered.train_step.lower(state, batch).compile()
     text = compiled.as_text()
     assert " while(" not in text and " conditional(" not in text
     assert "ragged-dot" not in text  # the held experts run the Pallas kernels
@@ -651,11 +629,7 @@ def test_glm_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
                   "moe_experts", "moe_shared", "mtp", "lm_head", "optimizer_update",
                   "opt_kernel", "rematted_computation"):
         assert any(in_scope(p, scope) for p in paths), scope
-    calls = {}
-    for line in text.splitlines():
-        if "custom-call(" in line and "dtpu_" in line:
-            name = line.split(" = ")[0].split()[-1].lstrip("%").rsplit(".", 1)[0]
-            calls.setdefault(name, []).append(line.split('op_name="')[1].split('"')[0])
+    calls = _dtpu_calls(text)
     # 3 blocks: the forward kernel once each, in the forward alone (a block
     # keeps its output, log-sum-exp, q, k and v), the one backward kernel once
     flash = {k: len(v) for k, v in calls.items() if "flash" in k}
@@ -664,17 +638,22 @@ def test_glm_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
                    for p in calls["dtpu_flash_fwd"])
     assert all(in_scope(p, "attn") and not in_scope(p, "mla_latent")
                for k in ("dtpu_flash_fwd", "dtpu_flash_bwd") for p in calls[k])
-    # the recomputation makes no q, k or v again: the two projections out of
-    # the latents run in the forward alone; those into them, their norms (what
-    # the former's own backward reads), W_o and the mixture still run again
+    # the recomputation makes no q, k or v again, nor the attention's output
+    # (the block keeps it: the sum the second norm reads is made of it): the
+    # two projections out of the latents and W_o run in the forward alone;
+    # those into the latents, their norms (what the former's own backward
+    # reads) and the mixture still run again, the mixture short of what
+    # nothing reads: the shared expert's down_proj and the combine
     recomputed = [p for p in paths if in_scope(p, "rematted_computation")]
-    for proj in ("q_b_proj", "kv_b_proj"):
+    for proj in ("q_b_proj", "kv_b_proj", "o_proj"):
         assert any(f"{proj}/dot_general" in p for p in paths), proj
         assert not any(f"{proj}/dot_general" in p for p in recomputed), proj
     for still in ("q_a_proj/dot_general", "kv_a_proj/dot_general", "q_a_norm",
-                  "kv_a_norm", "attn_norm", "o_proj/dot_general"):
+                  "kv_a_norm", "attn_norm"):
         assert any(in_scope(p, "attn") and still in p for p in recomputed), still
-    assert any(in_scope(p, "moe_shared") for p in recomputed)
+    assert any(in_scope(p, "moe_shared") and "gate_proj/dot_general" in p
+               for p in recomputed)
+    assert not any("down_proj/dot_general" in p for p in recomputed)
     # 2 mixtures: gate_up and fwd run forward and again, the four backward
     # kernels once; all under moe_experts, none under moe_shared
     gmm = {k: len(v) for k, v in calls.items() if "moe_gmm" in k}
@@ -691,3 +670,59 @@ def test_glm_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
     # it fits the chip with room for the cell's two more mixture layers
     m = compiled.memory_analysis()
     assert (m.argument_size_in_bytes + m.temp_size_in_bytes) < 12 * 2**30
+
+
+def test_trinity_step_compiles_for_the_v5e_short_of_each_branchs_last_matmul(
+        v5e_chip, monkeypatch):
+    """The step of ``trinity_mini.train_seq8192`` (``config/trinity_mini.yaml``:
+    published widths, 2 x 8192 tokens, 16 of 128 experts and 25,024 vocabulary
+    rows held, every block recomputed) compiled for the chip at the dense
+    layer and one sliding mixture (published layers 1..2; the cell's 1..5
+    compile in a minute here, and ``tests/benchmark/test_benchmark_trinity.py``
+    compiles 2..3 under the benchmark's scopes). A norm follows each of a
+    block's two parts and its backward reads that part's output, so the block
+    keeps both (``models/ouro.recomputed``) and the second forward stops
+    short of what made them: ``o_proj``, the dense MLP's and the shared
+    expert's ``down_proj`` and the movers' combine run once, in the forward.
+    The experts' down product ``dtpu_moe_gmm_fwd`` DOES run again: the
+    gradient of the routing weights is each expert's output row times the
+    cotangent, so the combine's backward reads the rows the kept sum was made
+    of (``ops/moe.sorted_experts``; ROADMAP S9 (a)), and with it the take and
+    ``dtpu_moe_gmm_gate_up`` before it."""
+    from benchmark.harness.trace import in_scope, op_names_from_hlo
+
+    lowered, state, compiled = _step_for_the_v5e(
+        v5e_chip, monkeypatch, "trinity_mini", (2, 8192), FIRST_LAYER=1, LAYERS=2)
+    model = lowered.model
+    assert model.layer_kinds == ("sliding_attention",) * 2 and model.dense_here == 1
+    assert model.held == (0, 16) and model.vocab_held == 25024 and model.recompute
+    text = compiled.as_text()
+    assert " while(" not in text and " conditional(" not in text
+    paths = list(op_names_from_hlo(text).values())
+    recomputed = [p for p in paths if in_scope(p, "rematted_computation")]
+    for last in ("v_proj", "o_proj", "down_proj"):
+        assert any(f"{last}/dot_general" in p for p in paths), last
+        assert not any(f"{last}/dot_general" in p for p in recomputed), last
+    assert any(in_scope(p, "moe_shared") and "down_proj/dot_general" in p for p in paths)
+    assert any(in_scope(p, "mlp") and "down_proj/dot_general" in p for p in paths)
+    # what still runs again: the four norms' inputs are kept or rebuilt from
+    # what is kept, q's, k's and the gate's projections, gate_proj and up_proj
+    # of the dense MLP and of the shared expert, the router
+    for still, scope in (("q_proj", "attn"), ("k_proj", "attn"), ("gate_proj", "attn_gate"),
+                         ("gate_proj", "mlp"), ("up_proj", "mlp"),
+                         ("gate_proj", "moe_shared"), ("up_proj", "moe_shared")):
+        assert any(in_scope(p, scope) and f"{still}/dot_general" in p
+                   for p in recomputed), (still, scope)
+    for norm in ("input_norm", "post_attn_norm", "pre_mlp_norm", "post_mlp_norm"):
+        assert any(norm in p for p in recomputed), norm
+    calls = _dtpu_calls(text)
+    again = {k: sum(in_scope(p, "rematted_computation") for p in v)
+             for k, v in calls.items() if "moe_" in k}
+    assert {k: v for k, v in again.items() if v} == {
+        "dtpu_moe_rows_pack": 1, "dtpu_moe_rows_take": 1,
+        "dtpu_moe_gmm_gate_up": 1, "dtpu_moe_gmm_fwd": 1}, again
+    _movers_of_held_mixtures(calls, in_scope, mixtures=1, normed_after=True)
+    flash = {k: len(v) for k, v in calls.items() if "flash" in k}
+    assert (flash["dtpu_flash_fwd"], flash["dtpu_flash_bwd"]) == (2, 2)
+    assert not any(in_scope(p, "rematted_computation") for p in calls["dtpu_flash_fwd"])
+    assert len(calls["dtpu_opt_update_adamw"]) == len(jax.tree.leaves(state.params))

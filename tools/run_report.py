@@ -414,6 +414,9 @@ def _recomputed(plan: dict) -> str:
         if plan.get("kept_flash_bytes") is not None:
             said += (f", {plan['kept_flash_bytes'] / 2**20:.1f} of them the "
                      "flash kernel's output, log-sum-exp, q, k and v")
+        if plan.get("kept_branch_bytes") is not None:
+            said += (f", {plan['kept_branch_bytes'] / 2**20:.1f} the branches' "
+                     "outputs")
     return said
 
 
