@@ -21,16 +21,6 @@ if "xla_force_host_platform_device_count" not in flags:
 import pytest  # noqa: E402
 
 
-def pytest_configure(config):
-    # session start stamp for the tier-1 wall-clock guard
-    # (tests/test_zz_tier1_budget.py): the suite must fit its timeout
-    # with margin, or the guard fails BEFORE the driver's `timeout` kills
-    # the run with no diagnostics
-    import time
-
-    config._t1_start = time.monotonic()
-
-
 @pytest.fixture
 def chip_bench_root(tmp_path):
     """A synthetic ``BENCH_r01``–``r05`` set in the driver's record shape

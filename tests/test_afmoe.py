@@ -5,10 +5,9 @@ published), a window of 24, a gated attention output, four norms a block, a
 dense MLP of 160 in the 2 leading layers, then 8 experts of 32 with 2 a
 token and a shared one, 6 layers by the pattern sliding, sliding, sliding,
 full, vocab 512 with an untied head; two chips share each layer unless a
-test says otherwise."""
-
-import importlib.util
-import os
+test says otherwise. The contracts it answers are
+``tests/decoder_contract.py``'s; below them, what only Trinity-Mini has: the
+window, rotary in the window layers alone, the gate, the scaled embedding."""
 
 import flax
 import jax
@@ -16,132 +15,55 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import decoder_contract as contract
+from decoder_contract import FULL, SLIDING, seeded, variables
 from distribuuuu_tpu import models
-from distribuuuu_tpu.models import afmoe, glm_moe, lfm2_moe
+from distribuuuu_tpu.models import afmoe, lfm2_moe
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_spec = importlib.util.spec_from_file_location(
-    "afmoe_reference", os.path.join(REPO, "benchmark", "reference", "afmoe.py")
-)
-reference = importlib.util.module_from_spec(_spec)
-_spec.loader.exec_module(reference)
-
-VOCAB, CHUNK = 512, 48
-SLIDING, FULL = "sliding_attention", "full_attention"
+ROW = contract.ROWS["afmoe"]
 
 
 def build(**kw):
-    return models.build_model("afmoe_tiny", num_classes=VOCAB, dtype=jnp.float32, **kw)
+    return contract.build(ROW, **kw)
 
 
-def architecture(model) -> dict:
-    first, count = model.held
-    return {
-        "layer_types": list(model.layer_kinds), "num_dense_layers": model.dense_here,
-        "hidden_size": model.dim, "num_attention_heads": model.num_heads,
-        "num_key_value_heads": model.kv_heads, "head_dim": model.head_dim,
-        "sliding_window": model.sliding_window,
-        "intermediate_size": model.mlp_hidden,
-        "moe_intermediate_size": model.expert_hidden,
-        "num_experts": model.num_experts, "num_experts_per_tok": model.top_k,
-        "num_shared_experts": model.shared_experts,
-        "route_scale": model.routed_scale, "route_norm_eps": 1e-20,
-        "mup_enabled": model.mup, "rms_norm_eps": model.norm_eps,
-        "rope_theta": model.rope_theta, "vocab_size": model.vocab_size,
-        "share_chips": model.share_chips, "share_rank": model.share_rank,
-        "experts_held": count, "vocab_held": model.vocab_held,
-        "bias_update_rate": model.bias_rate, "balance_loss_weight": model.aux_weight,
-    }
+class TestTrinityMini(contract.Decoder, contract.ThroughLower, contract.Recomputes,
+                      contract.HoldsAShare):
+    row = ROW
 
+    def shapes_of_its_own(self, full, model, state, hidden):
+        assert len(full.layer_kinds) == 32
+        assert full.layer_kinds.count(FULL) == 8
+        assert full.layer_kinds[:4] == (SLIDING, SLIDING, SLIDING, FULL)
+        assert hidden[1]["aux"].shape == (4,)
+        params = state["params"]
+        attn = params["Block_3"]["attn"]
+        # 4 heads of 32: wider than the model, as 32 heads of 128 are than 2048
+        assert attn["q_proj"]["kernel"].shape == attn["gate_proj"]["kernel"].shape == (64, 128)
+        assert attn["k_proj"]["kernel"].shape == attn["v_proj"]["kernel"].shape == (64, 32)
+        assert attn["o_proj"]["kernel"].shape == (128, 64)
+        assert attn["q_norm"]["scale"].shape == attn["k_norm"]["scale"].shape == (32,)
+        assert set(params["Block_3"]) == {"attn", "moe", *afmoe.NORMS}
+        assert set(params["Block_0"]) == {"attn", "mlp", *afmoe.NORMS}
+        assert "shared" in params["Block_3"]["moe"]
+        assert params["head"].shape == (64, 256)  # untied
+        assert model.head_kernel(params) is params["head"]
+        with pytest.raises(ValueError, match="inside the list"):
+            build(first_layer=4, depth=6).layer_kinds
+        with pytest.raises(ValueError, match="whose words are"):
+            build(layer_types=("conv", FULL), depth=2).layer_kinds
 
-def seeded(model, batch=2, seq=100, seed=0):
-    """(params, biases, tokens, labels): weights from the program's
-    initialiser with the norm scales moved off 1, so that a dropped scale
-    would show, biases off 0, and ids from the rows of the vocabulary the
-    rank holds."""
-    k_init, k_tok, k_scale, k_bias = jax.random.split(jax.random.key(seed), 4)
-    variables = flax.linen.meta.unbox(
-        jax.jit(model.init)(k_init, model.dummy_input()))
-    flat, tree = jax.tree_util.tree_flatten_with_path(variables["params"])
-    keys = jax.random.split(k_scale, len(flat))
-    flat = [
-        leaf * (1 + 0.2 * jax.random.normal(key, leaf.shape))
-        if "scale" in jax.tree_util.keystr(path) else leaf
-        for (path, leaf), key in zip(flat, keys)]
-    biases = jax.tree.map(
-        lambda b: 0.02 * jax.random.normal(k_bias, b.shape), variables["batch_stats"])
-    ids = model.share_rank * model.vocab_held + jax.random.randint(
-        k_tok, (batch, seq + 1), 0, model.vocab_held, jnp.int32)
-    return jax.tree.unflatten(tree, flat), biases, ids[:, :-1], ids[:, 1:]
+    def loss_of_its_own(self, model, loss, aux, want):
+        assert {SLIDING, FULL} <= set(model.layer_kinds)
 
+    def declared_of_its_own(self, arch, model):
+        assert model.layer_kinds == (SLIDING, SLIDING, FULL, SLIDING, SLIDING)
+        assert model.sliding_window == {"trinity_mini": 2048, "afmoe_tiny": 24}[arch]
 
-def program_loss(model, params, biases, tokens, labels):
-    """(loss, (step metrics, the biases the step leaves, what ``hidden_only``
-    returned)): the two calls the step's ``loss_fn`` makes."""
-    outputs, mutated = model.apply(
-        {"params": params, "batch_stats": biases}, tokens, train=True,
-        hidden_only=True, mutable=["batch_stats"])
-    loss, _hits, extra = model.head_loss(
-        outputs, model.head_kernel(params), labels, topk=(1, 5))
-    return loss, (extra, mutated["batch_stats"], outputs)
-
-
-def mixture_biases(model, biases):
-    """``[mixtures, E]`` in the reference's order."""
-    names = [f"Block_{i}" for i in range(model.dense_here, len(model.layer_kinds))]
-    return jnp.stack([biases[n]["moe"]["router_bias"] for n in names])
-
-
-def assert_trees_close(got, want, tolerance):
-    flat = jax.tree_util.tree_leaves_with_path(got)
-    for (path, g), w in zip(flat, jax.tree.leaves(want), strict=True):
-        norm = float(jnp.linalg.norm(w))
-        assert norm > 0, jax.tree_util.keystr(path)
-        assert float(jnp.linalg.norm(g - w)) <= tolerance * norm, jax.tree_util.keystr(path)
-
-
-def test_registry_and_shapes():
-    assert {"trinity_mini", "afmoe_tiny"} <= set(models.available_models())
-    full = models.build_model("trinity_mini")
-    assert (full.dim, len(full.layer_kinds), full.num_heads, full.kv_heads,
-            full.head_dim, full.sliding_window, full.num_experts, full.top_k,
-            full.shared_experts, full.vocab_size, full.share_chips, full.dense_here
-            ) == (2048, 32, 32, 4, 128, 2048, 128, 8, 1, 200192, 1, 2)
-    assert full.layer_kinds.count(FULL) == 8
-    assert full.layer_kinds[:4] == (SLIDING, SLIDING, SLIDING, FULL)
-    assert (full.routed_scale, full.rope_theta, full.norm_eps) == (2.826, 1e4, 1e-5)
-    model = build()
-    assert (model.held, model.vocab_held) == ((0, 4), 256)
-    assert build(share_rank=1).held == (4, 4)
-    # shapes alone: nothing here is compiled or run
-    variables = jax.eval_shape(lambda: flax.linen.meta.unbox(
-        model.init(jax.random.key(0), model.dummy_input())))
-    params, tokens = variables["params"], jax.ShapeDtypeStruct((2, 40), jnp.int32)
-    logits = jax.eval_shape(model.apply, variables, tokens)
-    assert logits.shape == (2, 40, 256) and logits.dtype == jnp.float32
-    states, stats = jax.eval_shape(
-        lambda v, t: model.apply(v, t, hidden_only=True), variables, tokens)
-    assert states.shape == (2, 40, 64) and stats["aux"].shape == (4,)
-    attn = params["Block_3"]["attn"]
-    # 4 heads of 32: wider than the model, as 32 heads of 128 are than 2048
-    assert attn["q_proj"]["kernel"].shape == attn["gate_proj"]["kernel"].shape == (64, 128)
-    assert attn["k_proj"]["kernel"].shape == attn["v_proj"]["kernel"].shape == (64, 32)
-    assert attn["o_proj"]["kernel"].shape == (128, 64)
-    assert attn["q_norm"]["scale"].shape == attn["k_norm"]["scale"].shape == (32,)
-    assert set(params["Block_3"]) == {"attn", "moe", *afmoe.NORMS}
-    assert set(params["Block_0"]) == {"attn", "mlp", *afmoe.NORMS}
-    assert "shared" in params["Block_3"]["moe"]
-    assert params["head"].shape == (64, 256)  # untied
-    assert model.head_kernel(params) is params["head"]
-    with pytest.raises(ValueError, match="exceeds the context"):
-        jax.eval_shape(model.apply, variables, jax.ShapeDtypeStruct((1, 129), jnp.int32))
-    with pytest.raises(ValueError, match="LM.SHARE_CHIPS=3"):
-        jax.eval_shape(build(share_chips=3).init, jax.random.key(0),
-                       jax.ShapeDtypeStruct((1, 8), jnp.int32))
-    with pytest.raises(ValueError, match="inside the list"):
-        build(first_layer=4, depth=6).layer_kinds
-    with pytest.raises(ValueError, match="whose words are"):
-        build(layer_types=("conv", FULL), depth=2).layer_kinds
+    def step_of_its_own(self, ran, want):
+        # every block recomputed, as the cell runs them; the loss cases hold
+        # the model that keeps everything against the same reference
+        assert ran.model.recompute
 
 
 @pytest.mark.parametrize("dense", [0, 1, 2])
@@ -167,56 +89,6 @@ def test_the_layer_pattern_says_which_block_is_which(dense, first):
     assert stage.dense_here == 1
 
 
-@pytest.mark.parametrize("recompute, dense", [
-    (True, 0), (True, 1), (True, 2), (False, 1)],
-    ids=["recomputed-0", "recomputed-1", "recomputed-2", "kept-1"])
-def test_logits_loss_every_gradient_and_the_bias_equal_the_reference(recompute, dense):
-    """Logits, the loss and its terms, the share of the choices on held
-    experts, the gradient on every leaf, and the biases one step leaves, with
-    0, 1 and 2 leading dense layers under the pattern sliding x 3, full (100
-    positions: four windows long; the head in chunks of 48),
-    for either of the two chips that share the layers, with every block
-    recomputed as the cell runs them and, once, with none. Each side is one
-    compiled function: what the CPU would otherwise compile operation by
-    operation is most of this file's time."""
-    rank = dense % 2
-    model = build(share_rank=rank, recompute=recompute, dense_layers=dense, depth=4)
-    assert {SLIDING, FULL} <= set(model.layer_kinds)
-    params, biases, tokens, labels = seeded(model, seed=dense)
-    arch = architecture(model)
-
-    @jax.jit
-    def program(p):
-        logits = model.apply({"params": p, "batch_stats": biases}, tokens)
-        return logits, jax.value_and_grad(
-            lambda p: program_loss(model, p, biases, tokens, labels), has_aux=True)(p)
-
-    @jax.jit
-    def plain(p):
-        def total(p):
-            terms = reference.loss(p, biases, tokens, labels, architecture=arch)
-            return terms["loss"], terms
-
-        return reference.logits(p, biases, tokens, architecture=arch), jax.value_and_grad(
-            total, has_aux=True)(p)
-
-    logits, ((loss, (extra, after, _)), grads) = program(params)
-    want_logits, ((_, want), want_grads) = plain(params)
-    np.testing.assert_allclose(logits, want_logits, atol=2e-5)
-    np.testing.assert_allclose(loss, want["loss"], rtol=1e-6)
-    for got, term in (("ce", "ce"), ("moe_aux", "load_balance"),
-                      ("moe_held_row_share", "held_row_share")):
-        np.testing.assert_allclose(extra[got], want[term], rtol=2e-6, err_msg=got)
-    assert float(extra["moe_dropped"]) == 0.0
-    assert 0.3 < float(extra["moe_held_row_share"]) < 0.7
-    assert_trees_close(grads, want_grads, 2e-5)
-    np.testing.assert_array_equal(
-        mixture_biases(model, after),
-        reference.bias_after(mixture_biases(model, biases), want["counts"], 0.001))
-    np.testing.assert_allclose(
-        extra["router_bias_abs_max"], jnp.abs(mixture_biases(model, after)).max())
-
-
 def _one_mixer(kind, **kw):
     """(module, variables, x): one attention mixer of the tiny model."""
     model = build()
@@ -236,11 +108,11 @@ def test_rotary_is_in_the_window_layers_and_in_no_full_layer():
     for bit."""
     shuffled = jax.random.permutation(jax.random.key(5), 60)
     for kind, moves in ((SLIDING, True), (FULL, False)):
-        mixer, variables, x = _one_mixer(kind)
-        base = mixer.apply(variables, x, jnp.arange(60))
+        mixer, state, x = _one_mixer(kind)
+        base = mixer.apply(state, x, jnp.arange(60))
         np.testing.assert_allclose(
-            mixer.apply(variables, x, jnp.arange(60) + 7), base, atol=1e-5)
-        moved = float(jnp.abs(mixer.apply(variables, x, shuffled) - base).max())
+            mixer.apply(state, x, jnp.arange(60) + 7), base, atol=1e-5)
+        moved = float(jnp.abs(mixer.apply(state, x, shuffled) - base).max())
         assert (moved > 1e-3) is moves, (kind, moved)
         if not moves:
             assert moved == 0.0
@@ -251,9 +123,9 @@ def test_the_window_is_in_the_sliding_layers_alone():
     more than the window behind it changes; a full layer's does."""
     window = build().sliding_window
     for kind, reaches in ((SLIDING, False), (FULL, True)):
-        mixer, variables, x = _one_mixer(kind)
-        base = mixer.apply(variables, x, jnp.arange(60))
-        far = mixer.apply(variables, x.at[0, 5].add(1.0), jnp.arange(60))
+        mixer, state, x = _one_mixer(kind)
+        base = mixer.apply(state, x, jnp.arange(60))
+        far = mixer.apply(state, x.at[0, 5].add(1.0), jnp.arange(60))
         delta = jnp.abs(far - base)[0].max(-1)
         assert float(delta[5 + window - 1]) > 1e-5  # the last row that sees it
         assert (float(delta[5 + window:].max()) > 1e-6) is reaches, kind
@@ -264,8 +136,8 @@ def test_the_gate_multiplies_the_heads_and_takes_a_gradient():
     """``out = (heads * sigmoid(x W_g)) W_o``: a gate projection of zeros
     halves the ungated mixer's output, the gate's gradient is the product
     rule's against a central difference, and it is nowhere zero."""
-    mixer, variables, x = _one_mixer(SLIDING)
-    params = variables["params"]
+    mixer, state, x = _one_mixer(SLIDING)
+    params = state["params"]
     ungated = lfm2_moe.Attention(
         mixer.dim, mixer.num_heads, mixer.kv_heads, mixer.eps, mixer.rope_theta,
         jnp.float32, head_dim=mixer.head_dim, window=mixer.window)
@@ -293,46 +165,64 @@ def test_the_gate_multiplies_the_heads_and_takes_a_gradient():
 def test_the_embedding_is_scaled_by_the_root_of_the_width():
     model = build(depth=3)
     params, biases, tokens, _ = seeded(model, seq=16)
-    variables = {"params": params, "batch_stats": biases}
     scaled = {**params, "tok_embed": {"embedding": params["tok_embed"]["embedding"] * 8.0}}
     np.testing.assert_allclose(
-        jax.jit(model.apply)(variables, tokens),
-        jax.jit(model.clone(mup=False).apply)(
-            {"params": scaled, "batch_stats": biases}, tokens),
+        jax.jit(model.apply)(variables(params, biases), tokens),
+        jax.jit(model.clone(mup=False).apply)(variables(scaled, biases), tokens),
         atol=1e-5)
 
 
-@pytest.mark.parametrize("chips", [2, 4])
-def test_the_shares_of_a_layer_add_up_to_the_whole_layer(chips):
-    """The guide's share test: with 16 experts split over 2 and over 4 ranks,
-    the ranks' partial mixture outputs, the shared expert (which every chip
-    computes alike) counted ONCE, add up to what the UNCUT reference gives
-    for the whole layer."""
-    E, k, d, f = 16, 4, 64, 32
-    whole = glm_moe.Mixture(d, f, E, k, 1, 2.826, 0.001, (0, E), jnp.float32)
-    x = jax.random.normal(jax.random.key(0), (2, 24, d))
-    variables = flax.linen.meta.unbox(whole.init(jax.random.key(1), x))
-    bias = 0.05 * jax.random.normal(jax.random.key(2), (E,))
-    p = variables["params"]
-    assert set(p) == {"router", "w_gate", "w_up", "w_down", "shared"}
-    arch = {"num_experts_per_tok": k, "route_scale": 2.826, "route_norm_eps": 1e-20,
-            "share_rank": 0, "experts_held": E}
-    with jax.default_matmul_precision("highest"):
-        want = reference._mixture(x, p, bias, arch)[0]
-        shared = reference._mlp(x, p["shared"])
-    parts, count = [], E // chips
-    for rank in range(chips):
-        held = slice(rank * count, (rank + 1) * count)
-        mine = {**p, **{n: p[n][held] for n in ("w_gate", "w_up", "w_down")}}
-        out, stats = glm_moe.Mixture(
-            d, f, E, k, 1, 2.826, 0.001, (rank * count, count), jnp.float32,
-        ).apply({"params": mine, "batch_stats": {"router_bias": bias}}, x)
-        parts.append(out)
-        assert 0 < float(stats["held_row_share"]) < 1
-        with jax.default_matmul_precision("highest"):  # the reference's share
-            np.testing.assert_allclose(out, reference._mixture(
-                x, mine, bias, arch, held=(rank * count, count))[0], atol=3e-6)
-    np.testing.assert_allclose(sum(parts) - (chips - 1) * shared, want, atol=5e-6)
-    # counted every time it is not the layer, and no share alone is
-    assert float(jnp.abs(sum(parts) - want).max()) > 1e-3
-    assert float(jnp.abs(parts[0] - want).max()) > 1e-3
+def test_the_model_says_its_layer_kinds_and_the_windowed_flash_its_window_once_a_shape(
+        tmp_path):
+    from unittest import mock
+
+    from distribuuuu_tpu.models.ouro import kept_plan
+    from distribuuuu_tpu.ops import flash_attention as fa
+    from distribuuuu_tpu.ops import pallas as kernel_tier
+    from distribuuuu_tpu.telemetry import schema, spans
+
+    kernel_tier.reset_selection()
+    spans.setup_telemetry(str(tmp_path), 0)
+    try:
+        model = build().clone(first_layer=1, depth=3, seq_len=24, share_rank=1)
+        state = flax.linen.meta.unbox(
+            jax.jit(model.init)(jax.random.key(0), jnp.full((3, 24), 256, jnp.int32)))
+        for _ in range(2):  # traced twice: the plan is said once a shape
+            jax.eval_shape(lambda v, t: model.apply(v, t, hidden_only=True),
+                           state, jnp.full((3, 24), 300, jnp.int32))
+        q = jnp.zeros((1, 4, 256, 32))
+        fa.flash_attention(q, q[:, :1], q[:, :1], causal=True, interpret=True, window=24)
+    finally:
+        spans.close_telemetry()
+    plans = contract.records(tmp_path, "share.plan")
+    assert len(plans) == 1
+    schema.check_fields("share.plan", plans[0])
+    assert {k: plans[0][k] for k in (
+        "share_chips", "share_rank", "experts_held", "experts_total", "vocab_held",
+        "vocab_total", "layer_kinds", "dense_layers",
+    )} == {"share_chips": 2, "share_rank": 1, "experts_held": 4, "experts_total": 8,
+           "vocab_held": 256, "vocab_total": 512,
+           "layer_kinds": [SLIDING, SLIDING, FULL], "dense_layers": 1}
+    assert "every block of either kind" in plans[0]["recomputed"]
+    # the scan path names nothing; a block keeps its float32 input and both
+    # its branches' outputs (float32 here): a norm follows each
+    assert plans[0]["kept_branch_bytes"] == 2 * 3 * 3 * 24 * 64 * 4
+    assert plans[0]["kept_bytes"] == 3 * 3 * 3 * 24 * 64 * 4
+    chose = [r for r in contract.records(tmp_path, "kernel.select")
+             if r["op"] == "flash_attn" and r["impl"] == "pallas"]
+    assert chose and (chose[-1]["window"], chose[-1]["kv_group"]) == (24, 4)
+    assert {"blk_q", "blk_k", "tiles_visited", "tiles_crossed"} <= set(chose[-1])
+    # what a recomputed block keeps where the kernels run: all five blocks'
+    # flash residuals, q at 32 heads of 128 and k, v at their own 4
+    cell = build().clone(
+        num_heads=32, kv_heads=4, head_dim=128, dim=2048, dtype=jnp.bfloat16)
+    with mock.patch.object(kernel_tier, "interpret_mode", lambda: False), \
+            mock.patch.object(kernel_tier, "compiled_across_devices", lambda: False):
+        one = fa.kept_under_remat_bytes((2, 32, 8192, 128), 2, kv_heads=4)
+        kept = kept_plan(
+            cell, 5, 2, 8192, cell.attn_head_dim, "x", branches=10, flash_blocks=5)
+    assert kept["kept_flash_bytes"] == 5 * one
+    assert kept["kept_branch_bytes"] == 10 * 2 * 8192 * 2048 * 2  # 0.625 GiB
+    assert kept["kept_bytes"] == 5 * 2 * 8192 * 2048 * (4 + 2 * 2) + 5 * one
+    assert one == 2 * (32 * (8192 * 128 * 2 + 8192 * 4 + 8192 * 128 * 2)
+                       + 2 * 4 * 8192 * 128 * 2)

@@ -21,7 +21,7 @@ import pytest
 
 from distribuuuu_tpu.ops import flash_attention as fa
 from distribuuuu_tpu.ops.ring_attention import reference_attention
-from test_ouro import forward_matmuls
+from decoder_contract import forward_matmuls
 
 BLK = dict(blk_q=256, blk_k=256)
 

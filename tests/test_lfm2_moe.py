@@ -4,11 +4,11 @@ hidden 64, 4 query heads on 2 key/value heads of 16, a gated short
 convolution of 3 taps, a dense MLP of 160 in the 2 leading layers, then 8
 experts of 32 with 2 a token, 6 layers by the pattern conv, conv, attention,
 conv, vocab 512 with the head tied to the embedding; two chips share each
-layer unless a test says otherwise."""
+layer unless a test says otherwise. The contracts it answers are
+``tests/decoder_contract.py``'s; below them, what only LFM2 has: the layer
+pattern, the tied head, the short convolution, the grouped flash kernels."""
 
 import functools
-import importlib.util
-import os
 
 import flax
 import jax
@@ -16,127 +16,71 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import decoder_contract as contract
+from decoder_contract import program_loss, variables
 from distribuuuu_tpu import models
 from distribuuuu_tpu.models import glm_moe, lfm2_moe
 from distribuuuu_tpu.ops import flash_attention as fa
 from distribuuuu_tpu.ops import moe as moe_ops
 from distribuuuu_tpu.ops.short_conv import gated_short_conv
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_spec = importlib.util.spec_from_file_location(
-    "lfm2_moe_reference", os.path.join(REPO, "benchmark", "reference", "lfm2_moe.py")
-)
-reference = importlib.util.module_from_spec(_spec)
-_spec.loader.exec_module(reference)
-
-VOCAB, CHUNK = 512, 48
+ROW = contract.ROWS["lfm2"]
 
 
 def build(**kw):
-    return models.build_model("lfm2_moe_tiny", num_classes=VOCAB, dtype=jnp.float32, **kw)
+    return contract.build(ROW, **kw)
 
 
-def architecture(model) -> dict:
-    first, count = model.held
-    return {
-        "layer_types": list(model.layer_kinds), "num_dense_layers": model.dense_here,
-        "hidden_size": model.dim, "num_attention_heads": model.num_heads,
-        "num_key_value_heads": model.kv_heads, "conv_L_cache": model.conv_taps,
-        "intermediate_size": model.mlp_hidden,
-        "moe_intermediate_size": model.expert_hidden,
-        "num_experts": model.num_experts, "num_experts_per_tok": model.top_k,
-        "routed_scaling_factor": model.routed_scale,
-        "route_norm_eps": model.route_norm_eps, "norm_eps": model.norm_eps,
-        "rope_theta": model.rope_theta, "vocab_size": model.vocab_size,
-        "share_chips": model.share_chips, "share_rank": model.share_rank,
-        "experts_held": count, "vocab_held": model.vocab_held,
-        "bias_update_rate": model.bias_rate, "balance_loss_weight": model.aux_weight,
-    }
+class TestLFM2(contract.Decoder, contract.ThroughLower, contract.Recomputes,
+               contract.RecomputesNothingInItsCell, contract.HoldsAShare):
+    row = ROW
 
+    def shapes_of_its_own(self, full, model, state, hidden):
+        assert len(full.layer_kinds) == 40
+        assert full.layer_kinds.count("full_attention") == 10
+        assert full.layer_kinds[:4] == ("conv", "conv", "full_attention", "conv")
+        assert hidden[1]["aux"].shape == (4,)
+        params = state["params"]
+        assert params["Block_0"]["short_conv"]["filter"].shape == (64, 3)
+        assert params["Block_0"]["short_conv"]["in_proj"]["kernel"].shape == (64, 192)
+        attn = params["Block_2"]["attn"]
+        assert attn["q_proj"]["kernel"].shape == (64, 64)
+        assert attn["k_proj"]["kernel"].shape == attn["v_proj"]["kernel"].shape == (64, 32)
+        assert attn["q_norm"]["scale"].shape == attn["k_norm"]["scale"].shape == (16,)
+        assert "shared" not in params["Block_2"]["moe"]  # no shared expert
+        # ONE matrix is embedding and head
+        assert "head" not in params
+        assert jax.eval_shape(model.head_kernel, params).shape == (64, 256)
+        with pytest.raises(ValueError, match="inside the list"):
+            build(first_layer=4, depth=6).layer_kinds
 
-def seeded(model, batch=2, seq=100, seed=0):
-    """(params, biases, tokens, labels): weights from the program's
-    initialiser with the norm scales moved off 1 and the filters made large,
-    so that a dropped scale or a shifted tap would show, biases off 0, and
-    ids from the rows of the vocabulary the rank holds."""
-    k_init, k_tok, k_scale, k_bias = jax.random.split(jax.random.key(seed), 4)
-    variables = flax.linen.meta.unbox(model.init(k_init, model.dummy_input()))
-    flat, tree = jax.tree_util.tree_flatten_with_path(variables["params"])
-    keys = jax.random.split(k_scale, len(flat))
+    def declared_of_its_own(self, arch, model):
+        assert model.layer_kinds == ("conv", "full_attention", "conv", "conv", "conv")
 
-    def moved(path, leaf, key):
-        name = jax.tree_util.keystr(path)
-        if "scale" in name:
-            return leaf * (1 + 0.2 * jax.random.normal(key, leaf.shape))
-        return jax.random.normal(key, leaf.shape) if "filter" in name else leaf
+    def step_of_its_own(self, ran, want):
+        assert ran.model.recompute is ran.overrides["recompute"]
 
-    flat = [moved(path, leaf, k) for (path, leaf), k in zip(flat, keys)]
-    biases = jax.tree.map(
-        lambda b: 0.02 * jax.random.normal(k_bias, b.shape), variables["batch_stats"])
-    ids = model.share_rank * model.vocab_held + jax.random.randint(
-        k_tok, (batch, seq + 1), 0, model.vocab_held, jnp.int32)
-    return jax.tree.unflatten(tree, flat), biases, ids[:, :-1], ids[:, 1:]
+    def test_the_tied_heads_gradient_has_two_sources(self, small):
+        """The embedding's gradient is the lookup's plus the head's: each
+        alone is another matrix, and their sum is the gradient of the tied
+        loss."""
+        model, params, biases, tokens, labels = small
 
+        def loss(table, head):
+            tied = {**params, "tok_embed": {"embedding": table}}
+            outputs = model.apply(variables(tied, biases), tokens, hidden_only=True)
+            return model.head_loss(outputs, head.T, labels, topk=(1, 5))[0]
 
-def program_loss(model, params, biases, tokens, labels):
-    """(loss, (step metrics, the biases the step leaves, what ``hidden_only``
-    returned)): the two calls the step's ``loss_fn`` makes."""
-    outputs, mutated = model.apply(
-        {"params": params, "batch_stats": biases}, tokens, train=True,
-        hidden_only=True, mutable=["batch_stats"])
-    loss, _hits, extra = model.head_loss(
-        outputs, model.head_kernel(params), labels, topk=(1, 5))
-    return loss, (extra, mutated["batch_stats"], outputs)
-
-
-def mixture_biases(model, biases):
-    """``[mixtures, E]`` in the reference's order."""
-    names = [f"Block_{i}" for i in range(model.dense_here, len(model.layer_kinds))]
-    return jnp.stack([biases[n]["moe"]["router_bias"] for n in names])
-
-
-def assert_trees_close(got, want, tolerance):
-    flat = jax.tree_util.tree_leaves_with_path(got)
-    for (path, g), w in zip(flat, jax.tree.leaves(want), strict=True):
-        norm = float(jnp.linalg.norm(w))
-        assert norm > 0, jax.tree_util.keystr(path)
-        assert float(jnp.linalg.norm(g - w)) <= tolerance * norm, jax.tree_util.keystr(path)
-
-
-def test_registry_and_shapes():
-    assert {"lfm2_24b_a2b", "lfm2_moe_tiny"} <= set(models.available_models())
-    full = models.build_model("lfm2_24b_a2b")
-    assert (full.dim, len(full.layer_kinds), full.num_heads, full.kv_heads,
-            full.num_experts, full.top_k, full.vocab_size, full.share_chips,
-            full.dense_here, full.conv_taps) == (2048, 40, 32, 8, 64, 4, 65536, 1, 2, 3)
-    assert full.layer_kinds.count("full_attention") == 10
-    assert full.layer_kinds[:4] == ("conv", "conv", "full_attention", "conv")
-    model = build()
-    assert (model.held, model.vocab_held) == ((0, 4), 256)
-    assert build(share_rank=1).held == (4, 4)
-    params, biases, tokens, _ = seeded(model, seq=40)
-    logits = model.apply({"params": params, "batch_stats": biases}, tokens)
-    assert logits.shape == (2, 40, 256) and logits.dtype == jnp.float32
-    states, stats = model.apply(
-        {"params": params, "batch_stats": biases}, tokens, hidden_only=True)
-    assert states.shape == (2, 40, 64) and stats["aux"].shape == (4,)
-    assert params["Block_0"]["short_conv"]["filter"].shape == (64, 3)
-    assert params["Block_0"]["short_conv"]["in_proj"]["kernel"].shape == (64, 192)
-    attn = params["Block_2"]["attn"]
-    assert attn["q_proj"]["kernel"].shape == (64, 64)
-    assert attn["k_proj"]["kernel"].shape == attn["v_proj"]["kernel"].shape == (64, 32)
-    assert attn["q_norm"]["scale"].shape == attn["k_norm"]["scale"].shape == (16,)
-    assert "shared" not in params["Block_2"]["moe"]  # no shared expert
-    # ONE matrix is embedding and head
-    assert "head" not in params
-    assert model.head_kernel(params).shape == (64, 256)
-    with pytest.raises(ValueError, match="exceeds the context"):
-        model.apply({"params": params, "batch_stats": biases},
-                    jnp.zeros((1, 129), jnp.int32))
-    with pytest.raises(ValueError, match="LM.SHARE_CHIPS=3"):
-        build(share_chips=3).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))
-    with pytest.raises(ValueError, match="inside the list"):
-        build(first_layer=4, depth=6).layer_kinds
+        table = params["tok_embed"]["embedding"]
+        lookup, head = jax.grad(loss, argnums=(0, 1))(table, table)
+        whole = jax.grad(lambda p: program_loss(model, p, biases, tokens, labels)[0])(
+            params)["tok_embed"]["embedding"]
+        np.testing.assert_allclose(whole, lookup + head, atol=1e-7)
+        assert float(jnp.abs(lookup).max()) > 0 and float(jnp.abs(head).max()) > 0
+        # rows no token drew get a gradient from the head alone
+        unseen = np.setdiff1d(np.arange(256), np.asarray(tokens))
+        assert not float(jnp.abs(lookup[unseen]).max())
+        assert float(jnp.abs(whole[unseen]).max()) > 0
 
 
 @pytest.mark.parametrize("dense", [0, 1, 2])
@@ -161,66 +105,6 @@ def test_the_layer_pattern_says_which_block_is_which(dense, first):
     stage = models.build_model("lfm2_24b_a2b", first_layer=1, depth=5)
     assert stage.layer_kinds == ("conv", "full_attention", "conv", "conv", "conv")
     assert stage.dense_here == 1
-
-
-@pytest.mark.parametrize("recompute", [True, False], ids=["recomputed", "kept"])
-@pytest.mark.parametrize("rank", [0, 1])
-def test_logits_loss_every_gradient_and_the_bias_equal_the_reference(rank, recompute):
-    """Logits, the loss and its terms, the share of the choices on held
-    experts, the gradient on every leaf (the embedding's from both of its
-    sources), and the biases one step leaves, for either of the two chips
-    that share the layers (the head in chunks of 48 of 100 positions), with
-    every block recomputed and with none."""
-    model = build(share_rank=rank, recompute=recompute)
-    params, biases, tokens, labels = seeded(model, seed=rank)
-    arch = architecture(model)
-    np.testing.assert_allclose(
-        model.apply({"params": params, "batch_stats": biases}, tokens),
-        reference.logits(params, biases, tokens, architecture=arch), atol=2e-5)
-    (loss, (extra, after, _)), grads = jax.value_and_grad(
-        lambda p: program_loss(model, p, biases, tokens, labels), has_aux=True)(params)
-
-    def plain(p):
-        terms = reference.loss(p, biases, tokens, labels, architecture=arch)
-        return terms["loss"], terms
-
-    (_, want), want_grads = jax.value_and_grad(plain, has_aux=True)(params)
-    np.testing.assert_allclose(loss, want["loss"], rtol=1e-6)
-    for got, term in (("ce", "ce"), ("moe_aux", "load_balance"),
-                      ("moe_held_row_share", "held_row_share")):
-        np.testing.assert_allclose(extra[got], want[term], rtol=2e-6, err_msg=got)
-    assert float(extra["moe_dropped"]) == 0.0
-    assert 0.3 < float(extra["moe_held_row_share"]) < 0.7
-    assert_trees_close(grads, want_grads, 2e-5)
-    np.testing.assert_array_equal(
-        mixture_biases(model, after),
-        reference.bias_after(mixture_biases(model, biases), want["counts"], 0.001))
-    np.testing.assert_allclose(
-        extra["router_bias_abs_max"], jnp.abs(mixture_biases(model, after)).max())
-
-
-def test_the_tied_heads_gradient_has_two_sources():
-    """The embedding's gradient is the lookup's plus the head's: each alone
-    is another matrix, and their sum is the gradient of the tied loss."""
-    model = build()
-    params, biases, tokens, labels = seeded(model, seq=40)
-
-    def loss(table, head):
-        tied = {**params, "tok_embed": {"embedding": table}}
-        outputs = model.apply(
-            {"params": tied, "batch_stats": biases}, tokens, hidden_only=True)
-        return model.head_loss(outputs, head.T, labels, topk=(1, 5))[0]
-
-    table = params["tok_embed"]["embedding"]
-    lookup, head = jax.grad(loss, argnums=(0, 1))(table, table)
-    whole = jax.grad(lambda p: program_loss(model, p, biases, tokens, labels)[0])(
-        params)["tok_embed"]["embedding"]
-    np.testing.assert_allclose(whole, lookup + head, atol=1e-7)
-    assert float(jnp.abs(lookup).max()) > 0 and float(jnp.abs(head).max()) > 0
-    # rows no token drew get a gradient from the head alone
-    unseen = np.setdiff1d(np.arange(256), np.asarray(tokens))
-    assert not float(jnp.abs(lookup[unseen]).max())
-    assert float(jnp.abs(whole[unseen]).max()) > 0
 
 
 def _explicit_conv(bcu, w):
@@ -338,40 +222,6 @@ def test_an_equal_head_call_keeps_the_index_maps_it_had():
     assert fa._kv_group(jnp.zeros((1, 8, 4, 2)), *[jnp.zeros((1, 2, 4, 2))] * 2) == 4
 
 
-@pytest.mark.parametrize("chips", [2, 4])
-def test_the_shares_of_a_layer_add_up_to_the_whole_layer(chips):
-    """The guide's share test: with 8 experts split over 2 and over 4 ranks,
-    the ranks' partial mixture outputs (there is no shared expert to count
-    once) add up to what the UNCUT reference gives for the whole layer."""
-    E, k, d, f = 8, 2, 64, 32
-    whole = glm_moe.Mixture(
-        d, f, E, k, 0, 1.0, 0.001, (0, E), jnp.float32, norm_eps=1e-6)
-    x = jax.random.normal(jax.random.key(0), (2, 24, d))
-    variables = flax.linen.meta.unbox(whole.init(jax.random.key(1), x))
-    bias = 0.05 * jax.random.normal(jax.random.key(2), (E,))
-    p = variables["params"]
-    assert set(p) == {"router", "w_gate", "w_up", "w_down"}
-    arch = {"num_experts_per_tok": k, "routed_scaling_factor": 1.0,
-            "route_norm_eps": 1e-6, "share_rank": 0, "experts_held": E}
-    with jax.default_matmul_precision("highest"):
-        want = reference._mixture(x, p, bias, arch)[0]
-    parts, count = [], E // chips
-    for rank in range(chips):
-        held = slice(rank * count, (rank + 1) * count)
-        mine = {**p, **{n: p[n][held] for n in ("w_gate", "w_up", "w_down")}}
-        out, stats = glm_moe.Mixture(
-            d, f, E, k, 0, 1.0, 0.001, (rank * count, count), jnp.float32,
-            norm_eps=1e-6,
-        ).apply({"params": mine, "batch_stats": {"router_bias": bias}}, x)
-        parts.append(out)
-        assert 0 < float(stats["held_row_share"]) < 1
-        with jax.default_matmul_precision("highest"):  # the reference's share
-            np.testing.assert_allclose(out, reference._mixture(
-                x, mine, bias, arch, held=(rank * count, count))[0], atol=2e-6)
-    np.testing.assert_allclose(sum(parts), want, atol=2e-6)
-    assert float(jnp.abs(parts[0] - want).max()) > 1e-3  # no share is the layer
-
-
 def test_the_weights_are_normalised_over_the_chosen_plus_the_given_epsilon():
     scores = jnp.asarray([[0.9, 0.5, 0.4, 0.1]])
     bias = jnp.zeros((4,))
@@ -386,3 +236,51 @@ def test_the_weights_are_normalised_over_the_chosen_plus_the_given_epsilon():
         moe_ops.top_k_biased(scores, bias, 2, 1.8, 1e-20)[0])
     assert glm_moe.Mixture.norm_eps == 1e-20
     assert lfm2_moe.LFM2MoE.route_norm_eps == 1e-6
+
+
+def test_the_model_says_its_layer_kinds_and_the_grouped_flash_its_group_once_a_shape(
+        tmp_path):
+    from unittest import mock
+
+    from distribuuuu_tpu.ops import pallas as kernel_tier
+    from distribuuuu_tpu.telemetry import schema, spans
+
+    kernel_tier.reset_selection()
+    spans.setup_telemetry(str(tmp_path), 0)
+    try:
+        model = build().clone(first_layer=1, depth=3, seq_len=24, share_rank=1)
+        state = flax.linen.meta.unbox(
+            model.init(jax.random.key(0), jnp.full((3, 24), 256, jnp.int32)))
+        for _ in range(2):
+            model.apply(state, jnp.full((3, 24), 300, jnp.int32), hidden_only=True)
+        q = jnp.zeros((1, 4, 256, 16))
+        fa.flash_attention(q, q[:, :2], q[:, :2], causal=True, interpret=True)
+    finally:
+        spans.close_telemetry()
+    plans = contract.records(tmp_path, "share.plan")
+    assert len(plans) == 1
+    schema.check_fields("share.plan", plans[0])
+    assert {k: plans[0][k] for k in (
+        "share_chips", "share_rank", "experts_held", "experts_total", "vocab_held",
+        "vocab_total", "layer_kinds", "dense_layers",
+    )} == {"share_chips": 2, "share_rank": 1, "experts_held": 4, "experts_total": 8,
+           "vocab_held": 256, "vocab_total": 512,
+           "layer_kinds": ["conv", "full_attention", "conv"], "dense_layers": 1}
+    assert "every block of either kind" in plans[0]["recomputed"]
+    # the scan path names nothing; a block keeps its float32 input and its
+    # mixer's output (float32 here), not its FFN's: no norm follows it
+    assert plans[0]["kept_branch_bytes"] == 3 * 3 * 24 * 64 * 4
+    assert plans[0]["kept_bytes"] == 2 * 3 * 3 * 24 * 64 * 4
+    chose = [r for r in contract.records(tmp_path, "kernel.select")
+             if r["op"] == "flash_attn" and r["impl"] == "pallas"]
+    assert chose and (chose[-1]["kv_group"], chose[-1]["kv_heads"]) == (2, 2)
+    assert {"blk_q", "blk_k", "tiles_visited"} <= set(chose[-1])
+    # what a recomputed block keeps of a grouped call: k and v at their own heads
+    equal = fa.kept_under_remat_bytes((2, 32, 8192, 64), 2)
+    grouped = fa.kept_under_remat_bytes((2, 32, 8192, 64), 2, kv_heads=8)
+    assert equal == grouped  # 0 here: the CPU takes the scan path
+    with mock.patch.object(kernel_tier, "interpret_mode", lambda: False), \
+            mock.patch.object(kernel_tier, "compiled_across_devices", lambda: False):
+        equal = fa.kept_under_remat_bytes((2, 32, 8192, 64), 2)
+        grouped = fa.kept_under_remat_bytes((2, 32, 8192, 64), 2, kv_heads=8)
+    assert equal - grouped == 2 * 2 * 24 * 8192 * 64 * 2

@@ -1,132 +1,36 @@
 """OLMoE on the normal path, against the benchmark's plain reference
 (``benchmark/reference/olmoe.py``), at sizes the CPU runs: hidden 64, 4 heads
 of 16, 8 experts of width 32 with 2 a token (and 16 with 8), vocab 512, 128
-tokens and a length that is no multiple of the loss chunk."""
+tokens and a length that is no multiple of the loss chunk. The contracts it
+answers are ``tests/decoder_contract.py``'s; below them, what only OLMoE has
+or was first to test: every expert held and none dropped, the chunked head
+(``ops/token_head.py``), the step with and without chunks."""
 
-import importlib.util
-import os
-
-import flax
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import distribuuuu_tpu.config as config
-from distribuuuu_tpu import models, trainer
-from distribuuuu_tpu.config import cfg
-from distribuuuu_tpu.models.olmoe import OLMoE
+import decoder_contract as contract
+from decoder_contract import CHUNK, VOCAB, olmoe_terms, olmoe_total, walk, wide_matmuls
+from distribuuuu_tpu import models
 from distribuuuu_tpu.ops import moe as moe_ops
 from distribuuuu_tpu.ops import token_head
 from distribuuuu_tpu.parallel import mesh as mesh_lib
-from distribuuuu_tpu.parallel.partition import lowering
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_spec = importlib.util.spec_from_file_location(
-    "olmoe_reference", os.path.join(REPO, "benchmark", "reference", "olmoe.py")
-)
-reference = importlib.util.module_from_spec(_spec)
-_spec.loader.exec_module(reference)
-
-VOCAB, CHUNK = 512, 48
-SIZES = {  # (experts, per token)
-    "top2of8": dict(num_experts=8, top_k=2),
-    "top8of16": dict(num_experts=16, top_k=8),
-}
-AUX_W, Z_W = 0.01, 0.001
+ROW = contract.ROWS["olmoe"]
+reference = ROW.reference
+SIZES = {name: kw for name, (kw, _) in ROW.gradient_cases.items()}  # (experts, per token)
 
 
-def build(size="top2of8", dtype=jnp.float32, seq_len=128, **kw):
-    return OLMoE(
-        vocab_size=VOCAB, seq_len=seq_len, dim=64, depth=2, num_heads=4,
-        expert_hidden=32, dtype=dtype, **SIZES[size], **kw,
-    )
-
-
-def architecture(model) -> dict:
-    return {
-        "layers": model.depth, "hidden_size": model.dim,
-        "intermediate_size": model.expert_hidden,
-        "num_attention_heads": model.num_heads, "num_experts": model.num_experts,
-        "num_experts_per_tok": model.top_k, "rms_norm_eps": model.rms_norm_eps,
-        "rope_theta": model.rope_theta, "vocab_size": model.vocab_size,
-        "max_position_embeddings": model.seq_len,
-    }
+def build(size="top2of8", **kw):
+    return contract.build(ROW, **SIZES[size], **kw)
 
 
 def seeded(model, batch=2, seq=128, seed=0):
-    """(params, tokens, labels): weights from the program's initialiser with
-    the norm scales moved off 1 so that a dropped scale would show."""
-    k_init, k_tok, k_scale = jax.random.split(jax.random.key(seed), 3)
-    params = flax.linen.meta.unbox(
-        model.init(k_init, jnp.zeros((1, 8), jnp.int32))["params"]
-    )
-    flat, tree = jax.tree_util.tree_flatten_with_path(params)
-    keys = jax.random.split(k_scale, len(flat))
-    flat = [
-        leaf * (1 + 0.2 * jax.random.normal(k, leaf.shape))
-        if "scale" in jax.tree_util.keystr(path) else leaf
-        for (path, leaf), k in zip(flat, keys)
-    ]
-    params = jax.tree.unflatten(tree, flat)
-    ids = jax.random.randint(k_tok, (batch, seq + 1), 0, VOCAB, jnp.int32)
-    return params, ids[:, :-1], ids[:, 1:]
-
-
-def program_terms(model, params, tokens, labels, chunk=CHUNK):
-    """The three loss terms and the experts chosen, from the modules the
-    step's ``loss_fn`` calls."""
-    hidden, sown = model.apply(
-        {"params": params}, tokens, train=True, hidden_only=True,
-        mutable=["intermediates", "moe_z", "moe_stats", "moe_load", "moe_route"],
-    )
-    ce, _ = token_head.loss_and_accuracy(
-        hidden, model.head_kernel(params), labels, topk=(1,), chunk=chunk
-    )
-
-    def mean(name):
-        leaves = jax.tree.leaves(sown[name])
-        return sum(leaves) / len(leaves)
-
-    return {
-        "ce": ce, "load_balance": mean("intermediates"),
-        "router_z": mean("moe_z"), "dropped": mean("moe_stats"),
-        "experts": jnp.stack(jax.tree.leaves(sown["moe_route"])),
-    }
-
-
-def total(terms):
-    return terms["ce"] + AUX_W * terms["load_balance"] + Z_W * terms["router_z"]
-
-
-def test_registry_and_shapes():
-    assert {"olmoe_1b_7b", "olmoe_tiny"} <= set(models.available_models())
-    model = models.build_model("olmoe_tiny", num_classes=VOCAB, dtype=jnp.float32)
-    params, tokens, _ = seeded(model, seq=16)
-    assert model.apply({"params": params}, tokens).shape == (2, 16, VOCAB)
-    assert model.apply({"params": params}, tokens, hidden_only=True).shape == (2, 16, 64)
-    published = models.build_model("olmoe_1b_7b")
-    assert (published.dim, published.depth, published.num_heads,
-            published.num_experts, published.top_k, published.expert_hidden,
-            published.vocab_size, published.seq_len) == (
-        2048, 16, 16, 64, 8, 1024, 50304, 4096)
-
-
-@pytest.mark.parametrize("size", sorted(SIZES))
-@pytest.mark.parametrize("seq", [128, 100])
-def test_float32_logits_and_experts_equal_the_reference(size, seq):
-    model = build(size)
-    params, tokens, labels = seeded(model, seq=seq)
-    got = model.apply({"params": params}, tokens)
-    want, _, chosen = reference.forward(params, tokens, architecture=architecture(model))
-    np.testing.assert_allclose(got, want, atol=1e-5, rtol=0)
-    terms = program_terms(model, params, tokens, labels)
-    # the SET of experts of every token, layer by layer
-    assert np.array_equal(
-        np.sort(terms["experts"].reshape(model.depth, -1, model.top_k), -1),
-        np.sort(np.stack(chosen), -1),
-    )
-    assert float(terms["dropped"]) == 0.0
+    """(params, tokens, labels) of the contract's ``seeded``, 128 tokens long."""
+    params, _, tokens, labels = contract.seeded(model, batch, seq, seed)
+    return params, tokens, labels
 
 
 def _agreement(model, got, want):
@@ -135,81 +39,60 @@ def _agreement(model, got, want):
     return float((got[:, :, None] == want[:, None, :]).any(-1).mean())
 
 
-def test_bfloat16_program_stays_near_the_reference_because_its_float32_parts_do(
-    monkeypatch,
-):
-    """The bfloat16 program (bfloat16 matmul inputs; router, norms, softmaxes
-    and loss in float32) against the float32 reference, and what each
-    tolerance catches. Readings at this size (4 x 128 tokens):
+class TestOLMoE(contract.Decoder, contract.RecomputesNothingInItsCell,
+                contract.ComputesInBfloat16):
+    row = ROW
 
-    * cross-entropy, relative: program 4e-6; the float32 program 1e-7 (so it
-      passes 2e-4 1000x inside); the reference run in bfloat16 THROUGHOUT
-      (router, norms, softmaxes, loss too) 8e-4. A log-sum-exp over 512
-      logits near 6.2 cannot be held in 8 bits. 2e-4 separates them.
-    * experts chosen, share of (token, slot) pairs whose expert the
-      reference chose too: program 0.9985 (the matmuls upstream read
-      bfloat16, so a near-tie at the k-th place can fall the other way); the
-      same program with a bfloat16 ROUTER 0.984. 0.995 separates them; the
-      float32 program scores 1.0.
-    * logits: rms error under 1 % of their spread (program 0.6 %, float32
-      program 2e-5 %). This one does NOT tell a bfloat16 router or bfloat16
-      scores apart (0.8 % for the all-bfloat16 reference): matmul input
-      rounding dominates a logit. The two checks above are the ones with
-      teeth."""
-    model32, model16 = build(), build(dtype=jnp.bfloat16)
-    params, tokens, labels = seeded(model32, batch=4)
-    arch = architecture(model32)
-    want = reference.loss(params, tokens, labels, architecture=arch)
-    low = reference.loss(params, tokens, labels, architecture=arch,
-                         precision=jnp.bfloat16)
-    got = program_terms(model16, params, tokens, labels)
+    def bfloat16_of_its_own(self, model16, params, tokens, labels, got, want, arch,
+                            monkeypatch):
+        """What each tolerance catches. Readings at this size (4 x 128 tokens):
 
-    def off(terms):
-        return abs(float(terms["ce"]) - float(want["ce"])) / float(want["ce"])
-
-    assert off(got) < 2e-4 < off(low)
-    assert off(program_terms(model32, params, tokens, labels)) < 2e-6
-    assert _agreement(model16, got["experts"], want["experts"]) >= 0.995
-    monkeypatch.setattr(
-        moe_ops, "gating_probs",
-        lambda x, w: jax.nn.softmax(
-            x.astype(jnp.bfloat16) @ w.astype(jnp.bfloat16), axis=-1
-        ).astype(jnp.float32),
-    )
-    routed_low = program_terms(model16, params, tokens, labels)
-    assert _agreement(model16, routed_low["experts"], want["experts"]) < 0.995
-    monkeypatch.undo()
-    logits = model16.apply({"params": params}, tokens)
-    assert logits.dtype == jnp.float32  # the head accumulates and returns float32
-    plain = reference.logits(params, tokens, architecture=arch)
-    assert float(jnp.sqrt(jnp.mean((logits - plain) ** 2))) < 0.01 * float(plain.std())
+        * cross-entropy, relative: program 4e-6; the float32 program 1e-7 (so it
+          passes 2e-4 1000x inside); the reference run in bfloat16 THROUGHOUT
+          (router, norms, softmaxes, loss too) 8e-4. A log-sum-exp over 512
+          logits near 6.2 cannot be held in 8 bits. 2e-4 separates them.
+        * experts chosen, share of (token, slot) pairs whose expert the
+          reference chose too: program 0.9985 (the matmuls upstream read
+          bfloat16, so a near-tie at the k-th place can fall the other way); the
+          same program with a bfloat16 ROUTER 0.984. 0.995 separates them; the
+          float32 program scores 1.0.
+        * logits: rms error under 1 % of their spread (program 0.6 %, float32
+          program 2e-5 %). This one does NOT tell a bfloat16 router or bfloat16
+          scores apart (0.8 % for the all-bfloat16 reference): matmul input
+          rounding dominates a logit. The two checks above are the ones with
+          teeth."""
+        assert _agreement(model16, got.extra["experts"], want["experts"]) >= 0.995
+        monkeypatch.setattr(
+            moe_ops, "gating_probs",
+            lambda x, w: jax.nn.softmax(
+                x.astype(jnp.bfloat16) @ w.astype(jnp.bfloat16), axis=-1
+            ).astype(jnp.float32),
+        )
+        routed_low = olmoe_terms(model16, params, tokens, labels)
+        assert _agreement(model16, routed_low["experts"], want["experts"]) < 0.995
+        monkeypatch.undo()
+        logits = model16.apply({"params": params}, tokens)
+        assert logits.dtype == jnp.float32  # the head accumulates and returns float32
+        plain = reference.logits(params, tokens, architecture=arch)
+        assert float(jnp.sqrt(jnp.mean((logits - plain) ** 2))) < 0.01 * float(plain.std())
 
 
 @pytest.mark.parametrize("size", sorted(SIZES))
-def test_loss_terms_and_every_gradient_equal_the_reference(size):
+@pytest.mark.parametrize("seq", [128, 100])
+def test_float32_logits_and_experts_equal_the_reference(size, seq):
     model = build(size)
-    params, tokens, labels = seeded(model, seq=100)
-    arch = architecture(model)
-
-    def program(p):
-        terms = program_terms(model, p, tokens, labels)
-        return total(terms), terms
-
-    def plain(p):
-        terms = reference.loss(p, tokens, labels, architecture=arch)
-        return total(terms), terms
-
-    (_, got), got_grads = jax.value_and_grad(program, has_aux=True)(params)
-    (_, want), want_grads = jax.value_and_grad(plain, has_aux=True)(params)
-    for term in ("ce", "load_balance", "router_z"):
-        np.testing.assert_allclose(got[term], want[term], rtol=1e-5)
-    flat_got = jax.tree_util.tree_leaves_with_path(got_grads)
-    flat_want = jax.tree.leaves(want_grads)
-    assert len(flat_got) == len(flat_want) == 3 + 12 * model.depth
-    for (path, g), w in zip(flat_got, flat_want):
-        norm = float(jnp.linalg.norm(w))
-        assert norm > 0, jax.tree_util.keystr(path)
-        assert float(jnp.linalg.norm(g - w)) <= 1e-4 * norm, jax.tree_util.keystr(path)
+    params, tokens, labels = seeded(model, seq=seq)
+    got = model.apply({"params": params}, tokens)
+    want, _, chosen = reference.forward(
+        params, tokens, architecture=ROW.architecture(model))
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=0)
+    terms = olmoe_terms(model, params, tokens, labels)
+    # the SET of experts of every token, layer by layer
+    assert np.array_equal(
+        np.sort(terms["experts"].reshape(model.depth, -1, model.top_k), -1),
+        np.sort(np.stack(chosen), -1),
+    )
+    assert float(terms["dropped"]) == 0.0
 
 
 def _head_inputs(dtype=jnp.float32):
@@ -253,12 +136,7 @@ def test_chunked_head_equals_the_whole_head():
 
 def _vocabulary_wide_matmuls(jaxpr) -> int:
     """``dot_general``s with the vocabulary among their dimensions."""
-    return sum(
-        eqn.primitive.name == "dot_general" and any(
-            VOCAB in getattr(v.aval, "shape", ())
-            for v in list(eqn.invars) + list(eqn.outvars))
-        for eqn in _walk(jaxpr)
-    )
+    return len(wide_matmuls(jaxpr, VOCAB))
 
 
 @pytest.mark.parametrize("chunk,chunks", [(CHUNK, 3), (0, 1)])
@@ -335,25 +213,6 @@ def test_rank_breaks_ties_as_top_k_does():
     assert rank.tolist() == [[1, 3]]  # one equal logit at a lower index; three larger
 
 
-def _lowered(chunk, seq_len=100, dtype="float32"):
-    config.reset_cfg()
-    cfg.MODEL.ARCH = "olmoe_tiny"
-    cfg.MODEL.NUM_CLASSES = VOCAB
-    cfg.MODEL.MOE.AUX_WEIGHT, cfg.MODEL.MOE.Z_WEIGHT = AUX_W, Z_W
-    cfg.LM.SEQ_LEN = seq_len
-    cfg.DEVICE.COMPUTE_DTYPE = dtype
-    cfg.OPTIM.OPTIMIZER, cfg.OPTIM.BASE_LR = "adamw", 1e-3
-    cfg.MESH.DATA = 8
-    topology = trainer.check_trainer_mesh()
-    model = trainer.build_model_from_cfg(topology).clone(head_chunk=chunk)
-    from distribuuuu_tpu.utils.optim import construct_optimizer
-
-    return lowering.lower(
-        model, construct_optimizer(), 5, mesh=mesh_lib.build_mesh(data=8),
-        topology=topology, im_size=32,
-    )
-
-
 def test_the_step_chunked_equals_the_step_unchunked_and_the_reference():
     """Through ``lowering.lower`` on the 8-device data mesh: the same metrics
     keys and values and the same updated parameters with ``head_chunk`` 48 and 0;
@@ -362,7 +221,7 @@ def test_the_step_chunked_equals_the_step_unchunked_and_the_reference():
     ids = np.random.default_rng(1).integers(0, VOCAB, (8, 101)).astype(np.int32)
     host = {"image": ids[:, :-1], "label": ids[:, 1:], "mask": np.ones(8, np.float32)}
     for chunk in (CHUNK, 0):
-        low = _lowered(chunk)
+        low = contract.lowered(ROW, chunk)
         state = low.init_state(jax.random.key(0), 32)
         params = jax.device_get(state.params)
         batch = low.put_batch(host)
@@ -375,7 +234,6 @@ def test_the_step_chunked_equals_the_step_unchunked_and_the_reference():
         evaluated = jax.device_get(low.eval_step(state, batch))
         state, metrics = low.train_step(state, {k: batch[k] for k in ("image", "label")})
         out[chunk] = jax.device_get((metrics, state.params, evaluated))
-    config.reset_cfg()
     (m_parts, p_parts, e_parts), (m_whole, p_whole, e_whole) = out[CHUNK], out[0]
     assert set(m_parts) == set(m_whole) >= {
         "loss", "top1", "topk", "ce", "moe_aux", "moe_z", "moe_dropped",
@@ -389,14 +247,12 @@ def test_the_step_chunked_equals_the_step_unchunked_and_the_reference():
         np.testing.assert_allclose(e_parts[key], e_whole[key], rtol=1e-5)
     assert float(e_parts["count"]) == 8 * 100
     assert float(m_parts["moe_dropped"]) == 0.0
-    model = models.build_model("olmoe_tiny", num_classes=VOCAB, dtype=jnp.float32,
-                               seq_len=100)
     want = reference.loss(params, host["image"], host["label"],
-                          architecture=architecture(model))
+                          architecture=ROW.architecture(build(seq_len=100)))
     np.testing.assert_allclose(m_parts["ce"], want["ce"], rtol=1e-5)
     np.testing.assert_allclose(m_parts["moe_aux"], want["load_balance"], rtol=1e-5)
     np.testing.assert_allclose(m_parts["moe_z"], want["router_z"], rtol=1e-5)
-    np.testing.assert_allclose(m_parts["loss"], total(want), rtol=1e-5)
+    np.testing.assert_allclose(m_parts["loss"], olmoe_total(want), rtol=1e-5)
 
 
 def test_nothing_is_dropped_when_one_expert_takes_half_of_all_assignments():
@@ -412,31 +268,20 @@ def test_nothing_is_dropped_when_one_expert_takes_half_of_all_assignments():
         block = params[f"Block_{i}"]
         block["moe_norm"]["scale"] = block["moe_norm"]["scale"].at[1].set(1.0)
         block["moe"]["router"] = block["moe"]["router"].at[1, 3].set(20.0)
-    terms = program_terms(model, params, tokens, labels)
+    terms = olmoe_terms(model, params, tokens, labels)
     experts = np.asarray(terms["experts"]).reshape(model.depth, -1, model.top_k)
     assert (experts == 3).any(axis=-1).all()  # every token, every layer
     counts = moe_ops.expert_counts(jnp.asarray(experts[0]), model.num_experts)
     assert int(counts[3]) == experts.shape[1] == int(counts.sum()) // 2
     assert float(moe_ops.load_max_over_mean(counts)) == pytest.approx(4.0)
     assert float(terms["dropped"]) == 0.0
-    want = reference.loss(params, tokens, labels, architecture=architecture(model))
+    want = reference.loss(params, tokens, labels, architecture=ROW.architecture(model))
     np.testing.assert_allclose(terms["ce"], want["ce"], rtol=1e-5)
     np.testing.assert_allclose(
         model.apply({"params": params}, tokens),
-        reference.logits(params, tokens, architecture=architecture(model)),
+        reference.logits(params, tokens, architecture=ROW.architecture(model)),
         atol=2e-5,
     )
-
-
-def _walk(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs inside its equations."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for value in eqn.params.values():
-            for inner in value if isinstance(value, (list, tuple)) else [value]:
-                inner = getattr(inner, "jaxpr", inner)
-                if hasattr(inner, "eqns"):
-                    yield from _walk(inner)
 
 
 def test_expert_matmuls_take_top_k_rows_a_token_not_one_an_expert():
@@ -448,9 +293,9 @@ def test_expert_matmuls_take_top_k_rows_a_token_not_one_an_expert():
     T, k, E = 2 * 128, model.top_k, model.num_experts
 
     def loss(p):
-        return total(program_terms(model, p, tokens, labels))
+        return olmoe_total(olmoe_terms(model, p, tokens, labels))
 
-    eqns = list(_walk(jax.make_jaxpr(jax.grad(loss))(params).jaxpr))
+    eqns = list(walk(jax.make_jaxpr(jax.grad(loss))(params).jaxpr))
     grouped = [e for e in eqns if "ragged_dot" in e.primitive.name]
     assert len(grouped) >= 3 * 3 * model.depth  # three matmuls, fwd + two bwd
     for eqn in grouped:
@@ -490,95 +335,33 @@ def test_sorted_experts_alone_match_a_dense_loop():
     np.testing.assert_allclose(out.reshape(-1, d), want, atol=1e-6)
 
 
-def test_lm_spec_table_places_every_leaf_and_the_mesh_rule_speaks():
-    from jax.sharding import PartitionSpec as P
-
-    from distribuuuu_tpu.parallel.partition import specs, topology
-
-    table = specs.lm_spec_table(moe_axis="expert")
-    model = build()
-    shapes = jax.eval_shape(
-        lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))
-    )["params"]
-    for path, _ in jax.tree_util.tree_leaves_with_path(flax.linen.meta.unbox(shapes)):
-        assert table.spec_for(specs.leaf_path(path)) is not None, specs.leaf_path(path)
-    assert table.spec_for("Block_0/moe/w_gate") == P("expert")
-    assert table.spec_for("Block_0/moe/w_down") == P("expert")
-    assert table.spec_for("Block_0/attn/q_proj/kernel") == P(None, "model")
-    assert table.spec_for("Block_0/attn/o_proj/kernel") == P("model")
-    assert table.spec_for("Block_0/attn/k_norm/scale") == P()
-    assert table.spec_for("Block_0/moe/router") == P()
-    assert table.spec_for("head") == P(None, "model")
-    config.reset_cfg()
-    cfg.MODEL.ARCH = "olmoe_tiny"
-    cfg.MESH.DATA, cfg.MESH.MODEL = 4, 2
-    with pytest.raises(topology.TopologyError, match="MESH.DATA=n meshes only, got model=2"):
-        topology.from_cfg(cfg, n_devices=8)
-    config.reset_cfg()
-
-
-def test_serving_refuses_the_arch_in_one_sentence():
-    import serve_net
+def test_the_generation_plane_refuses_the_arch_too():
+    """``serve_net.py``'s refusal is the contract's; the engine under it
+    (``lm/service.py``) says which archs it mirrors."""
+    from distribuuuu_tpu.config import cfg
     from distribuuuu_tpu.lm import service
 
-    config.reset_cfg()
     cfg.MODEL.ARCH = "olmoe_tiny"
     with pytest.raises(ValueError, match="gpt_\\* archs"):
         service.engine_from_cfg()
-    config.reset_cfg()
-    with pytest.raises(SystemExit, match="olmoe.*ROADMAP R1"):
-        serve_net.main(["--cfg", os.path.join(REPO, "config", "olmoe_1b_7b.yaml")])
-    config.reset_cfg()
-
-
-def test_train_net_trains_the_yaml_at_a_tiny_size(tmp_path, monkeypatch):
-    """``train_net.py --cfg config/olmoe_1b_7b.yaml`` with the tiny-size
-    override, through ``trainer.train_model``: one epoch on packed token
-    shards, a finite falling loss, an eval, a checkpoint."""
-    import train_net
-    from distribuuuu_tpu.data.shards import tokens as token_shards
-
-    S = 16
-    rng = np.random.default_rng(0)
-    docs = [bytes(rng.integers(32, 120, (400,)).astype(np.uint8)) for _ in range(12)]
-    for split in ("train", "val"):
-        token_shards.write_token_shards(
-            str(tmp_path / split), token_shards.pack_token_stream(docs, S), S,
-        )
-    out_dir = tmp_path / "out"
-    config.reset_cfg()
-    monkeypatch.setattr("sys.argv", [
-        "train_net.py", "--cfg", os.path.join(REPO, "config", "olmoe_1b_7b.yaml"),
-        "MODEL.ARCH", "olmoe_tiny", "MODEL.NUM_CLASSES", "512", "LM.SEQ_LEN", str(S),
-        "DEVICE.COMPUTE_DTYPE", "float32",
-        "TRAIN.BATCH_SIZE", "1", "TEST.BATCH_SIZE", "1", "TRAIN.WORKERS", "0",
-        "TRAIN.DATASET", str(tmp_path), "TEST.DATASET", str(tmp_path),
-        "TRAIN.PRINT_FREQ", "2", "OPTIM.MAX_EPOCH", "1", "OUT_DIR", str(out_dir),
-    ])
-    train_net.main()
-    config.reset_cfg()
-    assert any(name.startswith("ckpt") or "checkpoint" in name
-               for name in os.listdir(out_dir)), os.listdir(out_dir)
 
 
 @pytest.mark.parametrize("arch,want", [
-    ("olmoe_1b_7b", dict(token_batch=True, batch_norm=False, mesh_axes=("data",))),
-    ("olmoe_tiny", dict(token_batch=True, batch_norm=False, mesh_axes=("data",))),
     ("gpt_nano_moe", dict(token_batch=True, batch_norm=False, mesh_axes=None)),
     ("vit_tiny", dict(token_batch=False, batch_norm=False, mesh_axes=None)),
     ("resnet50", dict(token_batch=False, batch_norm=True, mesh_axes=None)),
     ("an_arch_the_zoo_lacks", dict(token_batch=False, batch_norm=True, mesh_axes=None)),
 ])
-def test_an_arch_declares_what_shared_code_asks_of_it(arch, want):
-    """``models.traits``: topology, specs, trainer and serve_net read the
-    arch's own declaration (models/traits.py), not its name."""
+def test_an_arch_that_declares_nothing_gets_its_familys_traits(arch, want):
+    """``models.traits`` beside the decoders (whose own declarations the
+    contract reads): an arch of the older families declares nothing and is
+    read by what it is."""
     from distribuuuu_tpu.parallel.partition import specs
 
     got = models.traits(arch)
     assert {k: getattr(got, k) for k in want} == want
     assert specs.is_token_arch(arch) is want["token_batch"]
-    assert bool(got.serve_refusal) is arch.startswith("olmoe")
-    assert (got.kwargs_from_cfg is not None) is arch.startswith("olmoe")
+    assert not got.serve_refusal and got.kwargs_from_cfg is None
 
 
 def test_flash_attention_runs_per_data_rank_on_a_mesh():
