@@ -44,6 +44,12 @@ SAFE_SCOPES = (
     # it, forward and transposed. A block under nn.remat resolves to the
     # line that applies it, so the file is the finest site there is
     r"models/(olmoe|ouro)\.py",
+    # ... and so do the decoders that are one chip's share of a layer
+    # (models/glm_moe.py's docstring; LFM2's, Trinity-Mini's and SDAR's
+    # blocks are models/share.py's): a pre-norm block adds each bf16 branch
+    # into the float32 stream on the block's own line, and recomputed, every
+    # cast inside it resolves to the line of run_blocks that applies it
+    r"models/share\.py",
     # the self-declaration convention: a DELIBERATE f32 region wraps
     # itself in jax.named_scope("<name>_fp32") at the promotion site
     # (attn_softmax_fp32, se_squeeze_fp32, …) — the code states the

@@ -171,6 +171,13 @@ def _check_and_coerce(new, old, full_key):
         # allow e.g. WEIGHT_DECAY-style float into int slot only if integral
         if float(new).is_integer():
             return int(new)
+    if isinstance(old, float) and isinstance(new, str):
+        # yaml 1.1 wants a dot in a float: "3e-05", python's own repr of one,
+        # comes back from it as a string
+        try:
+            return float(new)
+        except ValueError:
+            pass
     raise ValueError(
         f"Type mismatch ({old_type} vs {new_type}) for config key {full_key}: "
         f"cannot replace {old!r} with {new!r}"
@@ -355,7 +362,7 @@ _C.LM = CfgNode()
 # size, so generation prompts + new tokens must fit under it.
 _C.LM.SEQ_LEN = 256
 # Depth override for archs whose depth is a knob (olmoe_*, ouro_*, glm_*, lfm2_*,
-# trinity_mini/afmoe_tiny): 0
+# trinity_mini/afmoe_tiny, sdar_*): 0
 # keeps the arch's own. One chip holds 1 of OLMoE-1B-7B's 16 layers, 8 of
 # Ouro-2.6B's 48, or 1 + 4 of GLM-4.7-Flash's 47 as an eighth of each, with
 # its optimizer state (PERF.md section 4).
@@ -371,13 +378,13 @@ _C.LM.SHARE_CHIPS = 0
 # Which of the LM.SHARE_CHIPS chips that share a layer this program is: it
 # holds that rank's block of the experts and of the vocabulary's rows.
 _C.LM.SHARE_RANK = 0
-# The published layer a chip's stage starts at (the lfm2_* and afmoe archs,
-# models/lfm2_moe.py, models/afmoe.py): its LM.LAYERS layers are the published ``layer_types``
+# The published layer a chip's stage starts at (the lfm2_*, afmoe and sdar_* archs,
+# models/lfm2_moe.py, models/afmoe.py, models/sdar_moe.py): its LM.LAYERS layers are the published ``layer_types``
 # from here on, so a cut keeps the pattern's order and the leading dense
 # layers that fall into it. 1 with LM.LAYERS 5 is layers 1..5 of
 # LFM2-24B-A2B: one dense layer, then conv, attention, conv, conv mixtures.
 _C.LM.FIRST_LAYER = 0
-# Whether a block of the lfm2_* and afmoe archs is recomputed in the backward from its
+# Whether a block of the lfm2_*, afmoe and sdar_* archs is recomputed in the backward from its
 # float32 input and what the flash backward kernel reads (as the ouro_* and
 # glm_* archs always do), or keeps every activation: the first decoder here
 # whose state (7.5 GB) may leave a step room for them (PERF.md section 4).

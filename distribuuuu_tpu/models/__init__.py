@@ -44,6 +44,7 @@ from distribuuuu_tpu.models.ouro import ouro_2_6b, ouro_tiny  # noqa: F401
 from distribuuuu_tpu.models.glm_moe import glm_4_7_flash, glm_moe_tiny  # noqa: F401
 from distribuuuu_tpu.models.lfm2_moe import lfm2_24b_a2b, lfm2_moe_tiny  # noqa: F401
 from distribuuuu_tpu.models.afmoe import afmoe_tiny, trinity_mini  # noqa: F401
+from distribuuuu_tpu.models.sdar_moe import sdar_30b_a3b, sdar_moe_tiny  # noqa: F401
 from distribuuuu_tpu.models.traits import ArchTraits
 
 _REGISTRY = {}
@@ -107,6 +108,13 @@ for _fn in (
     # one chip's share of an expert-parallel group
     trinity_mini,
     afmoe_tiny,
+    # SDAR-30B-A3B-Chat (models/sdar_moe.py): Qwen3-MoE's block trained by
+    # block diffusion over a noised and a clean copy of every sequence (the
+    # mask inside the flash kernels), a softmax router renormalised over its
+    # choices in GLM's mixture, a loss over the masked positions alone; one
+    # chip's share of an expert-parallel group
+    sdar_30b_a3b,
+    sdar_moe_tiny,
 ):
     register_model(_fn)
 

@@ -145,13 +145,20 @@ class MLA(nn.Module):
 
 
 class Mixture(nn.Module):
-    """Sigmoid router with a balancing bias, this chip's share of the routed
-    experts, the shared expert (none where ``shared_experts`` is 0:
-    ``models/lfm2_moe.py``). Returns ``(out, statistics)``: ``aux`` (the
-    balancing term, ``ops/moe.balance_stats``' form on the scores normalised
-    over all experts), ``load_max_over_mean`` (all E), ``held_row_share``
-    (the share of the (token, slot) choices that fell on held experts) and
-    ``bias_abs_max``; sows ``moe_route/experts`` as ``models/olmoe.py``."""
+    """A router, this chip's share of the routed experts, the shared expert
+    (none where ``shared_experts`` is 0: ``models/lfm2_moe.py``). The router is
+    ``route`` where the model hands one over (``(tokens [T, d], router [d, E],
+    top_k) -> (probs, weights, indices)`` in float32, as
+    ``ops/moe.moe_ffn_sorted`` takes it: ``models/sdar_moe.py``'s softmax
+    renormalised over its choices), and carries no state; ``None`` is the
+    sigmoid router with a balancing bias (``scale``, ``bias_rate``,
+    ``norm_eps``), whose bias is the ``batch_stats`` variable ``router_bias``
+    that a rule moves in training. Returns ``(out, statistics)``: ``aux`` (the
+    balancing term, ``ops/moe.balance_stats``' form on the router's
+    probabilities over all experts), ``load_max_over_mean`` (all E),
+    ``held_row_share`` (the share of the (token, slot) choices that fell on
+    held experts) and, where there is a bias, ``bias_abs_max``; sows
+    ``moe_route/experts`` as ``models/olmoe.py``."""
 
     dim: int
     hidden: int
@@ -167,6 +174,7 @@ class Mixture(nn.Module):
     # added to the chosen scores' sum where the weights are normalised
     # (ops/moe.top_k_biased): 1e-20 is GLM's, models/lfm2_moe.py gives 1e-6
     norm_eps: float = 1e-20
+    route: Any = None  # the router as a function; None: sigmoid with a bias
 
     @nn.compact
     def __call__(self, x):
@@ -184,16 +192,18 @@ class Mixture(nn.Module):
             "w_up": expert("w_up", (count, d, f)),
             "w_down": expert("w_down", (count, f, d)),
         }
-        bias = self.variable(
-            "batch_stats", "router_bias", lambda: jnp.zeros((E,), jnp.float32))
+        route, bias = self.route, None
+        if route is None:
+            bias = self.variable(
+                "batch_stats", "router_bias", lambda: jnp.zeros((E,), jnp.float32))
+            route = functools.partial(
+                moe_ops.sigmoid_route, bias=bias.value, scale=self.scale,
+                eps=self.norm_eps)
         # the router reads the norm's float32 result, the experts its
         # rounding to the compute dtype
         out, verdict = moe_ops.moe_ffn_sorted(
             params, x.astype(self.dtype), top_k=self.top_k, mesh=self.mesh,
-            router_x=x, held=(first, E),
-            route=functools.partial(
-                moe_ops.sigmoid_route, bias=bias.value, scale=self.scale,
-                eps=self.norm_eps),
+            router_x=x, held=(first, E), route=route,
         )
         if self.shared_experts:
             with jax.named_scope("moe_shared"):
@@ -204,7 +214,7 @@ class Mixture(nn.Module):
             # read only by a caller that makes the collection mutable (the
             # benchmark's comparison with its reference)
             self.sow("moe_route", "experts", verdict["indices"])
-            if self.train and not self.is_initializing():
+            if bias is not None and self.train and not self.is_initializing():
                 bias.value = moe_ops.bias_after(bias.value, counts, self.bias_rate)
             share = counts.astype(jnp.float32) / counts.sum()
             stats = {
@@ -212,8 +222,9 @@ class Mixture(nn.Module):
                     share, verdict["probs"].mean(axis=0)),
                 "load_max_over_mean": moe_ops.load_max_over_mean(counts),
                 "held_row_share": share[first:first + count].sum(),
-                "bias_abs_max": jnp.abs(bias.value).max(),
             }
+            if bias is not None:
+                stats["bias_abs_max"] = jnp.abs(bias.value).max()
         return out, stats
 
 

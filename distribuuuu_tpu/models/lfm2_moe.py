@@ -87,11 +87,13 @@ class ShortConv(nn.Module):
 
 class Attention(nn.Module):
     """Grouped-query attention with a per-head RMSNorm on q and on k. The
-    defaults of the last four are LFM2's; ``models/afmoe.py`` gives them:
+    defaults of the last five are LFM2's; ``models/afmoe.py`` gives four:
     heads of ``head_dim`` (0: ``dim / num_heads``), a sliding ``window``
     (under the scope ``attn_window``), no ``rotary`` where a layer carries no
     position signal, and ``gated``: ``out = (heads * sigmoid(x W_g)) W_o``
-    (under ``attn_gate``)."""
+    (under ``attn_gate``); ``models/sdar_moe.py`` ``head_dim`` and
+    ``diffusion_block``: the rows are a noised and a clean copy of a sequence
+    under the block-diffusion mask (under ``attn_diffusion``)."""
 
     dim: int
     num_heads: int
@@ -105,6 +107,7 @@ class Attention(nn.Module):
     window: Any = None  # int: query t reads keys t - window < s <= t
     rotary: bool = True
     gated: bool = False
+    diffusion_block: Any = None  # int: ops/flash_attention's block-diffusion mask
 
     @nn.compact
     def __call__(self, x, positions):
@@ -123,14 +126,19 @@ class Attention(nn.Module):
                 t = rotary(t, positions, self.rope_theta)
             return t.astype(self.dtype)
 
-        windowed = (jax.named_scope("attn_window") if self.window is not None
-                    else contextlib.nullcontext())
-        with windowed:
+        masked = (jax.named_scope("attn_window") if self.window is not None
+                  else jax.named_scope("attn_diffusion")
+                  if self.diffusion_block is not None else contextlib.nullcontext())
+        with masked:
             q, k, v = normed("q", H), normed("k", G), heads("v", G)
             impl = VitAttention.resolve_impl(self.attn_impl, S, 0.0)
             if impl != "flash":  # the dense path takes q, k and v of one shape
                 k, v = (jnp.repeat(t, H // G, axis=1) for t in (k, v))
-            out = _attend(q, k, v, impl, self.dtype, self.mesh, self.window)
+            # the block-diffusion mask by keyword, and only where a layer has
+            # one: the entry's accepted callers hand it seven arguments
+            out = _attend(q, k, v, impl, self.dtype, self.mesh, self.window, **(
+                {} if self.diffusion_block is None
+                else {"diffusion_block": self.diffusion_block}))
             out = out.astype(self.dtype).transpose(0, 2, 1, 3).reshape(B, S, H * D)
             if self.gated:
                 with jax.named_scope("attn_gate"):

@@ -78,21 +78,29 @@ def rotary(x, positions, theta: float):
     return x * cos + rotated * sin
 
 
-def _attend(q, k, v, impl: str, dtype, mesh=None, window: int | None = None):
+def _attend(q, k, v, impl: str, dtype, mesh=None, window: int | None = None,
+            diffusion_block: int | None = None):
     """Causal softmax attention on ``[B, H, S, D]``; on a ``mesh`` whose data
     axis is populated the flash kernel runs per data rank. With a ``window``
-    query t reads keys ``t - window < s <= t`` (``models/afmoe.py``)."""
-    if impl == "flash":
-        from distribuuuu_tpu.ops import flash_attention as fa
+    query t reads keys ``t - window < s <= t`` (``models/afmoe.py``); with a
+    ``diffusion_block`` the S rows are a noised and a clean copy of a
+    sequence under the block-diffusion mask (``models/sdar_moe.py``;
+    ``ops/flash_attention.diffusion_mask``)."""
+    from distribuuuu_tpu.ops import flash_attention as fa
 
+    if impl == "flash":
         return fa.flash_attention(
-            q, k, v, causal=True, mesh=mesh, window=window)
+            q, k, v, causal=True, mesh=mesh, window=window,
+            diffusion_block=diffusion_block)
     S = q.shape[2]
     with jax.named_scope("attn_softmax_fp32"):
         scores = jnp.einsum(
             "bhqd,bhkd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32)
         ) * q.shape[-1] ** -0.5
-        keep = jnp.tril(jnp.ones((S, S), bool))
+        if diffusion_block is not None:
+            keep = fa.diffusion_mask(S, diffusion_block)
+        else:
+            keep = jnp.tril(jnp.ones((S, S), bool))
         if window is not None:  # and not the keys a window or more behind
             keep = keep & ~jnp.tril(jnp.ones((S, S), bool), -window)
         scores = jnp.where(keep[None, None], scores, jnp.float32(-1e30))

@@ -39,13 +39,15 @@ from distribuuuu_tpu.ops import token_head
 def mixture_metrics(stats) -> dict:
     """The step metrics every decoder with :class:`Mixture` layers reports,
     from their stacked statistics."""
-    return {
+    metrics = {
         "moe_aux": stats["aux"].mean(),
         "moe_dropped": jnp.float32(0.0),  # no capacity: nothing can drop
         "moe_load_max_over_mean": stats["load_max_over_mean"].max(),
         "moe_held_row_share": stats["held_row_share"].mean(),
-        "router_bias_abs_max": stats["bias_abs_max"].max(),
     }
+    if "bias_abs_max" in stats:  # a router that balances through a bias
+        metrics["router_bias_abs_max"] = stats["bias_abs_max"].max()
+    return metrics
 
 
 class ShareOfALayer(nn.Module):

@@ -23,7 +23,19 @@ wholly behind it: the forward's walk over key tiles starts at the first tile
 the block's FIRST row still sees, the backward's walk over query tiles ends
 at the last tile that still sees the key block (70 of causal's 136 tiles at
 8192 tokens, 512² blocks and a window of 2048; two tiles a row of blocks
-are then crossed, the diagonal's and the window's edge). Every tile they do
+are then crossed, the diagonal's and the window's edge). A third kind of mask,
+``diffusion_block`` (block diffusion's training pass over a noised copy of a
+sequence followed by its clean copy: a noised row reads the noised rows of
+its own block and the clean rows of earlier blocks, a clean row the clean
+rows of its own and earlier blocks), is no function of ``t - s``: each walk
+is then TWO ranges of tiles, the forward's a noised block's own diagonal
+stretch and the clean tiles before its block, or a clean block's clean tiles
+up to its diagonal, the backward's the transpose (288 of a causal walk's 528
+tiles at 2 x 8192 rows and 512² blocks, 48 of them crossed: the 16 + 16
+diagonal tiles of either half and the 16 clean tiles a noised block's own
+block ends in). The mask itself is two threshold tests on numbers a row and a
+key carry (:func:`_diffusion_codes`), so a tile pays what the causal mask
+pays. Every tile the walks
 visit runs the mask. Masking only the tiles the diagonal or the padding crosses was
 measured and is NOT done: a second loop body for them made the forward 5 %
 and the backward 2 % slower at d = 128 (the iotas, compare and select hide
@@ -50,7 +62,9 @@ in bf16, ~9.2k at D = 256 (8192 tokens hold 41.0 of the 48 MiB budget at
 512² blocks), half that in float32. A window changes no block: K and V (the
 forward) and q, dO and dq (the backward) stay whole-sequence resident, so a
 windowed layer is bounded by the same length though it reads ``window`` keys
-a row (a K/V block spec that follows the window is PERF.md section 7's). Longer sequences, any off-TPU call and
+a row (a K/V block spec that follows the window is PERF.md section 7's), and the
+block-diffusion mask's 2S rows are one sequence to the bound: 16,384 rows at D = 128
+hold 41.0 MiB. Longer sequences, any off-TPU call and
 a program that may span devices route to ``blockwise_attention`` — same
 exact-softmax math from HBM-resident tensors — and say so in a
 ``kernel.fallback`` record; the kernel path says its blocks and tile counts
@@ -61,8 +75,10 @@ Shapes it runs at: the token decoders' ``[B, 16, 4096, 128]`` causal
 ``[1, 20, 8192, 256]`` causal (``models/glm_moe.py``), grouped queries'
 ``[2, 32 on 8, 8192, 64]`` causal (``models/lfm2_moe.py``), a group of
 eight with and without a window of 2048, ``[2, 32 on 4, 8192, 128]`` causal
-(``models/afmoe.py``'s sliding and full layers), and ViT-Ti at 1024px ``[B,
-3, 4096, 64]``, non-causal.
+(``models/afmoe.py``'s sliding and full layers), the same group under the
+block-diffusion mask over a noised and a clean copy of 8192 tokens, ``[1, 32
+on 4, 16384, 128]`` (``models/sdar_moe.py``), and ViT-Ti at 1024px ``[B, 3,
+4096, 64]``, non-causal.
 """
 
 from __future__ import annotations
@@ -205,6 +221,10 @@ def _max(a, b):
     return max(a, b) if both else jnp.maximum(a, b)
 
 
+def _where(cond, a, b):
+    return (a if cond else b) if isinstance(cond, bool) else jnp.where(cond, a, b)
+
+
 def _key_tiles(j, blk_q, blk_k, lp, length, causal):
     """Query block ``j``'s walk over key tiles: ``(full, hi)``. It visits
     ``[0, hi)``: from ``hi`` on every score is masked. Of those, ``[0,
@@ -244,12 +264,121 @@ def _last_query_tile(j, blk_q, blk_k, lp, window):
     return _min(lp // blk_q, ((j + 1) * blk_k + window - 2) // blk_q + 1)
 
 
+# ---------------------------------------------------------------------------
+# the block-diffusion mask (``diffusion_block``). The call's rows are a
+# NOISED copy of a sequence followed by its CLEAN copy, each half padded to
+# ``half`` rows of which ``length`` are real; a row of either half at position
+# ``p`` of its half is in block ``p // block``. Query -> key:
+#
+#   noised -> noised  iff the same block        (two-sided inside a block)
+#   noised -> clean   iff an EARLIER block      (never its own block's answer)
+#   clean  -> clean   iff the same or an earlier block
+#   clean  -> noised  never
+#
+# The blocks divide ``half``, so no tile lies across the two halves, and each
+# walk is two ranges of tiles.
+# ---------------------------------------------------------------------------
+
+_FAR = 2 ** 30  # past every block index
+
+
+def _diffusion_codes(qpos, kpos, half, length, block, xp=jnp):
+    """The mask as two threshold tests on numbers a row and a key carry
+    (``keep = (k_first <= reach) & (k_second >= own)``), so that a tile pays
+    what the causal mask pays, two compares and an and, and everything else
+    is arithmetic on its thin ``[blk, 1]`` and ``[1, blk]`` positions:
+    ``reach`` the last clean block a row reads (its own for a clean row, the
+    one before for a noised row), ``own`` the one noised block it reads (none
+    for a clean row); a key's ``k_first`` is its block (less one for a noised
+    key, which the rows of ITS block must pass; past every block for a padded
+    key) and ``k_second`` its block (past every block for a clean key)."""
+
+    def blocks(pos):
+        clean = pos >= half
+        p = pos - xp.where(clean, half, 0)
+        if xp is jnp and block & (block - 1) == 0:  # a shift, not a division
+            return clean, p, p >> (block.bit_length() - 1)
+        return clean, p, p // block
+
+    q_clean, _, qb = blocks(qpos)
+    k_clean, kp, kb = blocks(kpos)
+    reach = xp.where(q_clean, qb, qb - 1)
+    own = xp.where(q_clean, _FAR, qb)
+    k_first = xp.where(kp < length, xp.where(k_clean, kb, kb - 1), _FAR)
+    k_second = xp.where(k_clean, _FAR, kb)
+    return reach, own, k_first, k_second
+
+
+def _diffusion_keep(qpos, kpos, half, length, block):
+    reach, own, k_first, k_second = _diffusion_codes(
+        qpos, kpos, half, length, block)
+    return (k_first <= reach) & (k_second >= own)
+
+
+def _diffusion_key_tiles(j, blk_q, blk_k, half, block):
+    """Query block ``j``'s walk over key tiles under the block-diffusion
+    mask, two ranges ``((lo, hi), (lo, hi))``: a noised block reads its own
+    diagonal stretch of the noised half and the clean tiles up to the block
+    before its last row's; a clean block no noised tile and the clean tiles
+    up to its own diagonal."""
+    r0, r1 = j * blk_q, (j + 1) * blk_q - 1
+    noised = r0 < half
+    first, last = _min(r0, half - 1) // block, _min(r1, half - 1) // block
+    own = (_where(noised, first * block // blk_k, 0),
+           _where(noised, _min((last + 1) * block - 1, half - 1) // blk_k + 1, 0))
+    # clean keys are read up to (not including) this position of their half
+    end = _where(noised, last * block,
+                 _min(((r1 - half) // block + 1) * block, half))
+    lo = half // blk_k
+    return own, (lo, _max(lo, _where(end > 0, (half + end - 1) // blk_k + 1, 0)))
+
+
+def _diffusion_query_tiles(j, blk_q, blk_k, half, block):
+    """Key block ``j``'s walk over query tiles, the transpose: a noised key
+    block is read by its own diagonal stretch of the noised rows alone; a
+    clean key block by the noised rows from the block AFTER its first key's
+    on, and by the clean rows from its first key's block on."""
+    k0, k1 = j * blk_k, (j + 1) * blk_k - 1
+    noised = k0 < half
+    first, last = _min(k0, half - 1) // block, _min(k1, half - 1) // block
+    own = (first * block // blk_q,
+           _min((last + 1) * block - 1, half - 1) // blk_q + 1)
+    c0 = (_max(k0, half) - half) // block  # the first clean key's block
+    after = (_min((c0 + 1) * block, half) // blk_q, half // blk_q)
+    clean = ((half + c0 * block) // blk_q, 2 * half // blk_q)
+    return (tuple(_where(noised, a, b) for a, b in zip(own, after)),
+            tuple(_where(noised, 0, c) for c in clean))
+
+
+def _diffusion_tile_counts(half, length, blk_q, blk_k, block):
+    import numpy as np
+
+    visited = crossed = 0
+    for j in range(2 * half // blk_q):
+        rows = np.arange(j * blk_q, (j + 1) * blk_q)
+        for lo, hi in _diffusion_key_tiles(j, blk_q, blk_k, half, block):
+            for t in range(lo, hi):
+                reach, own, k_first, k_second = _diffusion_codes(
+                    rows, np.arange(t * blk_k, (t + 1) * blk_k), half, length,
+                    block, xp=np)
+                whole = k_first.max() <= reach.min() and k_second.min() >= own.max()
+                visited, crossed = visited + 1, crossed + (not whole)
+    return visited, crossed
+
+
 def tile_counts(L: int, blk_q: int, blk_k: int, causal: bool,
-                window: int | None = None):
+                window: int | None = None, diffusion_block: int | None = None):
     """``(visited, crossed)`` score tiles of one sequence, ``blk_q``/``blk_k``
     as :func:`_resolve_blocks` snapped them: what ``kernel.select`` says.
     With a ``window`` a row of blocks is crossed at both ends, by the
-    diagonal and by the window's edge."""
+    diagonal and by the window's edge. With a ``diffusion_block`` ``L`` is
+    the call's 2S rows (a noised and a clean copy of S tokens) and the
+    blocks are those snapped to a half: 288 visited at S = 8192 and 512²
+    (the clean half's causal 136, the noised half's 16 diagonal tiles and
+    its 136 clean ones) where a causal walk of the 16,384 rows visits 528."""
+    if diffusion_block is not None:
+        return _diffusion_tile_counts(
+            _round_up(L // 2, 128), L // 2, blk_q, blk_k, diffusion_block)
     lp = _round_up(L, 128)
     visited = crossed = 0
     for j in range(lp // blk_q):
@@ -262,7 +391,21 @@ def tile_counts(L: int, blk_q: int, blk_k: int, causal: bool,
     return visited, crossed
 
 
-def _keep(qpos, kpos, length, causal, window=None):
+def diffusion_mask(rows: int, block: int, keys=None):
+    """The block-diffusion mask of ``rows`` = 2S rows, bool (query, key),
+    against every key or the key positions ``keys``: what the paths that hold
+    a score array apply (``blockwise_attention`` a chunk of keys at a time,
+    keys past the rows masked; the dense softmax of ``models/olmoe._attend``
+    whole; the kernels never build it)."""
+    half = rows // 2
+    keys = jnp.arange(rows) if keys is None else keys
+    return _diffusion_keep(
+        jnp.arange(rows)[:, None], keys[None, :], half, half, block)
+
+
+def _keep(qpos, kpos, length, causal, window=None, diffusion=None):
+    if diffusion is not None:
+        return _diffusion_keep(qpos, kpos, diffusion[0], length, diffusion[1])
     keep = kpos < length
     if causal:
         keep = keep & (kpos <= qpos)
@@ -277,7 +420,7 @@ def _keep(qpos, kpos, length, causal, window=None):
 
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, length, blk_k, causal,
-    window=None,
+    window=None, diffusion=None,
 ):
     q = q_ref[0]  # [blk_q, D]
     blk_q, d = q.shape
@@ -296,7 +439,8 @@ def _fwd_kernel(
             qpos = j * blk_q + jax.lax.broadcasted_iota(
                 jnp.int32, (blk_q, 1), 0)
             s = jnp.where(
-                _keep(qpos, kpos, length, causal, window), s, _NEG_BIG)
+                _keep(qpos, kpos, length, causal, window, diffusion), s,
+                _NEG_BIG)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
@@ -311,14 +455,22 @@ def _fwd_kernel(
     # see at all: that row leaves the tile with m = _NEG_BIG and p = 1
     # everywhere, and the first tile that holds a key it keeps (its own, at
     # the latest) wipes both through corr = exp(_NEG_BIG - m_new) = 0.
-    # Nothing divides before the walk ends.
-    _, hi = _key_tiles(j, blk_q, blk_k, lp, length, causal)
-    lo = 0 if window is None else _window_key_tiles(j, blk_q, blk_k, window)[0]
+    # Nothing divides before the walk ends. The block-diffusion mask's two
+    # ranges lean on the same: a clean row keeps nothing of a noised tile, and
+    # the first clean tile, which holds a key every clean row reads, wipes it.
+    if diffusion is None:
+        _, hi = _key_tiles(j, blk_q, blk_k, lp, length, causal)
+        lo = 0 if window is None else _window_key_tiles(j, blk_q, blk_k, window)[0]
+        walks = ((lo, hi),)
+    else:
+        walks = _diffusion_key_tiles(j, blk_q, blk_k, *diffusion)
     m0 = jnp.full((blk_q, 1), _NEG_BIG, jnp.float32)
     l0 = jnp.zeros((blk_q, 1), jnp.float32)
     a0 = jnp.zeros((blk_q, d), jnp.float32)
     # NOT unrolled: Mosaic keeps every unrolled iteration's float32 tile live
-    m, l, acc = jax.lax.fori_loop(lo, hi, tile, (m0, l0, a0))
+    m, l, acc = m0, l0, a0
+    for lo, hi in walks:
+        m, l, acc = jax.lax.fori_loop(lo, hi, tile, (m, l, acc))
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
     # the column of statistics leaves as a lane-major row: a [blk_q, 1]
@@ -334,7 +486,7 @@ def _fwd_kernel(
 
 def _bwd_kernel(
     q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
-    dq_acc, *, scale, length, blk_q, causal, window=None,
+    dq_acc, *, scale, length, blk_q, causal, window=None, diffusion=None,
 ):
     """Everything is TRANSPOSED (``sᵀ = k qᵀ``, ``[blk_k, blk_q]``): ``lse``
     and ``delta`` broadcast from their lane-major rows as they lie, four of
@@ -364,7 +516,8 @@ def _bwd_kernel(
             qpos = t * blk_q + jax.lax.broadcasted_iota(
                 jnp.int32, (1, blk_q), 1)
             s_t = jnp.where(
-                _keep(qpos, kpos, length, causal, window), s_t, _NEG_BIG)
+                _keep(qpos, kpos, length, causal, window, diffusion), s_t,
+                _NEG_BIG)
         p_t = jnp.exp(s_t - lse_ref[0, :, rows])  # lse: [1, blk_q]
         dv = dv + _dot(p_t.astype(dob.dtype), dob, (1, 0))  # [blk_k, D]
         dp_t = _dot(vb, dob, (1, 1))  # [blk_k, blk_q]
@@ -376,10 +529,15 @@ def _bwd_kernel(
     # causal block-skip: start at the first q tile the key block reaches;
     # with a window, end behind the last one that still sees it
     z = jnp.zeros((blk_k, d), jnp.float32)
-    last = lp // blk_q if window is None else _last_query_tile(
-        j, blk_q, blk_k, lp, window)
-    dk, dv = jax.lax.fori_loop(
-        _first_query_tile(j, blk_q, blk_k, causal), last, tile, (z, z))
+    if diffusion is None:
+        last = lp // blk_q if window is None else _last_query_tile(
+            j, blk_q, blk_k, lp, window)
+        walks = ((_first_query_tile(j, blk_q, blk_k, causal), last),)
+    else:
+        walks = _diffusion_query_tiles(j, blk_q, blk_k, *diffusion)
+    dk, dv = z, z
+    for first, last in walks:
+        dk, dv = jax.lax.fori_loop(first, last, tile, (dk, dv))
     # the score's scale, once on the float32 sums and not on every ds tile
     dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
@@ -446,32 +604,63 @@ def _under_the_old_name(t, name, interpret):
     )(t)
 
 
-def _windowed(window) -> dict:
-    """The kernels' ``window`` keyword, or none at all: a call without a
-    window builds the partial, and so the program, it always built."""
-    return {} if window is None else {"window": window}
+def _windowed(window, diffusion=None) -> dict:
+    """The kernels' ``window`` and ``diffusion`` keywords, or none at all: a
+    call without them builds the partial, and so the program, it always
+    built."""
+    return {**({} if window is None else {"window": window}),
+            **({} if diffusion is None else {"diffusion": diffusion})}
 
 
-def _pad_lhd(t, lp):
-    pad = lp - t.shape[1]
-    return jnp.pad(t, ((0, 0), (0, pad), (0, 0))) if pad else t
+def _pad_lhd(t, lp, halves: int = 1):
+    """``[bh, L, d]`` padded to ``lp`` rows; with ``halves = 2`` (the
+    block-diffusion layout) each half of the rows to half of ``lp``."""
+    bh, L, d = t.shape
+    pad = (lp - L) // halves
+    if not pad:
+        return t
+    if halves == 1:
+        return jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
+    t = t.reshape(bh, halves, L // halves, d)
+    return jnp.pad(t, ((0, 0), (0, 0), (0, pad), (0, 0))).reshape(bh, lp, d)
+
+
+def _unpad_lhd(t, L, halves: int = 1):
+    """:func:`_pad_lhd`'s inverse."""
+    bh, lp, d = t.shape
+    if halves == 1:
+        return t[:, :L]
+    return t.reshape(bh, halves, lp // halves, d)[:, :, :L // halves].reshape(bh, L, d)
+
+
+def _geometry(L, blk_q, blk_k, diffusion_block=None):
+    """``(blk_q, blk_k, lp, halves, the kernels' diffusion)``: the blocks as
+    :func:`_resolve_blocks` snaps them and the padded length; under the
+    block-diffusion mask the blocks divide a HALF (no tile lies across the
+    noised and the clean copy) and each half is padded on its own."""
+    if diffusion_block is None:
+        return (*_resolve_blocks(L, blk_q, blk_k), 1, None)
+    blk_q, blk_k, half = _resolve_blocks(L // 2, blk_q, blk_k)
+    return blk_q, blk_k, 2 * half, 2, (half, diffusion_block)
 
 
 def _flash_forward(q, k, v, scale, interpret, blk_q, blk_k, causal,
-                   window=None):
+                   window=None, diffusion_block=None):
     b, h, L, d = q.shape
-    blk_q, blk_k, lp = _resolve_blocks(L, blk_q, blk_k)
+    blk_q, blk_k, lp, halves, diffusion = _geometry(
+        L, blk_q, blk_k, diffusion_block)
     bh, group = b * h, h // k.shape[1]
 
-    qf = _pad_lhd(q.reshape(bh, L, d), lp)
-    kf = _pad_lhd(k.reshape(bh // group, L, d), lp)
-    vf = _pad_lhd(v.reshape(bh // group, L, d), lp)
+    qf = _pad_lhd(q.reshape(bh, L, d), lp, halves)
+    kf = _pad_lhd(k.reshape(bh // group, L, d), lp, halves)
+    vf = _pad_lhd(v.reshape(bh // group, L, d), lp, halves)
 
     blocked, _, vec_blocked, _, kv_whole, _ = _specs(lp, d, blk_q, group)
     o, lse = pl.pallas_call(
         functools.partial(
-            _fwd_kernel, scale=scale, length=L, blk_k=blk_k, causal=causal,
-            **_windowed(window),
+            # under the block-diffusion mask a half's real rows
+            _fwd_kernel, scale=scale, length=L // halves, blk_k=blk_k,
+            causal=causal, **_windowed(window, diffusion),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((bh, lp, d), v.dtype),
@@ -485,14 +674,14 @@ def _flash_forward(q, k, v, scale, interpret, blk_q, blk_k, causal,
         name="dtpu_flash_fwd",
     )(qf, kf, vf)
     return (
-        o[:, :L].reshape(b, h, L, d),
+        _unpad_lhd(o, L, halves).reshape(b, h, L, d),
         lse,  # [bh, 1, lp] — padded, kept for backward
         (qf, kf, vf),
     )
 
 
 def _flash_backward(res, g, scale, interpret, blk_q, blk_k, causal,
-                    g_lse=None, window=None):
+                    g_lse=None, window=None, diffusion_block=None):
     """dQ/dK/dV from the saved residuals. ``g_lse`` (padded [bh, 1, lp]) is
     the cotangent of the lse output when the caller exposed it
     (``flash_attention_with_lse``): dL/ds_ij gains the softmax term
@@ -504,10 +693,11 @@ def _flash_backward(res, g, scale, interpret, blk_q, blk_k, causal,
     bh, lp, _ = qf.shape
     group = bh // kf.shape[0]
     # same resolution as the forward (lp is already a multiple of both)
-    blk_q, blk_k, _ = _resolve_blocks(L, blk_q, blk_k)
+    blk_q, blk_k, _, halves, diffusion = _geometry(
+        L, blk_q, blk_k, diffusion_block)
 
-    gf = _pad_lhd(g.reshape(bh, L, d), lp)
-    of = _pad_lhd(o.reshape(bh, L, d), lp)
+    gf = _pad_lhd(g.reshape(bh, L, d), lp, halves)
+    of = _pad_lhd(o.reshape(bh, L, d), lp, halves)
     # delta_i = Σ_d dO_i · O_i  (zero on the padded rows)
     delta = (gf.astype(jnp.float32) * of.astype(jnp.float32)).sum(-1)[:, None]
     if g_lse is not None:
@@ -516,8 +706,8 @@ def _flash_backward(res, g, scale, interpret, blk_q, blk_k, causal,
     blocked_k, whole, _, vec_whole, _, kv_blocked = _specs(lp, d, blk_k, group)
     dq, dk, dv = pl.pallas_call(
         functools.partial(
-            _bwd_kernel, scale=scale, length=L, blk_q=blk_q, causal=causal,
-            **_windowed(window),
+            _bwd_kernel, scale=scale, length=L // halves, blk_q=blk_q,
+            causal=causal, **_windowed(window, diffusion),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((bh, lp, d), qf.dtype),
@@ -537,7 +727,7 @@ def _flash_backward(res, g, scale, interpret, blk_q, blk_k, causal,
     dk = _under_the_old_name(dk, "dtpu_flash_dkdv", interpret)
 
     def unpad(t):
-        return t[:, :L].reshape(b, -1, L, d)
+        return _unpad_lhd(t, L, halves).reshape(b, -1, L, d)
 
     if group > 1:
         # the kernel leaves dK and dV a QUERY head; a key/value head's is the
@@ -548,15 +738,16 @@ def _flash_backward(res, g, scale, interpret, blk_q, blk_k, causal,
     return unpad(dq), unpad(dk), unpad(dv)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash_attention(q, k, v, scale, interpret, blk_q, blk_k, causal,
-                     window=None):
+                     window=None, diffusion_block=None):
     o, _, _ = _flash_forward(
-        q, k, v, scale, interpret, blk_q, blk_k, causal, window)
+        q, k, v, scale, interpret, blk_q, blk_k, causal, window, diffusion_block)
     return o
 
 
-def _residuals(q, k, v, scale, interpret, blk_q, blk_k, causal, window=None):
+def _residuals(q, k, v, scale, interpret, blk_q, blk_k, causal, window=None,
+               diffusion_block=None):
     """``(o, lse, residuals)`` of a forward rule, everything the backward
     kernel reads NAMED (:data:`KEPT_UNDER_REMAT`): ``o``, ``lse`` and ``qf``,
     ``kf``, ``vf`` as the kernels take them. The rule's primal output must be
@@ -568,22 +759,25 @@ def _residuals(q, k, v, scale, interpret, blk_q, blk_k, causal, window=None):
     what a projection's own backward reads is its input, the norm's output
     (pinned on the gradient's jaxpr in ``tests/test_flash_attention.py``)."""
     o, lse, qkv = _flash_forward(
-        q, k, v, scale, interpret, blk_q, blk_k, causal, window)
+        q, k, v, scale, interpret, blk_q, blk_k, causal, window, diffusion_block)
     o, lse, *qkv = (
         checkpoint_name(t, name)
         for t, name in zip((o, lse, *qkv), KEPT_UNDER_REMAT))
     return o, lse, (*qkv, lse, o, q.shape)
 
 
-def _fa_fwd(q, k, v, scale, interpret, blk_q, blk_k, causal, window=None):
+def _fa_fwd(q, k, v, scale, interpret, blk_q, blk_k, causal, window=None,
+            diffusion_block=None):
     o, _, res = _residuals(
-        q, k, v, scale, interpret, blk_q, blk_k, causal, window)
+        q, k, v, scale, interpret, blk_q, blk_k, causal, window, diffusion_block)
     return o, res
 
 
-def _fa_bwd(scale, interpret, blk_q, blk_k, causal, window, res, g):
+def _fa_bwd(scale, interpret, blk_q, blk_k, causal, window, diffusion_block,
+            res, g):
     return _flash_backward(
-        res, g, scale, interpret, blk_q, blk_k, causal, window=window)
+        res, g, scale, interpret, blk_q, blk_k, causal, window=window,
+        diffusion_block=diffusion_block)
 
 
 _flash_attention.defvjp(_fa_fwd, _fa_bwd)
@@ -657,6 +851,7 @@ def flash_attention(
     q, k, v, *, scale: float | None = None, causal: bool = False,
     interpret: bool | None = None, blk_q: int | None = None,
     blk_k: int | None = None, mesh=None, window: int | None = None,
+    diffusion_block: int | None = None,
 ):
     """Exact softmax attention, flash-tiled in Pallas.
 
@@ -677,6 +872,15 @@ def flash_attention(
     window are never visited: 70 of causal's 136 at 8192 tokens, 512² blocks
     and a window of 2048). A window that reaches every key (``>= L``) is the
     causal call, and ``None`` lowers to the program it always did.
+
+    ``diffusion_block`` (causal calls only; block diffusion's training pass,
+    arXiv:2503.09573): the L = 2S rows are a NOISED copy of S tokens followed
+    by their CLEAN copy, row i of either half in block ``i // diffusion_block``
+    of its half. A noised row reads the noised rows of its own block and the
+    clean rows of EARLIER blocks, a clean row the clean rows of its own and
+    earlier blocks, and nothing else. Neither kernel visits a tile the mask
+    empties (288 of a causal walk's 528 at S = 8192 and 512² blocks); S is a
+    multiple of the block. ``None`` lowers to the program it always did.
 
     A caller that knows its ``mesh`` hands it over: where its ``data`` axis is
     populated (and divides the batch) every data rank runs the kernel on its
@@ -702,6 +906,13 @@ def flash_attention(
                 "least the query's own key")
         if window >= L:  # every key a row may read lies inside it
             window = None
+    if diffusion_block is not None and (
+            not causal or window is not None or diffusion_block < 1
+            or L % (2 * diffusion_block)):
+        raise ValueError(
+            f"diffusion_block={diffusion_block} on {L} rows: the mask takes a "
+            "causal call without a window whose rows are a noised and a clean "
+            "copy of a sequence, each a whole number of blocks")
     if _data_ranks(mesh, b) > 1:
         def per_shard(q, k, v):
             # one device's sequences: the kernel tier may engage
@@ -709,6 +920,7 @@ def flash_attention(
                 return flash_attention(
                     q, k, v, scale=scale, causal=causal, interpret=interpret,
                     blk_q=blk_q, blk_k=blk_k, window=window,
+                    diffusion_block=diffusion_block,
                 )
 
         rows = jax.sharding.PartitionSpec("data")
@@ -718,10 +930,10 @@ def flash_attention(
         )(q, k, v)
 
     blk_q, blk_k, itemsize = _blocks(q, k, v, causal, blk_q, blk_k)
-    rq, rk, lp = _resolve_blocks(L, blk_q, blk_k)
+    rq, rk, lp, _, diffusion = _geometry(L, blk_q, blk_k, diffusion_block)
     # the interpreter has no VMEM budget
     fits = interpret is True or fits_vmem(L, d, itemsize)
-    visited, crossed = tile_counts(L, rq, rk, causal, window)
+    visited, crossed = tile_counts(L, rq, rk, causal, window, diffusion_block)
     impl = kernel_tier.select(
         "flash_attn", supported=fits, forced=interpret is not None,
         reason="" if fits else (
@@ -733,6 +945,8 @@ def flash_attention(
         bwd_matmuls_a_tile=BWD_MATMULS_A_TILE,
         **({"kv_group": group, "kv_heads": h // group} if group > 1 else {}),
         **_windowed(window),
+        **({} if diffusion is None else {
+            "mask": "block_diffusion", "diffusion_block": diffusion_block}),
     )
     if impl == "xla":
         # stream from HBM via the scan path: off the TPU (the interpreter is
@@ -744,11 +958,12 @@ def flash_attention(
         # the scan takes q, k and v of one shape: the group's heads repeated
         k, v = (jnp.repeat(t, group, axis=1) if group > 1 else t for t in (k, v))
         return blockwise_attention(
-            q, k, v, causal=causal, scale=scale, **_windowed(window))
+            q, k, v, causal=causal, scale=scale, **_windowed(window),
+            diffusion_block=diffusion_block)
     if interpret is None:
         interpret = False
     return _flash_attention(
-        q, k, v, scale, interpret, blk_q, blk_k, causal, window)
+        q, k, v, scale, interpret, blk_q, blk_k, causal, window, diffusion_block)
 
 
 def kept_under_remat_bytes(q_shape, itemsize: int, mesh=None,

@@ -174,11 +174,16 @@ def bias_after(bias, counts, rate: float):
     return bias + rate * jnp.sign(counts.mean() - counts)
 
 
-def softmax_route(x, gate_w, top_k: int):
-    """``(probs, weights, indices)`` of the softmax router whose weights are
-    the probabilities as they are: :func:`moe_ffn_sorted`'s default."""
+def softmax_route(x, gate_w, top_k: int, *, renormalise: bool = False):
+    """``(probs, weights, indices)`` of the softmax router, a float32 softmax
+    over ALL experts and its ``top_k`` largest, under either setting of the
+    published ``norm_topk_prob``: false (the default, :func:`moe_ffn_sorted`'s
+    and OLMoE's) leaves the weights the probabilities as they are;
+    ``renormalise`` (true: Qwen3-MoE's and SDAR's) divides them by the chosen
+    ones' sum, so a token's weights sum to 1."""
     probs = gating_probs(x, gate_w)
-    return (probs, *top_k_as_is(probs, top_k))
+    top = top_k_from_probs if renormalise else top_k_as_is
+    return (probs, *top(probs, top_k))
 
 
 def sigmoid_route(x, gate_w, top_k: int, *, bias, scale: float,

@@ -293,7 +293,8 @@ def ulysses_attention(
 
 def blockwise_attention(q, k, v, *, chunk: int = 256, causal: bool = False,
                         scale: float | None = None, remat: bool = True,
-                        window: int | None = None):
+                        window: int | None = None,
+                        diffusion_block: int | None = None):
     """Single-device flash-style attention: exact softmax in O(L·chunk)
     memory instead of the dense path's O(L²) logits (Rabe & Staats,
     arXiv:2112.05682; the single-chip sibling of ring attention — same
@@ -309,7 +310,9 @@ def blockwise_attention(q, k, v, *, chunk: int = 256, causal: bool = False,
     ``window`` (with ``causal``): query t reads the keys s with ``t - window
     < s <= t``, as ``ops/flash_attention.py``'s kernels; every chunk is
     still walked (the mask alone says it: this is the fallback, not the
-    fast path).
+    fast path). ``diffusion_block`` (with ``causal``): the block-diffusion
+    mask over a noised and a clean copy of a sequence
+    (``ops/flash_attention.diffusion_mask``), likewise by the mask alone.
 
     q, k: [B, H, L, D]; v: [B, H, L, Dv]. Returns [B, H, L, Dv] in v.dtype.
     """
@@ -337,7 +340,11 @@ def blockwise_attention(q, k, v, *, chunk: int = 256, causal: bool = False,
         idx, kb, vb = inp
         k_pos = idx * chunk + jnp.arange(chunk)
         mask = None
-        if causal or need_pad_mask:
+        if diffusion_block is not None:
+            from distribuuuu_tpu.ops import flash_attention as fa
+
+            mask = fa.diffusion_mask(L, diffusion_block, k_pos)
+        elif causal or need_pad_mask:
             mask = jnp.broadcast_to((k_pos < L)[None, :], (L, chunk))
             if causal:
                 mask = mask & (q_pos[:, None] >= k_pos[None, :])

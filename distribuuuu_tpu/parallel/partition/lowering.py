@@ -331,6 +331,10 @@ def train_step_body(model, optimizer, topk: int, accum_steps: int = 1,
         return loss, hits, {"ce": loss}
 
     head_loss = getattr(model, "head_loss", one_head_loss)
+    # the streams of the step's key a model draws from beside dropout's
+    # (models/sdar_moe.py: the noise of its diffusion objective); none for
+    # every other arch, whose program is what it was
+    noise_streams = tuple(getattr(model, "noise_streams", ()))
     # FAULTS.NAN_STEP (utils/faults.py): trace-time gate — None (the
     # common case) compiles nothing in; an int multiplies the loss by
     # where(step==k, NaN, 1), poisoning loss AND grads at exactly step k.
@@ -349,7 +353,7 @@ def train_step_body(model, optimizer, topk: int, accum_steps: int = 1,
                 train=True,
                 mutable=["batch_stats", "intermediates", "moe_stats",
                          "moe_z", "moe_load"],
-                rngs={"dropout": key},
+                rngs={"dropout": key, **{name: key for name in noise_streams}},
                 **({} if head_kernel is None else {"hidden_only": True}),
             )
             if head_kernel is not None:
@@ -483,6 +487,10 @@ def make_eval_step(model, topk: int, layout=None):
     # of the vocabulary's rows (models/glm_moe.py; its ``head_loss`` does the
     # same in training)
     head_labels = getattr(model, "head_labels", lambda labels: labels)
+    # ... or, where the objective is not next-token cross-entropy
+    # (models/sdar_moe.py: a position's own token, weighted by its noise),
+    # the labels and a weight a position from what ``hidden_only`` returned
+    eval_targets = getattr(model, "eval_targets", None)
 
     def eval_step(state: TrainState, batch):
         params = gather_entry(state.params)
@@ -494,20 +502,25 @@ def make_eval_step(model, topk: int, layout=None):
                 **({} if head_kernel is None else {"hidden_only": True}),
             )
             if head_kernel is not None:
-                logits = eval_hidden(logits)
+                outputs, logits = logits, eval_hidden(logits)
         mask = batch["mask"]
         labels = batch["label"]
         if head_kernel is not None:
-            labels = head_labels(labels)
+            weights = None
+            if eval_targets is None:
+                labels = head_labels(labels)
+            else:
+                labels, weights = eval_targets(outputs, labels)
             # the head in chunks, as in the train step (ops/token_head.py)
             nll, rank = token_head.head_stats(
                 logits, head_kernel(params), labels, chunk=loss_chunk
             )
             mask = jnp.broadcast_to(mask[:, None], labels.shape)
+            weighted = mask if weights is None else mask * weights
             return {
-                "loss_sum": (nll * mask).sum(),
-                "correct1": ((rank < 1) * mask).sum(),
-                "correctk": ((rank < topk) * mask).sum(),
+                "loss_sum": (nll * weighted).sum(),
+                "correct1": ((rank < 1) * weighted).sum(),
+                "correctk": ((rank < topk) * weighted).sum(),
                 "count": mask.sum(),
             }
         if logits.ndim == 3:

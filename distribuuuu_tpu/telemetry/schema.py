@@ -310,6 +310,11 @@ DEVICE_SCOPES: dict[str, str] = {
     # attention output's gate (projection, sigmoid, product) of either kind
     "attn_window": "models",
     "attn_gate": "models",
+    # models/sdar_moe.py (``attn``, ``moe`` and ``lm_head`` as above): the
+    # draws of the diffusion objective's noise and the noised copy of the
+    # tokens; inside ``attn``, the mixer under the block-diffusion mask
+    "diffusion_noise": "models",
+    "attn_diffusion": "models",
     # ops/pallas/opt_update.py
     "opt_tile": "kernels",
     "opt_kernel": "kernels",

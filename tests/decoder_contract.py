@@ -1,14 +1,16 @@
 """What a decoder on the normal path must satisfy, written once.
 
-Five architectures train through ``lowering.lower`` and ``train_net.py``:
-OLMoE, Ouro, GLM-4.7-Flash, LFM2-24B-A2B and Trinity-Mini. Each is a ``Row``
+Six architectures train through ``lowering.lower`` and ``train_net.py``:
+OLMoE, Ouro, GLM-4.7-Flash, LFM2-24B-A2B, Trinity-Mini and SDAR-30B-A3B-Chat
+(the one that is not trained by next-token cross-entropy under a causal mask:
+``tests/test_sdar_moe.py``). Each is a ``Row``
 of ``ROWS`` (its names, its YAML, its plain reference under
 ``benchmark/reference/``, and the values its tests expect) and ONE collected
 file, ``tests/test_<arch>.py``, whose ``Test...`` class lists the contracts
 below that the architecture answers and sets ``row``. pytest collects nothing
 from this module (no ``test_`` in its name, no ``Test`` in its classes'), and
 the driver's ``--dist loadfile`` keeps each architecture's file on a worker of
-its own, which is why the five rows are not one parametrised file.
+its own, which is why the six rows are not one parametrised file.
 
 The contracts:
 
@@ -53,7 +55,8 @@ from jax.sharding import PartitionSpec as P
 import distribuuuu_tpu.config as config
 from distribuuuu_tpu import models, trainer
 from distribuuuu_tpu.config import cfg
-from distribuuuu_tpu.models import glm_moe, ouro, share
+from distribuuuu_tpu.models import glm_moe, ouro, sdar_moe, share
+from distribuuuu_tpu.ops import moe as moe_ops
 from distribuuuu_tpu.ops import token_head
 from distribuuuu_tpu.parallel import mesh as mesh_lib
 from distribuuuu_tpu.parallel.partition import lowering, specs, topology
@@ -135,8 +138,9 @@ def seeded(model, batch=2, seq=100, seed=0):
     misplaced scale would show; LFM2's filters made large, so that a shifted
     tap would; Ouro's gate wide enough for its distribution to leave 1/2;
     the routers' biases off 0, so that a router that ignored them would show
-    (None where the architecture has none); and ids from the rows of the
-    vocabulary the rank holds."""
+    (None where the architecture has none: OLMoE's, Ouro's and SDAR's state
+    holds no ``batch_stats``); and ids from the rows of the vocabulary the
+    rank holds."""
     shares = hasattr(model, "share_rank")
     k_init, k_tok, k_scale, *k_bias = jax.random.split(
         jax.random.key(seed), 4 if shares else 3)
@@ -157,7 +161,7 @@ def seeded(model, batch=2, seq=100, seed=0):
                                "bias": jnp.asarray([0.3])}
     biases = jax.tree.map(
         lambda b: 0.02 * jax.random.normal(k_bias[0], b.shape),
-        state["batch_stats"]) if shares else None
+        state["batch_stats"]) if "batch_stats" in state else None
     first, held = vocabulary(model)
     ids = first + jax.random.randint(k_tok, (batch, seq + 1), 0, held, jnp.int32)
     return params, biases, ids[:, :-1], ids[:, 1:]
@@ -300,7 +304,7 @@ def one_step(row, **overrides):
         return types.SimpleNamespace(
             model=low.model, overrides=overrides, tokens=host["image"],
             labels=host["label"],
-            params=params, biases=biases if row.shares else None,
+            params=params, biases=biases if row.biases else None,
             moment_leaves=len(jax.tree.leaves(moments[0].mu)),
             evaluated=evaluated, metrics=jax.device_get(metrics),
             params_after=jax.device_get(state.params),
@@ -405,6 +409,73 @@ def _afmoe_architecture(model) -> dict:
     }
 
 
+def _sdar_architecture(model) -> dict:
+    return {
+        "layers": len(model.layer_kinds), "hidden_size": model.dim,
+        "num_attention_heads": model.num_heads, "num_key_value_heads": model.kv_heads,
+        "head_dim": model.head_dim, "moe_intermediate_size": model.expert_hidden,
+        "num_experts": model.num_experts, "num_experts_per_tok": model.top_k,
+        "rms_norm_eps": model.norm_eps, "rope_theta": model.rope_theta,
+        "vocab_size": model.vocab_size, "share_chips": model.share_chips,
+        "share_rank": model.share_rank, "experts_held": model.held[1],
+        "vocab_held": model.vocab_held, "balance_loss_weight": model.aux_weight,
+        "block_length": model.block_length, "noise_eps": model.noise_eps,
+        "mask_id": model.mask_token,
+    }
+
+
+def stream_key(key, stream=sdar_moe.NOISE_STREAM):
+    """What ``make_rng(stream)`` returns at the root of a module applied with
+    ``rngs={stream: key}``: the key SDAR's reference draws its noise from,
+    which flax says, not the model."""
+    class Root(flax.linen.Module):
+        def __call__(self):
+            return self.make_rng(stream)
+
+    return Root().apply({}, rngs={stream: key})
+
+
+def sdar_step_key():
+    """SDAR's step key where a state starts from ``jax.random.key(0)``
+    (``one_step``): the loss cases hand the model the same, so that one
+    reference serves both."""
+    return jax.random.fold_in(jax.random.key(0), 0)
+
+
+def _sdar_loss(model, params, biases, tokens, labels):
+    """(loss, Aux) with the step's key as the noise's stream, as the step's
+    ``loss_fn`` hands it over; no state beside the parameters."""
+    assert biases is None
+    outputs = model.apply(
+        {"params": params}, tokens, train=True, hidden_only=True,
+        rngs={sdar_moe.NOISE_STREAM: sdar_step_key()})
+    loss, hits, extra = model.head_loss(
+        outputs, model.head_kernel(params), labels, topk=(1, 5))
+    return loss, Aux(extra, None, outputs, hits)
+
+
+def _sdar_reference():
+    """``benchmark/reference/sdar_moe.py`` with the noise's key bound to the
+    step's (``module``: the file itself)."""
+    module = _reference("sdar_moe")
+
+    def keyed(name):
+        return lambda *args, **kw: getattr(module, name)(
+            *args, noise_key=stream_key(sdar_step_key()), **kw)
+
+    return types.SimpleNamespace(
+        module=module, _mixture=module._mixture, loss=keyed("loss"),
+        logits=keyed("logits"))
+
+
+def _sdar_evaluated(want, ran):
+    """Evaluation is the training objective on the draws of a call without
+    the stream: the reference's at ``jax.random.key(0)``."""
+    return ROWS["sdar"].reference.module.loss(
+        ran.params, ran.tokens, architecture=_sdar_architecture(ran.model),
+        noise_key=jax.random.key(0))["ce"]
+
+
 @dataclasses.dataclass(frozen=True)
 class Row:
     tiny: str                 # the arch at the size the CPU runs
@@ -424,9 +495,11 @@ class Row:
     declared: dict            # ... and reads back off the model built from it
     refusal: str              # serving's sentence, as a pattern
     train_argv: tuple         # the CPU-size overrides of ``train_net.py``
-    shares: bool = True       # one chip's share of an expert-parallel group: the
-    #                           routers' biases ride ``batch_stats``, a rank holds
-    #                           its experts and its rows of the vocabulary
+    shares: bool = True       # one chip's share of an expert-parallel group: a rank
+    #                           holds its experts and its rows of the vocabulary
+    biased: bool = True       # ... whose routers balance through a bias that rides
+    #                           ``batch_stats`` (SDAR's softmax router has none)
+    aux_weight: float = 1e-4  # the YAML's MODEL.MOE.AUX_WEIGHT
     program_loss: Callable = program_loss
     total: Callable | None = None      # terms -> loss, where the reference has none
     gradient_leaves: Callable | None = None  # model -> leaves of the gradient
@@ -440,8 +513,14 @@ class Row:
     step_cases: dict = dataclasses.field(default_factory=lambda: {"whole": {}})
     step_metrics: frozenset = frozenset()
     step_metrics_absent: frozenset = frozenset()
-    evaluated: Callable = lambda want: want["ce"]  # the term evaluation reads
+    # the term evaluation reads (``ran``: ``one_step``'s host copies)
+    evaluated: Callable = lambda want, ran: want["ce"]
     head_walks: int = 1       # the states that share ONE walk of the head
+    # the step draws from its key: the CPU lowers threefry's rounds as a loop
+    # (the TPU unrolls them), so loops are looked for in the jaxpr, not the text
+    draws: bool = False
+    # tokens' shape -> the shape of the rows a block sees
+    rows: Callable = lambda shape: shape
     # the entries of a gradient whose SIGN AdamW's first step is held to
     firm: Callable = lambda g: jnp.ones(g.shape, bool)
     # Recomputes: the model of the recompute tests, the blocks a step applies
@@ -462,6 +541,10 @@ class Row:
     @property
     def yaml(self) -> str:
         return os.path.join(REPO, "config", f"{self.full}.yaml")
+
+    @property
+    def biases(self) -> bool:
+        return self.shares and self.biased
 
 
 _TRAIN = ("MODEL.NUM_CLASSES", "512", "DEVICE.COMPUTE_DTYPE", "float32")
@@ -535,7 +618,7 @@ ROWS = {
         step_metrics=frozenset({
             "loss", "top1", "topk", "ce", "ce_pass_0", "ce_pass_1", "ce_pass_2",
             "ce_pass_3", "exit_entropy", "exit_step_mean", "nonfinite"}),
-        evaluated=lambda want: want["ce_pass"][-1],  # evaluation reads the last pass
+        evaluated=lambda want, ran: want["ce_pass"][-1],  # evaluation reads the last pass
         head_walks=4,
         small=dict(depth=2), branches=2,
         plan=dict(
@@ -684,6 +767,72 @@ ROWS = {
                      share_atol=3e-6, sum_atol=5e-6),
         **_PATTERNED,
     ),
+    "sdar": Row(
+        tiny="sdar_moe_tiny", full="sdar_30b_a3b", reference=_sdar_reference(),
+        architecture=_sdar_architecture, biased=False, aux_weight=1e-3,
+        program_loss=_sdar_loss, evaluated=_sdar_evaluated, draws=True,
+        rows=lambda shape: (shape[0], 2 * shape[1]),  # the noised copy, the clean copy
+        refusal="'sdar_30b_a3b' trains only.*one token a sequence a step",
+        published=dict(dim=2048, num_heads=32, kv_heads=4, head_dim=128, num_experts=128,
+                       top_k=8, expert_hidden=768, vocab_size=151936, share_chips=1,
+                       dense_here=0, norm_eps=1e-6, rope_theta=1e6, block_length=4,
+                       noise_eps=1e-3, aux_weight=1e-3),
+        states=(2, 40, 64),
+        # either of the two chips, recomputed as the cell runs them and with
+        # nothing recomputed, and blocks of 20 tokens (no power of two)
+        gradient_cases={
+            "0-recomputed": (dict(share_rank=0), 0),
+            "1-recomputed": (dict(share_rank=1), 1),
+            "1-kept": (dict(share_rank=1, recompute=False), 1),
+            "0-blocks-of-20": (dict(share_rank=0, block_length=20), 2)},
+        jitted=True,
+        terms={**_SHARE_TERMS, "diffusion_masked_share": "masked_share"},
+        # the logits of a plain call are another key's (``tests/test_sdar_moe.py``
+        # holds them to the reference under the step's)
+        loss_rtol=1e-6, term_rtol=2e-6, gradient_tolerance=2e-5,
+        specs={**{f"Block_2/attn/{name}/kernel": P(None, "model")
+                  for name in ("q_proj", "k_proj", "v_proj")},
+               "Block_2/attn/o_proj/kernel": P("model"),
+               **{f"Block_2/{norm}/scale": P() for norm in (
+                   "attn/q_norm", "attn/k_norm", "input_norm", "post_attention_norm")},
+               "Block_2/moe/router": P(),
+               "final_norm/scale": P(), "head": P(None, "model"),
+               "tok_embed/embedding": P(None, "model")},
+        declared_cfg={"LM.FIRST_LAYER": 1, "LM.LAYERS": 3, "LM.RECOMPUTE": False,
+                      "LM.SHARE_CHIPS": 4, "LM.SHARE_RANK": 3,
+                      "MODEL.MOE.AUX_WEIGHT": 0.01},
+        declared={"seq_len": 64, "first_layer": 1, "depth": 3, "share_chips": 4,
+                  "share_rank": 3, "aux_weight": 0.01, "recompute": False,
+                  "dense_here": 0, "block_length": 4},
+        # two layers; the whole model: the shards' ids range over the whole
+        # vocabulary (its last row stands for [MASK], which no byte is)
+        train_argv=(*_TRAIN, "LM.LAYERS", "2", "LM.SHARE_CHIPS", "1"), epochs=1,
+        step_cfg={"LM.SHARE_CHIPS": 2}, step_cases={"rank1": dict(share_rank=1)},
+        step_metrics=(_SHARE_METRICS - {"router_bias_abs_max"}) | {"diffusion_masked_share"},
+        step_metrics_absent=frozenset({"ce_mtp", "router_bias_abs_max"}),
+        # the routers' gradients are small here (renormalised weights); 0: an
+        # embedding row no token drew, an expert no row chose
+        firm=lambda g: (jnp.abs(g) > 1e-7) | (g == 0),
+        blocks=lambda m: len(m.layer_kinds), small=dict(depth=2), branches=1,
+        plan=dict(
+            module=types.SimpleNamespace(
+                _planned=share._planned,
+                _say_plan=lambda model, batch, rows: share.say_plan(
+                    model, batch, rows, (None, None))),
+            kind="share.plan", build=dict(num_classes=151936, depth=6, share_chips=8),
+            tokens=(1, 16384),  # rows: one sequence's noised and clean copy
+            fields={"experts_held": 16, "vocab_held": 18992,
+                    "layer_kinds": [sdar_moe.KIND] * 6, "dense_layers": 0},
+            inputs=6 * 16384 * 2048 * 4, branches=6 * 16384 * 2048 * 2,
+            flash=1_824_522_240,
+            said="every block of either kind, from its float32 input, the outputs "
+                 "of its branches that are read again (whose last matmuls run once)"),
+        bfloat16=({}, 2, 64, [("ce", 5e-3, False, False)]),
+        mixture=dict(experts=16, top_k=4, shared=0, scale=1.0, chips=(2, 4),
+                     keywords={"route": functools.partial(
+                         moe_ops.softmax_route, renormalise=True)},
+                     reference={}, share_atol=3e-6, sum_atol=5e-6),
+    ),
 }
 
 
@@ -782,11 +931,11 @@ class Decoder(_Rowed):
             assert len(jax.tree.leaves(grads)) == row.gradient_leaves(model)
         assert_trees_close(grads, want_grads, row.gradient_tolerance)
         if row.shares:
-            # nothing is dropped, about half the choices land on held experts,
-            # and the biases one step leaves are the rule's on the reference's
-            # counts
+            # nothing is dropped, about half the choices land on held experts
             assert float(aux.extra["moe_dropped"]) == 0.0
             assert 0.3 < float(aux.extra["moe_held_row_share"]) < 0.7
+        if row.biases:
+            # the biases one step leaves are the rule's on the reference's counts
             np.testing.assert_array_equal(
                 mixture_biases(model, aux.after),
                 row.reference.bias_after(
@@ -926,8 +1075,8 @@ class ThroughLower(_Rowed):
             np.testing.assert_allclose(ran.metrics[got], want[term], rtol=1e-5, err_msg=got)
         assert float(ran.evaluated["count"]) == 8 * 100
         np.testing.assert_allclose(
-            ran.evaluated["loss_sum"] / ran.evaluated["count"], row.evaluated(want),
-            rtol=1e-5)
+            ran.evaluated["loss_sum"] / ran.evaluated["count"],
+            row.evaluated(want, ran), rtol=1e-5)
         if row.shares:
             self._the_step_moves_the_bias_and_takes_adamws_first_step(ran, want, grads)
         self.step_of_its_own(ran, want)
@@ -941,16 +1090,20 @@ class ThroughLower(_Rowed):
         reference's counts, the first AdamW update a plain one on the
         reference's gradient; the optimizer holds no bias."""
         row, model = self.row, ran.model
-        assert (model.share_chips, model.share_rank, model.aux_weight) == (2, 1, 1e-4)
-        fresh = mixture_biases(model, ran.biases)
-        assert not float(jnp.abs(fresh).max())
+        assert (model.share_chips, model.share_rank, model.aux_weight) == (
+            2, 1, row.aux_weight)
         assert ran.moment_leaves == len(jax.tree.leaves(ran.params))  # no leaf for a bias
         assert float(ran.metrics["moe_dropped"]) == 0.0
-        assert float(ran.metrics["router_bias_abs_max"]) == pytest.approx(0.001)
-        # the biases rode the state the step returns, by the rule and no gradient
-        np.testing.assert_array_equal(
-            mixture_biases(model, ran.biases_after),
-            row.reference.bias_after(jnp.zeros(fresh.shape), want["counts"], 0.001))
+        if row.biases:
+            fresh = mixture_biases(model, ran.biases)
+            assert not float(jnp.abs(fresh).max())
+            assert float(ran.metrics["router_bias_abs_max"]) == pytest.approx(0.001)
+            # the biases rode the state the step returns, by the rule and no gradient
+            np.testing.assert_array_equal(
+                mixture_biases(model, ran.biases_after),
+                row.reference.bias_after(jnp.zeros(fresh.shape), want["counts"], 0.001))
+        else:  # a router without a bias leaves the state nothing to carry
+            assert not jax.tree.leaves(ran.biases_after)
         # the first AdamW step (zero moments): lr * (g / (|g| + eps) + wd p), over
         # the length of the step (an element whose gradient is near eps is free)
         for (path, p0), g, p1 in zip(jax.tree_util.tree_leaves_with_path(ran.params),
@@ -969,7 +1122,11 @@ class ThroughLower(_Rowed):
         held vocabulary a chunk, over ``head_walks`` x B rows."""
         row = self.row
         ran = stepped(next(iter(row.step_cases)))
-        assert " while(" not in ran.text and " conditional(" not in ran.text
+        if row.draws:
+            assert not {"while", "scan", "cond"} & {
+                eqn.primitive.name for eqn in walk(ran.jaxpr)}
+        else:
+            assert " while(" not in ran.text and " conditional(" not in ran.text
         held = vocabulary(ran.model)[1]
         wide = wide_matmuls(ran.jaxpr, held)
         assert len(wide) == 3 * -(-100 // CHUNK)
@@ -1031,7 +1188,8 @@ class Recomputes(_Rowed):
                         saved_residuals(lambda p: loss(model, p), params)
                         if f"({ouro.branch_out.__name__})" in why]
 
-        assert named() == [(*tokens.shape, model.dim)] * row.branches * row.blocks(model)
+        assert named() == [(*row.rows(tokens.shape), model.dim)] * (
+            row.branches * row.blocks(model))
         assert named(jax.checkpoint_policies.nothing_saveable) == []
 
     def test_the_plan_counts_the_bytes_of_the_branches_kept(self, small, tmp_path):
@@ -1056,10 +1214,11 @@ class Recomputes(_Rowed):
         for plan in plans:
             schema.validate_record(plan)
         kept, nothing = plans
-        size = tokens.size * model.dim * jnp.dtype(model.dtype).itemsize
+        rows = int(np.prod(row.rows(tokens.shape)))
+        size = rows * model.dim * jnp.dtype(model.dtype).itemsize
         assert kept["kept_branch_bytes"] == row.branches * row.blocks(model) * size
         assert kept["kept_bytes"] == (  # the CPU's scan path names nothing of flash
-            row.blocks(model) * tokens.size * model.dim * 4 + kept["kept_branch_bytes"])
+            row.blocks(model) * rows * model.dim * 4 + kept["kept_branch_bytes"])
         assert "branches that are read again" in kept["recomputed"]
         assert (nothing["kept_branch_bytes"], nothing["kept_bytes"],
                 nothing["recomputed"]) == (None, None, "nothing")

@@ -389,6 +389,37 @@ def test_windowed_flash_compiles_for_the_v5e_at_a_group_of_eight(v5e_chip):
     assert fa.tile_counts(8192, 512, 512, True, 2048) == (70, 28)
 
 
+def test_diffusion_flash_compiles_for_the_v5e_at_two_copies_of_8192_tokens(v5e_chip):
+    """``sdar_30b_a3b.train_seq8192``'s layers: 32 query heads on 4 key/value
+    heads of 128 over the 16,384 rows of a noised and a clean copy of one
+    8192-token sequence, under the block-diffusion mask at blocks of 4 and of
+    32 tokens. Mosaic takes both kernels with each walk as TWO ranges of tiles
+    (traced loop bounds from the program id) and the mask as two compares of
+    per-row and per-key codes; the resident set is a causal call's of 16,384
+    rows and fits as it stands: no scan path."""
+    from jax.sharding import SingleDeviceSharding
+
+    chip = SingleDeviceSharding(v5e_chip)
+    q = jax.ShapeDtypeStruct((1, 32, 16384, 128), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((1, 4, 16384, 128), jnp.bfloat16, sharding=chip)
+    for block in (4, 32):
+        def loss(q, k, v, block=block):
+            return fa.flash_attention(
+                q, k, v, causal=True, interpret=False, diffusion_block=block,
+            ).astype(jnp.float32).sum()
+
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile()
+        text = compiled.as_text()
+        calls = [line for line in text.splitlines()
+                 if "custom-call(" in line and "dtpu_flash_" in line]
+        assert len(calls) == 4 and sum("dtpu_flash_bwd" in c for c in calls) == 1
+        assert "16384,16384" not in text and " while(" not in text
+        assert [tuple(x.shape) for x in compiled.out_info] == [
+            (1, 32, 16384, 128), (1, 4, 16384, 128), (1, 4, 16384, 128)]
+    assert fa.fits_vmem(16384, 128)
+    assert fa.tile_counts(16384, 512, 512, True, None, 4) == (288, 48)
+
+
 def _movers_of_held_mixtures(calls, in_scope, mixtures: int, normed_after=False):
     """A recomputed held mixture moves its rows through ``ops/pallas/
     moe_rows``: ``take`` forward, again under recomputation and as the

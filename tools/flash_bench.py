@@ -27,7 +27,7 @@ for OLMoE's cell and ``--batch 1`` for Ouro's):
     python tools/flash_bench.py [--batch 4] [--heads 3] [--seq 4096]
         [--dim 64] [--causal] [--iters 20] [--rounds 5] [--skip-dense]
         [--blk-q N] [--blk-k N] [--sweep] [--baseline FILE] [--kv-heads N]
-        [--window W]
+        [--window W] [--diffusion-block B]
 
 ``--blk-q``/``--blk-k`` default to what ``flash_attention.choose_blocks``
 picks for the shape (printed). ``--sweep`` times the flash path alone at
@@ -50,6 +50,14 @@ paths a sliding window of W keys (Trinity-Mini's window layers are ``--batch
 the useful work is then the (query, key) pairs the window keeps. Path
 ``base`` (another checkout's kernels, which may know no window) and the
 dense path stay plain causal: beside them the window's skip shows.
+
+``--diffusion-block B`` (with ``--causal``) reads ``--seq`` as the 2S rows of
+a noised and a clean copy of S tokens under the block-diffusion mask (SDAR's
+layers are ``--batch 1 --heads 32 --kv-heads 4 --seq 16384 --dim 128 --causal
+--diffusion-block 4``); the useful work is the S (S + B) pairs the mask
+keeps, and path ``causal`` is the same kernels on the same rows under the
+plain causal mask: beside it the two-range walk's skip shows (288 tiles
+against 528 at that shape).
 
 ``--kernel decode`` (ISSUE 13) switches the harness to the kernel
 tier's fused decode attention (ops/pallas/decode_attn.py) vs the dense
@@ -257,6 +265,13 @@ def main():
                          "against the causal scan and dense")
     ap.add_argument("--window", type=int, default=None,
                     help="a sliding window of this many keys (with --causal)")
+    ap.add_argument("--skip-scan", action="store_true",
+                    help="leave the lax.scan path and the check against it "
+                         "out (its backward does not fit the chip at 16,384 "
+                         "rows x 32 heads)")
+    ap.add_argument("--diffusion-block", type=int, default=None,
+                    help="the block-diffusion mask over a noised and a clean "
+                         "copy, --seq rows in all (with --causal)")
     args = ap.parse_args()
 
     from distribuuuu_tpu.config import cfg
@@ -305,6 +320,9 @@ def main():
         windowed = {"window": args.window}
         print(f"window: {args.window} keys, {pairs / L:.1f} a query on average; "
               f"tiles visited and crossed at the chosen blocks are below")
+    if args.diffusion_block:
+        pairs = (L // 2) * (L // 2 + args.diffusion_block)
+        windowed = {"diffusion_block": args.diffusion_block}
     flops = 2 * 2 * B * H * pairs * D
 
     chosen = fa.choose_blocks(L, D, args.causal)
@@ -319,6 +337,13 @@ def main():
               f"{fa.tile_counts(L, rq, rk, args.causal, args.window)} with the "
               f"window, {fa.tile_counts(L, rq, rk, args.causal)} without")
 
+    if args.diffusion_block:
+        rq, rk, *_ = fa._geometry(L, blk_q, blk_k, args.diffusion_block)
+        print(f"tiles: visited, crossed = "
+              f"{fa.tile_counts(L, rq, rk, True, None, args.diffusion_block)} "
+              f"under the block-diffusion mask, {fa.tile_counts(L, rq, rk, True)} "
+              f"causal; {pairs / (L // 2):.0f} keys a data token")
+
     def flash(blk_q, blk_k, module=fa):
         return lambda q, k, v: module.flash_attention(
             q, k, v, causal=args.causal, blk_q=blk_q, blk_k=blk_k, **windowed)
@@ -329,6 +354,9 @@ def main():
     paths = {"flash": flash(blk_q, blk_k)}
     if group > 1:
         paths["repeat"] = repeated(flash(blk_q, blk_k))
+    if args.diffusion_block:
+        paths["causal"] = lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, blk_q=blk_q, blk_k=blk_k)
     if args.baseline:
         import importlib.util
 
@@ -340,7 +368,7 @@ def main():
     if args.sweep:
         sizes = (256, 512, 1024)
         paths.update({f"{a}x{b}": flash(a, b) for a in sizes for b in sizes})
-    else:
+    elif not args.skip_scan:
         paths["scan"] = scan
         if not args.skip_dense:
             paths["dense"] = repeated(lambda q, k, v: ra.reference_attention(
@@ -353,8 +381,8 @@ def main():
         loss = lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) ** 2)  # noqa: E731
         return (fn(q, k, v), *jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v))
 
-    for name, a, b in zip(("o", "dq", "dk", "dv"), out_and_grads(paths["flash"]),
-                          out_and_grads(scan)):
+    for name, a, b in () if args.skip_scan else zip(
+            ("o", "dq", "dk", "dv"), out_and_grads(paths["flash"]), out_and_grads(scan)):
         a, b = a.astype(jnp.float32), b.astype(jnp.float32)
         print(f"check   flash-vs-scan {name}: max|d| {float(jnp.abs(a - b).max()):.3e} "
               f"of max|ref| {float(jnp.abs(b).max()):.3e}")
