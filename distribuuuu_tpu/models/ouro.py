@@ -34,7 +34,12 @@ that says after which pass a token may leave.
   ``gate_proj``, ``up_proj`` and the gated product. That is all it keeps:
   R x L applications hold R times the activations a parameter, and 32 of
   them at 4096 tokens do not fit a 16 GB chip beside the AdamW state
-  otherwise (PERF.md section 4).
+  otherwise (PERF.md section 4). The LAST applications of the forward, the
+  first the backward reaches, also keep the two products of ``gate_proj``
+  and ``up_proj`` (``KEPT_PROJ``: 2 x 44 MiB each at the cell's shape; their
+  one reader is elementwise) and run neither again: as many as
+  :func:`plan_kept_proj` finds room for on the device the step is compiled
+  for, from shapes, at trace time.
   Passes and layers are Python loops: a ``while`` shows in a device trace
   as one operation AND its body's, and every scope sum would count twice.
 
@@ -71,7 +76,9 @@ class MLP(nn.Module):
     dtype: Any
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, keep_proj: bool = False):
+        """``keep_proj``: this application names the products of
+        ``gate_proj`` and ``up_proj`` for :func:`recomputed`'s policy."""
         def proj(name, width):
             return nn.Dense(
                 width, use_bias=False, dtype=self.dtype,
@@ -79,13 +86,18 @@ class MLP(nn.Module):
             )
 
         x = x.astype(self.dtype)
-        gated = nn.silu(proj("gate_proj", self.hidden)(x)) * proj("up_proj", self.hidden)(x)
-        return proj("down_proj", self.dim)(gated)
+        gate, up = proj("gate_proj", self.hidden)(x), proj("up_proj", self.hidden)(x)
+        if keep_proj:
+            gate, up = checkpoint_name(gate, KEPT_PROJ), checkpoint_name(up, KEPT_PROJ)
+        return proj("down_proj", self.dim)(nn.silu(gate) * up)
 
 
 # the name a block gives the value each of its branches returns, as the next
 # operation reads it: kept where the block is recomputed, nothing otherwise
 BRANCH_OUT = "branch_out"
+# ... and the name an :class:`MLP` gives the two products its activation
+# reads, in the applications that were told to keep them
+KEPT_PROJ = "kept_proj"
 
 
 def branch_out(x):
@@ -93,18 +105,23 @@ def branch_out(x):
     return checkpoint_name(x, BRANCH_OUT)
 
 
-def recomputed(block):
+def recomputed(block, static_argnums=()):
     """``block`` (a module class) recomputed in the backward: THE definition
     of what a recomputed block keeps beside its input, for every decoder
     that recomputes (``models/glm_moe.py`` and ``models/share.py`` too): what
-    the flash backward kernel reads and each branch's output. The norm or the
-    residual add after a branch is the one reader of its last matmul's
-    result in the backward, so with that kept the second forward stops short
-    of it; where nothing reads it, ``jax.checkpoint`` keeps nothing."""
+    the flash backward kernel reads, each branch's output and, in the
+    applications that name them (``keep_proj``), the gated MLP's two
+    products. The norm or the residual add after a branch is the one reader
+    of its last matmul's result in the backward, so with that kept the second
+    forward stops short of it; where nothing reads it, ``jax.checkpoint``
+    keeps nothing. ``static_argnums`` (``self`` is 0) are the arguments of an
+    application that are Python values."""
     from distribuuuu_tpu.ops.flash_attention import KEPT_UNDER_REMAT
 
-    return nn.remat(block, policy=jax.checkpoint_policies.save_only_these_names(
-        *KEPT_UNDER_REMAT, BRANCH_OUT))
+    return nn.remat(
+        block, static_argnums=static_argnums,
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *KEPT_UNDER_REMAT, BRANCH_OUT, KEPT_PROJ))
 
 
 class Block(nn.Module):
@@ -118,9 +135,10 @@ class Block(nn.Module):
     mesh: Any
 
     @nn.compact
-    def __call__(self, x, positions):
+    def __call__(self, x, positions, keep_proj: bool = False):
         """``x`` is the float32 residual stream; each branch is normed on
-        its way in AND on its way out (the sandwich)."""
+        its way in AND on its way out (the sandwich). ``keep_proj`` is
+        :class:`MLP`'s, a Python value of THIS application."""
 
         def norm(name):
             return RMSNorm(self.eps, name=name)
@@ -133,7 +151,7 @@ class Block(nn.Module):
         with jax.named_scope("mlp"):
             x = x + norm("mlp_post_norm")(branch_out(
                 MLP(self.dim, self.mlp_hidden, self.dtype, name="mlp")(
-                    norm("mlp_norm")(x))))
+                    norm("mlp_norm")(x), keep_proj)))
         return x
 
 
@@ -158,27 +176,100 @@ def exit_log_probs(z):
     return jnp.concatenate([log_exit, stayed[:, -1:]], axis=1)  # the last takes the rest
 
 
+# Device bytes :func:`plan_kept_proj` leaves unplanned: the 1.7 GiB of a 16 GiB
+# chip a compiled step stays out of (at 14.3 GiB XLA:TPU still places every
+# buffer of the steps here; at 14.8 it rematerializes on its own) and the 0.45
+# GiB the cell's step compiles to over what its shapes show once it keeps
+# products (the flash backward's and the head's scratch, the gradients as
+# they are summed, XLA's own temporaries and the holes between buffers). ONE
+# constant, fixed from compiles of the real-size step for a described v5e
+# (PERF.md section 6, PR 49), never read from a run.
+RESERVE_BYTES = 2200 * 2**20
+
+
+def plan_kept_proj(capacity_bytes, held_bytes: int, proj_bytes: int,
+                   applications: int, reserve_bytes: int) -> int:
+    """How many block applications keep their two MLP products
+    (``KEPT_PROJ``), ``proj_bytes`` each: the most that leave ``held_bytes``
+    (what the step holds without them, counted as if it were all live at
+    once) and theirs under ``capacity_bytes - reserve_bytes``. A pure
+    function of its arguments; no capacity (a device the table lacks, the
+    CPU, a trace nobody declared a device for) plans none."""
+    if not capacity_bytes or proj_bytes <= 0:
+        return 0
+    room = int(capacity_bytes) - reserve_bytes - held_bytes
+    return max(0, min(applications, room // proj_bytes))
+
+
+def _capacity_bytes():
+    """HBM of the device the program being traced is compiled FOR
+    (``ops/pallas.lowered_for``: on the chip the chip, under
+    ``benchmark/rehearse_compile.py`` the chip it describes), by its kind from
+    ``telemetry/costmodel.DEVICE_PEAKS``; None where nobody declared one or
+    the table has no capacity for the kind. Never the live allocator's
+    ``bytes_limit``, which a described device lacks and which may differ from
+    process to process: every process must plan the same program."""
+    from distribuuuu_tpu.ops import pallas as kernel_tier
+    from distribuuuu_tpu.telemetry.costmodel import DEVICE_PEAKS
+
+    kind = getattr(kernel_tier.target_device(), "device_kind", None)
+    return DEVICE_PEAKS.get(kind, {}).get("capacity_bytes")
+
+
+def loop_plan(model, batch: int, seq: int, param_bytes: int) -> dict:
+    """The fields of ``loop.plan`` for one traced shape, from shapes alone:
+    what the R x L recomputed block applications keep (:func:`kept_plan`)
+    and how many of them, the LAST ``kept_proj_applications`` of the forward,
+    also keep the MLP's two products (:func:`plan_kept_proj`). ``planned_bytes``
+    is what the step is known to hold with them: ``param_bytes`` three times
+    (the parameters and AdamW's two moments), everything kept, and the head's
+    working set (its float32 ``[d, V]`` gradient and one chunk's logits over
+    the R x B stacked rows)."""
+    applications = model.depth * model.passes
+    fields = kept_plan(
+        model, applications, batch, seq, model.dim // model.num_heads,
+        "every block application", branches=2 * applications)
+    capacity = _capacity_bytes() if model.recompute else None
+    proj = 2 * batch * seq * model.mlp_hidden * jnp.dtype(model.dtype).itemsize
+    head = 4 * model.vocab_size * (
+        model.dim + batch * model.passes * min(seq, model.head_chunk or seq))
+    held = 3 * param_bytes + (fields["kept_bytes"] or 0) + head
+    n = plan_kept_proj(capacity, held, proj, applications, RESERVE_BYTES)
+    if n:
+        fields["kept_bytes"] += n * proj
+        fields["recomputed"] += (
+            f"; the last {n} applications also keep the products of gate_proj "
+            "and up_proj and run neither again")
+    return {
+        "layers": model.depth, "passes": model.passes,
+        "block_applications": applications, **fields,
+        "kept_proj_applications": n, "kept_proj_bytes": n * proj,
+        "capacity_bytes": capacity, "planned_bytes": held + n * proj,
+        "reserve_bytes": RESERVE_BYTES,
+    }
+
+
 _planned: set = set()
 
 
-def _say_plan(model, batch: int, seq: int) -> None:
-    """One ``loop.plan`` record a shape, at trace time, beside
-    ``kernel.select``: what the loop keeps for the backward and what it
-    computes again."""
-    key = (model.depth, model.passes, batch, seq, model.dim, model.recompute)
-    if key in _planned:
-        return
-    _planned.add(key)
-    from distribuuuu_tpu.telemetry import spans
+def _say_plan(model, batch: int, seq: int, param_bytes: int = 0) -> dict:
+    """:func:`loop_plan`, said as one ``loop.plan`` record a shape and count,
+    at trace time, beside ``kernel.select``: what the loop keeps for the
+    backward and what it computes again."""
+    plan = loop_plan(model, batch, seq, param_bytes)
+    key = (model.depth, model.passes, batch, seq, model.dim, model.recompute,
+           plan["kept_proj_applications"])
+    if key not in _planned:
+        _planned.add(key)
+        from distribuuuu_tpu.telemetry import spans
 
-    applications = model.depth * model.passes
-    spans.emit_event(
-        "loop.plan", layers=model.depth, passes=model.passes,
-        block_applications=applications,
-        **kept_plan(
-            model, applications, batch, seq, model.dim // model.num_heads,
-            "every block application", branches=2 * applications),
-    )
+        spans.emit_event("loop.plan", **plan)
+        if plan["capacity_bytes"]:  # a run without a telemetry sink says it too
+            from distribuuuu_tpu.utils.logger import get_logger
+
+            get_logger().info("loop.plan: %s", {
+                k: v for k, v in plan.items() if k != "recomputed"})
+    return plan
 
 
 def kept_plan(model, blocks: int, batch: int, seq: int, head_dim: int,
@@ -238,7 +329,7 @@ class Ouro(nn.Module):
     mesh: Any = None
     # a block application keeps its input, its two branches' outputs and what
     # the flash backward kernel reads (the forward's output, log-sum-exp, q, k
-    # and v), nothing else (see above)
+    # and v); the last of them, as many as fit, the MLP's two products (see above)
     recompute: bool = True
     # positions of every row the head takes at a time; its rows are the
     # batch's sequences R times over
@@ -251,14 +342,17 @@ class Ouro(nn.Module):
             raise ValueError(
                 f"input length {S} exceeds the context LM.SEQ_LEN={self.seq_len}"
             )
-        _say_plan(self, B, S)
+        # init differentiates nothing and its parameter tree is still empty
+        plan = _say_plan(self, B, S, 0 if self.is_initializing() else sum(
+            p.size * p.dtype.itemsize
+            for p in jax.tree.leaves(self.variables["params"])))
         x = nn.Embed(
             self.vocab_size, self.dim, name="tok_embed",
             dtype=head_dtype(self.dtype), param_dtype=jnp.float32,
             embedding_init=_normal(),
         )(tokens)
         positions = jnp.arange(S, dtype=jnp.int32)
-        block = recomputed(Block) if self.recompute else Block
+        block = recomputed(Block, static_argnums=(3,)) if self.recompute else Block
         blocks = [
             block(
                 self.dim, self.num_heads, self.mlp_hidden, self.rms_norm_eps,
@@ -269,9 +363,10 @@ class Ouro(nn.Module):
         final_norm = RMSNorm(self.rms_norm_eps, name="final_norm")
         exit_gate = ExitGate(name="exit_gate")
         states, gates = [], []
-        for _ in range(self.passes):
-            for apply_block in blocks:
-                x = apply_block(x, positions)
+        first_kept = self.depth * self.passes - plan["kept_proj_applications"]
+        for t in range(self.passes):
+            for i, apply_block in enumerate(blocks):
+                x = apply_block(x, positions, t * self.depth + i >= first_kept)
             x = final_norm(x)
             states.append(x.astype(self.dtype))
             with jax.named_scope("exit_gate"):

@@ -113,7 +113,8 @@ _NO_SHARD_MAP = ("conv_epilogue", "decode_attn", "moe_gmm", "moe_rows", "flash_a
 # requested) resolution, one kernel.fallback + warning per (op, reason)
 _emitted: set = set()
 _warned: set = set()
-_program = threading.local()  # trace-time: is this a one-device program?
+# trace-time: is this a one-device program? which device is it compiled for?
+_program = threading.local()
 
 
 def reset_selection() -> None:
@@ -178,6 +179,27 @@ def single_device_program():
         yield
     finally:
         _program.single = prev
+
+
+@contextlib.contextmanager
+def lowered_for(device):
+    """Declare the device the code traced inside is compiled FOR: the step
+    of ``partition/lowering.lower`` says its mesh's (on the chip the chip;
+    under ``benchmark/rehearse_compile.py`` the described chip, where
+    ``jax.devices()[0]`` is a CPU), so that a plan made from the device's
+    size at trace time (``models/ouro.plan_kept_proj``) is the same number
+    wherever the program is compiled."""
+    prev = getattr(_program, "device", None)
+    _program.device = device
+    try:
+        yield
+    finally:
+        _program.device = prev
+
+
+def target_device():
+    """The device :func:`lowered_for` declared around this trace, or None."""
+    return getattr(_program, "device", None)
 
 
 def compiled_across_devices() -> bool:

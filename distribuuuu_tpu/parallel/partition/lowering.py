@@ -34,6 +34,7 @@ legacy call sites (tests, tools, serve) keep working unchanged.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any
 
@@ -44,7 +45,7 @@ import optax
 
 from distribuuuu_tpu.config import cfg
 from distribuuuu_tpu.models.layers import head_dtype
-from distribuuuu_tpu.ops import token_head
+from distribuuuu_tpu.ops import pallas as kernel_tier, token_head
 from distribuuuu_tpu.parallel import sharding as sharding_lib, tp, zero
 from distribuuuu_tpu.parallel.partition import specs as specs_lib
 from distribuuuu_tpu.resilience import supervisor
@@ -454,14 +455,23 @@ def train_step_body(model, optimizer, topk: int, accum_steps: int = 1,
 
 
 def make_train_step(model, optimizer, topk: int, accum_steps: int = 1,
-                    layout=None, rest_layout=None):
+                    layout=None, rest_layout=None, mesh=None):
     """Compile-once train step: fwd + CE loss + bwd + SGD + metrics
-    (≙ the hot loop body, ref: trainer.py:37-58)."""
-    return jax.jit(
-        train_step_body(model, optimizer, topk, accum_steps, layout=layout,
-                        rest_layout=rest_layout),
-        donate_argnums=0,
-    )
+    (≙ the hot loop body, ref: trainer.py:37-58). Its trace declares the
+    first device of ``mesh`` (passed by :func:`lower`) as the one it is
+    compiled for, attached or described (``kernel_tier.lowered_for``): what a
+    model plans from the device's size (``models/ouro.plan_kept_proj``) it
+    plans for that device, and for none without a mesh."""
+    body = train_step_body(model, optimizer, topk, accum_steps, layout=layout,
+                           rest_layout=rest_layout)
+    device = None if mesh is None else mesh.devices.flat[0]
+
+    @functools.wraps(body)
+    def train_step(state, batch):
+        with kernel_tier.lowered_for(device):
+            return body(state, batch)
+
+    return jax.jit(train_step, donate_argnums=0)
 
 
 def make_eval_step(model, topk: int, layout=None):
@@ -680,7 +690,7 @@ def lower(model, optimizer, topk: int, *, mesh, topology, im_size: int,
         _log_zero_schedule(step_layout, topology)
     train_step = make_train_step(
         model, optimizer, topk, accum_steps=accum, layout=step_layout,
-        rest_layout=layout,
+        rest_layout=layout, mesh=mesh,
     )
     return Lowered(
         mesh=mesh, topology=topology, layout=layout, step_layout=step_layout,

@@ -161,11 +161,18 @@ KINDS: dict[str, frozenset] = {
     # L blocks, what the R x L block applications keep for the backward
     # (bytes a step: their float32 inputs, kept_branch_bytes, the outputs of
     # the branches the backward reads again, and kept_flash_bytes, the flash
-    # kernel's output, log-sum-exp, q, k and v, 0 where the scan path ran)
-    # and what the backward computes again
+    # kernel's output, log-sum-exp, q, k and v, 0 where the scan path ran;
+    # and kept_proj_bytes, the gated MLP's two products in the LAST
+    # kept_proj_applications of the forward), what the backward computes
+    # again, and the numbers that count was planned from at trace time
+    # (models/ouro.plan_kept_proj): the capacity of the device the step is
+    # compiled for (None: nothing planned), the bytes the step is known to
+    # hold from shapes, and the reserve left unplanned
     "loop.plan": frozenset(
         {"layers", "passes", "block_applications", "kept_bytes",
-         "kept_branch_bytes", "kept_flash_bytes", "recomputed"}
+         "kept_branch_bytes", "kept_flash_bytes", "recomputed",
+         "kept_proj_applications", "kept_proj_bytes", "capacity_bytes",
+         "planned_bytes", "reserve_bytes"}
     ),
     # one per traced shape of a model that is one chip's share of an
     # expert-parallel group (models/glm_moe.py): how many chips share each

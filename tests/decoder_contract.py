@@ -327,6 +327,21 @@ def read_only(tree):
     return jax.tree.map(frozen, tree)
 
 
+def room_for_kept_products(monkeypatch, model, params, batch: tuple, kept: int) -> dict:
+    """Patch the capacity source of Ouro's planner (``models/ouro._capacity_bytes``:
+    no model field, no config key) with the number at which exactly ``kept``
+    block applications of ``model`` on ``batch`` (sequences, length) keep the
+    MLP's two products; returns the plan at no capacity."""
+    monkeypatch.setattr(ouro, "_capacity_bytes", lambda: None)
+    size = sum(p.size * p.dtype.itemsize for p in jax.tree.leaves(params))
+    base = ouro.loop_plan(model, *batch, size)
+    assert base["kept_proj_applications"] == 0 and base["capacity_bytes"] is None
+    proj = 2 * batch[0] * batch[1] * model.mlp_hidden * jnp.dtype(model.dtype).itemsize
+    capacity = base["planned_bytes"] + ouro.RESERVE_BYTES + kept * proj + proj // 2
+    monkeypatch.setattr(ouro, "_capacity_bytes", lambda: capacity)
+    return base
+
+
 def records(directory, kind) -> list:
     """The telemetry records of one kind under ``directory``, file by file."""
     return [r for name in sorted(os.listdir(directory))

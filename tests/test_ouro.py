@@ -251,3 +251,194 @@ def test_the_loop_says_its_plan_once_a_shape(tmp_path):
     # float32 here: an input and two branches' outputs a block application
     assert plans[0]["kept_branch_bytes"] == 2 * 8 * 3 * 24 * 64 * 4
     assert plans[0]["kept_bytes"] == 3 * 8 * 3 * 24 * 64 * 4
+
+
+# ------------------------------------------- the MLP's two products, kept
+GIB = 2**30
+
+
+@pytest.mark.parametrize("capacity,held,proj,applications,reserve,want", [
+    (16 * GIB, 11 * GIB, GIB // 8, 32, 2 * GIB, 24),        # room for 24 of 32
+    (16 * GIB, 11 * GIB, GIB // 8, 16, 2 * GIB, 16),        # capped at the applications
+    (16 * GIB, 14 * GIB, GIB // 8, 32, 2 * GIB, 0),         # exactly full: none
+    (16 * GIB, 14 * GIB - GIB // 8, GIB // 8, 32, 2 * GIB, 1),  # room for exactly one
+    (16 * GIB, 15 * GIB, GIB // 8, 32, 2 * GIB, 0),         # nothing fits: never negative
+    (None, 0, GIB // 8, 32, 0, 0),                          # no device declared, or the CPU
+    (0, 0, GIB // 8, 32, 0, 0),
+    (16 * GIB, 0, 0, 32, 0, 0),                             # nothing to keep
+])
+def test_the_planner_counts_what_fits_under_capacity_less_reserve(
+        capacity, held, proj, applications, reserve, want):
+    assert ouro.plan_kept_proj(capacity, held, proj, applications, reserve) == want
+
+
+def test_the_planner_is_monotone_in_capacity_and_reads_no_environment(monkeypatch):
+    counts = [ouro.plan_kept_proj(c * GIB // 4, 12 * GIB, 88 * 2**20, 32, 2 * GIB)
+              for c in range(0, 100)]
+    assert counts == sorted(counts) and counts[0] == 0 and counts[-1] == 32
+    assert 0 < counts[64] < 32  # 16 GiB: the cell's own case lies between
+    before = ouro.plan_kept_proj(16 * GIB, 12_500_000_000, 92_274_688, 32, 2 * GIB)
+    for name in ("XLA_FLAGS", "JAX_PLATFORMS", "TPU_VISIBLE_CHIPS", "DTPU_CPU_PEAK_FLOPS",
+                 "JAX_COMPILATION_CACHE_DIR", "MEMORY_BUDGET"):
+        monkeypatch.setenv(name, "7")
+    assert ouro.plan_kept_proj(16 * GIB, 12_500_000_000, 92_274_688, 32, 2 * GIB) == before
+
+
+@pytest.mark.parametrize("kind,want", [
+    ("TPU v5 lite", 16 * GIB), ("TPU v5e", 16 * GIB), ("TPU v4", 32 * GIB),
+    ("cpu", None), ("a chip the table lacks", None), (None, None),
+])
+def test_the_capacity_is_the_tables_for_the_device_the_step_declared(kind, want):
+    """By ``device_kind`` from ``costmodel.DEVICE_PEAKS`` of the device the
+    trace was declared for (a described device has no allocator to ask);
+    nothing where no step declared one, whatever the live backend is."""
+    import contextlib
+    import types
+
+    from distribuuuu_tpu.ops import pallas as kernel_tier
+
+    declared = contextlib.nullcontext() if kind is None else kernel_tier.lowered_for(
+        types.SimpleNamespace(device_kind=kind))
+    with declared:
+        assert ouro._capacity_bytes() == want
+    assert ouro._capacity_bytes() is None and kernel_tier.target_device() is None
+
+
+def _capacity_for(monkeypatch, model, params, tokens, kept: int):
+    return contract.room_for_kept_products(monkeypatch, model, params, tokens.shape, kept)
+
+
+def _eqns(jaxpr, primitive):
+    return [e for e in jaxpr.eqns if e.primitive.name == primitive]
+
+
+def _recomputed_bodies(jaxpr) -> list:
+    """The jaxprs of ``jax.checkpoint``'s equations, in program order."""
+    return [e.params["jaxpr"] for e in _eqns(jaxpr, "remat2")]
+
+
+def _recomputes_the_products(body) -> bool:
+    """Whether a backward's recomputed body (``remat2``) runs ``gate_proj``
+    and ``up_proj`` again: both or neither."""
+    again = {proj for e in _eqns(body, "dot_general") for proj in ("gate_proj", "up_proj")
+             if "rematted_computation" in str(e.source_info.name_stack)
+             and str(e.source_info.name_stack).endswith(proj)}
+    assert len(again) in (0, 2), again
+    return bool(again)
+
+
+@pytest.mark.parametrize("kept,want", [(0, 0), (3, 3), (8, 8), (11, 8)])
+def test_the_last_applications_keep_the_products_and_the_others_run_them_again(
+        monkeypatch, kept, want):
+    """2 layers x 4 passes: with room for ``kept`` applications the forward
+    names the two products in the LAST ``want`` of its 8 recomputed bodies
+    and in no other, and the backward, which reaches those first, runs
+    ``gate_proj`` and ``up_proj`` again in exactly the other ``8 - want``."""
+    model = build(depth=2)
+    params, _, tokens, labels = seeded(model)
+    _capacity_for(monkeypatch, model, params, tokens, kept)
+
+    def loss(p):
+        return program_loss(model, p, None, tokens, labels)[0]
+
+    forward = _recomputed_bodies(jax.make_jaxpr(loss)(params).jaxpr)
+    named = [sum(e.params["name"] == ouro.KEPT_PROJ for e in _eqns(body, "name"))
+             for body in forward]
+    assert named == [0] * (8 - want) + [2] * want
+    backward = _recomputed_bodies(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+    assert [_recomputes_the_products(body) for body in backward] == (
+        [False] * want + [True] * (8 - want))
+
+
+@pytest.mark.parametrize("kept", [3, 8])
+def test_keeping_the_products_changes_no_bit_of_the_step(monkeypatch, kept):
+    """Loss, every gradient leaf and the parameters after one AdamW step with
+    ``kept`` applications keeping their products equal the step's that keeps
+    none, to the last bit: the kept values are the values it computes again."""
+    import optax
+
+    model = build(depth=2)
+    params, _, tokens, labels = seeded(model, seq=40)
+    optimizer = optax.adamw(1e-3, weight_decay=0.1)
+
+    def a_step():  # a function of its own a plan: jax caches a function's trace
+        def step(p):
+            loss, grads = jax.value_and_grad(
+                lambda p: program_loss(model, p, None, tokens, labels)[0])(p)
+            updates, _ = optimizer.update(grads, optimizer.init(p), p)
+            return loss, grads, optax.apply_updates(p, updates)
+        return step
+
+    ran = {}
+    for n in (0, kept):
+        _capacity_for(monkeypatch, model, params, tokens, n)
+        assert str(jax.make_jaxpr(a_step())(params)).count(f"name={ouro.KEPT_PROJ}") == 2 * n
+        ran[n] = jax.device_get(jax.jit(a_step())(params))
+    for a, b in zip(jax.tree.leaves(ran[0]), jax.tree.leaves(ran[kept])):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("kept", [0, 5])
+def test_the_plan_record_says_how_many_keep_and_what_it_was_planned_from(
+        monkeypatch, tmp_path, kept):
+    """``loop.plan`` of 2 layers x 4 passes on 3 x 24 tokens: the count, its
+    bytes (two ``[tokens, hidden]`` products an application in the compute
+    dtype) inside ``kept_bytes``, and the three numbers of the plan, which
+    add up: three times the parameters, what is kept, the head's float32
+    gradient and one chunk's logits over the 4 x 3 stacked rows."""
+    from distribuuuu_tpu.telemetry import schema, spans
+
+    model = build(depth=2, seq_len=24)
+    params, _, tokens, _ = seeded(model, batch=3, seq=24)
+    base = _capacity_for(monkeypatch, model, params, tokens, kept)
+    ouro._planned.clear()
+    spans.setup_telemetry(str(tmp_path), 0)
+    try:
+        for _ in range(2):
+            model.apply({"params": params}, tokens, hidden_only=True)
+    finally:
+        spans.close_telemetry()
+    (plan,) = contract.records(tmp_path, "loop.plan")
+    schema.validate_record(plan)
+    proj = 2 * 3 * 24 * model.mlp_hidden * 4
+    inputs_and_branches = 3 * 8 * 3 * 24 * 64 * 4
+    assert (plan["kept_proj_applications"], plan["kept_proj_bytes"]) == (kept, kept * proj)
+    assert plan["kept_bytes"] == inputs_and_branches + kept * proj
+    size = sum(p.size * 4 for p in jax.tree.leaves(params))
+    head = 4 * model.vocab_size * (64 + 4 * 3 * 24)
+    assert plan["planned_bytes"] == 3 * size + plan["kept_bytes"] + head
+    assert plan["planned_bytes"] == base["planned_bytes"] + kept * proj
+    assert plan["reserve_bytes"] == ouro.RESERVE_BYTES
+    assert plan["planned_bytes"] <= plan["capacity_bytes"] - plan["reserve_bytes"] < (
+        plan["planned_bytes"] + proj)
+    assert ("the last 5 applications also keep" in plan["recomputed"]) == bool(kept)
+
+
+@pytest.mark.parametrize("row", ["glm", "afmoe", "sdar"])
+def test_the_other_recomputing_stacks_name_and_plan_nothing_new(
+        monkeypatch, tmp_path, row):
+    """``GLMMoE`` and ``share.run_blocks`` pass no per-application argument:
+    with all the room in the world their dense MLPs name nothing, their
+    blocks keep what they kept and their plan records hold the fields they
+    held."""
+    from distribuuuu_tpu.models import glm_moe, share
+    from distribuuuu_tpu.telemetry import schema, spans
+
+    monkeypatch.setattr(ouro, "_capacity_bytes", lambda: 2**50)
+    row = contract.ROWS[row]
+    model = contract.build(row, **row.small)
+    params, biases, tokens, labels = seeded(model)
+    for module in (glm_moe, share):
+        module._planned.clear()
+    spans.setup_telemetry(str(tmp_path), 0)
+    try:
+        jaxpr = jax.make_jaxpr(  # the forward names what a block keeps
+            lambda p: program_loss(model, p, biases, tokens, labels)[0])(params)
+    finally:
+        spans.close_telemetry()
+    assert ouro.KEPT_PROJ not in str(jaxpr) and ouro.BRANCH_OUT in str(jaxpr)
+    (plan,) = contract.records(tmp_path, "share.plan")
+    record = {"kind", "rank", "t", "v"}
+    assert schema.KINDS["share.plan"] <= set(plan) - record <= (
+        schema.KINDS["share.plan"] | {"layer_kinds", "dense_layers"})
+    assert "keep the products" not in plan["recomputed"]

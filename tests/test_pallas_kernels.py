@@ -759,15 +759,21 @@ def test_run_report_prints_a_shares_experts_and_its_plan(tmp_path, capsys):
     assert "of 8 chips holds 8 of 64 experts and 19360 of 154880 vocabulary rows" in printed
 
 
-@pytest.mark.parametrize("kind,where", [
-    ("loop.plan", "looped stack: 4 passes over 8 layers, 32 block applications"),
-    ("share.plan", "share of a layer: rank 0 of 8 chips"),
+@pytest.mark.parametrize("kind,where,kept_proj", [
+    ("loop.plan", "looped stack: 4 passes over 8 layers, 32 block applications", None),
+    ("share.plan", "share of a layer: rank 0 of 8 chips", None),
+    ("loop.plan", "looped stack: 4 passes over 8 layers, 32 block applications", 23),
+    ("loop.plan", "looped stack: 4 passes over 8 layers, 32 block applications", 0),
 ])
-def test_run_report_prints_what_the_recomputed_blocks_keep(tmp_path, capsys, kind, where):
+def test_run_report_prints_what_the_recomputed_blocks_keep(
+        tmp_path, capsys, kind, where, kept_proj):
     """Both plan records in the ``kernels`` section with what their
     recomputed blocks keep a step: the bytes in all and, of them, the flash
     kernel's output, log-sum-exp, q, k and v and the branches' outputs
-    (``ouro_2_6b.train_seq4096``'s numbers)."""
+    (``ouro_2_6b.train_seq4096``'s numbers); a ``loop.plan`` that planned the
+    MLP's two products says how many applications keep them, their bytes and
+    the three numbers the count was planned from (23 on the v5e), or that it
+    had no device to plan for (0)."""
     import run_report
 
     plan = {
@@ -775,6 +781,13 @@ def test_run_report_prints_what_the_recomputed_blocks_keep(tmp_path, capsys, kin
         "share.plan": {"share_chips": 8, "share_rank": 0, "experts_held": 8,
                        "experts_total": 64, "vocab_held": 19360, "vocab_total": 154880},
     }[kind]
+    proj = 0
+    if kept_proj is not None:
+        proj = kept_proj * 92_274_688
+        plan.update(
+            kept_proj_applications=kept_proj, kept_proj_bytes=proj,
+            capacity_bytes=16 * 2**30 if kept_proj else None,
+            planned_bytes=12_457_918_476 + proj, reserve_bytes=2400 * 2**20)
     tdir = tmp_path / "telemetry"
     os.makedirs(tdir)
     recs = [
@@ -782,7 +795,7 @@ def test_run_report_prints_what_the_recomputed_blocks_keep(tmp_path, capsys, kin
         {"kind": "kernel.select", "rank": 0, "t": 1.0, "op": "flash_attn",
          "impl": "pallas", "requested": "auto"},
         {"kind": kind, "rank": 0, "t": 1.0, **plan,
-         "kept_bytes": 32 * 2**25 + 2**30 + 2155872256, "kept_branch_bytes": 2**30,
+         "kept_bytes": 32 * 2**25 + 2**30 + 2155872256 + proj, "kept_branch_bytes": 2**30,
          "kept_flash_bytes": 2155872256,
          "recomputed": "every block, from its float32 input and the flash "
                        "kernel's output, log-sum-exp, q, k and v"},
@@ -800,8 +813,17 @@ def test_run_report_prints_what_the_recomputed_blocks_keep(tmp_path, capsys, kin
     printed = capsys.readouterr().out
     assert where in printed
     assert "recomputed: every block, from its float32 input and the flash" in printed
-    assert ("kept 4104.0 MiB a step, 2056.0 of them the flash kernel's output, "
-            "log-sum-exp, q, k and v, 1024.0 the branches' outputs") in printed
+    assert (f"kept {4104 + proj / 2**20:.1f} MiB a step, 2056.0 of them the flash "
+            "kernel's output, log-sum-exp, q, k and v, 1024.0 the branches' "
+            "outputs") in printed
+    assert ("the MLP's two products" in printed) == (kept_proj is not None)
+    if kept_proj:
+        assert kern["loop_plan"]["kept_proj_applications"] == 23
+        assert ("outputs, 2024.0 the MLP's two products in the last 23 applications "
+                "(planned 13.58 GiB of 16.00 less a reserve of 2.34)") in printed
+    elif kept_proj == 0:
+        assert ("0.0 the MLP's two products in the last 0 applications "
+                "(no device to plan for)") in printed
 
 
 def test_bench_index_kernel_series_and_resnet50_reference(chip_bench_root):

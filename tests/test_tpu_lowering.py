@@ -244,10 +244,12 @@ def test_grouped_flash_compiles_for_the_v5e_without_a_repeated_key_or_value(v5e_
     assert fa.fits_vmem(8192, 64)
 
 
-def _step_for_the_v5e(v5e_chip, monkeypatch, yaml: str, batch: tuple, **lm):
+def _step_for_the_v5e(v5e_chip, monkeypatch, yaml: str, batch: tuple,
+                      compiled: bool = True, **lm):
     """``config/<yaml>.yaml``'s train step at ``batch`` tokens (sequences,
     length) with the ``LM`` keys ``lm`` over it, compiled for the chip:
-    ``(what lowering.lower returned, the abstract state, the executable)``."""
+    ``(what lowering.lower returned, the abstract state, the executable)``,
+    or with ``compiled=False`` the batch's avals in the executable's place."""
     from jax.sharding import SingleDeviceSharding
 
     import distribuuuu_tpu.config as config
@@ -279,6 +281,8 @@ def _step_for_the_v5e(v5e_chip, monkeypatch, yaml: str, batch: tuple, **lm):
         config.reset_cfg()
     chip = SingleDeviceSharding(v5e_chip)
     avals = {k: jax.ShapeDtypeStruct(batch, jnp.int32, sharding=chip) for k in avals}
+    if not compiled:
+        return lowered, state, avals
     return lowered, state, lowered.train_step.lower(state, avals).compile()
 
 
@@ -588,19 +592,28 @@ def test_moe_rows_compile_for_the_v5e_under_moe_route(v5e_chip, tokens):
 def test_ouro_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
     """The step of ``ouro_2_6b.train_seq4096`` (``config/ouro_2_6b.yaml``:
     published widths, 4096 tokens, all four passes) compiled for the chip at
-    1 of the cell's 8 layers (8 compile in five minutes here, 1 in under
-    one). What the benchmark's readers find in it: every scope they sum, the
-    three flash kernels by name, the recomputed forward by the ``op_name``
-    ``jax.checkpoint``'s transpose gives it, with NO forward kernel, no
-    projection of q, k or v and neither branch's last matmul (``o_proj``,
-    ``down_proj``: the block keeps what they made) in it, and no ``while`` (a
-    loop in a device trace is one operation AND its body's)."""
+    1 of the cell's 8 layers (8 compile in two minutes here, 1 in under
+    one), with a capacity handed to the planner at which 2 of the 4 block
+    applications keep the MLP's two products. What the benchmark's readers
+    find in it: every scope they sum, the three flash kernels by name, the
+    recomputed forward by the ``op_name`` ``jax.checkpoint``'s transpose gives
+    it, with NO forward kernel, no projection of q, k or v and neither
+    branch's last matmul (``o_proj``, ``down_proj``: the block keeps what they
+    made) in it, ``gate_proj`` and ``up_proj`` in it for exactly the 2
+    applications that do not keep their products, no instruction XLA
+    rematerialized on its own, and no ``while`` (a loop in a device trace is
+    one operation AND its body's)."""
+    import decoder_contract
     from benchmark.harness.trace import in_scope, op_names_from_hlo
 
-    _, state, compiled = _step_for_the_v5e(
-        v5e_chip, monkeypatch, "ouro_2_6b", (1, 4096), LAYERS=1)
+    lowered, state, avals = _step_for_the_v5e(
+        v5e_chip, monkeypatch, "ouro_2_6b", (1, 4096), compiled=False, LAYERS=1)
+    decoder_contract.room_for_kept_products(
+        monkeypatch, lowered.model, state.params, (1, 4096), kept=2)
+    compiled = lowered.train_step.lower(state, avals).compile()
     text = compiled.as_text()
     assert " while(" not in text and " conditional(" not in text
+    assert ".remat" not in text  # XLA found room for what the plan keeps
     paths = list(op_names_from_hlo(text).values())
     for scope in ("fwd", "bwd", "attn", "mlp", "exit_gate", "lm_head",
                   "optimizer_update", "opt_kernel"):
@@ -637,6 +650,51 @@ def test_ouro_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):
         assert any(f"{proj}/dot_general" in p for p in paths), proj
         assert not any(f"{proj}/dot_general" in p for p in recomputed), proj
     assert not any(in_scope(p, "lm_head") or in_scope(p, "exit_gate") for p in recomputed)
+    # the two products: made again in the 2 applications that do not keep
+    # them (one fusion each), in the forward of all 4
+    for proj in ("gate_proj", "up_proj"):
+        fusions = [line.split('op_name="')[1].split('"')[0]
+                   for line in text.splitlines() if " fusion(" in line
+                   and "calls=" in line and f'{proj}/dot_general"' in line]
+        assert sum(in_scope(p, "rematted_computation") for p in fusions) == 2, proj
+        assert sum(in_scope(p, "fwd") and not in_scope(p, "bwd") for p in fusions) == 4, proj
+
+
+def test_the_described_v5e_plans_what_its_capacity_by_hand_plans(v5e_chip, monkeypatch):
+    """The same step traced for the described chip with NOTHING patched: the
+    plan is made from the table's 16 GiB for a ``TPU v5 lite`` (the step
+    declared the chip it is lowered for; the live backend here is a CPU, which
+    plans none), it is the count the pure planner gives those numbers by hand,
+    and at 1 layer that is every application: the rehearsal and the chip
+    compile one program."""
+    from distribuuuu_tpu.models import ouro
+
+    lowered, state, avals = _step_for_the_v5e(
+        v5e_chip, monkeypatch, "ouro_2_6b", (1, 4096), compiled=False, LAYERS=1)
+    said = []
+
+    def say_plan(*a):
+        said.append(ouro.loop_plan(*a))
+        return said[-1]
+
+    monkeypatch.setattr(ouro, "_say_plan", say_plan)
+    jaxpr = jax.make_jaxpr(lowered.train_step)(state, avals)
+    plan = [p for p in said if p["capacity_bytes"]][-1]
+    assert v5e_chip.device_kind == "TPU v5 lite" and plan["capacity_bytes"] == 16 * 2**30
+    proj = 2 * 4096 * 5632 * 2
+    by_hand = ouro.plan_kept_proj(
+        16 * 2**30, plan["planned_bytes"] - plan["kept_proj_bytes"], proj, 4,
+        ouro.RESERVE_BYTES)
+    assert plan["kept_proj_applications"] == by_hand == 4
+    assert plan["kept_proj_bytes"] == 4 * proj
+    assert str(jaxpr).count(f"name={ouro.KEPT_PROJ}") == 2 * 4
+    # ... and the same trace with no declared device plans none
+    assert jax.devices()[0].platform == "cpu"
+    model = lowered.model
+    jax.eval_shape(
+        lambda p, t: model.apply({"params": p}, t, hidden_only=True),
+        state.params, avals["image"])
+    assert (said[-1]["capacity_bytes"], said[-1]["kept_proj_applications"]) == (None, 0)
 
 
 def test_glm_step_compiles_for_the_v5e_under_its_scopes(v5e_chip, monkeypatch):

@@ -417,6 +417,16 @@ def _recomputed(plan: dict) -> str:
         if plan.get("kept_branch_bytes") is not None:
             said += (f", {plan['kept_branch_bytes'] / 2**20:.1f} the branches' "
                      "outputs")
+        if plan.get("kept_proj_applications") is not None:
+            said += (f", {plan['kept_proj_bytes'] / 2**20:.1f} the MLP's two "
+                     f"products in the last {plan['kept_proj_applications']} "
+                     "applications")
+            if plan.get("capacity_bytes"):
+                said += (f" (planned {plan['planned_bytes'] / 2**30:.2f} GiB of "
+                         f"{plan['capacity_bytes'] / 2**30:.2f} less a reserve "
+                         f"of {plan['reserve_bytes'] / 2**30:.2f})")
+            else:
+                said += " (no device to plan for)"
     return said
 
 
