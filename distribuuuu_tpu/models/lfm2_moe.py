@@ -15,7 +15,10 @@ a decoder whose token mixer is chosen LAYER BY LAYER from the published
   ``num_attention_heads`` query heads on ``num_key_value_heads`` key/value
   heads, query head ``h`` reading key/value head ``h // group``; RMSNorm over
   each head's dims on q and on k, one learned scale each; rotary over the
-  whole head, rotate-half; causal softmax through ``ops/flash_attention.py``,
+  whole head, rotate-half (:class:`HeadNorm`: at heads a multiple of 128
+  wide in a one-device TPU program one Pallas call each way,
+  ``ops/head_prologue.py``; at this model's 64 the ``jax.numpy`` lines);
+  causal softmax through ``ops/flash_attention.py``,
   which takes k and v with their own fewer heads: nothing is repeated in
   HBM).
 * ``F_i`` is a dense gated-SiLU MLP of ``intermediate_size`` in the first
@@ -57,7 +60,7 @@ import jax.numpy as jnp
 
 from distribuuuu_tpu.models.glm_moe import Mixture, _dense
 from distribuuuu_tpu.models.layers import head_dtype
-from distribuuuu_tpu.models.olmoe import RMSNorm, _attend, _normal, rotary
+from distribuuuu_tpu.models.olmoe import RMSNorm, _attend, _normal, rms_norm, rotary
 from distribuuuu_tpu.models.share import (
     PatternStack,
     pattern_kwargs_from_cfg,
@@ -66,6 +69,7 @@ from distribuuuu_tpu.models.share import (
 )
 from distribuuuu_tpu.models.traits import ArchTraits
 from distribuuuu_tpu.models.vit import Attention as VitAttention
+from distribuuuu_tpu.ops import head_prologue
 from distribuuuu_tpu.ops.short_conv import gated_short_conv
 
 # config.json's layer_types: conv, conv, attention, conv, and so on to 40
@@ -85,8 +89,52 @@ class ShortConv(nn.Module):
         return _dense(self.dim, self.dtype, "out_proj")(gated_short_conv(bcu, w))
 
 
+class HeadNorm(nn.Module):
+    """A q or k projection's way to the attention kernels, under the device
+    scope ``attn_prologue``: ``t [B, S, n D]`` (``x W`` as the projection wrote
+    it) to heads-major ``[B, n, S, D]``, an RMSNorm over each head's dims with
+    ONE learned ``scale [D]``, the rotary where ``theta`` is a number, float32
+    throughout and rounded once to ``t``'s dtype.
+
+    **Which path runs where** is ``ops/head_prologue.kernel_runs``'s to say,
+    from what the call observes (no knob, no model's name): in a one-device
+    TPU program with heads a multiple of 128 wide (Trinity-Mini, SDAR) ONE
+    Pallas call each way, ``dtpu_head_prologue_fwd`` / ``_bwd``, which keeps
+    ``t`` alone for the backward; on the CPU, in a program that may span
+    devices and at LFM2's heads of 64 :meth:`xla`, the ``jax.numpy`` lines
+    this class always ran, under plain autodiff (and the kernel's reference in
+    ``tests/test_head_prologue.py``)."""
+
+    heads: int
+    eps: float
+    theta: Any  # None: the layer carries no position signal
+
+    @staticmethod
+    def xla(t, scale, positions, heads: int, eps: float, theta):
+        B, S, width = t.shape
+        x = t.reshape(B, S, heads, width // heads).transpose(0, 2, 1, 3)
+        x = rms_norm(x, scale, eps)
+        if theta is not None:
+            x = rotary(x, positions, theta)
+        return x.astype(t.dtype)
+
+    @nn.compact
+    def __call__(self, t, positions):
+        scale = self.param(
+            "scale", nn.initializers.ones, (t.shape[-1] // self.heads,), jnp.float32)
+        with jax.named_scope("attn_prologue"):
+            if head_prologue.kernel_runs(t, self.heads, self.theta is not None):
+                return head_prologue.head_prologue(
+                    t, scale, positions, heads=self.heads, eps=self.eps,
+                    theta=self.theta)
+            return self.xla(t, scale, positions, self.heads, self.eps, self.theta)
+
+
 class Attention(nn.Module):
-    """Grouped-query attention with a per-head RMSNorm on q and on k. The
+    """Grouped-query attention with a per-head RMSNorm on q and on k
+    (:class:`HeadNorm`, which says which path takes a projection's output to
+    the kernels' layout: one Pallas call each way on the TPU at heads of 128,
+    the ``jax.numpy`` lines elsewhere and at LFM2's 64). The
     defaults of the last five are LFM2's; ``models/afmoe.py`` gives four:
     heads of ``head_dim`` (0: ``dim / num_heads``), a sliding ``window``
     (under the scope ``attn_window``), no ``rotary`` where a layer carries no
@@ -116,15 +164,16 @@ class Attention(nn.Module):
         D = self.head_dim or self.dim // H
         x = x.astype(self.dtype)
 
+        def proj(name, n):
+            return _dense(n * D, self.dtype, f"{name}_proj")(x)
+
         def heads(name, n):  # x W -> [B, n, S, D]
-            t = _dense(n * D, self.dtype, f"{name}_proj")(x)
-            return t.reshape(B, S, n, D).transpose(0, 2, 1, 3)
+            return proj(name, n).reshape(B, S, n, D).transpose(0, 2, 1, 3)
 
         def normed(name, n):  # one scale of D for every head
-            t = RMSNorm(self.eps, name=f"{name}_norm")(heads(name, n))
-            if self.rotary:
-                t = rotary(t, positions, self.rope_theta)
-            return t.astype(self.dtype)
+            return HeadNorm(
+                n, self.eps, self.rope_theta if self.rotary else None,
+                name=f"{name}_norm")(proj(name, n), positions)
 
         masked = (jax.named_scope("attn_window") if self.window is not None
                   else jax.named_scope("attn_diffusion")

@@ -50,6 +50,14 @@ def _normal():
     return nn.initializers.normal(INIT_STD)
 
 
+def rms_norm(x, scale, eps: float):
+    """``x * rsqrt(mean(x^2) + eps) * scale`` over the last axis, statistics
+    and result in float32."""
+    x32 = x.astype(head_dtype(x.dtype))
+    inv = jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+    return x32 * inv * scale
+
+
 class RMSNorm(nn.Module):
     """Statistics and result in float32; the caller rounds where it feeds a
     matmul."""
@@ -59,9 +67,7 @@ class RMSNorm(nn.Module):
     @nn.compact
     def __call__(self, x):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
-        x32 = x.astype(head_dtype(x.dtype))
-        inv = jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.eps)
-        return x32 * inv * scale
+        return rms_norm(x, scale, self.eps)
 
 
 def rotary(x, positions, theta: float):

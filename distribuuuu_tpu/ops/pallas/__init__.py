@@ -1,7 +1,7 @@
 """The Pallas kernel tier (ISSUE 13): fused kernels for the memory-bound
 programs the cost ledger pinned, as ONE subsystem instead of one-offs.
 
-Six kernels, one discipline:
+Seven kernels, one discipline:
 
 * ``opt_update``     — fused optimizer update (opt_update.py): ONE HBM
                        pass over params+grads+moments for SGD-momentum
@@ -40,6 +40,16 @@ Six kernels, one discipline:
                        wrote shifted copies. No knob: it runs in a
                        one-device TPU program where the channels fill
                        the lanes and a sequence block divides the length.
+* ``head_prologue``  — a q or k projection's way to the flash kernels
+                       where every head has its own RMSNorm
+                       (head_prologue.py): the norm, the rotary (its
+                       half turn a permutation matmul on the idle MXU)
+                       and the heads-major layout (the out block's
+                       stride) as ONE call each way over the projection's
+                       output as it came, where XLA ran separate float32
+                       passes. No knob: it runs in a
+                       one-device TPU program where a head fills the
+                       lanes and a row block divides the length.
 
 Tier discipline (every kernel, no exceptions):
 
@@ -100,14 +110,14 @@ KNOBS = {
 # ops without a knob: ``auto``, or ``pallas`` where the caller forces it
 # (``flash_attn`` is ops/flash_attention.py's two kernels, outside this
 # package; it resolves here so that its record sits beside the others)
-KNOBLESS = ("moe_gmm", "moe_rows", "flash_attn", "short_conv")
+KNOBLESS = ("moe_gmm", "moe_rows", "flash_attn", "short_conv", "head_prologue")
 
 # ops that have no shard_map of their own: they engage in a program their
 # caller declared one-device (module docstring; ``moe_gmm``'s caller
 # declares it inside its shard_map over the data axis, as ``flash_attn``'s
 # does where it was handed a mesh)
 _NO_SHARD_MAP = ("conv_epilogue", "decode_attn", "moe_gmm", "moe_rows", "flash_attn",
-                 "short_conv")
+                 "short_conv", "head_prologue")
 
 # process-lifetime emission/warn dedup: one kernel.select per (op, impl,
 # requested) resolution, one kernel.fallback + warning per (op, reason)

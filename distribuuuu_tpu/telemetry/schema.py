@@ -152,7 +152,9 @@ KINDS: dict[str, frozenset] = {
     # and a sequence's tiles_visited, tiles_crossed (by the diagonal or the
     # padding), tiles_masked (those that run the mask), bwd_matmuls_a_tile;
     # `short_conv` (ops/pallas/short_conv.py) seq_block, row_chunk,
-    # lane_chunk, taps, channels, tokens
+    # lane_chunk, taps, channels, tokens; `head_prologue`
+    # (ops/pallas/head_prologue.py) rows, heads, head_dim, rotary,
+    # row_block, row_chunk
     "kernel.select": frozenset({"op", "impl", "requested"}),
     # a forced-but-unsupported site degrading to the XLA reference, with
     # the disqualifying reason (also warn-once logged)
@@ -322,6 +324,11 @@ DEVICE_SCOPES: dict[str, str] = {
     # tokens; inside ``attn``, the mixer under the block-diffusion mask
     "diffusion_noise": "models",
     "attn_diffusion": "models",
+    # models/lfm2_moe.HeadNorm (inside ``attn``, and inside ``attn_window`` /
+    # ``attn_diffusion`` where a layer has one): a q or k projection's way to
+    # the attention kernels and NOT the projection: the heads-major layout,
+    # the per-head norm, the rotary, the cast (ops/head_prologue.py)
+    "attn_prologue": "kernels",
     # ops/pallas/opt_update.py
     "opt_tile": "kernels",
     "opt_kernel": "kernels",
@@ -342,6 +349,9 @@ KERNEL_NAMES: tuple[str, ...] = (
     # ops/pallas/short_conv.py: _fwd, _bwd (under ``short_conv_gate``: the
     # two gates and the filter of a ``conv`` layer, one call each way)
     "dtpu_short_conv",
+    # ops/pallas/head_prologue.py: _fwd, _bwd (under ``attn_prologue``: q's
+    # and k's norm, rotary and layout, one call each way)
+    "dtpu_head_prologue",
 )
 
 

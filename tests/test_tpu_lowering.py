@@ -545,6 +545,52 @@ def test_short_conv_compiles_for_the_v5e_whole_in_and_whole_out(v5e_chip):
     assert not activations, activations
 
 
+@pytest.mark.parametrize("batch,seq,heads,rotary", [
+    (1, 16384, 32, True), (1, 16384, 4, True), (2, 8192, 32, False), (2, 8192, 4, False),
+], ids=["sdar_q", "sdar_k", "trinity_q_full", "trinity_k_full"])
+def test_head_prologue_compiles_for_the_v5e_as_the_projection_wrote_it(
+        v5e_chip, batch, seq, heads, rotary):
+    """A q or k projection's way to the flash kernels at SDAR's and
+    Trinity-Mini's shapes (heads of 128, bf16), forward and backward: Mosaic
+    takes the row blocks of 512 the whole ``n D`` wide in, the ``[n, 512,
+    128]`` head blocks out, the lane roll and the partial sums in the VMEM the
+    calls ask for; ``t`` reaches both calls as it came (no transpose, no
+    reshape that copies) and beside the two calls the program holds nothing
+    the size of an activation."""
+    from jax.sharding import SingleDeviceSharding
+
+    from distribuuuu_tpu.ops import head_prologue as op
+
+    chip = SingleDeviceSharding(v5e_chip)
+    t = jax.ShapeDtypeStruct((batch, seq, heads * 128), jnp.bfloat16, sharding=chip)
+    scale = jax.ShapeDtypeStruct((128,), jnp.float32, sharding=chip)
+    positions = jax.ShapeDtypeStruct((seq,), jnp.int32, sharding=chip)
+    dy = jax.ShapeDtypeStruct((batch, heads, seq, 128), jnp.bfloat16, sharding=chip)
+
+    def step(t, scale, positions, dy):
+        y, vjp = jax.vjp(lambda t, scale: op.head_prologue(
+            t, scale, positions, heads=heads, eps=1e-6,
+            theta=1e6 if rotary else None, interpret=False), t, scale)
+        return y, vjp(dy)
+
+    text = jax.jit(step).lower(t, scale, positions, dy).compile().as_text()
+    entry = [line.strip() for line in text[text.index("\nENTRY "):].splitlines()
+             if " = " in line]
+    calls = [line for line in entry if "custom-call(" in line]
+    assert sorted(line.split(" = ")[0].lstrip("%").rsplit(".", 1)[0] for line in calls) == [
+        "dtpu_head_prologue_bwd", "dtpu_head_prologue_fwd"]
+    activations = [line[:160] for line in entry
+                   if line.split(" = ")[1].startswith((
+                       f"bf16[{batch},{seq},", f"f32[{batch},{seq},",
+                       f"bf16[{batch},{heads},", f"f32[{batch},{heads},"))
+                   # k's 16 MiB alone: XLA stages them through the alternate
+                   # memory with asynchronous copies of its own
+                   and not any(f" {kind}(" in line for kind in (
+                       "custom-call", "get-tuple-element", "parameter",
+                       "copy-start", "copy-done"))]
+    assert not activations, activations
+
+
 @pytest.mark.parametrize("tokens", [8192, 16384], ids=["glm", "lfm2"])
 def test_moe_rows_compile_for_the_v5e_under_moe_route(v5e_chip, tokens):
     """The movers of a held share at the two cells' shapes (8 of 64 experts
@@ -814,4 +860,16 @@ def test_trinity_step_compiles_for_the_v5e_short_of_each_branchs_last_matmul(
     flash = {k: len(v) for k, v in calls.items() if "flash" in k}
     assert (flash["dtpu_flash_fwd"], flash["dtpu_flash_bwd"]) == (2, 2)
     assert not any(in_scope(p, "rematted_computation") for p in calls["dtpu_flash_fwd"])
+    # q's and k's way from their projections to the kernels: one call each way
+    # a layer (PR 51), under ``attn_prologue`` with no matmul in it; the second
+    # forward runs the projections for the backward call and never the
+    # forward one, whose one reader's outputs are kept
+    prologue = {k: v for k, v in calls.items() if "head_prologue" in k}
+    assert {k: len(v) for k, v in prologue.items()} == {
+        "dtpu_head_prologue_fwd": 4, "dtpu_head_prologue_bwd": 4}
+    assert all(in_scope(p, "attn_prologue") and in_scope(p, "attn_window")
+               for v in prologue.values() for p in v)
+    assert not any(in_scope(p, "rematted_computation")
+                   for p in prologue["dtpu_head_prologue_fwd"])
+    assert not any(p.endswith("dot_general") for p in paths if in_scope(p, "attn_prologue"))
     assert len(calls["dtpu_opt_update_adamw"]) == len(jax.tree.leaves(state.params))

@@ -475,6 +475,139 @@ def bench_short_conv(rounds: int, iters: int) -> dict:
     return out
 
 
+# (B, S, n, rotary): q and k of SDAR's cell (one sequence, a noised and a
+# clean copy: 16,384 rows at the positions 0..8191 twice) and of
+# Trinity-Mini's (two sequences; a sliding layer has a rotary, the full one
+# none); heads of 128
+HEAD_PROLOGUE_SHAPES = {
+    "sdar_q": (1, 16384, 32, True), "sdar_k": (1, 16384, 4, True),
+    "trinity_q": (2, 8192, 32, True), "trinity_k": (2, 8192, 4, True),
+    "trinity_q_full": (2, 8192, 32, False), "trinity_k_full": (2, 8192, 4, False),
+}
+HEAD_PROLOGUE_BLOCKS = (128, 256, 512, 1024)
+
+
+def _head_prologue_f64(t, scale, dy, positions, heads, eps, theta):
+    """y, dt and dscale of the per-head norm and rotary in float64 numpy,
+    from the inputs as the device holds them."""
+    import numpy as np
+
+    B, S, width = t.shape
+    D, half = width // heads, width // heads // 2
+    x = np.asarray(t, np.float64).reshape(B, S, heads, D).transpose(0, 2, 1, 3)
+    w, g = np.asarray(scale, np.float64), np.asarray(dy, np.float64)
+    inv = 1.0 / np.sqrt(np.mean(x * x, -1, keepdims=True) + eps)
+    xhat = x * inv
+    y = xhat * w
+    if theta is not None:
+        angles = np.asarray(positions, np.float64)[:, None] * theta ** (
+            -np.arange(half, dtype=np.float64) / half)
+        cos, sin = (np.concatenate([f(angles)] * 2, -1) for f in (np.cos, np.sin))
+
+        def rotate_half(v, sign):  # [-b, a]; its transpose [b, -a]
+            return np.concatenate([-sign * v[..., half:], sign * v[..., :half]], -1)
+
+        y = y * cos + rotate_half(y, 1.0) * sin
+        g = g * cos + rotate_half(g * sin, -1.0)
+    dscale = (g * xhat).sum((0, 1, 2))
+    g = g * w
+    dx = inv * (g - xhat * np.mean(g * xhat, -1, keepdims=True))
+    return {"y": y, "dt": dx.transpose(0, 2, 1, 3).reshape(B, S, width), "dscale": dscale}
+
+
+def bench_head_prologue(rounds: int, iters: int) -> dict:
+    """A q or k projection's way to the flash kernels ALONE (per-head norm,
+    rotary, heads-major layout) at SDAR's and Trinity-Mini's shapes in
+    bfloat16, the ``jax.numpy`` lines (``models/lfm2_moe.HeadNorm.xla`` under
+    autodiff) against the two Pallas calls (``ops/pallas/head_prologue.py``):
+    forward and forward + backward in ms and as a share of the HBM's
+    bandwidth on the bytes a perfect fusion moves (forward ``t`` in and the
+    heads out; backward ``dy`` and ``t`` in and ``dt`` out), each path's y,
+    dt and dscale against float64 numpy on the same inputs (largest error
+    over the reference's largest value), and the kernel at every row block
+    that fits. Off the TPU: a toy shape through the interpreter, which
+    rehearses the path and times nothing worth keeping."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distribuuuu_tpu.models.lfm2_moe import HeadNorm
+    from distribuuuu_tpu.ops import head_prologue as op
+    from distribuuuu_tpu.ops.pallas import head_prologue as kernel
+    from distribuuuu_tpu.telemetry import costmodel
+
+    on_chip = jax.default_backend() == "tpu"
+    shapes = HEAD_PROLOGUE_SHAPES if on_chip else {
+        "toy_q": (2, 64, 4, True), "toy_k_full": (2, 64, 2, False)}
+    if on_chip:  # a call is under a millisecond: amortise its dispatch
+        iters = max(iters, 20)
+    D, eps, dtype = 128, 1e-6, jnp.bfloat16
+    peaks = costmodel.peaks_for()
+    itemsize = jnp.dtype(dtype).itemsize
+    out = {"head_dim": D, "dtype": "bfloat16", "on_chip": on_chip, "shapes": {}}
+    for label, (B, S, n, rotary) in shapes.items():
+        theta = 1e6 if rotary else None
+        keys = jax.random.split(jax.random.key(n + rotary), 3)
+        t = (2.0 * jax.random.normal(keys[0], (B, S, n * D))).astype(dtype)
+        scale = 3.0 + 0.5 * jax.random.normal(keys[1], (D,))
+        dy = jax.random.normal(keys[2], (B, n, S, D)).astype(dtype)
+        positions = jnp.tile(jnp.arange(S // 2, dtype=jnp.int32), 2)
+
+        def both_ways(forward):
+            def fwd_bwd(t, scale, dy):
+                y, vjp = jax.vjp(forward, t, scale)
+                return y, vjp(dy)
+
+            return jax.jit(forward), jax.jit(fwd_bwd)
+
+        def xla(t, scale):
+            return HeadNorm.xla(t, scale, positions, n, eps, theta)
+
+        def calls(t, scale):  # the tables are part of the op: XLA makes them
+            return op.head_prologue(t, scale, positions, heads=n, eps=eps, theta=theta,
+                                    interpret=not on_chip)
+
+        rows = B * S * n * D * itemsize
+        moved = {"fwd": 2 * rows, "fwd_bwd": 5 * rows}
+        want = _head_prologue_f64(t, scale, dy, positions, n, eps, theta)
+        row = {"shape": [B, S, n * D], "rotary": rotary, "ideal_bytes": moved,
+               "row_block": kernel.row_block(S, n, D, dtype, rotary)}
+        for name, forward in (("xla", xla), ("kernel", calls)):
+            fwd, fwd_bwd = both_ways(forward)
+            y, (dt, dscale) = fwd_bwd(t, scale, dy)
+            got = {"y": y, "dt": dt, "dscale": dscale}
+            arm = {"max_err_over_max": {
+                k: float(np.abs(np.asarray(got[k], np.float64) - want[k]).max()
+                         / np.abs(want[k]).max()) for k in want}}
+            for part, fn, args in (("fwd", fwd, (t, scale)),
+                                   ("fwd_bwd", fwd_bwd, (t, scale, dy))):
+                arm[f"{part}_ms"] = ms = _med_ms(fn, args, rounds, iters)
+                if on_chip and peaks:
+                    arm[f"{part}_hbm_share"] = round(
+                        moved[part] / peaks["bytes_per_s"] / (ms / 1e3), 4)
+            row[name] = arm
+            print(f"head_prologue {label} {name}: " + "  ".join(
+                f"{part} {arm[f'{part}_ms']} ms ({arm.get(f'{part}_hbm_share')})"
+                for part in ("fwd", "fwd_bwd"))
+                + f"  against float64 {arm['max_err_over_max']}", flush=True)
+        row["xla_over_kernel"] = {
+            part: round(row["xla"][f"{part}_ms"] / row["kernel"][f"{part}_ms"], 2)
+            for part in ("fwd", "fwd_bwd")}
+        # the forward call alone at every row block that divides S and fits
+        tables = op.rotary_tables(positions, D, theta) if rotary else ()
+        row["fwd_ms_by_block"] = {
+            str(blk): _med_ms(functools.partial(
+                kernel.forward, heads=n, eps=eps, block=blk, interpret=not on_chip),
+                (t, scale, *tables), rounds, iters)
+            for blk in HEAD_PROLOGUE_BLOCKS
+            if S % blk == 0 and kernel._block_bytes(
+                blk, n, D, dtype, rotary, False) <= kernel._VMEM_BUDGET}
+        print(f"head_prologue {label}: xla over kernel {row['xla_over_kernel']}  "
+              f"forward by row block {row['fwd_ms_by_block']}", flush=True)
+        out["shapes"][label] = row
+    return out
+
+
 def _ledger_swap(step_bytes_xla, region_bytes_xla, region_bytes_kernel,
                  flops, peaks) -> dict:
     """The transparent swap arithmetic: whole-step bytes with the
@@ -636,13 +769,17 @@ def main(argv=None) -> int:
     ap.add_argument("--iters", type=int, default=3)
     ap.add_argument("--opt-params", type=int, default=2_000_000,
                     help="synthetic param count for the opt-update micro A/B")
-    ap.add_argument("--only", choices=["moe_rows", "short_conv"], default=None,
+    ap.add_argument("--only", choices=["moe_rows", "short_conv", "head_prologue"],
+                    default=None,
                     help="run one entry alone and write it to --out as it "
                          "is (moe_rows: the held mixtures' row movers "
                          "against XLA's gathers, PERF.md section 6, PR 42; "
                          "short_conv: LFM2's gated short convolution, the "
                          "jax.numpy path against the two Pallas calls and "
-                         "both against float64, PR 44)")
+                         "both against float64, PR 44; head_prologue: q's "
+                         "and k's per-head norm, rotary and heads-major "
+                         "layout at SDAR's and Trinity-Mini's shapes, the "
+                         "same comparison, PR 51)")
     ap.add_argument("--quick", action="store_true",
                     help="skip the in-context step ledgers (traces of the "
                          "full efficientnet/gpt programs)")
@@ -657,7 +794,8 @@ def main(argv=None) -> int:
 
     compile_cache.setup_from_cfg(cfg)  # on the chip: warm across processes
     if args.only:
-        bench = {"moe_rows": bench_moe_rows, "short_conv": bench_short_conv}[args.only]
+        bench = {"moe_rows": bench_moe_rows, "short_conv": bench_short_conv,
+                 "head_prologue": bench_head_prologue}[args.only]
         doc = {"bench": BENCH_SCHEMA, "generated_by": "tools/kernel_bench.py",
                "backend": jax.default_backend(),
                args.only: bench(args.rounds, args.iters)}
