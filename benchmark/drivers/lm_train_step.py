@@ -38,6 +38,7 @@ it) is refused before the device is touched.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 
@@ -251,39 +252,106 @@ class Reference:
         return jax.device_get(out)
 
 
+SMALL_LEAF = 8  # elements: under it a leaf's gradient is read with its module's
+
+
+def plain_adamw_step(adamw: dict, lr: float, p0, m1) -> tuple:
+    """(the gradient a first step applied, its step, its second moment) from
+    the first moment ``m1`` it left (zero moments before, t = 1)."""
+    b1, b2, eps, wd = (adamw[k] for k in ("b1", "b2", "eps", "weight_decay"))
+    g = m1 / (1 - b1)
+    m_hat, v = g, (1 - b2) * jnp.square(g)  # m1 / (1 - b1 ** 1)
+    return g, lr * (m_hat / (jnp.sqrt(v / (1 - b2)) + eps) + wd * p0), v
+
+
+def beyond_one_spacing(p1, want):
+    """``p1 - want`` where the two are further apart than neighbouring
+    values of the parameter's type, 0 where they are equal or neighbours:
+    two orders of the same arithmetic (``p + (u + wd p)(-lr)`` and ``p0 -
+    lr (...)``) round an exact result that lies near a midpoint to either
+    side, and both are right."""
+    want = want.astype(p1.dtype)
+    return jnp.where(p1 == jnp.nextafter(want, p1), 0, p1 - want)
+
+
+def with_its_module(sums: dict, path: str) -> float:
+    """The relative error of the gradient over the leaves of ``path``'s
+    parent module together: the norm of the joined difference over the joined
+    reference's norm. ``sums``: key string -> (parent's key string, squared
+    norm of the difference, squared norm of the reference)."""
+    parent = sums[path][0]
+    diff, ref = (sum(s[i] for s in sums.values() if s[0] == parent) for i in (1, 2))
+    return float(np.sqrt(diff) / max(np.sqrt(ref), 1e-30))
+
+
+def adamw_moments(opt_state):
+    """The one state of the optimizer that holds AdamW's ``mu`` and ``nu``."""
+    adam = [s for s in jax.tree.leaves(
+        opt_state, is_leaf=lambda s: hasattr(s, "mu")) if hasattr(s, "mu")]
+    if len(adam) != 1:
+        raise ValueError("the optimizer state holds no single AdamW moment pair")
+    return adam[0]
+
+
+def sum_of_squares(x):
+    return jnp.sum(jnp.square(x.astype(jnp.float32)))
+
+
+def relative(diff, ref):
+    """The norm of ``diff`` over the norm of ``ref``."""
+    return jnp.sqrt(sum_of_squares(diff)) / jnp.maximum(
+        jnp.sqrt(sum_of_squares(ref)), 1e-30)
+
+
+@functools.lru_cache(maxsize=None)
+def _leaf_errors(adamw: tuple, lr: float):
+    """The jitted per-leaf errors of :func:`first_step_errors`, traced once
+    for one optimizer (a process that reads many seeds calls it for each)."""
+    adamw = dict(adamw)
+
+    def leaf(p0, g_ref, p1, m1, v1):
+        g, step, v = plain_adamw_step(adamw, lr, p0, m1)
+        return {
+            "gradient": relative(g - g_ref, g_ref),
+            "update": relative(beyond_one_spacing(p1, p0 - step), step),
+            "second_moment": relative(v1 - v, v),
+            "gradient_sq": (sum_of_squares(g - g_ref), sum_of_squares(g_ref)),
+        }
+
+    return jax.jit(lambda *trees: jax.tree.map(leaf, *trees))
+
+
 def first_step_errors(adamw: dict, lr: float, before, grads, state) -> dict:
     """Per leaf, of the first step of a fresh state (zero moments, t = 1):
     ``gradient``, the gradient the step applied (AdamW's first moment over 1
     - b1) against the reference's; ``update``, its new parameters against a
     plain AdamW step from ``before`` on the gradient it applied, over the
-    length of that step; ``second_moment`` likewise."""
-    b1, b2, eps, wd = (adamw[k] for k in ("b1", "b2", "eps", "weight_decay"))
-    adam = [s for s in jax.tree.leaves(
-        state.opt_state, is_leaf=lambda s: hasattr(s, "mu")) if hasattr(s, "mu")]
-    if len(adam) != 1:
-        raise ValueError("the optimizer state holds no single AdamW moment pair")
+    length of that step; ``second_moment`` likewise.
 
-    def norm(x):
-        return jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))
-
-    def leaf(p0, g_ref, p1, m1, v1):
-        g = m1 / (1 - b1)
-        m_hat, v = g, (1 - b2) * jnp.square(g)  # m1 / (1 - b1 ** 1)
-        step = lr * (m_hat / (jnp.sqrt(v / (1 - b2)) + eps) + wd * p0)
-        return {
-            "gradient": norm(g - g_ref) / jnp.maximum(norm(g_ref), 1e-30),
-            "update": norm(p1 - (p0 - step)) / jnp.maximum(norm(step), 1e-30),
-            "second_moment": norm(v1 - v) / jnp.maximum(norm(v), 1e-30),
-        }
-
-    errors = jax.jit(lambda *trees: jax.tree.map(leaf, *trees))(
-        before, grads, state.params, adam[0].mu, adam[0].nu
+    ``update`` is held in units the parameter can represent: an element
+    whose new value is the plain step's or a NEIGHBOURING value of its type
+    counts as equal (:func:`beyond_one_spacing`); every other element's
+    difference is summed whole. A leaf of fewer than ``SMALL_LEAF`` elements
+    carries no relative error of its own (one float whose terms nearly cancel
+    reads anything): its ``gradient`` is read over its parent module's
+    leaves together (:func:`with_its_module`)."""
+    adam = adamw_moments(state.opt_state)
+    errors = _leaf_errors(tuple(sorted(adamw.items())), lr)(
+        before, grads, state.params, adam.mu, adam.nu
     )
     flat, _ = jax.tree_util.tree_flatten_with_path(
         jax.device_get(errors), is_leaf=lambda x: isinstance(x, dict) and "update" in x
     )
-    return {jax.tree_util.keystr(path): {k: float(v) for k, v in e.items()}
-            for path, e in flat}
+    out, sums = {}, {}
+    for path, e in flat:
+        key = jax.tree_util.keystr(path)
+        sums[key] = (jax.tree_util.keystr(path[:-1]), *map(float, e.pop("gradient_sq")))
+        out[key] = {k: float(v) for k, v in e.items()}
+    for path, x in jax.tree_util.tree_flatten_with_path(state.params)[0]:
+        if x.size < SMALL_LEAF:
+            key = jax.tree_util.keystr(path)
+            out[key]["gradient"] = with_its_module(sums, key)
+    return out
 
 
 def expert_agreement(chosen, want) -> tuple[float, float]:

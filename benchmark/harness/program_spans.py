@@ -125,7 +125,7 @@ class ProgramSpans:
 
 def device_window(reduction) -> tuple[float, float]:
     """First operation start to last operation end on the first device."""
-    ops = reduction.ops[reduction.devices[0]]
+    ops = reduction.ran(reduction.devices[0])
     return (min(e["start_ns"] for e in ops),
             max(e["start_ns"] + e["dur_ns"] for e in ops))
 
@@ -139,7 +139,7 @@ def device_gaps(reduction, lo: float | None = None,
     first, last = device_window(reduction)
     lo = first if lo is None else lo
     hi = last if hi is None else hi
-    ops = reduction.ops[reduction.devices[0]]
+    ops = reduction.ran(reduction.devices[0])
     _, merged = trace.interval_union(
         (max(e["start_ns"], lo), min(e["start_ns"] + e["dur_ns"], hi))
         for e in ops if e["start_ns"] < hi and e["start_ns"] + e["dur_ns"] > lo

@@ -29,7 +29,12 @@ Definitions, per device plane and then averaged over the devices used:
   window with no operation on the device; it is attributed to the benchmark's
   host span that covers most of it (what the host was doing meanwhile).
 * scope time: the sum of operation durations whose ``op_name`` path holds the
-  named scope (``optimizer_update``), autodiff decorations unwrapped.
+  named scope (``optimizer_update``), autodiff decorations unwrapped. A
+  ``while``, ``conditional`` or ``call`` is an event AND so is every operation
+  of its body: the sums take the body's operations and leave the enclosing
+  event out, so a scanned loop is counted once; busy time is a union and
+  takes both (the loop's own control between two body operations is time the
+  device was busy).
 * collective time: operations whose HLO name is a collective; its exposed
   share is the part of their union during which no other operation ran on
   that device.
@@ -48,6 +53,7 @@ DEVICE_PLANE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 ASYNC_LINE = "Async XLA Ops"
 WINDOW_SPAN = SPAN_PREFIX + "window"
+ENCLOSING = ("while", "conditional", "call")  # each spans its body's operations
 COLLECTIVES = (
     "all-reduce", "all-gather", "reduce-scatter", "collective-permute",
     "all-to-all",
@@ -192,6 +198,8 @@ class Reduction:
 
     def __init__(self, events: list[dict]):
         self.ops = collections.defaultdict(list)  # plane -> operations run
+        # plane -> while / conditional / call events: in the busy union, in no sum
+        self.enclosing = collections.defaultdict(list)
         # plane -> asynchronous pairs, start to done: a transfer in flight
         # overlaps compute, so a lifetime is not time the device was busy
         self.lifetimes = collections.defaultdict(list)
@@ -202,8 +210,9 @@ class Reduction:
             elif ev["line"] == ASYNC_LINE:
                 self.lifetimes[ev["plane"]].append(ev)
             elif ev["dur_ns"] > 0:
-                self.ops[ev["plane"]].append(ev)
-        self.devices = sorted(self.ops)
+                kind = self.enclosing if ev["opcode"] in ENCLOSING else self.ops
+                kind[ev["plane"]].append(ev)
+        self.devices = sorted({*self.ops, *self.enclosing})
         self._window = self._find_window()
 
     @classmethod
@@ -216,9 +225,16 @@ class Reduction:
         if marks:
             s = max(marks, key=lambda m: m["dur_ns"])
             return s["start_ns"], s["start_ns"] + s["dur_ns"]
-        starts = [e["start_ns"] for ops in self.ops.values() for e in ops]
-        ends = [e["start_ns"] + e["dur_ns"] for ops in self.ops.values() for e in ops]
-        return (min(starts), max(ends)) if starts else (0.0, 0.0)
+        ran = [e for d in self.devices for e in self.ran(d)]
+        if not ran:
+            return 0.0, 0.0
+        return (min(e["start_ns"] for e in ran),
+                max(e["start_ns"] + e["dur_ns"] for e in ran))
+
+    def ran(self, d) -> list:
+        """Every event in which device ``d`` was busy: its operations and
+        the events that enclose some of them."""
+        return self.ops[d] + self.enclosing[d]
 
     def _clipped(self, events):
         """(start, end) of each event, clipped to the window."""
@@ -240,7 +256,7 @@ class Reduction:
     # -- busy and idle --------------------------------------------------------
     def busy_s(self) -> float:
         return self._mean_over_devices(
-            lambda d: interval_union(self._clipped(self.ops[d]))[0]
+            lambda d: interval_union(self._clipped(self.ran(d)))[0]
         ) / 1e9
 
     def idle_frac(self) -> float | None:
@@ -255,7 +271,7 @@ class Reduction:
         if not self.devices:
             return []
         lo, hi = self._window
-        _, merged = interval_union(self._clipped(self.ops[self.devices[0]]))
+        _, merged = interval_union(self._clipped(self.ran(self.devices[0])))
         edges = [lo] + [x for iv in merged for x in iv] + [hi]
         gaps = [
             (edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
@@ -336,8 +352,10 @@ class Reduction:
 
     def describe(self) -> str:
         n_ops = sum(len(v) for v in self.ops.values())
+        n_enclosing = sum(len(v) for v in self.enclosing.values())
         return (
             f"{len(self.devices)} device plane(s), {n_ops} operation events, "
+            f"{n_enclosing} enclosing ({'/'.join(ENCLOSING)}) left out of the sums, "
             f"{len(self.spans)} host spans, window {self.window_s():.4f} s, "
             f"busy {self.busy_s():.4f} s"
         )

@@ -1,12 +1,11 @@
-"""The two readers of the ``attn_prologue`` scope (PR 51), and the entries a
-``benchmark`` PR appends to ``BENCHMARK.json`` ``per_layer`` for them: this PR
-could not (an accepted test holds SDAR's cell to the metrics it had and the
-list's last two entries to SDAR's own; ``PERF.md`` section 7), so the entries
-wait here, as the serving cell's wait in ``benchmark/pending/``. Their form,
-their sums on synthetic events of a program that runs the
-``dtpu_head_prologue_*`` kernels, of one where XLA still runs the chain
-(LFM2's heads of 64, and any parent of PR 51 laid over with these files) and
-of one without the scope, and the bytes the roofline counts by itself."""
+"""The two readers of the ``attn_prologue`` scope (PR 51) and their entries
+in ``BENCHMARK.json`` ``per_layer``, which PR 52 appended as ``ENTRIES`` has
+them (they waited here while an accepted test held the list's last two
+entries to SDAR's own; ``PERF.md`` section 7). Their form, their sums on
+synthetic events of a program that runs the ``dtpu_head_prologue_*`` kernels,
+of one where XLA still runs the chain (LFM2's heads of 64, and any parent of
+PR 51 laid over with these files) and of one without the scope, and the bytes
+the roofline counts by itself."""
 
 import pytest
 
@@ -53,16 +52,19 @@ def observed_for(cell_name, events, counters):
 
 
 def test_the_two_entries_are_of_the_manifests_form_and_name_what_is_there():
-    accepted = CATALOG.benchmark
-    layers = {m["layer"] for m in accepted["per_layer"]}
+    accepted = {m["name"]: m for m in CATALOG.benchmark["per_layer"]}
+    layers = {m["layer"] for m in accepted.values() if m["name"] not in ENTRIES}
     for name, entry in ENTRIES.items():
         assert set(entry) == {"name", "unit", "better", "source", "layer", "moves",
                               "workloads"}
-        assert name not in {m["name"] for m in accepted["per_layer"]}
+        assert accepted[name] == entry  # appended as it waited here (PR 52)
         assert entry["layer"] in layers
         assert callable(reader(name).read)  # declaration equals the entry
         for cell in entry["workloads"]:
             assert entry["moves"] in {m["name"] for m in CATALOG.cell(cell).end_to_end}
+            assert name in {m["name"] for m in CATALOG.cell(cell).per_layer}
+    # LFM2's cell reads the scope's time and is held to no share of a peak
+    assert ROOFLINE not in {m["name"] for m in CATALOG.cell(LFM2).per_layer}
 
 
 def test_the_roofline_counts_its_own_bytes_from_the_architecture():

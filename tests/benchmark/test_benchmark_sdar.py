@@ -84,7 +84,6 @@ def test_the_configuration_is_the_published_one_cut_in_depth_experts_held_and_vo
     assert entry["reduced"] == body["reduced"] == ["layers", "experts_held", "vocab_held"]
     assert entry["source"] == body["source"] == SOURCE
     assert entry["file"] == "benchmark/configs/sdar_30b_a3b.json"
-    assert CATALOG.benchmark["configs"][-1] is entry  # appended
     want = published()
     assert (want["num_hidden_layers"], want["num_experts"], want["vocab_size"],
             want["norm_topk_prob"], want["mlp_only_layers"]) == (48, 128, 151936, True, [])
@@ -152,11 +151,11 @@ def test_the_cell_is_one_chip_on_its_own_traffic_and_driver_by_appended_entries(
     share = CATALOG.traffic("train_device_tokens_share")
     for key in ("warmup_steps", "chunk_steps", "trace_steps"):
         assert cell.traffic[key] == share[key]
-    assert CATALOG.benchmark["workloads"][-1]["name"] == CELL  # appended
+    assert CELL in [w["name"] for w in CATALOG.benchmark["workloads"]]
     assert {m["name"] for m in cell.end_to_end} == {
         "train_items_per_s_per_chip", "setup_s"}
     names = {m["name"] for m in cell.per_layer}
-    assert names == {
+    assert names >= {
         *NEW, "models.mfu", "models.fwd_bwd_ms_per_step", "models.fwd_ms_per_step",
         "models.bwd_ms_per_step", "kernels.opt_update_ms_per_step",
         "kernels.opt_update_roofline", "kernels.opt_kernel_ms_per_step",
@@ -169,22 +168,20 @@ def test_the_cell_is_one_chip_on_its_own_traffic_and_driver_by_appended_entries(
     # the mask is a mode of the two accepted kernels: no second roofline name
     assert not [m for m in CATALOG.benchmark["per_layer"]
                 if "roofline" in m["name"] and "diffusion" in m["name"]]
-    assert [m["name"] for m in CATALOG.benchmark["per_layer"][-2:]] == list(NEW)
-    for m in CATALOG.benchmark["per_layer"][-2:]:
-        assert m["workloads"] == [CELL] and m["layer"] == "models"
+    mine = [m for m in CATALOG.benchmark["per_layer"] if m["name"] in NEW]
+    assert [m["name"] for m in mine] == list(NEW)
+    for m in mine:
+        assert CELL in m["workloads"] and m["layer"] == "models"
         assert m["moves"] == "train_items_per_s_per_chip"
-    for m in CATALOG.benchmark["per_layer"]:
-        if "workloads" in m and CELL in m["workloads"]:
-            assert m["workloads"][-1] == CELL  # appended to its list
     # the cells the benchmark had report neither of the new two
     for other in ("resnet50.train", "olmoe_1b_7b.train_seq4096",
                   "glm_4_7_flash.train_seq8192", "trinity_mini.train_seq8192"):
         assert not {m["name"] for m in CATALOG.cell(other).per_layer} & set(NEW)
     why = [w for w in CATALOG.benchmark["workloads"] if w["name"] == CELL][0]["why"]
     assert len(why) <= 200 and "1/8" in why and "16,384 rows" in why and "[MASK]" in why
-    four = [w for w in CATALOG.benchmark["workloads"] if w["chips"] == 4]
-    assert [w["name"] for w in four] == ["resnet50.train_dp4"]
-    assert len(CATALOG.benchmark["workloads"]) == 10 and len(CATALOG.benchmark["configs"]) == 8
+    # the cell asks for no second four-chip cell: the one there stays
+    four = [w["name"] for w in CATALOG.benchmark["workloads"] if w["chips"] == 4]
+    assert CELL not in four and "resnet50.train_dp4" in four
 
 
 def test_the_configuration_states_the_sizes_the_program_builds():

@@ -129,6 +129,51 @@ def test_scope_time_top_ops_and_per_device_mean():
     assert top[0][1] == pytest.approx(20e-3)
 
 
+@pytest.mark.parametrize("enclosing", [
+    "%while.3 = (s32[], f32[8]{0}) while((s32[], f32[8]{0}) %tuple.1), "
+    "condition=%cond.1, body=%body.1",
+    "%conditional.2 = f32[8]{0} conditional(pred[] %p, f32[8]{0} %a, f32[8]{0} %b), "
+    "true_computation=%t, false_computation=%f",
+    "%call.5 = f32[8]{0} call(f32[8]{0} %a), to_apply=%scanned",
+])
+def test_a_loop_is_counted_once(enclosing):
+    """A ``while`` (or ``conditional``, ``call``) is an event AND so is every
+    operation of its body: the sums and the breakdown take the body alone,
+    the busy union takes both (the loop's control between two body
+    operations is busy time)."""
+    scope = "jit(train_step)/jvp(fwd)/Model/mixer/while/body/dot_general"
+    events = [
+        span("window", 0, 100),
+        op(enclosing, 10, 60, scope),            # [10, 70): spans its body
+        op("fusion.1", 12, 10, scope),           # the body's three operations
+        op("fusion.2", 30, 10, scope),
+        op("fusion.1", 50, 10, scope),
+        op("fusion.9", 80, 10, "jit(train_step)/optimizer_update/mul"),
+    ]
+    r = Reduction(events)
+    assert r.scope_s("mixer") == pytest.approx(30e-9)  # not 90
+    assert r.seconds_where(lambda e: True) == pytest.approx(40e-9)
+    assert [name.split(" ")[0] for name, _ in r.top_ops(5)] == ["fusion.1", "fusion.2", "fusion.9"]
+    assert r.top_ops(1)[0][1] == pytest.approx(20e-9)
+    # busy: [10, 70) and [80, 90), as it read while the loop was a summand
+    assert r.busy_s() == pytest.approx(70e-9)
+    assert r.idle_frac() == pytest.approx(0.3)
+    assert [g[1] for g in r.idle_gaps(5)] == pytest.approx([10e-9, 10e-9, 10e-9])
+    assert "5 operation events" not in r.describe()
+    assert "4 operation events, 1 enclosing" in r.describe()
+    # a plane that holds nothing but the loop's own event is still a device
+    alone = Reduction([op(enclosing, 0, 10)])
+    assert alone.devices == ["/device:TPU:0"] and alone.busy_s() == pytest.approx(10e-9)
+    assert alone.seconds_where(lambda e: True) == 0 and alone.top_ops(3) == []
+
+
+def test_a_collective_inside_a_loop_is_not_hidden_by_the_loop():
+    loop = "%while.1 = (f32[8]{0}) while((f32[8]{0}) %t), condition=%c, body=%b"
+    r = Reduction([op(loop, 0, 100), op("all-reduce.1", 10, 40), op("fusion.1", 30, 40)])
+    assert r.collective_s() == pytest.approx(40e-9)
+    assert r.collective_exposed_frac() == pytest.approx(0.5)  # [10, 30) of [10, 50)
+
+
 def test_collective_time_and_exposed_share():
     # synchronous collective [0, 100); compute [50, 150): half hidden
     half = Reduction([op("all-reduce.1", 0, 100), op("fusion.1", 50, 100)])
