@@ -1,7 +1,7 @@
 """The Pallas kernel tier (ISSUE 13): fused kernels for the memory-bound
 programs the cost ledger pinned, as ONE subsystem instead of one-offs.
 
-Seven kernels, one discipline:
+Eight kernels, one discipline:
 
 * ``opt_update``     — fused optimizer update (opt_update.py): ONE HBM
                        pass over params+grads+moments for SGD-momentum
@@ -50,6 +50,15 @@ Seven kernels, one discipline:
                        passes. No knob: it runs in a
                        one-device TPU program where a head fills the
                        lanes and a row block divides the length.
+* ``ssd``            — Mamba-2's chunked scan (ssd.py; ops/ssd.py has
+                       the mathematics and the ``jax.numpy`` body): the
+                       chunks walked in order with the state in VMEM, a
+                       head's masked-decay matrix built in registers and
+                       fed to the MXU, ONE call each way where XLA sent
+                       the ``[L, L]`` matrices and the chunks' states
+                       through HBM. No knob: it runs in a one-device TPU
+                       program where the chunk is the 128 lanes and a
+                       group's heads fill whole lane tiles.
 
 Tier discipline (every kernel, no exceptions):
 
@@ -110,14 +119,14 @@ KNOBS = {
 # ops without a knob: ``auto``, or ``pallas`` where the caller forces it
 # (``flash_attn`` is ops/flash_attention.py's two kernels, outside this
 # package; it resolves here so that its record sits beside the others)
-KNOBLESS = ("moe_gmm", "moe_rows", "flash_attn", "short_conv", "head_prologue")
+KNOBLESS = ("moe_gmm", "moe_rows", "flash_attn", "short_conv", "head_prologue", "ssd")
 
 # ops that have no shard_map of their own: they engage in a program their
 # caller declared one-device (module docstring; ``moe_gmm``'s caller
 # declares it inside its shard_map over the data axis, as ``flash_attn``'s
 # does where it was handed a mesh)
 _NO_SHARD_MAP = ("conv_epilogue", "decode_attn", "moe_gmm", "moe_rows", "flash_attn",
-                 "short_conv", "head_prologue")
+                 "short_conv", "head_prologue", "ssd")
 
 # process-lifetime emission/warn dedup: one kernel.select per (op, impl,
 # requested) resolution, one kernel.fallback + warning per (op, reason)
@@ -239,7 +248,7 @@ def _emit_once(key, kind: str, **fields) -> None:
 
 
 def select(op: str, *, supported: bool = True, reason: str = "",
-           forced: bool = False, **detail) -> str:
+           forced: bool = False, work: dict | None = None, **detail) -> str:
     """Resolve which impl runs for ``op`` right now: ``"pallas"`` or
     ``"xla"``. The ONE policy point of the tier:
 
@@ -257,7 +266,8 @@ def select(op: str, *, supported: bool = True, reason: str = "",
 
     Every resolution emits ``kernel.select`` once per process (the
     run_report ``kernels`` section's source), with ``detail`` (the tiles
-    a kernel chose) beside the impl.
+    a kernel chose) beside the impl, and ``work`` (the operation's own
+    shape, ``ssd``'s chunking) whichever impl runs.
     """
     if op not in KNOBS and op not in KNOBLESS:
         raise ValueError(
@@ -301,8 +311,7 @@ def select(op: str, *, supported: bool = True, reason: str = "",
         if impl == "xla" and op in KNOBLESS:
             _emit_once(("fb", op, reason), "kernel.fallback", op=op,
                        requested=req, reason=reason)
-    if impl == "xla":
-        detail = {}
+    detail = {**(work or {}), **({} if impl == "xla" else detail)}
     _emit_once(("sel", op, impl, req, *sorted(detail.items())),
                "kernel.select", op=op, impl=impl, requested=req, **detail)
     return impl

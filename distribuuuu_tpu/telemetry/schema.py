@@ -155,8 +155,9 @@ KINDS: dict[str, frozenset] = {
     # lane_chunk, taps, channels, tokens; `head_prologue`
     # (ops/pallas/head_prologue.py) rows, heads, head_dim, rotary,
     # row_block, row_chunk; `ssd` (ops/ssd.py: Mamba-2's chunked scan, impl
-    # "xla" until a kernel exists) chunk, chunks_a_sequence, heads, groups,
-    # state, head_dim
+    # "pallas" where ops/pallas/ssd.py's two calls run it and "xla" where
+    # the jax.numpy body does: whichever, the operation's own shape) chunk,
+    # chunks_a_sequence, heads, groups, state, head_dim
     "kernel.select": frozenset({"op", "impl", "requested"}),
     # a forced-but-unsupported site degrading to the XLA reference, with
     # the disqualifying reason (also warn-once logged)
@@ -367,6 +368,9 @@ KERNEL_NAMES: tuple[str, ...] = (
     # ops/pallas/head_prologue.py: _fwd, _bwd (under ``attn_prologue``: q's
     # and k's norm, rotary and layout, one call each way)
     "dtpu_head_prologue",
+    # ops/pallas/ssd.py: _fwd, _bwd (under ``ssm_scan``: Mamba-2's chunked
+    # scan, one call each way)
+    "dtpu_ssd",
 )
 
 

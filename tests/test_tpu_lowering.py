@@ -545,6 +545,47 @@ def test_short_conv_compiles_for_the_v5e_whole_in_and_whole_out(v5e_chip):
     assert not activations, activations
 
 
+def test_ssd_compiles_for_the_v5e_with_no_decay_matrix_and_no_chunk_state_beside_it(
+        v5e_chip):
+    """Mamba-2's chunked scan at ``nemotron_3_super_120b_a12b.train_seq8192``'s
+    shape (``[1, 8192, 16, 64]`` bf16 on one group of a 128 state, 64 chunks
+    of 128), forward and backward: Mosaic takes the chunk's blocks, the
+    transposed-LHS contractions, the 128 x 128 transposes and the lane sums
+    in the VMEM the calls ask for; and beside the two calls the program holds
+    nothing the size of the decay matrices or of the chunks' states but the
+    states entering each chunk, which go from one call to the other."""
+    from jax.sharding import SingleDeviceSharding
+
+    from distribuuuu_tpu.ops import ssd as op
+
+    chip = SingleDeviceSharding(v5e_chip)
+    B, S, H, P, G, N = 1, 8192, 16, 64, 1, 128
+    f32, bf16 = jnp.float32, jnp.bfloat16
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    args = (aval((B, S, H, P), bf16), aval((B, S, H), f32), aval((H,), f32),
+            aval((B, S, G, N), bf16), aval((B, S, G, N), bf16), aval((H,), f32))
+    cotangents = (aval((B, S, H, P), f32), aval((B, H, P, N), f32))
+
+    def step(args, cotangents):
+        out, vjp = jax.vjp(lambda *t: op.ssd(*t, interpret=False), *args)
+        return out, vjp(cotangents)
+
+    text = jax.jit(step).lower(args, cotangents).compile().as_text()
+    entry = [line.strip() for line in text[text.index("\nENTRY "):].splitlines()
+             if " = " in line]
+    calls = [line for line in entry if "custom-call(" in line]
+    assert sorted(line.split(" = ")[0].lstrip("%").rsplit(".", 1)[0] for line in calls) == [
+        "dtpu_ssd_bwd", "dtpu_ssd_fwd"]
+    chunks = S // op.CHUNK
+    assert not [line[:160] for line in entry if f"[{B},{chunks},{H}," in line
+                or f",{op.CHUNK},{op.CHUNK}]" in line.split(" = ")[1].split("(")[0]]
+    states = [line for line in entry if f"f32[{B},{chunks},{N},{H * P}]" in line.split("(")[0]]
+    assert all("custom-call(" in line or "get-tuple-element(" in line for line in states)
+
+
 @pytest.mark.parametrize("batch,seq,heads,rotary", [
     (1, 16384, 32, True), (1, 16384, 4, True), (2, 8192, 32, False), (2, 8192, 4, False),
 ], ids=["sdar_q", "sdar_k", "trinity_q_full", "trinity_k_full"])

@@ -608,6 +608,95 @@ def bench_head_prologue(rounds: int, iters: int) -> dict:
     return out
 
 
+# B, S, H, P, G, N: the Mamba-2 heads Nemotron-3-Super's cell holds
+SSD_SHAPE = (1, 8192, 16, 64, 1, 128)
+
+
+def bench_ssd(rounds: int, iters: int) -> dict:
+    """Mamba-2's chunked scan ALONE at the cell's shape (``[1, 8192, 16, 64]``
+    bfloat16 on one group of a 128 state, chunks of 128), ``ops/ssd.py``'s
+    ``jax.numpy`` body under autodiff against the two Pallas calls
+    (``ops/pallas/ssd.py``): forward and forward + backward in ms and as a
+    share of the HBM's bandwidth on the bytes the calls' own operands and
+    results are (``x``, ``dt``, ``b``, ``c`` in and ``y`` out; backward those,
+    ``dy`` and the four gradients), and each path's y, last state and six
+    gradients against the body in float32 at full precision on the same
+    bfloat16 inputs (largest error over the reference's largest value). Off
+    the TPU: two chunks through the interpreter, which rehearses the path and
+    times nothing worth keeping."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distribuuuu_tpu.ops import ssd as op
+    from distribuuuu_tpu.telemetry import costmodel
+
+    on_chip = jax.default_backend() == "tpu"
+    B, S, H, P, G, N = SSD_SHAPE if on_chip else (1, 256, 16, 64, 1, 128)
+    if on_chip:  # a call is under a millisecond: amortise its dispatch
+        iters = max(iters, 20)
+    dtype, f32 = jnp.bfloat16, jnp.float32
+    keys = jax.random.split(jax.random.key(0), 8)
+    args = (
+        jax.random.normal(keys[0], (B, S, H, P)).astype(dtype),
+        jnp.exp(jax.random.uniform(keys[1], (B, S, H), minval=np.log(1e-3),
+                                   maxval=np.log(0.1))),
+        -jax.random.uniform(keys[2], (H,), minval=1.0, maxval=16.0),
+        jax.random.normal(keys[3], (B, S, G, N)).astype(dtype),
+        jax.random.normal(keys[4], (B, S, G, N)).astype(dtype),
+        jax.random.normal(keys[5], (H,)),
+    )
+    cotangents = (jax.random.normal(keys[6], (B, S, H, P)),
+                  jax.random.normal(keys[7], (B, H, P, N)))
+
+    def both_ways(forward):
+        def fwd_bwd(args, cotangents):
+            out, vjp = jax.vjp(forward, *args)
+            return out, vjp(cotangents)
+
+        return jax.jit(forward), jax.jit(fwd_bwd)
+
+    def body(*args):
+        return op._body(*args, op.CHUNK)
+
+    def calls(*args):
+        return op.ssd(*args, interpret=not on_chip)
+
+    with jax.default_matmul_precision("highest"):
+        want = both_ways(body)[1](tuple(t.astype(f32) for t in args), cotangents)
+    names = ("y", "last", "dx", "ddt", "da", "db", "dc", "dd")
+    want = dict(zip(names, (np.asarray(t, np.float64) for t in (*want[0], *want[1]))))
+    item = jnp.dtype(dtype).itemsize
+    rows = B * S * (H * P * item + 2 * G * N * item + H * 4)
+    moved = {"fwd": rows + B * S * H * P * 4, "fwd_bwd": 2 * (rows + B * S * H * P * 4)}
+    peaks = costmodel.peaks_for()
+    out = {"shape": [B, S, H, P], "groups": G, "state": N, "chunk": op.CHUNK,
+           "dtype": "bfloat16", "on_chip": on_chip, "ideal_bytes": moved}
+    for name, forward in (("body", body), ("kernel", calls)):
+        fwd, fwd_bwd = both_ways(forward)
+        got = fwd_bwd(args, cotangents)
+        got = dict(zip(names, (*got[0], *got[1])))
+        arm = {"max_err_over_max": {
+            k: float(np.abs(np.asarray(got[k], np.float64) - want[k]).max()
+                     / np.abs(want[k]).max()) for k in names}}
+        for part, fn, operands in (("fwd", fwd, args),
+                                   ("fwd_bwd", fwd_bwd, (args, cotangents))):
+            arm[f"{part}_ms"] = ms = _med_ms(fn, operands, rounds, iters)
+            if on_chip and peaks:
+                arm[f"{part}_hbm_share"] = round(
+                    moved[part] / peaks["bytes_per_s"] / (ms / 1e3), 4)
+        out[name] = arm
+        print(f"ssd {name}: " + "  ".join(
+            f"{part} {arm[f'{part}_ms']} ms ({arm.get(f'{part}_hbm_share')})"
+            for part in ("fwd", "fwd_bwd"))
+            + f"  against float32 {arm['max_err_over_max']}", flush=True)
+    out["body_over_kernel"] = {
+        part: round(out["body"][f"{part}_ms"] / out["kernel"][f"{part}_ms"], 2)
+        for part in ("fwd", "fwd_bwd")}
+    print(f"ssd: body over kernel {out['body_over_kernel']}", flush=True)
+    return out
+
+
 def _ledger_swap(step_bytes_xla, region_bytes_xla, region_bytes_kernel,
                  flops, peaks) -> dict:
     """The transparent swap arithmetic: whole-step bytes with the
@@ -769,7 +858,7 @@ def main(argv=None) -> int:
     ap.add_argument("--iters", type=int, default=3)
     ap.add_argument("--opt-params", type=int, default=2_000_000,
                     help="synthetic param count for the opt-update micro A/B")
-    ap.add_argument("--only", choices=["moe_rows", "short_conv", "head_prologue"],
+    ap.add_argument("--only", choices=["moe_rows", "short_conv", "head_prologue", "ssd"],
                     default=None,
                     help="run one entry alone and write it to --out as it "
                          "is (moe_rows: the held mixtures' row movers "
@@ -779,7 +868,10 @@ def main(argv=None) -> int:
                          "both against float64, PR 44; head_prologue: q's "
                          "and k's per-head norm, rotary and heads-major "
                          "layout at SDAR's and Trinity-Mini's shapes, the "
-                         "same comparison, PR 51)")
+                         "same comparison, PR 51; ssd: Mamba-2's chunked "
+                         "scan at Nemotron-3-Super's shape, ops/ssd.py's "
+                         "body against the two Pallas calls and both "
+                         "against the body in float32, PR 54)")
     ap.add_argument("--quick", action="store_true",
                     help="skip the in-context step ledgers (traces of the "
                          "full efficientnet/gpt programs)")
@@ -795,7 +887,7 @@ def main(argv=None) -> int:
     compile_cache.setup_from_cfg(cfg)  # on the chip: warm across processes
     if args.only:
         bench = {"moe_rows": bench_moe_rows, "short_conv": bench_short_conv,
-                 "head_prologue": bench_head_prologue}[args.only]
+                 "head_prologue": bench_head_prologue, "ssd": bench_ssd}[args.only]
         doc = {"bench": BENCH_SCHEMA, "generated_by": "tools/kernel_bench.py",
                "backend": jax.default_backend(),
                args.only: bench(args.rounds, args.iters)}
